@@ -59,18 +59,13 @@ def run_dir() -> Path:
 
 
 def service_env() -> dict[str, str]:
-    """Environment for spawned services: the operator's env plus
-    persistent cache defaults so every child of one fleet shares them —
-    ``PIO_COMPILATION_CACHE_DIR`` (under the run dir) so `pio start-all`
-    restarts skip XLA recompiles, and ``PIO_PREP_CACHE_DIR`` (the
+    """Environment for spawned services: the operator's env (which
+    already carries the compile-cache placement made at package import —
+    predictionio_tpu/__init__.py) plus ``PIO_PREP_CACHE_DIR`` (the
     resolved prep-cache dir) so supervisor-scheduled warm retrains hit
     the same packed-prep entries the deploy-time train published. An
     explicit env var (even empty, to disable) wins."""
     env = dict(os.environ)
-    if "PIO_COMPILATION_CACHE_DIR" not in env:
-        cache = run_dir() / "jit_cache"
-        cache.mkdir(parents=True, exist_ok=True)
-        env["PIO_COMPILATION_CACHE_DIR"] = str(cache)
     if "PIO_PREP_CACHE_DIR" not in env:
         from predictionio_tpu.core import prep_cache
 

@@ -893,7 +893,10 @@ def cmd_train(args) -> int:
         engine_factory=factory,
         workflow_params=wp,
     )
-    print(f"Training completed. Engine instance ID: {instance_id}")
+    from predictionio_tpu.obs import device as obs_device
+
+    where = ", ".join(f"{k}: {v}" for k, v in obs_device.where().items())
+    print(f"Training completed. Engine instance ID: {instance_id} ({where})")
     return 0
 
 
@@ -1026,30 +1029,6 @@ def _run_workers(args) -> int:
     return rc
 
 
-def _maybe_enable_compilation_cache() -> None:
-    """Wire jax's persistent compilation cache when
-    ``PIO_COMPILATION_CACHE_DIR`` is set (the daemon defaults it for
-    fleet services): deploy warmup compiles land on disk, so a restarted
-    server skips recompiles entirely — the first-query latency spike
-    dies at most once per (program, jax version) per machine."""
-    cache_dir = os.environ.get("PIO_COMPILATION_CACHE_DIR")
-    if not cache_dir:
-        return
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every program: serving top-k programs compile fast but
-        # re-compile on every restart without this
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - cache is an optimization
-        print(
-            f"persistent compilation cache unavailable ({e}); continuing",
-            file=sys.stderr,
-        )
-
-
 def cmd_deploy(args) -> int:
     from predictionio_tpu.data.storage import get_storage
     from predictionio_tpu.server.engine_server import EngineServer
@@ -1057,7 +1036,6 @@ def cmd_deploy(args) -> int:
     rc = _maybe_run_workers(args)
     if rc is not None:
         return rc
-    _maybe_enable_compilation_cache()
 
     engine, variant, factory = _engine_from_args(args)
     storage = get_storage()
@@ -1119,8 +1097,8 @@ def cmd_deploy(args) -> int:
         extra_variants=extra_variants,
     )
     # AOT warmup BEFORE the port binds: the first real query hits a
-    # compiled scoring program (and, with PIO_COMPILATION_CACHE_DIR, the
-    # compile itself persists across restarts)
+    # compiled scoring program (and the compile itself persists across
+    # restarts in the persistent cache — predictionio_tpu/__init__.py)
     if not getattr(args, "no_warmup", False):
         server.warmup()
     layers = []
@@ -2249,9 +2227,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
-    from predictionio_tpu.utils import apply_platform_env
-
-    apply_platform_env()  # honor JAX_PLATFORMS even under plugin boot hooks
     parser = build_parser()
     raw = sys.argv[1:] if argv is None else list(argv)
     if raw[:1] == ["help"]:

@@ -197,20 +197,34 @@ def sharded_pack_key(params, shards: int, mode: str) -> str:
 # ---------------------------------------------------------------------------
 
 
+# ids hashed per vectorized step: the step expands every id BYTE into
+# five 8-byte temporaries, so one step over a 20M-event corpus (~640M id
+# bytes) needed ~25 GB and ended `pio train` on a 40 GiB host. 2**18 ids
+# keep a step's temporaries near 0.3 GB; hashes are per id, so chunking
+# cannot change them.
+_HASH_CHUNK_IDS = 1 << 18
+
+
 def hash_event_ids(ids: list) -> np.ndarray | None:
     """Vectorized 64-bit polynomial hash of event-id strings; ``None``
     when any id is missing/empty (those entries can't be dedupe-checked,
     so the entry becomes exact-hit-only). Identical ids always hash
     equal — a true duplicate is never missed; distinct ids colliding
     only forces a spurious (safe) rebuild."""
-    if any(s is None for s in ids):
+    out = np.empty(len(ids), dtype=np.uint64)
+    for lo in range(0, len(ids), _HASH_CHUNK_IDS):
+        h = _hash_id_chunk(ids[lo: lo + _HASH_CHUNK_IDS])
+        if h is None:
+            return None
+        out[lo: lo + len(h)] = h
+    return out
+
+
+def _hash_id_chunk(ids: list) -> np.ndarray | None:
+    if any(not s for s in ids):
         return None
-    if not ids:
-        return np.zeros(0, dtype=np.uint64)
     enc = [s.encode("utf-8") for s in ids]
     lens = np.fromiter((len(b) for b in enc), np.int64, len(enc))
-    if (lens == 0).any():
-        return None
     starts = np.zeros(len(enc) + 1, dtype=np.int64)
     np.cumsum(lens, out=starts[1:])
     blob = np.frombuffer(b"".join(enc), dtype=np.uint8).astype(np.uint64)

@@ -386,6 +386,35 @@ class ModelFile:
     def _decode_bimap(self, fs: dict) -> BiMap:
         return _LazyDenseBiMap(self._arr(fs["blob"]), self._arr(fs["offs"]))
 
+    def fields(self, i: int) -> dict[str, Any]:
+        """The decoded fields of ``arrays`` entry ``i`` — arrays as
+        read-only views of this buffer, BiMaps lazy — WITHOUT importing
+        the model's class: what a reader that only wants the numbers
+        (chip_smoke.py's NumPy reference) needs."""
+        ent = self._header["entries"][i]
+        if ent["kind"] != "arrays":
+            raise ModelFileError(f"entry {i} is {ent['kind']!r}, not arrays")
+        out: dict[str, Any] = {}
+        for fname, fs in ent["fields"].items():
+            t = fs["t"]
+            if t == "array":
+                a = self._arr(fs["block"])
+                shape = fs.get("shape")
+                if shape is not None:
+                    a = a.reshape(shape)
+                out[fname] = a
+            elif t == "bimap":
+                out[fname] = self._decode_bimap(fs)
+            elif t == "none":
+                out[fname] = None
+            elif t == "json":
+                out[fname] = fs["v"]
+            else:
+                raise ModelFileError(
+                    f"entry {i} field {fname}: unknown type {t!r}"
+                )
+        return out
+
     def entries(self) -> list[tuple[str, Any]]:
         """Decode to persistence-manifest shape: ``(kind, payload)`` with
         ``arrays`` payloads reconstructed as model objects whose array
@@ -407,27 +436,8 @@ class ModelFile:
                     raise ModelFileError(
                         f"entry {i}: {mod_name}.{qual} is not a model dataclass"
                     )
-                kwargs: dict[str, Any] = {}
-                for fname, fs in ent["fields"].items():
-                    t = fs["t"]
-                    if t == "array":
-                        a = self._arr(fs["block"])
-                        shape = fs.get("shape")
-                        if shape is not None:
-                            a = a.reshape(shape)
-                        kwargs[fname] = a
-                    elif t == "bimap":
-                        kwargs[fname] = self._decode_bimap(fs)
-                    elif t == "none":
-                        kwargs[fname] = None
-                    elif t == "json":
-                        kwargs[fname] = fs["v"]
-                    else:
-                        raise ModelFileError(
-                            f"entry {i} field {fname}: unknown type {t!r}"
-                        )
                 try:
-                    out.append(("arrays", cls(**kwargs)))
+                    out.append(("arrays", cls(**self.fields(i))))
                 except TypeError as e:
                     raise ModelFileError(
                         f"entry {i}: {qual}(**fields) failed: {e}"

@@ -50,6 +50,7 @@ __all__ = [
     "compile_snapshot",
     "ensure_device_gauges",
     "device_block",
+    "where",
     "profile_capture",
     "profile_active",
 ]
@@ -298,6 +299,20 @@ def ensure_device_gauges() -> bool:
             ).set(float(n))
         _gauges_registered = True
         return True
+
+
+def where() -> dict:
+    """Which device this process computes on, as jax reports it — the
+    fields a trainer publishes and ``pio train`` prints so that a run
+    which quietly landed on the CPU says so. Initializes the backend."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
 
 
 def device_block() -> dict:

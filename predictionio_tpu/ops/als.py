@@ -543,7 +543,7 @@ def _gramian_rhs(vg, w, r):
     ``factors_other[col_ids]`` gather to materialize [B,K,D] in HBM,
     breaking XLA's fusion of gather+gramian+solve+scatter inside the
     fused training program. The kernel was deleted (git history:
-    ops/als_pallas.py); numbers recorded in BASELINE.md and bench.py.
+    ops/als_pallas.py).
     """
     # f32 inputs get HIGHEST precision so TPU hardware doesn't silently
     # decompose the matmul to bf16 passes (RMSE-parity requirement);
@@ -993,7 +993,8 @@ def als_train(
     nnz = len(data.vals)
     prog = obs_progress.ProgressPublisher(
         params.iterations, tol=tol, mesh="single", trainer="single",
-        warm_start=warm_start is not None, **(progress_extra or {}),
+        warm_start=warm_start is not None, **obs_device.where(),
+        **(progress_extra or {}),
     )
     t0 = _time.perf_counter()
     final_rmse = None

@@ -84,7 +84,6 @@ from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.obs import progress as obs_progress
 from predictionio_tpu.ops import als as als_ops
-from predictionio_tpu.parallel.compat import shard_map
 from predictionio_tpu.parallel.mesh import factor_sharding, replicated_sharding
 
 logger = logging.getLogger(__name__)
@@ -817,7 +816,7 @@ def _fused_trainer(mesh: Mesh, axis: str, mode: str, params: als_ops.ALSParams):
         # int8 factor tables are (values, scales) pairs: spell out the
         # matching spec structure (both leaves row-sharded over axis)
         other_spec = (P(axis), P(axis)) if isinstance(other, tuple) else P(axis)
-        x = shard_map(
+        x = jax.shard_map(
             functools.partial(shard_fn, R),
             mesh=mesh,
             in_specs=(other_spec, P(axis), P(axis), P(axis), P(axis)),
@@ -1069,7 +1068,8 @@ def sharded_als_train(
     # original-order (rows, cols) pairs would be wrong
     prog = obs_progress.ProgressPublisher(
         params.iterations, mesh=mesh_desc, trainer="sharded",
-        warm_start=warm_start is not None, **(progress_extra or {}),
+        warm_start=warm_start is not None, **obs_device.where(),
+        **(progress_extra or {}),
     )
     # multi-host: every host runs this loop; one writer is enough
     prog.enabled = prog.enabled and jax.process_index() == 0

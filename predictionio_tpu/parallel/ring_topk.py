@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.ops.als import dequantize_rows
 from predictionio_tpu.ops.topk import NEG_INF
-from predictionio_tpu.parallel.compat import pcast_varying, shard_map
 
 
 @functools.partial(
@@ -116,7 +115,7 @@ def _ring_topk_device(
         b = q_blk.shape[0]
         # constants must be marked device-varying to sit in a shard_map
         # scan carry alongside the ppermute'd (varying) shard arrays
-        varying = lambda x: pcast_varying(x, axis)
+        varying = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         init = (
             v_blk,
             ids_blk,
@@ -128,7 +127,7 @@ def _ring_topk_device(
         return best_s, best_i
 
     v_spec = (P(axis), P(axis)) if quantized else P(axis)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), v_spec, P(axis), P(axis)),

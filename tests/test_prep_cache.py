@@ -318,6 +318,20 @@ class TestFallbacks:
         assert h.status == "off"
 
 
+def test_event_id_hash_does_not_depend_on_the_chunking(monkeypatch):
+    """hash_event_ids works in bounded-memory chunks (one vectorized step
+    over a 20M-event corpus needed ~25 GB); hashes are per id, so any
+    chunking gives the same array, and a bad id in ANY chunk voids it."""
+    ids = [f"ev-{i:03d}" * (1 + i % 3) for i in range(11)]
+    whole = prep_cache.hash_event_ids(ids)
+    assert whole.dtype == np.uint64 and len(set(whole.tolist())) == len(ids)
+    monkeypatch.setattr(prep_cache, "_HASH_CHUNK_IDS", 3)
+    np.testing.assert_array_equal(prep_cache.hash_event_ids(ids), whole)
+    assert prep_cache.hash_event_ids(ids[:7] + [""] + ids[8:]) is None
+    assert prep_cache.hash_event_ids(ids[:10] + [None]) is None
+    assert len(prep_cache.hash_event_ids([])) == 0
+
+
 class TestWarmStart:
     def _data(self, rng, n, nu, ni):
         rows = rng.integers(0, nu, n)
