@@ -1,0 +1,9 @@
+"""batch_useful_rows_share.saturated — batch_useful_rows_share in the saturated cell (it moves serve_qps
+there): the same reader."""
+
+import os
+import runpy
+
+read = runpy.run_path(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), "batch_useful_rows_share.py")
+)["read"]

@@ -386,6 +386,8 @@ def cmd_profile(args) -> int:
         query = {"seconds": str(args.seconds)}
         if args.out:
             query["out"] = args.out
+        if args.python_tracer:
+            query["python_tracer"] = "1"
         url = (
             args.url.rstrip("/")
             + "/profile?"
@@ -408,7 +410,8 @@ def cmd_profile(args) -> int:
 
     try:
         result = obs_device.profile_capture(
-            args.seconds, out_dir=args.out, burn=True
+            args.seconds, out_dir=args.out, burn=True,
+            python_tracer=args.python_tracer,
         )
     except RuntimeError as e:
         print(f"profile failed: {e}", file=sys.stderr)
@@ -1771,6 +1774,12 @@ def build_parser() -> argparse.ArgumentParser:
     pr.add_argument(
         "--out", help="trace output directory (default: a timestamped "
         "dir under $PIO_RUN_DIR/profiles)",
+    )
+    pr.add_argument(
+        "--python-tracer", action="store_true",
+        help="also record every Python call (slows the traced process; "
+        "off by default: the host plane then holds the program's own "
+        "regions and the runtime's events)",
     )
     pr.set_defaults(fn=cmd_profile)
 

@@ -40,6 +40,7 @@ import threading
 import time
 
 from predictionio_tpu.obs import metrics as _metrics
+from predictionio_tpu.obs import trace as _trace
 
 logger = logging.getLogger(__name__)
 
@@ -374,10 +375,18 @@ def _default_profile_dir() -> str:
 
 
 def profile_capture(
-    seconds: float, out_dir: str | None = None, burn: bool = False
+    seconds: float, out_dir: str | None = None, burn: bool = False,
+    python_tracer: bool = False,
 ) -> dict:
     """Capture a ``jax.profiler`` trace for ``seconds`` and return
     {trace_dir, seconds, files, bytes}.
+
+    The host plane holds the program's own regions
+    (``obs.trace.region`` / ``annotate`` become ``TraceAnnotation`` s
+    for the length of the capture) and the runtime's events; the
+    profiler's Python tracer — one event per Python call, which slowed a
+    saturated server by a sixth — is off unless ``python_tracer`` asks
+    for frames.
 
     One capture at a time (RuntimeError when one is already running —
     the /profile route maps it to 409); seconds is clamped to
@@ -397,7 +406,10 @@ def profile_capture(
         import jax.profiler
 
         os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        _trace.set_annotating(True)
         try:
             deadline = time.perf_counter() + seconds
             if burn:
@@ -411,6 +423,7 @@ def profile_capture(
                 while time.perf_counter() < deadline:
                     time.sleep(min(0.05, max(deadline - time.perf_counter(), 0)))
         finally:
+            _trace.set_annotating(False)
             jax.profiler.stop_trace()
     finally:
         _profile_running = False

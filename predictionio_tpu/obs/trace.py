@@ -4,18 +4,30 @@ Every HTTP request handled by :mod:`predictionio_tpu.server.http` gets a
 :class:`Trace` — its id honors an incoming ``X-PIO-Trace`` header (so a
 client, a webhook source, or the feedback loop can stitch hops into one
 timeline) and is propagated on outbound framework POSTs. Stage
-boundaries record spans (name + offset + duration tuples, flat list —
-the waterfall IS the nesting for the pipelines traced here), and on
+boundaries record spans (name + offset + duration + parent tuples in a
+flat list; ``parent`` names the span that caused this one, so self time
+= duration - children is computable from ``/traces.json``), and on
 completion the trace is offered to :data:`TRACES`, a fixed-capacity ring
 that retains the N SLOWEST recent traces: the p99 outliers an operator
 actually wants to dissect survive, uninteresting fast requests fall out
 first. Served as ``GET /traces.json`` on every server and rendered as a
 waterfall table on the dashboard.
 
+There is ONE way to record a stage: :func:`region`, a context manager
+that timestamps with ``perf_counter``, adds the span to the current
+trace (or the one it is handed) under the enclosing region's name,
+feeds the stage's always-on histogram, and — only while a profiler
+capture runs (``obs.device.profile_capture``) — also enters a
+``jax.profiler.TraceAnnotation`` so the span lands in the ``.xplane.pb``
+on the device trace's clock. Retroactive ``Trace.add_span`` stays for
+spans whose start and end are on different threads.
+
 The current trace rides a thread-local so instrumented stages deep in a
 handler need no plumbing; work that hops threads (the micro-batch
-worker) carries the Trace object through its queue items instead —
-``add_span`` is safe from any thread.
+worker) carries the Trace objects through its queue items and installs
+a :class:`Fanout` of them for the duration of a dispatch —
+``add_span`` is safe from any thread. This module never imports jax
+unless a profile is running.
 """
 
 from __future__ import annotations
@@ -34,8 +46,13 @@ __all__ = [
     "Trace",
     "TraceRing",
     "TRACES",
+    "Fanout",
     "current_trace",
     "set_current_trace",
+    "use_trace",
+    "region",
+    "annotate",
+    "set_annotating",
     "new_trace_id",
 ]
 
@@ -62,7 +79,7 @@ _EPOCH_OFFSET = time.time() - time.perf_counter()
 
 class Trace:
     """One request's timeline. ``t0`` is a perf_counter anchor; spans are
-    ``(name, offset_s, duration_s)`` tuples relative to it.
+    ``(name, offset_s, duration_s, parent)`` tuples relative to it.
 
     Construction is on every request's entry path, so everything
     deferrable is deferred: the trace id is minted only when first read
@@ -76,7 +93,7 @@ class Trace:
         self._tid = trace_id
         self.name = name
         self.t0 = time.perf_counter() if t0 is None else t0
-        self.spans: list[tuple[str, float, float]] = []
+        self.spans: list[tuple[str, float, float, str | None]] = []
         self.status: int | None = None
         self.duration_s: float = 0.0
 
@@ -91,13 +108,20 @@ class Trace:
     def wall_start(self) -> float:
         return _EPOCH_OFFSET + self.t0
 
-    def add_span(self, name: str, start: float, end: float) -> None:
+    def add_span(self, name: str, start: float, end: float,
+                 parent: str | None = None) -> None:
         """Record a stage from perf_counter timestamps (thread-safe:
-        list.append is atomic under the GIL)."""
-        self.spans.append((name, start - self.t0, end - start))
+        list.append is atomic under the GIL). ``parent`` names the span
+        that caused this one."""
+        self.spans.append((name, start - self.t0, end - start, parent))
 
-    def span(self, name: str) -> "_SpanCtx":
-        return _SpanCtx(self, name)
+    def span(self, name: str) -> "region":
+        return region(name, trace=self)
+
+    def span_dict(self, name: str, start: float, end: float,
+                  parent: str | None = None) -> dict:
+        """One span in ``to_dict``'s wire shape."""
+        return _span_dict(name, start - self.t0, end - start, parent)
 
     def finish(self, status: int | None = None) -> None:
         self.status = status
@@ -110,31 +134,33 @@ class Trace:
             "start": round(self.wall_start, 3),
             "durationMs": round(self.duration_s * 1e3, 3),
             "status": self.status,
-            "spans": [
-                {
-                    "name": name,
-                    "offsetMs": round(off * 1e3, 3),
-                    "durationMs": round(dur * 1e3, 3),
-                }
-                for name, off, dur in self.spans
-            ],
+            "spans": [_span_dict(*span) for span in self.spans],
         }
 
 
-class _SpanCtx:
-    __slots__ = ("_trace", "_name", "_start")
+def _span_dict(name: str, off: float, dur: float, parent: str | None) -> dict:
+    return {
+        "name": name,
+        "offsetMs": round(off * 1e3, 3),
+        "durationMs": round(dur * 1e3, 3),
+        "parent": parent,
+    }
 
-    def __init__(self, trace: Trace, name: str):
-        self._trace = trace
-        self._name = name
 
-    def __enter__(self):
-        self._start = time.perf_counter()
-        return self
+class Fanout:
+    """Several requests' traces behind one ``add_span``: what the batch
+    worker installs as its current trace for the duration of a dispatch,
+    so a stage recorded once lands on every batchmate's timeline."""
 
-    def __exit__(self, *exc):
-        self._trace.add_span(self._name, self._start, time.perf_counter())
-        return False
+    __slots__ = ("traces",)
+
+    def __init__(self, traces):
+        self.traces = [t for t in traces if t is not None]
+
+    def add_span(self, name: str, start: float, end: float,
+                 parent: str | None = None) -> None:
+        for t in self.traces:
+            t.add_span(name, start, end, parent)
 
 
 # -- thread-local current trace ---------------------------------------------
@@ -142,12 +168,142 @@ class _SpanCtx:
 _tls = threading.local()
 
 
-def current_trace() -> Trace | None:
+def current_trace() -> "Trace | Fanout | None":
     return getattr(_tls, "trace", None)
 
 
-def set_current_trace(trace: Trace | None) -> None:
+def set_current_trace(trace: "Trace | Fanout | None") -> None:
     _tls.trace = trace
+
+
+class use_trace:
+    """Install ``trace`` as this thread's current trace for a block;
+    ``parent`` names the span (open on ANOTHER thread) that regions
+    entered inside the block are children of."""
+
+    __slots__ = ("_trace", "_parent", "_prev")
+
+    def __init__(self, trace, parent: str | None = None):
+        self._trace = trace
+        self._parent = parent
+
+    def __enter__(self):
+        tls = _tls
+        self._prev = (
+            getattr(tls, "trace", None), getattr(tls, "region", None)
+        )
+        tls.trace, tls.region = self._trace, self._parent
+        return self._trace
+
+    def __exit__(self, *exc):
+        _tls.trace, _tls.region = self._prev
+        return False
+
+
+# -- regions ------------------------------------------------------------------
+
+# True only while obs.device.profile_capture has a jax.profiler trace
+# running: the one flag a region reads to decide whether to annotate
+_annotating = False
+
+
+def set_annotating(flag: bool) -> None:
+    global _annotating
+    _annotating = bool(flag)
+
+
+class _Null:
+    """Shared do-nothing context manager (no profile running)."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL = _Null()
+
+
+def _annotation(name: str):
+    from jax.profiler import TraceAnnotation  # lazy: a profile is running
+
+    return TraceAnnotation(name)
+
+
+def annotate(name: str):
+    """Annotation-only region: a named host state (``batch.collect``,
+    ``http.poll``, ``serve.wait``) in the profiler's trace while a
+    capture runs, so an idle device can be put down to it; nothing —
+    one flag read — otherwise."""
+    if _annotating and _metrics.enabled():
+        return _annotation(name)
+    return _NULL
+
+
+class region:
+    """Record one stage: ``with region("serve.tail", hist=h): ...``.
+
+    On exit the span ``(name, start, end, parent)`` is added to ``trace``
+    (default: this thread's current trace, if any), where ``parent`` is
+    the region enclosing this one on this thread; ``hist`` (a
+    ``metrics.Histogram``) observes the duration; while a profiler
+    capture runs the block is also a ``jax.profiler.TraceAnnotation``.
+    ``start`` backdates the region to a perf_counter reading taken
+    earlier on this thread. After exit ``seconds`` is the duration and
+    ``self_seconds`` the duration minus the regions nested directly
+    inside it. A no-op under ``PIO_OBS=0``."""
+
+    __slots__ = (
+        "name", "start", "end", "seconds", "self_seconds",
+        "_hist", "_trace", "_parent", "_outer_children", "_ann", "_on",
+    )
+
+    def __init__(self, name: str, hist=None, trace=None,
+                 start: float | None = None):
+        self.name = name
+        self.start = start
+        self.end = self.seconds = self.self_seconds = 0.0
+        self._hist = hist
+        self._trace = trace
+
+    def __enter__(self):
+        on = self._on = _metrics.enabled()
+        if not on:
+            return self
+        tls = _tls
+        if self._trace is None:
+            self._trace = getattr(tls, "trace", None)
+        self._parent = getattr(tls, "region", None)
+        self._outer_children = getattr(tls, "children_s", 0.0)
+        tls.region = self.name
+        tls.children_s = 0.0
+        self._ann = None
+        if _annotating:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+        if self.start is None:
+            self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if not self._on:
+            return False
+        end = self.end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        dt = self.seconds = end - self.start
+        tls = _tls
+        self.self_seconds = dt - tls.children_s
+        tls.children_s = self._outer_children + dt
+        tls.region = self._parent
+        if self._hist is not None:
+            self._hist.observe(dt)
+        if self._trace is not None:
+            self._trace.add_span(self.name, self.start, end, self._parent)
+        return False
 
 
 # -- retention ---------------------------------------------------------------
@@ -176,9 +332,12 @@ class TraceRing:
         self._next_prune = 0.0
         self._heap: list[tuple[float, int, dict]] = []
 
-    def offer(self, trace: Trace) -> None:
+    def offer(self, trace: Trace) -> dict | None:
+        """Admit ``trace`` if it ranks; returns the retained entry (so a
+        span that ends after the offer — ``http.write`` — can still be
+        appended to its ``spans``) or None."""
         if not _metrics.enabled():
-            return
+            return None
         d = trace.duration_s
         heap = self._heap
         # unlocked peek (GIL-atomic list reads): once the ring is full,
@@ -190,20 +349,20 @@ class TraceRing:
             and heap[0][0] >= d
             and time.time() < self._next_prune
         ):
-            return
+            return None
+        entry = None
         with self._lock:
             now = time.time()
             if now >= self._next_prune:
                 self._prune_locked(now)
                 self._next_prune = now + 1.0
             if len(self._heap) < self.capacity:
-                heappush(
-                    self._heap, (d, self._next_seq(), self._admit(trace, d))
-                )
+                entry = self._admit(trace, d)
+                heappush(self._heap, (d, self._next_seq(), entry))
             elif self._heap and d > self._heap[0][0]:
-                heapreplace(
-                    self._heap, (d, self._next_seq(), self._admit(trace, d))
-                )
+                entry = self._admit(trace, d)
+                heapreplace(self._heap, (d, self._next_seq(), entry))
+        return entry
 
     @staticmethod
     def _admit(trace: Trace, duration_s: float) -> dict:

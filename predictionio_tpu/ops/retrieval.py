@@ -47,9 +47,13 @@ live):
 - ``PIO_RETRIEVAL_PROBE_EVERY``: every Nth two-stage dispatch re-scores
   one query exactly and publishes recall (default 256; 0 disables).
 
-Observability: ``pio_retrieval_*`` metrics (docs/observability.md) and
-a thread-local per-dispatch stage split the engine server turns into
-``dispatch.shortlist`` / ``dispatch.rescore`` trace spans.
+Observability: ``pio_retrieval_*`` metrics (docs/observability.md); the
+two stages record themselves as ``dispatch.shortlist`` /
+``dispatch.rescore`` regions (``obs.trace.region``: a span on the
+current trace — every batchmate's, under the batch worker — from the
+device call through its ``np.asarray``), and the two serving programs
+carry ``jax.named_scope`` s (``retrieval.shortlist.*``,
+``retrieval.rescore.*``) that name their ops in a trace viewer.
 """
 
 from __future__ import annotations
@@ -57,8 +61,6 @@ from __future__ import annotations
 import functools
 import itertools
 import os
-import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +68,7 @@ import numpy as np
 
 from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import trace as obs_trace
 
 NEG_INF = -1e30
 
@@ -141,7 +144,6 @@ _m_probes = obs_metrics.counter(
     "pio_retrieval_probes_total", "live recall probes run",
 )
 
-_tls = threading.local()
 _probe_clock = itertools.count(1)
 
 
@@ -149,23 +151,6 @@ def note_exact(n: int = 1) -> None:
     """Count queries that stayed on the exact path at retrieval scale
     (complex-filtered queries, shortlist-size fallbacks)."""
     _m_exact.inc(n)
-
-
-def _note_stage(stage: str, seconds: float) -> None:
-    split = getattr(_tls, "split", None)
-    if split is None:
-        split = _tls.split = {}
-    split[stage] = split.get(stage, 0.0) + seconds
-
-
-def take_stage_split() -> dict | None:
-    """Pop this thread's accumulated {shortlist, rescore} seconds since
-    the last call — the engine server's batch worker turns it into
-    ``dispatch.shortlist``/``dispatch.rescore`` spans on the request
-    traces it just dispatched."""
-    split = getattr(_tls, "split", None)
-    _tls.split = None
-    return split or None
 
 
 def probe_due() -> bool:
@@ -235,27 +220,32 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
             v, tid = xs
         else:
             v, s, tid = xs
-        if mode == "int8_dot":
-            sc = jax.lax.dot_general(
-                qi, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            ).astype(jnp.float32) * s[None, :]
-        else:
-            sc = jnp.matmul(
-                q, v.T.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
+        # named scopes are metadata only: they name these ops in a
+        # trace viewer (docs/observability.md)
+        with jax.named_scope("retrieval.shortlist.score"):
+            if mode == "int8_dot":
+                sc = jax.lax.dot_general(
+                    qi, v, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.int32,
+                ).astype(jnp.float32) * s[None, :]
+            else:
+                sc = jnp.matmul(
+                    q, v.T.astype(jnp.float32),
+                    preferred_element_type=jnp.float32,
+                )
+                if scales is not None:
+                    sc = sc * s[None, :]
+            sc = jnp.where(tid[None, :] >= 0, sc, NEG_INF)
+        with jax.named_scope("retrieval.shortlist.tile_topk"):
+            ts, tix = jax.lax.top_k(sc, k)
+            ti = jnp.take_along_axis(
+                jnp.broadcast_to(tid[None, :], sc.shape), tix, axis=1
             )
-            if scales is not None:
-                sc = sc * s[None, :]
-        sc = jnp.where(tid[None, :] >= 0, sc, NEG_INF)
-        ts, tix = jax.lax.top_k(sc, k)
-        ti = jnp.take_along_axis(
-            jnp.broadcast_to(tid[None, :], sc.shape), tix, axis=1
-        )
-        cs = jnp.concatenate([best_s, ts], axis=1)
-        ci = jnp.concatenate([best_i, ti], axis=1)
-        best_s, ix = jax.lax.top_k(cs, k)
-        best_i = jnp.take_along_axis(ci, ix, axis=1)
+        with jax.named_scope("retrieval.shortlist.merge"):
+            cs = jnp.concatenate([best_s, ts], axis=1)
+            ci = jnp.concatenate([best_i, ti], axis=1)
+            best_s, ix = jax.lax.top_k(cs, k)
+            best_i = jnp.take_along_axis(ci, ix, axis=1)
         return (best_s, best_i), None
 
     init = (
@@ -357,15 +347,13 @@ class CoarseCatalog:
         bp = _pow2(max(1, B))
         if bp > B:
             q = np.concatenate([q, np.repeat(q[:1], bp - B, axis=0)])
-        t0 = time.perf_counter()
-        s, ids = _coarse_topk(
-            jnp.asarray(q), self._tiles, self._scales, self._ids, k, self.mode
-        )
-        s, ids = np.asarray(s)[:B], np.asarray(ids)[:B]
-        dt = time.perf_counter() - t0
-        _m_shortlist_secs.observe(dt)
+        with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
+            s, ids = _coarse_topk(
+                jnp.asarray(q), self._tiles, self._scales, self._ids, k,
+                self.mode,
+            )
+            s, ids = np.asarray(s)[:B], np.asarray(ids)[:B]
         _m_shortlist_size.observe(float(k))
-        _note_stage("shortlist", dt)
         return s, ids
 
 
@@ -376,21 +364,24 @@ def _score_candidates(qvecs, item_factors, cand_ids, k: int):
     """Shared exact-f32 candidate scorer: gather the [B, S] candidate
     rows (dequantizing int8 pairs on device), dot against the query
     vectors, top-k. -1 candidate slots can never win and report id -1."""
-    cand = jnp.maximum(cand_ids.astype(jnp.int32), 0)
-    if isinstance(item_factors, tuple):
-        vq, vs = item_factors
-        rows = vq[cand].astype(jnp.float32) * vs[cand][..., None]
-    else:
-        rows = item_factors[cand].astype(jnp.float32)
-    sc = jnp.einsum(
-        "bd,bsd->bs", qvecs.astype(jnp.float32), rows,
-        preferred_element_type=jnp.float32,
-    )
-    sc = jnp.where(cand_ids >= 0, sc, NEG_INF)
-    k = min(k, int(cand_ids.shape[1]))
-    s, ix = jax.lax.top_k(sc, k)
-    ids = jnp.take_along_axis(cand_ids.astype(jnp.int32), ix, axis=1)
-    return s, jnp.where(s > NEG_INF / 2, ids, -1)
+    with jax.named_scope("retrieval.rescore.gather"):
+        cand = jnp.maximum(cand_ids.astype(jnp.int32), 0)
+        if isinstance(item_factors, tuple):
+            vq, vs = item_factors
+            rows = vq[cand].astype(jnp.float32) * vs[cand][..., None]
+        else:
+            rows = item_factors[cand].astype(jnp.float32)
+    with jax.named_scope("retrieval.rescore.score"):
+        sc = jnp.einsum(
+            "bd,bsd->bs", qvecs.astype(jnp.float32), rows,
+            preferred_element_type=jnp.float32,
+        )
+        sc = jnp.where(cand_ids >= 0, sc, NEG_INF)
+    with jax.named_scope("retrieval.rescore.topk"):
+        k = min(k, int(cand_ids.shape[1]))
+        s, ix = jax.lax.top_k(sc, k)
+        ids = jnp.take_along_axis(cand_ids.astype(jnp.int32), ix, axis=1)
+        return s, jnp.where(s > NEG_INF / 2, ids, -1)
 
 
 @obs_device.track_jit("retrieval.rescore_gather")
@@ -424,11 +415,13 @@ def _rescore_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
     return _score_candidates(qvecs, item_factors, cand_ids, k)
 
 
-def _finish_rescore(t0: float, out, n_queries: int):
-    s, ids = np.asarray(out[0]), np.asarray(out[1])
-    dt = time.perf_counter() - t0
-    _m_rescore_secs.observe(dt)
-    _note_stage("rescore", dt)
+def _rescore(call, n_queries: int):
+    """Run one exact-rescore stage: ``call()`` (input conversion + the
+    device program) through the results' ``np.asarray``, as one
+    ``dispatch.rescore`` region."""
+    with obs_trace.region("dispatch.rescore", hist=_m_rescore_secs):
+        out = call()
+        s, ids = np.asarray(out[0]), np.asarray(out[1])
     _m_two_stage.inc(n_queries)
     return s, ids
 
@@ -440,23 +433,19 @@ def rescore_gather_top_k_batch(user_ixs, user_factors, item_factors,
     instead of scoring [B, I]. The query vectors are gathered and
     dequantized exactly like the exact path's, so the returned ranking
     equals the exact ranking restricted to the candidates."""
-    t0 = time.perf_counter()
-    out = _rescore_gather(
+    return _rescore(lambda: _rescore_gather(
         jnp.asarray(np.asarray(user_ixs, np.int32)), user_factors,
         item_factors, jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
-    )
-    return _finish_rescore(t0, out, len(cand_ids))
+    ), len(cand_ids))
 
 
 def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int):
     """Shortlist-gather variant of ``top_k_items_batch``: [B, D] query
     vectors against a [B, S] candidate-id matrix."""
-    t0 = time.perf_counter()
-    out = _rescore_vectors(
+    return _rescore(lambda: _rescore_vectors(
         jnp.asarray(np.asarray(user_vectors, np.float32)), item_factors,
         jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
-    )
-    return _finish_rescore(t0, out, len(cand_ids))
+    ), len(cand_ids))
 
 
 def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
@@ -465,13 +454,11 @@ def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
     cosine-family templates: the query vector is the weighted sum of
     gathered catalog rows (built on device exactly like the exact op),
     scored against the [B, S] candidates only."""
-    t0 = time.perf_counter()
-    out = _rescore_sum_rows(
+    return _rescore(lambda: _rescore_sum_rows(
         jnp.asarray(np.asarray(row_ixs, np.int32)),
         jnp.asarray(np.asarray(row_weights, np.float32)),
         item_factors, jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
-    )
-    return _finish_rescore(t0, out, len(cand_ids))
+    ), len(cand_ids))
 
 
 def rescore_host(query_vectors, values, scales, cand_ids, k: int):
@@ -479,19 +466,20 @@ def rescore_host(query_vectors, values, scales, cand_ids, k: int):
     returns [B, S] global candidate ids; the exact factors live host-side
     in the model, and S is small, so the f32 gather + dot runs in numpy
     without staging anything back to the mesh."""
-    t0 = time.perf_counter()
-    cand_ids = np.asarray(cand_ids, dtype=np.int32)
-    cand = np.maximum(cand_ids, 0)
-    rows = np.asarray(values)[cand].astype(np.float32)
-    if scales is not None:
-        rows *= np.asarray(scales, np.float32)[cand][..., None]
-    sc = np.einsum(
-        "bd,bsd->bs", np.asarray(query_vectors, np.float32), rows
-    )
-    sc[cand_ids < 0] = NEG_INF
-    k = min(k, cand_ids.shape[1])
-    order = np.argsort(-sc, axis=1, kind="stable")[:, :k]
-    s = np.take_along_axis(sc, order, axis=1)
-    ids = np.take_along_axis(cand_ids, order, axis=1)
-    ids[s <= NEG_INF / 2] = -1
-    return _finish_rescore(t0, (s, ids), len(cand_ids))
+    with obs_trace.region("dispatch.rescore", hist=_m_rescore_secs):
+        cand_ids = np.asarray(cand_ids, dtype=np.int32)
+        cand = np.maximum(cand_ids, 0)
+        rows = np.asarray(values)[cand].astype(np.float32)
+        if scales is not None:
+            rows *= np.asarray(scales, np.float32)[cand][..., None]
+        sc = np.einsum(
+            "bd,bsd->bs", np.asarray(query_vectors, np.float32), rows
+        )
+        sc[cand_ids < 0] = NEG_INF
+        k = min(k, cand_ids.shape[1])
+        order = np.argsort(-sc, axis=1, kind="stable")[:, :k]
+        s = np.take_along_axis(sc, order, axis=1)
+        ids = np.take_along_axis(cand_ids, order, axis=1)
+        ids[s <= NEG_INF / 2] = -1
+    _m_two_stage.inc(len(cand_ids))
+    return s, ids

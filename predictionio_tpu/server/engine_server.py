@@ -180,20 +180,11 @@ class _MicroBatcher:
                 self.dispatch_cost_s * 1e3,
                 window_ms,
             )
-        # the numbers the ROADMAP "make the batcher win" item needs:
-        # where requests wait, how big batches actually get, and what a
-        # dispatch costs
-        self._m_batch_size = obs_metrics.histogram(
-            "pio_batch_size", "Queries coalesced per device dispatch",
-            bounds=(1, 2, 4, 8, 16, 32, 64, 128),
-        )
+        # where requests wait; how big batches get and what a dispatch
+        # costs are counted per dispatch by the server (_dispatch)
         self._m_queue_wait = obs_metrics.histogram(
             "pio_batch_queue_wait_seconds",
             "Per-query wait from submit to batch collection",
-        )
-        self._m_dispatch = obs_metrics.histogram(
-            "pio_batch_dispatch_seconds",
-            "batch_predict device-dispatch time per micro-batch",
         )
         obs_metrics.gauge(
             "pio_batch_engaged",
@@ -276,34 +267,46 @@ class _MicroBatcher:
             if not f.done():
                 f.set_exception(RuntimeError("server stopping"))
 
-    def _loop(self) -> None:
+    def _collect(self) -> list | None:
+        """Wait for the first item, then its window: the next batch, or
+        None once the batcher has stopped. One ``batch.collect``
+        annotation covers the whole wait, so a profile can put an idle
+        device down to "no request was queued"."""
         import queue
 
-        while not self._stopped:
-            try:
-                first = self._q.get(timeout=0.2)
-            except queue.Empty:
-                continue
-            batch = [first]
-            deadline = time.perf_counter() + self._window
-            while len(batch) < self._max:
+        with obs_trace.annotate("batch.collect"):
+            while not self._stopped:
                 try:
-                    batch.append(self._q.get_nowait())
+                    first = self._q.get(timeout=0.2)
+                except queue.Empty:
                     continue
-                except queue.Empty:
-                    pass
-                # queue is empty: idle-wait for more only when a saved
-                # dispatch is worth more than the window
-                if not self._window_wait:
-                    break
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    batch.append(self._q.get(timeout=remaining))
-                except queue.Empty:
-                    break
-            self._m_batch_size.observe(float(len(batch)))
+                batch = [first]
+                deadline = time.perf_counter() + self._window
+                while len(batch) < self._max:
+                    try:
+                        batch.append(self._q.get_nowait())
+                        continue
+                    except queue.Empty:
+                        pass
+                    # queue is empty: idle-wait for more only when a
+                    # saved dispatch is worth more than the window
+                    if not self._window_wait:
+                        break
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self._q.get(timeout=remaining))
+                    except queue.Empty:
+                        break
+                return batch
+        return None
+
+    def _loop(self) -> None:
+        while True:
+            batch = self._collect()
+            if batch is None:
+                return
             try:
                 self._server._handle_query_batch(batch)
             except Exception:  # pragma: no cover - worker must survive
@@ -627,6 +630,42 @@ class EngineServer:
             "pio_cache_lookup_seconds",
             "Query-cache canonicalize+lookup time (hits and misses)",
         )
+        # the request's stages on the request thread, in order
+        # (docs/observability.md has the whole chain)
+        self._m_submit = obs_metrics.histogram(
+            "pio_serving_submit_seconds",
+            "Handler entered -> query parsed, supplemented and enqueued",
+        )
+        self._m_wake = obs_metrics.histogram(
+            "pio_serving_wake_seconds",
+            "Batch worker resolved the future -> request thread runs again",
+        )
+        self._m_tail = obs_metrics.histogram(
+            "pio_serving_tail_seconds",
+            "Request thread resumed -> response bytes encoded "
+            "(serve, feedback, plugins, JSON)",
+        )
+        # every device dispatch, whatever its size and whichever path
+        # made it (micro-batch, its single-item fast path, unbatched)
+        self._m_batch_size = obs_metrics.histogram(
+            "pio_batch_size", "Queries coalesced per device dispatch",
+            bounds=(1, 2, 4, 8, 16, 32, 64, 128),
+        )
+        self._m_dispatch = obs_metrics.histogram(
+            "pio_batch_dispatch_seconds",
+            "Device-dispatch time per dispatch (predict / batch_predict)",
+        )
+        self._m_dispatch_self = obs_metrics.histogram(
+            "pio_batch_dispatch_self_seconds",
+            "Dispatch time outside its child stages (padding, index "
+            "plumbing, host side of batch_predict)",
+        )
+        self._m_rows_real = obs_metrics.counter(
+            "pio_batch_rows_total", "Query rows dispatched", kind="real"
+        )
+        self._m_rows_padded = obs_metrics.counter(
+            "pio_batch_rows_total", "Query rows dispatched", kind="padded"
+        )
         # default objectives: p99 latency, 5xx availability, the
         # warmup/deadline 503 budget, ingest-to-servable freshness
         obs_slo.install_engine_slos(self)
@@ -725,9 +764,22 @@ class EngineServer:
 
     # -- query path --------------------------------------------------------
     def serve_query_bytes(
-        self, body: dict[str, Any], variant: "_Variant | None" = None
+        self, body: dict[str, Any], variant: "_Variant | None" = None,
+        t_in: float | None = None,
     ) -> bytes:
-        """THE /queries.json read path: preserialized response bytes.
+        """THE /queries.json read path: preserialized response bytes,
+        under the ``serve`` span (backdated to ``t_in``, the route
+        handler's entry, when the caller has it)."""
+        with obs_trace.region("serve", start=t_in) as r:
+            return self._serve_query_bytes(
+                body, variant if variant is not None else self._default_variant,
+                r.start,
+            )
+
+    def _serve_query_bytes(
+        self, body: dict[str, Any], v: "_Variant", t_in: float | None
+    ) -> bytes:
+        """Cache lookup, scoring, encode.
 
         Cache hit: one canonical-bytes build + one sharded dict lookup —
         no device dispatch, no serving join, no JSON encode, and the
@@ -740,7 +792,6 @@ class EngineServer:
         the pre-swap epoch — it can never be served after the swap. (The
         reverse order would race: old-model results could be filed under
         the new epoch.)"""
-        v = variant if variant is not None else self._default_variant
         cache = self.query_cache
         key = None
         if cache is not None:
@@ -761,7 +812,7 @@ class EngineServer:
             if tr is not None:
                 tr.add_span(
                     "cache.hit" if payload is not None else "cache.miss",
-                    t_c0, t_c1,
+                    t_c0, t_c1, "serve",
                 )
             if payload is not None:
                 # a hit is still a served request; it adds ~0 to
@@ -773,7 +824,7 @@ class EngineServer:
                 return payload
         if self.batcher is not None and self.batcher.active:
             try:
-                response_obj = self._serve_batched(body, v)
+                payload = self._serve_batched(body, v, t_in)
             except RuntimeError as e:
                 # batcher INFRASTRUCTURE failure (dead worker / stopping
                 # server), not a query error: degrade to the unbatched
@@ -787,10 +838,9 @@ class EngineServer:
                 logger.warning(
                     "micro-batcher unavailable (%s); serving unbatched", e
                 )
-                response_obj = self._query_with_deadline(body, v)
+                payload = jsonx.dumps_bytes(self._query_with_deadline(body, v))
         else:
-            response_obj = self._query_with_deadline(body, v)
-        payload = jsonx.dumps_bytes(response_obj)
+            payload = jsonx.dumps_bytes(self._query_with_deadline(body, v))
         if key is not None and self._query_cacheable(body, v):
             cache.put(key, payload)
         return payload
@@ -812,21 +862,25 @@ class EngineServer:
         return all(a.cacheable_query(supplemented) for a in algorithms)
 
     def _serve_batched(
-        self, body: dict[str, Any], variant: "_Variant | None" = None
-    ) -> dict[str, Any]:
-        """Score through the micro-batcher. The worker resolves the
-        future with the per-algorithm predictions; serving/feedback/
-        plugins (``_finish_query``) run HERE on the request thread, so
-        batchmates' response tails overlap instead of serializing on
+        self, body: dict[str, Any], variant: "_Variant | None" = None,
+        t_in: float | None = None,
+    ) -> bytes:
+        """Score through the micro-batcher; returns the encoded
+        response. The worker resolves the future with the per-algorithm
+        predictions; serving/feedback/plugins (``_finish_query``) and
+        the JSON encode run HERE on the request thread (``serve.tail``),
+        so batchmates' response tails overlap instead of serializing on
         the worker. Deadline expiry is a timer-wheel entry that fails
         the future — the client gets its 503 AT the deadline even while
         the device call is still in flight."""
-        # legacy single-arg call for the default mount (submit defaults
-        # to it): solo-deploy wrappers/stubs of submit keep working
-        if variant is None or variant is self._default_variant:
-            sub = self.batcher.submit(body)
-        else:
-            sub = self.batcher.submit(body, variant)
+        with obs_trace.region("serve.submit", hist=self._m_submit, start=t_in):
+            # legacy single-arg call for the default mount (submit
+            # defaults to it): solo-deploy wrappers/stubs of submit keep
+            # working
+            if variant is None or variant is self._default_variant:
+                sub = self.batcher.submit(body)
+            else:
+                sub = self.batcher.submit(body, variant)
         fut = sub.fut
         handle = None
         if self.query_deadline_s is not None:
@@ -844,7 +898,8 @@ class EngineServer:
         else:
             timeout = self.query_deadline_s + 60.0
         try:
-            predictions = fut.result(timeout=timeout)
+            with obs_trace.annotate("serve.wait"):
+                predictions = fut.result(timeout=timeout)
         except FuturesTimeout:
             self._count_deadline("batched")
             raise QueryDeadlineExceeded(
@@ -853,9 +908,20 @@ class EngineServer:
         finally:
             if handle is not None:
                 handle.cancel()
-        return self._finish_query(
-            body, sub.query, predictions, sub.serving, sub.t0, variant=variant
-        )
+        t_resumed = time.perf_counter()
+        # start and end on different threads: the worker stamped the
+        # future as it resolved it (absent when a stub resolved it)
+        t_resolved = getattr(fut, "t_resolved", None)
+        if t_resolved is not None and obs_metrics.enabled():
+            self._m_wake.observe(t_resumed - t_resolved)
+            tr = obs_trace.current_trace()
+            if tr is not None:
+                tr.add_span("serve.wake", t_resolved, t_resumed, "serve")
+        with obs_trace.region("serve.tail", hist=self._m_tail, start=t_resumed):
+            return jsonx.dumps_bytes(self._finish_query(
+                body, sub.query, predictions, sub.serving, sub.t0,
+                variant=variant,
+            ))
 
     @staticmethod
     def _count_deadline(path: str) -> None:
@@ -952,25 +1018,31 @@ class EngineServer:
         with self._lock:
             algorithms, models, serving = v.algorithms, v.models, v.serving
         query, supplemented = self._parse_query(body, algorithms, serving)
-        predictions = [
-            a.predict(m, supplemented) for a, m in zip(algorithms, models)
-        ]
-        # drain the two-stage retrieval stage split unconditionally (the
-        # thread-local must not leak into the next query on this thread);
-        # attach sub-spans when this request is traced
-        from predictionio_tpu.ops import retrieval as _retrieval
-
-        split = _retrieval.take_stage_split()
-        if split is not None:
-            tr = obs_trace.current_trace()
-            if tr is not None:
-                ss = split.get("shortlist", 0.0)
-                rs = split.get("rescore", 0.0)
-                tr.add_span("dispatch.shortlist", t0, t0 + ss)
-                tr.add_span("dispatch.rescore", t0 + ss, t0 + ss + rs)
+        predictions = self._dispatch(
+            1, 1, lambda: [
+                a.predict(m, supplemented) for a, m in zip(algorithms, models)
+            ],
+        )
         return self._finish_query(
             body, query, predictions, serving, t0, variant=v
         )
+
+    def _dispatch(self, n_real: int, n_padded: int, call):
+        """One device dispatch of ``n_real`` queries in ``n_padded`` rows:
+        the ``batch.dispatch[n]`` span on the current trace(s), its
+        histograms and the row counters — EVERY dispatch, single or
+        batched, so ``pio_batch_dispatch_seconds`` and ``pio_batch_size``
+        count the same events. The score layer records its stages
+        (``dispatch.shortlist`` / ``dispatch.rescore``) as children."""
+        self._m_batch_size.observe(float(n_real))
+        self._m_rows_real.inc(n_real)
+        self._m_rows_padded.inc(n_padded)
+        with obs_trace.region(
+            f"batch.dispatch[{n_real}]", hist=self._m_dispatch
+        ) as r:
+            out = call()
+        self._m_dispatch_self.observe(r.self_seconds)
+        return out
 
     @staticmethod
     def _parse_query(body, algorithms, serving):
@@ -979,22 +1051,21 @@ class EngineServer:
         return query, serving.supplement(query)
 
     def _finish_query(
-        self, body, query, predictions, serving, t0, trace=None, variant=None
+        self, body, query, predictions, serving, t0, variant=None
     ) -> dict[str, Any]:
         """Per-query tail shared by the per-request and micro-batched
-        paths: serve, feedback, plugins, bookkeeping. ``trace`` is passed
-        explicitly from the batch worker (whose thread-local is not the
-        request thread's); the per-request path falls back to it."""
+        paths: serve, feedback, plugins, bookkeeping — on the request
+        thread, whose current trace names the feedback hop."""
         v = variant if variant is not None else self._default_variant
-        if trace is None:
-            trace = obs_trace.current_trace()
         result = serving.serve(query, predictions)
         response = _to_jsonable(result)
 
         pr_id: str | None = None
         if self.feedback:
             pr_id = body.get("prId") or uuid.uuid4().hex[:16]
-            self._send_feedback(body, response, pr_id, trace=trace)
+            self._send_feedback(
+                body, response, pr_id, trace=obs_trace.current_trace()
+            )
             if isinstance(response, dict):
                 response = {**response, "prId": pr_id}
 
@@ -1015,8 +1086,6 @@ class EngineServer:
             v._m_serving_v.observe(dt)
         if v._m_requests_v is not None:
             v._m_requests_v.inc()
-        if trace is not None:
-            trace.add_span("serve", t0, t_end)
         with self._lock:
             v.request_count += 1
             v.serving_seconds += dt
@@ -1029,6 +1098,8 @@ class EngineServer:
         # losing that race is normal, never an error
         if fut.done():
             return
+        # serve.wake starts here and ends on the request thread
+        fut.t_resolved = time.perf_counter()
         try:
             if exc is not None:
                 fut.set_exception(exc)
@@ -1070,15 +1141,23 @@ class EngineServer:
             if batcher is not None:
                 batcher._m_queue_wait.observe(t_collect - t0)
             if tr is not None:
-                tr.add_span("batch.queue_wait", t0, t_collect)
+                tr.add_span("batch.queue_wait", t0, t_collect, "serve")
+
+        def predict_one(sup):
+            return self._dispatch(1, 1, lambda: [
+                a.predict(m, sup) for a, m in zip(algorithms, models)
+            ])
+
+        # the worker has no trace of its own: for the dispatch it stands
+        # in for every batchmate's, as a child of their ``serve`` spans
+        fanout = obs_trace.Fanout(tr for _, _, tr, _, _ in items)
         if len(items) == 1:
             # FAST PATH: no padding, no index plumbing — lone-query
             # latency matches per-request serving
             fut, _, _, sup, _ = items[0]
             try:
-                predictions = [
-                    a.predict(m, sup) for a, m in zip(algorithms, models)
-                ]
+                with obs_trace.use_trace(fanout, parent="serve"):
+                    predictions = predict_one(sup)
             except Exception as e:
                 self._resolve(fut, exc=e)
                 return
@@ -1086,53 +1165,37 @@ class EngineServer:
             return
         per_algo: list[dict] | None
         try:
-            indexed = [
-                (i, sup) for i, (_, _, _, sup, _) in enumerate(items)
-            ]
             # pad to a power-of-two batch size with copies of the first
             # query (padding results are discarded): jitted batch
             # programs specialize on the batch shape, and
             # traffic-dependent sizes would recompile per distinct size
             # — the stall the window exists to avoid
-            n_real = len(indexed)
+            n_real = len(items)
             pad_to = 1 << max(0, n_real - 1).bit_length()
-            indexed = indexed + [
-                (n_real + j, indexed[0][1]) for j in range(pad_to - n_real)
-            ]
-            t_d0 = time.perf_counter()
-            faults.fault_point("serve.batch_dispatch")
-            per_algo = [
-                dict(a.batch_predict(m, indexed))
-                for a, m in zip(algorithms, models)
-            ]
-            t_d1 = time.perf_counter()
-            if batcher is not None:
-                batcher._m_dispatch.observe(t_d1 - t_d0)
-            # two-stage retrieval stage split for this dispatch (if the
-            # batch went coarse+rescore): sub-spans let /traces.json show
-            # where dispatch time went without a device round-trip
-            from predictionio_tpu.ops import retrieval as _retrieval
 
-            split = _retrieval.take_stage_split()
-            for _, _, tr, _, _ in items:
-                if tr is not None:
-                    tr.add_span(f"batch.dispatch[{n_real}]", t_d0, t_d1)
-                    if split is not None:
-                        ss = split.get("shortlist", 0.0)
-                        rs = split.get("rescore", 0.0)
-                        tr.add_span("dispatch.shortlist", t_d0, t_d0 + ss)
-                        tr.add_span(
-                            "dispatch.rescore", t_d0 + ss, t_d0 + ss + rs
-                        )
+            def batch_call():
+                indexed = [
+                    (i, sup) for i, (_, _, _, sup, _) in enumerate(items)
+                ]
+                indexed += [
+                    (n_real + j, indexed[0][1]) for j in range(pad_to - n_real)
+                ]
+                faults.fault_point("serve.batch_dispatch")
+                return [
+                    dict(a.batch_predict(m, indexed))
+                    for a, m in zip(algorithms, models)
+                ]
+
+            with obs_trace.use_trace(fanout, parent="serve"):
+                per_algo = self._dispatch(n_real, pad_to, batch_call)
         except Exception:
             logger.exception("batched scoring failed; retrying per query")
             per_algo = None
         for i, (fut, t0, tr, sup, _) in enumerate(items):
             if per_algo is None:
                 try:
-                    predictions = [
-                        a.predict(m, sup) for a, m in zip(algorithms, models)
-                    ]
+                    with obs_trace.use_trace(tr, parent="serve"):
+                        predictions = predict_one(sup)
                 except Exception as e:
                     self._resolve(fut, exc=e)
                     continue
@@ -1354,6 +1417,7 @@ class EngineServer:
             return server.variants.get(name)
 
         def _handle_queries(request: Request, v: "_Variant") -> Response:
+            t_in = time.perf_counter()  # serve / serve.submit start here
             if server._swapping.is_set():
                 obs_metrics.counter(
                     "pio_query_unavailable_total",
@@ -1375,7 +1439,9 @@ class EngineServer:
             if not isinstance(body, dict):
                 return Response.error("request body must be a JSON object", 400)
             try:
-                return Response.json_bytes(server.serve_query_bytes(body, v))
+                return Response.json_bytes(
+                    server.serve_query_bytes(body, v, t_in)
+                )
             except QueryDeadlineExceeded as e:
                 obs_metrics.counter(
                     "pio_query_unavailable_total",

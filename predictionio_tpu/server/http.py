@@ -278,7 +278,8 @@ def add_obs_routes(router: Router) -> None:
     ``?step=`` re-grids onto a coarser step), ``POST /incident``
     (on-demand flight-recorder bundle, ``?reason=``/``?note=``), and
     ``POST /profile`` (bounded on-demand ``jax.profiler`` capture,
-    ``?seconds=``/``?out=``). The GET endpoints are unauthenticated on
+    ``?seconds=``/``?out=``; ``?python_tracer=1`` adds Python frames).
+    The GET endpoints are unauthenticated on
     every server — standard scraper behavior; none exposes event data.
 
     Mounting also arms the passive obs machinery the routes read from:
@@ -325,7 +326,9 @@ def add_obs_routes(router: Router) -> None:
             return Response.error("seconds must be a number", 400)
         try:
             result = obs_device.profile_capture(
-                seconds, out_dir=req.query.get("out") or None
+                seconds, out_dir=req.query.get("out") or None,
+                python_tracer=req.query.get("python_tracer", "0")
+                not in ("", "0", "false"),
             )
         except RuntimeError as exc:
             return Response.error(str(exc), 409)
@@ -537,7 +540,7 @@ class _Connection:
 
     __slots__ = (
         "app", "sock", "addr", "reader", "close_connection",
-        "_rfile", "idle_timer",
+        "_rfile", "idle_timer", "t_ready",
     )
 
     def __init__(self, app: "HTTPApp", sock, addr):
@@ -548,6 +551,11 @@ class _Connection:
         self._rfile = None
         self.close_connection = False
         self.idle_timer: _TimerHandle | None = None
+        # perf_counter reading of the selector seeing this socket
+        # readable; the worker that takes the request consumes it
+        # (http.handoff). 0.0: the request reached its worker without a
+        # selector hop (pipelined, or caught by the linger)
+        self.t_ready = 0.0
 
     def _ensure_reader(self):
         r = self.reader
@@ -607,6 +615,7 @@ class _Connection:
         entities)."""
         app = self.app
         self.close_connection = True
+        t_ready, self.t_ready = self.t_ready, 0.0
         reader = self._ensure_reader()
         try:
             faults.fault_point("http.read")
@@ -730,27 +739,34 @@ class _Connection:
             shed.headers["Connection"] = "close"
             self._send(shed)
             return
-        tr = None
+        tr = kept = None
         t_parsed = 0.0
         if obs_metrics.enabled():
-            # trace anchored at first-line arrival; an incoming
-            # X-PIO-Trace id stitches this hop into the caller's
-            # timeline (read/parse happened before the header was
-            # known, so its span is added retroactively)
+            # trace anchored where the selector saw the request (else at
+            # first-line arrival); an incoming X-PIO-Trace id stitches
+            # this hop into the caller's timeline (hand-off and
+            # read/parse happened before the header was known, so their
+            # spans are added retroactively)
             t_parsed = time.perf_counter()
             tr = obs_trace.Trace(
                 f"{method} {parsed.path}",
                 trace_id=headers.get("x-pio-trace"),
-                t0=t_start,
+                t0=t_ready or t_start,
             )
+            if t_ready:
+                tr.add_span("http.handoff", t_ready, t_start)
+                app._m_handoff.observe(t_start - t_ready)
             tr.add_span("http.read_parse", t_start, t_parsed)
             obs_trace.set_current_trace(tr)
         try:
-            if stream_match is not None:
-                handler, request.path_params = stream_match
-                response = handler(request)
-            else:
-                response = app.router.dispatch(request)
+            # the HTTP router's span: parent of whatever the handler
+            # records (backdated to t_parsed so the chain has no hole)
+            with obs_trace.region("dispatch", start=t_parsed or None):
+                if stream_match is not None:
+                    handler, request.path_params = stream_match
+                    response = handler(request)
+                else:
+                    response = app.router.dispatch(request)
         except json.JSONDecodeError:
             response = Response.error("invalid JSON body", 400)
         except OSError:
@@ -800,18 +816,24 @@ class _Connection:
             # the GIL, and post-send bookkeeping then costs two
             # forced thread switches per request — far more than
             # the few µs of work itself. The measured duration
-            # excludes only the final buffered socket write.
+            # excludes only the final buffered socket write, which
+            # http.write times on its own.
             t_end = time.perf_counter()
-            tr.add_span("dispatch", t_parsed, t_end)
             tr.status = response.status
-            tr.duration_s = t_end - t_start
+            tr.duration_s = t_end - tr.t0
             app._m_request.observe(t_end - t_start)
             app._m_read_parse.observe(t_parsed - t_start)
             app._m_requests.inc()
             if response.status >= 500:
                 app._m_errors.inc()
-            obs_trace.TRACES.offer(tr)
-        self._send(response)
+            kept = obs_trace.TRACES.offer(tr)
+        # the current trace is cleared by now: the region feeds the
+        # histogram (and the profiler's trace) only; a trace the ring
+        # kept gets the span appended to its retained entry
+        with obs_trace.region("http.write", hist=app._m_write) as w:
+            self._send(response)
+        if kept is not None:
+            kept["spans"].append(tr.span_dict("http.write", w.start, w.end))
 
     def _send_simple(self, status: int, phrase: str) -> None:
         # cached constant bytes — parse-reject paths pay one
@@ -951,7 +973,8 @@ class _EventLoop:
         try:
             while not self._stopping:
                 try:
-                    events = self.selector.select(self._next_timeout())
+                    with obs_trace.annotate("http.poll"):
+                        events = self.selector.select(self._next_timeout())
                 except OSError:
                     continue
                 while self._pending:
@@ -1049,6 +1072,7 @@ class _EventLoop:
         if conn.idle_timer is not None:
             conn.idle_timer.cancel()
             conn.idle_timer = None
+        conn.t_ready = time.perf_counter()
         self.app._submit_conn(conn)
 
     def _teardown(self) -> None:
@@ -1102,6 +1126,17 @@ class HTTPApp:
         self._m_read_parse = obs_metrics.histogram(
             "pio_http_read_parse_seconds",
             "Request read+parse time, excluding keep-alive idle wait",
+            server=name,
+        )
+        self._m_handoff = obs_metrics.histogram(
+            "pio_http_handoff_seconds",
+            "Selector saw the socket readable -> a worker has the "
+            "request line (pool queue + thread wake-up)",
+            server=name,
+        )
+        self._m_write = obs_metrics.histogram(
+            "pio_http_write_seconds",
+            "Response encode + socket write (_send entered -> returned)",
             server=name,
         )
         self._m_requests = obs_metrics.counter(

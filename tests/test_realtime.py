@@ -1324,7 +1324,7 @@ class TestColumnarTail:
             assert t_col.poll_columnar().n_events == 1
         finally:
             obs_trace.set_current_trace(None)
-        assert any(name == "tail.decode" for name, _, _ in tr.spans)
+        assert any(name == "tail.decode" for name, *_ in tr.spans)
 
     def test_seq_backend_wraps_object_poll(self, tmp_path):
         """Backends without tail_files() keep working: poll_columnar

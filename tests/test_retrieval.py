@@ -430,15 +430,36 @@ class TestSubThresholdParity:
                 "exact_queries", "shortlist_size", "probe_recall"} <= set(block)
 
 
-class TestStageSplit:
-    def test_take_stage_split_drains(self):
+class TestStageSpans:
+    def test_stages_record_themselves_and_nothing_carries_over(self):
+        """The two stages land on the current trace as spans at their
+        true start and end, children of the enclosing region — and a
+        call made with no trace leaves nothing behind for the next one
+        (the thread-local side channel this replaced added up)."""
+        from predictionio_tpu.obs import trace as obs_trace
+
         v = _dense(300, 8, seed=27)
         cat = CoarseCatalog(v, tile=256)
-        retrieval.take_stage_split()  # drain anything earlier
-        _, cand = cat.shortlist(_dense(2, 8, seed=28), 32)
-        retrieval.rescore_top_k_batch(_dense(2, 8, seed=28), v, cand, k=8)
-        split = retrieval.take_stage_split()
-        assert split is not None
-        assert split.get("shortlist", 0) > 0
-        assert split.get("rescore", 0) > 0
-        assert retrieval.take_stage_split() is None  # drained
+        q = _dense(2, 8, seed=28)
+        for _ in range(3):  # untraced calls on this thread, first
+            _, cand = cat.shortlist(q, 32)
+            retrieval.rescore_top_k_batch(q, v, cand, k=8)
+        tr = obs_trace.Trace("t")
+        with obs_trace.use_trace(tr):
+            with obs_trace.region("batch.dispatch[2]") as outer:
+                _, cand = cat.shortlist(q, 32)
+                retrieval.rescore_top_k_batch(q, v, cand, k=8)
+        spans = {name: (off, dur, parent) for name, off, dur, parent in tr.spans}
+        assert set(spans) == {
+            "dispatch.shortlist", "dispatch.rescore", "batch.dispatch[2]"
+        }
+        s_off, s_dur, s_parent = spans["dispatch.shortlist"]
+        r_off, r_dur, r_parent = spans["dispatch.rescore"]
+        d_off, d_dur, _ = spans["batch.dispatch[2]"]
+        assert s_parent == r_parent == "batch.dispatch[2]"
+        assert s_dur > 0 and r_dur > 0
+        # inside the parent, in order, not overlapping
+        assert d_off <= s_off and s_off + s_dur <= r_off
+        assert r_off + r_dur <= d_off + d_dur
+        assert outer.self_seconds == pytest.approx(d_dur - s_dur - r_dur)
+        assert obs_trace.current_trace() is None
