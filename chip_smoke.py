@@ -3,7 +3,8 @@
 Drives the main path once through the entry points a user calls, each a
 `python -m predictionio_tpu.cli.main` child: `app new` -> `import` of a
 seeded JSONL event file -> `train` (twice, against one compile cache) ->
-`deploy` -> `POST /queries.json`, on the zero-config sqlite + jsonl +
+`deploy` -> `POST /queries.json` (and a second `deploy` that serves the
+same model through two-stage retrieval), on the zero-config sqlite + jsonl +
 localfs storage under a run-local directory. The model is the
 recommendation template's ALS at the ML-20M shape (138,493 users x 26,744
 items, rank 20, explicit, f32, lambda 0.05, 3 iterations) over 20,000,000
@@ -65,6 +66,7 @@ MAX_USER_DEGREE, MAX_ITEM_DEGREE = 9_254, 67_310  # at FULL_EVENTS
 RANK, ITERATIONS, LAMBDA = 20, 3, 0.05
 TOP_K = 10
 KNOWN_QUERIES = 20
+TWO_STAGE_QUERIES, TWO_STAGE_THRESHOLD = 5, 100  # the second serve leg
 RMSE_SAMPLE = 1_000_000
 DEADLINE_S = 1200.0
 
@@ -415,16 +417,17 @@ def check_answer(ref: Reference, who: str, user: int, got: list[dict]):
 
 
 def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
-                    users: list[int]) -> dict:
+                    users: list[int], **env) -> dict:
     """One `pio deploy` child: wait until it answers, ask it about the
     known users and one unknown user, check every answer against the
-    reference, read its own device report, stop it and wait for it."""
+    reference, read its own device report and what its rescore programs
+    need in temporary memory, stop it and wait for it."""
     port = free_port()
     log = f"{name}.log"
     t0 = time.perf_counter()
     proc = smoke.spawn(
         ["-m", "predictionio_tpu.cli.main", "deploy", "--variant", variant_file,
-         "--ip", "127.0.0.1", "--port", str(port)], log,
+         "--ip", "127.0.0.1", "--port", str(port)], log, **env,
     )
     try:
         while True:  # model load + warmup compile happen before the bind
@@ -458,9 +461,22 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
             deviations.append(dev)
             overlaps.append(overlap)
 
-        devices = json.loads(_http(port, "GET", "/stats.json")[1])["device"]["devices"]
+        stats = json.loads(_http(port, "GET", "/stats.json")[1])
+        devices = stats["device"]["devices"]
         if {d["device"].split(":")[0] for d in devices} != {smoke.platform}:
             raise SmokeFailure(f"{name}: the server reports devices {devices}")
+        # a rescore program that re-lays a factor table before it gathers
+        # from it needs a table's worth of temporary memory, on every call
+        # (on the chip: XLA:CPU has one layout, and the dry run's toy table
+        # is smaller than a program's honest temporaries)
+        temp = stats["retrieval"]["rescore_temp_bytes"]
+        if smoke.platform == "tpu" and any(
+            n > ref.V.nbytes // 100 for n in temp.values()
+        ):
+            raise SmokeFailure(
+                f"{name}: a rescore program's temporaries {temp} pass 1 % of "
+                f"the item table's {ref.V.nbytes} bytes"
+            )
         reading = {
             "ready_s": ready_s,
             "queries_200": len(latencies),
@@ -471,6 +487,8 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
             "overlap_mean": float(np.mean(overlaps)),
             "exact_lists": sum(o == 1.0 for o in overlaps),
             "devices": devices,
+            "two_stage_queries": stats["retrieval"]["two_stage_queries"],
+            "rescore_temp_bytes": temp,
         }
         try:
             _http(port, "POST", "/stop")
@@ -611,6 +629,18 @@ def run(smoke: Smoke, args) -> dict:
 
     users = rng.choice(num_users, KNOWN_QUERIES, replace=False).tolist()
     dense = serve_and_check(smoke, "deploy", "engine.json", ref, users)
+    # the same model through two-stage retrieval (threshold under the
+    # catalog): coarse shortlist, then the exact rescore's gathers
+    staged = serve_and_check(
+        smoke, "deploy_two_stage", "engine.json", ref, users[:TWO_STAGE_QUERIES],
+        PIO_RETRIEVAL_THRESHOLD=str(TWO_STAGE_THRESHOLD),
+    )
+    if (staged["two_stage_queries"] < TWO_STAGE_QUERIES  # + the deploy warm-up
+            or "retrieval.rescore_gather" not in staged["rescore_temp_bytes"]):
+        raise SmokeFailure(
+            f"deploy_two_stage: {staged['two_stage_queries']} two-stage queries, "
+            f"programs {staged['rescore_temp_bytes']}: the leg did not engage"
+        )
 
     if device["count"] >= 4:
         sharded_leg(smoke, ref, rmse, dense, users, (s_rows, s_cols, s_vals),

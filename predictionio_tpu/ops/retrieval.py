@@ -47,7 +47,30 @@ live):
 - ``PIO_RETRIEVAL_PROBE_EVERY``: every Nth two-stage dispatch re-scores
   one query exactly and publishes recall (default 256; 0 disables).
 
-Observability: ``pio_retrieval_*`` metrics (docs/observability.md); the
+Where the tables' layout is decided, and who reads them: nowhere in
+this repo. A template's ``device_factors()`` puts each [rows, D] factor
+array up with ``jnp.asarray``, once, in the device's default layout,
+and every reader takes it as it lies: the three rescore programs here,
+``ops/topk.py``'s exact programs (the recall probe, filtered queries,
+catalogs below the threshold) and the templates' own row math;
+``CoarseCatalog`` and ``rescore_host`` read the model's host arrays. On
+a TPU that default keeps the long axis minor wherever D is no multiple
+of 128, and XLA answers a gather of 64-column rows from it by first
+copying the entire table row-major — 2.4 GB read and 4.8 GB written per
+call for 128 rows of a 9.39 M x 64 f32 table. So the rescore gathers
+through a view of the same buffer that XLA reads in place
+(``_gather_rows``), and no program here has an instruction of a table's
+shape. (A non-default resident layout would do it too — leave the
+gather's operand layout to the compiler, ``Layout.AUTO``, and place the
+table in what it picks — but it doubles the table, and an array that a
+program loaded from the persistent compile cache writes in a
+non-default layout reports the default one (jax 0.9.0, v5e), so the
+next program is compiled for a layout the buffer does not have.)
+
+Observability: ``pio_retrieval_*`` metrics (docs/observability.md); each
+rescore program publishes the temporary bytes its compiled form needs
+(``pio_retrieval_rescore_temp_bytes``: a table-sized number means a
+re-layout came back); the
 two stages record themselves as ``dispatch.shortlist`` /
 ``dispatch.rescore`` regions (``obs.trace.region``: a span on the
 current trace — every batchmate's, under the batch worker — from the
@@ -61,6 +84,7 @@ from __future__ import annotations
 import functools
 import itertools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -186,6 +210,10 @@ def stats_block() -> dict:
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
         "rescore_seconds": _m_rescore_secs.summary(),
+        "rescore_temp_bytes": {
+            p.name: p.temp_bytes() for p in _RESCORE_PROGRAMS
+            if p._cache_size()
+        },
         "probes": _m_probes.value(),
         "probe_recall": _m_probe_recall.value(),
     }
@@ -360,17 +388,47 @@ class CoarseCatalog:
 # -- exact rescore kernels ---------------------------------------------------
 
 
+# A TPU keeps a resident [rows, D] table row-major only where D fills
+# the 128 lanes; otherwise the long axis is minor (no lane padding) and
+# a logical row is D strided elements. XLA gathers rows out of that
+# layout where they lie while the gathered minor dimension stays under
+# 64 (56 passes; f32, bf16 and int8 alike); from 64 on it first copies
+# the WHOLE table row-major, on every call. Seen as [rows, D/32, 32] the
+# same buffer keeps the gather under that limit, and the reshape is a
+# bitcast: 32 is a whole number of sublane tiles for all three dtypes
+# (a group of 25 is not: that reshape is a real copy and a 145 s compile).
+_VIEW_COLUMNS, _LANES = 32, 128
+
+
+def _gather_rows(table, ixs):
+    """f32 ``table[ixs]`` of a resident [rows, D] table, gathered
+    through the [rows, D/32, 32] view where that spares the table a
+    re-layout (above). Every other D takes the plain gather: free of
+    one below 64 columns and at multiples of 128, not at the ranks in
+    between that 32 does not divide (100: PERF.md section 7).
+    ``pio_retrieval_rescore_temp_bytes`` says when a program re-lays."""
+    rows, dim = table.shape
+    if dim > _VIEW_COLUMNS and dim % _VIEW_COLUMNS == 0 and dim % _LANES:
+        table = table.reshape(rows, dim // _VIEW_COLUMNS, _VIEW_COLUMNS)
+    return table[ixs].reshape(*ixs.shape, dim).astype(jnp.float32)
+
+
+def _table_rows(table, ixs):
+    """Dequantized f32 rows ``ixs`` of a factor table: the dense array,
+    or the int8 ``(values, scales)`` pair."""
+    if isinstance(table, tuple):
+        values, scales = table
+        return _gather_rows(values, ixs) * scales[ixs][..., None]
+    return _gather_rows(table, ixs)
+
+
 def _score_candidates(qvecs, item_factors, cand_ids, k: int):
     """Shared exact-f32 candidate scorer: gather the [B, S] candidate
     rows (dequantizing int8 pairs on device), dot against the query
     vectors, top-k. -1 candidate slots can never win and report id -1."""
     with jax.named_scope("retrieval.rescore.gather"):
         cand = jnp.maximum(cand_ids.astype(jnp.int32), 0)
-        if isinstance(item_factors, tuple):
-            vq, vs = item_factors
-            rows = vq[cand].astype(jnp.float32) * vs[cand][..., None]
-        else:
-            rows = item_factors[cand].astype(jnp.float32)
+        rows = _table_rows(item_factors, cand)
     with jax.named_scope("retrieval.rescore.score"):
         sc = jnp.einsum(
             "bd,bsd->bs", qvecs.astype(jnp.float32), rows,
@@ -384,33 +442,80 @@ def _score_candidates(qvecs, item_factors, cand_ids, k: int):
         return s, jnp.where(s > NEG_INF / 2, ids, -1)
 
 
-@obs_device.track_jit("retrieval.rescore_gather")
-@functools.partial(jax.jit, static_argnames=("k",))
+class _RescoreProgram:
+    """A rescore entry point as ``jax.jit`` would run it, compiled ahead
+    of the first call of each signature (shapes, dtypes, the tables'
+    layouts, ``k``) so that the compiled program's own memory analysis
+    can be published: ``pio_retrieval_rescore_temp_bytes{fn}`` holds
+    the most temporary bytes any of ``fn``'s programs needs. A program
+    that re-lays a table before it gathers needs a table's worth.
+    ``obs_device.track_jit`` counts these compiles through
+    ``_cache_size``, as it counts a jit's."""
+
+    def __init__(self, name: str, fn):
+        self.name = name
+        self._jit = jax.jit(fn, static_argnames=("k",))
+        self.lower = self._jit.lower
+        self._compiled: dict = {}
+        self._lock = threading.Lock()
+        self._m_temp = obs_metrics.gauge(
+            "pio_retrieval_rescore_temp_bytes",
+            "most temporary device bytes a compiled rescore program needs",
+            fn=name,
+        )
+
+    def _cache_size(self) -> int:
+        return len(self._compiled)
+
+    def temp_bytes(self) -> int:
+        return int(self._m_temp.value())
+
+    def __call__(self, *args, k: int):
+        key = (k, *(
+            (a.shape, a.dtype, getattr(a, "format", None))
+            for a in jax.tree.leaves(args)
+        ))
+        program = self._compiled.get(key)
+        if program is None:
+            with self._lock:
+                program = self._compiled.get(key)
+                if program is None:
+                    program = self._jit.lower(*args, k=k).compile()
+                    stats = program.memory_analysis()
+                    if stats is not None:  # a backend may not say
+                        self._m_temp.set(max(
+                            self._m_temp.value(), stats.temp_size_in_bytes
+                        ))
+                    self._compiled[key] = program
+        return program(*args)
+
+
+_RESCORE_PROGRAMS: list[_RescoreProgram] = []
+
+
+def _rescore_program(name: str):
+    def deco(fn):
+        program = _RescoreProgram(name, fn)
+        _RESCORE_PROGRAMS.append(program)
+        return obs_device.track_jit(name)(program)
+
+    return deco
+
+
+@_rescore_program("retrieval.rescore_gather")
 def _rescore_gather(user_ixs, user_factors, item_factors, cand_ids, k: int):
-    ixs = user_ixs.astype(jnp.int32)
-    if isinstance(user_factors, tuple):
-        uq, us = user_factors
-        qvecs = uq[ixs].astype(jnp.float32) * us[ixs][:, None]
-    else:
-        qvecs = user_factors[ixs].astype(jnp.float32)
+    qvecs = _table_rows(user_factors, user_ixs.astype(jnp.int32))
     return _score_candidates(qvecs, item_factors, cand_ids, k)
 
 
-@obs_device.track_jit("retrieval.rescore_vectors")
-@functools.partial(jax.jit, static_argnames=("k",))
+@_rescore_program("retrieval.rescore_vectors")
 def _rescore_vectors(user_vectors, item_factors, cand_ids, k: int):
     return _score_candidates(user_vectors, item_factors, cand_ids, k)
 
 
-@obs_device.track_jit("retrieval.rescore_sum_rows")
-@functools.partial(jax.jit, static_argnames=("k",))
+@_rescore_program("retrieval.rescore_sum_rows")
 def _rescore_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
-    ixs = row_ixs.astype(jnp.int32)
-    if isinstance(item_factors, tuple):
-        vq, vs = item_factors
-        rows = vq[ixs].astype(jnp.float32) * vs[ixs][..., None]
-    else:
-        rows = item_factors[ixs].astype(jnp.float32)
+    rows = _table_rows(item_factors, row_ixs.astype(jnp.int32))
     qvecs = jnp.sum(rows * row_weights[..., None], axis=1)
     return _score_candidates(qvecs, item_factors, cand_ids, k)
 

@@ -184,6 +184,122 @@ class TestRescoreExactness:
         )
 
 
+def _exact_tables(storage, rows, d, seed):
+    """A (host table, its f32 rows) pair whose every product and sum is
+    exact in f32 — eighths of small integers; int8 values under
+    power-of-two scales — so that any order of accumulation gives the
+    same bits and a NumPy reference can be held to equality."""
+    rng = np.random.default_rng(seed)
+    if storage == "int8":
+        vq = rng.integers(-127, 128, size=(rows, d)).astype(np.int8)
+        vs = (2.0 ** rng.integers(-8, -6, size=rows)).astype(np.float32)
+        return (vq, vs), vq.astype(np.float32) * vs[:, None]
+    f = (rng.integers(-16, 17, size=(rows, d)) / 8.0).astype(np.float32)
+    if storage == "bfloat16":
+        import ml_dtypes
+
+        return f.astype(ml_dtypes.bfloat16), f
+    return f, f
+
+
+def _numpy_rescore(qvecs, rows_f32, cand, k):
+    """Plain f32 gather-dot-top-k: the reference of every rescore."""
+    sc = np.einsum(
+        "bd,bsd->bs", qvecs.astype(np.float32),
+        rows_f32[np.maximum(cand, 0)],
+    ).astype(np.float32)
+    sc[cand < 0] = retrieval.NEG_INF
+    order = np.argsort(-sc, axis=1, kind="stable")[:, :k]
+    s = np.take_along_axis(sc, order, axis=1)
+    ids = np.take_along_axis(cand, order, axis=1)
+    return s, np.where(s > retrieval.NEG_INF / 2, ids, -1)
+
+
+class TestResidentTables:
+    """The three rescore programs over tables resident as
+    ``device_factors()`` leaves them (ops/retrieval.py module
+    docstring): D = 64 goes through ``_gather_rows``' view, 20 and 100
+    through the plain gather. 1,003 rows: no multiple of 8 or of 128."""
+
+    ROWS, USERS, S, K = 1003, 37, 128, 16
+
+    @pytest.mark.parametrize("b", [1, 3, 16])
+    @pytest.mark.parametrize("d", [20, 64, 100])
+    @pytest.mark.parametrize("storage", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("entry", ["gather", "vectors", "sum_rows"])
+    def test_rescore_bit_identical_to_numpy(self, entry, storage, d, b):
+        import jax
+        import jax.numpy as jnp
+
+        host, rows = _exact_tables(storage, self.ROWS, d, seed=d)
+        table = jax.tree.map(jnp.asarray, host)
+        rng = np.random.default_rng(100 * d + b)
+        cand = np.stack([
+            rng.permutation(self.ROWS)[: self.S] for _ in range(b)
+        ]).astype(np.int32)
+        cand[:, -5:] = -1  # padding slots
+        cand[0, 9:] = -1  # fewer live candidates than k
+        if entry == "gather":
+            uhost, urows = _exact_tables(storage, self.USERS, d, seed=d + 1)
+            uixs = rng.integers(0, self.USERS, size=b).astype(np.int32)
+            qvecs = urows[uixs]
+            s, ids = retrieval.rescore_gather_top_k_batch(
+                uixs, jax.tree.map(jnp.asarray, uhost), table, cand, k=self.K
+            )
+        elif entry == "vectors":
+            qvecs = (rng.integers(-16, 17, size=(b, d)) / 8.0).astype(
+                np.float32
+            )
+            s, ids = retrieval.rescore_top_k_batch(
+                qvecs, table, cand, k=self.K
+            )
+        else:
+            ixs = rng.integers(0, self.ROWS, size=(b, 4)).astype(np.int32)
+            w = np.ones((b, 4), np.float32)
+            w[:, -1] = 0.0  # a padding row
+            qvecs = np.sum(rows[ixs] * w[..., None], axis=1)
+            s, ids = retrieval.rescore_sum_rows_top_k_batch(
+                ixs, w, table, cand, k=self.K
+            )
+        want_s, want_ids = _numpy_rescore(qvecs, rows, cand, self.K)
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(s, want_s)
+        assert (ids[0, 9:] == -1).all() and (ids[0, :9] >= 0).all()
+
+    def test_temp_bytes_reach_the_gauge_and_stats(self):
+        """Each compiled rescore program needs far less temporary
+        memory than its table holds, and says so on ``/metrics`` and in
+        ``/stats.json``. XLA:CPU has one layout and re-lays nothing, so
+        this holds the reader to account; on the chip ``chip_smoke.py``
+        and the benchmark's list of device ops hold the layout."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        host = _dense(200_000, 64, seed=42)
+        table, users = jnp.asarray(host), jnp.asarray(host[:500])
+        cand = np.arange(self.S, dtype=np.int32)[None, :]
+        retrieval.rescore_gather_top_k_batch(
+            np.zeros(1, np.int32), users, table, cand, k=self.K
+        )
+        retrieval.rescore_top_k_batch(host[:1], table, cand, k=self.K)
+        retrieval.rescore_sum_rows_top_k_batch(
+            np.zeros((1, 2), np.int32), np.ones((1, 2), np.float32),
+            table, cand, k=self.K,
+        )
+        temp = retrieval.stats_block()["rescore_temp_bytes"]
+        assert set(temp) == {
+            "retrieval.rescore_gather", "retrieval.rescore_vectors",
+            "retrieval.rescore_sum_rows",
+        }
+        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
+        for fn, n in temp.items():
+            assert 0 < n < host.nbytes / 10, (fn, n)
+            assert scraped[
+                f'pio_retrieval_rescore_temp_bytes{{fn="{fn}"}}'
+            ] == n
+
+
 class TestSatelliteOps:
     def test_sum_rows_accepts_int8_pair(self):
         vq, vs = _int8(96, 8, seed=17)
@@ -324,6 +440,46 @@ class TestTemplateTwoStage:
         two = algo.batch_predict(model, queries)
         assert retrieval.stats_block()["two_stage_queries"] > before
         _assert_same_results(exact, two)
+
+    def test_resident_tables_serve_the_exact_path_and_the_probe(
+        self, monkeypatch
+    ):
+        """The readers of ``device_factors()`` other than the rescore,
+        over the same resident tables at D = 64 (the rescore's view
+        path): the exact program below the threshold and the recall
+        probe at its dispatch return what the exact path returns, and a
+        second probe compiles nothing — the rescore programs compile
+        ahead of time and are counted like any jit."""
+        from predictionio_tpu.models import recommendation as rec
+        from predictionio_tpu.obs import device as obs_device
+
+        def compiles():
+            return sum(
+                f["compiles"] for f in obs_device.compile_snapshot().values()
+            )
+
+        algo = rec.ALSAlgorithm(rec.ALSAlgorithmParams())
+        queries = [(0, rec.Query(user="u0", num=5)),
+                   (1, rec.Query(user="u3", num=3))]
+        model = _rec_model(d=64)
+        exact = algo.batch_predict(model, queries)  # below the threshold
+        tables = model.device_factors()
+        monkeypatch.setenv("PIO_RETRIEVAL_THRESHOLD", "64")
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "128")
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "2")
+        probes = retrieval.stats_block()["probes"]
+        before = compiles()
+        for _ in range(2):  # one of any two dispatches probes
+            _assert_same_results(exact, algo.batch_predict(model, queries))
+        assert retrieval.stats_block()["probes"] == probes + 1
+        warm = compiles()
+        assert warm > before  # the rescore program's compile was counted
+        for _ in range(2):
+            _assert_same_results(exact, algo.batch_predict(model, queries))
+        assert retrieval.stats_block()["probes"] == probes + 2
+        assert retrieval.stats_block()["probe_recall"] == 1.0
+        assert compiles() == warm
+        assert model.device_factors() is tables  # placed once, read by all
 
     @pytest.mark.parametrize("int8", [False, True])
     def test_similarproduct_with_boundary_exclusions(self, monkeypatch, int8):
