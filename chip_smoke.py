@@ -27,6 +27,13 @@ script names what it found and exits non-zero without a result line.
 With four or more devices it also runs the sharded leg (`--mesh data=4`
 train, ring top-k serving).
 
+The storefront leg runs the E-Commerce template's whole path on the chip at
+a small shape: item `$set`s with categories and view/buy events imported,
+`pio train`, `pio deploy` over the two-stage threshold, the three query
+kinds of a storefront (home, category page, cart blackList) held to a NumPy
+reference of the business rules, then a live `$set unavailableItems` and a
+`view`, both gone from the next answer.
+
 Last stdout line on success:
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 """
@@ -189,6 +196,21 @@ class Smoke:
                 p.kill()
                 p.wait()
 
+
+# The storefront leg (the E-Commerce template): small, so that training is
+# seconds; over SHOP_THRESHOLD rows, so that the rules run inside the coarse
+# scan and the rescore as they do at a storefront's size.
+SHOP_USERS, SHOP_ITEMS, SHOP_CATEGORIES, SHOP_EVENTS = 3_000, 20_000, 40, 150_000
+SHOP_RANK, SHOP_THRESHOLD, SHOP_UNAVAILABLE, SHOP_QUERIES = 16, 1_000, 200, 4
+SHOP_VARIANT = {
+    "id": "chip-smoke-shop",
+    "engineFactory": "predictionio_tpu.models.ecommerce.engine",
+    "datasource": {"params": {"appName": "ChipShop"}},
+    "algorithms": [{"name": "als", "params": {
+        "appName": "ChipShop", "unseenOnly": True, "seenEvents": ["buy", "view"],
+        "rank": SHOP_RANK, "numIterations": ITERATIONS, "lambda": 0.01,
+    }}],
+}
 
 # -- seeded event data ------------------------------------------------------
 
@@ -416,6 +438,35 @@ def check_answer(ref: Reference, who: str, user: int, got: list[dict]):
     return items, dev, overlap
 
 
+def wait_until_serving(smoke: Smoke, proc, port: int, name: str, log: str) -> None:
+    while True:  # model load + warmup compile happen before the bind
+        if proc.poll() is not None:
+            raise SmokeFailure(f"{name}: exited {proc.returncode} before "
+                               f"serving\n{smoke.log_tail(log)}")
+        smoke.remaining(f"{name} came up")
+        try:
+            if _http(port, "GET", "/stats.json", timeout=2.0)[0] == 200:
+                return
+        except (OSError, http.client.HTTPException):
+            pass
+        time.sleep(0.5)
+
+
+def stop_server(proc, port: int, name: str) -> None:
+    try:
+        _http(port, "POST", "/stop")
+    except (OSError, http.client.HTTPException):
+        pass  # it may hang up while going down; the wait decides
+    try:
+        proc.wait(timeout=60)
+    except subprocess.TimeoutExpired:
+        proc.terminate()
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            raise SmokeFailure(f"{name}: ignored /stop and SIGTERM") from None
+
+
 def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
                     users: list[int], **env) -> dict:
     """One `pio deploy` child: wait until it answers, ask it about the
@@ -430,17 +481,7 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
          "--ip", "127.0.0.1", "--port", str(port)], log, **env,
     )
     try:
-        while True:  # model load + warmup compile happen before the bind
-            if proc.poll() is not None:
-                raise SmokeFailure(f"{name}: exited {proc.returncode} before "
-                                   f"serving\n{smoke.log_tail(log)}")
-            smoke.remaining(f"{name} came up")
-            try:
-                if _http(port, "GET", "/stats.json", timeout=2.0)[0] == 200:
-                    break
-            except (OSError, http.client.HTTPException):
-                pass
-            time.sleep(0.5)
+        wait_until_serving(smoke, proc, port, name, log)
         ready_s = time.perf_counter() - t0
 
         overlaps, deviations, lists, latencies = [], [], {}, []
@@ -490,23 +531,149 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
             "two_stage_queries": stats["retrieval"]["two_stage_queries"],
             "rescore_temp_bytes": temp,
         }
-        try:
-            _http(port, "POST", "/stop")
-        except (OSError, http.client.HTTPException):
-            pass  # it may hang up while going down; the wait decides
-        try:
-            proc.wait(timeout=60)
-        except subprocess.TimeoutExpired:
-            proc.terminate()
-            try:
-                proc.wait(timeout=30)
-            except subprocess.TimeoutExpired:
-                raise SmokeFailure(f"{name}: ignored /stop and SIGTERM") from None
+        stop_server(proc, port, name)
     finally:
         smoke.stop_all()
     print(f"{name}: {json.dumps(reading)}", flush=True)
     reading["lists"] = lists
     return reading
+
+
+# -- the storefront leg -----------------------------------------------------------
+
+
+def _ask(port: int, name: str, body: dict) -> list[dict]:
+    status, raw = _http(port, "POST", "/queries.json", json.dumps(body).encode())
+    if status != 200:
+        raise SmokeFailure(f"{name}: {body} -> HTTP {status} {raw[:300]!r}")
+    return json.loads(raw)["itemScores"]
+
+
+def storefront_leg(smoke: Smoke, seed: int, scale: int) -> None:
+    """The E-Commerce template end to end: events -> train -> deploy -> the
+    three query kinds -> a live `$set` and `view`, every answer held to the
+    top of its ALLOWED set by the f32 scores of the persisted model."""
+    from predictionio_tpu.data.event import Event
+
+    rng = np.random.default_rng(seed + 2)
+    users, items, n = SHOP_USERS // scale, SHOP_ITEMS // scale, SHOP_EVENTS // scale
+    cat = rng.integers(0, SHOP_CATEGORIES, items)
+    who = rng.integers(0, users, n)
+    what = np.minimum((items * rng.random(n) ** 2).astype(np.int64), items - 1)
+    who[:users], what[:items] = rng.permutation(users), rng.permutation(items)
+    smoke.pio("shop_app_new", "app", "new", "ChipShop")
+    path = os.path.join(smoke.dir, "shop.jsonl")
+    stamp = b'"eventTime":"2020-01-01T00:00:00.000Z"}\n'
+    with open(path, "wb") as fh:
+        fh.write(b"".join(
+            b'{"event":"$set","entityType":"item","entityId":"i%d","properties":'
+            b'{"categories":["c%d"]},' % (i, c) + stamp for i, c in enumerate(cat.tolist())))
+        fh.write(b"".join(
+            b'{"event":"%s","entityType":"user","entityId":"u%d","targetEntityType":'
+            b'"item","targetEntityId":"i%d",' % (b"buy" if j % 50 == 0 else b"view", u, i)
+            + stamp for j, (u, i) in enumerate(zip(who.tolist(), what.tolist()))))
+    smoke.pio("shop_import", "import", "--appid-or-name", "ChipShop", "--input", path)
+    os.unlink(path)
+    with open(os.path.join(smoke.dir, "shop.json"), "w") as fh:
+        json.dump(SHOP_VARIANT, fh)
+    trained = train(smoke, "shop_train", "--variant", "shop.json")
+    if trained["platform"] != smoke.platform:
+        raise SmokeFailure(f"shop_train: the trainer reports {trained}")
+
+    fields = modelfile.load_path(
+        smoke.storage.get_model_data_models().local_path(trained["instance"])).fields(0)
+    U, V = (np.asarray(fields[k], np.float32) for k in ("user_factors", "item_factors"))
+    item_row = {sid: pos for sid, pos in fields["item_index"].items()}
+    row_item = {pos: sid for sid, pos in item_row.items()}
+    cat_of_row = np.asarray(fields["item_categories"])[:, 0]
+    cat_id = dict(fields["category_index"].items())
+    unavailable = {f"i{i}" for i in rng.choice(items, SHOP_UNAVAILABLE // scale, replace=False)}
+    app_id = smoke.storage.get_metadata_apps().get_by_name("ChipShop").id
+    events = smoke.storage.get_events()
+
+    def set_unavailable():
+        events.insert(Event(event="$set", entity_type="constraint",
+                            entity_id="unavailableItems",
+                            properties={"items": sorted(unavailable)}), app_id)
+
+    set_unavailable()
+    seen = {u: {f"i{i}" for i in what[who == u].tolist()} for u in range(users)}
+
+    def check(name, user, got, category=None, black=()):
+        allowed = np.ones(len(V), bool)
+        allowed[[item_row[i] for i in seen[user] | unavailable | set(black)
+                 if i in item_row]] = False
+        if category is not None:
+            allowed &= cat_of_row == cat_id[category]
+        want = np.where(allowed, V @ U[fields["user_index"][f"u{user}"]], -np.inf)
+        top = np.argsort(-want, kind="stable")[:TOP_K]
+        rows = [item_row[e["item"]] for e in got]
+        served = np.asarray([e["score"] for e in got], np.float32)
+        if len(rows) != TOP_K or len(set(rows)) != TOP_K or not allowed[rows].all():
+            raise SmokeFailure(f"{name}: served {[e['item'] for e in got]}: "
+                               f"{TOP_K} distinct allowed items expected")
+        dev = float(np.abs(served - want[rows]).max())
+        overlap = len(set(rows) & set(top.tolist())) / TOP_K
+        if dev > SCORE_TOL or overlap < MIN_OVERLAP or (np.diff(served) > 0).any():
+            raise SmokeFailure(
+                f"{name}: score deviation {dev:.3g}, overlap@{TOP_K} {overlap}\n"
+                f"served {[e['item'] for e in got]}\nreference {[row_item[r] for r in top]}")
+        return dev, overlap
+
+    port = free_port()
+    proc = smoke.spawn(
+        ["-m", "predictionio_tpu.cli.main", "deploy", "--variant", "shop.json",
+         "--ip", "127.0.0.1", "--port", str(port)], "shop_deploy.log",
+        PIO_RETRIEVAL_THRESHOLD=str(SHOP_THRESHOLD // scale),
+        PIO_RETRIEVAL_TILE=str(4096 // scale), PIO_RETRIEVAL_PROBE_EVERY="3",
+    )
+    try:
+        wait_until_serving(smoke, proc, port, "shop_deploy", "shop_deploy.log")
+        devs, overlaps = [], []
+        for user in rng.choice(users, SHOP_QUERIES, replace=False).tolist():
+            category = f"c{int(rng.integers(0, SHOP_CATEGORIES))}"
+            black = [f"i{i}" for i in rng.integers(0, items, 3)]
+            who_ = f"u{user}"
+            for name, body, kw in (
+                ("home", {}, {}),
+                ("category", {"categories": [category]}, {"category": category}),
+                ("cart", {"blackList": black}, {"black": black}),
+            ):
+                got = _ask(port, f"shop {name} {who_}", {"user": who_, "num": TOP_K, **body})
+                d, o = check(f"shop {name} {who_}", user, got, **kw)
+                devs.append(d)
+                overlaps.append(o)
+        # the live rules: the top item goes out of stock, the second is viewed
+        first = _ask(port, "shop live", {"user": who_, "num": TOP_K})
+        unavailable.add(first[0]["item"])
+        set_unavailable()
+        events.insert(Event(event="view", entity_type="user", entity_id=who_,
+                            target_entity_type="item",
+                            target_entity_id=first[1]["item"]), app_id)
+        seen[user].add(first[1]["item"])
+        again = _ask(port, "shop live", {"user": who_, "num": TOP_K})
+        if {first[0]["item"], first[1]["item"]} & {e["item"] for e in again}:
+            raise SmokeFailure(f"shop live: {first[:2]} still served after the $set "
+                               f"and the view: {again}")
+        check("shop live", user, again)
+        stats = json.loads(_http(port, "GET", "/stats.json")[1])
+        devices = stats["device"]["devices"]
+        programs = stats["retrieval"]["rescore_temp_bytes"]
+        if {d["device"].split(":")[0] for d in devices} != {smoke.platform}:
+            raise SmokeFailure(f"shop_deploy: the server reports devices {devices}")
+        if ("retrieval.rescore_vectors_masked" not in programs
+                or stats["retrieval"]["exact_queries"]):
+            raise SmokeFailure(
+                f"shop_deploy: programs {programs}, {stats['retrieval']['exact_queries']} "
+                "exact queries: the rules did not run inside two-stage retrieval")
+        print("shop_deploy: " + json.dumps({
+            "queries_200": len(devs) + 2, "score_deviation_max": max(devs),
+            "overlap_min": min(overlaps), "two_stage_queries":
+            stats["retrieval"]["two_stage_queries"], "probes": stats["retrieval"]["probes"],
+            "rescore_temp_bytes": programs}), flush=True)
+        stop_server(proc, port, "shop_deploy")
+    finally:
+        smoke.stop_all()
 
 
 # -- the run ----------------------------------------------------------------------
@@ -642,6 +809,7 @@ def run(smoke: Smoke, args) -> dict:
             f"programs {staged['rescore_temp_bytes']}: the leg did not engage"
         )
 
+    storefront_leg(smoke, args.seed, 10 if args.dry_run_cpu else 1)
     if device["count"] >= 4:
         sharded_leg(smoke, ref, rmse, dense, users, (s_rows, s_cols, s_vals),
                     num_users, num_items)

@@ -360,6 +360,29 @@ class Events(abc.ABC):
         — mirroring the reference's Option[Option[String]] semantics
         (LEvents.scala:282-313). ``limit=None`` or ``-1`` means all."""
 
+    def find_target_ids(
+        self,
+        app_id: int,
+        channel_id: int | None,
+        entity_type: str,
+        entity_id: str,
+        event_names: Sequence[str],
+        target_entity_type: str,
+    ) -> set[str]:
+        """The distinct targets of one entity's events — all a "has this
+        user seen this item" rule needs. The default reads the events
+        through ``find``; an indexed backend answers with a projection
+        and builds no Event objects."""
+        return {
+            e.target_entity_id
+            for e in self.find(
+                app_id=app_id, channel_id=channel_id, entity_type=entity_type,
+                entity_id=entity_id, event_names=event_names,
+                target_entity_type=target_entity_type,
+            )
+            if e.target_entity_id
+        }
+
     def batch_insert(
         self, events: Iterable[Event], app_id: int, channel_id: int | None = None
     ) -> list[str]:

@@ -678,6 +678,29 @@ class SQLiteEvents(base.Events):
                 raise
             return cur.rowcount > 0
 
+    def find_target_ids(
+        self, app_id, channel_id, entity_type, entity_id, event_names,
+        target_entity_type,
+    ) -> set[str]:
+        """One projection over the (entitytype, entityid) index."""
+        names = list(event_names)
+        if not names:
+            return set()
+        t = self._table(app_id, channel_id)
+        try:
+            rows = self._c.query(
+                f"SELECT DISTINCT targetentityid FROM {t} WHERE entitytype = ?"
+                " AND entityid = ? AND targetentitytype = ?"
+                " AND targetentityid IS NOT NULL AND event IN ("
+                + ",".join("?" * len(names)) + ")",
+                (entity_type, entity_id, target_entity_type, *names),
+            )
+        except sqlite3.OperationalError as err:
+            if _is_missing_table(err):
+                return set()
+            raise
+        return {r[0] for r in rows if r[0]}
+
     @staticmethod
     def _rating_value_col(rating_key: str) -> tuple[str, list]:
         """(SELECT expression, its bound params) extracting the numeric

@@ -122,6 +122,25 @@ def find_by_entity(
     )
 
 
+def find_target_ids(
+    app_name: str,
+    entity_type: str,
+    entity_id: str,
+    event_names: Sequence[str],
+    target_entity_type: str,
+    channel_name: str | None = None,
+    storage: Storage | None = None,
+) -> set[str]:
+    """The distinct targets of one entity's events (``Events.
+    find_target_ids``): the seen-items rule's serving-time read."""
+    storage = storage or get_storage()
+    app_id, channel_id = app_name_to_id(app_name, channel_name, storage)
+    return storage.get_events().find_target_ids(
+        app_id, channel_id, entity_type, entity_id, event_names,
+        target_entity_type,
+    )
+
+
 def find_ratings(
     app_name: str,
     channel_name: str | None = None,

@@ -16,9 +16,22 @@ Capability parity with the reference template
 - cold-start users are scored from their recently viewed items' factor
   vectors (predictNewUser, :332-410).
 
-TPU note: the device op is one fused score+top-k; the live business
-rules become a host-side exclusion mask built before the device call so
-the event-store read never stalls the device path mid-computation.
+TPU note: the rules reach the device as ``ops.topk.Rules`` and are
+applied where the scores are produced — inside the coarse scan before a
+tile's top-k, again in the exact rescore, or in the masked exact
+program below the retrieval threshold — so exclusions cost no headroom
+in k and every query kind shares the same batched programs:
+
+- catalog-wide rules are RESIDENT device vectors over the stored rows:
+  an availability byte (from the ``unavailableItems`` constraint) and
+  the items' category ids (from the model file's array block), rebuilt
+  only when the event store's change token moves AND the constraint's
+  content has changed;
+- per-query rules (seen items, ``blackList``) are short index lists
+  padded to one pow2 bucket (``_EXCLUDED_BUCKET``; longer lists take the
+  next power of two, counted), so the compiled shapes do not move with
+  the traffic; a ``whiteList`` is scored as a candidate list;
+- no request builds a dense [num_items] host array.
 """
 
 from __future__ import annotations
@@ -44,6 +57,8 @@ from predictionio_tpu.data import store
 from predictionio_tpu.data.storage.base import RatingsBatch
 from predictionio_tpu.models.columnar import aggregate_counts
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.ops import als as als_ops
 
 logger = logging.getLogger(__name__)
@@ -149,12 +164,36 @@ class ECommModel:
     item_index: BiMap
     user_factors: np.ndarray  # int8 values when user_scales set
     item_factors: np.ndarray  # int8 values when item_scales set
-    categories: dict[str, list[str]]
+    # construction-time input ({item id: [category, ...]}, what training
+    # and model files written before the array block hold); indexed into
+    # ``category_index`` / ``item_categories`` and dropped
+    categories: dict[str, list[str]] | None = None
     user_scales: np.ndarray | None = None  # [U] f32, int8 storage only
     item_scales: np.ndarray | None = None  # [I] f32, int8 storage only
+    category_index: BiMap | None = None  # category name -> dense id
+    # [I, W] int32: the items' category ids, -1 past an item's last (W is
+    # the most categories any item has; an array block in the model
+    # file, not a 4 M-entry JSON header)
+    item_categories: np.ndarray | None = None
 
     def __post_init__(self):
         self._device = None
+        self._index_categories()
+
+    def _index_categories(self) -> None:
+        if self.item_categories is None:
+            cats = self.categories or {}
+            self.category_index = BiMap.from_dense(
+                sorted({c for cs in cats.values() for c in cs})
+            )
+            width = max([1] + [len(cs) for cs in cats.values()])
+            table = np.full((len(self.item_index), width), -1, np.int32)
+            for iid, cs in cats.items():
+                ix = self.item_index.get(iid)
+                if ix is not None:
+                    table[ix, : len(cs)] = [self.category_index[c] for c in cs]
+            self.item_categories = table
+        self.categories = None
 
     def user_rows(self, ixs):
         """Dense f32 user vectors (dequantizes int8 storage)."""
@@ -190,12 +229,65 @@ class ECommModel:
     def __getstate__(self):
         state = self.__dict__.copy()
         state["_device"] = None
-        # derived serving caches (device arrays / index maps) rebuild
-        # lazily after unpickle
+        # derived serving caches (device arrays) rebuild lazily after
+        # unpickle
         state.pop("_weighted_V", None)
         state.pop("_coarse_V", None)
-        state.pop("_cat_members", None)
+        state.pop("_rules", None)
         return state
+
+    def __setstate__(self, state):
+        # a pickle from before the array block holds ``categories`` only
+        self.__dict__.update(
+            {"category_index": None, "item_categories": None, **state}
+        )
+        self._index_categories()
+
+
+# one pow2 bucket for a query's own exclusion list (seen + blackList):
+# it covers a storefront's seen sets (~100 events a user), so the
+# compiled shapes do not move with the traffic; a longer list takes the
+# next power of two and is counted
+_EXCLUDED_BUCKET = 128
+_SEEN_CACHE_USERS = 1 << 16
+
+_m_seen_read = obs_metrics.histogram(
+    "pio_ecomm_seen_read_seconds",
+    "one user's seen items read from the event store (cache misses only)",
+)
+_m_rules = obs_metrics.histogram(
+    "pio_ecomm_rules_seconds",
+    "host work turning a dispatch's queries into rule arguments",
+)
+_m_queries = {
+    kind: obs_metrics.counter(
+        "pio_ecomm_queries_total", "e-commerce queries by kind", kind=kind,
+    )
+    for kind in ("home", "category", "list")
+}
+_m_excluded = obs_metrics.histogram(
+    "pio_ecomm_excluded_items",
+    "items on one query's own exclusion list (seen + blackList)",
+    bounds=tuple(float(1 << p) for p in range(0, 14)),
+)
+_m_overflow = obs_metrics.counter(
+    "pio_ecomm_headroom_overflow_total",
+    "queries whose exclusion list outgrew the compiled bucket",
+)
+_m_refresh = obs_metrics.counter(
+    "pio_ecomm_rules_refresh_total",
+    "rebuilds of the resident availability vector",
+)
+_m_refresh_secs = obs_metrics.histogram(
+    "pio_ecomm_rules_refresh_seconds",
+    "one rebuild of the resident availability vector",
+)
+
+
+def _query_kind(q: Query) -> str:
+    if q.whiteList is not None or q.blackList:
+        return "list"
+    return "category" if q.categories is not None else "home"
 
 
 class ECommAlgorithm(Algorithm):
@@ -251,28 +343,26 @@ class ECommAlgorithm(Algorithm):
             item_scales=vs,
         )
 
-    # -- live business rules (host-side, before the device call) ----------
+    # -- live business rules ----------------------------------------------
     #
-    # Live semantics with cached cost: every filter read goes through a
+    # Live semantics with cached cost: every rule read goes through a
     # per-algorithm cache keyed by the event store's change_token — a
-    # static store serves seen/unavailable sets from memory (the reads
-    # that made live-filter serving ~100x the dense path replayed the
-    # event store per request), while ANY write to the store changes the
-    # token and drops the whole cache, so a just-ingested
-    # ``$set unavailableItems`` or view event takes effect on the next
-    # query. Every shipped backend produces a token (the http client
-    # proxies it to the storage service, so cross-host writes invalidate
-    # too); a custom Events DAO without a change_token override returns
-    # None, which disables caching and keeps the reference's
-    # read-per-request behavior.
+    # static store serves seen/unavailable sets from memory, while ANY
+    # write to the store changes the token and drops the cache, so a
+    # just-ingested ``$set unavailableItems`` or view event takes effect
+    # on the next query. Every shipped backend produces a token (the
+    # http client proxies it to the storage service, so cross-host
+    # writes invalidate too); a custom Events DAO without a change_token
+    # override returns None, which disables caching and keeps the
+    # reference's read-per-request behavior.
 
     def _filter_cache(self) -> tuple[dict | None, object]:
         """(cache dict or None if caching disabled, current token).
 
-        Read ONCE per query (predict passes the cache down): on remote
-        backends the token read is a network roundtrip. The (app_id,
-        channel_id) resolution is memoized — it is immutable for the
-        life of a deployed engine."""
+        Read ONCE per dispatch (batch_predict passes the cache down): on
+        remote backends the token read is a network roundtrip. The
+        (app_id, channel_id) resolution is memoized — it is immutable
+        for the life of a deployed engine."""
         try:
             from predictionio_tpu.data.storage import get_storage
 
@@ -295,19 +385,15 @@ class ECommAlgorithm(Algorithm):
         return cache, token
 
     def _seen_items(self, user: str, cache: dict | None) -> set[str]:
-        """Live read of the user's seen events (reference :234-249),
-        cached until the event store changes.
+        """Live read of the user's seen events (reference :234-249).
 
         On replay-style backends (jsonl, partitioned, memory — where a
         filtered read costs a full scan anyway) the first miss builds the
         seen sets of EVERY user in one scan, so 40 distinct users cost
-        one replay, not 40. Indexed backends (sqlite, http) keep cheap
-        per-user point reads."""
-        if cache is not None:
-            if user in cache["seen"]:
-                return cache["seen"][user]
-            if cache.get("seen_all") is not None:
-                return cache["seen_all"].get(user, frozenset())
+        one replay, not 40. Indexed backends (sqlite, http) answer a
+        per-user point read that builds no Event objects."""
+        if cache is not None and cache.get("seen_all") is not None:
+            return cache["seen_all"].get(user, frozenset())
         try:
             from predictionio_tpu.data.storage import get_storage
 
@@ -319,13 +405,14 @@ class ECommAlgorithm(Algorithm):
                 if cache.get("seen_all") is not None:  # double-check
                     return cache["seen_all"].get(user, frozenset())
                 try:
-                    events = store.find(
-                        app_name=self.params.app_name,
-                        entity_type="user",
-                        event_names=list(self.params.seen_events),
-                        target_entity_type="item",
-                        limit=None,
-                    )
+                    with obs_trace.region("rules.seen_read", hist=_m_seen_read):
+                        events = store.find(
+                            app_name=self.params.app_name,
+                            entity_type="user",
+                            event_names=list(self.params.seen_events),
+                            target_entity_type="item",
+                            limit=None,
+                        )
                 except Exception:
                     logger.exception(
                         "seen-items scan failed; serving without filter"
@@ -340,25 +427,46 @@ class ECommAlgorithm(Algorithm):
                 cache["seen_all"] = seen_all
                 return seen_all.get(user, frozenset())
         try:
-            events = store.find_by_entity(
-                app_name=self.params.app_name,
-                entity_type="user",
-                entity_id=user,
-                event_names=list(self.params.seen_events),
-                target_entity_type="item",
-                limit=None,
-            )
+            with obs_trace.region("rules.seen_read", hist=_m_seen_read):
+                return store.find_target_ids(
+                    app_name=self.params.app_name,
+                    entity_type="user",
+                    entity_id=user,
+                    event_names=list(self.params.seen_events),
+                    target_entity_type="item",
+                )
         except Exception:
             logger.exception("seen-items read failed; serving without filter")
             return set()
-        seen = {e.target_entity_id for e in events if e.target_entity_id}
-        if cache is not None:
-            cache["seen"][user] = seen
-        return seen
 
-    def _unavailable_items(self, cache: dict | None) -> set[str]:
+    def _seen_rows(self, model: ECommModel, user: str,
+                   cache: dict | None) -> np.ndarray:
+        """The catalog rows of the user's seen items, cached until the
+        event store changes."""
+        if cache is not None:
+            rows = cache["seen"].get(user)
+            if rows is not None:
+                return rows
+        index = model.item_index
+        rows = np.fromiter(
+            (
+                ix
+                for ix in map(index.get, self._seen_items(user, cache))
+                if ix is not None
+            ),
+            np.int32,
+        )
+        if cache is not None:
+            if len(cache["seen"]) >= _SEEN_CACHE_USERS:
+                cache["seen"].clear()
+            cache["seen"][user] = rows
+        return rows
+
+    def _unavailable_rows(self, model: ECommModel,
+                          cache: dict | None) -> np.ndarray:
         """Live read of the latest unavailableItems constraint
-        (reference :250-265), cached until the event store changes."""
+        (reference :250-265) as sorted catalog rows, cached until the
+        event store changes."""
         if cache is not None and cache["unavail"] is not None:
             return cache["unavail"]
         try:
@@ -372,15 +480,19 @@ class ECommAlgorithm(Algorithm):
             )
         except Exception:
             logger.exception("constraint read failed; serving without filter")
-            return set()
-        unavail = (
-            set(events[0].properties.get_opt("items", default=[]) or [])
+            return np.zeros(0, np.int32)
+        items = (
+            events[0].properties.get_opt("items", default=[]) or []
             if events
-            else set()
+            else []
         )
+        index = model.item_index
+        rows = np.unique(np.fromiter(
+            (ix for ix in map(index.get, items) if ix is not None), np.int32,
+        ))
         if cache is not None:
-            cache["unavail"] = unavail
-        return unavail
+            cache["unavail"] = rows
+        return rows
 
     def _recent_item_vector(self, model: ECommModel, user: str):
         """Cold-start: mean factor vector of recently viewed items
@@ -406,52 +518,43 @@ class ECommAlgorithm(Algorithm):
             return None
         return model.item_rows(ixs).mean(axis=0)
 
-    def _category_members(self, model: ECommModel, category: str) -> np.ndarray:
-        """Item indices carrying ``category`` — built once per (model,
-        category), replacing the per-query full-catalog Python loop."""
-        index = getattr(model, "_cat_members", None)
-        if index is None:
-            index = {}
-            model._cat_members = index
-        got = index.get(category)
-        if got is None:
-            with self._serve_lock:
-                got = index.get(category)  # double-check
-                if got is None:
-                    got = np.fromiter(
-                        (
-                            ix
-                            for iid, ix in model.item_index.items()
-                            if category in model.categories.get(iid, ())
-                        ),
-                        np.int64,
-                    )
-                    index[category] = got
-        return got
+    def _catalog_rules(self, model: ECommModel, rows: int,
+                       cache: dict | None):
+        """The resident catalog-wide rules over ``rows`` stored rows
+        (the coarse catalog's, padding included; the catalog's own below
+        the retrieval threshold): (availability [rows] uint8, one
+        [rows] int32 category vector per category column), on the
+        device. The category vectors are built once; the availability
+        vector again only when the constraint's CONTENT has changed —
+        a view event moves the token and costs one point read here."""
+        import jax.numpy as jnp
 
-    def _exclusions(self, model: ECommModel, query: Query) -> np.ndarray:
-        """Per-query exclusion mask: white/black lists, categories,
-        unavailable items, seen items (reference :234-295)."""
-        from predictionio_tpu.models.filters import entity_exclusion_mask
-
-        n = len(model.item_index)
-        mask = entity_exclusion_mask(
-            model.item_index, (), query.whiteList, query.blackList
-        )
-        if query.categories is not None:
-            in_any = np.zeros(n, bool)
-            for cat in query.categories:
-                in_any[self._category_members(model, cat)] = True
-            mask |= ~in_any
-        cache, _ = self._filter_cache()  # one token read per query
-        for iid in self._unavailable_items(cache):
-            if iid in model.item_index:
-                mask[model.item_index[iid]] = True
-        if self.params.unseen_only:
-            for iid in self._seen_items(query.user, cache):
-                if iid in model.item_index:
-                    mask[model.item_index[iid]] = True
-        return mask
+        unavail = self._unavailable_rows(model, cache)
+        states = model.__dict__.setdefault("_rules", {})
+        state = states.get(rows)
+        if state is not None and (
+            state["unavail"] is unavail
+            or np.array_equal(state["unavail"], unavail)
+        ):
+            return state["avail"], state["cats"]
+        with self._serve_lock:
+            n = len(model.item_index)
+            if state is None:
+                cols = np.full(
+                    (model.item_categories.shape[1], rows), -1, np.int32
+                )
+                cols[:, :n] = model.item_categories.T
+                cats = tuple(jnp.asarray(c) for c in cols)
+            else:
+                cats = state["cats"]
+            with obs_trace.region("rules.refresh", hist=_m_refresh_secs):
+                avail = np.zeros(rows, np.uint8)
+                avail[:n] = 1
+                avail[unavail] = 0
+                avail = jnp.asarray(avail)
+            _m_refresh.inc()
+            states[rows] = {"unavail": unavail, "avail": avail, "cats": cats}
+        return avail, cats
 
     def _weighted_item_factors(self, model: ECommModel):
         """Device-resident ``V * weights`` — weights are static per
@@ -534,108 +637,178 @@ class ECommAlgorithm(Algorithm):
         # same query arriving inside a coalesced micro-batch
         return self.batch_predict(model, [(0, query)])[0][1]
 
+    def _query_rows(self, model: ECommModel, q: Query, cache: dict | None):
+        """One query's own rules as index lists: (excluded catalog rows,
+        category ids or None, whiteList rows or None)."""
+        index = model.item_index
+        excluded = [
+            ix for ix in map(index.get, q.blackList or ()) if ix is not None
+        ]
+        if self.params.unseen_only:
+            excluded = np.union1d(
+                self._seen_rows(model, q.user, cache),
+                np.asarray(excluded, np.int32),
+            )
+        else:
+            excluded = np.unique(np.asarray(excluded, np.int32))
+        cats = None
+        if q.categories is not None:
+            cats = [
+                c
+                for c in map(model.category_index.get, q.categories)
+                if c is not None
+            ]
+        white = None
+        if q.whiteList is not None:
+            white = np.unique(np.fromiter(
+                (ix for ix in map(index.get, q.whiteList) if ix is not None),
+                np.int32,
+            ))
+        return excluded, cats, white
+
     def batch_predict(
         self, model: ECommModel, queries: Sequence[tuple[int, Query]]
     ) -> list[tuple[int, PredictedResult]]:
-        """Batched scoring with the live business rules intact: the
-        exclusion masks (seen/unavailable/black-list) are built host-side
-        per query BEFORE dispatch, then every category/whiteList-free
-        query in the micro-batch shares one ``top_k_items_batch`` call
-        with headroom k = pow2(num + |excluded|) and drops its exclusions
-        host-side. Category/whiteList queries can exclude most of the
-        catalog (headroom would balloon to the catalog size), so they
-        keep per-query masked calls through the same batched op."""
-        import jax.numpy as jnp
-
+        """Batched scoring with the live business rules intact. The host
+        turns each query into index lists (``rules.build``), the rules
+        travel to the device as ``ops.topk.Rules`` and every home,
+        category and blackList query of the micro-batch shares ONE
+        masked program per stage: the coarse scan and the exact rescore
+        at retrieval scale (``ops/retrieval.py``), the masked exact
+        top-k below it. k is pow2(num) — exclusions are applied before
+        each top-k and need no headroom. ``whiteList`` queries score
+        their own candidate lists through the same rescore program."""
         from predictionio_tpu.ops import retrieval
-        from predictionio_tpu.ops.topk import top_k_items_batch
+        from predictionio_tpu.ops.topk import Rules, top_k_items_batch_masked
 
-        inv = model.item_index.inverse
         results: list[PredictedResult | None] = [None] * len(queries)
-        vecs: list[np.ndarray | None] = [None] * len(queries)
-        masks: list[np.ndarray | None] = [None] * len(queries)
-        simple: list[int] = []
-        complex_: list[int] = []
-        for qi, (_, q) in enumerate(queries):
-            if q.user in model.user_index:
-                vec = np.asarray(model.user_rows(model.user_index[q.user]))
-            else:
-                recent = self._recent_item_vector(model, q.user)
-                if recent is None:
-                    logger.info(
-                        "user %s has no factors and no recent views;"
-                        " empty result",
-                        q.user,
-                    )
-                    results[qi] = PredictedResult(itemScores=[])
-                    continue
-                vec = np.asarray(recent)
-            vecs[qi] = vec.astype(np.float32)
-            masks[qi] = self._exclusions(model, q)
-            if q.categories is None and q.whiteList is None:
-                simple.append(qi)
-            else:
-                complex_.append(qi)
-        V = self._weighted_item_factors(model)
         n_items = len(model.item_index)
-        if simple:
-            batch = np.stack([vecs[qi] for qi in simple])
-            k = _pow2(
-                max(
-                    int(queries[qi][1].num) + int(masks[qi].sum())
-                    for qi in simple
+        V = self._weighted_item_factors(model)
+        k = _pow2(max(int(q.num) for _, q in queries)) if queries else 1
+        kp = (
+            retrieval.shortlist_k(k, n_items)
+            if retrieval.engaged(n_items)
+            else 0
+        )
+        two_stage = bool(kp) and k <= kp < n_items
+        with obs_trace.region("rules.build", hist=_m_rules):
+            cache, _ = self._filter_cache()  # one token read per dispatch
+            coarse = self._coarse_catalog(model) if two_stage else None
+            avail, cats = self._catalog_rules(
+                model, coarse.stored_rows if two_stage else n_items, cache
+            )
+            scored: list[int] = []
+            vecs, excluded, qcats, whites = [], [], [], []
+            for qi, (_, q) in enumerate(queries):
+                _m_queries[_query_kind(q)].inc()
+                if q.user in model.user_index:
+                    vec = np.asarray(model.user_rows(model.user_index[q.user]))
+                else:
+                    recent = self._recent_item_vector(model, q.user)
+                    if recent is None:
+                        logger.info(
+                            "user %s has no factors and no recent views;"
+                            " empty result",
+                            q.user,
+                        )
+                        results[qi] = PredictedResult(itemScores=[])
+                        continue
+                    vec = np.asarray(recent)
+                ex, qc, white = self._query_rows(model, q, cache)
+                _m_excluded.observe(float(len(ex)))
+                if len(ex) > _EXCLUDED_BUCKET:
+                    _m_overflow.inc()
+                scored.append(qi)
+                vecs.append(vec.astype(np.float32))
+                excluded.append(ex)
+                qcats.append(qc)
+                whites.append(white)
+
+            def padded(rows: list[int]) -> list[int]:
+                """``rows`` filled to a power of two by copies of the
+                first (the batch shapes the programs compile for)."""
+                return rows + rows[:1] * (_pow2(len(rows)) - len(rows))
+
+            def rules_for(rows: list[int]) -> Rules:
+                """The rules of ``scored[r] for r in rows``, padded."""
+                rows = padded(rows)
+                width = max(
+                    [_EXCLUDED_BUCKET] + [len(excluded[r]) for r in rows]
                 )
-            )
-            kp = (
-                retrieval.shortlist_k(k, n_items)
-                if retrieval.engaged(n_items)
-                else 0
-            )
-            if kp and k <= kp < n_items:
-                # two-stage: coarse shortlist over the weighted catalog,
-                # exact rescore of the [B, S] candidates (ops/retrieval.py)
-                _, cand = self._coarse_catalog(model).shortlist(batch, kp)
+                ex = np.full((len(rows), _pow2(width)), -1, np.int32)
+                qcat = np.full(
+                    (len(rows), _pow2(max(
+                        [1] + [len(qcats[r] or ()) for r in rows]
+                    ))), -2, np.int32,
+                )
+                for j, r in enumerate(rows):
+                    ex[j, : len(excluded[r])] = excluded[r]
+                    if qcats[r]:
+                        qcat[j, : len(qcats[r])] = qcats[r]
+                has_cat = np.asarray([qcats[r] is not None for r in rows])
+                return retrieval.device_rules(
+                    Rules(avail, cats, qcat, has_cat, ex)
+                )
+
+            def batch_for(rows: list[int]) -> np.ndarray:
+                return np.stack([vecs[r] for r in padded(rows)])
+
+            open_ = [r for r in range(len(scored)) if whites[r] is None]
+            listed = [r for r in range(len(scored)) if whites[r] is not None]
+            open_rules = rules_for(open_) if open_ else None
+            listed_rules = rules_for(listed) if listed else None
+
+        def publish(rows, scores, ids):
+            inv = model.item_index.inverse
+            scores, ids = np.asarray(scores), np.asarray(ids)
+            for j, r in enumerate(rows):
+                qi = scored[r]
+                num = int(queries[qi][1].num)
+                results[qi] = PredictedResult(itemScores=[
+                    ItemScore(item=inv[int(i)], score=float(s))
+                    for s, i in zip(scores[j, :num], ids[j, :num])
+                    if int(i) >= 0
+                ])
+
+        if open_:
+            batch = batch_for(open_)
+            if two_stage:
+                # coarse shortlist over the weighted catalog, exact
+                # rescore of the [B, S] candidates — both under the rules
+                _, cand = coarse.shortlist(batch, kp, open_rules)
                 scores, ids = retrieval.rescore_top_k_batch(
-                    batch, V, cand, k=k
+                    batch, V, cand, k=k, rules=open_rules
                 )
                 if retrieval.probe_due():
-                    _, exact_ids = top_k_items_batch(batch[:1], V, k=k)
+                    _, exact_ids = top_k_items_batch_masked(
+                        batch[:1], V,
+                        open_rules._replace(
+                            qcat=open_rules.qcat[:1],
+                            has_cat=open_rules.has_cat[:1],
+                            ex=open_rules.ex[:1],
+                        ), k=k,
+                    )
+                    n0 = int(queries[scored[open_[0]]][1].num)
                     retrieval.probe_recall(
-                        ids[0], np.asarray(exact_ids)[0]
+                        ids[0, :n0], np.asarray(exact_ids)[0, :n0]
                     )
             else:
-                scores, ids = top_k_items_batch(batch, V, k=k)
-            scores, ids = np.asarray(scores), np.asarray(ids)
-            for row, qi in enumerate(simple):
-                mask, num = masks[qi], int(queries[qi][1].num)
-                item_scores: list[ItemScore] = []
-                for s, i in zip(scores[row], ids[row]):
-                    ii = int(i)
-                    if ii < 0 or mask[ii]:
-                        continue
-                    item_scores.append(ItemScore(item=inv[ii], score=float(s)))
-                    if len(item_scores) == num:
-                        break
-                results[qi] = PredictedResult(itemScores=item_scores)
-        if complex_ and retrieval.engaged(n_items):
-            # category/whiteList masks can cover most of the catalog:
-            # exact masked path
-            retrieval.note_exact(len(complex_))
-        for qi in complex_:
-            num = int(queries[qi][1].num)
-            scores, ids = top_k_items_batch(
-                vecs[qi][None, :], V, k=_pow2(num),
-                exclude_mask=jnp.asarray(masks[qi]),
+                scores, ids = top_k_items_batch_masked(
+                    batch, V, open_rules, k=k
+                )
+            publish(open_, scores, ids)
+        if listed:
+            # a whiteList IS the candidate list: every allowed member is
+            # scored exactly, whatever the catalog's size
+            width = _pow2(max([k] + [len(whites[r]) for r in listed]))
+            cand = np.full((len(listed_rules.ex), width), -1, np.int32)
+            for j, r in enumerate(listed):
+                cand[j, : len(whites[r])] = whites[r]
+            cand[len(listed):] = cand[0]
+            scores, ids = retrieval.rescore_top_k_batch(
+                batch_for(listed), V, cand, k=k, rules=listed_rules
             )
-            row_s = np.asarray(scores)[0][:num]
-            row_i = np.asarray(ids)[0][:num]
-            results[qi] = PredictedResult(
-                itemScores=[
-                    ItemScore(item=inv[int(i)], score=float(s))
-                    for s, i in zip(row_s, row_i)
-                    if s > -1e29
-                ]
-            )
+            publish(listed, scores, ids)
         return [(ix, r) for (ix, _), r in zip(queries, results)]
 
 

@@ -93,6 +93,7 @@ import numpy as np
 from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.obs import trace as obs_trace
+from predictionio_tpu.ops.topk import Rules, rows_allowed
 
 NEG_INF = -1e30
 
@@ -222,9 +223,7 @@ def stats_block() -> dict:
 # -- coarse shortlist kernel -------------------------------------------------
 
 
-@obs_device.track_jit("retrieval.coarse_topk")
-@functools.partial(jax.jit, static_argnames=("k", "mode"))
-def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
+def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
     tile scores [B, T] in the catalog's storage precision, takes the
     tile's top-k', and merges into the running best — the [B, I] score
@@ -234,8 +233,27 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
     ``mode``: "int8" (values*scale columns, f32 GEMM on cast values),
     "int8_dot" (int8 x int8 -> int32 accumulation, quantized queries —
     the per-query quantization scale is positive so it drops out of the
-    within-row ranking), or "bf16" (scales is None)."""
+    within-row ranking), or "bf16" (scales is None).
+
+    ``rules`` (ops/topk.py ``Rules`` over the NT * T stored rows): rows
+    a query may not be served score NEG_INF BEFORE the tile's top-k and
+    come out as id -1, so exclusions cost no headroom in k. The queries'
+    own lists are scattered once per call into a [NT, B, T] mask that
+    the scan slices like the tiles. Without ``rules`` the program is the
+    one it was before rules existed, op for op."""
     B = q.shape[0]
+    if rules is not None:
+        nt, t = ids.shape
+        with jax.named_scope("retrieval.shortlist.mask"):
+            ex = jnp.where(rules.ex >= 0, rules.ex, nt * t)  # pads drop
+            hit = jnp.zeros((nt, B, t), bool).at[
+                ex // t, jnp.arange(B)[:, None], ex % t
+            ].set(True, mode="drop")
+        masks = (
+            rules.avail.reshape(nt, t),
+            tuple(c.reshape(nt, t) for c in rules.cats),
+            hit,
+        )
     if mode == "int8_dot":
         qs = jnp.max(jnp.abs(q), axis=1, keepdims=True) / 127.0
         qi = jnp.clip(
@@ -244,6 +262,8 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
 
     def step(carry, xs):
         best_s, best_i = carry
+        if rules is not None:
+            xs, (av, cs, ht) = xs
         if scales is None:
             v, tid = xs
         else:
@@ -264,11 +284,17 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
                 if scales is not None:
                     sc = sc * s[None, :]
             sc = jnp.where(tid[None, :] >= 0, sc, NEG_INF)
+        if rules is not None:
+            with jax.named_scope("retrieval.shortlist.mask"):
+                ok = rows_allowed(av, cs, ht, rules.qcat, rules.has_cat)
+                sc = jnp.where(ok, sc, NEG_INF)
         with jax.named_scope("retrieval.shortlist.tile_topk"):
             ts, tix = jax.lax.top_k(sc, k)
             ti = jnp.take_along_axis(
                 jnp.broadcast_to(tid[None, :], sc.shape), tix, axis=1
             )
+            if rules is not None:
+                ti = jnp.where(ts > NEG_INF / 2, ti, -1)
         with jax.named_scope("retrieval.shortlist.merge"):
             cs = jnp.concatenate([best_s, ts], axis=1)
             ci = jnp.concatenate([best_i, ti], axis=1)
@@ -281,8 +307,36 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
         jnp.full((B, k), -1, jnp.int32),
     )
     xs = (tiles, ids) if scales is None else (tiles, scales, ids)
+    if rules is not None:
+        xs = (xs, masks)
     (best_s, best_i), _ = jax.lax.scan(step, init, xs)
     return best_s, best_i
+
+
+@obs_device.track_jit("retrieval.coarse_topk")
+@functools.partial(jax.jit, static_argnames=("k", "mode"))
+def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
+    return _coarse_scan(q, tiles, scales, ids, k, mode)
+
+
+@obs_device.track_jit("retrieval.coarse_topk_masked")
+@functools.partial(jax.jit, static_argnames=("k", "mode"))
+def _coarse_topk_masked(q, tiles, scales, ids, rules: Rules, k: int,
+                        mode: str):
+    """The same scan under business rules: a program of its own, so the
+    unmasked one stays what it is (and a device trace tells them apart:
+    ``jit__coarse_topk_masked``)."""
+    return _coarse_scan(q, tiles, scales, ids, k, mode, rules)
+
+
+def device_rules(rules: Rules) -> Rules:
+    """``rules`` with its per-query parts put on the device: once per
+    dispatch, for both stages."""
+    return rules._replace(
+        qcat=jnp.asarray(np.asarray(rules.qcat, np.int32)),
+        has_cat=jnp.asarray(np.asarray(rules.has_cat, bool)),
+        ex=jnp.asarray(np.asarray(rules.ex, np.int32)),
+    )
 
 
 class CoarseCatalog:
@@ -363,12 +417,22 @@ class CoarseCatalog:
             n += self._scales.size * 4
         return n + self._ids.size * 4
 
-    def shortlist(self, queries, k: int):
+    @property
+    def stored_rows(self) -> int:
+        """Rows the tiles hold, padding included: the length of a
+        ``Rules`` vector that this catalog's scan can slice."""
+        return int(self._ids.size)
+
+    def shortlist(self, queries, k: int, rules: Rules | None = None):
         """Coarse top-k' candidate ids for a [B, D] f32 query batch ->
         ([B, k'] coarse scores, [B, k'] int32 ids, -1 past the catalog).
         B pads to a pow2 bucket (copies of row 0, discarded) and k'
         clamps to the tile width, so arbitrary traffic reuses a bounded
-        set of compiled programs."""
+        set of compiled programs. Under ``rules`` (``device_rules``:
+        vectors of ``stored_rows``, per-query rows for a batch the
+        caller has padded to a power of two) only rows the query may be
+        served are candidates; a query with fewer than k' of them gets
+        id -1 in the rest."""
         q = np.ascontiguousarray(np.asarray(queries, dtype=np.float32))
         B = q.shape[0]
         k = max(1, min(int(k), self.tile))
@@ -376,10 +440,20 @@ class CoarseCatalog:
         if bp > B:
             q = np.concatenate([q, np.repeat(q[:1], bp - B, axis=0)])
         with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
-            s, ids = _coarse_topk(
-                jnp.asarray(q), self._tiles, self._scales, self._ids, k,
-                self.mode,
-            )
+            if rules is None:
+                s, ids = _coarse_topk(
+                    jnp.asarray(q), self._tiles, self._scales, self._ids, k,
+                    self.mode,
+                )
+            else:
+                if len(rules.ex) != bp:
+                    raise ValueError(
+                        f"rules for {len(rules.ex)} queries, batch of {bp}"
+                    )
+                s, ids = _coarse_topk_masked(
+                    jnp.asarray(q), self._tiles, self._scales, self._ids,
+                    rules, k, self.mode,
+                )
             s, ids = np.asarray(s)[:B], np.asarray(ids)[:B]
         _m_shortlist_size.observe(float(k))
         return s, ids
@@ -422,10 +496,13 @@ def _table_rows(table, ixs):
     return _gather_rows(table, ixs)
 
 
-def _score_candidates(qvecs, item_factors, cand_ids, k: int):
+def _score_candidates(qvecs, item_factors, cand_ids, k: int, rules=None):
     """Shared exact-f32 candidate scorer: gather the [B, S] candidate
     rows (dequantizing int8 pairs on device), dot against the query
-    vectors, top-k. -1 candidate slots can never win and report id -1."""
+    vectors, top-k. -1 candidate slots can never win and report id -1;
+    nor can a candidate that ``rules`` keeps from its query (the
+    shortlist applied them already: a second, independent application
+    where the served scores are produced)."""
     with jax.named_scope("retrieval.rescore.gather"):
         cand = jnp.maximum(cand_ids.astype(jnp.int32), 0)
         rows = _table_rows(item_factors, cand)
@@ -435,6 +512,14 @@ def _score_candidates(qvecs, item_factors, cand_ids, k: int):
             preferred_element_type=jnp.float32,
         )
         sc = jnp.where(cand_ids >= 0, sc, NEG_INF)
+    if rules is not None:
+        with jax.named_scope("retrieval.rescore.mask"):
+            hit = (cand_ids[:, :, None] == rules.ex[:, None, :]).any(-1)
+            ok = rows_allowed(
+                rules.avail[cand], tuple(c[cand] for c in rules.cats), hit,
+                rules.qcat, rules.has_cat,
+            )
+            sc = jnp.where(ok, sc, NEG_INF)
     with jax.named_scope("retrieval.rescore.topk"):
         k = min(k, int(cand_ids.shape[1]))
         s, ix = jax.lax.top_k(sc, k)
@@ -513,6 +598,12 @@ def _rescore_vectors(user_vectors, item_factors, cand_ids, k: int):
     return _score_candidates(user_vectors, item_factors, cand_ids, k)
 
 
+@_rescore_program("retrieval.rescore_vectors_masked")
+def _rescore_vectors_masked(user_vectors, item_factors, cand_ids,
+                            rules: Rules, k: int):
+    return _score_candidates(user_vectors, item_factors, cand_ids, k, rules)
+
+
 @_rescore_program("retrieval.rescore_sum_rows")
 def _rescore_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
     rows = _table_rows(item_factors, row_ixs.astype(jnp.int32))
@@ -544,12 +635,19 @@ def rescore_gather_top_k_batch(user_ixs, user_factors, item_factors,
     ), len(cand_ids))
 
 
-def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int):
+def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int,
+                        rules: Rules | None = None):
     """Shortlist-gather variant of ``top_k_items_batch``: [B, D] query
-    vectors against a [B, S] candidate-id matrix."""
-    return _rescore(lambda: _rescore_vectors(
+    vectors against a [B, S] candidate-id matrix, under ``rules`` where
+    given (``device_rules``, their per-query rows for these B queries)."""
+    if rules is None:
+        return _rescore(lambda: _rescore_vectors(
+            jnp.asarray(np.asarray(user_vectors, np.float32)), item_factors,
+            jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
+        ), len(cand_ids))
+    return _rescore(lambda: _rescore_vectors_masked(
         jnp.asarray(np.asarray(user_vectors, np.float32)), item_factors,
-        jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
+        jnp.asarray(np.asarray(cand_ids, np.int32)), rules, k=k,
     ), len(cand_ids))
 
 

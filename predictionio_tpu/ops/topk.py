@@ -12,6 +12,7 @@ weighted-items/src/main/scala/ALSAlgorithm.scala:234-265).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +86,78 @@ def top_k_items_batch(user_vectors, item_factors, k: int, exclude_mask=None):
         scores = jnp.where(exclude_mask.astype(bool)[None, :], NEG_INF, scores)
     k = min(k, catalog_rows(item_factors))
     return jax.lax.top_k(scores, k)
+
+
+class Rules(NamedTuple):
+    """Serve-time business rules as device arguments (the e-commerce
+    template builds them, models/ecommerce.py): the catalog-wide rules
+    as resident vectors over the P >= I stored rows, the per-query rules
+    as short padded index lists — never a dense [I] mask per query.
+
+    ``avail`` [P] uint8: 0 = the row may not be served (unavailable, or
+    padding past the catalog); ``cats``: W vectors [P] int32, the row's
+    w-th category id (-1 = none); ``qcat`` [B, C] int32: the categories
+    a query is restricted to (-2 pads) and ``has_cat`` [B] bool whether
+    it is restricted at all; ``ex`` [B, E] int32: rows excluded for this
+    query alone (seen, blackList; -1 pads)."""
+
+    avail: jax.Array
+    cats: tuple
+    qcat: jax.Array
+    has_cat: jax.Array
+    ex: jax.Array
+
+
+def rows_allowed(av, cs, hit, qcat, has_cat):
+    """[B, S] bool — may each of S rows be served to each of B queries?
+    ``av`` / ``cs`` are the rows' ``Rules.avail`` / ``Rules.cats``
+    entries ([S], or [B, S] where every query has rows of its own),
+    ``hit`` [B, S] marks the rows on the query's own exclusion list."""
+    if av.ndim == 1:
+        av, cs = av[None], tuple(c[None] for c in cs)
+    in_cat = jnp.zeros(hit.shape, bool)
+    for c in cs:
+        in_cat = in_cat | (c[..., None] == qcat[:, None, :]).any(-1)
+    return (av != 0) & ~hit & (in_cat | ~has_cat[:, None])
+
+
+@obs_device.track_jit("topk.top_k_items_batch_masked")
+@functools.partial(jax.jit, static_argnames=("k",))
+def top_k_items_batch_masked(user_vectors, item_factors, rules: Rules, k: int):
+    """``top_k_items_batch`` under ``Rules``: the mask is built on the
+    device from the resident vectors and the queries' index lists and
+    applied before the top-k, so k carries no headroom for exclusions.
+    A query with fewer than k allowed rows reports id -1 in the slots it
+    cannot fill. The products are f32 on every backend
+    (``precision=HIGHEST``, as ops/als.py's solves: a TPU's default
+    rounds f32 operands to bf16, 1.4e-2 off on unit-variance scores)."""
+    hi = jax.lax.Precision.HIGHEST
+    if isinstance(item_factors, tuple):
+        q, s = item_factors
+        scores = (
+            jnp.matmul(
+                user_vectors.astype(jnp.float32), q.T.astype(jnp.float32),
+                precision=hi, preferred_element_type=jnp.float32,
+            )
+            * s[None, :]
+        )  # [B, I]
+    else:
+        scores = jnp.matmul(
+            user_vectors.astype(jnp.float32),
+            item_factors.astype(jnp.float32).T,
+            precision=hi, preferred_element_type=jnp.float32,
+        )  # [B, I]
+    B, n = scores.shape
+    ex = jnp.where(rules.ex >= 0, rules.ex, n)  # pads fall off the end
+    hit = jnp.zeros((B, n), bool).at[
+        jnp.arange(B)[:, None], ex
+    ].set(True, mode="drop")
+    ok = rows_allowed(
+        rules.avail[:n], tuple(c[:n] for c in rules.cats), hit,
+        rules.qcat, rules.has_cat,
+    )
+    s, ids = jax.lax.top_k(jnp.where(ok, scores, NEG_INF), min(k, n))
+    return s, jnp.where(s > NEG_INF / 2, ids, -1)
 
 
 @obs_device.track_jit("topk.gather_top_k_batch")
