@@ -19,6 +19,12 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    carry a bf16 coarse copy. On the mesh, the coarse pass is
    parallel/ring_topk.py's ``coarse=True`` variant (per-shard
    oversampled top-k', int8 slabs scored without dequantization).
+   A scan step never selects over its whole tile where the tile is
+   large: it takes the maximum of each group of G scores, the k' best
+   groups, and the k' best of those groups' scores — exactly the
+   tile's top-k', from selections over T/G + k'G elements instead of T
+   (``tile_select_group`` decides from T and k' alone; every mode and
+   the masked scan share ``_tile_top_k``).
 2. **Exact rescore** — gather the [B, S] shortlisted rows and rescore
    them in f32 through shortlist-gather variants of the fused ops
    (``rescore_*_top_k_batch`` below). The rescore builds its query
@@ -67,7 +73,9 @@ program loaded from the persistent compile cache writes in a
 non-default layout reports the default one (jax 0.9.0, v5e), so the
 next program is compiled for a layout the buffer does not have.)
 
-Observability: ``pio_retrieval_*`` metrics (docs/observability.md); each
+Observability: ``pio_retrieval_*`` metrics (docs/observability.md);
+``pio_retrieval_tile_select_total{path}`` says which selection a
+shortlist call's program holds (``two_level`` / ``plain``); each
 rescore program publishes the temporary bytes its compiled form needs
 (``pio_retrieval_rescore_temp_bytes``: a table-sized number means a
 re-layout came back); the
@@ -96,6 +104,7 @@ from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.ops.topk import Rules, rows_allowed
 
 NEG_INF = -1e30
+_LANES = 128  # the minor dimension of a TPU's vector registers and tiles
 
 # -- knobs (env-read per call: operators flip them on a live server) --------
 
@@ -169,6 +178,15 @@ _m_probes = obs_metrics.counter(
     "pio_retrieval_probes_total", "live recall probes run",
 )
 
+_m_tile_select = {
+    path: obs_metrics.counter(
+        "pio_retrieval_tile_select_total",
+        "shortlist calls by how a scan step selects its tile's k' best",
+        path=path,
+    )
+    for path in ("two_level", "plain")
+}
+
 _probe_clock = itertools.count(1)
 
 
@@ -211,6 +229,7 @@ def stats_block() -> dict:
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
         "rescore_seconds": _m_rescore_secs.summary(),
+        "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
         "rescore_temp_bytes": {
             p.name: p.temp_bytes() for p in _RESCORE_PROGRAMS
             if p._cache_size()
@@ -221,6 +240,73 @@ def stats_block() -> dict:
 
 
 # -- coarse shortlist kernel -------------------------------------------------
+
+
+# A [B, T] tile's k' best in two exact levels: the T scores of a row in
+# T/G groups of G, each group's maximum (the one pass that still touches
+# all T values), ``top_k`` of the [B, T/G] maxima, the chosen groups'
+# [B, k', G] scores read out of the same array, and the k' best of those
+# [B, k'G] candidates. An element among the k' largest has fewer than k'
+# elements above it, and every group whose maximum exceeds its own
+# group's holds one of them: its group is among the k' best by maximum.
+# On a TPU v5e a ``top_k`` of 262,144 scores with k' = 128 is 115 us at
+# B = 1 (two sorts) and 1,620 us at B = 16 (the ``TopK`` custom call)
+# against 92 / 46 us to score the tile; a sort of [B, 2048] is 6 / 14 us
+# and one of 1,024 costs hardly less (PERF.md section 6, PR 27). So
+# nothing under _MIN_SPLIT is split, a group is a 128-lane row of the
+# tile where that pays (the reshape then follows the tile's own layout),
+# and the candidates go through the same helper again: a 2^18 tile at
+# k' = 128 sorts [B, 2048] maxima, then its [B, 16384] candidates as
+# [B, 1024] maxima and [B, 2048] candidates.
+_MIN_SPLIT = 1 << 13
+
+
+def tile_select_group(t: int, k: int) -> int:
+    """Group width G of the two-level selection of the k' = ``k`` best
+    of T = ``t`` scores, or 0 where the plain ``lax.top_k`` stays: T
+    under _MIN_SPLIT (the tiles of the CPU tests) or no whole number of
+    groups, fewer groups than k', or the two selections' T/G + k'G
+    elements more than a quarter of T (a k' that nears the tile). G is
+    a row of 128 lanes where that passes, else the power of two at or
+    above sqrt(T/k'), which balances the two. Decided from the two
+    shapes alone: at trace time, and on the host for the counter."""
+    if t < _MIN_SPLIT:
+        return 0
+    for g in (_LANES, _pow2(int(np.ceil(np.sqrt(t / k))))):
+        if t % g == 0 and t // g >= k and 4 * (t // g + k * g) <= t:
+            return g
+    return 0
+
+
+def _pick(table, ix):
+    """``jnp.take_along_axis(table, ix, axis=1)`` for [B, k] ``table``
+    and ``ix`` as a compare and a sum over k: a TPU gathers scalars one
+    at a time (16 us for [16, 128] of them, the cost of a sort of
+    [16, 2048]), and the [B, k, k] compare fuses into the sum."""
+    hit = ix[:, :, None] == jnp.arange(table.shape[1], dtype=ix.dtype)
+    return jnp.sum(jnp.where(hit, table[:, None, :], 0), axis=2)
+
+
+def _two_level_top_k(sc, k: int, g: int):
+    """``jax.lax.top_k(sc, k)`` of a [B, T] array through groups of
+    ``g`` (T a multiple of g, T/g >= k). The candidates are read, not
+    recomputed: the values are bit-equal to ``lax.top_k``'s, and the
+    positions can differ from its only among exactly equal scores."""
+    b, t = sc.shape
+    groups = sc.reshape(b, t // g, g)
+    _, gix = jax.lax.top_k(groups.max(axis=2), k)
+    cand = jnp.take_along_axis(groups, gix[:, :, None], axis=1)
+    ts, cix = _tile_top_k(cand.reshape(b, k * g), k)
+    return ts, _pick(gix, cix // g) * g + cix % g
+
+
+def _tile_top_k(sc, k: int):
+    """The k best of each row of a [B, T] array of coarse scores, and
+    their positions in it."""
+    g = tile_select_group(sc.shape[1], k)
+    if not g:
+        return jax.lax.top_k(sc, k)
+    return _two_level_top_k(sc, k, g)
 
 
 def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
@@ -289,7 +375,7 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
                 ok = rows_allowed(av, cs, ht, rules.qcat, rules.has_cat)
                 sc = jnp.where(ok, sc, NEG_INF)
         with jax.named_scope("retrieval.shortlist.tile_topk"):
-            ts, tix = jax.lax.top_k(sc, k)
+            ts, tix = _tile_top_k(sc, k)
             ti = jnp.take_along_axis(
                 jnp.broadcast_to(tid[None, :], sc.shape), tix, axis=1
             )
@@ -456,6 +542,9 @@ class CoarseCatalog:
                 )
             s, ids = np.asarray(s)[:B], np.asarray(ids)[:B]
         _m_shortlist_size.observe(float(k))
+        _m_tile_select[
+            "two_level" if tile_select_group(self.tile, k) else "plain"
+        ].inc()
         return s, ids
 
 
@@ -471,7 +560,7 @@ class CoarseCatalog:
 # same buffer keeps the gather under that limit, and the reshape is a
 # bitcast: 32 is a whole number of sublane tiles for all three dtypes
 # (a group of 25 is not: that reshape is a real copy and a 145 s compile).
-_VIEW_COLUMNS, _LANES = 32, 128
+_VIEW_COLUMNS = 32
 
 
 def _gather_rows(table, ixs):

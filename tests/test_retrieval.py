@@ -627,3 +627,276 @@ class TestStageSpans:
         assert r_off + r_dur <= d_off + d_dur
         assert outer.self_seconds == pytest.approx(d_dur - s_dur - r_dur)
         assert obs_trace.current_trace() is None
+
+
+# -- the tile's top-k' in two exact levels ------------------------------------
+
+
+def _top_k_eqns(jaxpr):
+    """Operand shapes of every ``top_k`` in ``jaxpr``, sub-jaxprs (the
+    scan's body) included."""
+    shapes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "top_k":
+            shapes.append(tuple(eqn.invars[0].aval.shape))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    shapes.extend(_top_k_eqns(inner))
+    return shapes
+
+
+def _scan_args(b, nt, t, d):
+    import jax.numpy as jnp
+
+    return (
+        jnp.ones((b, d), jnp.float32), jnp.ones((nt, t, d), jnp.bfloat16),
+        jnp.arange(nt * t, dtype=jnp.int32).reshape(nt, t),
+    )
+
+
+def _scan_jaxpr(b, nt, t, d, k, rules=None):
+    import jax
+
+    return jax.make_jaxpr(
+        lambda q, tiles, ids: retrieval._coarse_scan(
+            q, tiles, None, ids, k, "bf16", rules
+        )
+    )(*_scan_args(b, nt, t, d)).jaxpr
+
+
+def _tie_rows(kind, b, t, k, rng):
+    sc = rng.normal(size=(b, t)).astype(np.float32)
+    if kind == "heavy_ties":
+        sc = rng.integers(0, 6, size=(b, t)).astype(np.float32)
+    elif kind == "neg_inf_band":
+        sc[:, t // 4: 3 * t // 4] = retrieval.NEG_INF
+    elif kind == "few_finite":
+        keep = rng.permutation(t)[: k // 2]
+        few = np.full(t, retrieval.NEG_INF, np.float32)
+        few[keep] = sc[0, keep]
+        sc[0] = few
+    elif kind == "all_neg_inf":
+        sc[-1] = retrieval.NEG_INF
+    return sc
+
+
+class TestTileSelect:
+    """``_two_level_top_k`` against ``jax.lax.top_k``: the values are
+    read, not recomputed, so they are bit-equal; ids may differ only
+    among exactly equal scores."""
+
+    @staticmethod
+    def _group(t, k):
+        # a row of lanes where the shape allows it, else the widest
+        # that leaves k groups
+        return min(128, t // k)
+
+    @pytest.mark.parametrize("k", [16, 128, 1024])
+    @pytest.mark.parametrize("t", [1 << 12, 1 << 14, 1 << 16])
+    @pytest.mark.parametrize("b", [1, 4, 16])
+    def test_equals_lax_top_k(self, b, t, k):
+        import jax
+
+        # every row a permutation of t distinct values: the ids too
+        rng = np.random.default_rng(b * t + k)
+        sc = np.stack([rng.permutation(t) for _ in range(b)])
+        sc = (sc.astype(np.float32) - t / 3) * np.float32(0.37)
+        assert all(len(np.unique(r)) == t for r in sc)
+        want_s, want_i = jax.lax.top_k(sc, k)
+        got_s, got_i = retrieval._two_level_top_k(sc, k, self._group(t, k))
+        np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+        np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+    @pytest.mark.parametrize("b", [1, 4])
+    @pytest.mark.parametrize(
+        "kind", ["heavy_ties", "neg_inf_band", "few_finite", "all_neg_inf"]
+    )
+    def test_ties_and_masked_rows(self, kind, b):
+        import jax
+
+        t, k = 1 << 14, 128
+        sc = _tie_rows(kind, b, t, k, np.random.default_rng(len(kind) + b))
+        want_s, _ = jax.lax.top_k(sc, k)
+        got_s, got_i = retrieval._two_level_top_k(sc, k, self._group(t, k))
+        got_s, got_i = np.asarray(got_s), np.asarray(got_i)
+        np.testing.assert_array_equal(got_s, np.asarray(want_s))
+        # every returned position holds the returned value, none repeats
+        np.testing.assert_array_equal(
+            np.take_along_axis(sc, got_i, axis=1), got_s
+        )
+        assert all(len(set(r.tolist())) == k for r in got_i)
+
+    @pytest.mark.parametrize("t,k,path", [
+        (1 << 18, 128, "two_level"),   # both benchmark configurations
+        (1 << 18, 16, "two_level"),
+        (1 << 18, 1024, "two_level"),
+        (1 << 18, 1 << 14, "plain"),   # k' nears the tile
+        (1 << 18, 1 << 18, "plain"),
+        (1 << 16, 128, "two_level"),
+        (1 << 13, 128, "two_level"),
+        (1 << 12, 16, "plain"),        # under _MIN_SPLIT
+        (256, 64, "plain"),            # the tiles of the tests above
+        (16, 4, "plain"),
+        (3 * (1 << 16) + 1, 128, "plain"),  # no whole number of groups
+    ])
+    def test_shape_rule(self, t, k, path):
+        g = retrieval.tile_select_group(t, k)
+        assert ("two_level" if g else "plain") == path
+        if g:
+            assert t % g == 0 and t // g >= k
+            assert 4 * (t // g + k * g) <= t
+
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_engaged_scan_never_sorts_a_whole_tile(self, masked):
+        """The jaxpr of an engaged scan holds no ``top_k`` whose operand
+        is T wide: the group maxima, the candidates, the merge."""
+        b, nt, t, d, k = 2, 2, 1 << 13, 8, 16
+        g = retrieval.tile_select_group(t, k)
+        assert g
+        rules = None
+        if masked:
+            rules = _rules(nt * t, b)
+        shapes = _top_k_eqns(_scan_jaxpr(b, nt, t, d, k, rules))
+        assert sorted(shapes) == sorted(
+            [(b, t // g), (b, k * g), (b, 2 * k)]
+        )
+
+    def test_the_benchmark_tile_is_selected_in_small_sorts(self):
+        """2^18 rows at k' = 128, both configurations' shape: [B, 2048]
+        group maxima, then the [B, 16384] candidates through the same
+        helper ([B, 1024] maxima, [B, 2048] candidates), then the merge."""
+        b, t, k = 16, 1 << 18, 128
+        assert retrieval.tile_select_group(t, k) == 128
+        assert retrieval.tile_select_group(k * 128, k) == 16
+        assert sorted(_top_k_eqns(_scan_jaxpr(b, 1, t, 8, k))) == [
+            (b, 2 * k), (b, 1024), (b, 2048), (b, 2048)
+        ]
+
+    def test_plain_scan_is_the_program_it_was(self):
+        """Where two levels do not pay the step holds exactly the one
+        ``top_k`` over the tile, and the merge's."""
+        b, nt, t, d, k = 2, 3, 256, 8, 64
+        assert not retrieval.tile_select_group(t, k)
+        assert sorted(_top_k_eqns(_scan_jaxpr(b, nt, t, d, k))) == [
+            (b, 2 * k), (b, t)
+        ]
+
+    def test_the_programs_keep_their_names(self):
+        """The benchmark's roofline readers find the two programs in a
+        device trace as ``jit__coarse_topk`` / ``jit__coarse_topk_masked``."""
+        b, nt, t, d, k = 2, 2, 1 << 13, 8, 16
+        q, tiles, ids = _scan_args(b, nt, t, d)
+        text = retrieval._coarse_topk.lower(
+            q, tiles, None, ids, k=k, mode="bf16"
+        ).as_text()
+        assert "module @jit__coarse_topk " in text
+        text = retrieval._coarse_topk_masked.lower(
+            q, tiles, None, ids, _rules(nt * t, b), k=k, mode="bf16"
+        ).as_text()
+        assert "module @jit__coarse_topk_masked " in text
+
+
+def _rules(stored, b, *, small_cat=(), ex=None, qcat=None):
+    """Rules over ``stored`` rows for ``b`` queries: every 97th row
+    unavailable, category 1 = ``small_cat`` rows, category 0 the rest."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops.topk import Rules
+
+    avail = np.ones(stored, np.uint8)
+    avail[::97] = 0
+    cat = np.zeros(stored, np.int32)
+    cat[list(small_cat)] = 1
+    return retrieval.device_rules(Rules(
+        avail=jnp.asarray(avail), cats=(jnp.asarray(cat),),
+        qcat=np.full((b, 1), -2, np.int32) if qcat is None else qcat,
+        has_cat=np.zeros(b, bool) if qcat is None else (qcat[:, 0] >= 0),
+        ex=np.full((b, 4), -1, np.int32) if ex is None else ex,
+    ))
+
+
+def _coarse_scores(cat, table, q):
+    """NumPy's copy of the coarse scores ``cat`` ranks by: [B, I] f32."""
+    import jax.numpy as jnp
+
+    vals, scales = table
+    if cat.mode == "bf16":
+        f = vals.astype(np.float32) * scales[:, None]
+        return q @ np.asarray(
+            jnp.asarray(f).astype(jnp.bfloat16).astype(jnp.float32)
+        ).T
+    if cat.mode == "int8":
+        return (q @ vals.astype(np.float32).T) * scales[None, :]
+    qs = np.abs(q).max(axis=1, keepdims=True) / np.float32(127.0)
+    qi = np.clip(np.round(q / np.maximum(qs, 1e-12)), -127, 127)
+    dots = qi.astype(np.int64) @ vals.astype(np.int64).T
+    return dots.astype(np.float32) * scales[None, :]
+
+
+class TestTwoLevelShortlist:
+    """``CoarseCatalog.shortlist`` at a tile wide enough to engage two
+    levels: the shortlist is the top-k' of the coarse scores, as a set."""
+
+    I, D, T, K = 40_000, 16, 1 << 14, 64
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_shortlist_is_the_numpy_selection(self, mode, masked):
+        assert retrieval.tile_select_group(self.T, self.K)
+        table = _int8(self.I, self.D, seed=31)
+        q = _dense(4, self.D, seed=32)
+        cat = CoarseCatalog(table, tile=self.T, mode=mode)
+        assert cat.tile == self.T and cat.stored_rows == 3 * self.T
+        sc = _coarse_scores(cat, table, q)
+        allowed = np.ones((4, self.I), bool)
+        rules = None
+        if masked:
+            small = np.random.default_rng(33).permutation(self.I)[:40]
+            small = small[small % 97 != 0]  # available ones
+            ex = np.full((4, 4), -1, np.int32)
+            ex[2, :3] = np.argsort(-sc[2])[:3]  # the query's own best
+            qcat = np.asarray([[-2], [1], [-2], [0]], np.int32)
+            rules = _rules(cat.stored_rows, 4, small_cat=small, ex=ex,
+                           qcat=qcat)
+            allowed[:, ::97] = False
+            in_small = np.zeros(self.I, bool)
+            in_small[small] = True
+            allowed[1] &= in_small
+            allowed[3] &= ~in_small
+            allowed[2, ex[2, :3]] = False
+            assert 0 < allowed[1].sum() < self.K
+        before = retrieval.stats_block()["tile_select"]
+        s, ids = cat.shortlist(q, self.K, rules)
+        after = retrieval.stats_block()["tile_select"]
+        assert after["two_level"] == before["two_level"] + 1
+        assert after["plain"] == before["plain"]
+        for b in range(4):
+            ranked = np.argsort(-np.where(allowed[b], sc[b], -np.inf),
+                                kind="stable")
+            want = ranked[: min(self.K, int(allowed[b].sum()))]
+            got = ids[b][ids[b] >= 0]
+            assert len(got) == len(set(got.tolist()))
+            assert set(got.tolist()) == set(want.tolist()), (mode, b)
+            # a short answer's tail is -1, at the end
+            assert (ids[b][len(want):] == -1).all()
+            np.testing.assert_allclose(
+                s[b][: len(want)], sc[b][ids[b][: len(want)]],
+                rtol=1e-5, atol=1e-5,
+            )
+
+    def test_a_small_tile_counts_as_plain(self):
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        cat = CoarseCatalog(_dense(600, 8, seed=34), tile=256)
+        before = retrieval.stats_block()["tile_select"]
+        cat.shortlist(_dense(2, 8, seed=35), 32)
+        after = retrieval.stats_block()["tile_select"]
+        assert after["plain"] == before["plain"] + 1
+        assert after["two_level"] == before["two_level"]
+        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
+        for path, n in after.items():
+            assert scraped[
+                f'pio_retrieval_tile_select_total{{path="{path}"}}'
+            ] == n

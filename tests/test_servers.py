@@ -1369,6 +1369,15 @@ class TestQueryCacheServing:
         assert status == 200
         assert body["cache"] == {"enabled": False}
 
+    def test_stats_route_carries_the_tile_select_counter(self, deployed_engine):
+        from predictionio_tpu.ops import retrieval
+
+        status, body = http("GET", deployed_engine["base"] + "/stats.json")
+        assert status == 200
+        block = body["retrieval"]["tile_select"]
+        assert set(block) == {"two_level", "plain"}
+        assert block == retrieval.stats_block()["tile_select"]
+
     def test_reload_invalidates(self, cached_engine):
         from predictionio_tpu.core.workflow import run_train
 
