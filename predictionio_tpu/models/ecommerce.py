@@ -60,6 +60,7 @@ from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.ops import als as als_ops
+from predictionio_tpu.ops.retrieval import _pow2
 
 logger = logging.getLogger(__name__)
 
@@ -633,8 +634,9 @@ class ECommAlgorithm(Algorithm):
         return False
 
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
-        # batch of one through the batched scorer: byte-identical to the
-        # same query arriving inside a coalesced micro-batch
+        # batch of one through the batched scorer: the same programs as
+        # the same query inside a coalesced micro-batch (same items in
+        # the same order, scores to the last bits of f32)
         return self.batch_predict(model, [(0, query)])[0][1]
 
     def _query_rows(self, model: ECommModel, q: Query, cache: dict | None):
@@ -673,26 +675,23 @@ class ECommAlgorithm(Algorithm):
         turns each query into index lists (``rules.build``), the rules
         travel to the device as ``ops.topk.Rules`` and every home,
         category and blackList query of the micro-batch shares ONE
-        masked program per stage: the coarse scan and the exact rescore
-        at retrieval scale (``ops/retrieval.py``), the masked exact
-        top-k below it. k is pow2(num) — exclusions are applied before
+        masked program per stage (``ops.retrieval.top_k``: the coarse
+        scan and the exact rescore at retrieval scale, the masked exact
+        top-k below it). k is pow2(num) — exclusions are applied before
         each top-k and need no headroom. ``whiteList`` queries score
         their own candidate lists through the same rescore program."""
         from predictionio_tpu.ops import retrieval
-        from predictionio_tpu.ops.topk import Rules, top_k_items_batch_masked
+        from predictionio_tpu.ops.topk import Rules
 
         results: list[PredictedResult | None] = [None] * len(queries)
         n_items = len(model.item_index)
         V = self._weighted_item_factors(model)
         k = _pow2(max(int(q.num) for _, q in queries)) if queries else 1
-        kp = (
-            retrieval.shortlist_k(k, n_items)
-            if retrieval.engaged(n_items)
-            else 0
-        )
-        two_stage = bool(kp) and k <= kp < n_items
+        two_stage = retrieval.two_stage_k(k, n_items)
         with obs_trace.region("rules.build", hist=_m_rules):
             cache, _ = self._filter_cache()  # one token read per dispatch
+            # the rules are vectors over the rows the scan will slice:
+            # the coarse catalog's (padding included) where one is used
             coarse = self._coarse_catalog(model) if two_stage else None
             avail, cats = self._catalog_rules(
                 model, coarse.stored_rows if two_stage else n_items, cache
@@ -771,31 +770,11 @@ class ECommAlgorithm(Algorithm):
                 ])
 
         if open_:
-            batch = batch_for(open_)
-            if two_stage:
-                # coarse shortlist over the weighted catalog, exact
-                # rescore of the [B, S] candidates — both under the rules
-                _, cand = coarse.shortlist(batch, kp, open_rules)
-                scores, ids = retrieval.rescore_top_k_batch(
-                    batch, V, cand, k=k, rules=open_rules
-                )
-                if retrieval.probe_due():
-                    _, exact_ids = top_k_items_batch_masked(
-                        batch[:1], V,
-                        open_rules._replace(
-                            qcat=open_rules.qcat[:1],
-                            has_cat=open_rules.has_cat[:1],
-                            ex=open_rules.ex[:1],
-                        ), k=k,
-                    )
-                    n0 = int(queries[scored[open_[0]]][1].num)
-                    retrieval.probe_recall(
-                        ids[0, :n0], np.asarray(exact_ids)[0, :n0]
-                    )
-            else:
-                scores, ids = top_k_items_batch_masked(
-                    batch, V, open_rules, k=k
-                )
+            # over the weighted catalog, every stage under the rules
+            scores, ids = retrieval.top_k(
+                retrieval.Vectors(batch_for(open_), open_rules), V, n_items,
+                coarse, k, probe_n=int(queries[scored[open_[0]]][1].num),
+            )
             publish(open_, scores, ids)
         if listed:
             # a whiteList IS the candidate list: every allowed member is
@@ -810,10 +789,6 @@ class ECommAlgorithm(Algorithm):
             )
             publish(listed, scores, ids)
         return [(ix, r) for (ix, _), r in zip(queries, results)]
-
-
-def _pow2(n: int) -> int:
-    return 1 << max(0, n - 1).bit_length()
 
 
 def engine() -> Engine:

@@ -618,11 +618,11 @@ class ALSAlgorithm(Algorithm):
         return Query(user=model.user_index.inverse[0], num=4)
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
-        # delegate to the batch path with a batch of one: the batched
-        # matmul's rows are invariant to the batch size, so a query gets
-        # byte-identical scores whether it arrives alone or coalesced —
-        # the parity the micro-batcher's correctness rests on (a matvec
-        # here would differ from the batched matmat in the low bits)
+        # delegate to the batch path with a batch of one: a query is
+        # answered by the same programs alone and coalesced — the same
+        # items in the same order, scores equal to the last bits of f32
+        # (a dot's summation order can move with the batch size; a
+        # matvec here would differ more)
         return self.batch_predict(model, [(0, query)])[0][1]
 
     def batch_predict(
@@ -635,14 +635,13 @@ class ALSAlgorithm(Algorithm):
         dequantized f32 vectors — `gather_top_k_batch` dequantizes
         f32/bf16/int8 storage on device.
 
-        Catalogs with at least ``PIO_RETRIEVAL_THRESHOLD`` rows route
-        through two-stage retrieval (ops/retrieval.py): a coarse
-        shortlist over the storage-precision catalog (tiled scan off the
-        mesh, coarse ring pass on it), then exact f32 rescoring of the
-        [B, S] shortlist — O(I) work leaves the exact precision path.
-        Below the threshold nothing changes, bit for bit."""
+        Exact or two-stage (a coarse shortlist over the
+        storage-precision catalog, then exact f32 rescoring of the
+        [B, S] shortlist) is ``ops.retrieval.top_k``'s decision; below
+        ``PIO_RETRIEVAL_THRESHOLD`` rows nothing changes, bit for bit.
+        ``sharded_serving`` runs the same two stages on the mesh (coarse
+        ring pass, host rescore): the one chain outside ``top_k``."""
         from predictionio_tpu.ops import retrieval
-        from predictionio_tpu.ops.topk import gather_top_k_batch
 
         known = [(ix, q) for ix, q in queries if q.user in model.user_index]
         out: list[tuple[int, PredictedResult]] = [
@@ -659,53 +658,28 @@ class ALSAlgorithm(Algorithm):
             # distinct max(num) in a batch (results slice to q.num;
             # lax.top_k's prefix is k-invariant, so the slice equals
             # the smaller-k result exactly)
-            k = max(int(q.num) for _, q in known)
-            k = 1 << max(0, k - 1).bit_length()
+            k = retrieval._pow2(max(int(q.num) for _, q in known))
             num_items = len(model.item_index)
-            kp = (
-                retrieval.shortlist_k(k, num_items)
-                if retrieval.engaged(num_items)
-                else 0
-            )
-            two_stage = bool(kp) and k <= kp < num_items
+            n0 = int(known[0][1].num)  # the recall probe's row
             if self.params.sharded_serving:
-                if two_stage:
-                    _, cand = model.ring_catalog().top_k(
-                        model.user_rows(uixs), kp, coarse=True
-                    )
+                ring, vecs = model.ring_catalog(), model.user_rows(uixs)
+                kp = retrieval.two_stage_k(k, num_items)
+                if kp:
+                    _, cand = ring.top_k(vecs, kp, coarse=True)
                     scores, ids = retrieval.rescore_host(
-                        model.user_rows(uixs), model.item_factors,
-                        model.item_scales, cand, k,
+                        vecs, model.item_factors, model.item_scales, cand, k,
                     )
+                    retrieval.probe(ids[0, :n0], lambda: np.asarray(
+                        ring.top_k(vecs[:1], k)[1]
+                    )[0, :n0])
                 else:
-                    scores, ids = model.ring_catalog().top_k(
-                        model.user_rows(uixs), k
-                    )
-            elif two_stage:
-                U, V = model.device_factors()
-                _, cand = model.coarse_catalog().shortlist(
-                    model.user_rows(uixs), kp
-                )
-                scores, ids = retrieval.rescore_gather_top_k_batch(
-                    uixs, U, V, cand, k=k
-                )
+                    scores, ids = ring.top_k(vecs, k)
+                scores, ids = np.asarray(scores), np.asarray(ids)
             else:
                 U, V = model.device_factors()
-                scores, ids = gather_top_k_batch(uixs, U, V, k=k)
-            scores, ids = np.asarray(scores), np.asarray(ids)
-            if two_stage and retrieval.probe_due():
-                # live recall probe: exact-score the dispatch's first
-                # query and publish overlap with the two-stage row
-                if self.params.sharded_serving:
-                    _, exact_ids = model.ring_catalog().top_k(
-                        model.user_rows(uixs[:1]), k
-                    )
-                else:
-                    U, V = model.device_factors()
-                    _, exact_ids = gather_top_k_batch(uixs[:1], U, V, k=k)
-                n0 = int(known[0][1].num)
-                retrieval.probe_recall(
-                    ids[0, :n0], np.asarray(exact_ids)[0, :n0]
+                scores, ids = retrieval.top_k(
+                    retrieval.UserRows(uixs, U, model.user_rows), V,
+                    num_items, model.coarse_catalog, k, probe_n=n0,
                 )
             inv = model.item_index.inverse
             for row, (ix, q) in enumerate(known):

@@ -14,7 +14,6 @@ Query: ``{"users": [...], "num": N, "whiteList": [...]?,
 
 from __future__ import annotations
 
-import logging
 from dataclasses import dataclass, field
 from typing import Sequence
 
@@ -33,10 +32,13 @@ from predictionio_tpu.core import (
 from predictionio_tpu.data import store
 from predictionio_tpu.data.storage.base import RatingsBatch
 from predictionio_tpu.models.columnar import aggregate_counts
+from predictionio_tpu.models.filters import (
+    CosineCatalog,
+    entity_exclusion_mask,
+    score_similar_batch,
+)
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.ops import als as als_ops
-
-logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -107,50 +109,12 @@ class ALSAlgorithmParams(Params):
 
 
 @dataclass
-class RecommendedUserModel:
+class RecommendedUserModel(CosineCatalog):
     followed_index: BiMap  # followed-user id <-> column index
     followed_factors: np.ndarray  # [F, D] row-normalized at device load
     followed_scales: np.ndarray | None = None  # [F] f32, int8 storage only
 
-    def __post_init__(self):
-        self._device = None
-        self._norms = None
-        self._coarse = None
-
-    def device_factors(self):
-        """Row-normalized catalog on device (dot == cosine); int8
-        storage stays the quantized pair — see
-        models/similarproduct.py's device_factors."""
-        if self._device is None:
-            from predictionio_tpu.models.filters import normalized_device_factors
-
-            self._device, self._norms = normalized_device_factors(
-                self.followed_factors, self.followed_scales
-            )
-        return self._device
-
-    def device_norms(self):
-        """Device-resident [F] stored-row norms, computed once at load
-        (``ops.topk.top_k_similar``'s ``norms`` argument)."""
-        if self._norms is None:
-            self.device_factors()
-        return self._norms
-
-    def coarse_catalog(self):
-        """Tiled coarse copy of the normalized catalog for the
-        two-stage shortlist pass (ops/retrieval.py), cached."""
-        if self._coarse is None:
-            from predictionio_tpu.ops.retrieval import CoarseCatalog
-
-            self._coarse = CoarseCatalog(self.device_factors())
-        return self._coarse
-
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state["_device"] = None
-        state["_norms"] = None
-        state["_coarse"] = None
-        return state
+    _catalog_fields = ("followed_factors", "followed_scales")
 
 
 class ALSAlgorithm(Algorithm):
@@ -192,129 +156,32 @@ class ALSAlgorithm(Algorithm):
         )
 
     def predict(self, model: RecommendedUserModel, query: Query) -> PredictedResult:
-        # batch of one through the batched scorer: byte-identical to the
-        # same query arriving inside a coalesced micro-batch
-        return _score_users_batch(model, [query])[0]
+        # batch of one through the batched scorer: the same programs as
+        # the same query inside a coalesced micro-batch (same users in
+        # the same order, scores to the last bits of f32)
+        return self.batch_predict(model, [(0, query)])[0][1]
 
     def batch_predict(
         self, model: RecommendedUserModel,
         queries: Sequence[tuple[int, Query]],
     ) -> list[tuple[int, PredictedResult]]:
-        results = _score_users_batch(model, [q for _, q in queries])
+        """``filters.score_similar_batch`` over followed users: a
+        ``whiteList`` is the filter that can rule out most of the
+        catalog."""
+        index = model.followed_index
+        results = score_similar_batch(
+            model, index, [q for _, q in queries],
+            entities=lambda q: q.users,
+            dense_mask=lambda q: (
+                entity_exclusion_mask(index, q.users, q.whiteList, q.blackList)
+                if q.whiteList is not None
+                else None
+            ),
+            result=lambda pairs: PredictedResult(
+                userScores=[UserScore(user=u, score=s) for u, s in pairs]
+            ),
+        )
         return [(ix, r) for (ix, _), r in zip(queries, results)]
-
-
-def _pow2(n: int) -> int:
-    return 1 << max(0, n - 1).bit_length()
-
-
-def _score_users_batch(
-    model: RecommendedUserModel, queries: Sequence[Query]
-) -> list[PredictedResult]:
-    """Batched user-user scoring: one fused gather-sum + top-k device
-    call covers every no-whiteList query in the micro-batch (the
-    excluded set — the query's own users plus ``blackList`` hits — is
-    small, so the batch requests top-(num + |excluded|) unmasked and
-    drops exclusions host-side; a whiteList can exclude most of the
-    catalog, so those queries keep per-query masked scoring through the
-    same op). Single-query ``predict`` delegates here with a batch of
-    one — see models/similarproduct.py for the parity argument."""
-    import jax.numpy as jnp
-
-    from predictionio_tpu.models.filters import entity_exclusion_mask
-    from predictionio_tpu.ops import retrieval
-    from predictionio_tpu.ops.topk import sum_rows_top_k_batch
-
-    index = model.followed_index
-    inv = index.inverse
-    results: list[PredictedResult | None] = [None] * len(queries)
-    simple: list[tuple[int, list[int], set[int], int]] = []
-    complex_: list[tuple[int, list[int], np.ndarray, int]] = []
-    for qi, q in enumerate(queries):
-        known = [index[u] for u in q.users if u in index]
-        if not known:
-            logger.info("no query users with factors; returning empty result")
-            results[qi] = PredictedResult(userScores=[])
-            continue
-        if q.whiteList is not None:
-            mask = entity_exclusion_mask(
-                index, q.users, q.whiteList, q.blackList
-            )
-            complex_.append((qi, known, mask, int(q.num)))
-        else:
-            excluded = set(known)
-            if q.blackList is not None:
-                excluded.update(index[u] for u in q.blackList if u in index)
-            simple.append((qi, known, excluded, int(q.num)))
-    V = model.device_factors()
-    num_rows = len(index)
-    if simple:
-        L = _pow2(max(len(known) for _, known, _, _ in simple))
-        ixs = np.zeros((len(simple), L), dtype=np.int32)
-        weights = np.zeros((len(simple), L), dtype=np.float32)
-        for row, (_, known, _, _) in enumerate(simple):
-            ixs[row, : len(known)] = known
-            weights[row, : len(known)] = 1.0
-        k = _pow2(max(num + len(excl) for _, _, excl, num in simple))
-        kp = (
-            retrieval.shortlist_k(k, num_rows)
-            if retrieval.engaged(num_rows)
-            else 0
-        )
-        if kp and k <= kp < num_rows:
-            # two-stage: coarse shortlist, exact rescore of [B, S]
-            # candidates (see models/similarproduct.py)
-            from predictionio_tpu.models.filters import (
-                normalized_query_vectors,
-            )
-
-            qv = normalized_query_vectors(
-                model.followed_factors, model.followed_scales, ixs, weights
-            )
-            _, cand = model.coarse_catalog().shortlist(qv, kp)
-            scores, ids = retrieval.rescore_sum_rows_top_k_batch(
-                ixs, weights, V, cand, k=k
-            )
-            if retrieval.probe_due():
-                _, exact_ids = sum_rows_top_k_batch(
-                    ixs[:1], weights[:1], V, k=k
-                )
-                retrieval.probe_recall(ids[0], np.asarray(exact_ids)[0])
-        else:
-            scores, ids = sum_rows_top_k_batch(ixs, weights, V, k=k)
-        scores, ids = np.asarray(scores), np.asarray(ids)
-        for row, (qi, _, excluded, num) in enumerate(simple):
-            user_scores: list[UserScore] = []
-            for s, i in zip(scores[row], ids[row]):
-                ii = int(i)
-                if ii < 0 or ii in excluded:
-                    continue
-                user_scores.append(UserScore(user=inv[ii], score=float(s)))
-                if len(user_scores) == num:
-                    break
-            results[qi] = PredictedResult(userScores=user_scores)
-    if complex_ and retrieval.engaged(num_rows):
-        # whiteList filters can mask most of the catalog: exact path
-        retrieval.note_exact(len(complex_))
-    for qi, known, mask, num in complex_:
-        L = _pow2(len(known))
-        ixs = np.zeros((1, L), dtype=np.int32)
-        weights = np.zeros((1, L), dtype=np.float32)
-        ixs[0, : len(known)] = known
-        weights[0, : len(known)] = 1.0
-        scores, ids = sum_rows_top_k_batch(
-            ixs, weights, V, k=_pow2(num), exclude_mask=jnp.asarray(mask)
-        )
-        row_s = np.asarray(scores)[0][:num]
-        row_i = np.asarray(ids)[0][:num]
-        results[qi] = PredictedResult(
-            userScores=[
-                UserScore(user=inv[int(i)], score=float(s))
-                for s, i in zip(row_s, row_i)
-                if s > -1e29
-            ]
-        )
-    return results  # type: ignore[return-value]
 
 
 def engine() -> Engine:

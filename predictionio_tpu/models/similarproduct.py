@@ -24,7 +24,6 @@ Query: ``{"items": [...], "num": N, "categories": [...]?,
 
 from __future__ import annotations
 
-import logging
 from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Sequence
@@ -49,9 +48,12 @@ from predictionio_tpu.models.columnar import (
     aggregate_counts,
     from_triples,
 )
+from predictionio_tpu.models.filters import (
+    CosineCatalog,
+    entity_exclusion_mask,
+    score_similar_batch,
+)
 from predictionio_tpu.ops import als as als_ops
-
-logger = logging.getLogger(__name__)
 
 
 @dataclass
@@ -141,52 +143,13 @@ class ALSAlgorithmParams(Params):
 
 
 @dataclass
-class SimilarProductModel:
+class SimilarProductModel(CosineCatalog):
     item_index: BiMap
     item_factors: np.ndarray  # [I, D]; int8 values when item_scales set
     categories: dict[str, list[str]]
     item_scales: np.ndarray | None = None  # [I] f32, int8 storage only
 
-    def __post_init__(self):
-        self._device = None
-        self._norms = None
-        self._coarse = None
-
-    def device_factors(self):
-        """Row-normalized catalog on device (dot == cosine). int8
-        storage stays the quantized (values, 1/||values||) pair — cosine
-        drops the positive per-row scale, so normalization folds into
-        the scale and the device table keeps the 4x size win."""
-        if self._device is None:
-            from predictionio_tpu.models.filters import normalized_device_factors
-
-            self._device, self._norms = normalized_device_factors(
-                self.item_factors, self.item_scales
-            )
-        return self._device
-
-    def device_norms(self):
-        """Device-resident [I] stored-row norms, computed once at load
-        (``ops.topk.top_k_similar``'s ``norms`` argument)."""
-        if self._norms is None:
-            self.device_factors()
-        return self._norms
-
-    def coarse_catalog(self):
-        """Tiled coarse copy of the normalized catalog for the
-        two-stage shortlist pass (ops/retrieval.py), cached."""
-        if self._coarse is None:
-            from predictionio_tpu.ops.retrieval import CoarseCatalog
-
-            self._coarse = CoarseCatalog(self.device_factors())
-        return self._coarse
-
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state["_device"] = None
-        state["_norms"] = None
-        state["_coarse"] = None
-        return state
+    _catalog_fields = ("item_factors", "item_scales")
 
 
 def _exclude_mask(
@@ -194,8 +157,6 @@ def _exclude_mask(
 ) -> np.ndarray:
     """Build the candidate-exclusion mask from query items, category,
     white/black lists (reference ALSAlgorithm.scala:193-244 filters)."""
-    from predictionio_tpu.models.filters import entity_exclusion_mask
-
     mask = entity_exclusion_mask(
         item_index, query.items, query.whiteList, query.blackList
     )
@@ -205,138 +166,6 @@ def _exclude_mask(
             if not wanted.intersection(categories.get(iid, ())):
                 mask[ix] = True
     return mask
-
-
-def _pow2(n: int) -> int:
-    return 1 << max(0, n - 1).bit_length()
-
-
-def _score_similar_batch(
-    model: SimilarProductModel, queries: Sequence[Query]
-) -> list[PredictedResult]:
-    """Score a whole micro-batch of similar-item queries with ONE fused
-    gather-sum + top-k device call for the common case.
-
-    Two filter regimes:
-
-    - SIMPLE (no ``categories``/``whiteList``): the excluded set is
-      small and enumerable host-side (the query's own items plus any
-      ``blackList`` hits), so instead of shipping an [I] mask per query
-      the batch requests top-(num + |excluded|) with NO mask and drops
-      excluded ids from the returned prefix — identical results
-      (masking sinks excluded entries without perturbing the others,
-      and ``lax.top_k`` prefixes are k-invariant), zero mask traffic,
-      one shared device call for every simple query in the batch.
-    - COMPLEX (``categories``/``whiteList`` present): the exclusion can
-      cover most of the catalog, so headroom-k is unbounded — these
-      queries keep masked scoring, one [1, I]-masked call each, through
-      the same fused op.
-
-    Single-query ``predict`` delegates here with a batch of one, so a
-    query's response bytes are identical whether or not it was
-    coalesced (gather-sum rows pad with exactly-zero vectors and matmul
-    rows are batch-size-invariant)."""
-    import jax.numpy as jnp
-
-    from predictionio_tpu.ops import retrieval
-    from predictionio_tpu.ops.topk import sum_rows_top_k_batch
-
-    index = model.item_index
-    inv = index.inverse
-    results: list[PredictedResult | None] = [None] * len(queries)
-    simple: list[tuple[int, list[int], set[int], int]] = []
-    complex_: list[tuple[int, list[int], np.ndarray, int]] = []
-    for qi, q in enumerate(queries):
-        known = [index[i] for i in q.items if i in index]
-        if not known:
-            logger.info("no query items with factors; returning empty result")
-            results[qi] = PredictedResult(itemScores=[])
-            continue
-        if q.categories is not None or q.whiteList is not None:
-            complex_.append(
-                (qi, known,
-                 _exclude_mask(index, model.categories, q), int(q.num))
-            )
-        else:
-            excluded = set(known)
-            if q.blackList is not None:
-                excluded.update(index[i] for i in q.blackList if i in index)
-            simple.append((qi, known, excluded, int(q.num)))
-    V = model.device_factors()  # row-normalized: dot == cosine
-    num_rows = len(index)
-    if simple:
-        # pad the per-query item lists to a shared pow2 width with
-        # weight-0 rows (index 0 gathered, then zeroed — exact), and
-        # size k for the worst headroom in the batch; both pow2 so the
-        # jitted program specializes on a bounded shape set
-        L = _pow2(max(len(known) for _, known, _, _ in simple))
-        ixs = np.zeros((len(simple), L), dtype=np.int32)
-        weights = np.zeros((len(simple), L), dtype=np.float32)
-        for row, (_, known, _, _) in enumerate(simple):
-            ixs[row, : len(known)] = known
-            weights[row, : len(known)] = 1.0
-        k = _pow2(max(num + len(excl) for _, _, excl, num in simple))
-        kp = (
-            retrieval.shortlist_k(k, num_rows)
-            if retrieval.engaged(num_rows)
-            else 0
-        )
-        if kp and k <= kp < num_rows:
-            # two-stage: coarse shortlist over the tiled catalog, exact
-            # rescore of the [B, S] candidates (query vectors rebuilt on
-            # device exactly like the exact op)
-            from predictionio_tpu.models.filters import (
-                normalized_query_vectors,
-            )
-
-            qv = normalized_query_vectors(
-                model.item_factors, model.item_scales, ixs, weights
-            )
-            _, cand = model.coarse_catalog().shortlist(qv, kp)
-            scores, ids = retrieval.rescore_sum_rows_top_k_batch(
-                ixs, weights, V, cand, k=k
-            )
-            if retrieval.probe_due():
-                _, exact_ids = sum_rows_top_k_batch(
-                    ixs[:1], weights[:1], V, k=k
-                )
-                retrieval.probe_recall(ids[0], np.asarray(exact_ids)[0])
-        else:
-            scores, ids = sum_rows_top_k_batch(ixs, weights, V, k=k)
-        scores, ids = np.asarray(scores), np.asarray(ids)
-        for row, (qi, _, excluded, num) in enumerate(simple):
-            item_scores: list[ItemScore] = []
-            for s, i in zip(scores[row], ids[row]):
-                ii = int(i)
-                if ii < 0 or ii in excluded:
-                    continue
-                item_scores.append(ItemScore(item=inv[ii], score=float(s)))
-                if len(item_scores) == num:
-                    break
-            results[qi] = PredictedResult(itemScores=item_scores)
-    if complex_ and retrieval.engaged(num_rows):
-        # category/whiteList filters can mask most of the catalog, so
-        # these stay on the exact masked path even at retrieval scale
-        retrieval.note_exact(len(complex_))
-    for qi, known, mask, num in complex_:
-        L = _pow2(len(known))
-        ixs = np.zeros((1, L), dtype=np.int32)
-        weights = np.zeros((1, L), dtype=np.float32)
-        ixs[0, : len(known)] = known
-        weights[0, : len(known)] = 1.0
-        scores, ids = sum_rows_top_k_batch(
-            ixs, weights, V, k=_pow2(num), exclude_mask=jnp.asarray(mask)
-        )
-        row_s = np.asarray(scores)[0][:num]
-        row_i = np.asarray(ids)[0][:num]
-        results[qi] = PredictedResult(
-            itemScores=[
-                ItemScore(item=inv[int(i)], score=float(s))
-                for s, i in zip(row_s, row_i)
-                if s > -1e29  # drop fully-masked placeholders
-            ]
-        )
-    return results  # type: ignore[return-value]
 
 
 def _view_counts(td: TrainingData) -> IndexedRatings:
@@ -385,15 +214,31 @@ class ALSAlgorithm(Algorithm):
         )
 
     def predict(self, model: SimilarProductModel, query: Query) -> PredictedResult:
-        # batch of one through the batched scorer: byte-identical to the
-        # same query arriving inside a coalesced micro-batch
-        return _score_similar_batch(model, [query])[0]
+        # batch of one through the batched scorer: the same programs as
+        # the same query inside a coalesced micro-batch (same items in
+        # the same order, scores to the last bits of f32)
+        return self.batch_predict(model, [(0, query)])[0][1]
 
     def batch_predict(
         self, model: SimilarProductModel,
         queries: Sequence[tuple[int, Query]],
     ) -> list[tuple[int, PredictedResult]]:
-        results = _score_similar_batch(model, [q for _, q in queries])
+        """``filters.score_similar_batch`` over items: ``categories``
+        and ``whiteList`` are the filters that can rule out most of the
+        catalog (reference ALSAlgorithm.scala:193-244)."""
+        index = model.item_index
+        results = score_similar_batch(
+            model, index, [q for _, q in queries],
+            entities=lambda q: q.items,
+            dense_mask=lambda q: (
+                _exclude_mask(index, model.categories, q)
+                if q.categories is not None or q.whiteList is not None
+                else None
+            ),
+            result=lambda pairs: PredictedResult(
+                itemScores=[ItemScore(item=i, score=s) for i, s in pairs]
+            ),
+        )
         return [(ix, r) for (ix, _), r in zip(queries, results)]
 
 

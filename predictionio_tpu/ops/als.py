@@ -737,38 +737,6 @@ def init_factors(num: int, rank: int, key, scale: float | None = None):
     return scale * jax.random.normal(key, (num, rank), dtype="float32")
 
 
-@obs_device.track_jit("als.solve_bucket_step")
-@functools.partial(jax.jit, static_argnames=("params", "num_solved_rows"))
-def _solve_bucket_step(
-    factors_other, gram, col_ids, ratings, mask, seg_row, params, num_solved_rows
-):
-    return _solve_bucket_inline(
-        factors_other,
-        gram,
-        (col_ids, ratings, mask),
-        params,
-        seg_row=seg_row,
-        num_solved_rows=num_solved_rows,
-    )
-
-
-def _half_step(factors_self, factors_other, buckets, params: ALSParams, gram):
-    """Update factors_self given factors_other over all degree buckets."""
-    for bucket in buckets:
-        x = _solve_bucket_step(
-            factors_other,
-            gram,
-            bucket.col_ids,
-            bucket.ratings,
-            bucket.mask,
-            bucket.seg_row,
-            params,
-            len(bucket.row_ids),
-        )
-        factors_self = _scatter_rows(factors_self, bucket.row_ids, x)
-    return factors_self
-
-
 def _solve_bucket_inline(
     factors_other,
     gram,
@@ -1235,22 +1203,6 @@ def als_train_sweep(
         (cand(U, c, p.rank), cand(V, c, p.rank))
         for c, p in enumerate(params_list)
     ]
-
-
-def als_train_stepwise(data: RatingsData, params: ALSParams):
-    """Step-by-step variant (one jitted call per bucket solve): same math
-    as als_train, useful for debugging / profiling individual solves."""
-    key_u, key_v = jax.random.split(jax.random.PRNGKey(params.seed))
-    U = to_storage(init_factors(data.num_rows, params.rank, key_u), params.storage_dtype)
-    V = to_storage(init_factors(data.num_cols, params.rank, key_v), params.storage_dtype)
-
-    for it in range(params.iterations):
-        gram_v = compute_gram(V, params.compute_dtype) if params.implicit else None
-        U = _half_step(U, V, data.row_buckets, params, gram_v)
-        gram_u = compute_gram(U, params.compute_dtype) if params.implicit else None
-        V = _half_step(V, U, data.col_buckets, params, gram_u)
-        logger.debug("ALS iteration %d/%d done", it + 1, params.iterations)
-    return U, V
 
 
 def predict_pairs(U, V, rows: np.ndarray, cols: np.ndarray):

@@ -35,21 +35,25 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    pow2-bucketed like every serving shape so jit compile count stays
    flat).
 
-Engagement is catalog-size gated: templates route ``batch_predict``
-through this module only when the catalog has at least
-``PIO_RETRIEVAL_THRESHOLD`` rows (default 100_000), so small catalogs
-— including every byte-parity test fixture — stay on the exact path
-bit-for-bit. Knobs (read per call, so tests and operators can flip them
-live):
+One function chains the two stages, ``top_k`` (the end of this file):
+the four ALS templates hand it a query batch in one of three forms
+(``UserRows``, ``Vectors``, ``SumRows`` — each the argument list that an
+exact op of ops/topk.py and its rescore variant share), the resident
+exact table, the catalog's row count, the coarse copy and ``k``, and it
+alone decides exact or two-stage, runs shortlist -> rescore, and every
+Nth two-stage dispatch re-scores row 0 exactly (the live recall probe).
+Engagement is catalog-size gated: a catalog under
+``PIO_RETRIEVAL_THRESHOLD`` rows (default 100_000) — every small test
+fixture — is served by the form's exact op, bit for bit what it was
+before this module existed. The oversampling factor is 8 (recall@num
+>= 0.999 holds with margin); the coarse representation follows the
+table and the platform (int8 catalogs stay int8, ``int8_dot`` on TPU;
+dense catalogs get a bf16 copy). Knobs (read per call, so tests and
+operators can flip them live):
 
 - ``PIO_RETRIEVAL_THRESHOLD``: catalog rows below which serving stays
   exact (default 100000; <= 0 disables two-stage entirely).
-- ``PIO_RETRIEVAL_OVERSAMPLE``: shortlist oversampling factor (default
-  8; recall@num >= 0.999 gate holds with margin at the default).
 - ``PIO_RETRIEVAL_TILE``: coarse tile width (default 2^18 rows).
-- ``PIO_RETRIEVAL_COARSE``: coarse representation — ``auto`` (int8
-  catalogs stay int8, ``int8_dot`` on TPU; dense catalogs get a bf16
-  copy), or force ``int8`` / ``int8_dot`` / ``bf16``.
 - ``PIO_RETRIEVAL_PROBE_EVERY``: every Nth two-stage dispatch re-scores
   one query exactly and publishes recall (default 256; 0 disables).
 
@@ -93,6 +97,7 @@ import functools
 import itertools
 import os
 import threading
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -101,7 +106,14 @@ import numpy as np
 from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.obs import trace as obs_trace
-from predictionio_tpu.ops.topk import Rules, rows_allowed
+from predictionio_tpu.ops.topk import (
+    Rules,
+    gather_top_k_batch,
+    rows_allowed,
+    sum_rows_top_k_batch,
+    top_k_items_batch,
+    top_k_items_batch_masked,
+)
 
 NEG_INF = -1e30
 _LANES = 128  # the minor dimension of a TPU's vector registers and tiles
@@ -109,17 +121,13 @@ _LANES = 128  # the minor dimension of a TPU's vector registers and tiles
 # -- knobs (env-read per call: operators flip them on a live server) --------
 
 _DEFAULT_THRESHOLD = 100_000
-_DEFAULT_OVERSAMPLE = 8.0
+_OVERSAMPLE = 8  # k' over the pow2 headroom-k: a power of two itself
 _DEFAULT_TILE = 1 << 18
 _DEFAULT_PROBE_EVERY = 256
 
 
 def retrieval_threshold() -> int:
     return int(os.environ.get("PIO_RETRIEVAL_THRESHOLD", _DEFAULT_THRESHOLD))
-
-
-def oversample() -> float:
-    return float(os.environ.get("PIO_RETRIEVAL_OVERSAMPLE", _DEFAULT_OVERSAMPLE))
 
 
 def tile_size() -> int:
@@ -144,8 +152,19 @@ def shortlist_k(k: int, num_rows: int) -> int:
     """Shortlist size k' for a headroom-k request against ``num_rows``
     catalog rows: oversample * k, pow2-bucketed (compile-count flat),
     capped at the tile width and the catalog's pow2 envelope."""
-    kp = _pow2(int(np.ceil(oversample() * _pow2(max(1, k)))))
+    kp = _OVERSAMPLE * _pow2(max(1, k))
     return max(1, min(kp, tile_size(), _pow2(num_rows)))
+
+
+def two_stage_k(k: int, num_rows: int) -> int:
+    """The shortlist size k' with which a headroom-k request against
+    ``num_rows`` catalog rows is served in two stages, or 0 where the
+    exact path serves it: a catalog under the threshold, or a k' that
+    would not sit between k and the catalog (nothing to shortlist)."""
+    if not engaged(num_rows):
+        return 0
+    kp = shortlist_k(k, num_rows)
+    return kp if k <= kp < num_rows else 0
 
 
 # -- metrics -----------------------------------------------------------------
@@ -190,40 +209,32 @@ _m_tile_select = {
 _probe_clock = itertools.count(1)
 
 
-def note_exact(n: int = 1) -> None:
-    """Count queries that stayed on the exact path at retrieval scale
-    (complex-filtered queries, shortlist-size fallbacks)."""
-    _m_exact.inc(n)
-
-
-def probe_due() -> bool:
-    """True every ``PIO_RETRIEVAL_PROBE_EVERY``-th two-stage dispatch:
-    the caller should exact-score one query and ``record_probe`` the
-    measured recall."""
-    n = probe_every()
-    return n > 0 and next(_probe_clock) % n == 0
-
-
-def record_probe(recall: float) -> None:
-    _m_probes.inc()
-    _m_probe_recall.set(recall)
-
-
 def probe_recall(two_stage_ids, exact_ids) -> float:
     """Measure + publish id-set recall of a two-stage result row
     against its exact-path counterpart (the live recall probe)."""
     want = {int(i) for i in np.asarray(exact_ids).ravel() if int(i) >= 0}
     got = {int(i) for i in np.asarray(two_stage_ids).ravel() if int(i) >= 0}
     recall = len(got & want) / len(want) if want else 1.0
-    record_probe(recall)
+    _m_probes.inc()
+    _m_probe_recall.set(recall)
     return recall
+
+
+def probe(two_stage_ids, exact_ids: Callable) -> None:
+    """The live recall probe, called once per two-stage dispatch with
+    the served ids of its first query: on every
+    ``PIO_RETRIEVAL_PROBE_EVERY``-th call ``exact_ids()`` scores that
+    query on the exact path and the overlap is published."""
+    n = probe_every()
+    if n > 0 and next(_probe_clock) % n == 0:
+        probe_recall(two_stage_ids, exact_ids())
 
 
 def stats_block() -> dict:
     """Compact ``retrieval`` object for the servers' ``/stats.json``."""
     return {
         "threshold": retrieval_threshold(),
-        "oversample": oversample(),
+        "oversample": _OVERSAMPLE,
         "two_stage_queries": _m_two_stage.value(),
         "exact_queries": _m_exact.value(),
         "shortlist_size": _m_shortlist_size.summary(),
@@ -448,8 +459,6 @@ class CoarseCatalog:
         self.num_rows = int(vals.shape[0])
         self.dim = int(vals.shape[1])
         if mode is None:
-            mode = os.environ.get("PIO_RETRIEVAL_COARSE", "auto")
-        if mode == "auto":
             if quantized:
                 mode = (
                     "int8_dot" if jax.default_backend() == "tpu" else "int8"
@@ -774,4 +783,134 @@ def rescore_host(query_vectors, values, scales, cand_ids, k: int):
         ids = np.take_along_axis(cand_ids, order, axis=1)
         ids[s <= NEG_INF / 2] = -1
     _m_two_stage.inc(len(cand_ids))
+    return s, ids
+
+
+# -- the serving chain ---------------------------------------------------------
+#
+# A query batch reaches ``top_k`` in one of three forms. A form is the
+# argument list that an exact op of ops/topk.py and its rescore variant
+# above share, and knows three things: the f32 vectors the coarse pass
+# scores (``coarse_vectors``), its exact program and its rescore
+# program. Every form leads with its [B, ...] per-query array, carries
+# ``rules`` (None unless the coarse pass applies any) and ``exact_only``,
+# and ``head()`` is its first query alone: what the recall probe scores,
+# in the shapes that query would have arriving alone.
+
+
+class UserRows(NamedTuple):
+    """[B] row indices into a resident user table
+    (``gather_top_k_batch``)."""
+
+    ixs: np.ndarray
+    users: object  # the device-resident user table
+    vectors: Callable  # ixs -> their [B, D] f32 rows, on the host
+    rules = None
+    exact_only = False
+
+    def coarse_vectors(self):
+        return self.vectors(self.ixs)
+
+    def exact(self, table, k: int):
+        return gather_top_k_batch(self.ixs, self.users, table, k=k)
+
+    def rescore(self, table, cand, k: int):
+        return rescore_gather_top_k_batch(
+            self.ixs, self.users, table, cand, k=k
+        )
+
+    def head(self):
+        return self._replace(ixs=self.ixs[:1])
+
+
+class Vectors(NamedTuple):
+    """[B, D] f32 query vectors (``top_k_items_batch``), under
+    ``device_rules`` where given (``top_k_items_batch_masked``): the
+    caller has padded the batch to the power of two the rules hold."""
+
+    vectors: np.ndarray
+    rules: Rules | None = None
+    exact_only = False
+
+    def coarse_vectors(self):
+        return self.vectors
+
+    def exact(self, table, k: int):
+        if self.rules is None:
+            return top_k_items_batch(self.vectors, table, k=k)
+        return top_k_items_batch_masked(self.vectors, table, self.rules, k=k)
+
+    def rescore(self, table, cand, k: int):
+        return rescore_top_k_batch(self.vectors, table, cand, k, self.rules)
+
+    def head(self):
+        r = self.rules
+        return Vectors(self.vectors[:1], r and r._replace(
+            qcat=r.qcat[:1], has_cat=r.has_cat[:1], ex=r.ex[:1]
+        ))
+
+
+class SumRows(NamedTuple):
+    """Weighted sums of catalog rows, [B, L] indices and weights
+    (``sum_rows_top_k_batch``). A dense ``exclude_mask`` can rule out
+    most of the catalog, so no shortlist is sized for it: such a query
+    is scored exactly whatever the catalog's size."""
+
+    ixs: np.ndarray
+    weights: np.ndarray
+    vectors: Callable  # (ixs, weights) -> the [B, D] f32 sums, on the host
+    exclude_mask: object = None
+    rules = None
+
+    @property
+    def exact_only(self) -> bool:
+        return self.exclude_mask is not None
+
+    def coarse_vectors(self):
+        return self.vectors(self.ixs, self.weights)
+
+    def exact(self, table, k: int):
+        if self.exclude_mask is None:
+            return sum_rows_top_k_batch(self.ixs, self.weights, table, k=k)
+        return sum_rows_top_k_batch(
+            self.ixs, self.weights, table, k=k,
+            exclude_mask=self.exclude_mask,
+        )
+
+    def rescore(self, table, cand, k: int):
+        return rescore_sum_rows_top_k_batch(
+            self.ixs, self.weights, table, cand, k=k
+        )
+
+    def head(self):
+        return self._replace(ixs=self.ixs[:1], weights=self.weights[:1])
+
+
+def top_k(query, table, num_rows: int, coarse, k: int,
+          probe_n: int | None = None):
+    """Host ([B, k] scores, [B, k] ids, -1 where a query has fewer
+    answers) for ``query`` (a form above) against the resident exact
+    ``table`` of a catalog of ``num_rows`` rows. The one place that
+    decides how: the form's exact program, or — where ``two_stage_k``
+    says so — a shortlist from ``coarse`` (the catalog's
+    ``CoarseCatalog``, or a callable that returns it, called only then;
+    a caller that asked ``two_stage_k`` itself and got 0 has none to
+    give) rescored by the form's rescore program, and on every
+    ``PIO_RETRIEVAL_PROBE_EVERY``-th such dispatch the exact program
+    again on the first query, whose leading ``probe_n`` ids (the ones
+    its answer is cut from; all k by default) are compared."""
+    kp = 0 if query.exact_only else two_stage_k(k, num_rows)
+    if not kp:
+        if query.exact_only and engaged(num_rows):
+            _m_exact.inc(len(query[0]))  # kept from the shortlist by a filter
+        s, ids = query.exact(table, k)
+        return np.asarray(s), np.asarray(ids)
+    if callable(coarse):
+        coarse = coarse()
+    _, cand = coarse.shortlist(query.coarse_vectors(), kp, query.rules)
+    s, ids = query.rescore(table, cand, k)
+    probe(
+        ids[0, :probe_n],
+        lambda: np.asarray(query.head().exact(table, k)[1])[0, :probe_n],
+    )
     return s, ids

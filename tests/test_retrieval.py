@@ -91,7 +91,6 @@ class TestShortlistRecall:
             assert len(set(vr.tolist())) == vr.size  # no duplicates
 
     def test_shortlist_k_bucketing(self, monkeypatch):
-        monkeypatch.setenv("PIO_RETRIEVAL_OVERSAMPLE", "8")
         monkeypatch.setenv("PIO_RETRIEVAL_TILE", str(1 << 18))
         # pow2(8 * pow2(k)); capped by the catalog's pow2 envelope
         assert retrieval.shortlist_k(5, 1 << 20) == 64
@@ -900,3 +899,205 @@ class TestTwoLevelShortlist:
             assert scraped[
                 f'pio_retrieval_tile_select_total{{path="{path}"}}'
             ] == n
+
+
+# -- the chain: one owner of "exact or two-stage, shortlist -> rescore, probe" --
+
+
+class TestServingChain:
+    """``retrieval.top_k`` returns what the explicit sequence returns —
+    the form's exact op below the threshold, ``shortlist`` then the
+    form's ``rescore_*`` above it — and probes row 0 alone on every
+    ``PIO_RETRIEVAL_PROBE_EVERY``-th two-stage dispatch, never below."""
+
+    I, D, B, K = 500, 8, 4, 8  # 500 rows in 4 tiles of 128: 12 rows of padding
+
+    def _form(self, form, table, host, stored):
+        """(the query form, its exact call, its rescore call given the
+        candidates) over ``table``; ``stored`` sizes the rules."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import topk
+
+        if form == "user_rows":
+            users = _dense(32, self.D, seed=42)
+            ixs, U = np.asarray([3, 7, 1, 30], np.int32), jnp.asarray(users)
+            return (
+                retrieval.UserRows(ixs, U, lambda i: users[i]),
+                lambda: gather_top_k_batch(ixs, U, table, k=self.K),
+                lambda cand: retrieval.rescore_gather_top_k_batch(
+                    ixs, U, table, cand, k=self.K),
+            )
+        if form == "sum_rows":
+            ixs = np.asarray([[5, 9], [2, 0], [7, 7], [40, 41]], np.int32)
+            weights = np.asarray([[1, 1], [1, 0], [1, 1], [1, 1]], np.float32)
+            return (
+                retrieval.SumRows(
+                    ixs, weights,
+                    lambda i, w: (host[i] * w[..., None]).sum(axis=1),
+                ),
+                lambda: sum_rows_top_k_batch(ixs, weights, table, k=self.K),
+                lambda cand: retrieval.rescore_sum_rows_top_k_batch(
+                    ixs, weights, table, cand, k=self.K),
+            )
+        v = _dense(self.B, self.D, seed=43)
+        if form == "vectors":
+            return (
+                retrieval.Vectors(v),
+                lambda: topk.top_k_items_batch(v, table, k=self.K),
+                lambda cand: retrieval.rescore_top_k_batch(
+                    v, table, cand, self.K),
+            )
+        ex = np.full((self.B, 4), -1, np.int32)
+        ex[2, :3] = np.argsort(-(v[2] @ host.T))[:3]  # the query's own best
+        rules = _rules(
+            stored, self.B, small_cat=range(0, self.I, 5), ex=ex,
+            qcat=np.asarray([[-2], [1], [-2], [0]], np.int32),
+        )
+        return (
+            retrieval.Vectors(v, rules),
+            lambda: topk.top_k_items_batch_masked(v, table, rules, k=self.K),
+            lambda cand: retrieval.rescore_top_k_batch(
+                v, table, cand, self.K, rules),
+        )
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+    @pytest.mark.parametrize(
+        "two_stage", [False, True], ids=["below_threshold", "two_stage"]
+    )
+    @pytest.mark.parametrize(
+        "form", ["user_rows", "vectors", "vectors_rules", "sum_rows"]
+    )
+    def test_chain_is_the_explicit_sequence(
+        self, monkeypatch, form, two_stage, int8
+    ):
+        import itertools
+
+        import jax.numpy as jnp
+
+        monkeypatch.setenv(
+            "PIO_RETRIEVAL_THRESHOLD", "64" if two_stage else "100000"
+        )
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "128")
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "3")
+        monkeypatch.setattr(retrieval, "_probe_clock", itertools.count(1))
+        if int8:
+            vals, scales = _int8(self.I, self.D, seed=41)
+            table = (jnp.asarray(vals), jnp.asarray(scales))
+            host = vals.astype(np.float32) * scales[:, None]
+        else:
+            host = _dense(self.I, self.D, seed=41)
+            table = jnp.asarray(host)
+        coarse = CoarseCatalog(table)
+        assert coarse.stored_rows == 512
+        kp = retrieval.two_stage_k(self.K, self.I)
+        assert kp == (64 if two_stage else 0)
+        query, exact, rescore = self._form(
+            form, table, host, coarse.stored_rows if two_stage else self.I
+        )
+        if two_stage:
+            _, cand = coarse.shortlist(query.coarse_vectors(), kp, query.rules)
+            want = rescore(cand)
+        else:
+            want = exact()
+        want_s, want_ids = np.asarray(want[0]), np.asarray(want[1])
+        assert (want_ids[:, 0] >= 0).all()
+
+        lookups, shortlists, exact_rows, probed = [], [], [], []
+        real_shortlist, real_exact = CoarseCatalog.shortlist, type(query).exact
+        monkeypatch.setattr(
+            CoarseCatalog, "shortlist",
+            lambda self, q, k, rules=None: shortlists.append(k)
+            or real_shortlist(self, q, k, rules),
+        )
+        monkeypatch.setattr(
+            type(query), "exact",
+            lambda self, table, k: exact_rows.append(len(self[0]))
+            or real_exact(self, table, k),
+        )
+        real_recall = retrieval.probe_recall
+        monkeypatch.setattr(
+            retrieval, "probe_recall",
+            lambda got, want: probed.append((len(got), len(want)))
+            or real_recall(got, want),
+        )
+        before = retrieval.stats_block()
+        for _ in range(6):
+            s, ids = retrieval.top_k(
+                query, table, self.I, lambda: lookups.append(1) or coarse,
+                self.K, probe_n=5,
+            )
+            assert isinstance(s, np.ndarray) and isinstance(ids, np.ndarray)
+            np.testing.assert_array_equal(ids, want_ids)
+            np.testing.assert_array_equal(s, want_s)
+        after = retrieval.stats_block()
+        assert after["exact_queries"] == before["exact_queries"]
+        if two_stage:
+            # every dispatch shortlists k' and rescores; the 3rd and the
+            # 6th also run the exact program, on the first query alone
+            assert shortlists == [kp] * 6 and len(lookups) == 6
+            assert exact_rows == [1, 1] and probed == [(5, 5)] * 2
+            assert after["probes"] == before["probes"] + 2
+            assert after["probe_recall"] == 1.0
+            assert after["two_stage_queries"] == (
+                before["two_stage_queries"] + 6 * self.B
+            )
+        else:
+            assert not shortlists and not lookups and not probed
+            assert exact_rows == [self.B] * 6
+            assert after["probes"] == before["probes"]
+            assert after["two_stage_queries"] == before["two_stage_queries"]
+
+    @pytest.mark.parametrize(
+        "engaged", [False, True], ids=["below_threshold", "engaged"]
+    )
+    def test_a_dense_mask_is_scored_exactly_and_counted(
+        self, monkeypatch, engaged
+    ):
+        """A query whose filter can rule out most of the catalog never
+        shortlists; at retrieval scale it counts as ``path="exact"``."""
+        import jax.numpy as jnp
+
+        monkeypatch.setenv(
+            "PIO_RETRIEVAL_THRESHOLD", "64" if engaged else "100000"
+        )
+        host = _dense(self.I, self.D, seed=44)
+        table = jnp.asarray(host)
+        mask = np.ones(self.I, bool)
+        mask[[4, 17, 300]] = False
+        ixs, weights = np.asarray([[5]], np.int32), np.ones((1, 1), np.float32)
+        before = retrieval.stats_block()
+        s, ids = retrieval.top_k(
+            retrieval.SumRows(ixs, weights, None, jnp.asarray(mask)), table,
+            self.I, lambda: pytest.fail("no coarse copy is needed"), self.K,
+        )
+        after = retrieval.stats_block()
+        es, ei = sum_rows_top_k_batch(
+            ixs, weights, table, k=self.K, exclude_mask=jnp.asarray(mask)
+        )
+        np.testing.assert_array_equal(ids, np.asarray(ei))
+        np.testing.assert_array_equal(s, np.asarray(es))
+        assert set(ids[0][s[0] > -1e29].tolist()) == {4, 17, 300}
+        assert after["exact_queries"] == before["exact_queries"] + int(engaged)
+        assert after["two_stage_queries"] == before["two_stage_queries"]
+
+
+def test_the_templates_leave_the_decision_to_the_chain():
+    """No module under models/ decides exact-or-two-stage, sizes a
+    shortlist or runs the probe's clock itself (``retrieval.top_k`` and,
+    for the mesh branch, ``two_stage_k`` / ``probe`` own that)."""
+    import pathlib
+    import re
+
+    import predictionio_tpu.models as models
+
+    owned = re.compile(
+        r"\b(shortlist_k|engaged|probe_due|probe_recall|note_exact)\b"
+    )
+    for path in sorted(pathlib.Path(models.__file__).parent.glob("*.py")):
+        hits = [
+            f"{path.name}:{n}: {line.strip()}"
+            for n, line in enumerate(path.read_text().splitlines(), 1)
+            if owned.search(line)
+        ]
+        assert not hits, hits

@@ -1,12 +1,17 @@
 """Batched-vs-unbatched serving parity.
 
-The device-batched predict path must be invisible to clients: the bytes
-on the wire for ``/queries.json`` are identical whether a query is
-served alone or coalesced into an [N, K] device batch — across every
-factor storage dtype, with mixed query shapes sharing one batch — and
-business-rule filters (blackList, seen items) apply per query INSIDE a
-batch. A batchmate whose batch dispatch fails is retried individually
-without poisoning its neighbors.
+The device-batched predict path must be invisible to clients: a query's
+answer on ``/queries.json`` holds the same items in the same order, with
+scores equal to the last bits of f32, whether it is served alone or
+coalesced into an [N, K] device batch — across every factor storage
+dtype, with mixed query shapes sharing one batch — and business-rule
+filters (blackList, seen items) apply per query INSIDE a batch. Byte for
+byte is not a property of the dot on either backend (its summation order
+can move with the batch size: 0.6818156838 alone, 0.6818156242 in a
+batch; PERF.md section 6, PR 26), so scores are held to 2e-6 as
+tests/test_ecommerce_rules.py holds the storefront's. A batchmate whose
+batch dispatch fails is retried individually — a batch of one again, and
+that IS byte-identical — without poisoning its neighbors.
 """
 
 from __future__ import annotations
@@ -105,6 +110,20 @@ def _expected_bytes(engine, inst, storage) -> dict[str, tuple[int, bytes]]:
         server.stop()
 
 
+def _assert_same_answer(got: bytes, want: bytes, what) -> None:
+    """Two response bodies: the same JSON but for the scores' last bits."""
+    got, want = json.loads(got), json.loads(want)
+    assert set(got) == set(want), what
+    assert [s["item"] for s in got["itemScores"]] == [
+        s["item"] for s in want["itemScores"]
+    ], what
+    np.testing.assert_allclose(
+        [s["score"] for s in got["itemScores"]],
+        [s["score"] for s in want["itemScores"]],
+        rtol=2e-6, atol=2e-6, err_msg=str(what),
+    )
+
+
 def _batched_server(engine, inst, storage):
     from predictionio_tpu.server.engine_server import EngineServer
 
@@ -136,9 +155,10 @@ def _concurrent_post(port, queries) -> dict[str, tuple[int, bytes]]:
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
-def test_byte_identical_responses(storage, dtype):
-    """Same wire bytes batched and unbatched, per storage dtype, with
-    mixed query shapes coalesced into one device batch."""
+def test_batched_answers_match_unbatched(storage, dtype):
+    """The same answer batched and unbatched (items and order equal,
+    scores to 2e-6), per storage dtype, with mixed query shapes
+    coalesced into one device batch."""
     engine, inst = _train_rec(storage, storage_dtype=dtype)
     expected = _expected_bytes(engine, inst, storage)
 
@@ -158,8 +178,8 @@ def test_byte_identical_responses(storage, dtype):
             key = json.dumps(q)
             status, body = results[key]
             assert status == 200, (q, body)
-            assert body == expected[key][1], (
-                f"batched bytes diverge for {q}"
+            _assert_same_answer(
+                body, expected[key][1], f"batched answer diverges for {q}"
             )
         coalesced = [b for b in batches if len(b) > 1]
         assert coalesced, f"no coalesced batch formed: {batches}"
@@ -218,8 +238,8 @@ def _interaction(name, user, item):
 class TestPerQueryFiltersInBatch:
     """Business rules are per-query even when queries share a device
     dispatch: blackList hits and seen items vanish from exactly the
-    queries that asked, and a filtered query byte-matches its own
-    unbatched result."""
+    queries that asked, and a filtered query matches its own unbatched
+    result (items and order; scores to 2e-6)."""
 
     def _similar_model(self, storage):
         from predictionio_tpu.data.storage import App
@@ -259,16 +279,18 @@ class TestPerQueryFiltersInBatch:
         assert "i2" not in black_items and "i4" not in black_items
         assert all(int(s.item[1:]) % 2 == 1 for s in got[2].itemScores)
         # the un-filtered batchmate is untouched by its neighbors'
-        # filters — identical to its own solo prediction, scores and all
-        solo = algo.predict(model, q_plain)
-        assert [(s.item, s.score) for s in got[1].itemScores] == [
-            (s.item, s.score) for s in solo.itemScores
-        ]
-        # and the filtered one matches ITS solo prediction too
-        solo_black = algo.predict(model, q_black)
-        assert [(s.item, s.score) for s in got[0].itemScores] == [
-            (s.item, s.score) for s in solo_black.itemScores
-        ]
+        # filters (its solo prediction's items in its order, the scores
+        # to f32's last bits), and the filtered ones match THEIR solo
+        # predictions too
+        for row, q in ((1, q_plain), (0, q_black), (2, q_cat)):
+            solo = algo.predict(model, q)
+            assert [s.item for s in got[row].itemScores] == [
+                s.item for s in solo.itemScores
+            ], q
+            np.testing.assert_allclose(
+                [s.score for s in got[row].itemScores],
+                [s.score for s in solo.itemScores], rtol=2e-6, atol=2e-6,
+            )
 
     def test_seen_items_filtered_per_user_in_batch(self, storage):
         from predictionio_tpu.data.storage import App

@@ -278,7 +278,10 @@ def _burst(port, users, prefix):
 class TestServingChain:
     def test_one_request_yields_every_span_once(self, served):
         _, port = served
-        _query(port, "u0")  # compiles; its trace is not the one read
+        # compiles; its trace is not the one read, but its http.write is
+        # recorded after the response left: wait for it, or it counts below
+        _query(port, "u0", "feedc0de00000000")
+        _retained("feedc0de00000000")
         obs_trace.TRACES.clear()
         before = {n: _hist_count(n, lab) for n, lab in NEW_HISTOGRAMS}
         rows = (_counter("pio_batch_rows_total", kind="real"),
