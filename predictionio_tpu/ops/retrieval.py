@@ -85,8 +85,12 @@ rescore program publishes the temporary bytes its compiled form needs
 re-layout came back); the
 two stages record themselves as ``dispatch.shortlist`` /
 ``dispatch.rescore`` regions (``obs.trace.region``: a span on the
-current trace — every batchmate's, under the batch worker — from the
-device call through its ``np.asarray``), and the two serving programs
+current trace — every batchmate's, under the batch worker — around a
+stage's conversions, uploads and launch: what it costs to enqueue),
+the one blocking read that ends the chain as ``dispatch.fetch``
+(``pio_retrieval_fetch_seconds``: the device time of both programs and
+the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
+one a dispatch through ``top_k``), and the two serving programs
 carry ``jax.named_scope`` s (``retrieval.shortlist.*``,
 ``retrieval.rescore.*``) that name their ops in a trace viewer.
 """
@@ -189,6 +193,15 @@ _m_shortlist_secs = obs_metrics.histogram(
 _m_rescore_secs = obs_metrics.histogram(
     "pio_retrieval_rescore_seconds", "exact rescore pass wall time",
 )
+_m_fetch_secs = obs_metrics.histogram(
+    "pio_retrieval_fetch_seconds",
+    "the two-stage chain's blocking read of its result: what is left of "
+    "both device programs, and the copy to the host",
+)
+_m_host_reads = obs_metrics.counter(
+    "pio_retrieval_host_reads_total",
+    "blocking device-to-host reads made by two-stage retrieval",
+)
 _m_probe_recall = obs_metrics.gauge(
     "pio_retrieval_probe_recall",
     "recall@num of the most recent exact-rescored probe query",
@@ -240,6 +253,8 @@ def stats_block() -> dict:
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
         "rescore_seconds": _m_rescore_secs.summary(),
+        "fetch_seconds": _m_fetch_secs.summary(),
+        "host_reads": _m_host_reads.value(),
         "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
         "rescore_temp_bytes": {
             p.name: p.temp_bytes() for p in _RESCORE_PROGRAMS
@@ -436,6 +451,38 @@ def device_rules(rules: Rules) -> Rules:
     )
 
 
+def _up(a, dtype, rows: int = 0):
+    """``a`` as a device array: one that is there already as it lies
+    (the chain's arrays go up once, for both stages), a host array
+    converted to ``dtype``, padded to ``rows`` rows with copies of row 0
+    (discarded after the read) and uploaded."""
+    if isinstance(a, jax.Array):
+        return a
+    a = np.ascontiguousarray(a, dtype=dtype)
+    if len(a) < rows:
+        a = np.concatenate([a, np.repeat(a[:1], rows - len(a), axis=0)])
+    return jnp.asarray(a)
+
+
+def _fetch(out, n: int):
+    """The read that ends a chain of launches: device ``(scores, ids)``
+    -> their first ``n`` rows on the host, in one ``device_get``, as a
+    ``dispatch.fetch`` region. The host waits here, and only here, for
+    whatever the programs behind ``out`` still have to do."""
+    with obs_trace.region("dispatch.fetch", hist=_m_fetch_secs):
+        s, ids = jax.device_get(out)
+    _m_host_reads.inc()
+    return s[:n], ids[:n]
+
+
+class Scan(NamedTuple):
+    """What ``CoarseCatalog.launch`` left on the device."""
+
+    queries: jax.Array  # [bp, D] f32, as uploaded
+    scores: jax.Array  # [bp, k'] coarse scores
+    ids: jax.Array  # [bp, k'] int32 candidate ids, -1 past the catalog
+
+
 class CoarseCatalog:
     """A catalog staged in tiled coarse form for the shortlist pass.
 
@@ -518,43 +565,46 @@ class CoarseCatalog:
         ``Rules`` vector that this catalog's scan can slice."""
         return int(self._ids.size)
 
-    def shortlist(self, queries, k: int, rules: Rules | None = None):
-        """Coarse top-k' candidate ids for a [B, D] f32 query batch ->
-        ([B, k'] coarse scores, [B, k'] int32 ids, -1 past the catalog).
-        B pads to a pow2 bucket (copies of row 0, discarded) and k'
-        clamps to the tile width, so arbitrary traffic reuses a bounded
-        set of compiled programs. Under ``rules`` (``device_rules``:
-        vectors of ``stored_rows``, per-query rows for a batch the
-        caller has padded to a power of two) only rows the query may be
-        served are candidates; a query with fewer than k' of them gets
-        id -1 in the rest."""
-        q = np.ascontiguousarray(np.asarray(queries, dtype=np.float32))
-        B = q.shape[0]
+    def launch(self, queries, k: int, rules: Rules | None = None):
+        """The coarse scan enqueued, nothing read: -> ``Scan``, device
+        arrays of bp rows, bp the power of two at or above the B queries
+        given (``shortlist`` below has the contract). ``top_k`` hands
+        the ids to a rescore program as they are."""
         k = max(1, min(int(k), self.tile))
-        bp = _pow2(max(1, B))
-        if bp > B:
-            q = np.concatenate([q, np.repeat(q[:1], bp - B, axis=0)])
         with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
+            q = _up(queries, np.float32, _pow2(len(queries)))
             if rules is None:
                 s, ids = _coarse_topk(
-                    jnp.asarray(q), self._tiles, self._scales, self._ids, k,
-                    self.mode,
+                    q, self._tiles, self._scales, self._ids, k, self.mode,
                 )
             else:
-                if len(rules.ex) != bp:
+                if len(rules.ex) != len(q):
                     raise ValueError(
-                        f"rules for {len(rules.ex)} queries, batch of {bp}"
+                        f"rules for {len(rules.ex)} queries, "
+                        f"batch of {len(q)}"
                     )
                 s, ids = _coarse_topk_masked(
-                    jnp.asarray(q), self._tiles, self._scales, self._ids,
-                    rules, k, self.mode,
+                    q, self._tiles, self._scales, self._ids, rules, k,
+                    self.mode,
                 )
-            s, ids = np.asarray(s)[:B], np.asarray(ids)[:B]
         _m_shortlist_size.observe(float(k))
         _m_tile_select[
             "two_level" if tile_select_group(self.tile, k) else "plain"
         ].inc()
-        return s, ids
+        return Scan(q, s, ids)
+
+    def shortlist(self, queries, k: int, rules: Rules | None = None):
+        """Coarse top-k' candidate ids for a [B, D] f32 query batch ->
+        host ([B, k'] coarse scores, [B, k'] int32 ids, -1 past the
+        catalog). B pads to a pow2 bucket (copies of row 0, discarded)
+        and k' clamps to the tile width, so arbitrary traffic reuses a
+        bounded set of compiled programs. Under ``rules``
+        (``device_rules``: vectors of ``stored_rows``, per-query rows
+        for a batch the caller has padded to a power of two) only rows
+        the query may be served are candidates; a query with fewer than
+        k' of them gets id -1 in the rest."""
+        scan = self.launch(queries, k, rules)
+        return _fetch((scan.scores, scan.ids), len(queries))
 
 
 # -- exact rescore kernels ---------------------------------------------------
@@ -709,15 +759,49 @@ def _rescore_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
     return _score_candidates(qvecs, item_factors, cand_ids, k)
 
 
-def _rescore(call, n_queries: int):
-    """Run one exact-rescore stage: ``call()`` (input conversion + the
-    device program) through the results' ``np.asarray``, as one
-    ``dispatch.rescore`` region."""
-    with obs_trace.region("dispatch.rescore", hist=_m_rescore_secs):
-        out = call()
-        s, ids = np.asarray(out[0]), np.asarray(out[1])
+# Each rescore entry point comes twice: ``_launch_*`` converts and uploads
+# what is not on the device yet (per-query arrays pad to the candidates'
+# rows) and enqueues the program, as one ``dispatch.rescore`` region that
+# reads nothing; the host-facing ``rescore_*_top_k_batch`` is that plus
+# the read, for callers whose candidate lists are built on the host.
+# ``top_k`` calls the launchers with the scan's device ids.
+
+_rescore_stage = functools.partial(
+    obs_trace.region, "dispatch.rescore", hist=_m_rescore_secs
+)
+
+
+def _read_rescore(out, n_queries: int):
     _m_two_stage.inc(n_queries)
-    return s, ids
+    return _fetch(out, n_queries)
+
+
+def _launch_gather(user_ixs, user_factors, item_factors, cand_ids, k: int):
+    with _rescore_stage():
+        cand = _up(cand_ids, np.int32)
+        return _rescore_gather(
+            _up(user_ixs, np.int32, len(cand)), user_factors, item_factors,
+            cand, k=k,
+        )
+
+
+def _launch_vectors(user_vectors, item_factors, cand_ids, k: int,
+                    rules: Rules | None = None):
+    with _rescore_stage():
+        cand = _up(cand_ids, np.int32)
+        vecs = _up(user_vectors, np.float32, len(cand))
+        if rules is None:
+            return _rescore_vectors(vecs, item_factors, cand, k=k)
+        return _rescore_vectors_masked(vecs, item_factors, cand, rules, k=k)
+
+
+def _launch_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
+    with _rescore_stage():
+        cand = _up(cand_ids, np.int32)
+        return _rescore_sum_rows(
+            _up(row_ixs, np.int32, len(cand)),
+            _up(row_weights, np.float32, len(cand)), item_factors, cand, k=k,
+        )
 
 
 def rescore_gather_top_k_batch(user_ixs, user_factors, item_factors,
@@ -727,9 +811,8 @@ def rescore_gather_top_k_batch(user_ixs, user_factors, item_factors,
     instead of scoring [B, I]. The query vectors are gathered and
     dequantized exactly like the exact path's, so the returned ranking
     equals the exact ranking restricted to the candidates."""
-    return _rescore(lambda: _rescore_gather(
-        jnp.asarray(np.asarray(user_ixs, np.int32)), user_factors,
-        item_factors, jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
+    return _read_rescore(_launch_gather(
+        user_ixs, user_factors, item_factors, cand_ids, k
     ), len(cand_ids))
 
 
@@ -738,14 +821,8 @@ def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int,
     """Shortlist-gather variant of ``top_k_items_batch``: [B, D] query
     vectors against a [B, S] candidate-id matrix, under ``rules`` where
     given (``device_rules``, their per-query rows for these B queries)."""
-    if rules is None:
-        return _rescore(lambda: _rescore_vectors(
-            jnp.asarray(np.asarray(user_vectors, np.float32)), item_factors,
-            jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
-        ), len(cand_ids))
-    return _rescore(lambda: _rescore_vectors_masked(
-        jnp.asarray(np.asarray(user_vectors, np.float32)), item_factors,
-        jnp.asarray(np.asarray(cand_ids, np.int32)), rules, k=k,
+    return _read_rescore(_launch_vectors(
+        user_vectors, item_factors, cand_ids, k, rules
     ), len(cand_ids))
 
 
@@ -755,10 +832,8 @@ def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
     cosine-family templates: the query vector is the weighted sum of
     gathered catalog rows (built on device exactly like the exact op),
     scored against the [B, S] candidates only."""
-    return _rescore(lambda: _rescore_sum_rows(
-        jnp.asarray(np.asarray(row_ixs, np.int32)),
-        jnp.asarray(np.asarray(row_weights, np.float32)),
-        item_factors, jnp.asarray(np.asarray(cand_ids, np.int32)), k=k,
+    return _read_rescore(_launch_sum_rows(
+        row_ixs, row_weights, item_factors, cand_ids, k
     ), len(cand_ids))
 
 
@@ -792,10 +867,12 @@ def rescore_host(query_vectors, values, scales, cand_ids, k: int):
 # argument list that an exact op of ops/topk.py and its rescore variant
 # above share, and knows three things: the f32 vectors the coarse pass
 # scores (``coarse_vectors``), its exact program and its rescore
-# program. Every form leads with its [B, ...] per-query array, carries
-# ``rules`` (None unless the coarse pass applies any) and ``exact_only``,
-# and ``head()`` is its first query alone: what the recall probe scores,
-# in the shapes that query would have arriving alone.
+# program (``rescore``: enqueued behind the scan on the scan's device
+# ids, at their power-of-two rows). Every form leads with its [B, ...]
+# per-query array, carries ``rules`` (None unless the coarse pass
+# applies any) and ``exact_only``, and ``head()`` is its first query
+# alone: what the recall probe scores, in the shapes that query would
+# have arriving alone.
 
 
 class UserRows(NamedTuple):
@@ -815,9 +892,7 @@ class UserRows(NamedTuple):
         return gather_top_k_batch(self.ixs, self.users, table, k=k)
 
     def rescore(self, table, cand, k: int):
-        return rescore_gather_top_k_batch(
-            self.ixs, self.users, table, cand, k=k
-        )
+        return _launch_gather(self.ixs, self.users, table, cand, k)
 
     def head(self):
         return self._replace(ixs=self.ixs[:1])
@@ -841,7 +916,7 @@ class Vectors(NamedTuple):
         return top_k_items_batch_masked(self.vectors, table, self.rules, k=k)
 
     def rescore(self, table, cand, k: int):
-        return rescore_top_k_batch(self.vectors, table, cand, k, self.rules)
+        return _launch_vectors(self.vectors, table, cand, k, self.rules)
 
     def head(self):
         r = self.rules
@@ -878,9 +953,7 @@ class SumRows(NamedTuple):
         )
 
     def rescore(self, table, cand, k: int):
-        return rescore_sum_rows_top_k_batch(
-            self.ixs, self.weights, table, cand, k=k
-        )
+        return _launch_sum_rows(self.ixs, self.weights, table, cand, k)
 
     def head(self):
         return self._replace(ixs=self.ixs[:1], weights=self.weights[:1])
@@ -895,7 +968,9 @@ def top_k(query, table, num_rows: int, coarse, k: int,
     says so — a shortlist from ``coarse`` (the catalog's
     ``CoarseCatalog``, or a callable that returns it, called only then;
     a caller that asked ``two_stage_k`` itself and got 0 has none to
-    give) rescored by the form's rescore program, and on every
+    give) rescored by the form's rescore program — the scan's ids stay
+    on the device, the rescore is enqueued behind the scan, and one read
+    brings the answer back — and on every
     ``PIO_RETRIEVAL_PROBE_EVERY``-th such dispatch the exact program
     again on the first query, whose leading ``probe_n`` ids (the ones
     its answer is cut from; all k by default) are compared."""
@@ -907,8 +982,13 @@ def top_k(query, table, num_rows: int, coarse, k: int,
         return np.asarray(s), np.asarray(ids)
     if callable(coarse):
         coarse = coarse()
-    _, cand = coarse.shortlist(query.coarse_vectors(), kp, query.rules)
-    s, ids = query.rescore(table, cand, k)
+    scan = coarse.launch(query.coarse_vectors(), kp, query.rules)
+    chained = query
+    if isinstance(query, Vectors):  # the scan's queries are its own: up once
+        chained = query._replace(vectors=scan.queries)
+    s, ids = _read_rescore(
+        chained.rescore(table, scan.ids, k), len(query[0])
+    )
     probe(
         ids[0, :probe_n],
         lambda: np.asarray(query.head().exact(table, k)[1])[0, :probe_n],

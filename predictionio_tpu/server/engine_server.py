@@ -1033,7 +1033,8 @@ class EngineServer:
         histograms and the row counters — EVERY dispatch, single or
         batched, so ``pio_batch_dispatch_seconds`` and ``pio_batch_size``
         count the same events. The score layer records its stages
-        (``dispatch.shortlist`` / ``dispatch.rescore``) as children."""
+        (``dispatch.shortlist`` / ``dispatch.rescore`` / ``dispatch.fetch``:
+        two launches, then one read) as children."""
         self._m_batch_size.observe(float(n_real))
         self._m_rows_real.inc(n_real)
         self._m_rows_padded.inc(n_padded)

@@ -237,13 +237,13 @@ def test_a_set_and_a_view_take_effect_on_the_next_query(world, monkeypatch, enga
 
 def test_the_unavailable_list_changes_no_shape(world, two_stage, monkeypatch):
     sizes = []
-    real = retrieval.CoarseCatalog.shortlist
+    real = retrieval.CoarseCatalog.launch
 
     def spy(self, queries, k, rules=None):
         sizes.append(k)
         return real(self, queries, k, rules)
 
-    monkeypatch.setattr(retrieval.CoarseCatalog, "shortlist", spy)
+    monkeypatch.setattr(retrieval.CoarseCatalog, "launch", spy)
     tracked = ("retrieval.coarse_topk_masked", "retrieval.rescore_vectors_masked",
                "topk.top_k_items_batch_masked")
     q = ec.Query(user="u5", num=10)
@@ -287,7 +287,7 @@ def test_spans_and_counters_of_the_rules(world, two_stage):
         {"home": 1, "category": 1, "list": 2}
     spans = {s[0]: s for s in trace.spans}
     assert {"rules.build", "rules.seen_read", "dispatch.shortlist",
-            "dispatch.rescore"} <= set(spans)
+            "dispatch.rescore", "dispatch.fetch"} <= set(spans)
     assert spans["rules.seen_read"][3] == "rules.build"
     assert ec._m_rules.summary()["count"] >= 1
     assert ec._m_excluded.summary()["count"] >= 4

@@ -29,7 +29,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHAIN = (
     "http.handoff", "http.read_parse", "dispatch", "serve", "serve.submit",
     "batch.queue_wait", "batch.dispatch[1]", "dispatch.shortlist",
-    "dispatch.rescore", "serve.wake", "serve.tail", "http.write",
+    "dispatch.rescore", "dispatch.fetch", "serve.wake", "serve.tail",
+    "http.write",
 )
 PARENTS = {
     "http.handoff": None, "http.read_parse": None, "dispatch": None,
@@ -37,6 +38,7 @@ PARENTS = {
     "batch.queue_wait": "serve", "batch.dispatch[1]": "serve",
     "dispatch.shortlist": "batch.dispatch[1]",
     "dispatch.rescore": "batch.dispatch[1]",
+    "dispatch.fetch": "batch.dispatch[1]",
     "serve.wake": "serve", "serve.tail": "serve",
 }
 NEW_HISTOGRAMS = (
@@ -371,8 +373,9 @@ class TestServingChain:
             assert sl["parent"] == disp[0]
             assert sl["durationMs"] <= d["durationMs"]
             assert sl["offsetMs"] >= d["offsetMs"] - 2e-3
-            assert (sl["durationMs"] + by_name["dispatch.rescore"]["durationMs"]
-                    <= d["durationMs"] + 4e-3)
+            assert (sum(by_name[n]["durationMs"] for n in (
+                "dispatch.shortlist", "dispatch.rescore", "dispatch.fetch"
+            )) <= d["durationMs"] + 6e-3)
         assert batched >= 2  # the burst did coalesce
 
     def test_pio_obs_off_records_none(self, served):
@@ -411,6 +414,7 @@ class TestServingChain:
         assert got == {
             "dispatch.shortlist": "batch.dispatch[1]",
             "dispatch.rescore": "batch.dispatch[1]",
+            "dispatch.fetch": "batch.dispatch[1]",
             "batch.dispatch[1]": None,
         }
         assert _hist_count("pio_batch_dispatch_seconds", {}) == disp0 + 1
