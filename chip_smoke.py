@@ -32,7 +32,11 @@ a small shape: item `$set`s with categories and view/buy events imported,
 `pio train`, `pio deploy` over the two-stage threshold, the three query
 kinds of a storefront (home, category page, cart blackList) held to a NumPy
 reference of the business rules, then a live `$set unavailableItems` and a
-`view`, both gone from the next answer.
+`view`, both gone from the next answer. The item-page leg then trains the
+Similar Product template on the same app and serves its three query kinds
+(similar, same category, session with a blackList) over the same threshold,
+held to the f32 cosines of the persisted model and to the masked sum-rows
+rescore having been staged with no temporary bytes.
 
 Last stdout line on success:
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -208,6 +212,17 @@ SHOP_VARIANT = {
     "datasource": {"params": {"appName": "ChipShop"}},
     "algorithms": [{"name": "als", "params": {
         "appName": "ChipShop", "unseenOnly": True, "seenEvents": ["buy", "view"],
+        "rank": SHOP_RANK, "numIterations": ITERATIONS, "lambda": 0.01,
+    }}],
+}
+
+# The item-page leg (the Similar Product template) trains on the same app's
+# item `$set`s and views and is served over the same threshold.
+SIMILAR_VARIANT = {
+    "id": "chip-smoke-similar",
+    "engineFactory": "predictionio_tpu.models.similarproduct.engine",
+    "datasource": {"params": {"appName": "ChipShop"}},
+    "algorithms": [{"name": "als", "params": {
         "rank": SHOP_RANK, "numIterations": ITERATIONS, "lambda": 0.01,
     }}],
 }
@@ -676,6 +691,96 @@ def storefront_leg(smoke: Smoke, seed: int, scale: int) -> None:
         smoke.stop_all()
 
 
+def similar_leg(smoke: Smoke, seed: int, scale: int) -> None:
+    """The Similar Product template end to end, on the storefront leg's app:
+    train -> deploy over the threshold -> the item page's three query kinds,
+    every answer held to the top of its ALLOWED set (the query's own items, its
+    blackList and every item outside its category removed) by the f32 cosines
+    of the persisted model."""
+    rng = np.random.default_rng(seed + 3)
+    with open(os.path.join(smoke.dir, "similar.json"), "w") as fh:
+        json.dump(SIMILAR_VARIANT, fh)
+    trained = train(smoke, "similar_train", "--variant", "similar.json")
+    if trained["platform"] != smoke.platform:
+        raise SmokeFailure(f"similar_train: the trainer reports {trained}")
+    fields = modelfile.load_path(
+        smoke.storage.get_model_data_models().local_path(trained["instance"])).fields(0)
+    V = np.asarray(fields["item_factors"], np.float32)
+    unit = V / np.maximum(np.linalg.norm(V, axis=1, keepdims=True), 1e-12)
+    item_row = dict(fields["item_index"].items())
+    row_item = {pos: sid for sid, pos in item_row.items()}
+    cat_of_row = np.asarray(fields["item_categories"])[:, 0]
+    cat_name = {pos: name for name, pos in fields["category_index"].items()}
+
+    def check(name, body, got):
+        own = [item_row[i] for i in body["items"]]
+        allowed = np.ones(len(unit), bool)
+        allowed[own + [item_row[i] for i in body.get("blackList", ())]] = False
+        for c in body.get("categories", ()):
+            allowed &= cat_of_row == fields["category_index"][c]
+        want = np.where(allowed, unit @ unit[own].sum(axis=0), -np.inf)
+        top = np.argsort(-want, kind="stable")[:TOP_K]
+        rows = [item_row[e["item"]] for e in got]
+        served = np.asarray([e["score"] for e in got], np.float32)
+        if len(rows) != TOP_K or len(set(rows)) != TOP_K or not allowed[rows].all():
+            raise SmokeFailure(f"{name}: served {[e['item'] for e in got]}: "
+                               f"{TOP_K} distinct allowed items expected")
+        dev = float(np.abs(served - want[rows]).max())
+        overlap = len(set(rows) & set(top.tolist())) / TOP_K
+        if dev > SCORE_TOL or overlap < MIN_OVERLAP or (np.diff(served) > 0).any():
+            raise SmokeFailure(
+                f"{name}: score deviation {dev:.3g}, overlap@{TOP_K} {overlap}\n"
+                f"served {[e['item'] for e in got]}\nreference {[row_item[r] for r in top]}")
+        return dev, overlap
+
+    port = free_port()
+    proc = smoke.spawn(
+        ["-m", "predictionio_tpu.cli.main", "deploy", "--variant", "similar.json",
+         "--ip", "127.0.0.1", "--port", str(port)], "similar_deploy.log",
+        PIO_RETRIEVAL_THRESHOLD=str(SHOP_THRESHOLD // scale),
+        PIO_RETRIEVAL_TILE=str(4096 // scale), PIO_RETRIEVAL_PROBE_EVERY="3",
+    )
+    try:
+        wait_until_serving(smoke, proc, port, "similar_deploy", "similar_deploy.log")
+        devs, overlaps = [], []
+        for lead in rng.choice(len(unit), SHOP_QUERIES, replace=False).tolist():
+            strip = [row_item[r] for r in rng.choice(len(unit), 4, replace=False).tolist()]
+            black = [row_item[r] for r in rng.integers(0, len(unit), 3).tolist()]
+            for name, body in (
+                ("similar", {"items": [row_item[lead]]}),
+                ("same_category", {"items": [row_item[lead]],
+                                   "categories": [cat_name[int(cat_of_row[lead])]]}),
+                ("session", {"items": strip, "blackList": black}),
+            ):
+                label = f"similar {name} {body['items'][0]}"
+                got = _ask(port, label, {"num": TOP_K, **body})
+                d, o = check(label, body, got)
+                devs.append(d)
+                overlaps.append(o)
+        stats = json.loads(_http(port, "GET", "/stats.json")[1])
+        devices = stats["device"]["devices"]
+        programs = stats["retrieval"]["rescore_temp_bytes"]
+        if {d["device"].split(":")[0] for d in devices} != {smoke.platform}:
+            raise SmokeFailure(f"similar_deploy: the server reports devices {devices}")
+        # a rescore that re-lays its table needs a table's worth of temporary
+        # bytes (0 on the chip; XLA:CPU keeps ~1 KB of scratch)
+        if (programs.get("retrieval.rescore_sum_rows_masked", V.nbytes) >= V.nbytes
+                or stats["retrieval"]["exact_queries"]):
+            raise SmokeFailure(
+                f"similar_deploy: programs {programs}, {stats['retrieval']['exact_queries']} "
+                "exact queries: the filters did not run inside two-stage retrieval, or "
+                "the rescore re-lays its table")
+        print("similar_deploy: " + json.dumps({
+            "queries_200": len(devs), "score_deviation_max": max(devs),
+            "overlap_min": min(overlaps), "two_stage_queries":
+            stats["retrieval"]["two_stage_queries"], "probes": stats["retrieval"]["probes"],
+            "host_reads": stats["retrieval"]["host_reads"],
+            "rescore_temp_bytes": programs}), flush=True)
+        stop_server(proc, port, "similar_deploy")
+    finally:
+        smoke.stop_all()
+
+
 # -- the run ----------------------------------------------------------------------
 
 
@@ -810,6 +915,7 @@ def run(smoke: Smoke, args) -> dict:
         )
 
     storefront_leg(smoke, args.seed, 10 if args.dry_run_cpu else 1)
+    similar_leg(smoke, args.seed, 10 if args.dry_run_cpu else 1)
     if device["count"] >= 4:
         sharded_leg(smoke, ref, rmse, dense, users, (s_rows, s_cols, s_vals),
                     num_users, num_items)

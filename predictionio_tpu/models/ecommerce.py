@@ -56,6 +56,14 @@ from predictionio_tpu.core import (
 from predictionio_tpu.data import store
 from predictionio_tpu.data.storage.base import RatingsBatch
 from predictionio_tpu.models.columnar import aggregate_counts
+from predictionio_tpu.models.filters import (
+    ItemCategories,
+    availability_vector,
+    candidate_lists,
+    category_vectors,
+    padded_rows,
+    query_rules,
+)
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.obs import trace as obs_trace
@@ -160,7 +168,7 @@ class ECommAlgorithmParams(Params):
 
 
 @dataclass
-class ECommModel:
+class ECommModel(ItemCategories):
     user_index: BiMap
     item_index: BiMap
     user_factors: np.ndarray  # int8 values when user_scales set
@@ -180,21 +188,6 @@ class ECommModel:
     def __post_init__(self):
         self._device = None
         self._index_categories()
-
-    def _index_categories(self) -> None:
-        if self.item_categories is None:
-            cats = self.categories or {}
-            self.category_index = BiMap.from_dense(
-                sorted({c for cs in cats.values() for c in cs})
-            )
-            width = max([1] + [len(cs) for cs in cats.values()])
-            table = np.full((len(self.item_index), width), -1, np.int32)
-            for iid, cs in cats.items():
-                ix = self.item_index.get(iid)
-                if ix is not None:
-                    table[ix, : len(cs)] = [self.category_index[c] for c in cs]
-            self.item_categories = table
-        self.categories = None
 
     def user_rows(self, ixs):
         """Dense f32 user vectors (dequantizes int8 storage)."""
@@ -236,13 +229,6 @@ class ECommModel:
         state.pop("_coarse_V", None)
         state.pop("_rules", None)
         return state
-
-    def __setstate__(self, state):
-        # a pickle from before the array block holds ``categories`` only
-        self.__dict__.update(
-            {"category_index": None, "item_categories": None, **state}
-        )
-        self._index_categories()
 
 
 # one pow2 bucket for a query's own exclusion list (seen + blackList):
@@ -528,8 +514,6 @@ class ECommAlgorithm(Algorithm):
         device. The category vectors are built once; the availability
         vector again only when the constraint's CONTENT has changed —
         a view event moves the token and costs one point read here."""
-        import jax.numpy as jnp
-
         unavail = self._unavailable_rows(model, cache)
         states = model.__dict__.setdefault("_rules", {})
         state = states.get(rows)
@@ -539,20 +523,14 @@ class ECommAlgorithm(Algorithm):
         ):
             return state["avail"], state["cats"]
         with self._serve_lock:
-            n = len(model.item_index)
             if state is None:
-                cols = np.full(
-                    (model.item_categories.shape[1], rows), -1, np.int32
-                )
-                cols[:, :n] = model.item_categories.T
-                cats = tuple(jnp.asarray(c) for c in cols)
+                cats = category_vectors(model.item_categories, rows)
             else:
                 cats = state["cats"]
             with obs_trace.region("rules.refresh", hist=_m_refresh_secs):
-                avail = np.zeros(rows, np.uint8)
-                avail[:n] = 1
-                avail[unavail] = 0
-                avail = jnp.asarray(avail)
+                avail = availability_vector(
+                    len(model.item_index), rows, unavail
+                )
             _m_refresh.inc()
             states[rows] = {"unavail": unavail, "avail": avail, "cats": cats}
         return avail, cats
@@ -653,13 +631,7 @@ class ECommAlgorithm(Algorithm):
             )
         else:
             excluded = np.unique(np.asarray(excluded, np.int32))
-        cats = None
-        if q.categories is not None:
-            cats = [
-                c
-                for c in map(model.category_index.get, q.categories)
-                if c is not None
-            ]
+        cats = model.category_ids(q.categories)
         white = None
         if q.whiteList is not None:
             white = np.unique(np.fromiter(
@@ -723,34 +695,16 @@ class ECommAlgorithm(Algorithm):
                 qcats.append(qc)
                 whites.append(white)
 
-            def padded(rows: list[int]) -> list[int]:
-                """``rows`` filled to a power of two by copies of the
-                first (the batch shapes the programs compile for)."""
-                return rows + rows[:1] * (_pow2(len(rows)) - len(rows))
-
             def rules_for(rows: list[int]) -> Rules:
                 """The rules of ``scored[r] for r in rows``, padded."""
-                rows = padded(rows)
-                width = max(
-                    [_EXCLUDED_BUCKET] + [len(excluded[r]) for r in rows]
-                )
-                ex = np.full((len(rows), _pow2(width)), -1, np.int32)
-                qcat = np.full(
-                    (len(rows), _pow2(max(
-                        [1] + [len(qcats[r] or ()) for r in rows]
-                    ))), -2, np.int32,
-                )
-                for j, r in enumerate(rows):
-                    ex[j, : len(excluded[r])] = excluded[r]
-                    if qcats[r]:
-                        qcat[j, : len(qcats[r])] = qcats[r]
-                has_cat = np.asarray([qcats[r] is not None for r in rows])
-                return retrieval.device_rules(
-                    Rules(avail, cats, qcat, has_cat, ex)
+                rows = padded_rows(rows)
+                return query_rules(
+                    avail, cats, [excluded[r] for r in rows],
+                    [qcats[r] for r in rows], _EXCLUDED_BUCKET,
                 )
 
             def batch_for(rows: list[int]) -> np.ndarray:
-                return np.stack([vecs[r] for r in padded(rows)])
+                return np.stack([vecs[r] for r in padded_rows(rows)])
 
             open_ = [r for r in range(len(scored)) if whites[r] is None]
             listed = [r for r in range(len(scored)) if whites[r] is not None]
@@ -779,11 +733,9 @@ class ECommAlgorithm(Algorithm):
         if listed:
             # a whiteList IS the candidate list: every allowed member is
             # scored exactly, whatever the catalog's size
-            width = _pow2(max([k] + [len(whites[r]) for r in listed]))
-            cand = np.full((len(listed_rules.ex), width), -1, np.int32)
-            for j, r in enumerate(listed):
-                cand[j, : len(whites[r])] = whites[r]
-            cand[len(listed):] = cand[0]
+            cand = candidate_lists(
+                [whites[r] for r in listed], len(listed_rules.ex), k
+            )
             scores, ids = retrieval.rescore_top_k_batch(
                 batch_for(listed), V, cand, k=k, rules=listed_rules
             )

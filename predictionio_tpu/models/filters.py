@@ -15,11 +15,13 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Callable, Iterable, Sequence
+from typing import Callable, Sequence
 
 import numpy as np
 
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import trace as obs_trace
 
 logger = logging.getLogger(__name__)
 
@@ -69,26 +71,128 @@ def normalized_query_vectors(
     return (rows * np.asarray(row_weights, np.float32)[..., None]).sum(axis=1)
 
 
-def entity_exclusion_mask(
-    index: BiMap,
-    self_entities: Iterable[str],
-    white_list: Sequence[str] | None,
-    black_list: Sequence[str] | None,
-) -> np.ndarray:
-    """[len(index)] bool mask; True = candidate may never be returned."""
-    n = len(index)
-    mask = np.zeros(n, dtype=bool)
-    for ent in self_entities:
-        if ent in index:
-            mask[index[ent]] = True
-    if white_list is not None:
-        allowed = {index[e] for e in white_list if e in index}
-        mask |= ~np.isin(np.arange(n), list(allowed))
-    if black_list:
-        for ent in black_list:
-            if ent in index:
-                mask[index[ent]] = True
-    return mask
+class ItemCategories:
+    """The items' categories of a model dataclass with the fields
+    ``item_index``, ``categories`` (construction-time input: ``{item id:
+    [category, ...]}``, what training and model files written before
+    the array block hold), ``category_index`` (category name -> dense
+    id) and ``item_categories`` ([I, W] int32 category ids, -1 past an
+    item's last; W is the most categories any item has): the dictionary
+    is indexed into the other two and dropped, so the model file holds
+    an array block, not a 4 M-entry JSON header, and
+    ``category_vectors`` has a table to put on the device."""
+
+    def _index_categories(self) -> None:
+        if self.item_categories is None:
+            cats = self.categories or {}
+            self.category_index = BiMap.from_dense(
+                sorted({c for cs in cats.values() for c in cs})
+            )
+            width = max([1] + [len(cs) for cs in cats.values()])
+            table = np.full((len(self.item_index), width), -1, np.int32)
+            for iid, cs in cats.items():
+                ix = self.item_index.get(iid)
+                if ix is not None:
+                    table[ix, : len(cs)] = [self.category_index[c] for c in cs]
+            self.item_categories = table
+        self.categories = None
+
+    def __setstate__(self, state):
+        # a pickle from before the array block holds ``categories`` only
+        self.__dict__.update(
+            {"category_index": None, "item_categories": None, **state}
+        )
+        self._index_categories()
+
+    def category_ids(self, names) -> list[int] | None:
+        """The known ids of the categories a query names (None where it
+        names none: unrestricted; a query that names only unknown
+        categories is restricted to nothing)."""
+        if names is None:
+            return None
+        return [c for c in map(self.category_index.get, names) if c is not None]
+
+
+# -- ``ops.topk.Rules`` from host lists ---------------------------------------
+#
+# The catalog-wide rules are resident device vectors over the ``rows``
+# stored rows the programs slice (the coarse catalog's, padding
+# included; the catalog's own below the retrieval threshold); a query's
+# own rules are short index lists padded to power-of-two widths, so the
+# compiled shapes do not move with the traffic. No request builds a
+# dense [num_items] host array.
+
+
+def category_vectors(item_categories, rows: int) -> tuple:
+    """One device [rows] int32 vector per category column of the
+    [I, W] table (-1 = none, and past the catalog); () for a catalog
+    without categories."""
+    import jax.numpy as jnp
+
+    if item_categories is None:
+        return ()
+    cols = np.full((item_categories.shape[1], rows), -1, np.int32)
+    cols[:, : len(item_categories)] = np.asarray(item_categories).T
+    return tuple(jnp.asarray(c) for c in cols)
+
+
+def availability_vector(num_items: int, rows: int, unavailable=None):
+    """Device [rows] uint8: 1 = the row may be served; 0 for the
+    ``unavailable`` rows and the padding past the catalog."""
+    import jax.numpy as jnp
+
+    avail = np.zeros(rows, np.uint8)
+    avail[:num_items] = 1
+    if unavailable is not None:
+        avail[unavailable] = 0
+    return jnp.asarray(avail)
+
+
+def padded_rows(rows: list[int]) -> list[int]:
+    """``rows`` filled to a power of two by copies of the first (the
+    batch shapes the programs compile for)."""
+    from predictionio_tpu.ops.retrieval import _pow2
+
+    return rows + rows[:1] * (_pow2(len(rows)) - len(rows))
+
+
+def query_rules(avail, cats, excluded: Sequence, qcats: Sequence,
+                bucket: int):
+    """``device_rules`` of a padded batch: ``excluded[j]`` the rows
+    query j alone may not be served, ``qcats[j]`` the category ids it is
+    restricted to (None = unrestricted; an empty list allows nothing).
+    ``ex`` is ``bucket`` wide unless a list outgrows it (the next power
+    of two)."""
+    from predictionio_tpu.ops import retrieval
+    from predictionio_tpu.ops.topk import Rules
+
+    n = len(excluded)
+    width = retrieval._pow2(max([bucket] + [len(e) for e in excluded]))
+    ex = np.full((n, width), -1, np.int32)
+    qcat = np.full(
+        (n, retrieval._pow2(max([1] + [len(c or ()) for c in qcats]))),
+        -2, np.int32,
+    )
+    for j, (e, c) in enumerate(zip(excluded, qcats)):
+        ex[j, : len(e)] = e
+        if c:
+            qcat[j, : len(c)] = c
+    has_cat = np.asarray([c is not None for c in qcats])
+    return retrieval.device_rules(Rules(avail, cats, qcat, has_cat, ex))
+
+
+def candidate_lists(lists: Sequence, rows: int, k: int) -> np.ndarray:
+    """[rows, width] int32 candidate ids of a ``whiteList`` batch: the
+    lists side by side, -1 past each one's end, ``width`` the power of
+    two at or above the longest (and k), the rows past the last list
+    copies of the first (the batch's padding)."""
+    from predictionio_tpu.ops.retrieval import _pow2
+
+    cand = np.full((rows, _pow2(max([k] + [len(c) for c in lists]))), -1, np.int32)
+    for j, c in enumerate(lists):
+        cand[j, : len(c)] = c
+    cand[len(lists):] = cand[0]
+    return cand
 
 
 class CosineCatalog:
@@ -97,6 +201,10 @@ class CosineCatalog:
     scale vector in ``_catalog_fields``."""
 
     _catalog_fields: tuple[str, str]
+
+    # [rows, W] int32 category ids of the catalog's rows (``ItemCategories``),
+    # or None for a catalog without categories
+    item_categories = None
 
     def __post_init__(self):
         self._device = None
@@ -135,12 +243,60 @@ class CosineCatalog:
             self._coarse = CoarseCatalog(self.device_factors())
         return self._coarse
 
+    def rule_vectors(self, rows: int):
+        """The resident catalog-wide ``Rules`` vectors over ``rows``
+        stored rows, built once per ``rows``: (availability — only the
+        padding past the catalog is unavailable — and one category
+        vector per category column)."""
+        cache = self.__dict__.setdefault("_rule_vectors", {})
+        vectors = cache.get(rows)
+        if vectors is None:
+            n = len(self.host_catalog()[0])
+            vectors = cache[rows] = (
+                availability_vector(n, rows),
+                category_vectors(self.item_categories, rows),
+            )
+        return vectors
+
     def __getstate__(self):
         state = self.__dict__.copy()
         state["_device"] = None
         state["_norms"] = None
         state["_coarse"] = None
+        state.pop("_rule_vectors", None)
         return state
+
+
+# one pow2 bucket each for the rows summed into a query vector and for a
+# query's own exclusion list (its entities + blackList), so the compiled
+# shapes do not move with the traffic: a session's 8 items and 5
+# black-listed ones fit; a longer list takes the next power of two
+_ROWS_BUCKET = 8
+_EXCLUDED_BUCKET = 16
+
+_m_build = obs_metrics.histogram(
+    "pio_similar_build_seconds",
+    "host work turning a dispatch's queries into summed rows and rules",
+)
+_m_queries = {
+    kind: obs_metrics.counter(
+        "pio_similar_queries_total", "similar-to-these queries by kind",
+        kind=kind,
+    )
+    for kind in ("plain", "category", "blacklist", "whitelist")
+}
+_m_query_rows = obs_metrics.histogram(
+    "pio_similar_query_rows", "catalog rows summed into one query vector",
+    bounds=tuple(float(1 << p) for p in range(0, 8)),
+)
+
+
+def _query_kind(q, categories) -> str:
+    if q.whiteList is not None:
+        return "whitelist"
+    if categories is not None:
+        return "category"
+    return "blacklist" if q.blackList else "plain"
 
 
 def score_similar_batch(
@@ -148,100 +304,124 @@ def score_similar_batch(
     index: BiMap,
     queries: Sequence,
     entities: Callable,
-    dense_mask: Callable,
     result: Callable,
+    categories: Callable = lambda q: None,
 ) -> list:
     """Score a micro-batch of "similar to these" queries against a
     ``CosineCatalog``: ``entities(q)`` are a query's own ids in
-    ``index``, ``result(pairs)`` builds the template's answer from
-    ``[(id, score), ...]``.
+    ``index``, ``categories(q)`` the category ids it is restricted to
+    (None = unrestricted), ``result(pairs)`` builds the template's
+    answer from ``[(id, score), ...]``.
 
-    Two filter regimes:
+    One regime for every query, filtered or not: its exclusions — its
+    own entities and its ``blackList`` — and its categories travel to
+    the device as ``ops.topk.Rules`` (``similar.build``: short index
+    lists, never a dense [rows] mask) and are applied where the scores
+    are produced — inside the coarse scan and again in the rescore at
+    retrieval scale, in the masked exact program below it
+    (``ops.retrieval.top_k`` decides) — so k = pow2(num) carries no
+    headroom, nothing is dropped on the host, and every such query of
+    the batch shares one program per stage. A ``whiteList`` IS the
+    candidate list: its members are scored exactly by the same rescore
+    program, whatever the catalog's size. A query with fewer than
+    ``num`` allowed rows gets a short, exact answer.
 
-    - SIMPLE (``dense_mask(q)`` is None): the excluded set is small and
-      enumerable host-side (the query's own entities plus any
-      ``blackList`` hits), so instead of shipping a [rows] mask per
-      query the batch requests top-(num + |excluded|) with NO mask and
-      drops excluded ids from the returned prefix — identical results
-      (masking sinks excluded entries without perturbing the others,
-      and ``lax.top_k`` prefixes are k-invariant), zero mask traffic,
-      one shared device call for every simple query in the batch.
-    - DENSE (``dense_mask(q)`` is the query's [rows] bool exclusion
-      mask: a ``whiteList`` or a category filter can cover most of the
-      catalog, so headroom-k is unbounded): masked exact scoring, one
-      call each, through the same fused op.
-
-    How a call is scored — exact or shortlist + rescore — is
-    ``ops.retrieval.top_k``'s decision. Single-query ``predict``
-    delegates here with a batch of one, so a query is answered by the
-    same programs alone and coalesced: the same entities in the same
-    order, scores equal to the last bits of f32 (a dot's summation
-    order can move with the batch size; tests hold them to 2e-6)."""
-    import jax.numpy as jnp
-
+    Single-query ``predict`` delegates here with a batch of one, so a
+    query is answered by the same programs alone and coalesced: the same
+    entities in the same order, scores equal to the last bits of f32 (a
+    dot's summation order can move with the batch size; tests hold them
+    to 2e-6)."""
     from predictionio_tpu.ops import retrieval
 
-    inv = index.inverse
     results: list = [None] * len(queries)
-    simple: list[tuple[int, list[int], set[int], int]] = []
-    dense: list[tuple[int, list[int], np.ndarray, int]] = []
-    for qi, q in enumerate(queries):
-        known = [index[e] for e in entities(q) if e in index]
-        if not known:
-            logger.info("no query entities with factors; returning empty result")
-            results[qi] = result([])
-            continue
-        mask = dense_mask(q)
-        if mask is not None:
-            dense.append((qi, known, mask, int(q.num)))
-        else:
-            excluded = set(known)
-            if q.blackList is not None:
-                excluded.update(index[e] for e in q.blackList if e in index)
-            simple.append((qi, known, excluded, int(q.num)))
+    n_rows = len(index)
     V = model.device_factors()  # row-normalized: dot == cosine
-    vectors = functools.partial(normalized_query_vectors, *model.host_catalog())
+    k = retrieval._pow2(max([1] + [int(q.num) for q in queries]))
+    two_stage = retrieval.two_stage_k(k, n_rows)
+    with obs_trace.region("similar.build", hist=_m_build):
+        # the rules are vectors over the rows the scan will slice: the
+        # coarse catalog's (padding included) where one is used
+        coarse = model.coarse_catalog() if two_stage else None
+        avail, cats = model.rule_vectors(
+            coarse.stored_rows if two_stage else n_rows
+        )
+        scored: list[int] = []
+        knowns, excluded, qcats, whites = [], [], [], []
+        for qi, q in enumerate(queries):
+            qc = categories(q)
+            _m_queries[_query_kind(q, qc)].inc()
+            known = [ix for ix in map(index.get, entities(q)) if ix is not None]
+            if not known:
+                logger.info(
+                    "no query entities with factors; returning empty result"
+                )
+                results[qi] = result([])
+                continue
+            _m_query_rows.observe(float(len(known)))
+            black = [
+                ix for ix in map(index.get, q.blackList or ()) if ix is not None
+            ]
+            scored.append(qi)
+            knowns.append(known)
+            excluded.append(np.unique(np.asarray(known + black, np.int32)))
+            qcats.append(qc)
+            whites.append(None if q.whiteList is None else np.unique(np.fromiter(
+                (ix for ix in map(index.get, q.whiteList) if ix is not None),
+                np.int32,
+            )))
 
-    def top(knowns: list[list[int]], k: int, probe_n=None, mask=None):
-        # pad the per-query id lists to a shared pow2 width with
-        # weight-0 rows (index 0 gathered, then zeroed — exact); k is
-        # pow2 as well, so the jitted programs specialize on a bounded
-        # shape set
-        ixs = np.zeros(
-            (len(knowns), retrieval._pow2(max(map(len, knowns)))), np.int32
-        )
-        weights = np.zeros(ixs.shape, np.float32)
-        for row, known in enumerate(knowns):
-            ixs[row, : len(known)] = known
-            weights[row, : len(known)] = 1.0
-        return retrieval.top_k(
-            retrieval.SumRows(ixs, weights, vectors, mask), V, len(index),
-            model.coarse_catalog, retrieval._pow2(k), probe_n,
-        )
+        def batch_for(rows: list[int]):
+            """(SumRows' ixs and weights, the rules) of ``scored[r] for r
+            in rows``, padded to a power of two: the id lists to the
+            bucket's width with weight-0 rows (index 0 gathered, then
+            zeroed — exact)."""
+            rows = padded_rows(rows)
+            width = retrieval._pow2(
+                max([_ROWS_BUCKET] + [len(knowns[r]) for r in rows])
+            )
+            ixs = np.zeros((len(rows), width), np.int32)
+            weights = np.zeros(ixs.shape, np.float32)
+            for j, r in enumerate(rows):
+                ixs[j, : len(knowns[r])] = knowns[r]
+                weights[j, : len(knowns[r])] = 1.0
+            return ixs, weights, query_rules(
+                avail, cats, [excluded[r] for r in rows],
+                [qcats[r] for r in rows], _EXCLUDED_BUCKET,
+            )
 
-    if simple:
-        # k for the worst headroom in the batch; the probe compares the
-        # ids the first query's answer is cut from
-        scores, ids = top(
-            [known for _, known, _, _ in simple],
-            max(num + len(excl) for _, _, excl, num in simple),
-            probe_n=simple[0][3] + len(simple[0][2]),
+        open_ = [r for r in range(len(scored)) if whites[r] is None]
+        listed = [r for r in range(len(scored)) if whites[r] is not None]
+        open_batch = batch_for(open_) if open_ else None
+        listed_batch = batch_for(listed) if listed else None
+
+    def publish(rows, scores, ids):
+        inv = index.inverse
+        for j, r in enumerate(rows):
+            num = int(queries[scored[r]].num)
+            results[scored[r]] = result([
+                (inv[int(i)], float(s))
+                for s, i in zip(scores[j, :num], ids[j, :num])
+                if int(i) >= 0
+            ])
+
+    if open_:
+        ixs, weights, rules = open_batch
+        scores, ids = retrieval.top_k(
+            retrieval.SumRows(
+                ixs, weights,
+                functools.partial(
+                    normalized_query_vectors, *model.host_catalog()
+                ),
+                rules,
+            ),
+            V, n_rows, coarse, k, probe_n=int(queries[scored[open_[0]]].num),
         )
-        for row, (qi, _, excluded, num) in enumerate(simple):
-            pairs: list[tuple[str, float]] = []
-            for s, i in zip(scores[row], ids[row]):
-                ii = int(i)
-                if ii < 0 or ii in excluded:
-                    continue
-                pairs.append((inv[ii], float(s)))
-                if len(pairs) == num:
-                    break
-            results[qi] = result(pairs)
-    for qi, known, mask, num in dense:
-        scores, ids = top([known], num, mask=jnp.asarray(mask))
-        results[qi] = result([
-            (inv[int(i)], float(s))
-            for s, i in zip(scores[0][:num], ids[0][:num])
-            if s > -1e29  # drop fully-masked placeholders
-        ])
+        publish(open_, scores, ids)
+    if listed:
+        ixs, weights, rules = listed_batch
+        cand = candidate_lists([whites[r] for r in listed], len(ixs), k)
+        scores, ids = retrieval.rescore_sum_rows_top_k_batch(
+            ixs, weights, V, cand, k=k, rules=rules
+        )
+        publish(listed, scores, ids)
     return results

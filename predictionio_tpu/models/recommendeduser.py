@@ -34,7 +34,6 @@ from predictionio_tpu.data.storage.base import RatingsBatch
 from predictionio_tpu.models.columnar import aggregate_counts
 from predictionio_tpu.models.filters import (
     CosineCatalog,
-    entity_exclusion_mask,
     score_similar_batch,
 )
 from predictionio_tpu.data.bimap import BiMap
@@ -165,18 +164,12 @@ class ALSAlgorithm(Algorithm):
         self, model: RecommendedUserModel,
         queries: Sequence[tuple[int, Query]],
     ) -> list[tuple[int, PredictedResult]]:
-        """``filters.score_similar_batch`` over followed users: a
-        ``whiteList`` is the filter that can rule out most of the
-        catalog."""
-        index = model.followed_index
+        """``filters.score_similar_batch`` over followed users: the
+        query's own users and its ``blackList`` are rules applied where
+        the scores are produced, a ``whiteList`` is a candidate list."""
         results = score_similar_batch(
-            model, index, [q for _, q in queries],
+            model, model.followed_index, [q for _, q in queries],
             entities=lambda q: q.users,
-            dense_mask=lambda q: (
-                entity_exclusion_mask(index, q.users, q.whiteList, q.blackList)
-                if q.whiteList is not None
-                else None
-            ),
             result=lambda pairs: PredictedResult(
                 userScores=[UserScore(user=u, score=s) for u, s in pairs]
             ),

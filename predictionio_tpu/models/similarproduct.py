@@ -50,7 +50,7 @@ from predictionio_tpu.models.columnar import (
 )
 from predictionio_tpu.models.filters import (
     CosineCatalog,
-    entity_exclusion_mask,
+    ItemCategories,
     score_similar_batch,
 )
 from predictionio_tpu.ops import als as als_ops
@@ -143,29 +143,21 @@ class ALSAlgorithmParams(Params):
 
 
 @dataclass
-class SimilarProductModel(CosineCatalog):
+class SimilarProductModel(ItemCategories, CosineCatalog):
     item_index: BiMap
     item_factors: np.ndarray  # [I, D]; int8 values when item_scales set
-    categories: dict[str, list[str]]
+    # ``filters.ItemCategories``: the dictionary is construction-time
+    # input, the model keeps the index and the [I, W] int32 array block
+    categories: dict[str, list[str]] | None = None
     item_scales: np.ndarray | None = None  # [I] f32, int8 storage only
+    category_index: BiMap | None = None
+    item_categories: np.ndarray | None = None
 
     _catalog_fields = ("item_factors", "item_scales")
 
-
-def _exclude_mask(
-    item_index: BiMap, categories: dict[str, list[str]], query: Query
-) -> np.ndarray:
-    """Build the candidate-exclusion mask from query items, category,
-    white/black lists (reference ALSAlgorithm.scala:193-244 filters)."""
-    mask = entity_exclusion_mask(
-        item_index, query.items, query.whiteList, query.blackList
-    )
-    if query.categories is not None:
-        wanted = set(query.categories)
-        for iid, ix in item_index.items():
-            if not wanted.intersection(categories.get(iid, ())):
-                mask[ix] = True
-    return mask
+    def __post_init__(self):
+        super().__post_init__()
+        self._index_categories()
 
 
 def _view_counts(td: TrainingData) -> IndexedRatings:
@@ -223,18 +215,14 @@ class ALSAlgorithm(Algorithm):
         self, model: SimilarProductModel,
         queries: Sequence[tuple[int, Query]],
     ) -> list[tuple[int, PredictedResult]]:
-        """``filters.score_similar_batch`` over items: ``categories``
-        and ``whiteList`` are the filters that can rule out most of the
-        catalog (reference ALSAlgorithm.scala:193-244)."""
-        index = model.item_index
+        """``filters.score_similar_batch`` over items: the query's own
+        items, its ``blackList`` and its ``categories`` are rules
+        applied where the scores are produced, a ``whiteList`` is a
+        candidate list (reference ALSAlgorithm.scala:193-244)."""
         results = score_similar_batch(
-            model, index, [q for _, q in queries],
+            model, model.item_index, [q for _, q in queries],
             entities=lambda q: q.items,
-            dense_mask=lambda q: (
-                _exclude_mask(index, model.categories, q)
-                if q.categories is not None or q.whiteList is not None
-                else None
-            ),
+            categories=lambda q: model.category_ids(q.categories),
             result=lambda pairs: PredictedResult(
                 itemScores=[ItemScore(item=i, score=s) for i, s in pairs]
             ),
@@ -300,10 +288,25 @@ class CosineAlgorithm(Algorithm):
             for score, jx in zip(model.sim_scores[ix], model.sim_ids[ix]):
                 if np.isfinite(score):
                     combined[int(jx)] += float(score)
-        mask = _exclude_mask(model.item_index, model.categories, query)
-        inv = model.item_index.inverse
+        index, inv = model.item_index, model.item_index.inverse
+        # the neighbour lists live on the host: set look-ups are enough
+        dropped = {index.get(i) for i in (*query.items, *(query.blackList or ()))}
+        white = (
+            None if query.whiteList is None
+            else {index.get(i) for i in query.whiteList}
+        )
+        wanted = None if query.categories is None else set(query.categories)
+
+        def allowed(jx: int) -> bool:
+            return (
+                jx not in dropped
+                and (white is None or jx in white)
+                and (wanted is None
+                     or not wanted.isdisjoint(model.categories.get(inv[jx], ())))
+            )
+
         ranked = sorted(
-            ((jx, s) for jx, s in combined.items() if not mask[jx]),
+            ((jx, s) for jx, s in combined.items() if allowed(jx)),
             key=lambda kv: -kv[1],
         )[: int(query.num)]
         return PredictedResult(

@@ -38,7 +38,9 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
 One function chains the two stages, ``top_k`` (the end of this file):
 the four ALS templates hand it a query batch in one of three forms
 (``UserRows``, ``Vectors``, ``SumRows`` — each the argument list that an
-exact op of ops/topk.py and its rescore variant share), the resident
+exact op of ops/topk.py and its rescore variant share; the last two
+under ``ops.topk.Rules`` where the template has filters: no filter
+keeps a query from the shortlist), the resident
 exact table, the catalog's row count, the coarse copy and ``k``, and it
 alone decides exact or two-stage, runs shortlist -> rescore, and every
 Nth two-stage dispatch re-scores row 0 exactly (the live recall probe).
@@ -60,9 +62,9 @@ operators can flip them live):
 Where the tables' layout is decided, and who reads them: nowhere in
 this repo. A template's ``device_factors()`` puts each [rows, D] factor
 array up with ``jnp.asarray``, once, in the device's default layout,
-and every reader takes it as it lies: the three rescore programs here,
-``ops/topk.py``'s exact programs (the recall probe, filtered queries,
-catalogs below the threshold) and the templates' own row math;
+and every reader takes it as it lies: the rescore programs here,
+``ops/topk.py``'s exact programs (the recall probe, catalogs below the
+threshold) and the templates' own row math;
 ``CoarseCatalog`` and ``rescore_host`` read the model's host arrays. On
 a TPU that default keeps the long axis minor wherever D is no multiple
 of 128, and XLA answers a gather of 64-column rows from it by first
@@ -114,7 +116,7 @@ from predictionio_tpu.ops.topk import (
     Rules,
     gather_top_k_batch,
     rows_allowed,
-    sum_rows_top_k_batch,
+    sum_rows_top_k_batch_masked,
     top_k_items_batch,
     top_k_items_batch_masked,
 )
@@ -175,13 +177,16 @@ def two_stage_k(k: int, num_rows: int) -> int:
 
 _SIZE_BOUNDS = tuple(float(1 << p) for p in range(4, 20, 2))  # 16 .. 262144
 
+_QUERIES_HELP = (
+    "serving queries of a catalog at retrieval scale, by path: two_stage = "
+    "shortlist then rescore; exact = the exact program, because k leaves a "
+    "shortlist no room (no filter takes a query there since PR 30)"
+)
 _m_two_stage = obs_metrics.counter(
-    "pio_retrieval_queries_total",
-    "serving queries at retrieval scale, by path", path="two_stage",
+    "pio_retrieval_queries_total", _QUERIES_HELP, path="two_stage",
 )
 _m_exact = obs_metrics.counter(
-    "pio_retrieval_queries_total",
-    "serving queries at retrieval scale, by path", path="exact",
+    "pio_retrieval_queries_total", _QUERIES_HELP, path="exact",
 )
 _m_shortlist_size = obs_metrics.histogram(
     "pio_retrieval_shortlist_size",
@@ -752,11 +757,12 @@ def _rescore_vectors_masked(user_vectors, item_factors, cand_ids,
     return _score_candidates(user_vectors, item_factors, cand_ids, k, rules)
 
 
-@_rescore_program("retrieval.rescore_sum_rows")
-def _rescore_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
+@_rescore_program("retrieval.rescore_sum_rows_masked")
+def _rescore_sum_rows_masked(row_ixs, row_weights, item_factors, cand_ids,
+                             rules: Rules, k: int):
     rows = _table_rows(item_factors, row_ixs.astype(jnp.int32))
     qvecs = jnp.sum(rows * row_weights[..., None], axis=1)
-    return _score_candidates(qvecs, item_factors, cand_ids, k)
+    return _score_candidates(qvecs, item_factors, cand_ids, k, rules)
 
 
 # Each rescore entry point comes twice: ``_launch_*`` converts and uploads
@@ -795,12 +801,14 @@ def _launch_vectors(user_vectors, item_factors, cand_ids, k: int,
         return _rescore_vectors_masked(vecs, item_factors, cand, rules, k=k)
 
 
-def _launch_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int):
+def _launch_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int,
+                     rules: Rules):
     with _rescore_stage():
         cand = _up(cand_ids, np.int32)
-        return _rescore_sum_rows(
+        return _rescore_sum_rows_masked(
             _up(row_ixs, np.int32, len(cand)),
-            _up(row_weights, np.float32, len(cand)), item_factors, cand, k=k,
+            _up(row_weights, np.float32, len(cand)),
+            item_factors, cand, rules, k=k,
         )
 
 
@@ -827,13 +835,15 @@ def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int,
 
 
 def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
-                                 cand_ids, k: int):
-    """Shortlist-gather variant of ``sum_rows_top_k_batch`` for the
-    cosine-family templates: the query vector is the weighted sum of
+                                 cand_ids, k: int, rules: Rules):
+    """Shortlist-gather variant of ``sum_rows_top_k_batch_masked`` for
+    the cosine-family templates: the query vector is the weighted sum of
     gathered catalog rows (built on device exactly like the exact op),
-    scored against the [B, S] candidates only."""
+    scored against the [B, S] candidates only, under ``rules`` (as
+    ``rescore_top_k_batch``: a query of these templates always excludes
+    its own rows, so the form has no rule-less program)."""
     return _read_rescore(_launch_sum_rows(
-        row_ixs, row_weights, item_factors, cand_ids, k
+        row_ixs, row_weights, item_factors, cand_ids, k, rules
     ), len(cand_ids))
 
 
@@ -869,10 +879,15 @@ def rescore_host(query_vectors, values, scales, cand_ids, k: int):
 # scores (``coarse_vectors``), its exact program and its rescore
 # program (``rescore``: enqueued behind the scan on the scan's device
 # ids, at their power-of-two rows). Every form leads with its [B, ...]
-# per-query array, carries ``rules`` (None unless the coarse pass
-# applies any) and ``exact_only``, and ``head()`` is its first query
-# alone: what the recall probe scores, in the shapes that query would
-# have arriving alone.
+# per-query array and carries ``rules`` (None where the form has none;
+# ``SumRows`` always has), and ``head()`` is its first query alone: what the
+# recall probe scores, in the shapes that query would have arriving
+# alone.
+
+
+def _head_rules(r: Rules | None) -> Rules | None:
+    """``r`` with the first query's rows only."""
+    return r and r._replace(qcat=r.qcat[:1], has_cat=r.has_cat[:1], ex=r.ex[:1])
 
 
 class UserRows(NamedTuple):
@@ -883,7 +898,6 @@ class UserRows(NamedTuple):
     users: object  # the device-resident user table
     vectors: Callable  # ixs -> their [B, D] f32 rows, on the host
     rules = None
-    exact_only = False
 
     def coarse_vectors(self):
         return self.vectors(self.ixs)
@@ -905,7 +919,6 @@ class Vectors(NamedTuple):
 
     vectors: np.ndarray
     rules: Rules | None = None
-    exact_only = False
 
     def coarse_vectors(self):
         return self.vectors
@@ -919,44 +932,39 @@ class Vectors(NamedTuple):
         return _launch_vectors(self.vectors, table, cand, k, self.rules)
 
     def head(self):
-        r = self.rules
-        return Vectors(self.vectors[:1], r and r._replace(
-            qcat=r.qcat[:1], has_cat=r.has_cat[:1], ex=r.ex[:1]
-        ))
+        return Vectors(self.vectors[:1], _head_rules(self.rules))
 
 
 class SumRows(NamedTuple):
-    """Weighted sums of catalog rows, [B, L] indices and weights
-    (``sum_rows_top_k_batch``). A dense ``exclude_mask`` can rule out
-    most of the catalog, so no shortlist is sized for it: such a query
-    is scored exactly whatever the catalog's size."""
+    """Weighted sums of catalog rows, [B, L] indices and weights, under
+    ``device_rules`` (``sum_rows_top_k_batch_masked``; the batch padded
+    as for ``Vectors``): a query's own rows, its blackList and its
+    categories are rules like any other, applied inside the scan and the
+    rescore, and every query of these templates has the first."""
 
     ixs: np.ndarray
     weights: np.ndarray
     vectors: Callable  # (ixs, weights) -> the [B, D] f32 sums, on the host
-    exclude_mask: object = None
-    rules = None
-
-    @property
-    def exact_only(self) -> bool:
-        return self.exclude_mask is not None
+    rules: Rules
 
     def coarse_vectors(self):
         return self.vectors(self.ixs, self.weights)
 
     def exact(self, table, k: int):
-        if self.exclude_mask is None:
-            return sum_rows_top_k_batch(self.ixs, self.weights, table, k=k)
-        return sum_rows_top_k_batch(
-            self.ixs, self.weights, table, k=k,
-            exclude_mask=self.exclude_mask,
+        return sum_rows_top_k_batch_masked(
+            self.ixs, self.weights, table, self.rules, k=k
         )
 
     def rescore(self, table, cand, k: int):
-        return _launch_sum_rows(self.ixs, self.weights, table, cand, k)
+        return _launch_sum_rows(
+            self.ixs, self.weights, table, cand, k, self.rules
+        )
 
     def head(self):
-        return self._replace(ixs=self.ixs[:1], weights=self.weights[:1])
+        return self._replace(
+            ixs=self.ixs[:1], weights=self.weights[:1],
+            rules=_head_rules(self.rules),
+        )
 
 
 def top_k(query, table, num_rows: int, coarse, k: int,
@@ -973,11 +981,14 @@ def top_k(query, table, num_rows: int, coarse, k: int,
     brings the answer back — and on every
     ``PIO_RETRIEVAL_PROBE_EVERY``-th such dispatch the exact program
     again on the first query, whose leading ``probe_n`` ids (the ones
-    its answer is cut from; all k by default) are compared."""
-    kp = 0 if query.exact_only else two_stage_k(k, num_rows)
+    its answer is cut from; all k by default) are compared. No filter
+    keeps a query from the shortlist: ``path="exact"`` counts the
+    queries of a catalog at retrieval scale whose k leaves a shortlist
+    no room."""
+    kp = two_stage_k(k, num_rows)
     if not kp:
-        if query.exact_only and engaged(num_rows):
-            _m_exact.inc(len(query[0]))  # kept from the shortlist by a filter
+        if engaged(num_rows):
+            _m_exact.inc(len(query[0]))
         s, ids = query.exact(table, k)
         return np.asarray(s), np.asarray(ids)
     if callable(coarse):

@@ -121,32 +121,34 @@ def rows_allowed(av, cs, hit, qcat, has_cat):
     return (av != 0) & ~hit & (in_cat | ~has_cat[:, None])
 
 
-@obs_device.track_jit("topk.top_k_items_batch_masked")
-@functools.partial(jax.jit, static_argnames=("k",))
-def top_k_items_batch_masked(user_vectors, item_factors, rules: Rules, k: int):
-    """``top_k_items_batch`` under ``Rules``: the mask is built on the
-    device from the resident vectors and the queries' index lists and
-    applied before the top-k, so k carries no headroom for exclusions.
-    A query with fewer than k allowed rows reports id -1 in the slots it
-    cannot fill. The products are f32 on every backend
+def _f32_scores(query_vectors, item_factors):
+    """[B, I] f32 products of [B, D] query vectors with every catalog
+    row (dense, or the int8 pair), f32 on every backend
     (``precision=HIGHEST``, as ops/als.py's solves: a TPU's default
     rounds f32 operands to bf16, 1.4e-2 off on unit-variance scores)."""
     hi = jax.lax.Precision.HIGHEST
     if isinstance(item_factors, tuple):
         q, s = item_factors
-        scores = (
+        return (
             jnp.matmul(
-                user_vectors.astype(jnp.float32), q.T.astype(jnp.float32),
+                query_vectors.astype(jnp.float32), q.T.astype(jnp.float32),
                 precision=hi, preferred_element_type=jnp.float32,
             )
             * s[None, :]
-        )  # [B, I]
-    else:
-        scores = jnp.matmul(
-            user_vectors.astype(jnp.float32),
-            item_factors.astype(jnp.float32).T,
-            precision=hi, preferred_element_type=jnp.float32,
-        )  # [B, I]
+        )
+    return jnp.matmul(
+        query_vectors.astype(jnp.float32),
+        item_factors.astype(jnp.float32).T,
+        precision=hi, preferred_element_type=jnp.float32,
+    )
+
+
+def _top_k_allowed(scores, rules: Rules, k: int):
+    """Top-k of [B, I] ``scores`` over the rows ``rules`` allow each
+    query: the mask is built on the device from the resident vectors
+    and the queries' index lists and applied before the top-k, so k
+    carries no headroom for exclusions. A query with fewer than k
+    allowed rows reports id -1 in the slots it cannot fill."""
     B, n = scores.shape
     ex = jnp.where(rules.ex >= 0, rules.ex, n)  # pads fall off the end
     hit = jnp.zeros((B, n), bool).at[
@@ -158,6 +160,14 @@ def top_k_items_batch_masked(user_vectors, item_factors, rules: Rules, k: int):
     )
     s, ids = jax.lax.top_k(jnp.where(ok, scores, NEG_INF), min(k, n))
     return s, jnp.where(s > NEG_INF / 2, ids, -1)
+
+
+@obs_device.track_jit("topk.top_k_items_batch_masked")
+@functools.partial(jax.jit, static_argnames=("k",))
+def top_k_items_batch_masked(user_vectors, item_factors, rules: Rules, k: int):
+    """``top_k_items_batch`` under ``Rules`` (``_top_k_allowed``), its
+    products f32 on every backend (``_f32_scores``)."""
+    return _top_k_allowed(_f32_scores(user_vectors, item_factors), rules, k)
 
 
 @obs_device.track_jit("topk.gather_top_k_batch")
@@ -205,13 +215,15 @@ def gather_top_k_batch(user_ixs, user_factors, item_factors, k: int,
     return jax.lax.top_k(scores, k)
 
 
-@obs_device.track_jit("topk.sum_rows_top_k_batch")
+@obs_device.track_jit("topk.sum_rows_top_k_batch_masked")
 @functools.partial(jax.jit, static_argnames=("k",))
-def sum_rows_top_k_batch(row_ixs, row_weights, item_factors, k: int,
-                         exclude_mask=None):
-    """Fused multi-row gather-sum + batched top-k for the cosine-family
-    templates (similarproduct, recommendeduser), whose query vector is
-    the SUM of several catalog rows.
+def sum_rows_top_k_batch_masked(row_ixs, row_weights, item_factors,
+                                rules: Rules, k: int):
+    """Fused multi-row gather-sum + batched top-k under ``Rules`` for
+    the cosine-family templates (similarproduct, recommendeduser), whose
+    query vector is the SUM of several catalog rows and whose every
+    query excludes at least its own rows. What their recall probe and
+    their catalogs under the retrieval threshold are scored by.
 
     ``row_ixs``: [B, L] int32 rows of ``item_factors`` (dense [I, D]
     row-normalized array, or the int8 (values, scales) pair whose
@@ -222,29 +234,17 @@ def sum_rows_top_k_batch(row_ixs, row_weights, item_factors, k: int,
     ``row_weights``: [B, L] f32, 1.0 for real rows and 0.0 for padding
     (adding an exactly-zero vector never perturbs the f32 sum, so rows
     are bitwise-invariant across padded widths).
-    ``exclude_mask``: optional [I] mask shared by the batch — the
-    complex-filter path calls this with B == 1 and its query's own mask.
-    Returns ([B, k] scores, [B, k] ids)."""
+    The products are f32 on every backend (``_f32_scores``), the rules
+    applied before the top-k (``_top_k_allowed``). Returns ([B, k]
+    scores, [B, k] ids, -1 where fewer than k rows are allowed)."""
     ixs = row_ixs.astype(jnp.int32)
     if isinstance(item_factors, tuple):
         vq, vs = item_factors
         rows = vq[ixs].astype(jnp.float32) * vs[ixs][..., None]  # [B, L, D]
-        qvecs = jnp.sum(rows * row_weights[..., None], axis=1)  # [B, D]
-        scores = (
-            jnp.matmul(
-                qvecs, vq.T.astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
-            * vs[None, :]
-        )
     else:
-        V = item_factors
-        qvecs = jnp.sum(V[ixs] * row_weights[..., None], axis=1)  # [B, D]
-        scores = jnp.matmul(qvecs, V.T, preferred_element_type=jnp.float32)
-    if exclude_mask is not None:
-        scores = jnp.where(exclude_mask.astype(bool)[None, :], NEG_INF, scores)
-    k = min(k, catalog_rows(item_factors))
-    return jax.lax.top_k(scores, k)
+        rows = item_factors[ixs].astype(jnp.float32)
+    qvecs = jnp.sum(rows * row_weights[..., None], axis=1)  # [B, D]
+    return _top_k_allowed(_f32_scores(qvecs, item_factors), rules, k)
 
 
 @obs_device.track_jit("topk.ranking_metrics_batch")

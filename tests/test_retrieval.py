@@ -20,8 +20,9 @@ from predictionio_tpu.ops.als import quantize_rows
 from predictionio_tpu.ops.retrieval import CoarseCatalog
 from predictionio_tpu.ops.topk import (
     catalog_norms,
+    Rules,
     gather_top_k_batch,
-    sum_rows_top_k_batch,
+    sum_rows_top_k_batch_masked,
     top_k_similar,
 )
 
@@ -35,6 +36,19 @@ def _int8(i, d, seed=0):
     f = _dense(i, d, seed)
     q, s = quantize_rows(f)
     return np.asarray(q), np.asarray(s)
+
+
+def _open_rules(stored, b):
+    """Rules over ``stored`` rows that rule nothing out for ``b``
+    queries: what a sum-of-rows call carries where the test is about
+    something else (the form has no rule-less program)."""
+    import jax.numpy as jnp
+
+    return retrieval.device_rules(Rules(
+        avail=jnp.ones(stored, jnp.uint8), cats=(),
+        qcat=np.full((b, 1), -2, np.int32), has_cat=np.zeros(b, bool),
+        ex=np.full((b, 1), -1, np.int32),
+    ))
 
 
 def _exact_top(q, v, scales, k):
@@ -135,9 +149,12 @@ class TestRescoreExactness:
         table = _int8(200, 8, seed=10)
         ixs = np.array([[0, 3, 7, 0], [5, 5, 9, 0]], np.int32)
         w = np.array([[1, 1, 1, 0], [1, 0.5, 1, 0]], np.float32)
-        es, ei = sum_rows_top_k_batch(ixs, w, table, k=8)
+        rules = _open_rules(200, 2)
+        es, ei = sum_rows_top_k_batch_masked(ixs, w, table, rules, k=8)
         cand = np.tile(np.arange(200, dtype=np.int32), (2, 1))
-        s, ids = retrieval.rescore_sum_rows_top_k_batch(ixs, w, table, cand, k=8)
+        s, ids = retrieval.rescore_sum_rows_top_k_batch(
+            ixs, w, table, cand, k=8, rules=rules
+        )
         np.testing.assert_array_equal(ids, np.asarray(ei))
         np.testing.assert_allclose(s, np.asarray(es), rtol=1e-5, atol=1e-6)
 
@@ -258,7 +275,8 @@ class TestResidentTables:
             w[:, -1] = 0.0  # a padding row
             qvecs = np.sum(rows[ixs] * w[..., None], axis=1)
             s, ids = retrieval.rescore_sum_rows_top_k_batch(
-                ixs, w, table, cand, k=self.K
+                ixs, w, table, cand, k=self.K,
+                rules=_open_rules(self.ROWS, b),
             )
         want_s, want_ids = _numpy_rescore(qvecs, rows, cand, self.K)
         np.testing.assert_array_equal(ids, want_ids)
@@ -284,14 +302,14 @@ class TestResidentTables:
         retrieval.rescore_top_k_batch(host[:1], table, cand, k=self.K)
         retrieval.rescore_sum_rows_top_k_batch(
             np.zeros((1, 2), np.int32), np.ones((1, 2), np.float32),
-            table, cand, k=self.K,
+            table, cand, k=self.K, rules=_open_rules(len(host), 1),
         )
         temp = retrieval.stats_block()["rescore_temp_bytes"]
         # (another test file on this worker may have compiled the masked
-        # program too: it reports like the three called here)
+        # vectors program too: it reports like the three called here)
         assert {
             "retrieval.rescore_gather", "retrieval.rescore_vectors",
-            "retrieval.rescore_sum_rows",
+            "retrieval.rescore_sum_rows_masked",
         } <= set(temp) <= {p.name for p in retrieval._RESCORE_PROGRAMS}
         scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
         for fn, n in temp.items():
@@ -307,8 +325,9 @@ class TestSatelliteOps:
         dense = vq.astype(np.float32) * vs[:, None]
         ixs = np.array([[0, 5], [9, 9]], np.int32)
         w = np.ones((2, 2), np.float32)
-        ds, di = sum_rows_top_k_batch(ixs, w, dense, k=8)
-        qs, qi = sum_rows_top_k_batch(ixs, w, (vq, vs), k=8)
+        rules = _open_rules(96, 2)
+        ds, di = sum_rows_top_k_batch_masked(ixs, w, dense, rules, k=8)
+        qs, qi = sum_rows_top_k_batch_masked(ixs, w, (vq, vs), rules, k=8)
         np.testing.assert_array_equal(np.asarray(qi), np.asarray(di))
         np.testing.assert_allclose(
             np.asarray(qs), np.asarray(ds), rtol=1e-5, atol=1e-6
@@ -934,14 +953,17 @@ class TestServingChain:
         if form == "sum_rows":
             ixs = np.asarray([[5, 9], [2, 0], [7, 7], [40, 41]], np.int32)
             weights = np.asarray([[1, 1], [1, 0], [1, 1], [1, 1]], np.float32)
+            rules = _open_rules(stored, self.B)
             return (
                 retrieval.SumRows(
                     ixs, weights,
                     lambda i, w: (host[i] * w[..., None]).sum(axis=1),
+                    rules,
                 ),
-                lambda: sum_rows_top_k_batch(ixs, weights, table, k=self.K),
+                lambda: sum_rows_top_k_batch_masked(
+                    ixs, weights, table, rules, k=self.K),
                 lambda cand: retrieval.rescore_sum_rows_top_k_batch(
-                    ixs, weights, table, cand, k=self.K),
+                    ixs, weights, table, cand, k=self.K, rules=rules),
             )
         v = _dense(self.B, self.D, seed=43)
         if form == "vectors":
@@ -1054,35 +1076,55 @@ class TestServingChain:
     @pytest.mark.parametrize(
         "engaged", [False, True], ids=["below_threshold", "engaged"]
     )
-    def test_a_dense_mask_is_scored_exactly_and_counted(
+    def test_a_filter_that_rules_out_most_of_the_catalog_is_rules(
         self, monkeypatch, engaged
     ):
-        """A query whose filter can rule out most of the catalog never
-        shortlists; at retrieval scale it counts as ``path="exact"``."""
+        """What the dense ``exclude_mask`` of a sum-of-rows query was
+        (PR 30 removed it with ``exact_only``): a filter that leaves
+        three rows travels as ``Rules``, shortlists at retrieval scale
+        like any query and is answered by the masked exact program below
+        it; ``path="exact"`` counts only a k that leaves a shortlist no
+        room."""
         import jax.numpy as jnp
+
+        from predictionio_tpu.ops.topk import sum_rows_top_k_batch_masked
 
         monkeypatch.setenv(
             "PIO_RETRIEVAL_THRESHOLD", "64" if engaged else "100000"
         )
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "128")
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "0")
         host = _dense(self.I, self.D, seed=44)
         table = jnp.asarray(host)
-        mask = np.ones(self.I, bool)
-        mask[[4, 17, 300]] = False
-        ixs, weights = np.asarray([[5]], np.int32), np.ones((1, 1), np.float32)
-        before = retrieval.stats_block()
-        s, ids = retrieval.top_k(
-            retrieval.SumRows(ixs, weights, None, jnp.asarray(mask)), table,
-            self.I, lambda: pytest.fail("no coarse copy is needed"), self.K,
+        coarse = CoarseCatalog(table)
+        stored = coarse.stored_rows if engaged else self.I
+        rules = _rules(
+            stored, 1, small_cat=[4, 17, 300], qcat=np.asarray([[1]], np.int32)
         )
+        ixs, weights = np.asarray([[5]], np.int32), np.ones((1, 1), np.float32)
+        query = retrieval.SumRows(
+            ixs, weights, lambda i, w: (host[i] * w[..., None]).sum(axis=1),
+            rules,
+        )
+        before = retrieval.stats_block()
+        s, ids = retrieval.top_k(query, table, self.I, coarse, self.K)
         after = retrieval.stats_block()
-        es, ei = sum_rows_top_k_batch(
-            ixs, weights, table, k=self.K, exclude_mask=jnp.asarray(mask)
+        es, ei = sum_rows_top_k_batch_masked(
+            ixs, weights, table, rules, k=self.K
         )
         np.testing.assert_array_equal(ids, np.asarray(ei))
-        np.testing.assert_array_equal(s, np.asarray(es))
-        assert set(ids[0][s[0] > -1e29].tolist()) == {4, 17, 300}
-        assert after["exact_queries"] == before["exact_queries"] + int(engaged)
-        assert after["two_stage_queries"] == before["two_stage_queries"]
+        np.testing.assert_allclose(s, np.asarray(es), atol=2e-6, rtol=0)
+        assert set(ids[0][ids[0] >= 0].tolist()) == {4, 17, 300}
+        assert after["exact_queries"] == before["exact_queries"]
+        assert after["two_stage_queries"] == (
+            before["two_stage_queries"] + int(engaged)
+        )
+        # a k the shortlist cannot oversample: the exact program, counted
+        s, ids = retrieval.top_k(query, table, self.I, coarse, 256)
+        assert set(ids[0][ids[0] >= 0].tolist()) == {4, 17, 300}
+        assert retrieval.stats_block()["exact_queries"] == (
+            after["exact_queries"] + int(engaged)
+        )
 
 
 class TestOneCrossing:
@@ -1125,16 +1167,21 @@ class TestOneCrossing:
                     ixs, U, table, cand, k=self.K),
             )
         if form == "sum_rows":
-            ixs = rng.integers(0, len(host), (b, 2)).astype(np.int32)
-            weights = np.ones((b, 2), np.float32)
+            # always under rules, so padded to the power of two like
+            # ``vectors_rules`` below (the cosine templates do)
+            bp = retrieval._pow2(b)
+            ixs = rng.integers(0, len(host), (bp, 2)).astype(np.int32)
+            weights = np.ones((bp, 2), np.float32)
             weights[::3, 1] = 0.0
+            rules = _open_rules(stored, bp)
             return (
                 retrieval.SumRows(
                     ixs, weights,
                     lambda i, w: (host[i] * w[..., None]).sum(axis=1),
+                    rules,
                 ),
                 lambda cand: retrieval.rescore_sum_rows_top_k_batch(
-                    ixs, weights, table, cand, k=self.K),
+                    ixs, weights, table, cand, k=self.K, rules=rules),
             )
         if form == "vectors":
             v = _dense(b, d, seed=seed + b)
@@ -1225,14 +1272,17 @@ class TestOneCrossing:
         programs = {p.name: p for p in retrieval._RESCORE_PROGRAMS}
         for form, name in (("user_rows", "retrieval.rescore_gather"),
                            ("vectors", "retrieval.rescore_vectors"),
-                           ("sum_rows", "retrieval.rescore_sum_rows")):
+                           ("sum_rows", "retrieval.rescore_sum_rows_masked")):
             program = programs[name]
             for want in (5, 0):
                 before = program._cache_size()
                 for b in range(1, 17):
-                    query, _ = self._form(form, b, table, host, 0, seed=62)
+                    query, _ = self._form(
+                        form, b, table, host, coarse.stored_rows, seed=62
+                    )
                     s, ids = retrieval.top_k(query, table, 777, coarse, self.K)
-                    assert ids.shape == (b, self.K) and (ids >= 0).all()
+                    assert ids.shape == (len(query[0]), self.K)
+                    assert (ids >= 0).all()
                 assert program._cache_size() - before == want, (name, want)
 
     @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
