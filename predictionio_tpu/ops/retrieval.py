@@ -24,7 +24,15 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    groups, and the k' best of those groups' scores — exactly the
    tile's top-k', from selections over T/G + k'G elements instead of T
    (``tile_select_group`` decides from T and k' alone; every mode and
-   the masked scan share ``_tile_top_k``).
+   the masked scan share ``_tile_top_k``). And a step scores its tile
+   in one of two forms (``score_form`` decides from B and D alone):
+   the batch's f32 rows against the tile cast to f32 ("rows": two or
+   more queries, or D >= 128), or — ONE query whose rank leaves the
+   128 lanes unfilled — the query split into three bf16 rows whose
+   sum it is, one dot against the tile as stored, the three partial
+   scores added back ("dot": the same f32 product to summation
+   order, through the form of it that the chip reads at the memory's
+   speed). Everything after the score is one row either way.
 2. **Exact rescore** — gather the [B, S] shortlisted rows and rescore
    them in f32 through shortlist-gather variants of the fused ops
    (``rescore_*_top_k_batch`` below). The rescore builds its query
@@ -81,7 +89,9 @@ next program is compiled for a layout the buffer does not have.)
 
 Observability: ``pio_retrieval_*`` metrics (docs/observability.md);
 ``pio_retrieval_tile_select_total{path}`` says which selection a
-shortlist call's program holds (``two_level`` / ``plain``); each
+shortlist call's program holds (``two_level`` / ``plain``) and
+``pio_retrieval_score_form_total{form}`` which score (``dot`` /
+``rows``); each
 rescore program publishes the temporary bytes its compiled form needs
 (``pio_retrieval_rescore_temp_bytes``: a table-sized number means a
 re-layout came back); the
@@ -224,6 +234,17 @@ _m_tile_select = {
     for path in ("two_level", "plain")
 }
 
+_m_score_form = {
+    form: obs_metrics.counter(
+        "pio_retrieval_score_form_total",
+        "shortlist calls by how a scan step scores its tile: dot = a "
+        "single query under 128 columns, as three bf16 rows through one "
+        "dot; rows = the batch's rows as they are",
+        form=form,
+    )
+    for form in ("dot", "rows")
+}
+
 _probe_clock = itertools.count(1)
 
 
@@ -261,6 +282,7 @@ def stats_block() -> dict:
         "fetch_seconds": _m_fetch_secs.summary(),
         "host_reads": _m_host_reads.value(),
         "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
+        "score_form": {f: m.value() for f, m in _m_score_form.items()},
         "rescore_temp_bytes": {
             p.name: p.temp_bytes() for p in _RESCORE_PROGRAMS
             if p._cache_size()
@@ -340,6 +362,56 @@ def _tile_top_k(sc, k: int):
     return _two_level_top_k(sc, k, g)
 
 
+# One query against a [T, D] tile: XLA:TPU turns the one-row product
+# into a multiply-and-reduce fusion that costs 92 us a 2^18-row tile at
+# rank 64 and at rank 128 alike (a resident tile lies feature-major, T in
+# the lanes, nothing padded: the fusion is bound by its own arithmetic),
+# which is 91 % of the memory's speed at rank 128 and 44 % at rank 64. A
+# dot reads the rank-64 tile in 46 us, and XLA makes a dot of two rows or
+# more (PERF.md section 6, PR 27 and PR 31). So a single's f32 query goes
+# through a dot as three bf16 rows whose sum it is, and the three [T]
+# partial scores are added back into the one row everything after the
+# score works on. At D >= 128 the dot wins nothing (within 1 %), and a
+# batch is a dot as it stands — of queries XLA rounds to ONE bf16 term
+# at default precision, which the single's three terms do not copy.
+
+
+def score_form(b: int, d: int, mode: str = "bf16") -> str:
+    """How a scan step scores its tile for ``b`` f32 queries of rank
+    ``d``: "dot" — the single query split into three bf16 rows
+    (``_split_bf16``), one ``dot_general`` against the tile as stored,
+    the three partial scores summed — where b == 1 and d < 128; "rows"
+    — the batch's rows against the tile cast to f32, the program every
+    other shape has always had (and mode ``int8_dot``, which has no f32
+    query to split). Decided from the two shapes alone: at trace time,
+    and on the host for the counter."""
+    if mode == "int8_dot" or b > 1 or d >= _LANES:
+        return "rows"
+    return "dot"
+
+
+def _split_bf16(q):
+    """f32 [B, D] -> bf16 [3B, D]: the rows ``hi = bf16(q)``, ``mid =
+    bf16(q - hi)``, ``lo = q - hi - mid``, smallest term LAST. Both
+    differences are exact in f32 and a 24-bit significand is three of
+    8, so ``lo`` is a bf16 number too and ``hi + mid + lo == q`` bit
+    for bit (down to |q| ~ 2^-103, under which the last term would be
+    subnormal and is worth under 2^-126): a bf16 x bf16 product is
+    exact in f32, and the three partial dots sum to the f32 product up
+    to the order of the additions. The rounding is ``reduce_precision``
+    and not a cast to bf16 and back: XLA:TPU computes such a pair of
+    casts inside one fusion in f32 (excess precision), ``q - hi`` comes
+    out 0 and the query is silently ONE bf16 term (7.7e-2 off on scores
+    of ~40: my chip run, PR 31)."""
+    def rounded(x):  # to bf16's 8 significant bits, still f32
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+    hi = rounded(q)
+    mid = rounded(q - hi)
+    lo = q - hi - mid
+    return jnp.concatenate([hi, mid, lo]).astype(jnp.bfloat16)
+
+
 def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
     tile scores [B, T] in the catalog's storage precision, takes the
@@ -359,6 +431,7 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
     the scan slices like the tiles. Without ``rules`` the program is the
     one it was before rules existed, op for op."""
     B = q.shape[0]
+    dot = score_form(B, q.shape[1], mode) == "dot"
     if rules is not None:
         nt, t = ids.shape
         with jax.named_scope("retrieval.shortlist.mask"):
@@ -376,6 +449,8 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
         qi = jnp.clip(
             jnp.round(q / jnp.maximum(qs, 1e-12)), -127, 127
         ).astype(jnp.int8)
+    elif dot:
+        q3 = _split_bf16(q)
 
     def step(carry, xs):
         best_s, best_i = carry
@@ -394,10 +469,18 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
                     preferred_element_type=jnp.int32,
                 ).astype(jnp.float32) * s[None, :]
             else:
-                sc = jnp.matmul(
-                    q, v.T.astype(jnp.float32),
-                    preferred_element_type=jnp.float32,
-                )
+                if dot:
+                    # int8 values are whole numbers under 2^7: exact in bf16
+                    p = jax.lax.dot_general(
+                        q3, v.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    sc = (p[2:3] + p[1:2]) + p[:1]
+                else:
+                    sc = jnp.matmul(
+                        q, v.T.astype(jnp.float32),
+                        preferred_element_type=jnp.float32,
+                    )
                 if scales is not None:
                     sc = sc * s[None, :]
             sc = jnp.where(tid[None, :] >= 0, sc, NEG_INF)
@@ -596,6 +679,7 @@ class CoarseCatalog:
         _m_tile_select[
             "two_level" if tile_select_group(self.tile, k) else "plain"
         ].inc()
+        _m_score_form[score_form(len(q), self.dim, self.mode)].inc()
         return Scan(q, s, ids)
 
     def shortlist(self, queries, k: int, rules: Rules | None = None):

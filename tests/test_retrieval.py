@@ -653,38 +653,45 @@ class TestStageSpans:
 # -- the tile's top-k' in two exact levels ------------------------------------
 
 
-def _top_k_eqns(jaxpr):
-    """Operand shapes of every ``top_k`` in ``jaxpr``, sub-jaxprs (the
-    scan's body) included."""
-    shapes = []
+def _operands(jaxpr, primitive):
+    """The first operand's aval of every ``primitive`` equation in
+    ``jaxpr``, sub-jaxprs (the scan's body) included."""
+    avals = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "top_k":
-            shapes.append(tuple(eqn.invars[0].aval.shape))
+        if eqn.primitive.name == primitive:
+            avals.append(eqn.invars[0].aval)
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    shapes.extend(_top_k_eqns(inner))
-    return shapes
+                    avals.extend(_operands(inner, primitive))
+    return avals
 
 
-def _scan_args(b, nt, t, d):
+def _top_k_eqns(jaxpr):
+    """Operand shapes of every ``top_k`` in ``jaxpr``."""
+    return [tuple(a.shape) for a in _operands(jaxpr, "top_k")]
+
+
+def _scan_args(b, nt, t, d, mode="bf16"):
     import jax.numpy as jnp
 
     return (
-        jnp.ones((b, d), jnp.float32), jnp.ones((nt, t, d), jnp.bfloat16),
+        jnp.ones((b, d), jnp.float32),
+        jnp.ones((nt, t, d), jnp.bfloat16 if mode == "bf16" else jnp.int8),
+        None if mode == "bf16" else jnp.ones((nt, t), jnp.float32),
         jnp.arange(nt * t, dtype=jnp.int32).reshape(nt, t),
     )
 
 
-def _scan_jaxpr(b, nt, t, d, k, rules=None):
+def _scan_jaxpr(b, nt, t, d, k, rules=None, mode="bf16"):
     import jax
 
     return jax.make_jaxpr(
-        lambda q, tiles, ids: retrieval._coarse_scan(
-            q, tiles, None, ids, k, "bf16", rules
+        lambda q, tiles, scales, ids: retrieval._coarse_scan(
+            q, tiles, scales, ids, k, mode, rules
         )
-    )(*_scan_args(b, nt, t, d)).jaxpr
+    )(*_scan_args(b, nt, t, d, mode)).jaxpr
 
 
 def _tie_rows(kind, b, t, k, rng):
@@ -804,11 +811,14 @@ class TestTileSelect:
             (b, 2 * k), (b, t)
         ]
 
-    def test_the_programs_keep_their_names(self):
+    @pytest.mark.parametrize("b", [1, 2])
+    def test_the_programs_keep_their_names(self, b):
         """The benchmark's roofline readers find the two programs in a
-        device trace as ``jit__coarse_topk`` / ``jit__coarse_topk_masked``."""
-        b, nt, t, d, k = 2, 2, 1 << 13, 8, 16
-        q, tiles, ids = _scan_args(b, nt, t, d)
+        device trace as ``jit__coarse_topk`` / ``jit__coarse_topk_masked``,
+        the single's (whose score is the three-row dot) like the batch's."""
+        nt, t, d, k = 2, 1 << 13, 8, 16
+        assert retrieval.score_form(b, d) == ("dot" if b == 1 else "rows")
+        q, tiles, _, ids = _scan_args(b, nt, t, d)
         text = retrieval._coarse_topk.lower(
             q, tiles, None, ids, k=k, mode="bf16"
         ).as_text()
@@ -920,6 +930,202 @@ class TestTwoLevelShortlist:
         for path, n in after.items():
             assert scraped[
                 f'pio_retrieval_tile_select_total{{path="{path}"}}'
+            ] == n
+
+
+# -- a single's score through the batched scans' dot ---------------------------
+
+
+def _split_rows(kind, d=64):
+    rng = np.random.default_rng(len(kind))
+    q = rng.normal(size=(1, d)).astype(np.float32)
+    if kind == "tiny":
+        q *= np.float32(1e-24)  # the smallest term stays a normal number
+    elif kind == "large":
+        q *= np.float32(3e30)
+    elif kind == "negative":
+        q = -np.abs(q)
+    elif kind == "zero":
+        q[:] = 0.0
+    elif kind == "mixed":
+        q[0, ::3] = 0.0
+        q[0, 1::3] *= np.float32(1e-12)
+        q[0, 2::3] *= np.float32(-4e6)
+    return q
+
+
+class TestScoreForm:
+    """One f32 query under 128 columns is scored as three bf16 rows
+    through one dot (``score_form`` -> "dot"); every other shape keeps
+    the program it had. The split is exact, so the shortlist is the
+    selection it was."""
+
+    I, D, T, K = 40_000, 64, 1 << 14, 64
+
+    @pytest.mark.parametrize("d", [8, 64, 127, 128, 256])
+    @pytest.mark.parametrize("b", [1, 2, 16])
+    def test_shape_rule(self, b, d):
+        want = "dot" if b == 1 and d < 128 else "rows"
+        assert retrieval.score_form(b, d) == want
+
+    @pytest.mark.parametrize(
+        "kind", ["normal", "tiny", "large", "negative", "zero", "mixed"]
+    )
+    def test_the_three_terms_sum_back_bit_for_bit(self, kind):
+        import jax.numpy as jnp
+
+        q = _split_rows(kind)
+        terms = retrieval._split_bf16(jnp.asarray(q))
+        assert terms.dtype == jnp.bfloat16 and terms.shape == (3, q.shape[1])
+        hi, mid, lo = np.asarray(terms.astype(jnp.float32))
+        # smallest term first, in f32: every partial sum is representable
+        back = (lo + mid) + hi
+        np.testing.assert_array_equal(back.view(np.uint32),
+                                      q[0].view(np.uint32))
+        np.testing.assert_array_equal(
+            hi, np.asarray(jnp.asarray(q[0]).astype(jnp.bfloat16)
+                           .astype(jnp.float32))
+        )
+        assert (np.abs(mid) <= np.abs(hi)).all()
+        assert (np.abs(lo) <= np.abs(mid)).all()
+
+    def test_the_split_rounds_by_reduce_precision_not_by_a_cast_and_back(self):
+        """XLA:TPU computes ``q.astype(bf16).astype(f32)`` inside a
+        fusion in f32, so ``q - hi`` would be 0 and the query ONE bf16
+        term (seen on the chip, PR 31; XLA:CPU does not show it): the
+        only cast is the last one, of terms that are bf16 numbers."""
+        import jax
+        import jax.numpy as jnp
+
+        jaxpr = jax.make_jaxpr(retrieval._split_bf16)(
+            jnp.ones((1, 64), jnp.float32)
+        ).jaxpr
+        names = [e.primitive.name for e in jaxpr.eqns]
+        assert names.count("reduce_precision") == 2
+        assert names.count("convert_element_type") == 1
+        assert names[-1] == "convert_element_type"
+        q = _split_rows("normal")
+        eager = np.asarray(retrieval._split_bf16(q).astype(jnp.float32))
+        jitted = np.asarray(
+            jax.jit(retrieval._split_bf16)(q).astype(jnp.float32)
+        )
+        np.testing.assert_array_equal(eager, jitted)
+
+    def _allowed(self, sc, masked):
+        allowed = np.ones((1, self.I), bool)
+        if not masked:
+            return None, allowed
+        ex = np.full((1, 4), -1, np.int32)
+        ex[0, :3] = np.argsort(-sc[0])[:3]  # the query's own best
+        allowed[:, ::97] = False
+        allowed[0, ex[0, :3]] = False
+        return ex, allowed
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("mode", ["bf16", "int8"])
+    def test_a_single_is_the_numpy_selection_and_its_row_in_a_pair(
+        self, mode, masked
+    ):
+        assert retrieval.score_form(1, self.D) == "dot"
+        assert retrieval.tile_select_group(self.T, self.K)
+        table = _int8(self.I, self.D, seed=41)
+        q = _dense(1, self.D, seed=42)
+        cat = CoarseCatalog(table, tile=self.T, mode=mode)
+        sc = _coarse_scores(cat, table, q.astype(np.float64))
+        ex, allowed = self._allowed(sc, masked)
+        rules = pair_rules = None
+        if masked:
+            rules = _rules(cat.stored_rows, 1, ex=ex)
+            pair_rules = _rules(cat.stored_rows, 2, ex=np.repeat(ex, 2, 0))
+        s, ids = cat.shortlist(q, self.K, rules)
+        assert s.shape == ids.shape == (1, self.K)
+        want = np.argsort(-np.where(allowed[0], sc[0], -np.inf),
+                          kind="stable")[: self.K]
+        assert set(ids[0].tolist()) == set(want.tolist())
+        np.testing.assert_allclose(
+            s[0], sc[0][ids[0]], rtol=0, atol=2e-6 * np.abs(sc[0]).max()
+        )
+        # the same query as row 0 of a batch of two: the "rows" program
+        assert retrieval.score_form(2, self.D) == "rows"
+        s2, ids2 = cat.shortlist(np.repeat(q, 2, 0), self.K, pair_rules)
+        np.testing.assert_array_equal(ids2[0], ids[0])
+        np.testing.assert_array_equal(ids2[1], ids[0])
+        np.testing.assert_allclose(
+            s2[0], s[0], rtol=0, atol=2e-6 * np.abs(sc[0]).max()
+        )
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("mode", ["bf16", "int8"])
+    def test_the_engaged_scan_is_one_dot_and_the_rest_stays_one_row(
+        self, mode, masked
+    ):
+        """One ``dot_general``, its query operand the three bf16 terms,
+        the tile in no wider dtype than bf16; every ``top_k`` operand —
+        group maxima, candidates, merge — still has ONE row."""
+        import jax.numpy as jnp
+
+        nt, t, d, k = 2, 1 << 13, 64, 16
+        g = retrieval.tile_select_group(t, k)
+        rules = _rules(nt * t, 1) if masked else None
+        jaxpr = _scan_jaxpr(1, nt, t, d, k, rules, mode)
+        (query,) = _operands(jaxpr, "dot_general")
+        assert query.shape[0] >= 3 and query.shape[1] == d
+        assert query.dtype == jnp.bfloat16
+        assert sorted(_top_k_eqns(jaxpr)) == sorted(
+            [(1, t // g), (1, k * g), (1, 2 * k)]
+        )
+
+    @pytest.mark.parametrize("b,d", [(2, 64), (16, 64), (1, 128), (2, 128)])
+    @pytest.mark.parametrize("masked", [False, True])
+    def test_every_other_shape_keeps_its_program(self, b, d, masked):
+        """B >= 2 and D >= 128: the f32 queries as they are against the
+        tile cast to f32, the ``top_k`` shapes of PR 27 (the StableHLO
+        of these programs is the parent's: hashes in CHANGES.md)."""
+        import jax.numpy as jnp
+
+        nt, t, k = 2, 1 << 13, 16
+        assert retrieval.score_form(b, d) == "rows"
+        g = retrieval.tile_select_group(t, k)
+        rules = _rules(nt * t, b) if masked else None
+        jaxpr = _scan_jaxpr(b, nt, t, d, k, rules)
+        (query,) = _operands(jaxpr, "dot_general")
+        assert query.shape == (b, d) and query.dtype == jnp.float32
+        assert sorted(_top_k_eqns(jaxpr)) == sorted(
+            [(b, t // g), (b, k * g), (b, 2 * k)]
+        )
+
+    def test_int8_dot_has_no_f32_query_to_split(self):
+        import jax.numpy as jnp
+
+        assert retrieval.score_form(1, 64, "int8_dot") == "rows"
+        assert retrieval.score_form(1, 64, "int8") == "dot"
+        jaxpr = _scan_jaxpr(1, 2, 256, 64, 16, mode="int8_dot")
+        (query,) = _operands(jaxpr, "dot_general")
+        assert query.shape == (1, 64) and query.dtype == jnp.int8
+
+    @pytest.mark.parametrize("b,d,mode,form", [
+        (1, 64, "bf16", "dot"),
+        (1, 64, "int8", "dot"),
+        (1, 8, "bf16", "dot"),
+        (2, 64, "bf16", "rows"),
+        (3, 64, "int8", "rows"),      # pads to a batch of four
+        (1, 128, "bf16", "rows"),
+        (1, 64, "int8_dot", "rows"),
+    ])
+    def test_the_counter_counts_one_a_call(self, b, d, mode, form):
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        cat = CoarseCatalog(_int8(600, d, seed=43), tile=256, mode=mode)
+        before = retrieval.stats_block()["score_form"]
+        cat.shortlist(_dense(b, d, seed=44), 32)
+        after = retrieval.stats_block()["score_form"]
+        other = "rows" if form == "dot" else "dot"
+        assert after[form] == before[form] + 1
+        assert after[other] == before[other]
+        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
+        for f, n in after.items():
+            assert scraped[
+                f'pio_retrieval_score_form_total{{form="{f}"}}'
             ] == n
 
 

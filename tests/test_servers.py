@@ -1378,6 +1378,15 @@ class TestQueryCacheServing:
         assert set(block) == {"two_level", "plain"}
         assert block == retrieval.stats_block()["tile_select"]
 
+    def test_stats_route_carries_the_score_form_counter(self, deployed_engine):
+        from predictionio_tpu.ops import retrieval
+
+        status, body = http("GET", deployed_engine["base"] + "/stats.json")
+        assert status == 200
+        block = body["retrieval"]["score_form"]
+        assert set(block) == {"dot", "rows"}
+        assert block == retrieval.stats_block()["score_form"]
+
     def test_reload_invalidates(self, cached_engine):
         from predictionio_tpu.core.workflow import run_train
 
