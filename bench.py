@@ -18,7 +18,7 @@ two-line contract. The extras cover the whole story:
   - "bf16_storage": bf16 factor STORAGE (halved HBM gather bytes)
   - "mfu":     achieved FLOP/s and model-FLOPs-utilization of the 20M run
   - "serving": POST /queries.json p50/p99 through a real EngineServer —
-               dense top-k, RingCatalog (mesh-sharded), and the
+               dense top-k, ShardedCatalog (mesh-sharded), and the
                e-commerce live-filter path
   - "e2e":     import -> train through the whole framework (jsonl event
                log, splice import, columnar scan) with peak RSS
@@ -722,7 +722,7 @@ def _http_floor_us(recv_buffer: bool, n: int = 2000) -> float:
 
 def bench_serving(extras: dict) -> None:
     """POST /queries.json p50/p99 through a real EngineServer: dense
-    top-k, RingCatalog sharded serving, and the e-commerce live-filter
+    top-k, ShardedCatalog sharded serving, and the e-commerce live-filter
     path (reference serving bookkeeping: CreateServer.scala:582-590).
     Plus the PR-4 serving fast path: query-cache hit vs miss qps, hit
     rate under a Zipf replay, and the raw HTTP floor before/after the
@@ -957,7 +957,7 @@ def bench_serving(extras: dict) -> None:
         "delta_us": round(floor_rfile - floor_buf, 1),
     }
 
-    # RingCatalog (mesh-resident item factors; 1-chip mesh on this box)
+    # ShardedCatalog (mesh-resident item rows; 1-chip mesh on this box)
     server = train(
         "predictionio_tpu.models.recommendation.engine",
         recommendation.engine(),

@@ -544,6 +544,8 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
             "exact_lists": sum(o == 1.0 for o in overlaps),
             "devices": devices,
             "two_stage_queries": stats["retrieval"]["two_stage_queries"],
+            "sharded_queries": stats["retrieval"].get("sharded_queries", 0),
+            "shards": stats["retrieval"].get("shards", 0),
             "rescore_temp_bytes": temp,
         }
         stop_server(proc, port, name)
@@ -919,13 +921,18 @@ def run(smoke: Smoke, args) -> dict:
     if device["count"] >= 4:
         sharded_leg(smoke, ref, rmse, dense, users, (s_rows, s_cols, s_vals),
                     num_users, num_items)
+    else:
+        print(f"sharded leg: SKIPPED — {device['count']} device(s) visible; "
+              "sharded training and sharded serving need four", flush=True)
     return device
 
 
 def sharded_leg(smoke: Smoke, ref: Reference, rmse: float, dense: dict,
                 users: list[int], sample, num_users: int, num_items: int) -> None:
     """Four devices: the same events through `--mesh data=4` training and
-    ring top-k serving, held to the one-chip run."""
+    sharded serving (item rows stationary on the four devices: the exact
+    program, then two-stage retrieval over the shards with the threshold
+    under the catalog), held to the one-chip run."""
     variant = json.loads(json.dumps(VARIANT))
     variant["id"] = "chip-smoke-sharded"
     variant["algorithms"][0]["params"].update(
@@ -944,7 +951,21 @@ def sharded_leg(smoke: Smoke, ref: Reference, rmse: float, dense: dict,
           f"(half-step mode: {r['mesh'].rsplit(':', 1)[1]})", flush=True)
     if abs(srmse - rmse) > 1e-3:
         raise SmokeFailure(f"sharded RMSE {srmse} is not within 1e-3 of {rmse}")
-    ring = serve_and_check(smoke, "deploy_sharded", "sharded.json", sref, users)
+    ring = serve_and_check(smoke, "deploy_sharded", "sharded.json", sref, users,
+                           PIO_MESH="data=4")
+    staged = serve_and_check(
+        smoke, "deploy_sharded_two_stage", "sharded.json", sref,
+        users[:TWO_STAGE_QUERIES], PIO_MESH="data=4",
+        PIO_RETRIEVAL_THRESHOLD=str(TWO_STAGE_THRESHOLD),
+    )
+    if (ring["shards"] != 4 or staged["shards"] != 4
+            or staged["sharded_queries"] < TWO_STAGE_QUERIES
+            or staged["two_stage_queries"]):
+        raise SmokeFailure(
+            f"deploy_sharded_two_stage: {staged['sharded_queries']} sharded and "
+            f"{staged['two_stage_queries']} one-chip two-stage queries over "
+            f"{staged['shards']} shards: the sharded chain did not engage"
+        )
     # two trainings of the same events: the lists may differ in near-ties
     shared = [
         len(set(ring["lists"][u]) & set(dense["lists"][u])) / TOP_K for u in users

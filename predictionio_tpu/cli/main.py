@@ -823,24 +823,12 @@ def cmd_accesskey(args) -> int:
 
 def _parse_mesh(spec: str | None) -> list[tuple[str, int]] | None:
     """'data=4,model=2' -> [("data", 4), ("model", 2)]."""
-    if not spec:
-        return None
-    axes = []
-    for part in spec.split(","):
-        name, _, size = part.partition("=")
-        try:
-            n = int(size)
-        except ValueError:
-            n = 0
-        if not name or n == 0 or n < -1:
-            raise SystemExit(
-                f"bad --mesh axis {part!r}; expected name=size with a "
-                "positive integer size (or -1 once for the remainder)"
-            )
-        axes.append((name.strip(), n))
-    if sum(1 for _, n in axes if n == -1) > 1:
-        raise SystemExit("--mesh: at most one axis may be -1")
-    return axes
+    from predictionio_tpu.parallel.mesh import parse_axes
+
+    try:
+        return parse_axes(spec)
+    except ValueError as e:
+        raise SystemExit(f"--mesh: {e}") from None
 
 
 def cmd_train(args) -> int:
@@ -1040,6 +1028,9 @@ def cmd_deploy(args) -> int:
     if rc is not None:
         return rc
 
+    if getattr(args, "mesh", None):
+        _parse_mesh(args.mesh)  # refuse a bad spec here, by name
+        os.environ["PIO_MESH"] = args.mesh  # parallel/mesh.py serving_mesh
     engine, variant, factory = _engine_from_args(args)
     storage = get_storage()
     instances = storage.get_metadata_engine_instances()
@@ -1985,6 +1976,11 @@ def build_parser() -> argparse.ArgumentParser:
         "invalidated exactly on every /reload and speed-layer patch via "
         "the epoch fence (0 = disabled); engines opt out per query via "
         "cacheable_query — see docs/serving.md",
+    )
+    d.add_argument(
+        "--mesh", metavar="data=N",
+        help="the devices a `sharded_serving` model splits its item rows "
+        "over (one process owns them all); default: every device visible",
     )
     d.add_argument(
         "--no-warmup", action="store_true",

@@ -38,6 +38,7 @@ __all__ = [
     "RETRAIN",
     "ModelFileError",
     "serialize_models",
+    "save_models",
     "deserialize_models",
     "deserialize_model_path",
 ]
@@ -144,6 +145,31 @@ def serialize_models(
     buf = io.BytesIO()
     pickle.dump(manifests, buf, protocol=4)
     return buf.getvalue()
+
+
+def save_models(
+    model_store: Any,
+    algorithms: Sequence[Any],
+    models: Sequence[Any],
+    model_id: str,
+) -> None:
+    """Persist one engine instance's models into ``model_store``: one
+    blob, or — where an array-table model is larger than a segment and
+    the store keeps local files (``spanning_path``) — a model file that
+    spans files (models/modelfile.py ``write_spanning``), written
+    segment by segment without ever being one bytes object."""
+    spanning_path = getattr(model_store, "spanning_path", None)
+    if spanning_path is not None and modelfile.mmap_enabled():
+        entries = _manifest_entries(algorithms, models, model_id)
+        if any(k == "arrays" and modelfile.spans(m) for k, m in entries):
+            modelfile.write_spanning(spanning_path(model_id), entries, model_id)
+            return
+        blob = modelfile.serialize(entries, model_id)
+    else:
+        blob = serialize_models(algorithms, models, model_id)
+    from predictionio_tpu.data.storage.base import Model
+
+    model_store.insert(Model(model_id, blob))
 
 
 def _resolve_entries(

@@ -30,7 +30,6 @@ from predictionio_tpu.core.engine import (
 from predictionio_tpu.data.storage import (
     EngineInstance,
     EngineInstanceStatus,
-    Model,
     Storage,
     get_storage,
 )
@@ -126,8 +125,9 @@ def run_train(
         else:
             models = engine.train(ctx, engine_params, wp, algorithms=algorithms)
         if wp.save_model and primary:
-            blob = persistence.serialize_models(algorithms, models, instance_id)
-            storage.get_model_data_models().insert(Model(instance_id, blob))
+            persistence.save_models(
+                storage.get_model_data_models(), algorithms, models, instance_id
+            )
         instance.status = EngineInstanceStatus.COMPLETED
         instance.end_time = _now()
         if primary:

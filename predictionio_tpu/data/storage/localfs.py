@@ -56,8 +56,17 @@ class LocalFSModels(base.Models):
         p = self._path(model_id)
         return str(p) if p.exists() else None
 
+    def spanning_path(self, model_id: str) -> str:
+        """Where a model that spans files puts its head file
+        (models/modelfile.py ``write_spanning``: the segments lie beside
+        it under its name + ``.segNNNN``). Only a store of local files
+        has one."""
+        return str(self._path(model_id))
+
     def delete(self, model_id: str) -> bool:
         p = self._path(model_id)
+        for seg in p.parent.glob(p.name + ".seg[0-9]*"):
+            seg.unlink()
         if p.exists():
             p.unlink()
             return True

@@ -23,6 +23,17 @@ defeat the O(pages-touched) load). Truncation is caught unconditionally by
 block bounds checks. Every validation failure raises ``ModelFileError`` —
 never garbage scores.
 
+A model too large for one file SPANS files (``write_spanning``): the
+head file (same magic, ``"version": 2``) holds only the header, which
+names segment files of at most ``SEGMENT_BYTES`` (1 GiB) beside it; a
+block lives in one segment, and an array larger than a segment is cut
+row-wise into several blocks and comes back as a ``SpannedArray`` — its
+parts mmap'd where they lie, never one host array. Segments are written
+and checksummed part by part, by a few threads, from arrays or from row
+sources that produce a range of rows at a time (so neither side ever
+holds a 12 GB table whole). One-file models are written and read as
+before.
+
 ``shared_entries(path)`` is the serving-side entry point: a process-wide
 cache keyed by the file's identity ``(realpath, mtime_ns, size)`` so N
 variants mounting the same instance share ONE mapping and ONE resolved
@@ -39,9 +50,10 @@ import logging
 import mmap
 import os
 import threading
+import time
 import zlib
 from pathlib import Path
-from typing import Any
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -52,6 +64,8 @@ logger = logging.getLogger(__name__)
 
 MAGIC = b"PIOMODF1"
 VERSION = 1
+SPAN_VERSION = 2  # a head file whose blocks lie in segment files
+SEGMENT_BYTES = 1 << 30  # the largest file of a spanning model
 _ALIGN = 64
 _HDR_FIXED = len(MAGIC) + 8 + 4  # magic + header length + header crc32
 
@@ -135,7 +149,7 @@ def can_encode(model: Any) -> bool:
         return False
     for f in flds:
         v = getattr(model, f.name)
-        if isinstance(v, np.ndarray):
+        if isinstance(v, np.ndarray) or _is_rows(v):
             continue
         if isinstance(v, BiMap):
             if _dense_ids(v) is None:
@@ -158,6 +172,65 @@ def _encode_ids(ids: list[str]) -> tuple[np.ndarray, np.ndarray]:
     return blob, offs
 
 
+def _aligned(off: int) -> int:
+    return (off + _ALIGN - 1) // _ALIGN * _ALIGN
+
+
+def _describe_entries(entries, put_array, put_block) -> list[dict]:
+    """The header's ``entries`` list for manifest ``entries``, shared by
+    the one-file and the spanning writer, which differ only in where
+    bytes go: every array (an ndarray or a row source) is handed to
+    ``put_array(name, v)`` -> its field spec, every other block (an id
+    dictionary's blob and offsets, a pickle) to ``put_block(name, arr)``
+    -> its name. An ``arrays`` payload is a model object or ``Fields``;
+    an id dictionary a dense BiMap, one loaded from a model file (taken
+    as stored: no decode) or ``EncodedIds``."""
+    out: list[dict] = []
+    for i, (kind, payload) in enumerate(entries):
+        if kind == "arrays":
+            if isinstance(payload, Fields):
+                cls_path, values = list(payload.cls), payload.values
+            else:
+                cls = type(payload)
+                cls_path = [cls.__module__, cls.__qualname__]
+                values = {f.name: getattr(payload, f.name)
+                          for f in dataclasses.fields(payload)}
+            fields: dict[str, dict] = {}
+            for fname, v in values.items():
+                if isinstance(v, np.ndarray) or _is_rows(v):
+                    fields[fname] = put_array(f"e{i}.{fname}", v)
+                elif isinstance(v, (BiMap, EncodedIds)):
+                    if isinstance(v, _LazyDenseBiMap):
+                        v = EncodedIds(v._blob, v._offs)
+                    elif isinstance(v, BiMap):
+                        ids = _dense_ids(v)
+                        if ids is None:
+                            raise ModelFileError(
+                                f"entry {i} field {fname}: BiMap is not dense"
+                            )
+                        v = EncodedIds(*_encode_ids(ids))
+                    fields[fname] = {
+                        "t": "bimap",
+                        "blob": put_block(f"e{i}.{fname}.blob", v.blob),
+                        "offs": put_block(f"e{i}.{fname}.offs", v.offs),
+                    }
+                elif v is None:
+                    fields[fname] = {"t": "none"}
+                else:
+                    fields[fname] = {"t": "json", "v": v}
+            out.append({"kind": "arrays", "cls": cls_path, "fields": fields})
+        elif kind == "pickle":
+            blob = np.frombuffer(payload, dtype=np.uint8)
+            out.append({"kind": "pickle", "block": put_block(f"e{i}.pickle", blob)})
+        elif kind == "persistent":
+            out.append({"kind": "persistent", "cls": list(payload)})
+        elif kind == "retrain":
+            out.append({"kind": "retrain"})
+        else:
+            raise ModelFileError(f"unknown entry kind {kind!r}")
+    return out
+
+
 def serialize(entries: list[tuple[str, Any]], model_id: str) -> bytes:
     """Encode manifest entries to the flat format.
 
@@ -166,56 +239,19 @@ def serialize(entries: list[tuple[str, Any]], model_id: str) -> bytes:
     ``("persistent", (module, qualname))``, or ``("retrain", None)``.
     """
     arrays: list[tuple[str, np.ndarray]] = []
-    header_entries: list[dict] = []
 
     def _block(name: str, arr: np.ndarray) -> str:
         arrays.append((name, np.ascontiguousarray(arr)))
         return name
 
-    for i, (kind, payload) in enumerate(entries):
-        if kind == "arrays":
-            cls = type(payload)
-            fields: dict[str, dict] = {}
-            for f in dataclasses.fields(payload):
-                v = getattr(payload, f.name)
-                if isinstance(v, np.ndarray):
-                    fields[f.name] = {
-                        "t": "array",
-                        "block": _block(f"e{i}.{f.name}", v),
-                        "shape": list(v.shape),
-                    }
-                elif isinstance(v, BiMap):
-                    ids = _dense_ids(v)
-                    if ids is None:
-                        raise ModelFileError(
-                            f"entry {i} field {f.name}: BiMap is not dense"
-                        )
-                    blob, offs = _encode_ids(ids)
-                    fields[f.name] = {
-                        "t": "bimap",
-                        "blob": _block(f"e{i}.{f.name}.blob", blob),
-                        "offs": _block(f"e{i}.{f.name}.offs", offs),
-                    }
-                elif v is None:
-                    fields[f.name] = {"t": "none"}
-                else:
-                    fields[f.name] = {"t": "json", "v": v}
-            header_entries.append({
-                "kind": "arrays",
-                "cls": [cls.__module__, cls.__qualname__],
-                "fields": fields,
-            })
-        elif kind == "pickle":
-            blob = np.frombuffer(payload, dtype=np.uint8)
-            header_entries.append({
-                "kind": "pickle", "block": _block(f"e{i}.pickle", blob),
-            })
-        elif kind == "persistent":
-            header_entries.append({"kind": "persistent", "cls": list(payload)})
-        elif kind == "retrain":
-            header_entries.append({"kind": "retrain"})
-        else:
-            raise ModelFileError(f"unknown entry kind {kind!r}")
+    header_entries = _describe_entries(
+        entries,
+        lambda name, v: {
+            "t": "array", "block": _block(name, np.asarray(v)),
+            "shape": list(v.shape),
+        },
+        _block,
+    )
 
     header: dict = {
         "version": VERSION,
@@ -224,10 +260,6 @@ def serialize(entries: list[tuple[str, Any]], model_id: str) -> bytes:
         "blocks": {},
     }
     offset = 0
-
-    def _aligned(off: int) -> int:
-        return (off + _ALIGN - 1) // _ALIGN * _ALIGN
-
     layout: list[tuple[str, np.ndarray, int]] = []
     for name, arr in arrays:
         offset = _aligned(offset)
@@ -260,13 +292,274 @@ def serialize(entries: list[tuple[str, Any]], model_id: str) -> bytes:
 
 
 # --------------------------------------------------------------------------
+# models that span files
+# --------------------------------------------------------------------------
+
+
+class Fields(NamedTuple):
+    """An ``arrays`` payload given as its parts instead of as a model
+    object: the class to rebuild and its field values. For writers
+    whose values are not the model's own types — a row source in an
+    array's place, ``EncodedIds`` in a BiMap's."""
+
+    cls: tuple[str, str]  # (module, qualname)
+    values: dict[str, Any]
+
+
+class EncodedIds(NamedTuple):
+    """A dense id dictionary already in its stored form (``_encode_ids``):
+    the utf-8 blob and the [n+1] int64 offsets."""
+
+    blob: np.ndarray
+    offs: np.ndarray
+
+
+def _is_rows(v: Any) -> bool:
+    """An array's stand-in: ``shape``, ``dtype`` and ``rows(lo, hi)`` ->
+    that range of leading-axis rows as an ndarray (``SpannedArray`` is
+    one; a writer may bring its own, made block by block)."""
+    return (not isinstance(v, np.ndarray) and hasattr(v, "shape")
+            and hasattr(v, "dtype") and callable(getattr(v, "rows", None)))
+
+
+class SpannedArray:
+    """A read-only [rows, ...] array whose rows lie in several mmap'd
+    blocks (one a segment). Looks like an ndarray where the templates
+    look — ``shape`` / ``dtype`` / ``len`` / row indexing by int, slice
+    or index array — and is never concatenated unless a caller asks
+    (``np.asarray``: small models and tests). ``rows(lo, hi)`` is what
+    a loader stages a shard from: a view where the range lies in one
+    part, else a copy of just that range."""
+
+    def __init__(self, parts: list[np.ndarray], shape):
+        self.parts = parts
+        self.shape = tuple(int(n) for n in shape)
+        self.dtype = parts[0].dtype
+        self.ndim = len(self.shape)
+        self.bounds = np.cumsum([0] + [len(p) for p in parts])
+        if int(self.bounds[-1]) != self.shape[0]:
+            raise ModelFileError(
+                f"parts hold {int(self.bounds[-1])} rows, shape says "
+                f"{self.shape[0]}"
+            )
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.shape)) * self.dtype.itemsize
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def rows(self, lo: int, hi: int) -> np.ndarray:
+        lo, hi = max(0, int(lo)), min(int(hi), self.shape[0])
+        first = int(np.searchsorted(self.bounds, lo, side="right")) - 1
+        pieces = []
+        for j in range(first, len(self.parts)):
+            a, b = int(self.bounds[j]), int(self.bounds[j + 1])
+            if a >= hi:
+                break
+            pieces.append(self.parts[j][max(lo, a) - a: min(hi, b) - a])
+        if len(pieces) == 1:
+            return pieces[0]
+        if not pieces:
+            return np.empty((0, *self.shape[1:]), self.dtype)
+        return np.concatenate(pieces)
+
+    def __getitem__(self, ix):
+        if isinstance(ix, slice):
+            lo, hi, step = ix.indices(self.shape[0])
+            return self.rows(lo, hi)[::step] if step > 0 else np.asarray(self)[ix]
+        ix = np.asarray(ix)
+        if ix.dtype == bool:
+            ix = np.flatnonzero(ix)
+        flat = np.where(ix < 0, ix + self.shape[0], ix).reshape(-1)
+        part = np.searchsorted(self.bounds, flat, side="right") - 1
+        out = np.empty((len(flat), *self.shape[1:]), self.dtype)
+        for j in np.unique(part):
+            sel = part == j
+            out[sel] = self.parts[j][flat[sel] - int(self.bounds[j])]
+        return out.reshape(*ix.shape, *self.shape[1:])
+
+    def __array__(self, dtype=None, copy=None):
+        a = np.concatenate(self.parts) if len(self.parts) > 1 else self.parts[0]
+        return a if dtype is None else a.astype(dtype, copy=False)
+
+
+class _Part(NamedTuple):
+    """One block of a spanning model as planned: where it goes and which
+    rows of which value it holds."""
+
+    name: str
+    value: Any  # ndarray or row source
+    lo: int
+    hi: int
+    dtype: np.dtype
+    nbytes: int
+    segment: int
+    offset: int
+
+
+def segment_name(head: str | os.PathLike, j: int) -> str:
+    return f"{os.path.basename(os.fspath(head))}.seg{j:04d}"
+
+
+def write_spanning(head_path: str | os.PathLike,
+                   entries: list[tuple[str, Any]], model_id: str, *,
+                   segment_bytes: int | None = None,
+                   workers: int | None = None) -> dict:
+    """Write ``entries`` (as ``serialize`` takes them; an ``arrays``
+    payload may also be ``Fields``, its arrays row sources and its
+    BiMaps ``EncodedIds``) as a model that spans files: segments of at
+    most ``segment_bytes`` (``SEGMENT_BYTES``) beside ``head_path``, then
+    the head. Every
+    array is cut row-wise into blocks no larger than a segment; a block
+    is produced (``rows(lo, hi)``), checksummed and written at its place
+    by one of ``workers`` threads, so the segments fill side by side and
+    no more than ``workers`` blocks are in memory at once. Segments and
+    head are written under temporary names, synced, and renamed — the
+    head last, so a reader never finds a head whose segments are not
+    there. Returns
+    ``{"segments": n, "bytes": total, "seconds": {"fill", "sync"}}``: the
+    blocks produced and written, then the syncs, renames and the head."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    head_path = os.fspath(head_path)
+    segment_bytes = int(segment_bytes or SEGMENT_BYTES)
+    where = os.path.dirname(os.path.abspath(head_path))
+    parts: list[_Part] = []
+    seg_sizes: list[int] = []
+
+    def place(name: str, value, lo: int, hi: int, dtype, nbytes: int):
+        if nbytes > segment_bytes:
+            raise ModelFileError(  # one row of an array, or an id dictionary
+                f"block {name} of {nbytes} bytes exceeds a segment of "
+                f"{segment_bytes}"
+            )
+        if not seg_sizes or _aligned(seg_sizes[-1]) + nbytes > segment_bytes:
+            seg_sizes.append(0)
+        off = _aligned(seg_sizes[-1])
+        seg_sizes[-1] = off + nbytes
+        parts.append(_Part(name, value, lo, hi, np.dtype(dtype), nbytes,
+                           len(seg_sizes) - 1, off))
+        return name
+
+    def array_field(name: str, v) -> dict:
+        shape = tuple(int(n) for n in v.shape)
+        dt = np.dtype(v.dtype)
+        row = int(np.prod(shape[1:], dtype=np.int64)) * dt.itemsize if shape else dt.itemsize
+        n = shape[0] if shape else 1
+        per = max(1, segment_bytes // max(1, row))
+        if n <= per:
+            block = place(name, v, 0, n, dt, n * row)
+            return {"t": "array", "block": block, "shape": list(shape)}
+        blocks = [
+            place(f"{name}.p{j}", v, lo, min(lo + per, n), dt,
+                  (min(lo + per, n) - lo) * row)
+            for j, lo in enumerate(range(0, n, per))
+        ]
+        return {"t": "array", "blocks": blocks, "shape": list(shape)}
+
+    header_entries = _describe_entries(
+        entries, array_field,
+        lambda name, a: place(name, a, 0, len(a), a.dtype, a.nbytes),
+    )
+    names = [segment_name(head_path, j) for j in range(len(seg_sizes))]
+    tmp = [os.path.join(where, f"{n}.tmp.{os.getpid()}") for n in names]
+    fds = [os.open(t, os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o644) for t in tmp]
+    crcs: dict[str, int] = {}
+
+    def write(p: _Part) -> None:
+        v = p.value
+        a = v.rows(p.lo, p.hi) if _is_rows(v) else (v[p.lo:p.hi] if v.ndim else v)
+        a = np.ascontiguousarray(a, dtype=p.dtype)
+        if a.nbytes != p.nbytes:
+            raise ModelFileError(
+                f"block {p.name}: rows [{p.lo}, {p.hi}) came as {a.nbytes} "
+                f"bytes, planned {p.nbytes}"
+            )
+        buf = memoryview(a.reshape(-1)).cast("B")
+        crcs[p.name] = zlib.crc32(buf) & 0xFFFFFFFF
+        done = 0
+        while done < len(buf):  # pwrite caps at 2 GiB - 4 KiB a call
+            done += os.pwrite(fds[p.segment], buf[done:], p.offset + done)
+
+    t_start = time.perf_counter()
+    try:
+        for fd, size in zip(fds, seg_sizes):
+            os.ftruncate(fd, size)
+        with ThreadPoolExecutor(max_workers=workers or min(8, os.cpu_count() or 1)) as pool:
+            list(pool.map(write, parts))
+        t_filled = time.perf_counter()
+        for fd in fds:
+            faults.fault_point("storage.fsync")
+            os.fsync(fd)
+    except BaseException:
+        for fd, t in zip(fds, tmp):
+            os.close(fd)
+            try:
+                os.unlink(t)
+            except OSError:
+                pass
+        raise
+    for fd in fds:
+        os.close(fd)
+    header = {
+        "version": SPAN_VERSION,
+        "model_id": model_id,
+        "entries": header_entries,
+        "segments": [{"file": n, "bytes": b} for n, b in zip(names, seg_sizes)],
+        "blocks": {
+            p.name: {
+                "dtype": _dtype_tag(p.dtype),
+                "count": p.nbytes // p.dtype.itemsize,
+                "segment": p.segment,
+                "offset": p.offset,
+                "crc32": crcs[p.name],
+            }
+            for p in parts
+        },
+    }
+    hdr = json.dumps(header, sort_keys=True).encode("utf-8")
+    faults.fault_point("storage.rename")
+    for t, n in zip(tmp, names):
+        os.replace(t, os.path.join(where, n))
+    head_tmp = f"{head_path}.tmp.{os.getpid()}"
+    with open(head_tmp, "wb") as f:
+        f.write(MAGIC)
+        f.write(len(hdr).to_bytes(8, "little"))
+        f.write((zlib.crc32(hdr) & 0xFFFFFFFF).to_bytes(4, "little"))
+        f.write(hdr)
+        f.flush()
+        os.fsync(f.fileno())
+    os.replace(head_tmp, head_path)
+    return {
+        "segments": len(names),
+        "bytes": sum(seg_sizes) + _HDR_FIXED + len(hdr),
+        "seconds": {"fill": t_filled - t_start,
+                    "sync": time.perf_counter() - t_filled},
+    }
+
+
+def spans(model: Any) -> bool:
+    """Would ``serialize`` make of this model one file over a segment's
+    size (``SEGMENT_BYTES``)? Then it is written spanning."""
+    if not dataclasses.is_dataclass(model) or isinstance(model, type):
+        return False
+    total = 0
+    for f in dataclasses.fields(model):
+        total += int(getattr(getattr(model, f.name), "nbytes", 0) or 0)
+    return total > SEGMENT_BYTES
+
+
+# --------------------------------------------------------------------------
 # decode
 # --------------------------------------------------------------------------
 
 
-def _parse_header(buf) -> tuple[dict, int]:
+def _parse_header(buf, where: str | None = None) -> tuple[dict, int]:
     """Validate magic / length / crc and return (header, payload_base).
-    ``buf`` is any buffer (mmap or bytes)."""
+    ``buf`` is any buffer (mmap or bytes); ``where`` the directory of a
+    spanning head's segments."""
     total = len(buf)
     if total < _HDR_FIXED or bytes(buf[: len(MAGIC)]) != MAGIC:
         raise ModelFileError("bad magic: not a model file")
@@ -281,15 +574,29 @@ def _parse_header(buf) -> tuple[dict, int]:
         header = json.loads(hdr_bytes)
     except ValueError as e:
         raise ModelFileError(f"header is not JSON: {e}") from e
-    if header.get("version") != VERSION:
+    if header.get("version") not in (VERSION, SPAN_VERSION):
         raise ModelFileError(f"unsupported version {header.get('version')!r}")
-    payload_base = (_HDR_FIXED + hlen + _ALIGN - 1) // _ALIGN * _ALIGN
+    payload_base = _aligned(_HDR_FIXED + hlen)
+    segments = header.get("segments", [])
+    if segments and where is None:
+        raise ModelFileError(
+            "a model that spans files loads from its head file's path, "
+            "not from bytes"
+        )
+    sizes = []
+    for seg in segments:
+        try:
+            sizes.append(os.path.getsize(os.path.join(where, seg["file"])))
+        except OSError as e:
+            raise ModelFileError(f"segment {seg['file']} missing: {e}") from e
     for name, spec in header.get("blocks", {}).items():
         dt = _tag_dtype(spec["dtype"])
-        end = payload_base + spec["offset"] + spec["count"] * dt.itemsize
-        if spec["offset"] < 0 or end > total:
+        j = spec.get("segment")
+        base, size = (payload_base, total) if j is None else (0, sizes[j])
+        end = base + spec["offset"] + spec["count"] * dt.itemsize
+        if spec["offset"] < 0 or end > size:
             raise ModelFileError(
-                f"block {name} [{end} bytes] exceeds file size {total}: "
+                f"block {name} [{end} bytes] exceeds file size {size}: "
                 "truncated model file"
             )
     return header, payload_base
@@ -309,7 +616,9 @@ class _LazyDenseBiMap(BiMap):
 
     Never calls ``BiMap.__init__``; ``_m``/``_inverse`` are materializing
     properties shadowing the base class's instance attributes, so every
-    inherited accessor works unchanged once touched."""
+    inherited accessor works unchanged once touched. The inverse
+    (index -> id) decodes nothing: ``_OffsetInverse`` reads one id at a
+    time from the blob and its offsets."""
 
     def __init__(self, blob: np.ndarray, offs: np.ndarray):
         self._blob = blob
@@ -334,11 +643,16 @@ class _LazyDenseBiMap(BiMap):
     @property
     def _inverse(self) -> BiMap:
         if self._inv is None:
-            # dense by construction: values are exactly 0..n-1
-            self._inv = BiMap(
-                {i: k for k, i in self._m.items()}, _inverse=self
-            )
+            self._inv = _OffsetInverse(self)
         return self._inv
+
+    def id_at(self, i: int) -> str:
+        """The id of dense index ``i``, read from the blob and its
+        offsets: an answer needs k ids, not the dictionary."""
+        if not 0 <= i < len(self._offs) - 1:
+            raise KeyError(i)
+        lo, hi = self._offs[i], self._offs[i + 1]
+        return self._blob[lo:hi].tobytes().decode("utf-8")
 
     def __len__(self) -> int:  # cheap without decoding
         return len(self._offs) - 1
@@ -349,6 +663,51 @@ class _LazyDenseBiMap(BiMap):
         return (BiMap, (self._m,))
 
 
+class _OffsetInverse(BiMap):
+    """``_LazyDenseBiMap.inverse``: index -> id, answered from the blob
+    and offsets one id at a time. Serving reads k ids an answer through
+    ``[]`` / ``get``; only a caller that walks the whole mapping
+    (``items``, iteration, equality) pays the decode of every id — at
+    48 M ids that is minutes and gigabytes, so no serving path does."""
+
+    def __init__(self, forward: "_LazyDenseBiMap"):
+        self._forward = forward
+        self._all: dict | None = None
+
+    @property
+    def _m(self) -> dict:
+        if self._all is None:
+            self._all = {i: k for k, i in self._forward._m.items()}
+        return self._all
+
+    @property
+    def _inverse(self) -> BiMap:
+        return self._forward
+
+    @staticmethod
+    def _is_index(i) -> bool:
+        return isinstance(i, (int, np.integer)) and not isinstance(i, bool)
+
+    def __getitem__(self, i):
+        if self._all is not None:
+            return self._all[i]
+        if not self._is_index(i):
+            raise KeyError(i)
+        return self._forward.id_at(int(i))
+
+    def get(self, i, default=None):
+        try:
+            return self[i]
+        except KeyError:
+            return default
+
+    def __contains__(self, i) -> bool:
+        return self._is_index(i) and 0 <= i < len(self)
+
+    def __len__(self) -> int:
+        return len(self._forward)
+
+
 class ModelFile:
     """A parsed model file over an mmap (or bytes) buffer. Arrays are
     read-only zero-copy views; the buffer must outlive them (the loader
@@ -357,9 +716,31 @@ class ModelFile:
     def __init__(self, buf, *, source: str = "<bytes>"):
         self._buf = buf
         self._source = source
-        self._header, self._base = _parse_header(buf)
+        self._where = (
+            os.path.dirname(os.path.abspath(source))
+            if source != "<bytes>" else None
+        )
+        self._header, self._base = _parse_header(buf, self._where)
+        self._segments: dict[int, Any] = {}  # mapped on first use
+        self._seg_lock = threading.Lock()
         if _verify_blocks():
             self._verify()
+
+    @property
+    def segments(self) -> list[dict]:
+        """The segment files of a spanning model ([] for one file)."""
+        return list(self._header.get("segments", []))
+
+    def _segment(self, j: int):
+        with self._seg_lock:
+            mm = self._segments.get(j)
+            if mm is None:
+                path = os.path.join(self._where, self._header["segments"][j]["file"])
+                with open(path, "rb") as f:
+                    mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+                self._segments[j] = mm
+                _count_segment()
+            return mm
 
     @property
     def model_id(self) -> str:
@@ -367,13 +748,13 @@ class ModelFile:
 
     def _arr(self, name: str) -> np.ndarray:
         spec = self._header["blocks"][name]
-        a = np.frombuffer(
-            self._buf,
+        j = spec.get("segment")
+        return np.frombuffer(
+            self._buf if j is None else self._segment(j),
             dtype=_tag_dtype(spec["dtype"]),
             count=spec["count"],
-            offset=self._base + spec["offset"],
+            offset=spec["offset"] + (self._base if j is None else 0),
         )
-        return a
 
     def _verify(self) -> None:
         for name, spec in self._header["blocks"].items():
@@ -397,7 +778,13 @@ class ModelFile:
         out: dict[str, Any] = {}
         for fname, fs in ent["fields"].items():
             t = fs["t"]
-            if t == "array":
+            if t == "array" and "blocks" in fs:  # cut row-wise over segments
+                shape = fs["shape"]
+                out[fname] = SpannedArray(
+                    [self._arr(b).reshape(-1, *shape[1:]) for b in fs["blocks"]],
+                    shape,
+                )
+            elif t == "array":
                 a = self._arr(fs["block"])
                 shape = fs.get("shape")
                 if shape is not None:
@@ -464,6 +851,21 @@ def deserialize(blob: bytes) -> list[tuple[str, Any]]:
 # --------------------------------------------------------------------------
 
 _m_fallback = None  # lazy: obs counter for mmap -> bytes fallbacks
+
+
+_m_segments = None  # lazy, as above: segment files mapped
+
+
+def _count_segment() -> None:
+    global _m_segments
+    if _m_segments is None:
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        _m_segments = obs_metrics.counter(
+            "pio_model_segments_total",
+            "segment files of models that span files mapped by this process",
+        )
+    _m_segments.inc()
 
 
 def _count_fallback() -> None:

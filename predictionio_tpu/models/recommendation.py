@@ -227,9 +227,11 @@ class ALSAlgorithmParams(Params):
     # parity RMSE, "int8" quarters it (values + per-row f32 scale,
     # dequantized at gather; solves still accumulate float32; ops/als.py)
     storage_dtype: str = "float32"
-    # serve with item factors sharded over the device mesh (ring top-k) —
-    # the TPU answer to the reference's PAlgorithm "model bigger than one
-    # host" case, which issues a Spark job per query instead
+    # serve with the item rows split over the device mesh, each device
+    # scanning and rescoring the rows it holds and one small all-gather
+    # merging the answers (parallel/shard_topk.py) — the TPU answer to
+    # the reference's PAlgorithm "model bigger than one host" case, which
+    # issues a Spark job per query instead
     # (examples/.../ALSAlgorithm.scala:88)
     sharded_serving: bool = False
     # train over the WorkflowContext device mesh (factors sharded row-wise,
@@ -271,7 +273,7 @@ class ALSModel:
 
     def __post_init__(self):
         self._device = None
-        self._ring = None
+        self._sharded = None
         self._coarse = None
 
     def user_rows(self, ixs):
@@ -306,15 +308,20 @@ class ALSModel:
             )
         return self._device
 
-    def ring_catalog(self):
-        """Item factors staged sharded over the full mesh, cached — the
-        deployed-server resident layout for catalogs bigger than one chip."""
-        if self._ring is None:
-            from predictionio_tpu.parallel.mesh import make_mesh
-            from predictionio_tpu.parallel.ring_topk import RingCatalog
+    def sharded_catalog(self):
+        """The item rows staged over the serving mesh, shard ``i`` read
+        from the model file's segments onto device ``i``, cached — the
+        deployed-server resident layout for a catalog bigger than one
+        chip: exact rows, coarse copy and ids per device, the user table
+        left on the host (a query reads one row of it)."""
+        if self._sharded is None:
+            from predictionio_tpu.obs import trace as obs_trace
+            from predictionio_tpu.parallel.mesh import serving_mesh
+            from predictionio_tpu.parallel.shard_topk import ShardedCatalog
 
-            self._ring = RingCatalog(self.item_table(), make_mesh())
-        return self._ring
+            with obs_trace.region("model.load_segments"):
+                self._sharded = ShardedCatalog(self.item_table(), serving_mesh())
+        return self._sharded
 
     def coarse_catalog(self):
         """Tiled coarse copy of the item table for the two-stage
@@ -329,7 +336,7 @@ class ALSModel:
     def __getstate__(self):
         state = self.__dict__.copy()
         state["_device"] = None
-        state["_ring"] = None
+        state["_sharded"] = None
         state["_coarse"] = None
         return state
 
@@ -639,8 +646,8 @@ class ALSAlgorithm(Algorithm):
         storage-precision catalog, then exact f32 rescoring of the
         [B, S] shortlist) is ``ops.retrieval.top_k``'s decision; below
         ``PIO_RETRIEVAL_THRESHOLD`` rows nothing changes, bit for bit.
-        ``sharded_serving`` runs the same two stages on the mesh (coarse
-        ring pass, host rescore): the one chain outside ``top_k``."""
+        ``sharded_serving`` hands ``top_k`` the catalog split over the
+        mesh in the table's place: the same decision, on the shards."""
         from predictionio_tpu.ops import retrieval
 
         known = [(ix, q) for ix, q in queries if q.user in model.user_index]
@@ -661,26 +668,16 @@ class ALSAlgorithm(Algorithm):
             k = retrieval._pow2(max(int(q.num) for _, q in known))
             num_items = len(model.item_index)
             n0 = int(known[0][1].num)  # the recall probe's row
-            if self.params.sharded_serving:
-                ring, vecs = model.ring_catalog(), model.user_rows(uixs)
-                kp = retrieval.two_stage_k(k, num_items)
-                if kp:
-                    _, cand = ring.top_k(vecs, kp, coarse=True)
-                    scores, ids = retrieval.rescore_host(
-                        vecs, model.item_factors, model.item_scales, cand, k,
-                    )
-                    retrieval.probe(ids[0, :n0], lambda: np.asarray(
-                        ring.top_k(vecs[:1], k)[1]
-                    )[0, :n0])
-                else:
-                    scores, ids = ring.top_k(vecs, k)
-                scores, ids = np.asarray(scores), np.asarray(ids)
+            if self.params.sharded_serving:  # the catalog is table and
+                # coarse copy both; a query's user row is gathered on the host
+                users, table, coarse = None, model.sharded_catalog(), None
             else:
-                U, V = model.device_factors()
-                scores, ids = retrieval.top_k(
-                    retrieval.UserRows(uixs, U, model.user_rows), V,
-                    num_items, model.coarse_catalog, k, probe_n=n0,
-                )
+                users, table = model.device_factors()
+                coarse = model.coarse_catalog
+            scores, ids = retrieval.top_k(
+                retrieval.UserRows(uixs, users, model.user_rows), table,
+                num_items, coarse, k, probe_n=n0,
+            )
             inv = model.item_index.inverse
             for row, (ix, q) in enumerate(known):
                 out.append(
@@ -729,7 +726,9 @@ class ALSAlgorithm(Algorithm):
                 dtype=np.int32,
             )
             if self.params.sharded_serving:
-                s, i = model.ring_catalog().top_k(model.user_rows(uixs), kr)
+                s, i = model.sharded_catalog().exact_top_k(
+                    model.user_rows(uixs), kr
+                )
             else:
                 _, V = model.device_factors()
                 s, i = top_k_items_batch(model.user_rows(uixs), V, k=kr)

@@ -16,9 +16,9 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    of the within-row dot and multiplies back scalar-per-column);
    ``int8_dot`` additionally quantizes the queries and accumulates in
    int32 (the MXU-native form — auto-selected on TPU); dense catalogs
-   carry a bf16 coarse copy. On the mesh, the coarse pass is
-   parallel/ring_topk.py's ``coarse=True`` variant (per-shard
-   oversampled top-k', int8 slabs scored without dequantization).
+   carry a bf16 coarse copy. On a mesh each device runs this same
+   scan over the rows it holds (parallel/shard_topk.py: stationary
+   shards, the query replicated).
    A scan step never selects over its whole tile where the tile is
    large: it takes the maximum of each group of G scores, the k' best
    groups, and the k' best of those groups' scores — exactly the
@@ -52,6 +52,9 @@ keeps a query from the shortlist), the resident
 exact table, the catalog's row count, the coarse copy and ``k``, and it
 alone decides exact or two-stage, runs shortlist -> rescore, and every
 Nth two-stage dispatch re-scores row 0 exactly (the live recall probe).
+Where the table is a ``parallel.shard_topk.ShardedCatalog`` — item rows
+split over a mesh, for a catalog one chip cannot hold — the same
+decision runs both stages and a merge as one program on the shards.
 Engagement is catalog-size gated: a catalog under
 ``PIO_RETRIEVAL_THRESHOLD`` rows (default 100_000) — every small test
 fixture — is served by the form's exact op, bit for bit what it was
@@ -73,7 +76,7 @@ array up with ``jnp.asarray``, once, in the device's default layout,
 and every reader takes it as it lies: the rescore programs here,
 ``ops/topk.py``'s exact programs (the recall probe, catalogs below the
 threshold) and the templates' own row math;
-``CoarseCatalog`` and ``rescore_host`` read the model's host arrays. On
+``CoarseCatalog`` reads the model's host arrays. On
 a TPU that default keeps the long axis minor wherever D is no multiple
 of 128, and XLA answers a gather of 64-column rows from it by first
 copying the entire table row-major — 2.4 GB read and 4.8 GB written per
@@ -190,7 +193,8 @@ _SIZE_BOUNDS = tuple(float(1 << p) for p in range(4, 20, 2))  # 16 .. 262144
 _QUERIES_HELP = (
     "serving queries of a catalog at retrieval scale, by path: two_stage = "
     "shortlist then rescore; exact = the exact program, because k leaves a "
-    "shortlist no room (no filter takes a query there since PR 30)"
+    "shortlist no room (no filter takes a query there since PR 30); sharded "
+    "= both stages and the merge in one program over stationary shards"
 )
 _m_two_stage = obs_metrics.counter(
     "pio_retrieval_queries_total", _QUERIES_HELP, path="two_stage",
@@ -198,6 +202,29 @@ _m_two_stage = obs_metrics.counter(
 _m_exact = obs_metrics.counter(
     "pio_retrieval_queries_total", _QUERIES_HELP, path="exact",
 )
+_m_sharded = obs_metrics.counter(
+    "pio_retrieval_queries_total", _QUERIES_HELP, path="sharded",
+)
+_m_gather_bytes = obs_metrics.counter(
+    "pio_retrieval_shard_gather_bytes_total",
+    "bytes the sharded chain's all-gather moved: shards x B x k x 8 a "
+    "dispatch (every shard's [B, k] f32 scores and int32 ids)",
+)
+_m_shards = obs_metrics.gauge(
+    "pio_retrieval_shards",
+    "devices the served catalog's rows are split over (0: one chip)",
+)
+_m_load = {
+    stage: obs_metrics.histogram(
+        "pio_model_load_seconds",
+        "staging one shard of a sharded catalog, by stage: read = its rows "
+        "out of the model's segments into one host block; stage_to_device = "
+        "the block's upload to its device; coarse_build = the bf16 tiles "
+        "made of it there",
+        stage=stage,
+    )
+    for stage in ("read", "stage_to_device", "coarse_build")
+}
 _m_shortlist_size = obs_metrics.histogram(
     "pio_retrieval_shortlist_size",
     "shortlist candidates per query (k')", bounds=_SIZE_BOUNDS,
@@ -276,6 +303,10 @@ def stats_block() -> dict:
         "oversample": _OVERSAMPLE,
         "two_stage_queries": _m_two_stage.value(),
         "exact_queries": _m_exact.value(),
+        "sharded_queries": _m_sharded.value(),
+        "shards": int(_m_shards.value()),
+        "shard_gather_bytes": _m_gather_bytes.value(),
+        "load_seconds": {st: m.summary() for st, m in _m_load.items()},
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
         "rescore_seconds": _m_rescore_secs.summary(),
@@ -539,17 +570,18 @@ def device_rules(rules: Rules) -> Rules:
     )
 
 
-def _up(a, dtype, rows: int = 0):
+def _up(a, dtype, rows: int = 0, sharding=None):
     """``a`` as a device array: one that is there already as it lies
     (the chain's arrays go up once, for both stages), a host array
     converted to ``dtype``, padded to ``rows`` rows with copies of row 0
-    (discarded after the read) and uploaded."""
+    (discarded after the read) and uploaded — to the default device, or
+    as ``sharding`` says (the sharded chain's replicated queries)."""
     if isinstance(a, jax.Array):
         return a
     a = np.ascontiguousarray(a, dtype=dtype)
     if len(a) < rows:
         a = np.concatenate([a, np.repeat(a[:1], rows - len(a), axis=0)])
-    return jnp.asarray(a)
+    return jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
 
 
 def _fetch(out, n: int):
@@ -733,20 +765,23 @@ def _table_rows(table, ixs):
     return _gather_rows(table, ixs)
 
 
-def _score_candidates(qvecs, item_factors, cand_ids, k: int, rules=None):
+def _score_candidates(qvecs, item_factors, cand_ids, k: int, rules=None,
+                      precision=None):
     """Shared exact-f32 candidate scorer: gather the [B, S] candidate
     rows (dequantizing int8 pairs on device), dot against the query
     vectors, top-k. -1 candidate slots can never win and report id -1;
     nor can a candidate that ``rules`` keeps from its query (the
     shortlist applied them already: a second, independent application
-    where the served scores are produced)."""
+    where the served scores are produced). ``precision``: the dot's,
+    where a caller states one (the sharded chain: HIGHEST); None leaves
+    the programs of one chip what they were."""
     with jax.named_scope("retrieval.rescore.gather"):
         cand = jnp.maximum(cand_ids.astype(jnp.int32), 0)
         rows = _table_rows(item_factors, cand)
     with jax.named_scope("retrieval.rescore.score"):
         sc = jnp.einsum(
             "bd,bsd->bs", qvecs.astype(jnp.float32), rows,
-            preferred_element_type=jnp.float32,
+            precision=precision, preferred_element_type=jnp.float32,
         )
         sc = jnp.where(cand_ids >= 0, sc, NEG_INF)
     if rules is not None:
@@ -931,30 +966,6 @@ def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
     ), len(cand_ids))
 
 
-def rescore_host(query_vectors, values, scales, cand_ids, k: int):
-    """Host-side exact rescore for the mesh path: the ring coarse pass
-    returns [B, S] global candidate ids; the exact factors live host-side
-    in the model, and S is small, so the f32 gather + dot runs in numpy
-    without staging anything back to the mesh."""
-    with obs_trace.region("dispatch.rescore", hist=_m_rescore_secs):
-        cand_ids = np.asarray(cand_ids, dtype=np.int32)
-        cand = np.maximum(cand_ids, 0)
-        rows = np.asarray(values)[cand].astype(np.float32)
-        if scales is not None:
-            rows *= np.asarray(scales, np.float32)[cand][..., None]
-        sc = np.einsum(
-            "bd,bsd->bs", np.asarray(query_vectors, np.float32), rows
-        )
-        sc[cand_ids < 0] = NEG_INF
-        k = min(k, cand_ids.shape[1])
-        order = np.argsort(-sc, axis=1, kind="stable")[:, :k]
-        s = np.take_along_axis(sc, order, axis=1)
-        ids = np.take_along_axis(cand_ids, order, axis=1)
-        ids[s <= NEG_INF / 2] = -1
-    _m_two_stage.inc(len(cand_ids))
-    return s, ids
-
-
 # -- the serving chain ---------------------------------------------------------
 #
 # A query batch reaches ``top_k`` in one of three forms. A form is the
@@ -1070,6 +1081,8 @@ def top_k(query, table, num_rows: int, coarse, k: int,
     queries of a catalog at retrieval scale whose k leaves a shortlist
     no room."""
     kp = two_stage_k(k, num_rows)
+    if getattr(table, "shards", 0):
+        return _top_k_sharded(query, table, kp, k, probe_n)
     if not kp:
         if engaged(num_rows):
             _m_exact.inc(len(query[0]))
@@ -1087,5 +1100,35 @@ def top_k(query, table, num_rows: int, coarse, k: int,
     probe(
         ids[0, :probe_n],
         lambda: np.asarray(query.head().exact(table, k)[1])[0, :probe_n],
+    )
+    return s, ids
+
+
+def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
+    """``top_k`` over a ``parallel.shard_topk.ShardedCatalog`` (the
+    exact rows AND the coarse copy, split row-wise over a mesh): the
+    form gives its f32 vectors, which go up replicated, and ONE program
+    scans, rescores and merges on the shards — ``dispatch.shortlist`` is
+    the conversion, the upload and that launch, ``dispatch.fetch`` the
+    one read; there is no second enqueue to call ``dispatch.rescore``.
+    The decision is ``top_k``'s (kp = 0: the sharded exact program), the
+    probe re-scores the first query with that program."""
+    if query.rules is not None:
+        raise ValueError("a sharded catalog serves no query under rules yet")
+    n = len(query[0])
+    with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
+        q = catalog.put_queries(query.coarse_vectors())
+        out = catalog.launch(q, kp, k) if kp else catalog.launch_exact(q, k)
+    s, ids = _fetch(out, n)
+    _m_gather_bytes.inc(catalog.gather_bytes(len(q), min(k, kp) if kp else k))
+    if not kp:
+        if engaged(catalog.num_rows):
+            _m_exact.inc(n)
+        return s, ids
+    _m_sharded.inc(n)
+    _m_shortlist_size.observe(float(min(kp, catalog.tile)))
+    probe(
+        ids[0, :probe_n],
+        lambda: _fetch(catalog.launch_exact(q[:1], k), 1)[1][0, :probe_n],
     )
     return s, ids

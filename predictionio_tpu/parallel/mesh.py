@@ -109,6 +109,42 @@ def make_mesh(axes: Sequence[tuple[str, int]] | None = None):
     return Mesh(np.array(devices[:total]).reshape(sizes), tuple(names))
 
 
+def parse_axes(spec: str | None) -> list[tuple[str, int]] | None:
+    """'data=4,model=2' -> [("data", 4), ("model", 2)]; None for an empty
+    spec. A size is a positive integer, or -1 once for the remainder."""
+    if not spec:
+        return None
+    axes = []
+    for part in spec.split(","):
+        name, _, size = part.partition("=")
+        try:
+            n = int(size)
+        except ValueError:
+            n = 0
+        if not name.strip() or n == 0 or n < -1:
+            raise ValueError(
+                f"bad mesh axis {part!r}; expected name=size with a "
+                "positive integer size (or -1 once for the remainder)"
+            )
+        axes.append((name.strip(), n))
+    if sum(1 for _, n in axes if n == -1) > 1:
+        raise ValueError("at most one mesh axis may be -1")
+    return axes
+
+
+def serving_mesh():
+    """The 1-D ``data`` mesh a serving process shards a catalog over:
+    ``PIO_MESH`` (``pio deploy --mesh data=4``) where set, else every
+    device this process sees."""
+    axes = parse_axes(os.environ.get("PIO_MESH"))
+    if axes and [n for n, _ in axes] != ["data"]:
+        raise ValueError(
+            f"serving shards item rows over one axis, 'data'; PIO_MESH "
+            f"gives {axes}"
+        )
+    return make_mesh(axes)
+
+
 def factor_sharding(mesh, axis: str = "data"):
     """Row sharding for factor tables / packed bucket tables: ``P(axis)``
     on dim 0. A pytree-prefix of this covers the int8 ``(values, scales)``

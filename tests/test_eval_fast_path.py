@@ -134,7 +134,7 @@ class TestRankingKernel:
 
 @pytest.fixture(scope="module")
 def _unshard_ring_cache():
-    """RingCatalog instances cache per-process; nothing to reset, but
+    """ShardedCatalog instances cache per-model; nothing to reset, but
     keep a hook here so mesh-shape assumptions are in one place."""
     import jax
 
@@ -255,7 +255,7 @@ class TestEvalDeviceParity:
         assert "serial" in serial.phase_seconds
 
     def test_device_matches_per_query_sharded_mesh(self, _unshard_ring_cache):
-        """sharded_serving ranks via the ring catalog over the virtual
+        """sharded_serving ranks via the sharded catalog over the virtual
         8-device mesh; parity must hold across that path too."""
         candidates = _candidates(2, sharded_serving=True)
         fast = MetricEvaluator(PrecisionAtK(k=K), **METRIC_KW).evaluate(

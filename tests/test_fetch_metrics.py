@@ -3,7 +3,9 @@ ends a two-stage dispatch. Each is a data file beside ``shortlist_ms*``
 that reads ``pio_retrieval_fetch_seconds`` from a counters delta, or nothing
 — None, no raise — from a program that has no such histogram (the parent
 commit); a traced CPU rehearsal of a cell prints its own. Kept outside
-tests/benchmark/: this PR adds data files to the benchmark, no code.
+tests/benchmark/: this PR adds data files to the benchmark, no code. The
+sharded cell (PR 32) runs both stages and the merge as ONE program, so its
+chain is ``shortlist_ms.sharded`` (the enqueue) and ``fetch_ms.sharded``.
 
 ``fetch_ms.storefront`` has its file and NO ``per_layer`` entry yet: the
 accepted tests/benchmark/test_storefront_cell.py counts that cell's traced
@@ -36,6 +38,7 @@ FETCH = {
     "fetch_ms.saturated": ("serve_qps", "retrieval-yambda.serve-saturated"),
     "fetch_ms.storefront": ("query_p50_ms", "ecommerce-taobao.serve-storefront"),
     "fetch_ms.itempage": ("query_p50_ms", "similarproduct-taobao.serve-itempage"),  # PR 30
+    "fetch_ms.sharded": ("query_p50_ms", "recommendation-amazon23.serve-sharded-steady"),  # PR 32
 }
 LISTED = [n for n in FETCH if n != "fetch_ms.storefront"]  # see the docstring
 
@@ -50,9 +53,11 @@ def test_fetch_metric_reads_its_histogram_or_nothing(name):
                     if m["name"] == name.replace("fetch_ms", "shortlist_ms"))
         assert {**entry, "name": twin["name"]} == twin  # beside shortlist_ms*, alike
         assert (entry["moves"], entry["workloads"], entry["layer"]) == (moves, [cell], "score")
-        # appended: nothing follows it but PR 29's other entry and PR 30's cell
+        # appended: nothing follows it but PR 29's other entry and the cells of
+        # PR 30 and PR 32
         later = MANIFEST["per_layer"][MANIFEST["per_layer"].index(entry) + 1:]
-        assert all(m["name"] in FETCH or m["name"].endswith(".itempage") for m in later)
+        assert all(m["name"] in FETCH or m["name"].endswith((".itempage", ".sharded"))
+                   for m in later)
     with open(os.path.join(METRICS_DIR, name + ".json")) as fh:
         assert json.load(fh) == {"reader": "histogram_mean", "scale": 1000.0,
                                  "series": "pio_retrieval_fetch_seconds"}
@@ -90,12 +95,15 @@ def test_traced_rehearsal_prints_the_three_stages(cell, tmp_path):
     lines = proc.stdout.strip().splitlines()
     would = json.loads(next(ln for ln in lines if ln.startswith("would print: "))[13:])
     m = would["metrics"]
-    sfx = "." + cell.rsplit("-", 1)[1] if not cell.endswith("steady") else ""
+    sharded = "sharded" in cell  # PR 32: one program a dispatch, no second enqueue
+    sfx = ".sharded" if sharded else \
+        "." + cell.rsplit("-", 1)[1] if not cell.endswith("steady") else ""
     # the score layer's three stages lie inside a dispatch, one after the
     # other, and the wait for the device is in the last of them
     listed = "fetch_ms" + sfx in LISTED
     assert ("fetch_ms" + sfx in m) == listed
-    names = ["shortlist_ms", "rescore_ms"] + ["fetch_ms"] * listed
+    assert ("rescore_ms" + sfx in m) != sharded
+    names = ["shortlist_ms"] + ["rescore_ms"] * (not sharded) + ["fetch_ms"] * listed
     stages = [m[n + sfx]["value"] for n in names]
     assert all(v == v and v > 0.0 for v in stages), stages
     dispatch = "dispatch_ms" + (".saturated" if cell.endswith("saturated") else "")

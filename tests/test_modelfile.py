@@ -339,3 +339,211 @@ class TestPublishAtomicity:
         assert got is not None and got.models == v1
         entries = modelfile.deserialize(got.models)
         assert entries[0][1].user_factors.dtype == np.float32
+
+
+MIB = 1 << 20
+
+
+def _span(tmp_path, model, segment_bytes=MIB, **kw):
+    head = tmp_path / "pio_model_span.bin"
+    info = modelfile.write_spanning(
+        head, [("arrays", model)], "span", segment_bytes=segment_bytes, **kw
+    )
+    return head, info
+
+
+class TestSpanningModel:
+    """A model that spans files: a head file + segments of bounded size."""
+
+    def test_span_and_reload_round_trip_at_a_one_mib_segment(self, tmp_path):
+        m = _als(n_users=300, n_items=50_000, rank=16)  # item table 3.2 MB
+        head, info = _span(tmp_path, m)
+        segs = sorted(p.name for p in tmp_path.iterdir() if ".seg" in p.name)
+        assert info["segments"] == len(segs) >= 4
+        assert all((tmp_path / s).stat().st_size <= MIB for s in segs)
+        assert head.stat().st_size < 8192  # the head holds the header only
+        mf = modelfile.load_path(head)
+        assert [s["file"] for s in mf.segments] == segs
+        got = mf.entries()[0][1]
+        assert isinstance(got.item_factors, modelfile.SpannedArray)
+        assert len(got.item_factors.parts) == 4  # 16,384 rows of 64 B a segment
+        assert isinstance(got.user_factors, np.ndarray)  # fits one block
+        np.testing.assert_array_equal(np.asarray(got.item_factors), m.item_factors)
+        np.testing.assert_array_equal(got.user_factors, m.user_factors)
+        assert got.user_index == m.user_index and len(got.item_index) == 50_000
+
+    def test_spanned_array_reads_rows_where_they_lie(self, tmp_path):
+        m = _als(n_users=4, n_items=50_000, rank=16)
+        V = modelfile.load_path(_span(tmp_path, m)[0]).fields(0)["item_factors"]
+        assert V.shape == (50_000, 16) and len(V) == 50_000 and V.dtype == np.float32
+        ix = np.array([0, 49_999, 16_384, 16_383, 777, -1])
+        np.testing.assert_array_equal(V[ix], m.item_factors[ix])
+        np.testing.assert_array_equal(V[[[1, 2], [40_000, 3]]], m.item_factors[[[1, 2], [40_000, 3]]])
+        np.testing.assert_array_equal(V[16_000:33_000], m.item_factors[16_000:33_000])
+        np.testing.assert_array_equal(V.rows(49_990, 60_000), m.item_factors[49_990:])
+        inside = V.rows(100, 200)  # one part: a view of the mapping, no copy
+        assert inside.base is not None and not inside.flags.writeable
+        assert V.rows(7, 7).shape == (0, 16)
+
+    def test_row_sources_and_encoded_ids_are_never_whole(self, tmp_path):
+        """What a writer of a 12 GB table hands over: a row source asked for
+        one block at a time, ids already in their stored form."""
+        rng = np.random.default_rng(3)
+        V = rng.standard_normal((40_000, 16)).astype(np.float32)
+        asked = []
+
+        class Rows:
+            shape, dtype = V.shape, V.dtype
+
+            def rows(self, lo, hi):
+                asked.append((lo, hi))
+                return V[lo:hi]
+
+        ids = modelfile.EncodedIds(*modelfile._encode_ids([f"i{n}" for n in range(40_000)]))
+        fields = modelfile.Fields(
+            ("predictionio_tpu.models.recommendation", "ALSModel"),
+            {"user_index": BiMap({"u0": 0}), "item_index": ids,
+             "user_factors": V[:1].copy(), "item_factors": Rows(),
+             "user_scales": None, "item_scales": None},
+        )
+        head = tmp_path / "pio_model_rows.bin"
+        modelfile.write_spanning(head, [("arrays", fields)], "rows",
+                                 segment_bytes=MIB, workers=3)
+        assert sorted(asked) == [(0, 16_384), (16_384, 32_768), (32_768, 40_000)]
+        got = modelfile.load_path(head).entries()[0][1]
+        np.testing.assert_array_equal(np.asarray(got.item_factors), V)
+        assert got.item_index.inverse[39_999] == "i39999"
+
+    def test_block_checksums_cover_every_segment(self, tmp_path, monkeypatch):
+        head, _ = _span(tmp_path, _als(n_users=4, n_items=50_000, rank=16))
+        seg = tmp_path / (head.name + ".seg0002")
+        raw = bytearray(seg.read_bytes())
+        raw[1000] ^= 0xFF
+        seg.write_bytes(bytes(raw))
+        modelfile.load_path(head)  # O(pages touched): not read, not caught
+        monkeypatch.setenv("PIO_MODEL_VERIFY", "1")
+        with pytest.raises(ModelFileError, match="checksum mismatch"):
+            modelfile.load_path(head)
+
+    def test_a_missing_or_short_segment_is_a_named_error(self, tmp_path):
+        head, _ = _span(tmp_path, _als(n_users=4, n_items=50_000, rank=16))
+        seg = tmp_path / (head.name + ".seg0001")
+        whole = seg.read_bytes()
+        seg.write_bytes(whole[: len(whole) // 2])
+        with pytest.raises(ModelFileError, match="truncated"):
+            modelfile.load_path(head)
+        seg.unlink()
+        with pytest.raises(ModelFileError, match="missing"):
+            modelfile.load_path(head)
+
+    def test_a_spanning_head_does_not_load_from_bytes(self, tmp_path):
+        head, _ = _span(tmp_path, _als(n_users=4, n_items=50_000, rank=16))
+        with pytest.raises(ModelFileError, match="head file's path"):
+            modelfile.deserialize(head.read_bytes())
+
+    def test_one_row_wider_than_a_segment_is_refused(self, tmp_path):
+        with pytest.raises(ModelFileError, match="exceeds a segment"):
+            _span(tmp_path, _als(rank=64), segment_bytes=128)
+        assert not [p for p in tmp_path.iterdir() if ".seg" in p.name]
+
+    def test_one_file_models_still_load_and_are_written_as_before(self, tmp_path):
+        m = _als()
+        blob = modelfile.serialize([("arrays", m)], "one")
+        assert b'"version": 1' in blob and b"segments" not in blob[:4096]
+        p = tmp_path / "one.bin"
+        p.write_bytes(blob)
+        mf = modelfile.load_path(p)
+        assert mf.segments == []
+        got = mf.entries()[0][1]
+        assert isinstance(got.item_factors, np.ndarray)
+        np.testing.assert_array_equal(got.item_factors, m.item_factors)
+
+    def test_a_loaded_spanning_model_goes_back_into_one_file(self, tmp_path):
+        m = _als(n_users=4, n_items=50_000, rank=16)
+        got = modelfile.load_path(_span(tmp_path, m)[0]).entries()[0][1]
+        assert modelfile.can_encode(got)
+        again = modelfile.deserialize(modelfile.serialize([("arrays", got)], "x"))[0][1]
+        np.testing.assert_array_equal(again.item_factors, m.item_factors)
+
+    def test_persistence_spans_a_model_over_a_segment(self, tmp_path, monkeypatch):
+        """`pio train`'s save (core/persistence.py save_models): a model
+        over a segment's size goes to a local store spanning, and
+        `prepare_deploy`'s path loads it; the store's delete takes the
+        segments along."""
+        from predictionio_tpu.core import persistence
+        from predictionio_tpu.data.storage.localfs import (
+            LocalFSModels, LocalFSStorageClient,
+        )
+        from predictionio_tpu.models.recommendation import ALSAlgorithm
+
+        monkeypatch.setattr(modelfile, "SEGMENT_BYTES", MIB)
+        store = LocalFSModels(LocalFSStorageClient({"path": str(tmp_path)}))
+        algo = ALSAlgorithm()
+        big, small = _als(n_users=4, n_items=50_000, rank=16), _als()
+        persistence.save_models(store, [algo], [big], "big")
+        persistence.save_models(store, [algo], [small], "small")
+        names = sorted(p.name for p in tmp_path.iterdir())
+        assert [n for n in names if "big" in n and ".seg" in n]
+        assert not [n for n in names if "small" in n and ".seg" in n]
+        got = persistence.deserialize_model_path(store.local_path("big"), [algo], "big")[0]
+        np.testing.assert_array_equal(np.asarray(got.item_factors), big.item_factors)
+        assert store.delete("big")
+        assert not [p for p in tmp_path.iterdir() if "big" in p.name]
+
+
+class TestIdsFromTheBlob:
+    """An answer needs k ids: read from the blob and its offsets, the id
+    list never built."""
+
+    def _index(self, monkeypatch, n=1000):
+        blob, offs = modelfile._encode_ids([f"item-{i}-é" for i in range(n)])
+        bm = modelfile._LazyDenseBiMap(blob, offs)
+        built = []
+        monkeypatch.setattr(
+            modelfile._LazyDenseBiMap, "_ids",
+            lambda self: built.append(1) or pytest.fail("the id list was built"),
+        )
+        return bm, built
+
+    def test_inverse_lookups_decode_one_id(self, monkeypatch):
+        bm, built = self._index(monkeypatch)
+        inv = bm.inverse
+        assert inv[0] == "item-0-é" and inv[999] == "item-999-é"
+        assert inv[np.int32(17)] == "item-17-é"
+        assert inv.get(1000) is None and inv.get(-1, "x") == "x"
+        assert 5 in inv and 1000 not in inv and "item-5-é" not in inv
+        assert len(inv) == len(bm) == 1000
+        assert inv.inverse is bm
+        with pytest.raises(KeyError):
+            inv[1000]
+        with pytest.raises(KeyError):
+            inv[True]
+        assert not built and bm._fwd is None
+
+    def test_a_served_answer_builds_no_id_list(self, monkeypatch):
+        """batch_predict over a model loaded from a file: the item index's
+        inverse answers k ids from the blob (the USER index still decodes:
+        a query names its user by string)."""
+        from predictionio_tpu.models import recommendation as rec
+
+        m = _als(n_users=8, n_items=64, rank=4)
+        got = modelfile.deserialize(modelfile.serialize([("arrays", m)], "x"))[0][1]
+        sound = modelfile._LazyDenseBiMap._ids
+        monkeypatch.setattr(
+            modelfile._LazyDenseBiMap, "_ids",
+            lambda self: pytest.fail("the item id list was built")
+            if self is got.item_index else sound(self),
+        )
+        algo = rec.ALSAlgorithm(rec.ALSAlgorithmParams(rank=4))
+        res = algo.predict(got, rec.Query(user="u3", num=5))
+        want = rec.ALSAlgorithm(rec.ALSAlgorithmParams(rank=4)).predict(
+            m, rec.Query(user="u3", num=5))
+        assert [s.item for s in res.itemScores] == [s.item for s in want.itemScores]
+        assert got.item_index._fwd is None
+
+    def test_walking_the_whole_mapping_still_works(self):
+        blob, offs = modelfile._encode_ids(["a", "b", "c"])
+        inv = modelfile._LazyDenseBiMap(blob, offs).inverse
+        assert dict(inv.items()) == {0: "a", 1: "b", 2: "c"}
+        assert inv[2] == "c" and list(inv) == [0, 1, 2]
+        assert inv == BiMap({0: "a", 1: "b", 2: "c"})

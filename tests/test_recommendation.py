@@ -213,7 +213,7 @@ class TestTrainPredict:
         assert algo.predict(model, rec.Query(user="stranger")).itemScores == []
 
     def test_sharded_serving_matches_dense(self, seeded_app):
-        """Ring-sharded serving (mesh-resident item factors) returns the
+        """Sharded serving (item rows stationary on the mesh) returns the
         same recommendations as the single-device dense path."""
         td = rec.RecommendationDataSource(
             rec.DataSourceParams(app_name="RecApp")

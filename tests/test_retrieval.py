@@ -167,17 +167,6 @@ class TestRescoreExactness:
         assert (ids[:, 3:] == -1).all()
         assert set(ids[0, :3].tolist()) == {1, 2, 3}
 
-    def test_rescore_host_matches_device_rescore(self):
-        v, sc = _int8(128, 8, seed=13)
-        q = _dense(3, 8, seed=14)
-        cand = np.stack(
-            [np.random.default_rng(b).permutation(128)[:32] for b in range(3)]
-        ).astype(np.int32)
-        hs, hi = retrieval.rescore_host(q, v, sc, cand, 8)
-        ds, di = retrieval.rescore_top_k_batch(q, (v, sc), cand, k=8)
-        np.testing.assert_array_equal(hi, di)
-        np.testing.assert_allclose(hs, ds, rtol=1e-5, atol=1e-6)
-
     def test_near_ties_preserve_score_multiset(self):
         """Adversarial near-ties: 512 rows drawn from 16 archetypes plus
         1e-6 noise. Ids may legitimately differ between paths at equal
@@ -372,18 +361,6 @@ def mesh():
 
 
 class TestMeshCoarse:
-    def test_coarse_ring_matches_dense_ranking(self, mesh):
-        from predictionio_tpu.parallel.ring_topk import RingCatalog
-
-        vq, vs = _int8(208, 8, seed=20)  # not divisible by 8: padding
-        q = _dense(5, 8, seed=21)
-        cat = RingCatalog((vq, vs), mesh)
-        es, ei = cat.top_k(q, 8)
-        _, cand = cat.top_k(q, 64, coarse=True)
-        s, ids = retrieval.rescore_host(q, vq, vs, cand, 8)
-        np.testing.assert_array_equal(ids, ei)
-        np.testing.assert_allclose(s, es, rtol=1e-5, atol=1e-6)
-
     def test_sharded_two_stage_template_parity(self, mesh, monkeypatch):
         from predictionio_tpu.models import recommendation as rec
 

@@ -1,0 +1,220 @@
+"""Stationary-shard retrieval vs the plain reference and the one-chip chain.
+
+Runs on the virtual 8-device CPU mesh (conftest), the stand-in for a
+four-chip host — the analog of the reference testing "distributed"
+behavior on Spark local[4] (core/src/test/scala/.../workflow/
+BaseTest.scala:31-92). The plain reference is NumPy f32 over the whole
+table and knows nothing of shards.
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from predictionio_tpu.ops import retrieval
+from predictionio_tpu.parallel import shard_topk
+from predictionio_tpu.parallel.mesh import make_mesh, parse_axes, serving_mesh
+from predictionio_tpu.parallel.shard_topk import ShardedCatalog
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return make_mesh([("data", 4)])
+
+
+@pytest.fixture(scope="module")
+def mesh8():
+    return make_mesh([("data", 8)])
+
+
+@pytest.fixture()
+def two_stage(monkeypatch):
+    """Toy sizes that still go through shortlist -> rescore: threshold
+    1,000 rows, tiles of 512, no recall probe unless a test asks."""
+    monkeypatch.setenv("PIO_RETRIEVAL_THRESHOLD", "1000")
+    monkeypatch.setenv("PIO_RETRIEVAL_TILE", "512")
+    monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "0")
+
+
+def _tables(items, d=16, users=24, seed=0):
+    rng = np.random.default_rng(seed)
+    return (rng.standard_normal((users, d)).astype(np.float32),
+            rng.standard_normal((items, d)).astype(np.float32))
+
+
+def _reference(q, v, k):
+    """([B, k] scores, [B, k] ids) of the whole catalog in plain f32."""
+    sc = q.astype(np.float32) @ v.astype(np.float32).T
+    ids = np.argsort(-sc, axis=1, kind="stable")[:, :k]
+    return np.take_along_axis(sc, ids, axis=1), ids
+
+
+def _served(cat, U, uix, num_items, k):
+    return retrieval.top_k(
+        retrieval.UserRows(np.asarray(uix), None, lambda ix: U[ix]),
+        cat, num_items, None, k,
+    )
+
+
+class TestShardedChain:
+    @pytest.mark.parametrize("batch", [1, 2, 8])
+    def test_matches_the_plain_reference(self, mesh4, two_stage, batch):
+        U, V = _tables(9000)
+        cat = ShardedCatalog(V, mesh4)
+        s, ids = _served(cat, U, range(batch), len(V), 16)
+        rs, ri = _reference(U[:batch], V, 16)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=2e-6 * np.abs(rs).max())
+
+    @pytest.mark.parametrize("batch", [1, 2, 8])
+    def test_matches_the_one_chip_chain(self, mesh4, two_stage, batch):
+        U, V = _tables(9000, seed=1)
+        uix = np.arange(batch)
+        s, ids = _served(ShardedCatalog(V, mesh4), U, uix, len(V), 16)
+        one = retrieval.top_k(
+            retrieval.UserRows(uix, jax.numpy.asarray(U), lambda ix: U[ix]),
+            jax.numpy.asarray(V), len(V), retrieval.CoarseCatalog(V), 16,
+        )
+        np.testing.assert_array_equal(ids, one[1])
+        np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
+
+    def test_eight_shards(self, mesh8, two_stage):
+        U, V = _tables(9000, seed=2)
+        s, ids = _served(ShardedCatalog(V, mesh8), U, [3, 4, 5], len(V), 8)
+        rs, ri = _reference(U[3:6], V, 8)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=4e-6)
+
+    def test_rows_the_mesh_does_not_divide_pad_with_minus_one(self, mesh4, two_stage):
+        """4 does not divide 9,001 rows, nor do the tiles fill: the
+        padding carries id -1 and is never served, even where k asks for
+        more than a small catalog has."""
+        U, V = _tables(9001, seed=3)
+        cat = ShardedCatalog(V, mesh4)
+        assert cat.rows_per_shard * 4 > len(V)
+        assert int((np.asarray(cat._ids) >= 0).sum()) == len(V)
+        s, ids = _served(cat, U, range(4), len(V), 16)
+        assert ids.min() >= 0 and ids.max() < len(V)
+        np.testing.assert_array_equal(ids, _reference(U[:4], V, 16)[1])
+        tiny = ShardedCatalog(V[:6], mesh4)
+        s, ids = tiny.exact_top_k(U[:2], 16)
+        assert (np.sort(ids, axis=1)[:, -6:] == np.arange(6)).all()
+        assert (ids[:, 6:] == -1).all()
+
+    def test_a_shortlist_wider_than_a_tile_clamps(self, mesh4, two_stage, monkeypatch):
+        """k' = 8 * pow2(k) = 1,024 against tiles of 128 rows: a shard
+        can shortlist a tile's width at most, and the answer is still
+        the reference's."""
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "128")
+        U, V = _tables(4000, seed=4)
+        cat = ShardedCatalog(V, mesh4)
+        assert cat.tile == 128 and retrieval.two_stage_k(128, len(V)) == 128
+        s, ids = _served(cat, U, range(2), len(V), 128)
+        assert ids.shape == (2, 128)
+        np.testing.assert_array_equal(ids, _reference(U[:2], V, 128)[1])
+
+    def test_the_shards_answers_merged_are_the_uncut_reference(self, mesh4):
+        """The shares add up: each shard's own top-k, taken from its rows
+        alone by the plain reference, merged by the program's merge, is
+        the whole catalog's top-k."""
+        U, V = _tables(1203, seed=5)
+        k, r = 8, -(-len(V) // 4)
+        parts = [_reference(U[:5], V[i * r:(i + 1) * r], k) for i in range(4)]
+        all_s = np.stack([p[0] for p in parts])
+        all_i = np.stack([p[1] + i * r for i, p in enumerate(parts)]).astype(np.int32)
+        s, ids = shard_topk._merge(jax.numpy.asarray(all_s), jax.numpy.asarray(all_i), k)
+        rs, ri = _reference(U[:5], V, k)
+        np.testing.assert_array_equal(np.asarray(ids), ri)
+        np.testing.assert_array_equal(np.asarray(s), rs)
+
+    def test_one_read_a_dispatch_and_the_counters(self, mesh4, two_stage):
+        U, V = _tables(9000, seed=6)
+        cat = ShardedCatalog(V, mesh4)
+        before = retrieval.stats_block()
+        _served(cat, U, range(3), len(V), 16)
+        after = retrieval.stats_block()
+        assert after["host_reads"] - before["host_reads"] == 1
+        assert after["sharded_queries"] - before["sharded_queries"] == 3
+        assert after["two_stage_queries"] == before["two_stage_queries"]
+        # 4 shards x 4 padded rows x k 16 x 8 B
+        assert after["shard_gather_bytes"] - before["shard_gather_bytes"] == 4 * 4 * 16 * 8
+        assert after["shards"] == 4
+
+    def test_the_probe_runs_the_sharded_exact_program(self, mesh4, two_stage, monkeypatch):
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "1")
+        U, V = _tables(9000, seed=7)
+        cat = ShardedCatalog(V, mesh4)
+        probes = retrieval._m_probes.value()
+        reads = retrieval._m_host_reads.value()
+        _served(cat, U, range(2), len(V), 16)
+        assert retrieval._m_probes.value() == probes + 1
+        assert retrieval._m_probe_recall.value() == 1.0
+        assert retrieval._m_host_reads.value() == reads + 2  # the answer, the probe
+
+    def test_under_the_threshold_the_exact_program_serves(self, mesh4):
+        U, V = _tables(300, seed=8)
+        s, ids = _served(ShardedCatalog(V, mesh4), U, range(5), len(V), 4)
+        rs, ri = _reference(U[:5], V, 4)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=4e-6)
+
+    def test_varied_traffic_reuses_compiled_programs(self, mesh4, two_stage):
+        U, V = _tables(9000, seed=9)
+        cat = ShardedCatalog(V, mesh4)
+        _served(cat, U, range(3), len(V), 16)  # bucket 4
+        before = shard_topk._sharded_topk._cache_size()
+        _served(cat, U, range(4), len(V), 16)
+        _served(cat, U, [7, 8, 9], len(V), 16)
+        assert shard_topk._sharded_topk._cache_size() == before
+
+    def test_int8_pairs_stage_dequantized(self, mesh4, two_stage):
+        rng = np.random.default_rng(10)
+        vq = rng.integers(-127, 128, (2000, 8)).astype(np.int8)
+        vs = rng.uniform(0.01, 0.02, 2000).astype(np.float32)
+        U = rng.standard_normal((4, 8)).astype(np.float32)
+        cat = ShardedCatalog((vq, vs), mesh4)
+        s, ids = _served(cat, U, range(4), 2000, 8)
+        rs, ri = _reference(U, vq.astype(np.float32) * vs[:, None], 8)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=2e-6)
+
+    def test_rules_are_refused_by_name(self, mesh4, two_stage):
+        from predictionio_tpu.ops.topk import Rules
+
+        U, V = _tables(2000, seed=11)
+        rules = Rules(avail=None, cats=(), qcat=None, has_cat=None, ex=None)
+        with pytest.raises(ValueError, match="under rules"):
+            retrieval.top_k(retrieval.Vectors(U[:1], rules),
+                            ShardedCatalog(V, mesh4), len(V), None, 8)
+
+    def test_a_mesh_of_two_axes_is_refused(self):
+        with pytest.raises(ValueError, match="1-D mesh"):
+            ShardedCatalog(_tables(64)[1], make_mesh([("data", 2), ("model", 2)]))
+
+
+class TestServingMesh:
+    def test_default_is_every_device(self, monkeypatch):
+        monkeypatch.delenv("PIO_MESH", raising=False)
+        assert dict(serving_mesh().shape) == {"data": len(jax.devices())}
+
+    def test_pio_mesh_names_the_shards(self, monkeypatch):
+        monkeypatch.setenv("PIO_MESH", "data=4")
+        assert dict(serving_mesh().shape) == {"data": 4}
+
+    @pytest.mark.parametrize("spec", ["model=4", "data=2,model=2"])
+    def test_another_axis_is_refused(self, monkeypatch, spec):
+        monkeypatch.setenv("PIO_MESH", spec)
+        with pytest.raises(ValueError, match="one axis"):
+            serving_mesh()
+
+    @pytest.mark.parametrize("spec", ["data", "data=0", "=4", "data=x", "a=-1,b=-1"])
+    def test_a_bad_spec_is_refused(self, spec):
+        with pytest.raises(ValueError):
+            parse_axes(spec)
+
+    def test_deploy_refuses_a_bad_mesh_before_anything_loads(self):
+        from predictionio_tpu.cli.main import _parse_mesh
+
+        with pytest.raises(SystemExit, match="--mesh"):
+            _parse_mesh("data=none")
+        assert _parse_mesh("data=4") == [("data", 4)]
