@@ -24,7 +24,14 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    groups, and the k' best of those groups' scores — exactly the
    tile's top-k', from selections over T/G + k'G elements instead of T
    (``tile_select_group`` decides from T and k' alone; every mode and
-   the masked scan share ``_tile_top_k``). And a step scores its tile
+   the masked scan share ``_tile_top_k``). ONE query does not select in
+   the step at all (``scan_select`` decides from B, NT, T and k' alone):
+   its step scores the tile and writes the [1, T] scores and their
+   [1, T/G] group maxima to the scan's stacked outputs, and the k' best
+   come once, after the loop, from the same two levels over ALL tiles'
+   groups (``_select_deferred``) — the same shortlist, its values
+   bit-equal; a batch of two or more keeps the per-tile selection and
+   the running merge. And a step scores its tile
    in one of two forms (``score_form`` decides from B and D alone):
    the batch's f32 rows against the tile cast to f32 ("rows": two or
    more queries, or D >= 128), or — ONE query whose rank leaves the
@@ -92,7 +99,8 @@ next program is compiled for a layout the buffer does not have.)
 
 Observability: ``pio_retrieval_*`` metrics (docs/observability.md);
 ``pio_retrieval_tile_select_total{path}`` says which selection a
-shortlist call's program holds (``two_level`` / ``plain``) and
+shortlist call's program holds (``deferred`` / ``two_level`` /
+``plain``) and
 ``pio_retrieval_score_form_total{form}`` which score (``dot`` /
 ``rows``); each
 rescore program publishes the temporary bytes its compiled form needs
@@ -106,8 +114,10 @@ the one blocking read that ends the chain as ``dispatch.fetch``
 (``pio_retrieval_fetch_seconds``: the device time of both programs and
 the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
 one a dispatch through ``top_k``), and the two serving programs
-carry ``jax.named_scope`` s (``retrieval.shortlist.*``,
-``retrieval.rescore.*``) that name their ops in a trace viewer.
+carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a single's
+step is ``score`` / ``mask`` / ``group_max`` and ``select`` follows
+the loop; a batch's is ``score`` / ``mask`` / ``tile_topk`` / ``merge``
+— and ``retrieval.rescore.*``) that name their ops in a trace viewer.
 """
 
 from __future__ import annotations
@@ -255,10 +265,12 @@ _m_probes = obs_metrics.counter(
 _m_tile_select = {
     path: obs_metrics.counter(
         "pio_retrieval_tile_select_total",
-        "shortlist calls by how a scan step selects its tile's k' best",
+        "shortlist calls by where the scan selects its k' best: deferred = "
+        "once, after the tile loop (a single query); two_level / plain = "
+        "every step its tile's, by group maxima or one top_k",
         path=path,
     )
-    for path in ("two_level", "plain")
+    for path in ("deferred", "two_level", "plain")
 }
 
 _m_score_form = {
@@ -273,6 +285,15 @@ _m_score_form = {
 }
 
 _probe_clock = itertools.count(1)
+
+
+def _count_scan(b: int, nt: int, t: int, k: int, d: int, mode: str) -> None:
+    """One shortlist call, by the two rules its program was traced
+    with: where it selects (``scan_select``) and how it scores
+    (``score_form``)."""
+    _m_shortlist_size.observe(float(k))
+    _m_tile_select[scan_select(b, nt, t, k)].inc()
+    _m_score_form[score_form(b, d, mode)].inc()
 
 
 def probe_recall(two_stage_ids, exact_ids) -> float:
@@ -353,7 +374,9 @@ def tile_select_group(t: int, k: int) -> int:
     elements more than a quarter of T (a k' that nears the tile). G is
     a row of 128 lanes where that passes, else the power of two at or
     above sqrt(T/k'), which balances the two. Decided from the two
-    shapes alone: at trace time, and on the host for the counter."""
+    shapes alone: at trace time, and on the host for the counter
+    (``scan_select``, which also says WHERE the selection runs: in
+    every step, or — one query — once after the loop, with this G)."""
     if t < _MIN_SPLIT:
         return 0
     for g in (_LANES, _pow2(int(np.ceil(np.sqrt(t / k))))):
@@ -380,6 +403,14 @@ def _two_level_top_k(sc, k: int, g: int):
     groups = sc.reshape(b, t // g, g)
     _, gix = jax.lax.top_k(groups.max(axis=2), k)
     cand = jnp.take_along_axis(groups, gix[:, :, None], axis=1)
+    return _best_of_groups(cand, gix)
+
+
+def _best_of_groups(cand, gix):
+    """The second level: the k best of the [B, k, G] scores of the
+    chosen groups ``gix`` (through ``_tile_top_k`` again), and their
+    positions ``group * G + lane`` in the array the groups number."""
+    b, k, g = cand.shape
     ts, cix = _tile_top_k(cand.reshape(b, k * g), k)
     return ts, _pick(gix, cix // g) * g + cix % g
 
@@ -391,6 +422,53 @@ def _tile_top_k(sc, k: int):
     if not g:
         return jax.lax.top_k(sc, k)
     return _two_level_top_k(sc, k, g)
+
+
+# ONE query selects once, after the tile loop. A step's selection is
+# thrown away almost whole — of the 36 x 128 candidates that a 36-tile
+# scan sorts, merges and looks up ids for, 128 survive — and at B = 1 it
+# was a fifth to a quarter of the loop (PERF.md section 6, PR 33). The
+# argument above ``_MIN_SPLIT`` never needed the tile: read "catalog" for
+# "tile" and the k' groups with the largest maxima among ALL tiles'
+# groups hold the catalog's k' best. So a single's step only scores its
+# tile and writes
+# the [1, T] scores and their [1, T/G] group maxima to the scan's
+# stacked outputs (B x NT x T x 4 bytes: 38 MB at 36 tiles), and
+# ``_select_deferred`` picks once: the k' best groups of NT x T/G maxima,
+# their [1, k'G] scores read back out of the stored array, the k' best
+# of those, 128 ids looked up. Two or more queries keep the selection in
+# the step, as it was: B x NT x T x 4 bytes of scores is 604 MB a call at
+# B = 16, and the batched programs' rhythm is another measurement
+# (PERF.md section 6 has the deferred body alone at B = 2..16).
+
+
+def scan_select(b: int, nt: int, t: int, k: int) -> str:
+    """Where a scan of ``nt`` tiles of ``t`` rows for ``b`` queries
+    selects its k' = ``k`` best: "deferred" — once, after the loop,
+    from the stored scores by their group maxima — where b == 1 and the
+    tile splits into groups (``tile_select_group`` leaves every tile at
+    least k' of them, so the ``nt`` tiles have k' groups to pick from
+    whatever ``nt`` is); "two_level" — every step its own tile's, merged
+    into a running best — for every batch of two or more; "plain" — one
+    ``lax.top_k`` a step — where ``tile_select_group`` splits nothing.
+    Decided from the shapes alone: at trace time, and on the host for
+    the counter."""
+    if not tile_select_group(t, k):
+        return "plain"
+    return "deferred" if b == 1 else "two_level"
+
+
+def _select_deferred(scores, maxima, ids, k: int):
+    """The k best of each query over ALL tiles: [NT, B, T/G, G] stored
+    scores, their [NT, B, T/G] group maxima and the [NT, T] row ids ->
+    ([B, k] scores, [B, k] ids). ``_two_level_top_k`` with the catalog
+    in the tile's place; the values are read, not recomputed."""
+    nt, b, per, g = scores.shape
+    mx = maxima.transpose(1, 0, 2).reshape(b, nt * per)
+    _, gix = _tile_top_k(mx, k)  # groups, numbered tile-major
+    cand = scores[gix // per, jnp.arange(b)[:, None], gix % per]
+    best_s, pos = _best_of_groups(cand, gix)  # rows of the stored catalog
+    return best_s, ids[pos // (per * g), pos % (per * g)]
 
 
 # One query against a [T, D] tile: XLA:TPU turns the one-row product
@@ -443,12 +521,19 @@ def _split_bf16(q):
     return jnp.concatenate([hi, mid, lo]).astype(jnp.bfloat16)
 
 
-def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
+def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
+                 select: str | None = None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
     tile scores [B, T] in the catalog's storage precision, takes the
     tile's top-k', and merges into the running best — the [B, I] score
     matrix and the full-catalog top-k never materialize, which is where
-    the win over the exact path comes from once I outgrows cache.
+    the win over the exact path comes from once I outgrows cache. ONE
+    query (``scan_select`` -> "deferred") leaves the selection to the
+    end: its step scores the tile and keeps the scores and their group
+    maxima, and the k' best come from one selection over all tiles —
+    the same shortlist, its values bit-equal. ``select`` overrides the
+    rule: for the tests and measurements that compare the two bodies on
+    one input; nothing served passes it.
 
     ``mode``: "int8" (values*scale columns, f32 GEMM on cast values),
     "int8_dot" (int8 x int8 -> int32 accumulation, quantized queries —
@@ -463,8 +548,10 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
     one it was before rules existed, op for op."""
     B = q.shape[0]
     dot = score_form(B, q.shape[1], mode) == "dot"
+    nt, t = ids.shape
+    deferred = (select or scan_select(B, nt, t, k)) == "deferred"
+    g = tile_select_group(t, k)  # a deferred step keeps a maximum a group
     if rules is not None:
-        nt, t = ids.shape
         with jax.named_scope("retrieval.shortlist.mask"):
             ex = jnp.where(rules.ex >= 0, rules.ex, nt * t)  # pads drop
             hit = jnp.zeros((nt, B, t), bool).at[
@@ -484,7 +571,6 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
         q3 = _split_bf16(q)
 
     def step(carry, xs):
-        best_s, best_i = carry
         if rules is not None:
             xs, (av, cs, ht) = xs
         if scales is None:
@@ -519,6 +605,10 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
             with jax.named_scope("retrieval.shortlist.mask"):
                 ok = rows_allowed(av, cs, ht, rules.qcat, rules.has_cat)
                 sc = jnp.where(ok, sc, NEG_INF)
+        if deferred:
+            with jax.named_scope("retrieval.shortlist.group_max"):
+                groups = sc.reshape(B, t // g, g)
+                return None, (groups, groups.max(axis=2))
         with jax.named_scope("retrieval.shortlist.tile_topk"):
             ts, tix = _tile_top_k(sc, k)
             ti = jnp.take_along_axis(
@@ -527,19 +617,27 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
             if rules is not None:
                 ti = jnp.where(ts > NEG_INF / 2, ti, -1)
         with jax.named_scope("retrieval.shortlist.merge"):
+            best_s, best_i = carry
             cs = jnp.concatenate([best_s, ts], axis=1)
             ci = jnp.concatenate([best_i, ti], axis=1)
             best_s, ix = jax.lax.top_k(cs, k)
             best_i = jnp.take_along_axis(ci, ix, axis=1)
         return (best_s, best_i), None
 
+    xs = (tiles, ids) if scales is None else (tiles, scales, ids)
+    if rules is not None:
+        xs = (xs, masks)
+    if deferred:
+        _, (scores, maxima) = jax.lax.scan(step, None, xs)
+        with jax.named_scope("retrieval.shortlist.select"):
+            best_s, best_i = _select_deferred(scores, maxima, ids, k)
+            if rules is not None:
+                best_i = jnp.where(best_s > NEG_INF / 2, best_i, -1)
+        return best_s, best_i
     init = (
         jnp.full((B, k), NEG_INF, jnp.float32),
         jnp.full((B, k), -1, jnp.int32),
     )
-    xs = (tiles, ids) if scales is None else (tiles, scales, ids)
-    if rules is not None:
-        xs = (xs, masks)
     (best_s, best_i), _ = jax.lax.scan(step, init, xs)
     return best_s, best_i
 
@@ -707,11 +805,8 @@ class CoarseCatalog:
                     q, self._tiles, self._scales, self._ids, rules, k,
                     self.mode,
                 )
-        _m_shortlist_size.observe(float(k))
-        _m_tile_select[
-            "two_level" if tile_select_group(self.tile, k) else "plain"
-        ].inc()
-        _m_score_form[score_form(len(q), self.dim, self.mode)].inc()
+        _count_scan(len(q), self._ids.shape[0], self.tile, k, self.dim,
+                    self.mode)
         return Scan(q, s, ids)
 
     def shortlist(self, queries, k: int, rules: Rules | None = None):
@@ -1126,7 +1221,8 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
             _m_exact.inc(n)
         return s, ids
     _m_sharded.inc(n)
-    _m_shortlist_size.observe(float(min(kp, catalog.tile)))
+    _count_scan(len(q), catalog.tiles_per_shard, catalog.tile,
+                min(kp, catalog.tile), catalog.dim, catalog.mode)
     probe(
         ids[0, :probe_n],
         lambda: _fetch(catalog.launch_exact(q[:1], k), 1)[1][0, :probe_n],

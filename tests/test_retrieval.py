@@ -11,6 +11,8 @@ all (the byte-parity regression).
 
 from __future__ import annotations
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -630,19 +632,23 @@ class TestStageSpans:
 # -- the tile's top-k' in two exact levels ------------------------------------
 
 
-def _operands(jaxpr, primitive):
-    """The first operand's aval of every ``primitive`` equation in
-    ``jaxpr``, sub-jaxprs (the scan's body) included."""
-    avals = []
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, sub-jaxprs (the scan's body)
+    included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == primitive:
-            avals.append(eqn.invars[0].aval)
+        yield eqn
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    avals.extend(_operands(inner, primitive))
-    return avals
+                    yield from _eqns(inner)
+
+
+def _operands(jaxpr, primitive):
+    """The first operand's aval of every ``primitive`` equation in
+    ``jaxpr``."""
+    return [e.invars[0].aval for e in _eqns(jaxpr)
+            if e.primitive.name == primitive]
 
 
 def _top_k_eqns(jaxpr):
@@ -1038,7 +1044,8 @@ class TestScoreForm:
     ):
         """One ``dot_general``, its query operand the three bf16 terms,
         the tile in no wider dtype than bf16; every ``top_k`` operand —
-        group maxima, candidates, merge — still has ONE row."""
+        the group maxima of all tiles and the candidates, once, after
+        the loop (PR 33) — still has ONE row."""
         import jax.numpy as jnp
 
         nt, t, d, k = 2, 1 << 13, 64, 16
@@ -1049,15 +1056,16 @@ class TestScoreForm:
         assert query.shape[0] >= 3 and query.shape[1] == d
         assert query.dtype == jnp.bfloat16
         assert sorted(_top_k_eqns(jaxpr)) == sorted(
-            [(1, t // g), (1, k * g), (1, 2 * k)]
+            [(1, nt * t // g), (1, k * g)]
         )
 
     @pytest.mark.parametrize("b,d", [(2, 64), (16, 64), (1, 128), (2, 128)])
     @pytest.mark.parametrize("masked", [False, True])
     def test_every_other_shape_keeps_its_program(self, b, d, masked):
         """B >= 2 and D >= 128: the f32 queries as they are against the
-        tile cast to f32, the ``top_k`` shapes of PR 27 (the StableHLO
-        of these programs is the parent's: hashes in CHANGES.md)."""
+        tile cast to f32; a batch keeps the ``top_k`` shapes of PR 27
+        (the StableHLO of the B >= 2 programs is the parent's: hashes in
+        CHANGES.md), a single at rank 128 selects after the loop."""
         import jax.numpy as jnp
 
         nt, t, k = 2, 1 << 13, 16
@@ -1068,6 +1076,7 @@ class TestScoreForm:
         (query,) = _operands(jaxpr, "dot_general")
         assert query.shape == (b, d) and query.dtype == jnp.float32
         assert sorted(_top_k_eqns(jaxpr)) == sorted(
+            [(1, nt * t // g), (1, k * g)] if b == 1 else
             [(b, t // g), (b, k * g), (b, 2 * k)]
         )
 
@@ -1103,6 +1112,254 @@ class TestScoreForm:
         for f, n in after.items():
             assert scraped[
                 f'pio_retrieval_score_form_total{{form="{f}"}}'
+            ] == n
+
+
+# -- one query selects once, after the tile loop -------------------------------
+
+
+def _scan_body(jaxpr):
+    """The body of the one ``scan`` in ``jaxpr``."""
+    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    return scan.params["jaxpr"].jaxpr
+
+
+def _tile_rules(rules, i, t):
+    """``rules`` cut to tile ``i`` of ``t`` rows: its own vectors, the
+    queries' lists in the tile's positions."""
+    import jax.numpy as jnp
+
+    ex = np.asarray(rules.ex)
+    inside = (ex >= i * t) & (ex < (i + 1) * t)
+    return rules._replace(
+        avail=rules.avail[i * t: (i + 1) * t],
+        cats=tuple(c[i * t: (i + 1) * t] for c in rules.cats),
+        ex=jnp.asarray(np.where(inside, ex - i * t, -1).astype(np.int32)),
+    )
+
+
+class TestDeferredSelect:
+    """A single query's scan selects once, after the loop
+    (``scan_select`` -> "deferred"): against the per-tile body on the
+    same inputs (the rule forced through ``_coarse_scan``'s ``select``)
+    and against ``lax.top_k`` over the whole guarded score row — the
+    scores bit-equal, the ids equal wherever the scores are distinct."""
+
+    T, K = 1 << 13, 128
+
+    @staticmethod
+    def _scan(cat, q, k, rules, select):
+        import jax
+
+        return jax.device_get(jax.jit(
+            lambda q, tiles, scales, ids, rules: retrieval._coarse_scan(
+                q, tiles, scales, ids, k, cat.mode, rules, select=select
+            )
+        )(q, cat._tiles, cat._scales, cat._ids, rules))
+
+    def _row(self, cat, q, rules):
+        """The whole guarded (and masked) [B, stored] score row, a tile
+        at a time through the plain body at k = T: the program this
+        module had before any selection was split, which keeps every
+        score of its one tile. Positions of rows that may not be served
+        hold ``NEG_INF``."""
+        nt, t = cat._ids.shape
+        row = np.full((len(q), nt * t), retrieval.NEG_INF, np.float32)
+        for i in range(nt):
+            one = SimpleNamespace(
+                mode=cat.mode, _tiles=cat._tiles[i: i + 1],
+                _scales=None if cat._scales is None else cat._scales[i: i + 1],
+                # positions, not ids: padding keeps its place in the row
+                _ids=np.arange(i * t, (i + 1) * t, dtype=np.int32)[None],
+            )
+            guard = np.asarray(cat._ids[i]) >= 0
+            s, pos = self._scan(
+                one, q, t, None if rules is None else _tile_rules(rules, i, t),
+                "plain",
+            )
+            for b in range(len(q)):
+                keep = pos[b] >= 0
+                row[b, pos[b][keep]] = s[b][keep]
+            row[:, i * t: (i + 1) * t][:, ~guard] = retrieval.NEG_INF
+        return row
+
+    def _catalog(self, mode, d, rows, seed, twin_tiles=False):
+        table = _int8(rows, d, seed=seed)
+        if twin_tiles:  # tile 1 repeats tile 0: every score comes twice
+            vals, scales = table = tuple(np.array(a) for a in table)
+            vals[self.T: 2 * self.T] = vals[: self.T]
+            scales[self.T: 2 * self.T] = scales[: self.T]
+        return CoarseCatalog(table, tile=self.T, mode=mode)
+
+    @pytest.mark.parametrize("case", [
+        "whole_tiles", "padded_last_tile", "one_tile", "five_tiles",
+        "small_category", "own_rows_excluded", "unavailable_rows",
+        "equal_scores_across_tiles", "batch_of_four_forced",
+    ])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_the_shortlist_it_was(self, mode, d, case):
+        import jax
+
+        t, k = self.T, self.K
+        rows = {"padded_last_tile": 2 * t + 1000, "one_tile": t,
+                "five_tiles": 5 * t}.get(case, 3 * t)
+        b = 4 if case == "batch_of_four_forced" else 1
+        cat = self._catalog(mode, d, rows, seed=61,
+                            twin_tiles=case == "equal_scores_across_tiles")
+        nt = cat._ids.shape[0]
+        assert nt == -(-rows // t) and cat.tile == t
+        want_rule = "deferred" if b == 1 else "two_level"
+        assert retrieval.scan_select(b, nt, t, k) == want_rule
+        assert retrieval.score_form(b, d, mode) == (
+            "dot" if b == 1 and d == 64 and mode != "int8_dot" else "rows"
+        )
+        q = _dense(b, d, seed=62)
+        rules = None
+        if case == "small_category":
+            small = np.random.default_rng(63).permutation(rows)[:40]
+            rules = _rules(cat.stored_rows, b, small_cat=small,
+                           qcat=np.asarray([[1]], np.int32))
+        elif case == "own_rows_excluded":
+            first = self._scan(cat, q, k, None, "two_level")[1]
+            rules = _rules(cat.stored_rows, b, ex=first[:, :4].astype(np.int32))
+        elif case == "unavailable_rows":
+            rules = _rules(cat.stored_rows, b)
+        got_s, got_i = self._scan(cat, q, k, rules, "deferred")
+        per_s, per_i = self._scan(cat, q, k, rules, "two_level")
+        np.testing.assert_array_equal(
+            got_s.view(np.uint32), per_s.view(np.uint32)
+        )
+        row = self._row(cat, q, rules)
+        ref_s, ref_pos = jax.device_get(jax.lax.top_k(row, k))
+        np.testing.assert_array_equal(
+            got_s.view(np.uint32), ref_s.view(np.uint32)
+        )
+        flat_ids = np.asarray(cat._ids).reshape(-1)
+        ref_i = flat_ids[ref_pos]
+        if rules is not None:
+            ref_i = np.where(ref_s > retrieval.NEG_INF / 2, ref_i, -1)
+        if case == "equal_scores_across_tiles":
+            # another choice among equals: every id holds its score, once
+            assert len(np.unique(got_s)) < k
+            served = got_i[0]
+            assert len(set(served.tolist())) == k and (served >= 0).all()
+            np.testing.assert_array_equal(row[0][served], got_s[0])
+        else:
+            assert all(len(np.unique(r[r > retrieval.NEG_INF / 2]))
+                       == (r > retrieval.NEG_INF / 2).sum() for r in got_s)
+            np.testing.assert_array_equal(got_i, per_i)
+            np.testing.assert_array_equal(got_i, ref_i)
+        if case == "small_category":
+            live = int((got_i >= 0).sum())
+            assert 0 < live < k and (got_i[0, live:] == -1).all()
+            assert (got_s[0, live:] == np.float32(retrieval.NEG_INF)).all()
+        elif case == "own_rows_excluded":
+            assert not set(got_i[0].tolist()) & set(first[0, :4].tolist())
+        elif case == "unavailable_rows":
+            assert (got_i % 97 != 0).all()
+        elif case == "padded_last_tile":
+            assert (got_i >= 0).all() and got_i.max() < rows
+
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_a_shortlist_too_wide_to_split_keeps_the_step_it_had(self, mode):
+        """k' = T/2: ``tile_select_group`` splits nothing, the rule
+        says "plain" for a single too, and the served scan is the
+        ``lax.top_k`` of the row."""
+        import jax
+
+        t, k = self.T, self.T // 2
+        assert retrieval.scan_select(1, 3, t, k) == "plain"
+        cat = self._catalog(mode, 64, 2 * t + 1000, seed=64)
+        q = _dense(1, 64, seed=65)
+        before = retrieval.stats_block()["tile_select"]
+        s, ids = cat.shortlist(q, k)
+        after = retrieval.stats_block()["tile_select"]
+        assert after["plain"] == before["plain"] + 1
+        assert after["deferred"] == before["deferred"]
+        ref_s, ref_pos = jax.device_get(
+            jax.lax.top_k(self._row(cat, q, None), k)
+        )
+        np.testing.assert_array_equal(s.view(np.uint32), ref_s.view(np.uint32))
+        np.testing.assert_array_equal(
+            ids, np.asarray(cat._ids).reshape(-1)[ref_pos]
+        )
+
+    @pytest.mark.parametrize("b,nt,t,k,want", [
+        (1, 36, 1 << 18, 128, "deferred"),   # yambda
+        (1, 16, 1 << 18, 128, "deferred"),   # both Taobao configurations
+        (1, 46, 1 << 18, 128, "deferred"),   # a chip of the sharded catalog
+        (1, 1, 1 << 18, 128, "deferred"),
+        (1, 3, 1 << 13, 16, "deferred"),
+        (1, 36, 1 << 18, 1024, "deferred"),
+        (2, 36, 1 << 18, 128, "two_level"),
+        (4, 36, 1 << 18, 128, "two_level"),
+        (8, 16, 1 << 18, 128, "two_level"),
+        (16, 36, 1 << 18, 128, "two_level"),
+        (1, 36, 1 << 18, 1 << 14, "plain"),  # k' nears the tile
+        (1, 3, 1 << 12, 16, "plain"),        # under _MIN_SPLIT
+        (1, 3, 256, 64, "plain"),            # the CPU fixtures' tiles
+        (16, 3, 256, 64, "plain"),
+    ])
+    def test_the_rule(self, b, nt, t, k, want):
+        assert retrieval.scan_select(b, nt, t, k) == want
+        assert (want == "plain") == (not retrieval.tile_select_group(t, k))
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("mode,d", [
+        ("bf16", 64), ("bf16", 128), ("int8", 64), ("int8_dot", 64),
+    ])
+    def test_a_singles_step_selects_nothing_and_a_pairs_still_does(
+        self, mode, d, masked
+    ):
+        """The jaxpr: the B = 1 scan body holds no ``sort`` / ``top_k``
+        — score, guard, mask, one maximum a group — and every selection
+        stands after the loop; the B = 2 body holds PR 27's three."""
+        nt, t, k = 2, 1 << 13, 16
+        g = retrieval.tile_select_group(t, k)
+        single = _scan_jaxpr(1, nt, t, d, k, _rules(nt * t, 1) if masked
+                             else None, mode)
+        inside = [e.primitive.name for e in _eqns(_scan_body(single))]
+        assert not {"sort", "top_k", "gather", "concatenate"} & set(inside)
+        assert inside.count("reduce_max") == 1
+        groups = nt * t // g
+        g2 = retrieval.tile_select_group(groups, k)
+        assert not g2 and retrieval.tile_select_group(k * g, k) == 0
+        assert sorted(_top_k_eqns(single)) == [(1, k * g), (1, groups)]
+        pair = _scan_jaxpr(2, nt, t, d, k, _rules(nt * t, 2) if masked
+                           else None, mode)
+        assert sorted(_top_k_eqns(_scan_body(pair))) == sorted(
+            [(2, t // g), (2, k * g), (2, 2 * k)]
+        )
+        assert _top_k_eqns(pair) == _top_k_eqns(_scan_body(pair))
+
+    def test_the_benchmark_shapes_select_in_small_sorts_once(self):
+        """36 tiles of 2^18 at k' = 128: [1, 73728] maxima through the
+        tiles' helper ([1, 576] maxima, [1, 16384] candidates as
+        [1, 1024] + [1, 2048]), then the [1, 16384] scores of the chosen
+        groups the same way: five selections of at most 2,048 a call."""
+        jaxpr = _scan_jaxpr(1, 36, 1 << 18, 8, 128)
+        assert not _top_k_eqns(_scan_body(jaxpr))
+        assert sorted(_top_k_eqns(jaxpr)) == [
+            (1, 576), (1, 1024), (1, 1024), (1, 2048), (1, 2048)
+        ]
+
+    @pytest.mark.parametrize("b,path", [(1, "deferred"), (2, "two_level"),
+                                        (3, "two_level")])
+    def test_the_counter_counts_one_a_call(self, b, path):
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        cat = self._catalog("bf16", 16, 2 * self.T + 5, seed=66)
+        before = retrieval.stats_block()["tile_select"]
+        cat.shortlist(_dense(b, 16, seed=67), 64)
+        after = retrieval.stats_block()["tile_select"]
+        assert set(after) == {"deferred", "two_level", "plain"}
+        for p in after:
+            assert after[p] == before[p] + (p == path)
+        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
+        for p, n in after.items():
+            assert scraped[
+                f'pio_retrieval_tile_select_total{{path="{p}"}}'
             ] == n
 
 

@@ -1375,8 +1375,44 @@ class TestQueryCacheServing:
         status, body = http("GET", deployed_engine["base"] + "/stats.json")
         assert status == 200
         block = body["retrieval"]["tile_select"]
-        assert set(block) == {"two_level", "plain"}
+        assert set(block) == {"deferred", "two_level", "plain"}
         assert block == retrieval.stats_block()["tile_select"]
+
+    @pytest.mark.parametrize("batch,path", [(1, "deferred"), (2, "two_level")])
+    def test_a_shortlist_call_moves_its_path_by_one_on_both_routes(
+        self, deployed_engine, batch, path
+    ):
+        """``/stats.json`` and ``/metrics`` of the engine server read the
+        counter that the shortlist call of its process counts: a single
+        query's call (tiles wide enough to split) is ``deferred``."""
+        import numpy as np
+
+        from predictionio_tpu.obs import metrics as obs_metrics
+        from predictionio_tpu.ops import retrieval
+
+        base = deployed_engine["base"]
+
+        def read():
+            status, body = http("GET", base + "/stats.json")
+            assert status == 200
+            with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
+                scraped = obs_metrics.parse_prometheus(r.read().decode())
+            block = body["retrieval"]["tile_select"]
+            for p, n in block.items():
+                assert scraped[
+                    f'pio_retrieval_tile_select_total{{path="{p}"}}'
+                ] == n
+            return block
+
+        rng = np.random.default_rng(7)
+        cat = retrieval.CoarseCatalog(
+            rng.normal(size=(2 * 8192 + 3, 8)).astype(np.float32), tile=8192
+        )
+        before = read()
+        cat.shortlist(rng.normal(size=(batch, 8)).astype(np.float32), 16)
+        after = read()
+        for p in after:
+            assert after[p] == before[p] + (p == path)
 
     def test_stats_route_carries_the_score_form_counter(self, deployed_engine):
         from predictionio_tpu.ops import retrieval

@@ -78,6 +78,75 @@ class TestShardedChain:
         np.testing.assert_array_equal(ids, one[1])
         np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
 
+    @pytest.mark.parametrize("batch,path", [(1, "deferred"), (2, "two_level")])
+    @pytest.mark.parametrize("items", [4 * 2 * 8192, 70_001])
+    def test_tiles_wide_enough_to_split_give_the_one_chip_answer(
+        self, mesh4, two_stage, monkeypatch, items, batch, path
+    ):
+        """Tiles of 8,192 rows, two or three a shard: a single's scan
+        under ``shard_map`` selects once after each shard's loop
+        (``scan_select`` -> "deferred"), a pair's in every step; both
+        serve the one-chip chain's answer and the plain reference's."""
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "8192")
+        U, V = _tables(items, seed=12)
+        cat = ShardedCatalog(V, mesh4)
+        kp = retrieval.two_stage_k(16, len(V))
+        assert cat.tile == 8192 and cat.tiles_per_shard == -(-items // 4 // 8192)
+        assert retrieval.scan_select(
+            batch, cat.tiles_per_shard, cat.tile, kp
+        ) == path
+        uix = np.arange(batch)
+        before = retrieval.stats_block()["tile_select"]
+        s, ids = _served(cat, U, uix, len(V), 16)
+        after = retrieval.stats_block()["tile_select"]
+        for p_ in after:
+            assert after[p_] == before[p_] + (p_ == path)
+        rs, ri = _reference(U[:batch], V, 16)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=4e-6 * np.abs(rs).max())
+        one = retrieval.top_k(
+            retrieval.UserRows(uix, jax.numpy.asarray(U), lambda ix: U[ix]),
+            jax.numpy.asarray(V), len(V), retrieval.CoarseCatalog(V), 16,
+        )
+        np.testing.assert_array_equal(ids, one[1])
+        np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
+
+    def test_the_sharded_singles_scan_body_selects_nothing(self, mesh4):
+        """The lowered sharded program at B = 1: no ``sort`` inside the
+        scan's ``while``; at B = 2 the step still sorts."""
+        nt, t, d = 2, 8192, 16
+        shapes = lambda b: (  # noqa: E731
+            jax.ShapeDtypeStruct((b, d), np.float32),
+            jax.ShapeDtypeStruct((4 * nt * t, d), np.float32),
+            jax.ShapeDtypeStruct((4 * nt, t, d), jax.numpy.bfloat16),
+            jax.ShapeDtypeStruct((4 * nt, t), np.int32),
+        )
+
+        def selections(jp, in_loop=False):
+            """``sort`` / ``top_k`` equations under a ``scan``."""
+            n = 0
+            for e in jp.eqns:
+                n += in_loop and e.primitive.name in ("sort", "top_k")
+                for v in e.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else (v,):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            n += selections(
+                                inner, in_loop or e.primitive.name == "scan"
+                            )
+            return n
+
+        def loop_sorts(b):
+            return selections(jax.make_jaxpr(
+                lambda *a: shard_topk._sharded_topk(
+                    *a, r=nt * t, kp=128, k=16, mode="bf16", mesh=mesh4,
+                    axis="data",
+                )
+            )(*shapes(b)).jaxpr)
+
+        assert loop_sorts(1) == 0
+        assert loop_sorts(2) == 3
+
     def test_eight_shards(self, mesh8, two_stage):
         U, V = _tables(9000, seed=2)
         s, ids = _served(ShardedCatalog(V, mesh8), U, [3, 4, 5], len(V), 8)
