@@ -37,9 +37,17 @@ tracing-timeline argument of the TensorFlow system paper 1605.08695):
   incident bundles under ``$PIO_RUN_DIR/incidents/`` on SLO violation,
   unhandled exception, or ``POST /incident`` (``pio incidents``).
 
-Instrumentation is ALWAYS-ON and cheap (<2% serving qps, gated by the
-bench ``obs`` section); ``PIO_OBS=0`` turns every instrument into a
-no-op for A/B measurement.
+- :mod:`predictionio_tpu.obs.runtime` — host time in which the device
+  could have been working, by cause: the batch worker's time by state,
+  ``thread_time`` beside wall time for the stages that only enqueue
+  (``trace.region(cpu_hist=)``), collector pauses (one ``gc.callbacks``
+  hook), process stops (the 20 ms ``obs-beat`` thread; ``runtime``
+  block on ``/stats.json``).
+
+Instrumentation is ALWAYS-ON and cheap: what it costs is measured end
+to end on the chip by each tracing PR (``PERF.md`` section 6: PR 24,
+PR 34) and held by the benchmark's bounds; ``PIO_OBS=0`` turns every
+instrument into a no-op for A/B measurement.
 
 ``device`` and ``progress`` are intentionally NOT imported here:
 ``obs.device`` must stay importable-but-inert on jax-free processes,
@@ -48,7 +56,7 @@ instruments even where they can never fire. Import them explicitly.
 """
 
 from predictionio_tpu.obs import metrics, trace  # noqa: F401
-from predictionio_tpu.obs import freshness, history, incident, slo  # noqa: F401
+from predictionio_tpu.obs import freshness, history, incident, runtime, slo  # noqa: F401
 
 __all__ = [
     "metrics",
@@ -57,6 +65,7 @@ __all__ = [
     "freshness",
     "history",
     "incident",
+    "runtime",
     "device",
     "progress",
 ]

@@ -357,8 +357,11 @@ def register_provider(name: str, fn) -> None:
     _PROVIDERS[name] = fn
 
 
-def unregister_provider(name: str) -> None:
-    _PROVIDERS.pop(name, None)
+def unregister_provider(name: str, fn=None) -> None:
+    """Drop ``name``; given ``fn``, only while it is still the one
+    registered (a stopping server must not take its successor's)."""
+    if fn is None or _PROVIDERS.get(name) == fn:
+        _PROVIDERS.pop(name, None)
 
 
 def reset_for_tests() -> None:

@@ -13,9 +13,9 @@ Design constraints (module used on every hot path in the framework):
   bucket index is one C-speed ``bisect``. p50/p90/p99 are read by
   interpolating exactly within the containing bucket.
 - **always-on, disableable** — ``PIO_OBS=0`` (or ``set_enabled(False)``)
-  turns every update into a flag check + return; the bench ``obs``
-  section measures instrumented vs disabled serving qps and gates the
-  delta at <2%.
+  turns every update into a flag check + return; what the instruments
+  cost enabled is measured on the chip by each tracing PR (``PERF.md``
+  section 6) and held by the benchmark's bounds.
 
 Exposure: :func:`render_prometheus` is the ``GET /metrics`` body
 (Prometheus text format 0.0.4); :func:`stats_block` is the compact

@@ -243,6 +243,17 @@ def annotate(name: str):
     return _NULL
 
 
+# ``time.thread_time()`` is a system call: 0.25 us on a plain Linux host,
+# but 6 us alone and ~35 us inside a serving process on a sandboxed one
+# (the benchmark's: PERF.md section 6, PR 34). So a region given a
+# ``cpu_hist`` reads it on one call in CPU_EVERY, counted per histogram:
+# the histogram's mean stays a mean, to set beside the wall histogram's.
+# Seven and not eight: a stride that shares a factor with a batcher's
+# rhythm (a small batch, a large one, ...) would always meet the same kind.
+CPU_EVERY = 7
+_cpu_calls: dict[int, int] = {}
+
+
 class region:
     """Record one stage: ``with region("serve.tail", hist=h): ...``.
 
@@ -252,22 +263,28 @@ class region:
     ``metrics.Histogram``) observes the duration; while a profiler
     capture runs the block is also a ``jax.profiler.TraceAnnotation``.
     ``start`` backdates the region to a perf_counter reading taken
-    earlier on this thread. After exit ``seconds`` is the duration and
-    ``self_seconds`` the duration minus the regions nested directly
-    inside it. A no-op under ``PIO_OBS=0``."""
+    earlier on this thread. ``cpu_hist`` observes the block's
+    ``time.thread_time()`` — this thread's CPU, so wall minus CPU is time
+    it waited (for the interpreter, for a runtime call that blocks) — on
+    one call in ``CPU_EVERY``; without it no second clock is read. After
+    exit ``seconds`` is the
+    duration and ``self_seconds`` the duration minus the regions nested
+    directly inside it. A no-op under ``PIO_OBS=0``."""
 
     __slots__ = (
         "name", "start", "end", "seconds", "self_seconds",
         "_hist", "_trace", "_parent", "_outer_children", "_ann", "_on",
+        "_cpu_hist", "_cpu0",
     )
 
     def __init__(self, name: str, hist=None, trace=None,
-                 start: float | None = None):
+                 start: float | None = None, cpu_hist=None):
         self.name = name
         self.start = start
         self.end = self.seconds = self.self_seconds = 0.0
         self._hist = hist
         self._trace = trace
+        self._cpu_hist = cpu_hist
 
     def __enter__(self):
         on = self._on = _metrics.enabled()
@@ -284,6 +301,14 @@ class region:
         if _annotating:
             self._ann = _annotation(self.name)
             self._ann.__enter__()
+        if self._cpu_hist is not None:
+            key = id(self._cpu_hist)
+            n = _cpu_calls.get(key, 0)
+            _cpu_calls[key] = n + 1
+            if n % CPU_EVERY:
+                self._cpu_hist = None
+            else:
+                self._cpu0 = time.thread_time()
         if self.start is None:
             self.start = time.perf_counter()
         return self
@@ -292,6 +317,8 @@ class region:
         if not self._on:
             return False
         end = self.end = time.perf_counter()
+        if self._cpu_hist is not None:
+            self._cpu_hist.observe(time.thread_time() - self._cpu0)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         dt = self.seconds = end - self.start

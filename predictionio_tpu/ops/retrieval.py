@@ -245,6 +245,16 @@ _m_shortlist_secs = obs_metrics.histogram(
 _m_rescore_secs = obs_metrics.histogram(
     "pio_retrieval_rescore_seconds", "exact rescore pass wall time",
 )
+# the two stages that only enqueue, on their thread's CPU clock: wall
+# minus CPU is time the thread wanted to run and did not
+_m_shortlist_cpu = obs_metrics.histogram(
+    "pio_retrieval_shortlist_cpu_seconds",
+    "coarse shortlist pass thread_time (its enqueue, on the CPU)",
+)
+_m_rescore_cpu = obs_metrics.histogram(
+    "pio_retrieval_rescore_cpu_seconds",
+    "exact rescore pass thread_time (its enqueue, on the CPU)",
+)
 _m_fetch_secs = obs_metrics.histogram(
     "pio_retrieval_fetch_seconds",
     "the two-stage chain's blocking read of its result: what is left of "
@@ -682,6 +692,12 @@ def _up(a, dtype, rows: int = 0, sharding=None):
     return jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
 
 
+_shortlist_stage = functools.partial(
+    obs_trace.region, "dispatch.shortlist", hist=_m_shortlist_secs,
+    cpu_hist=_m_shortlist_cpu,
+)
+
+
 def _fetch(out, n: int):
     """The read that ends a chain of launches: device ``(scores, ids)``
     -> their first ``n`` rows on the host, in one ``device_get``, as a
@@ -789,7 +805,7 @@ class CoarseCatalog:
         given (``shortlist`` below has the contract). ``top_k`` hands
         the ids to a rescore program as they are."""
         k = max(1, min(int(k), self.tile))
-        with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
+        with _shortlist_stage():
             q = _up(queries, np.float32, _pow2(len(queries)))
             if rules is None:
                 s, ids = _coarse_topk(
@@ -987,7 +1003,8 @@ def _rescore_sum_rows_masked(row_ixs, row_weights, item_factors, cand_ids,
 # ``top_k`` calls the launchers with the scan's device ids.
 
 _rescore_stage = functools.partial(
-    obs_trace.region, "dispatch.rescore", hist=_m_rescore_secs
+    obs_trace.region, "dispatch.rescore", hist=_m_rescore_secs,
+    cpu_hist=_m_rescore_cpu,
 )
 
 
@@ -1211,7 +1228,7 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
     if query.rules is not None:
         raise ValueError("a sharded catalog serves no query under rules yet")
     n = len(query[0])
-    with obs_trace.region("dispatch.shortlist", hist=_m_shortlist_secs):
+    with _shortlist_stage():
         q = catalog.put_queries(query.coarse_vectors())
         out = catalog.launch(q, kp, k) if kp else catalog.launch_exact(q, k)
     s, ids = _fetch(out, n)

@@ -45,6 +45,7 @@ from predictionio_tpu.data.storage import EngineInstance, Storage, get_storage
 from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import freshness as obs_freshness
 from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import runtime as obs_runtime
 from predictionio_tpu.obs import slo as obs_slo
 from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.server import jsonx
@@ -186,6 +187,9 @@ class _MicroBatcher:
             "pio_batch_queue_wait_seconds",
             "Per-query wait from submit to batch collection",
         )
+        # the worker's time by state (idle / collect / dispatch /
+        # resolve); only the worker thread moves it
+        self.clock = obs_runtime.WorkerClock()
         obs_metrics.gauge(
             "pio_batch_engaged",
             "1 when the micro-batcher serves queries, 0 when disengaged",
@@ -274,12 +278,17 @@ class _MicroBatcher:
         device down to "no request was queued"."""
         import queue
 
+        clock = self.clock
         with obs_trace.annotate("batch.collect"):
             while not self._stopped:
                 try:
-                    first = self._q.get(timeout=0.2)
+                    first = self._q.get(timeout=0.05)
                 except queue.Empty:
+                    # idle so far is counted now: a scrape never finds
+                    # more than this timeout of the worker's time missing
+                    clock.to("idle")
                     continue
+                clock.to("collect")
                 batch = [first]
                 deadline = time.perf_counter() + self._window
                 while len(batch) < self._max:
@@ -314,6 +323,7 @@ class _MicroBatcher:
                 for f, *_ in batch:
                     if not f.done():
                         f.set_exception(RuntimeError("batch worker failed"))
+            self.clock.to("idle")
 
 
 class _Submitted(NamedTuple):
@@ -435,12 +445,6 @@ class _Variant:
             epoch=epoch,
         )
         self.last_reload_ts = time.time()
-        if self.variant_name is not None:
-            obs_metrics.gauge(
-                "pio_serving_epoch",
-                "Model swap epoch, per tenant",
-                variant=self.name,
-            ).set(float(epoch))
         logger.info(
             "engine instance %s loaded for serving (variant %s)",
             instance.id,
@@ -482,12 +486,6 @@ class _Variant:
         )
         if self.query_cache is not None:
             self.query_cache.sweep(epoch, variant=self.name)
-        if self.variant_name is not None:
-            obs_metrics.gauge(
-                "pio_serving_epoch",
-                "Model swap epoch, per tenant",
-                variant=self.name,
-            ).set(float(epoch))
         return True
 
     # -- observability -------------------------------------------------------
@@ -654,6 +652,11 @@ class EngineServer:
         self._m_dispatch = obs_metrics.histogram(
             "pio_batch_dispatch_seconds",
             "Device-dispatch time per dispatch (predict / batch_predict)",
+        )
+        self._m_dispatch_cpu = obs_metrics.histogram(
+            "pio_batch_dispatch_cpu_seconds",
+            "Dispatching thread's thread_time per dispatch: wall minus "
+            "this is time it waited (the device, the interpreter)",
         )
         self._m_dispatch_self = obs_metrics.histogram(
             "pio_batch_dispatch_self_seconds",
@@ -1027,21 +1030,29 @@ class EngineServer:
             body, query, predictions, serving, t0, variant=v
         )
 
-    def _dispatch(self, n_real: int, n_padded: int, call):
+    def _dispatch(self, n_real: int, n_padded: int, call, clock=None):
         """One device dispatch of ``n_real`` queries in ``n_padded`` rows:
         the ``batch.dispatch[n]`` span on the current trace(s), its
         histograms and the row counters — EVERY dispatch, single or
         batched, so ``pio_batch_dispatch_seconds`` and ``pio_batch_size``
         count the same events. The score layer records its stages
         (``dispatch.shortlist`` / ``dispatch.rescore`` / ``dispatch.fetch``:
-        two launches, then one read) as children."""
+        two launches, then one read) as children. ``clock`` is the batch
+        worker's: in ``dispatch`` for the region, in ``resolve`` after."""
         self._m_batch_size.observe(float(n_real))
         self._m_rows_real.inc(n_real)
         self._m_rows_padded.inc(n_padded)
-        with obs_trace.region(
-            f"batch.dispatch[{n_real}]", hist=self._m_dispatch
-        ) as r:
-            out = call()
+        if clock is not None:
+            clock.to("dispatch")
+        try:
+            with obs_trace.region(
+                f"batch.dispatch[{n_real}]", hist=self._m_dispatch,
+                cpu_hist=self._m_dispatch_cpu,
+            ) as r:
+                out = call()
+        finally:
+            if clock is not None:
+                clock.to("resolve")
         self._m_dispatch_self.observe(r.self_seconds)
         return out
 
@@ -1137,6 +1148,7 @@ class EngineServer:
         with self._lock:
             algorithms, models = variant.algorithms, variant.models
         batcher = self.batcher
+        clock = batcher.clock if batcher is not None else None
         t_collect = time.perf_counter()
         for fut, t0, tr, _, _ in items:
             if batcher is not None:
@@ -1147,7 +1159,7 @@ class EngineServer:
         def predict_one(sup):
             return self._dispatch(1, 1, lambda: [
                 a.predict(m, sup) for a, m in zip(algorithms, models)
-            ])
+            ], clock=clock)
 
         # the worker has no trace of its own: for the dispatch it stands
         # in for every batchmate's, as a child of their ``serve`` spans
@@ -1188,7 +1200,9 @@ class EngineServer:
                 ]
 
             with obs_trace.use_trace(fanout, parent="serve"):
-                per_algo = self._dispatch(n_real, pad_to, batch_call)
+                per_algo = self._dispatch(
+                    n_real, pad_to, batch_call, clock=clock
+                )
         except Exception:
             logger.exception("batched scoring failed; retrying per query")
             per_algo = None
@@ -1400,6 +1414,7 @@ class EngineServer:
             body["obs"] = obs_metrics.stats_block()
             body["device"] = obs_device.device_block()
             body["freshness"] = obs_freshness.block()
+            body["runtime"] = obs_runtime.block()
             try:
                 from predictionio_tpu.ops import retrieval as _retrieval
 

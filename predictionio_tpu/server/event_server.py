@@ -723,6 +723,9 @@ class EventServer:
 
     def stop(self) -> None:
         self.app.stop()
+        # a stopped server's buckets leave /history.json with it (the
+        # provider table is the process's: a later server's, a test's)
+        obs_history.unregister_provider("ingest_stats", self.stats.history_series)
 
 
 def create_event_server(**kwargs) -> EventServer:

@@ -49,6 +49,7 @@ from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.obs import history as obs_history
 from predictionio_tpu.obs import incident as obs_incident
 from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import runtime as obs_runtime
 from predictionio_tpu.obs import slo as obs_slo
 from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.server import jsonx
@@ -283,10 +284,12 @@ def add_obs_routes(router: Router) -> None:
     every server — standard scraper behavior; none exposes event data.
 
     Mounting also arms the passive obs machinery the routes read from:
-    the history sampler's ticker and the flight recorder's crash/SLO
-    hooks — both no-ops under ``PIO_OBS=0``."""
+    the history sampler's ticker, the flight recorder's crash/SLO hooks
+    and the runtime instruments (collector hook, ``obs-beat`` thread) —
+    all no-ops under ``PIO_OBS=0``."""
     obs_history.ensure_ticker()
     obs_incident.install_crash_hooks()
+    obs_runtime.arm()
 
     def _metrics_route(_req: Request) -> Response:
         # Registers the per-device memory gauges on first scrape after

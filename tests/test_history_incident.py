@@ -48,6 +48,16 @@ class _Clock:
 
 
 class TestHistorySampler:
+    @pytest.fixture(autouse=True)
+    def _own_providers(self):
+        """A sampler's snapshot merges the PROCESS's provider table: an
+        event server an earlier test file of this worker started with
+        stats on left its ``ingest_stats`` there, and its minute buckets
+        came up as a third series (PR 34)."""
+        history.reset_for_tests()
+        yield
+        history.reset_for_tests()
+
     def _sampler(self, reg: Registry, clock: _Clock, **kw) -> history.HistorySampler:
         kw.setdefault("step_s", 5.0)
         kw.setdefault("slots", 8)
