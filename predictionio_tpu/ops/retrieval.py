@@ -24,22 +24,23 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    groups, and the k' best of those groups' scores — exactly the
    tile's top-k', from selections over T/G + k'G elements instead of T
    (``tile_select_group`` decides from T and k' alone; every mode and
-   the masked scan share ``_tile_top_k``). ONE query does not select in
-   the step at all (``scan_select`` decides from B, NT, T and k' alone):
-   its step scores the tile and writes the [1, T] scores and their
-   [1, T/G] group maxima to the scan's stacked outputs, and the k' best
-   come once, after the loop, from the same two levels over ALL tiles'
-   groups (``_select_deferred``) — the same shortlist, its values
-   bit-equal; a batch of two or more keeps the per-tile selection and
-   the running merge. And a step scores its tile
-   in one of two forms (``score_form`` decides from B and D alone):
-   the batch's f32 rows against the tile cast to f32 ("rows": two or
-   more queries, or D >= 128), or — ONE query whose rank leaves the
-   128 lanes unfilled — the query split into three bf16 rows whose
-   sum it is, one dot against the tile as stored, the three partial
-   scores added back ("dot": the same f32 product to summation
-   order, through the form of it that the chip reads at the memory's
-   speed). Everything after the score is one row either way.
+   the masked scan share ``_tile_top_k``). Where the stored scores fit
+   the step does not select at all (``scan_select`` decides from B, NT,
+   T, k', D and the storage mode alone): it scores the tile and writes
+   the [B, T] scores and their [B, T/G] group maxima to the scan's
+   stacked outputs, and the k' best come once, after the loop, from the
+   same two levels over ALL tiles' groups (``_select_deferred``) — the
+   same shortlist, its values bit-equal; a batch whose scores would
+   outweigh half the coarse tiles (more than 16 queries at rank 64 in
+   bf16) keeps the per-tile selection and the running merge. And a
+   step scores its tile in one of two forms (``score_form`` decides
+   from B and D alone): the batch's f32 rows against the tile cast to
+   f32 ("rows": two or more queries, or D >= 128), or — ONE query
+   whose rank leaves the 128 lanes unfilled — the query split into
+   three bf16 rows whose sum it is, one dot against the tile as stored,
+   the three partial scores added back ("dot": the same f32 product to
+   summation order, through the form of it that the chip reads at the
+   memory's speed). Everything after the score is one row either way.
 2. **Exact rescore** — gather the [B, S] shortlisted rows and rescore
    them in f32 through shortlist-gather variants of the fused ops
    (``rescore_*_top_k_batch`` below). The rescore builds its query
@@ -114,10 +115,11 @@ the one blocking read that ends the chain as ``dispatch.fetch``
 (``pio_retrieval_fetch_seconds``: the device time of both programs and
 the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
 one a dispatch through ``top_k``), and the two serving programs
-carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a single's
+carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a deferred
 step is ``score`` / ``mask`` / ``group_max`` and ``select`` follows
-the loop; a batch's is ``score`` / ``mask`` / ``tile_topk`` / ``merge``
-— and ``retrieval.rescore.*``) that name their ops in a trace viewer.
+the loop; a per-tile one is ``score`` / ``mask`` / ``tile_topk`` /
+``merge`` — and ``retrieval.rescore.*``) that name their ops in a
+trace viewer.
 """
 
 from __future__ import annotations
@@ -276,8 +278,9 @@ _m_tile_select = {
     path: obs_metrics.counter(
         "pio_retrieval_tile_select_total",
         "shortlist calls by where the scan selects its k' best: deferred = "
-        "once, after the tile loop (a single query); two_level / plain = "
-        "every step its tile's, by group maxima or one top_k",
+        "once, after the tile loop (while the stored scores are at most "
+        "half the coarse tiles' bytes); two_level / plain = every step "
+        "its tile's, by group maxima or one top_k",
         path=path,
     )
     for path in ("deferred", "two_level", "plain")
@@ -302,7 +305,7 @@ def _count_scan(b: int, nt: int, t: int, k: int, d: int, mode: str) -> None:
     with: where it selects (``scan_select``) and how it scores
     (``score_form``)."""
     _m_shortlist_size.observe(float(k))
-    _m_tile_select[scan_select(b, nt, t, k)].inc()
+    _m_tile_select[scan_select(b, nt, t, k, d, mode)].inc()
     _m_score_form[score_form(b, d, mode)].inc()
 
 
@@ -434,38 +437,61 @@ def _tile_top_k(sc, k: int):
     return _two_level_top_k(sc, k, g)
 
 
-# ONE query selects once, after the tile loop. A step's selection is
-# thrown away almost whole — of the 36 x 128 candidates that a 36-tile
-# scan sorts, merges and looks up ids for, 128 survive — and at B = 1 it
-# was a fifth to a quarter of the loop (PERF.md section 6, PR 33). The
-# argument above ``_MIN_SPLIT`` never needed the tile: read "catalog" for
-# "tile" and the k' groups with the largest maxima among ALL tiles'
-# groups hold the catalog's k' best. So a single's step only scores its
-# tile and writes
-# the [1, T] scores and their [1, T/G] group maxima to the scan's
-# stacked outputs (B x NT x T x 4 bytes: 38 MB at 36 tiles), and
-# ``_select_deferred`` picks once: the k' best groups of NT x T/G maxima,
-# their [1, k'G] scores read back out of the stored array, the k' best
-# of those, 128 ids looked up. Two or more queries keep the selection in
-# the step, as it was: B x NT x T x 4 bytes of scores is 604 MB a call at
-# B = 16, and the batched programs' rhythm is another measurement
-# (PERF.md section 6 has the deferred body alone at B = 2..16).
+# A scan selects once, after the tile loop. A step's selection is thrown
+# away almost whole — of the 36 x 128 candidates that a 36-tile scan
+# sorts, merges and looks up ids for, 128 a query survive — and it was a
+# fifth to a quarter of a single's loop and over two fifths of a batch
+# of 8 or 16's (PERF.md section 6, PR 33 and PR 36). The argument above
+# ``_MIN_SPLIT`` never needed the tile: read "catalog" for "tile" and the
+# k' groups with the largest maxima among ALL tiles' groups hold the
+# catalog's k' best. So a step only scores its tile and writes the
+# [B, T] scores and their [B, T/G] group maxima to the scan's stacked
+# outputs, and ``_select_deferred`` picks once: the k' best groups of
+# NT x T/G maxima, their [B, k'G] scores read back out of the stored
+# array, the k' best of those, B x k' ids looked up.
+#
+# What that costs is memory: the stored scores are B x NT x T x 4 bytes a
+# call (38 MB for one query over 36 tiles of 2^18 rows, 604 MB for 16;
+# from B = 4 a temporary in the chip's main memory, written 8 or 16 MB a
+# step at ~600 GB/s), on top of everything resident, for the length of
+# the call. On a TPU v5e the deferred body is the faster one at every
+# batch measured, B = 1 .. 64 (the scan 1.4x shorter at B = 1 .. 4, 1.7x
+# at 8 and 16, 1.8 .. 1.9x at 32 and 64: PERF.md section 6, PR 36), so
+# the bound is not about speed. The bound: A CALL'S STORED SCORES MAY BE
+# AT MOST HALF THE COARSE TILES THEY SCORE, B x 4 <= D x itemsize / 2 —
+# 16 queries over a rank-64 bf16 copy, 32 over rank 128, 8 over rank-64
+# int8: a temporary that scales with what the deployment already keeps
+# resident for this scan (0.6 of 1.2 GB on 9.39 M x 64), whatever the
+# catalog's size, and covers every batch a benchmark cell dispatches. A
+# batch beyond it (``_MicroBatcher`` collects up to 64: 2.4 GB of scores
+# at rank 64) keeps the selection in the step and the running merge, as
+# every batch did before PR 36; widening the bound wants a cell that
+# sends such batches (PERF.md section 7).
 
 
-def scan_select(b: int, nt: int, t: int, k: int) -> str:
-    """Where a scan of ``nt`` tiles of ``t`` rows for ``b`` queries
-    selects its k' = ``k`` best: "deferred" — once, after the loop,
-    from the stored scores by their group maxima — where b == 1 and the
+def _stored_scores_fit(b: int, d: int, mode: str) -> bool:
+    """The bound above: ``b`` queries' f32 scores of a row against
+    half the row's ``d`` stored coarse values (bf16, or int8 in both
+    int8 modes)."""
+    return 8 * b <= d * (2 if mode == "bf16" else 1)
+
+
+def scan_select(b: int, nt: int, t: int, k: int, d: int,
+                mode: str = "bf16") -> str:
+    """Where a scan of ``nt`` tiles of ``t`` rows of rank ``d`` for
+    ``b`` queries selects its k' = ``k`` best: "deferred" — once, after
+    the loop, from the stored scores by their group maxima — where the
     tile splits into groups (``tile_select_group`` leaves every tile at
     least k' of them, so the ``nt`` tiles have k' groups to pick from
-    whatever ``nt`` is); "two_level" — every step its own tile's, merged
-    into a running best — for every batch of two or more; "plain" — one
-    ``lax.top_k`` a step — where ``tile_select_group`` splits nothing.
-    Decided from the shapes alone: at trace time, and on the host for
-    the counter."""
+    whatever ``nt`` is) and the stored scores fit
+    (``_stored_scores_fit``: at most half the coarse tiles' bytes);
+    "two_level" — every step its own tile's, merged into a running best
+    — for a batch beyond that bound; "plain" — one ``lax.top_k`` a step
+    — where ``tile_select_group`` splits nothing. Decided from the
+    shapes alone: at trace time, and on the host for the counter."""
     if not tile_select_group(t, k):
         return "plain"
-    return "deferred" if b == 1 else "two_level"
+    return "deferred" if _stored_scores_fit(b, d, mode) else "two_level"
 
 
 def _select_deferred(scores, maxima, ids, k: int):
@@ -537,13 +563,13 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
     tile scores [B, T] in the catalog's storage precision, takes the
     tile's top-k', and merges into the running best — the [B, I] score
     matrix and the full-catalog top-k never materialize, which is where
-    the win over the exact path comes from once I outgrows cache. ONE
-    query (``scan_select`` -> "deferred") leaves the selection to the
-    end: its step scores the tile and keeps the scores and their group
-    maxima, and the k' best come from one selection over all tiles —
-    the same shortlist, its values bit-equal. ``select`` overrides the
-    rule: for the tests and measurements that compare the two bodies on
-    one input; nothing served passes it.
+    the win over the exact path comes from once I outgrows cache. Where
+    the stored scores fit (``scan_select`` -> "deferred") the selection
+    is left to the end: a step scores the tile and keeps the scores and
+    their group maxima, and the k' best come from one selection over
+    all tiles — the same shortlist, its values bit-equal. ``select``
+    overrides the rule: for the tests and measurements that compare the
+    two bodies on one input; nothing served passes it.
 
     ``mode``: "int8" (values*scale columns, f32 GEMM on cast values),
     "int8_dot" (int8 x int8 -> int32 accumulation, quantized queries —
@@ -559,7 +585,9 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
     B = q.shape[0]
     dot = score_form(B, q.shape[1], mode) == "dot"
     nt, t = ids.shape
-    deferred = (select or scan_select(B, nt, t, k)) == "deferred"
+    deferred = (
+        select or scan_select(B, nt, t, k, q.shape[1], mode)
+    ) == "deferred"
     g = tile_select_group(t, k)  # a deferred step keeps a maximum a group
     if rules is not None:
         with jax.named_scope("retrieval.shortlist.mask"):

@@ -15,14 +15,15 @@ the item rows stand still and the query travels:
 - a dispatch replicates the [B, D] query vectors (256 B a query at rank
   64) and runs ONE program under ``shard_map``: each device scans its
   own tiles with the one-chip scan (``retrieval._coarse_scan``, not a
-  copy — so ONE query's step only scores its tile and keeps the scores
-  and their group maxima, and each device selects its k' best once,
-  after its own loop, as ``retrieval.scan_select`` says from the local
-  shapes; a batch of two or more selects in every step, as on one
-  chip), rescores its own shortlist against its own f32 rows
-  (``retrieval._score_candidates``, ``precision=HIGHEST``), keeps its k
-  best, and one ``all_gather`` of [B, k] scores and ids — ``shards x B x
-  k x 8`` bytes — feeds the merge to the global top-k on every device;
+  copy — so a step only scores its tile and keeps the scores and their
+  group maxima, and each device selects its k' best once, after its
+  own loop, as ``retrieval.scan_select`` says from the local shapes;
+  a batch whose stored scores would outweigh half a shard's tiles
+  selects in every step, as on one chip), rescores its own shortlist
+  against its own f32 rows (``retrieval._score_candidates``,
+  ``precision=HIGHEST``), keeps its k best, and one ``all_gather`` of
+  [B, k] scores and ids — ``shards x B x k x 8`` bytes — feeds the
+  merge to the global top-k on every device;
 - the host reads the answer once (``retrieval.top_k`` owns the chain and
   its one ``device_get``).
 

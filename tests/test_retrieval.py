@@ -667,12 +667,12 @@ def _scan_args(b, nt, t, d, mode="bf16"):
     )
 
 
-def _scan_jaxpr(b, nt, t, d, k, rules=None, mode="bf16"):
+def _scan_jaxpr(b, nt, t, d, k, rules=None, mode="bf16", select=None):
     import jax
 
     return jax.make_jaxpr(
         lambda q, tiles, scales, ids: retrieval._coarse_scan(
-            q, tiles, scales, ids, k, mode, rules
+            q, tiles, scales, ids, k, mode, rules, select=select
         )
     )(*_scan_args(b, nt, t, d, mode)).jaxpr
 
@@ -762,10 +762,12 @@ class TestTileSelect:
     @pytest.mark.parametrize("masked", [False, True])
     def test_engaged_scan_never_sorts_a_whole_tile(self, masked):
         """The jaxpr of an engaged scan holds no ``top_k`` whose operand
-        is T wide: the group maxima, the candidates, the merge."""
-        b, nt, t, d, k = 2, 2, 1 << 13, 8, 16
+        is T wide: the group maxima, the candidates, the merge. (Eight
+        queries of rank 8: more stored scores than tile, so the rule
+        keeps the selection in the step.)"""
+        b, nt, t, d, k = 8, 2, 1 << 13, 8, 16
         g = retrieval.tile_select_group(t, k)
-        assert g
+        assert g and retrieval.scan_select(b, nt, t, k, d) == "two_level"
         rules = None
         if masked:
             rules = _rules(nt * t, b)
@@ -855,24 +857,32 @@ class TestTwoLevelShortlist:
 
     I, D, T, K = 40_000, 16, 1 << 14, 64
 
+    @pytest.mark.parametrize("n", [4, 16])
     @pytest.mark.parametrize("masked", [False, True])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
-    def test_shortlist_is_the_numpy_selection(self, mode, masked):
+    def test_shortlist_is_the_numpy_selection(self, mode, masked, n):
+        """Four queries of rank 16 over a bf16 copy select once after
+        the loop; sixteen, or four over int8 values, would store more
+        than half of what a step reads and select in every step, merged
+        (``scan_select``). Either way the numpy selection."""
         assert retrieval.tile_select_group(self.T, self.K)
+        path = retrieval.scan_select(n, 3, self.T, self.K, self.D, mode)
+        assert path == ("deferred" if (n, mode) == (4, "bf16") else "two_level")
         table = _int8(self.I, self.D, seed=31)
-        q = _dense(4, self.D, seed=32)
+        q = _dense(n, self.D, seed=32)
         cat = CoarseCatalog(table, tile=self.T, mode=mode)
         assert cat.tile == self.T and cat.stored_rows == 3 * self.T
         sc = _coarse_scores(cat, table, q)
-        allowed = np.ones((4, self.I), bool)
+        allowed = np.ones((n, self.I), bool)
         rules = None
         if masked:
             small = np.random.default_rng(33).permutation(self.I)[:40]
             small = small[small % 97 != 0]  # available ones
-            ex = np.full((4, 4), -1, np.int32)
+            ex = np.full((n, 4), -1, np.int32)
             ex[2, :3] = np.argsort(-sc[2])[:3]  # the query's own best
-            qcat = np.asarray([[-2], [1], [-2], [0]], np.int32)
-            rules = _rules(cat.stored_rows, 4, small_cat=small, ex=ex,
+            qcat = np.full((n, 1), -2, np.int32)
+            qcat[1], qcat[3] = 1, 0
+            rules = _rules(cat.stored_rows, n, small_cat=small, ex=ex,
                            qcat=qcat)
             allowed[:, ::97] = False
             in_small = np.zeros(self.I, bool)
@@ -884,9 +894,9 @@ class TestTwoLevelShortlist:
         before = retrieval.stats_block()["tile_select"]
         s, ids = cat.shortlist(q, self.K, rules)
         after = retrieval.stats_block()["tile_select"]
-        assert after["two_level"] == before["two_level"] + 1
-        assert after["plain"] == before["plain"]
-        for b in range(4):
+        for p in after:
+            assert after[p] == before[p] + (p == path)
+        for b in range(n):
             ranked = np.argsort(-np.where(allowed[b], sc[b], -np.inf),
                                 kind="stable")
             want = ranked[: min(self.K, int(allowed[b].sum()))]
@@ -1059,13 +1069,15 @@ class TestScoreForm:
             [(1, nt * t // g), (1, k * g)]
         )
 
-    @pytest.mark.parametrize("b,d", [(2, 64), (16, 64), (1, 128), (2, 128)])
+    @pytest.mark.parametrize("b,d", [(2, 64), (16, 64), (64, 64), (1, 128),
+                                     (2, 128)])
     @pytest.mark.parametrize("masked", [False, True])
-    def test_every_other_shape_keeps_its_program(self, b, d, masked):
+    def test_every_other_shape_keeps_its_score(self, b, d, masked):
         """B >= 2 and D >= 128: the f32 queries as they are against the
-        tile cast to f32; a batch keeps the ``top_k`` shapes of PR 27
-        (the StableHLO of the B >= 2 programs is the parent's: hashes in
-        CHANGES.md), a single at rank 128 selects after the loop."""
+        tile cast to f32. Where the selection stands is another rule's
+        (``scan_select``): after the loop for every batch whose stored
+        scores fit, in the step (PR 27's three ``top_k`` shapes) for 64
+        queries of rank 64."""
         import jax.numpy as jnp
 
         nt, t, k = 2, 1 << 13, 16
@@ -1075,8 +1087,10 @@ class TestScoreForm:
         jaxpr = _scan_jaxpr(b, nt, t, d, k, rules)
         (query,) = _operands(jaxpr, "dot_general")
         assert query.shape == (b, d) and query.dtype == jnp.float32
+        deferred = retrieval.scan_select(b, nt, t, k, d) == "deferred"
+        assert deferred == (b < 64)
         assert sorted(_top_k_eqns(jaxpr)) == sorted(
-            [(1, nt * t // g), (1, k * g)] if b == 1 else
+            [(b, nt * t // g), (b, k * g)] if deferred else
             [(b, t // g), (b, k * g), (b, 2 * k)]
         )
 
@@ -1139,11 +1153,12 @@ def _tile_rules(rules, i, t):
 
 
 class TestDeferredSelect:
-    """A single query's scan selects once, after the loop
-    (``scan_select`` -> "deferred"): against the per-tile body on the
-    same inputs (the rule forced through ``_coarse_scan``'s ``select``)
-    and against ``lax.top_k`` over the whole guarded score row — the
-    scores bit-equal, the ids equal wherever the scores are distinct."""
+    """A scan selects once, after the loop (``scan_select`` ->
+    "deferred": one query since PR 33, every batch whose stored scores
+    fit since PR 36): against the per-tile body on the same inputs
+    (forced through ``_coarse_scan``'s ``select``) and against
+    ``lax.top_k`` over the whole guarded score row — the scores
+    bit-equal, the ids equal wherever the scores are distinct."""
 
     T, K = 1 << 13, 128
 
@@ -1191,26 +1206,48 @@ class TestDeferredSelect:
             scales[self.T: 2 * self.T] = scales[: self.T]
         return CoarseCatalog(table, tile=self.T, mode=mode)
 
+    # a batched case: (queries, what the single's case of that name has)
+    BATCHED = {
+        "pair": (2, "whole_tiles"),
+        "four": (4, "whole_tiles"),
+        "eight_padded_last_tile": (8, "padded_last_tile"),
+        "sixteen": (16, "whole_tiles"),
+        "pair_small_category": (2, "small_category"),
+        "eight_own_rows_excluded": (8, "own_rows_excluded"),
+        "sixteen_unavailable_padded": (16, "unavailable_rows"),
+        "eight_equal_scores_across_tiles": (8, "equal_scores_across_tiles"),
+    }
+
     @pytest.mark.parametrize("case", [
         "whole_tiles", "padded_last_tile", "one_tile", "five_tiles",
         "small_category", "own_rows_excluded", "unavailable_rows",
-        "equal_scores_across_tiles", "batch_of_four_forced",
+        "equal_scores_across_tiles", *BATCHED,
     ])
     @pytest.mark.parametrize("d", [64, 128])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
     def test_the_shortlist_it_was(self, mode, d, case):
+        """The scan as the RULE serves it (``select`` left alone) — a
+        single and batches of 2, 4, 8 and 16, with and without
+        ``Rules`` — against the per-tile body forced on the same input
+        and against the whole row."""
         import jax
 
         t, k = self.T, self.K
+        padded = case == "sixteen_unavailable_padded"
+        b, case = self.BATCHED.get(case, (1, case))
         rows = {"padded_last_tile": 2 * t + 1000, "one_tile": t,
-                "five_tiles": 5 * t}.get(case, 3 * t)
-        b = 4 if case == "batch_of_four_forced" else 1
+                "five_tiles": 5 * t}.get(case, 3 * t - 1000 * padded)
         cat = self._catalog(mode, d, rows, seed=61,
                             twin_tiles=case == "equal_scores_across_tiles")
         nt = cat._ids.shape[0]
         assert nt == -(-rows // t) and cat.tile == t
-        want_rule = "deferred" if b == 1 else "two_level"
-        assert retrieval.scan_select(b, nt, t, k) == want_rule
+        # the rule serves every case here but sixteen queries over
+        # rank-64 int8 values (more than half of what a step reads):
+        # there the deferred body is forced, as PR 33 forced its batch
+        beyond = b == 16 and d == 64 and mode != "bf16"
+        assert retrieval.scan_select(b, nt, t, k, d, mode) == (
+            "two_level" if beyond else "deferred"
+        )
         assert retrieval.score_form(b, d, mode) == (
             "dot" if b == 1 and d == 64 and mode != "int8_dot" else "rows"
         )
@@ -1219,13 +1256,14 @@ class TestDeferredSelect:
         if case == "small_category":
             small = np.random.default_rng(63).permutation(rows)[:40]
             rules = _rules(cat.stored_rows, b, small_cat=small,
-                           qcat=np.asarray([[1]], np.int32))
+                           qcat=np.full((b, 1), 1, np.int32))
         elif case == "own_rows_excluded":
             first = self._scan(cat, q, k, None, "two_level")[1]
             rules = _rules(cat.stored_rows, b, ex=first[:, :4].astype(np.int32))
         elif case == "unavailable_rows":
             rules = _rules(cat.stored_rows, b)
-        got_s, got_i = self._scan(cat, q, k, rules, "deferred")
+        got_s, got_i = self._scan(cat, q, k, rules,
+                                  "deferred" if beyond else None)
         per_s, per_i = self._scan(cat, q, k, rules, "two_level")
         np.testing.assert_array_equal(
             got_s.view(np.uint32), per_s.view(np.uint32)
@@ -1239,25 +1277,34 @@ class TestDeferredSelect:
         ref_i = flat_ids[ref_pos]
         if rules is not None:
             ref_i = np.where(ref_s > retrieval.NEG_INF / 2, ref_i, -1)
+        twins = 0
+        for r in range(b):
+            live = got_s[r] > retrieval.NEG_INF / 2
+            if len(np.unique(got_s[r][live])) == live.sum():
+                np.testing.assert_array_equal(got_i[r], per_i[r])
+                np.testing.assert_array_equal(got_i[r], ref_i[r])
+                continue
+            # another choice among equals (tiles that repeat; a batch's
+            # int8 products): every id holds its score, once
+            twins += 1
+            served = got_i[r][live]
+            assert len(set(served.tolist())) == live.sum()
+            assert (served >= 0).all() and (got_i[r][~live] == -1).all()
+            np.testing.assert_array_equal(row[r][served], got_s[r][live])
         if case == "equal_scores_across_tiles":
-            # another choice among equals: every id holds its score, once
-            assert len(np.unique(got_s)) < k
-            served = got_i[0]
-            assert len(set(served.tolist())) == k and (served >= 0).all()
-            np.testing.assert_array_equal(row[0][served], got_s[0])
+            assert twins == b
         else:
-            assert all(len(np.unique(r[r > retrieval.NEG_INF / 2]))
-                       == (r > retrieval.NEG_INF / 2).sum() for r in got_s)
-            np.testing.assert_array_equal(got_i, per_i)
-            np.testing.assert_array_equal(got_i, ref_i)
+            assert twins == 0 or (b > 1 and mode == "int8_dot")
         if case == "small_category":
-            live = int((got_i >= 0).sum())
-            assert 0 < live < k and (got_i[0, live:] == -1).all()
-            assert (got_s[0, live:] == np.float32(retrieval.NEG_INF)).all()
+            for r in range(b):
+                live = int((got_i[r] >= 0).sum())
+                assert 0 < live < k and (got_i[r, live:] == -1).all()
+                assert (got_s[r, live:] == np.float32(retrieval.NEG_INF)).all()
         elif case == "own_rows_excluded":
-            assert not set(got_i[0].tolist()) & set(first[0, :4].tolist())
+            for r in range(b):
+                assert not set(got_i[r].tolist()) & set(first[r, :4].tolist())
         elif case == "unavailable_rows":
-            assert (got_i % 97 != 0).all()
+            assert (got_i % 97 != 0).all() and got_i.max() < rows
         elif case == "padded_last_tile":
             assert (got_i >= 0).all() and got_i.max() < rows
 
@@ -1269,7 +1316,7 @@ class TestDeferredSelect:
         import jax
 
         t, k = self.T, self.T // 2
-        assert retrieval.scan_select(1, 3, t, k) == "plain"
+        assert retrieval.scan_select(1, 3, t, k, 64, mode) == "plain"
         cat = self._catalog(mode, 64, 2 * t + 1000, seed=64)
         q = _dense(1, 64, seed=65)
         before = retrieval.stats_block()["tile_select"]
@@ -1285,53 +1332,95 @@ class TestDeferredSelect:
             ids, np.asarray(cat._ids).reshape(-1)[ref_pos]
         )
 
-    @pytest.mark.parametrize("b,nt,t,k,want", [
-        (1, 36, 1 << 18, 128, "deferred"),   # yambda
-        (1, 16, 1 << 18, 128, "deferred"),   # both Taobao configurations
-        (1, 46, 1 << 18, 128, "deferred"),   # a chip of the sharded catalog
-        (1, 1, 1 << 18, 128, "deferred"),
-        (1, 3, 1 << 13, 16, "deferred"),
-        (1, 36, 1 << 18, 1024, "deferred"),
-        (2, 36, 1 << 18, 128, "two_level"),
-        (4, 36, 1 << 18, 128, "two_level"),
-        (8, 16, 1 << 18, 128, "two_level"),
-        (16, 36, 1 << 18, 128, "two_level"),
-        (1, 36, 1 << 18, 1 << 14, "plain"),  # k' nears the tile
-        (1, 3, 1 << 12, 16, "plain"),        # under _MIN_SPLIT
-        (1, 3, 256, 64, "plain"),            # the CPU fixtures' tiles
-        (16, 3, 256, 64, "plain"),
+    @pytest.mark.parametrize("b,nt,t,k,d,mode,want", [
+        (1, 36, 1 << 18, 128, 64, "bf16", "deferred"),    # yambda
+        (1, 16, 1 << 18, 128, 128, "bf16", "deferred"),   # both Taobao ones
+        (1, 46, 1 << 18, 128, 64, "bf16", "deferred"),    # a sharded chip
+        (1, 1, 1 << 18, 128, 64, "bf16", "deferred"),
+        (1, 3, 1 << 13, 16, 64, "bf16", "deferred"),
+        (1, 36, 1 << 18, 1024, 64, "bf16", "deferred"),
+        # every batch the cells dispatch (B = 2 .. 16), all four shapes
+        (2, 36, 1 << 18, 128, 64, "bf16", "deferred"),
+        (4, 36, 1 << 18, 128, 64, "bf16", "deferred"),
+        (8, 36, 1 << 18, 128, 64, "bf16", "deferred"),
+        (16, 36, 1 << 18, 128, 64, "bf16", "deferred"),
+        (2, 16, 1 << 18, 128, 128, "bf16", "deferred"),
+        (8, 16, 1 << 18, 128, 128, "bf16", "deferred"),
+        (16, 16, 1 << 18, 128, 128, "bf16", "deferred"),
+        (2, 46, 1 << 18, 128, 64, "bf16", "deferred"),
+        (16, 46, 1 << 18, 128, 64, "bf16", "deferred"),
+        # the bound: B x 4 <= D x itemsize / 2, whatever the tiles' number
+        (32, 36, 1 << 18, 128, 64, "bf16", "two_level"),  # 1.2 GB of scores
+        (64, 36, 1 << 18, 128, 64, "bf16", "two_level"),
+        (32, 46, 1 << 18, 128, 64, "bf16", "two_level"),
+        (32, 16, 1 << 18, 128, 128, "bf16", "deferred"),
+        (64, 16, 1 << 18, 128, 128, "bf16", "two_level"),
+        (8, 36, 1 << 18, 128, 64, "int8", "deferred"),    # a byte a value
+        (16, 36, 1 << 18, 128, 64, "int8", "two_level"),
+        (16, 36, 1 << 18, 128, 64, "int8_dot", "two_level"),
+        (2, 3, 1 << 13, 16, 8, "bf16", "deferred"),
+        (4, 3, 1 << 13, 16, 8, "bf16", "two_level"),
+        (1, 36, 1 << 18, 1 << 14, 64, "bf16", "plain"),   # k' nears the tile
+        (1, 3, 1 << 12, 16, 64, "bf16", "plain"),         # under _MIN_SPLIT
+        (1, 3, 256, 64, 64, "bf16", "plain"),             # the CPU fixtures'
+        (16, 3, 256, 64, 64, "bf16", "plain"),
     ])
-    def test_the_rule(self, b, nt, t, k, want):
-        assert retrieval.scan_select(b, nt, t, k) == want
+    def test_the_rule(self, b, nt, t, k, d, mode, want):
+        assert retrieval.scan_select(b, nt, t, k, d, mode) == want
         assert (want == "plain") == (not retrieval.tile_select_group(t, k))
+        if want != "plain":  # the stored scores against the tiles' bytes
+            tiles = nt * t * d * (2 if mode == "bf16" else 1)
+            assert (want == "deferred") == (b * nt * t * 4 <= tiles / 2)
 
     @pytest.mark.parametrize("masked", [False, True])
     @pytest.mark.parametrize("mode,d", [
         ("bf16", 64), ("bf16", 128), ("int8", 64), ("int8_dot", 64),
     ])
-    def test_a_singles_step_selects_nothing_and_a_pairs_still_does(
+    def test_a_step_selects_nothing_until_the_scores_outweigh_half_the_tile(
         self, mode, d, masked
     ):
-        """The jaxpr: the B = 1 scan body holds no ``sort`` / ``top_k``
-        — score, guard, mask, one maximum a group — and every selection
-        stands after the loop; the B = 2 body holds PR 27's three."""
+        """The jaxpr: the scan body of a single, a pair and a batch of
+        eight holds no ``sort`` / ``top_k`` — score, guard, mask, one
+        maximum a group — and every selection stands after the loop;
+        the body of the first batch beyond the bound holds PR 27's
+        three."""
         nt, t, k = 2, 1 << 13, 16
         g = retrieval.tile_select_group(t, k)
-        single = _scan_jaxpr(1, nt, t, d, k, _rules(nt * t, 1) if masked
-                             else None, mode)
-        inside = [e.primitive.name for e in _eqns(_scan_body(single))]
-        assert not {"sort", "top_k", "gather", "concatenate"} & set(inside)
-        assert inside.count("reduce_max") == 1
         groups = nt * t // g
         g2 = retrieval.tile_select_group(groups, k)
         assert not g2 and retrieval.tile_select_group(k * g, k) == 0
-        assert sorted(_top_k_eqns(single)) == [(1, k * g), (1, groups)]
-        pair = _scan_jaxpr(2, nt, t, d, k, _rules(nt * t, 2) if masked
-                           else None, mode)
-        assert sorted(_top_k_eqns(_scan_body(pair))) == sorted(
-            [(2, t // g), (2, k * g), (2, 2 * k)]
+        for b in (1, 2, 8):
+            assert retrieval.scan_select(b, nt, t, k, d, mode) == "deferred"
+            jaxpr = _scan_jaxpr(b, nt, t, d, k, _rules(nt * t, b) if masked
+                                else None, mode)
+            inside = [e.primitive.name for e in _eqns(_scan_body(jaxpr))]
+            assert not {"sort", "top_k", "gather", "concatenate"} & set(inside)
+            assert inside.count("reduce_max") == 1
+            assert sorted(_top_k_eqns(jaxpr)) == [(b, k * g), (b, groups)]
+        b = d // 4 if mode == "bf16" else d // 8  # the bound's edge
+        assert retrieval.scan_select(b, nt, t, k, d, mode) == "deferred"
+        b *= 2
+        assert retrieval.scan_select(b, nt, t, k, d, mode) == "two_level"
+        beyond = _scan_jaxpr(b, nt, t, d, k, _rules(nt * t, b) if masked
+                             else None, mode)
+        assert sorted(_top_k_eqns(_scan_body(beyond))) == sorted(
+            [(b, t // g), (b, k * g), (b, 2 * k)]
         )
-        assert _top_k_eqns(pair) == _top_k_eqns(_scan_body(pair))
+        assert _top_k_eqns(beyond) == _top_k_eqns(_scan_body(beyond))
+
+    @pytest.mark.parametrize("b", [1, 2, 8, 16])
+    def test_the_scores_are_stored_query_major_at_every_batch(self, b):
+        """The scan's stacked outputs: [NT, B, T/G, G] scores and
+        [NT, B, T/G] maxima, whatever B. (On the chip a dot leaves a
+        batch of 8 in blocks of 8 queries x 128 lanes, group after
+        group; storing it in that order was tried and lost: PERF.md
+        section 6, PR 36.)"""
+        nt, t, k = 2, 1 << 13, 128
+        assert retrieval.tile_select_group(t, k) == 8
+        (scan,) = [e for e in _scan_jaxpr(b, nt, t, 64, k).eqns
+                   if e.primitive.name == "scan"]
+        scores, maxima = (v.aval.shape for v in scan.outvars)
+        assert scores == (nt, b, 1024, 8) and maxima == (nt, b, 1024)
 
     def test_the_benchmark_shapes_select_in_small_sorts_once(self):
         """36 tiles of 2^18 at k' = 128: [1, 73728] maxima through the
@@ -1344,9 +1433,12 @@ class TestDeferredSelect:
             (1, 576), (1, 1024), (1, 1024), (1, 2048), (1, 2048)
         ]
 
-    @pytest.mark.parametrize("b,path", [(1, "deferred"), (2, "two_level"),
-                                        (3, "two_level")])
+    @pytest.mark.parametrize("b,path", [(1, "deferred"), (2, "deferred"),
+                                        (3, "deferred"), (4, "deferred"),
+                                        (5, "two_level")])
     def test_the_counter_counts_one_a_call(self, b, path):
+        """Rank 16 in bf16: the stored scores of up to 4 queries fit; 5
+        pad to 8 and select in the step."""
         from predictionio_tpu.obs import metrics as obs_metrics
 
         cat = self._catalog("bf16", 16, 2 * self.T + 5, seed=66)

@@ -1378,13 +1378,17 @@ class TestQueryCacheServing:
         assert set(block) == {"deferred", "two_level", "plain"}
         assert block == retrieval.stats_block()["tile_select"]
 
-    @pytest.mark.parametrize("batch,path", [(1, "deferred"), (2, "two_level")])
+    @pytest.mark.parametrize("batch,path", [
+        (1, "deferred"), (2, "deferred"), (4, "two_level"),
+    ])
     def test_a_shortlist_call_moves_its_path_by_one_on_both_routes(
         self, deployed_engine, batch, path
     ):
         """``/stats.json`` and ``/metrics`` of the engine server read the
         counter that the shortlist call of its process counts: a single
-        query's call (tiles wide enough to split) is ``deferred``."""
+        query's call (tiles wide enough to split) is ``deferred``, and
+        so is a batched dispatch's while its stored scores fit (rank 8
+        in bf16: two queries)."""
         import numpy as np
 
         from predictionio_tpu.obs import metrics as obs_metrics

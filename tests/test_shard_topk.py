@@ -78,22 +78,26 @@ class TestShardedChain:
         np.testing.assert_array_equal(ids, one[1])
         np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
 
-    @pytest.mark.parametrize("batch,path", [(1, "deferred"), (2, "two_level")])
+    @pytest.mark.parametrize("batch,path", [
+        (1, "deferred"), (2, "deferred"), (4, "deferred"), (8, "two_level"),
+    ])
     @pytest.mark.parametrize("items", [4 * 2 * 8192, 70_001])
     def test_tiles_wide_enough_to_split_give_the_one_chip_answer(
         self, mesh4, two_stage, monkeypatch, items, batch, path
     ):
-        """Tiles of 8,192 rows, two or three a shard: a single's scan
-        under ``shard_map`` selects once after each shard's loop
-        (``scan_select`` -> "deferred"), a pair's in every step; both
-        serve the one-chip chain's answer and the plain reference's."""
+        """Tiles of 8,192 rows, two or three a shard: a scan under
+        ``shard_map`` selects once after each shard's loop
+        (``scan_select`` -> "deferred") — a single's, a pair's, a batch
+        of four's; eight queries of this rank would store more than
+        half of what a step reads and select in every step. All serve
+        the one-chip chain's answer and the plain reference's."""
         monkeypatch.setenv("PIO_RETRIEVAL_TILE", "8192")
         U, V = _tables(items, seed=12)
         cat = ShardedCatalog(V, mesh4)
         kp = retrieval.two_stage_k(16, len(V))
         assert cat.tile == 8192 and cat.tiles_per_shard == -(-items // 4 // 8192)
         assert retrieval.scan_select(
-            batch, cat.tiles_per_shard, cat.tile, kp
+            batch, cat.tiles_per_shard, cat.tile, kp, cat.dim, cat.mode
         ) == path
         uix = np.arange(batch)
         before = retrieval.stats_block()["tile_select"]
@@ -112,8 +116,9 @@ class TestShardedChain:
         np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
 
     def test_the_sharded_singles_scan_body_selects_nothing(self, mesh4):
-        """The lowered sharded program at B = 1: no ``sort`` inside the
-        scan's ``while``; at B = 2 the step still sorts."""
+        """The lowered sharded program at B = 1 and B = 2: no ``sort``
+        inside the scan's ``while``; at B = 16 (rank 16: beyond the
+        bound) the step still sorts."""
         nt, t, d = 2, 8192, 16
         shapes = lambda b: (  # noqa: E731
             jax.ShapeDtypeStruct((b, d), np.float32),
@@ -145,7 +150,8 @@ class TestShardedChain:
             )(*shapes(b)).jaxpr)
 
         assert loop_sorts(1) == 0
-        assert loop_sorts(2) == 3
+        assert loop_sorts(2) == 0
+        assert loop_sorts(16) == 3
 
     def test_eight_shards(self, mesh8, two_stage):
         U, V = _tables(9000, seed=2)
