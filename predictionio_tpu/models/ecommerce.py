@@ -644,8 +644,9 @@ class ECommAlgorithm(Algorithm):
         self, model: ECommModel, queries: Sequence[tuple[int, Query]]
     ) -> list[tuple[int, PredictedResult]]:
         """Batched scoring with the live business rules intact. The host
-        turns each query into index lists (``rules.build``), the rules
-        travel to the device as ``ops.topk.Rules`` and every home,
+        turns each query into index lists (``rules.build``: no upload),
+        the rules travel to the device as ``ops.topk.Rules`` — with the
+        query vectors, in one upload a dispatch — and every home,
         category and blackList query of the micro-batch shares ONE
         masked program per stage (``ops.retrieval.top_k``: the coarse
         scan and the exact rescore at retrieval scale, the masked exact
@@ -737,7 +738,8 @@ class ECommAlgorithm(Algorithm):
                 [whites[r] for r in listed], len(listed_rules.ex), k
             )
             scores, ids = retrieval.rescore_top_k_batch(
-                batch_for(listed), V, cand, k=k, rules=listed_rules
+                batch_for(listed), V, cand, k=k,
+                rules=retrieval.device_rules(listed_rules),
             )
             publish(listed, scores, ids)
         return [(ix, r) for (ix, _), r in zip(queries, results)]

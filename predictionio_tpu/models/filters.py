@@ -119,8 +119,10 @@ class ItemCategories:
 # stored rows the programs slice (the coarse catalog's, padding
 # included; the catalog's own below the retrieval threshold); a query's
 # own rules are short index lists padded to power-of-two widths, so the
-# compiled shapes do not move with the traffic. No request builds a
-# dense [num_items] host array.
+# compiled shapes do not move with the traffic, and stay HOST arrays
+# here: ``ops.retrieval.top_k`` sends them up, in the one upload of a
+# dispatch under rules. No request builds a dense [num_items] host
+# array.
 
 
 def category_vectors(item_categories, rows: int) -> tuple:
@@ -158,27 +160,27 @@ def padded_rows(rows: list[int]) -> list[int]:
 
 def query_rules(avail, cats, excluded: Sequence, qcats: Sequence,
                 bucket: int):
-    """``device_rules`` of a padded batch: ``excluded[j]`` the rows
+    """The ``Rules`` of a padded batch, their per-query parts on the
+    host (no runtime call here): ``excluded[j]`` the rows
     query j alone may not be served, ``qcats[j]`` the category ids it is
     restricted to (None = unrestricted; an empty list allows nothing).
     ``ex`` is ``bucket`` wide unless a list outgrows it (the next power
     of two)."""
-    from predictionio_tpu.ops import retrieval
+    from predictionio_tpu.ops.retrieval import _pow2
     from predictionio_tpu.ops.topk import Rules
 
     n = len(excluded)
-    width = retrieval._pow2(max([bucket] + [len(e) for e in excluded]))
+    width = _pow2(max([bucket] + [len(e) for e in excluded]))
     ex = np.full((n, width), -1, np.int32)
     qcat = np.full(
-        (n, retrieval._pow2(max([1] + [len(c or ()) for c in qcats]))),
-        -2, np.int32,
+        (n, _pow2(max([1] + [len(c or ()) for c in qcats]))), -2, np.int32,
     )
     for j, (e, c) in enumerate(zip(excluded, qcats)):
         ex[j, : len(e)] = e
         if c:
             qcat[j, : len(c)] = c
     has_cat = np.asarray([c is not None for c in qcats])
-    return retrieval.device_rules(Rules(avail, cats, qcat, has_cat, ex))
+    return Rules(avail, cats, qcat, has_cat, ex)
 
 
 def candidate_lists(lists: Sequence, rows: int, k: int) -> np.ndarray:
@@ -316,7 +318,8 @@ def score_similar_batch(
     One regime for every query, filtered or not: its exclusions — its
     own entities and its ``blackList`` — and its categories travel to
     the device as ``ops.topk.Rules`` (``similar.build``: short index
-    lists, never a dense [rows] mask) and are applied where the scores
+    lists on the host, never a dense [rows] mask, and no upload — they
+    go up with the summed rows, once) and are applied where the scores
     are produced — inside the coarse scan and again in the rescore at
     retrieval scale, in the masked exact program below it
     (``ops.retrieval.top_k`` decides) — so k = pow2(num) carries no
@@ -421,7 +424,7 @@ def score_similar_batch(
         ixs, weights, rules = listed_batch
         cand = candidate_lists([whites[r] for r in listed], len(ixs), k)
         scores, ids = retrieval.rescore_sum_rows_top_k_batch(
-            ixs, weights, V, cand, k=k, rules=rules
+            ixs, weights, V, cand, k=k, rules=retrieval.device_rules(rules)
         )
         publish(listed, scores, ids)
     return results

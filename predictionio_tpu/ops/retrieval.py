@@ -56,7 +56,8 @@ the four ALS templates hand it a query batch in one of three forms
 (``UserRows``, ``Vectors``, ``SumRows`` — each the argument list that an
 exact op of ops/topk.py and its rescore variant share; the last two
 under ``ops.topk.Rules`` where the template has filters: no filter
-keeps a query from the shortlist), the resident
+keeps a query from the shortlist, and such a dispatch goes up to the
+device in ONE buffer, ``pack``), the resident
 exact table, the catalog's row count, the coarse copy and ``k``, and it
 alone decides exact or two-stage, runs shortlist -> rescore, and every
 Nth two-stage dispatch re-scores row 0 exactly (the live recall probe).
@@ -114,7 +115,9 @@ stage's conversions, uploads and launch: what it costs to enqueue),
 the one blocking read that ends the chain as ``dispatch.fetch``
 (``pio_retrieval_fetch_seconds``: the device time of both programs and
 the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
-one a dispatch through ``top_k``), and the two serving programs
+one a dispatch through ``top_k``, and ``pio_retrieval_uploads_total``
+the transfers the other way: one a dispatch under rules — ``pack``'s
+buffer — two for ``UserRows``), and the two serving programs
 carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a deferred
 step is ``score`` / ``mask`` / ``group_max`` and ``select`` follows
 the loop; a per-tile one is ``score`` / ``mask`` / ``tile_topk`` /
@@ -125,6 +128,7 @@ trace viewer.
 from __future__ import annotations
 
 import functools
+import inspect
 import itertools
 import os
 import threading
@@ -266,6 +270,12 @@ _m_host_reads = obs_metrics.counter(
     "pio_retrieval_host_reads_total",
     "blocking device-to-host reads made by two-stage retrieval",
 )
+_m_uploads = obs_metrics.counter(
+    "pio_retrieval_uploads_total",
+    "host-to-device transfers made by the serving chain: every host array "
+    "a stage converts and puts up, each per-query part of device_rules, "
+    "the one packed buffer of a dispatch under rules",
+)
 _m_probe_recall = obs_metrics.gauge(
     "pio_retrieval_probe_recall",
     "recall@num of the most recent exact-rescored probe query",
@@ -346,6 +356,7 @@ def stats_block() -> dict:
         "rescore_seconds": _m_rescore_secs.summary(),
         "fetch_seconds": _m_fetch_secs.summary(),
         "host_reads": _m_host_reads.value(),
+        "uploads": _m_uploads.value(),
         "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
         "score_form": {f: m.value() for f, m in _m_score_form.items()},
         "rescore_temp_bytes": {
@@ -686,24 +697,120 @@ def _coarse_topk(q, tiles, scales, ids, k: int, mode: str):
     return _coarse_scan(q, tiles, scales, ids, k, mode)
 
 
+# Under rules a scan cannot start before its queries' rules are on the
+# device, and an upload costs the host ~0.25 ms to hand over and ~0.6 ms
+# to land whatever its size (PERF.md section 6, PR 29 and PR 34): three
+# rule arrays, the vectors and a sum-of-rows form's two more were four and
+# six of them a dispatch, every one before the launch. So a dispatch under
+# rules goes up ONCE: ``pack`` lays the form's per-query arrays and the
+# rules' beside each other in one [bp, W] int32 buffer (f32 columns as
+# their bits), ``Layout`` says where each lies — a static argument, so a
+# layout is a shape like any other: one program per (bp, C, E, L), as the
+# separate arrays had — and the three masked programs take the buffer
+# apart themselves (``_unpack``: slices and bit-casts in front of the
+# bodies they always had; no program of its own, no eager slice). A form
+# without rules has nothing to wait for but its vectors, and whatever
+# else its rescore wants goes up behind the running scan: it stays as it
+# was.
+
+
+class Layout(NamedTuple):
+    """Column widths of a packed dispatch, in the buffer's order: the
+    [dim] f32 query vectors, ``Rules.qcat`` [cats], ``Rules.has_cat``
+    (one column), ``Rules.ex`` [excluded], and for a sum of rows its
+    [rows] indices and [rows] f32 weights (0: a form without)."""
+
+    dim: int
+    cats: int
+    excluded: int
+    rows: int = 0
+
+    def bounds(self):
+        """The six parts' (start, stop) columns."""
+        ends = np.cumsum([self.dim, self.cats, 1, self.excluded,
+                          self.rows, self.rows]).tolist()
+        return list(zip([0] + ends, ends))
+
+
+def pack(vectors, rules: Rules, ixs=None, weights=None):
+    """(the [bp, W] int32 buffer, its ``Layout``) of a dispatch under
+    ``rules`` from the host (models/filters.py ``query_rules``): B rows
+    of every part, padded to the power of two at or above B with copies
+    of row 0 (discarded after the read). f32 parts travel as their
+    bits: ``_unpack`` gives every value back exactly."""
+    parts = [
+        np.ascontiguousarray(vectors, np.float32).view(np.int32),
+        np.asarray(rules.qcat, np.int32),
+        np.asarray(rules.has_cat, np.int32)[:, None],
+        np.asarray(rules.ex, np.int32),
+    ]
+    if ixs is not None:
+        parts += [
+            np.asarray(ixs, np.int32),
+            np.ascontiguousarray(weights, np.float32).view(np.int32),
+        ]
+    if len({len(p) for p in parts}) != 1:
+        raise ValueError(
+            f"parts of {[len(p) for p in parts]} rows do not make one batch"
+        )
+    layout = Layout(
+        parts[0].shape[1], parts[1].shape[1], parts[3].shape[1],
+        0 if ixs is None else parts[4].shape[1],
+    )
+    return _pad_rows(np.concatenate(parts, axis=1), _pow2(len(parts[0]))), layout
+
+
+def _unpack(packed, layout: Layout, rules: Rules):
+    """On the device, inside the program that reads them: ``pack``'s
+    buffer -> (vectors, ``rules`` with its per-query parts filled in,
+    ixs, weights); the last two empty for a form without rows."""
+    f32 = functools.partial(
+        jax.lax.bitcast_convert_type, new_dtype=jnp.float32
+    )
+    vecs, qcat, has_cat, ex, ixs, weights = (
+        packed[:, a:b] for a, b in layout.bounds()
+    )
+    rules = rules._replace(qcat=qcat, has_cat=has_cat[:, 0] != 0, ex=ex)
+    return f32(vecs), rules, ixs, f32(weights)
+
+
 @obs_device.track_jit("retrieval.coarse_topk_masked")
-@functools.partial(jax.jit, static_argnames=("k", "mode"))
+@functools.partial(jax.jit, static_argnames=("k", "mode", "layout"))
 def _coarse_topk_masked(q, tiles, scales, ids, rules: Rules, k: int,
-                        mode: str):
+                        mode: str, layout: Layout | None = None):
     """The same scan under business rules: a program of its own, so the
     unmasked one stays what it is (and a device trace tells them apart:
-    ``jit__coarse_topk_masked``)."""
+    ``jit__coarse_topk_masked``). Under ``layout``, ``q`` is ``pack``'s
+    buffer and ``rules`` holds the resident vectors alone."""
+    if layout is not None:
+        q, rules, _, _ = _unpack(q, layout, rules)
     return _coarse_scan(q, tiles, scales, ids, k, mode, rules)
 
 
 def device_rules(rules: Rules) -> Rules:
-    """``rules`` with its per-query parts put on the device: once per
-    dispatch, for both stages."""
+    """``rules`` with its per-query parts put on the device, one array
+    each: for the callers off ``top_k``'s packed chain (the exact
+    programs, a ``whiteList``'s host-built candidates, the tests'
+    references)."""
+    _m_uploads.inc(3)
     return rules._replace(
         qcat=jnp.asarray(np.asarray(rules.qcat, np.int32)),
         has_cat=jnp.asarray(np.asarray(rules.has_cat, bool)),
         ex=jnp.asarray(np.asarray(rules.ex, np.int32)),
     )
+
+
+def _resident(rules: Rules) -> Rules:
+    """``rules``' catalog-wide vectors alone: what a program is handed
+    beside a packed buffer, which holds the rest."""
+    return Rules(rules.avail, rules.cats, None, None, None)
+
+
+def _pad_rows(a, rows: int):
+    """Host ``a`` filled to ``rows`` rows with copies of row 0."""
+    if len(a) < rows:
+        a = np.concatenate([a, np.repeat(a[:1], rows - len(a), axis=0)])
+    return a
 
 
 def _up(a, dtype, rows: int = 0, sharding=None):
@@ -714,9 +821,8 @@ def _up(a, dtype, rows: int = 0, sharding=None):
     as ``sharding`` says (the sharded chain's replicated queries)."""
     if isinstance(a, jax.Array):
         return a
-    a = np.ascontiguousarray(a, dtype=dtype)
-    if len(a) < rows:
-        a = np.concatenate([a, np.repeat(a[:1], rows - len(a), axis=0)])
+    a = _pad_rows(np.ascontiguousarray(a, dtype=dtype), rows)
+    _m_uploads.inc()
     return jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
 
 
@@ -740,9 +846,10 @@ def _fetch(out, n: int):
 class Scan(NamedTuple):
     """What ``CoarseCatalog.launch`` left on the device."""
 
-    queries: jax.Array  # [bp, D] f32, as uploaded
+    queries: jax.Array  # as uploaded: [bp, D] f32, or ``pack``'s buffer
     scores: jax.Array  # [bp, k'] coarse scores
     ids: jax.Array  # [bp, k'] int32 candidate ids, -1 past the catalog
+    layout: Layout | None = None  # of ``queries``, where they are packed
 
 
 class CoarseCatalog:
@@ -827,31 +934,42 @@ class CoarseCatalog:
         ``Rules`` vector that this catalog's scan can slice."""
         return int(self._ids.size)
 
-    def launch(self, queries, k: int, rules: Rules | None = None):
+    def launch(self, queries, k: int, rules: Rules | None = None,
+               pack_with: tuple | None = None):
         """The coarse scan enqueued, nothing read: -> ``Scan``, device
         arrays of bp rows, bp the power of two at or above the B queries
         given (``shortlist`` below has the contract). ``top_k`` hands
-        the ids to a rescore program as they are."""
+        the ids to a rescore program as they are. ``pack_with``: None —
+        the queries go up alone, the ``rules`` are ``device_rules`` —
+        or what else of the form goes into ONE upload with the queries
+        and the host ``rules``' rows for them (``pack``: nothing, or a
+        sum of rows' indices and weights), which the rescore then reads
+        out of ``Scan.queries`` too."""
         k = max(1, min(int(k), self.tile))
+        layout = None
         with _shortlist_stage():
-            q = _up(queries, np.float32, _pow2(len(queries)))
+            if pack_with is None:
+                q = _up(queries, np.float32, _pow2(len(queries)))
+            else:
+                queries, layout = pack(queries, rules, *pack_with)
+                q, rules = _up(queries, np.int32), _resident(rules)
             if rules is None:
                 s, ids = _coarse_topk(
                     q, self._tiles, self._scales, self._ids, k, self.mode,
                 )
             else:
-                if len(rules.ex) != len(q):
+                if layout is None and len(rules.ex) != len(q):
                     raise ValueError(
                         f"rules for {len(rules.ex)} queries, "
                         f"batch of {len(q)}"
                     )
                 s, ids = _coarse_topk_masked(
                     q, self._tiles, self._scales, self._ids, rules, k,
-                    self.mode,
+                    self.mode, layout,
                 )
         _count_scan(len(q), self._ids.shape[0], self.tile, k, self.dim,
                     self.mode)
-        return Scan(q, s, ids)
+        return Scan(q, s, ids, layout)
 
     def shortlist(self, queries, k: int, rules: Rules | None = None):
         """Coarse top-k' candidate ids for a [B, D] f32 query batch ->
@@ -942,7 +1060,8 @@ class _RescoreProgram:
     """A rescore entry point as ``jax.jit`` would run it, compiled ahead
     of the first call of each signature (shapes, dtypes, the tables'
     layouts, ``k``) so that the compiled program's own memory analysis
-    can be published: ``pio_retrieval_rescore_temp_bytes{fn}`` holds
+    can be published (``k`` and, where ``fn`` has one, ``layout`` are
+    static keywords): ``pio_retrieval_rescore_temp_bytes{fn}`` holds
     the most temporary bytes any of ``fn``'s programs needs. A program
     that re-lays a table before it gathers needs a table's worth.
     ``obs_device.track_jit`` counts these compiles through
@@ -950,7 +1069,8 @@ class _RescoreProgram:
 
     def __init__(self, name: str, fn):
         self.name = name
-        self._jit = jax.jit(fn, static_argnames=("k",))
+        static = {"k", "layout"} & set(inspect.signature(fn).parameters)
+        self._jit = jax.jit(fn, static_argnames=tuple(sorted(static)))
         self.lower = self._jit.lower
         self._compiled: dict = {}
         self._lock = threading.Lock()
@@ -966,8 +1086,8 @@ class _RescoreProgram:
     def temp_bytes(self) -> int:
         return int(self._m_temp.value())
 
-    def __call__(self, *args, k: int):
-        key = (k, *(
+    def __call__(self, *args, **static):
+        key = (*sorted(static.items()), *(
             (a.shape, a.dtype, getattr(a, "format", None))
             for a in jax.tree.leaves(args)
         ))
@@ -976,7 +1096,7 @@ class _RescoreProgram:
             with self._lock:
                 program = self._compiled.get(key)
                 if program is None:
-                    program = self._jit.lower(*args, k=k).compile()
+                    program = self._jit.lower(*args, **static).compile()
                     stats = program.memory_analysis()
                     if stats is not None:  # a backend may not say
                         self._m_temp.set(max(
@@ -1009,15 +1129,26 @@ def _rescore_vectors(user_vectors, item_factors, cand_ids, k: int):
     return _score_candidates(user_vectors, item_factors, cand_ids, k)
 
 
+# Under ``layout`` a masked program's first argument is the scan's
+# packed buffer, ``rules`` the resident vectors alone, and a sum of
+# rows' weights are in the buffer too (``row_weights`` None).
+
+
 @_rescore_program("retrieval.rescore_vectors_masked")
 def _rescore_vectors_masked(user_vectors, item_factors, cand_ids,
-                            rules: Rules, k: int):
+                            rules: Rules, k: int,
+                            layout: Layout | None = None):
+    if layout is not None:
+        user_vectors, rules, _, _ = _unpack(user_vectors, layout, rules)
     return _score_candidates(user_vectors, item_factors, cand_ids, k, rules)
 
 
 @_rescore_program("retrieval.rescore_sum_rows_masked")
 def _rescore_sum_rows_masked(row_ixs, row_weights, item_factors, cand_ids,
-                             rules: Rules, k: int):
+                             rules: Rules, k: int,
+                             layout: Layout | None = None):
+    if layout is not None:
+        _, rules, row_ixs, row_weights = _unpack(row_ixs, layout, rules)
     rows = _table_rows(item_factors, row_ixs.astype(jnp.int32))
     qvecs = jnp.sum(rows * row_weights[..., None], axis=1)
     return _score_candidates(qvecs, item_factors, cand_ids, k, rules)
@@ -1051,13 +1182,15 @@ def _launch_gather(user_ixs, user_factors, item_factors, cand_ids, k: int):
 
 
 def _launch_vectors(user_vectors, item_factors, cand_ids, k: int,
-                    rules: Rules | None = None):
+                    rules: Rules | None = None, layout: Layout | None = None):
     with _rescore_stage():
         cand = _up(cand_ids, np.int32)
         vecs = _up(user_vectors, np.float32, len(cand))
         if rules is None:
             return _rescore_vectors(vecs, item_factors, cand, k=k)
-        return _rescore_vectors_masked(vecs, item_factors, cand, rules, k=k)
+        return _rescore_vectors_masked(
+            vecs, item_factors, cand, rules, k=k, layout=layout
+        )
 
 
 def _launch_sum_rows(row_ixs, row_weights, item_factors, cand_ids, k: int,
@@ -1110,14 +1243,17 @@ def rescore_sum_rows_top_k_batch(row_ixs, row_weights, item_factors,
 #
 # A query batch reaches ``top_k`` in one of three forms. A form is the
 # argument list that an exact op of ops/topk.py and its rescore variant
-# above share, and knows three things: the f32 vectors the coarse pass
-# scores (``coarse_vectors``), its exact program and its rescore
-# program (``rescore``: enqueued behind the scan on the scan's device
-# ids, at their power-of-two rows). Every form leads with its [B, ...]
-# per-query array and carries ``rules`` (None where the form has none;
-# ``SumRows`` always has), and ``head()`` is its first query alone: what the
-# recall probe scores, in the shapes that query would have arriving
-# alone.
+# above share, and knows four things: the f32 vectors the coarse pass
+# scores (``coarse_vectors``), what else of it the scan's one upload
+# carries under rules (``pack_with``; None: the vectors go up alone), its
+# exact program and its rescore program (``rescore``: enqueued behind
+# the ``Scan`` on its device ids, at their power-of-two rows — and under
+# rules on its packed buffer, so that nothing more goes up). Every form
+# leads with its [B, ...] per-query array and carries ``rules`` (None
+# where the form has none; ``SumRows`` always has; their per-query parts
+# host arrays, models/filters.py ``query_rules``), and ``head()`` is its
+# first query alone: what the recall probe scores, in the shapes that
+# query would have arriving alone.
 
 
 def _head_rules(r: Rules | None) -> Rules | None:
@@ -1137,11 +1273,14 @@ class UserRows(NamedTuple):
     def coarse_vectors(self):
         return self.vectors(self.ixs)
 
+    def pack_with(self):
+        return None  # the indices go up behind the running scan
+
     def exact(self, table, k: int):
         return gather_top_k_batch(self.ixs, self.users, table, k=k)
 
-    def rescore(self, table, cand, k: int):
-        return _launch_gather(self.ixs, self.users, table, cand, k)
+    def rescore(self, table, scan: Scan, k: int):
+        return _launch_gather(self.ixs, self.users, table, scan.ids, k)
 
     def head(self):
         return self._replace(ixs=self.ixs[:1])
@@ -1149,8 +1288,8 @@ class UserRows(NamedTuple):
 
 class Vectors(NamedTuple):
     """[B, D] f32 query vectors (``top_k_items_batch``), under
-    ``device_rules`` where given (``top_k_items_batch_masked``): the
-    caller has padded the batch to the power of two the rules hold."""
+    ``Rules`` where given (``top_k_items_batch_masked``): the caller
+    has padded the batch to the power of two the rules hold."""
 
     vectors: np.ndarray
     rules: Rules | None = None
@@ -1158,13 +1297,22 @@ class Vectors(NamedTuple):
     def coarse_vectors(self):
         return self.vectors
 
+    def pack_with(self):
+        return None if self.rules is None else ()
+
     def exact(self, table, k: int):
         if self.rules is None:
             return top_k_items_batch(self.vectors, table, k=k)
-        return top_k_items_batch_masked(self.vectors, table, self.rules, k=k)
+        return top_k_items_batch_masked(
+            self.vectors, table, device_rules(self.rules), k=k
+        )
 
-    def rescore(self, table, cand, k: int):
-        return _launch_vectors(self.vectors, table, cand, k, self.rules)
+    def rescore(self, table, scan: Scan, k: int):
+        # the scan's upload is the rescore's: the queries, or the buffer
+        return _launch_vectors(
+            scan.queries, table, scan.ids, k,
+            self.rules and _resident(self.rules), scan.layout,
+        )
 
     def head(self):
         return Vectors(self.vectors[:1], _head_rules(self.rules))
@@ -1172,7 +1320,7 @@ class Vectors(NamedTuple):
 
 class SumRows(NamedTuple):
     """Weighted sums of catalog rows, [B, L] indices and weights, under
-    ``device_rules`` (``sum_rows_top_k_batch_masked``; the batch padded
+    ``Rules`` (``sum_rows_top_k_batch_masked``; the batch padded
     as for ``Vectors``): a query's own rows, its blackList and its
     categories are rules like any other, applied inside the scan and the
     rescore, and every query of these templates has the first."""
@@ -1185,15 +1333,20 @@ class SumRows(NamedTuple):
     def coarse_vectors(self):
         return self.vectors(self.ixs, self.weights)
 
+    def pack_with(self):
+        return self.ixs, self.weights
+
     def exact(self, table, k: int):
         return sum_rows_top_k_batch_masked(
-            self.ixs, self.weights, table, self.rules, k=k
+            self.ixs, self.weights, table, device_rules(self.rules), k=k
         )
 
-    def rescore(self, table, cand, k: int):
-        return _launch_sum_rows(
-            self.ixs, self.weights, table, cand, k, self.rules
-        )
+    def rescore(self, table, scan: Scan, k: int):
+        with _rescore_stage():  # all it reads went up with the scan
+            return _rescore_sum_rows_masked(
+                scan.queries, None, table, scan.ids, _resident(self.rules),
+                k=k, layout=scan.layout,
+            )
 
     def head(self):
         return self._replace(
@@ -1213,7 +1366,10 @@ def top_k(query, table, num_rows: int, coarse, k: int,
     a caller that asked ``two_stage_k`` itself and got 0 has none to
     give) rescored by the form's rescore program — the scan's ids stay
     on the device, the rescore is enqueued behind the scan, and one read
-    brings the answer back — and on every
+    brings the answer back; a form under rules, whose scan has to wait
+    for them, goes up in ONE upload that both programs take apart
+    (``pack``), a form without as it always did: its vectors, then what
+    its rescore wants behind the running scan — and on every
     ``PIO_RETRIEVAL_PROBE_EVERY``-th such dispatch the exact program
     again on the first query, whose leading ``probe_n`` ids (the ones
     its answer is cut from; all k by default) are compared. No filter
@@ -1230,13 +1386,10 @@ def top_k(query, table, num_rows: int, coarse, k: int,
         return np.asarray(s), np.asarray(ids)
     if callable(coarse):
         coarse = coarse()
-    scan = coarse.launch(query.coarse_vectors(), kp, query.rules)
-    chained = query
-    if isinstance(query, Vectors):  # the scan's queries are its own: up once
-        chained = query._replace(vectors=scan.queries)
-    s, ids = _read_rescore(
-        chained.rescore(table, scan.ids, k), len(query[0])
+    scan = coarse.launch(
+        query.coarse_vectors(), kp, query.rules, query.pack_with()
     )
+    s, ids = _read_rescore(query.rescore(table, scan, k), len(query[0]))
     probe(
         ids[0, :probe_n],
         lambda: np.asarray(query.head().exact(table, k)[1])[0, :probe_n],

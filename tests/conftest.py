@@ -43,3 +43,48 @@ def storage():
     set_storage(s)
     yield s
     set_storage(None)
+
+
+@pytest.fixture()
+def region_uploads(monkeypatch):
+    """``watch(name)`` -> a list that gets, for every later region
+    ``name``, (how far ``pio_retrieval_uploads_total`` moved inside it,
+    the ``jnp.asarray`` / ``jax.device_put`` calls made inside it): what
+    the template tests hold the build regions to."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.obs import trace as obs_trace
+    from predictionio_tpu.ops import retrieval
+
+    open_, calls = [], []
+
+    def watch(name):
+        seen = []
+
+        class Watched(obs_trace.region):
+            def __enter__(self):
+                if self.name == name:
+                    open_.append((retrieval._m_uploads.value(), len(calls)))
+                return super().__enter__()
+
+            def __exit__(self, *exc):
+                if self.name == name:
+                    before, n = open_.pop()
+                    seen.append(
+                        (retrieval._m_uploads.value() - before, calls[n:])
+                    )
+                return super().__exit__(*exc)
+
+        monkeypatch.setattr(obs_trace, "region", Watched)
+        return seen
+
+    for owner, fn in ((jnp, "asarray"), (jax, "device_put")):
+        real = getattr(owner, fn)
+
+        def counted(*a, _real=real, _fn=fn, **kw):
+            if open_:
+                calls.append(_fn)
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(owner, fn, counted)
+    return watch

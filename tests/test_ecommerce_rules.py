@@ -239,9 +239,9 @@ def test_the_unavailable_list_changes_no_shape(world, two_stage, monkeypatch):
     sizes = []
     real = retrieval.CoarseCatalog.launch
 
-    def spy(self, queries, k, rules=None):
+    def spy(self, queries, k, *rest):
         sizes.append(k)
-        return real(self, queries, k, rules)
+        return real(self, queries, k, *rest)
 
     monkeypatch.setattr(retrieval.CoarseCatalog, "launch", spy)
     tracked = ("retrieval.coarse_topk_masked", "retrieval.rescore_vectors_masked",
@@ -291,6 +291,52 @@ def test_spans_and_counters_of_the_rules(world, two_stage):
     assert spans["rules.seen_read"][3] == "rules.build"
     assert ec._m_rules.summary()["count"] >= 1
     assert ec._m_excluded.summary()["count"] >= 4
+
+
+def test_rules_build_makes_no_upload(world, two_stage, region_uploads):
+    """The build turns queries into host index lists; what of them the
+    device needs goes up inside ``dispatch.shortlist``, once."""
+    world.algo.predict(world.model, world.query("home", 1))  # the constraint read
+    builds = region_uploads("rules.build")
+    before = retrieval.stats_block()
+    world.algo.batch_predict(world.model, [
+        (0, world.query("home", 2)), (1, world.query("category", 3)),
+        (2, world.query("blackList", 4))])
+    world.algo.predict(world.model, world.query("cold", 1))
+    after = retrieval.stats_block()
+    assert builds == [(0, [])] * 2
+    dispatches = after["shortlist_seconds"]["count"] - before["shortlist_seconds"]["count"]
+    probes = after["probes"] - before["probes"]  # each: device_rules' three
+    assert dispatches == 2
+    assert after["uploads"] - before["uploads"] == dispatches + 3 * probes
+
+
+def _jit_compiles():
+    return {f: s["compiles"] for f, s in obs_device.compile_snapshot().items()}
+
+
+def test_no_layout_is_first_compiled_after_the_warm_up(storage, two_stage):
+    """The storefront cell warms by closed-loop traffic of its mix
+    (benchmark/drivers/serve.py ``_phases``): singles up to the recall
+    probe's turn, then bursts that fill every batch bucket. A packed
+    layout is a shape like any other — the bucket and widths that no
+    kind of the cell's queries moves — so a warm-up that met each bucket
+    with the mix's most frequent kind alone has compiled what every kind
+    runs: home, category page and cart at B = 1, 2, 4, 8 compile
+    nothing."""
+    world = World(storage, "float32")
+
+    def batch(kind, b, first):
+        return [(n, world.query(kind, first + n)) for n in range(b)]
+
+    for b in (1, 1, 2, 4, 8):  # the probe every second dispatch (two_stage)
+        world.algo.batch_predict(world.model, batch("home", b, 1))
+    before = _jit_compiles()
+    for kind in ("home", "category", "blackList"):
+        for b in (1, 2, 4, 8):
+            out = world.algo.batch_predict(world.model, batch(kind, b, 9 + b))
+            assert all(len(r.itemScores) > 0 for _, r in out)
+    assert _jit_compiles() == before
 
 
 def test_sqlite_reads_seen_items_by_projection(tmp_path, monkeypatch):

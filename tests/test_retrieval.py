@@ -53,6 +53,21 @@ def _open_rules(stored, b):
     ))
 
 
+def _host(rules):
+    """``rules`` as a template hands them to ``top_k``: the per-query
+    parts host arrays (``top_k`` packs them into its one upload)."""
+    return rules._replace(
+        qcat=np.asarray(rules.qcat), has_cat=np.asarray(rules.has_cat),
+        ex=np.asarray(rules.ex),
+    )
+
+
+def _device(rules):
+    """The ``device_rules`` of a form's host rules, for the host-facing
+    calls a test compares ``top_k`` with (None stays None)."""
+    return rules and retrieval.device_rules(rules)
+
+
 def _exact_top(q, v, scales, k):
     """Numpy exact reference: ids of the top-k dequantized dot scores."""
     vf = v.astype(np.float32)
@@ -1490,7 +1505,7 @@ class TestServingChain:
                 retrieval.SumRows(
                     ixs, weights,
                     lambda i, w: (host[i] * w[..., None]).sum(axis=1),
-                    rules,
+                    _host(rules),
                 ),
                 lambda: sum_rows_top_k_batch_masked(
                     ixs, weights, table, rules, k=self.K),
@@ -1512,7 +1527,7 @@ class TestServingChain:
             qcat=np.asarray([[-2], [1], [-2], [0]], np.int32),
         )
         return (
-            retrieval.Vectors(v, rules),
+            retrieval.Vectors(v, _host(rules)),
             lambda: topk.top_k_items_batch_masked(v, table, rules, k=self.K),
             lambda cand: retrieval.rescore_top_k_batch(
                 v, table, cand, self.K, rules),
@@ -1553,7 +1568,9 @@ class TestServingChain:
             form, table, host, coarse.stored_rows if two_stage else self.I
         )
         if two_stage:
-            _, cand = coarse.shortlist(query.coarse_vectors(), kp, query.rules)
+            _, cand = coarse.shortlist(
+                query.coarse_vectors(), kp, _device(query.rules)
+            )
             want = rescore(cand)
         else:
             want = exact()
@@ -1564,8 +1581,8 @@ class TestServingChain:
         real_launch, real_exact = CoarseCatalog.launch, type(query).exact
         monkeypatch.setattr(
             CoarseCatalog, "launch",
-            lambda self, q, k, rules=None: shortlists.append(k)
-            or real_launch(self, q, k, rules),
+            lambda self, q, k, *rest: shortlists.append(k)
+            or real_launch(self, q, k, *rest),
         )
         monkeypatch.setattr(
             type(query), "exact",
@@ -1636,7 +1653,7 @@ class TestServingChain:
         ixs, weights = np.asarray([[5]], np.int32), np.ones((1, 1), np.float32)
         query = retrieval.SumRows(
             ixs, weights, lambda i, w: (host[i] * w[..., None]).sum(axis=1),
-            rules,
+            _host(rules),
         )
         before = retrieval.stats_block()
         s, ids = retrieval.top_k(query, table, self.I, coarse, self.K)
@@ -1710,7 +1727,7 @@ class TestOneCrossing:
                 retrieval.SumRows(
                     ixs, weights,
                     lambda i, w: (host[i] * w[..., None]).sum(axis=1),
-                    rules,
+                    _host(rules),
                 ),
                 lambda cand: retrieval.rescore_sum_rows_top_k_batch(
                     ixs, weights, table, cand, k=self.K, rules=rules),
@@ -1734,7 +1751,7 @@ class TestOneCrossing:
         rules = _rules(stored, bp, small_cat=range(0, len(host), 5),
                        ex=ex, qcat=qcat)
         return (
-            retrieval.Vectors(v, rules),
+            retrieval.Vectors(v, _host(rules)),
             lambda cand: retrieval.rescore_top_k_batch(
                 v, table, cand, self.K, rules),
         )
@@ -1757,7 +1774,9 @@ class TestOneCrossing:
             form, b, table, host, coarse.stored_rows
         )
         n = len(query[0])
-        _, cand = coarse.shortlist(query.coarse_vectors(), kp, query.rules)
+        _, cand = coarse.shortlist(
+            query.coarse_vectors(), kp, _device(query.rules)
+        )
         assert isinstance(cand, np.ndarray) and cand.shape == (n, kp)
         want_s, want_ids = host_rescore(cand)
         assert want_ids.shape == (n, self.K) and (want_ids[:, 0] >= 0).all()
@@ -1769,11 +1788,12 @@ class TestOneCrossing:
         handed = []
         real_rescore, real_fetch = type(query).rescore, retrieval._fetch
 
-        def guarded_rescore(self, table, cand, k):
-            handed.append(cand)
-            if form.startswith("vectors"):  # up once, for both stages
-                assert isinstance(self.vectors, jax.Array)
-            return real_rescore(self, table, cand, k)
+        def guarded_rescore(self, table, scan, k):
+            handed.append(scan.ids)
+            # what went up for the scan is on the device for the rescore
+            assert isinstance(scan.queries, jax.Array)
+            assert (scan.layout is not None) == (query.rules is not None)
+            return real_rescore(self, table, scan, k)
 
         def fetch(out, rows):
             with jax.transfer_guard_device_to_host("allow"):
@@ -1828,7 +1848,7 @@ class TestOneCrossing:
         qcat = np.asarray([[1], [-2]], np.int32)
         rules = _rules(coarse.stored_rows, 2, small_cat=allowed, qcat=qcat)
         s, ids = retrieval.top_k(
-            retrieval.Vectors(v, rules), table, self.I, coarse, self.K
+            retrieval.Vectors(v, _host(rules)), table, self.I, coarse, self.K
         )
         order = np.argsort(-(v[0] @ host[allowed].T), kind="stable")
         assert ids[0].tolist() == [allowed[i] for i in order] + [-1] * 3
@@ -1837,6 +1857,212 @@ class TestOneCrossing:
         )
         assert (s[0, 5:] < -1e29).all()
         assert (ids[1] >= 0).all()  # the unrestricted batchmate is full
+
+
+# -- a dispatch under rules goes up once ----------------------------------------
+
+
+def _packed_case(b, c, e, rows, d, seed=81):
+    """Host parts of ``b`` queries: f32 vectors and weights that hold
+    the bit patterns a bit-cast has to carry (signed zero, a subnormal,
+    infinities, a NaN with a payload), category ids with -2 pads,
+    ``has_cat`` of both kinds, exclusion lists with -1 pads."""
+    rng = np.random.default_rng(seed + b)
+    odd = np.asarray(
+        [0x80000000, 0x00000001, 0x7F800000, 0xFF800000, 0x7FC01234],
+        np.uint32,
+    ).view(np.float32)
+    vectors = rng.standard_normal((b, d)).astype(np.float32)
+    vectors[0, : len(odd)] = odd
+    qcat = np.full((b, c), -2, np.int32)
+    qcat[::2, : max(1, c - 1)] = rng.integers(0, 9000, (len(qcat[::2]), max(1, c - 1)))
+    ex = np.full((b, e), -1, np.int32)
+    ex[:, : e - 3] = rng.integers(0, 1 << 22, (b, e - 3))
+    has_cat = (np.arange(b) % 2 == 0)
+    ixs = weights = None
+    if rows:
+        ixs = rng.integers(0, 1 << 22, (b, rows)).astype(np.int32)
+        weights = (rng.random((b, rows)) < 0.7).astype(np.float32)
+        weights[0, : len(odd)] = odd
+    return vectors, qcat, has_cat, ex, ixs, weights
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.int32)
+
+
+class TestPackedDispatch:
+    """Under rules everything a dispatch puts on the device is ONE
+    buffer (``retrieval.pack``) that the masked programs take apart
+    (``retrieval._unpack``): every part comes back bit for bit, the
+    chain answers as the separate arrays do, and the uploads are
+    counted."""
+
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("rows", [0, 8, 16])
+    @pytest.mark.parametrize("e", [16, 32], ids=["bucket", "outgrown"])
+    @pytest.mark.parametrize("c", [1, 2, 4])
+    @pytest.mark.parametrize("b", [1, 2, 5, 11])
+    def test_the_layout_round_trips(self, b, c, e, rows, d):
+        import jax
+        import jax.numpy as jnp
+
+        vectors, qcat, has_cat, ex, ixs, weights = _packed_case(b, c, e, rows, d)
+        avail = jnp.ones(7, jnp.uint8)
+        rules = Rules(avail, (avail,), qcat, has_cat, ex)
+        packed, layout = retrieval.pack(vectors, rules, ixs, weights)
+        bp = retrieval._pow2(b)
+        assert layout == retrieval.Layout(d, c, e, rows)
+        assert packed.dtype == np.int32
+        assert packed.shape == (bp, d + c + 1 + e + 2 * rows)
+        np.testing.assert_array_equal(packed[b:], np.repeat(packed[:1], bp - b, 0))
+        got_v, got_r, got_ixs, got_w = jax.jit(
+            retrieval._unpack, static_argnames="layout"
+        )(jnp.asarray(packed), layout=layout, rules=retrieval._resident(rules))
+        assert got_r.avail.shape == (7,) and len(got_r.cats) == 1
+        assert got_v.dtype == jnp.float32 and got_w.dtype == jnp.float32
+        assert got_r.has_cat.dtype == jnp.bool_
+        for got, want in (
+            (_bits(got_v), _bits(vectors)), (got_r.qcat, qcat),
+            (got_r.has_cat, has_cat), (got_r.ex, ex),
+        ):
+            got = np.asarray(got)
+            np.testing.assert_array_equal(got[:b], want)
+            np.testing.assert_array_equal(got[b:], np.repeat(want[:1], bp - b, 0))
+        assert got_ixs.shape == got_w.shape == (bp, rows)
+        if rows:
+            np.testing.assert_array_equal(np.asarray(got_ixs)[:b], ixs)
+            np.testing.assert_array_equal(_bits(np.asarray(got_w))[:b], _bits(weights))
+
+    def test_parts_of_different_lengths_make_no_batch(self):
+        vectors, qcat, has_cat, ex, _, _ = _packed_case(4, 1, 16, 0, 8)
+        with pytest.raises(ValueError, match="do not make one batch"):
+            retrieval.pack(vectors[:3], Rules(None, (), qcat, has_cat, ex))
+
+    I, D, K = 500, 8, 8  # 4 tiles of 128; k' = 64
+
+    @pytest.fixture()
+    def chain(self, monkeypatch):
+        import jax.numpy as jnp
+
+        monkeypatch.setenv("PIO_RETRIEVAL_THRESHOLD", "64")
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "128")
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "0")
+        host = _dense(self.I, self.D, seed=91)
+        host /= np.linalg.norm(host, axis=1, keepdims=True)
+        table = jnp.asarray(host)
+        return host, table, CoarseCatalog(table)
+
+    def _query(self, form, case, b, host, stored):
+        """(the form for ``top_k``, its ``shortlist`` + host-facing
+        rescore on separate arrays) of ``b`` queries padded to a power
+        of two, as the templates pad."""
+        bp = retrieval._pow2(b)
+        rng = np.random.default_rng(92 + b)
+        small = [3, 110, 257, 258, 499]  # the whole of category 1
+        qcat = np.full((bp, 2), -2, np.int32)
+        ex = np.full((bp, 16), -1, np.int32)
+        if case == "category":
+            qcat[::2, 0], qcat[1::2, :] = 0, (1, 7)
+        elif case == "fewer_than_kp":
+            qcat[:, 0] = 1  # five rows allowed, k' = 64
+        if form == "vectors":
+            v = _dense(b, self.D, seed=93 + b)
+            v = np.concatenate([v, np.repeat(v[:1], bp - b, axis=0)])
+            sums, rows = v, None
+        else:
+            ixs = rng.integers(0, self.I, (bp, 8)).astype(np.int32)
+            weights = (np.arange(8)[None, :] < rng.integers(1, 9, (bp, 1)))
+            weights = weights.astype(np.float32)
+            ixs[b:], weights[b:] = ixs[0], weights[0]
+            ex[:, :8] = np.where(weights > 0, ixs, -1)  # a query's own rows
+            sums, rows = (host[ixs] * weights[..., None]).sum(axis=1), (ixs, weights)
+        if case == "blacklist":
+            best = np.argsort(-(sums @ host.T), axis=1)[:, :5]
+            ex[:, 8:13] = best  # the query's own best, ruled out
+        rules = _host(_rules(stored, bp, small_cat=small, ex=ex, qcat=qcat))
+        rules = rules._replace(has_cat=qcat[:, 0] >= 0)
+        if rows is None:
+            return retrieval.Vectors(sums, rules), (
+                lambda dev, cand: retrieval.rescore_top_k_batch(
+                    sums, self.table, cand, self.K, dev))
+        return retrieval.SumRows(*rows, lambda i, w: sums, rules), (
+            lambda dev, cand: retrieval.rescore_sum_rows_top_k_batch(
+                *rows, self.table, cand, self.K, dev))
+
+    @pytest.mark.parametrize("b", [1, 2, 5, 11])
+    @pytest.mark.parametrize(
+        "case", ["plain", "category", "blacklist", "fewer_than_kp"]
+    )
+    @pytest.mark.parametrize("form", ["vectors", "sum_rows"])
+    def test_the_packed_chain_answers_as_the_separate_arrays(
+        self, chain, form, case, b
+    ):
+        host, self.table, coarse = chain
+        query, rescore = self._query(form, case, b, host, coarse.stored_rows)
+        dev = retrieval.device_rules(query.rules)
+        kp = retrieval.two_stage_k(self.K, self.I)
+        cs, cand = coarse.shortlist(query.coarse_vectors(), kp, dev)
+        want_s, want_ids = rescore(dev, cand)
+        before = retrieval.stats_block()
+        s, ids = retrieval.top_k(query, self.table, self.I, coarse, self.K)
+        after = retrieval.stats_block()
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(_bits(s), _bits(want_s))
+        assert after["uploads"] == before["uploads"] + 1
+        assert after["host_reads"] == before["host_reads"] + 1
+        if case == "fewer_than_kp":
+            assert (np.sort(ids[:, :5], axis=1) == [3, 110, 257, 258, 499]).all()
+            assert (ids[:, 5:] == -1).all() and (cand[:, 5:] == -1).all()
+        elif case == "blacklist":
+            assert not (ids[:, :, None] == query.rules.ex[:, None, :]).any()
+        else:  # a category case's odd rows are held to the small one
+            assert (ids[:: 2 if case == "category" else 1] >= 0).all()
+
+    @pytest.mark.parametrize("form,uploads", [
+        ("user_rows", 2), ("vectors", 1), ("vectors_rules", 1), ("sum_rows", 1),
+    ])
+    def test_uploads_a_dispatch(self, chain, form, uploads):
+        """A form without rules goes up as it always did — its vectors,
+        then (``UserRows``) its indices behind the running scan — and a
+        form under rules in one buffer; the reference path on separate
+        arrays counts each of its own."""
+        host, table, coarse = chain
+        one = TestOneCrossing()
+        query, host_rescore = one._form(form, 3, table, host, coarse.stored_rows)
+        before = retrieval.stats_block()["uploads"]
+        retrieval.top_k(query, table, self.I, coarse, self.K)
+        assert retrieval.stats_block()["uploads"] == before + uploads
+        if query.rules is not None:
+            dev = retrieval.device_rules(query.rules)
+            assert retrieval.stats_block()["uploads"] == before + uploads + 3
+            coarse.launch(query.coarse_vectors(), 64, dev)
+            assert retrieval.stats_block()["uploads"] == before + uploads + 4
+
+    def test_the_masked_programs_keep_their_names_packed(self, chain):
+        """The benchmark's roofline readers find the packed programs by
+        the names the separate ones had."""
+        import jax.numpy as jnp
+
+        host, table, coarse = chain
+        vectors, qcat, has_cat, ex, ixs, weights = _packed_case(2, 1, 16, 8, self.D)
+        rules = _host(_rules(coarse.stored_rows, 2))
+        packed, layout = retrieval.pack(vectors, rules, ixs % self.I, weights)
+        resident = retrieval._resident(rules)
+        text = retrieval._coarse_topk_masked.lower(
+            jnp.asarray(packed), coarse._tiles, None, coarse._ids, resident,
+            k=64, mode="bf16", layout=layout,
+        ).as_text()
+        assert "module @jit__coarse_topk_masked " in text
+        cand = jnp.zeros((2, 64), jnp.int32)
+        for name, args in (
+            ("_rescore_vectors_masked", (jnp.asarray(packed), table)),
+            ("_rescore_sum_rows_masked", (jnp.asarray(packed), None, table)),
+        ):
+            text = getattr(retrieval, name).lower(
+                *args, cand, resident, k=self.K, layout=layout
+            ).as_text()
+            assert f"module @jit_{name} " in text
 
 
 def test_the_templates_leave_the_decision_to_the_chain():

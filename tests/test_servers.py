@@ -1378,6 +1378,35 @@ class TestQueryCacheServing:
         assert set(block) == {"deferred", "two_level", "plain"}
         assert block == retrieval.stats_block()["tile_select"]
 
+    def test_stats_route_carries_the_upload_counter(self, deployed_engine):
+        """``retrieval.uploads`` beside ``retrieval.host_reads``: the
+        host-to-device transfers of the serving chain, as ``/metrics``
+        has them under ``pio_retrieval_uploads_total``."""
+        import numpy as np
+
+        from predictionio_tpu.obs import metrics as obs_metrics
+        from predictionio_tpu.ops import retrieval
+
+        base = deployed_engine["base"]
+
+        def read():
+            status, body = http("GET", base + "/stats.json")
+            assert status == 200
+            with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
+                scraped = obs_metrics.parse_prometheus(r.read().decode())
+            block = body["retrieval"]
+            assert scraped["pio_retrieval_uploads_total"] == block["uploads"]
+            assert scraped["pio_retrieval_host_reads_total"] == block["host_reads"]
+            return block["uploads"]
+
+        rng = np.random.default_rng(8)
+        cat = retrieval.CoarseCatalog(
+            rng.normal(size=(300, 8)).astype(np.float32), tile=128
+        )
+        before = read()
+        cat.shortlist(rng.normal(size=(3, 8)).astype(np.float32), 16)
+        assert read() == before + 1
+
     @pytest.mark.parametrize("batch,path", [
         (1, "deferred"), (2, "deferred"), (4, "two_level"),
     ])

@@ -209,6 +209,8 @@ class TestShardedChain:
         _served(cat, U, range(3), len(V), 16)
         after = retrieval.stats_block()
         assert after["host_reads"] - before["host_reads"] == 1
+        # the replicated vectors and nothing else: the chain without rules
+        assert after["uploads"] - before["uploads"] == 1
         assert after["sharded_queries"] - before["sharded_queries"] == 3
         assert after["two_stage_queries"] == before["two_stage_queries"]
         # 4 shards x 4 padded rows x k 16 x 8 B

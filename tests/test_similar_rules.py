@@ -337,6 +337,55 @@ class TestOneRegime:
         assert snap()["pio_similar_query_rows_sum"] == rows + 6
 
 
+def test_similar_build_makes_no_upload(world, two_stage, region_uploads):
+    """The build leaves summed-row indices, weights and rules on the
+    host; ``top_k`` sends them up with the vectors, once a dispatch."""
+    # a model's first query stages its coarse copy and resident vectors
+    world.algo.predict(world.model, world.query("similar", 0))
+    world.user_algo.predict(world.users, ru.Query(users=["i1"], num=4))
+    builds = region_uploads("similar.build")
+    before = retrieval.stats_block()
+    world.algo.batch_predict(world.model, [
+        (0, world.query("similar", 1)), (1, world.query("same_category", 2)),
+        (2, world.query("session", 3))])
+    world.user_algo.predict(world.users, ru.Query(users=["i3", "i9"], num=4))
+    after = retrieval.stats_block()
+    assert builds == [(0, [])] * 2
+    dispatches = after["shortlist_seconds"]["count"] - before["shortlist_seconds"]["count"]
+    probes = after["probes"] - before["probes"]  # each: device_rules' three
+    assert dispatches == 2
+    assert after["uploads"] - before["uploads"] == dispatches + 3 * probes
+
+
+def test_no_layout_is_first_compiled_after_the_warm_up(two_stage):
+    """The item-page cell warms by closed-loop traffic of its mix
+    (benchmark/drivers/serve.py ``_phases``): singles up to the recall
+    probe's turn, then bursts that fill every batch bucket. A packed
+    layout is a shape like any other — the bucket and widths that no
+    kind of the cell's queries moves (one category an item, a session's
+    2-8 items in the bucket of 8, its own rows and blackList in the
+    bucket of 16) — so a warm-up that met each bucket with the mix's
+    most frequent kind alone has compiled what every kind runs."""
+    from predictionio_tpu.obs import device as obs_device
+
+    def compiles():
+        return {f: s["compiles"] for f, s in obs_device.compile_snapshot().items()}
+
+    world = World("float32", 1)
+
+    def batch(kind, b):
+        return [(n, world.query(kind, n)) for n in range(b)]
+
+    for b in (1, 1, 2, 4, 8):  # the probe every second dispatch (two_stage)
+        world.algo.batch_predict(world.model, batch("similar", b))
+    before = compiles()
+    for kind in ("similar", "same_category", "session"):
+        for b in (1, 2, 4, 8):
+            out = world.algo.batch_predict(world.model, batch(kind, b))
+            assert all(len(r.itemScores) > 0 for _, r in out)
+    assert compiles() == before
+
+
 class TestExactProgramIsF32:
     def test_the_masked_sum_rows_program_asks_for_highest(self, world):
         """On a TPU a default-precision f32 product is bf16 passes; the

@@ -88,3 +88,44 @@ def test_a_batch_beyond_the_bound_stores_nothing(one_chip):
     compiled = _compiled(one_chip, 64, 36, 64)
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
     assert "f32[36," not in compiled.as_text()  # no scores stacked by tile
+
+
+@pytest.mark.parametrize("b", [1, 8])
+def test_the_packed_masked_scan_keeps_its_loop(one_chip, b):
+    """Both Taobao cells' masked scan (16 tiles, rank 128) from ONE
+    packed buffer against the same program from separate arrays: the
+    unpacking is a preamble — the compiled loop body holds the ops it
+    held, none more — and the temporaries stay within 1 MB."""
+    from collections import Counter
+
+    from predictionio_tpu.ops.topk import Rules
+
+    nt, d, e = 16, 128, 128  # a storefront query's seen list: 65-128 rows
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    resident = Rules(
+        arg((nt * TILE,), jnp.uint8), (arg((nt * TILE,), jnp.int32),),
+        None, None, None,
+    )
+    separate = resident._replace(
+        qcat=arg((b, 1), jnp.int32), has_cat=arg((b,), jnp.bool_),
+        ex=arg((b, e), jnp.int32),
+    )
+    layout = retrieval.Layout(d, 1, e)
+    catalog = (arg((nt, TILE, d), jnp.bfloat16), None, arg((nt, TILE), jnp.int32))
+    was = retrieval._coarse_topk_masked.lower(
+        arg((b, d), jnp.float32), *catalog, separate, k=KP, mode="bf16",
+    ).compile()
+    packed = retrieval._coarse_topk_masked.lower(
+        arg((b, sum(hi - lo for lo, hi in layout.bounds())), jnp.int32),
+        *catalog, resident, k=KP, mode="bf16", layout=layout,
+    ).compile()
+    held = Counter(op for _, op in _loop_body(was.as_text()))
+    holds = Counter(op for _, op in _loop_body(packed.as_text()))
+    assert holds and not holds - held, holds - held
+    assert abs(
+        packed.memory_analysis().temp_size_in_bytes
+        - was.memory_analysis().temp_size_in_bytes
+    ) <= 1 << 20
