@@ -36,7 +36,10 @@ reference of the business rules, then a live `$set unavailableItems` and a
 Similar Product template on the same app and serves its three query kinds
 (similar, same category, session with a blackList) over the same threshold,
 held to the f32 cosines of the persisted model and to the masked sum-rows
-rescore having been staged with no temporary bytes.
+rescore having been staged with no temporary bytes. Before both, the int8
+leg trains the same events with ``storage_dtype="int8"`` and serves that
+model over the threshold: the int8 coarse scan and the dequantizing rescore,
+held to the f32 product of the persisted model's dequantized rows.
 
 Last stdout line on success:
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
@@ -46,6 +49,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import copy
 import errno
 import http.client
 import importlib.metadata
@@ -377,6 +381,10 @@ class Reference:
         fields = modelfile.load_path(path).fields(0)
         self.U = np.asarray(fields["user_factors"], np.float32)
         self.V = np.asarray(fields["item_factors"], np.float32)
+        self.quantized = fields["item_factors"].dtype == np.int8
+        if self.quantized:  # an int8 model IS its dequantized rows
+            self.U *= np.asarray(fields["user_scales"], np.float32)[:, None]
+            self.V *= np.asarray(fields["item_scales"], np.float32)[:, None]
         if self.U.shape != (num_users, RANK) or self.V.shape != (num_items, RANK):
             raise SmokeFailure(
                 f"model {instance}: factors {self.U.shape} x {self.V.shape}, "
@@ -547,6 +555,8 @@ def serve_and_check(smoke: Smoke, name: str, variant_file: str, ref: Reference,
             "sharded_queries": stats["retrieval"].get("sharded_queries", 0),
             "shards": stats["retrieval"].get("shards", 0),
             "rescore_temp_bytes": temp,
+            "coarse_mode": stats["retrieval"].get("coarse_mode"),
+            "resident_bytes": stats["retrieval"].get("resident_bytes"),
         }
         stop_server(proc, port, name)
     finally:
@@ -916,6 +926,8 @@ def run(smoke: Smoke, args) -> dict:
             f"programs {staged['rescore_temp_bytes']}: the leg did not engage"
         )
 
+    int8_leg(smoke, users[:TWO_STAGE_QUERIES], (s_rows, s_cols, s_vals),
+             mean_rmse, num_users, num_items)
     storefront_leg(smoke, args.seed, 10 if args.dry_run_cpu else 1)
     similar_leg(smoke, args.seed, 10 if args.dry_run_cpu else 1)
     if device["count"] >= 4:
@@ -927,13 +939,46 @@ def run(smoke: Smoke, args) -> dict:
     return device
 
 
+def int8_leg(smoke: Smoke, users: list[int], sample, mean_rmse: float,
+             num_users: int, num_items: int) -> None:
+    """The same events trained with ``storage_dtype="int8"`` and served over
+    the two-stage threshold: a TRAINED (not only a written) int8 model on the
+    normal path — quantized by the program's own ``quantize_rows``, persisted
+    as values + row scales, staged as stored, scanned int8, rescored
+    dequantized — held to the f32 product of the dequantized rows of the
+    persisted model."""
+    variant = copy.deepcopy(VARIANT)
+    variant["id"] = "chip-smoke-int8"
+    variant["algorithms"][0]["params"]["storage_dtype"] = "int8"
+    with open(os.path.join(smoke.dir, "engine_int8.json"), "w") as fh:
+        json.dump(variant, fh)
+    trained = train(smoke, "train_int8", "--variant", "engine_int8.json")
+    if trained["platform"] != smoke.platform:
+        raise SmokeFailure(f"train_int8: the trainer reports {trained}")
+    ref = Reference(smoke, trained["instance"], num_users, num_items)
+    rmse = ref.rmse(*sample)
+    print(f"int8 leg: rmse {rmse:.4f} (global-mean predictor {mean_rmse:.4f})",
+          flush=True)
+    if not ref.quantized or not np.isfinite(rmse) or rmse >= mean_rmse:
+        raise SmokeFailure(
+            f"train_int8: quantized {ref.quantized}, rmse {rmse} against the "
+            f"global mean's {mean_rmse}")
+    served = serve_and_check(
+        smoke, "deploy_int8", "engine_int8.json", ref, users,
+        PIO_RETRIEVAL_THRESHOLD=str(TWO_STAGE_THRESHOLD),
+    )
+    if served["two_stage_queries"] < len(users):
+        raise SmokeFailure(f"deploy_int8: {served['two_stage_queries']} "
+                           "two-stage queries: the leg did not engage")
+
+
 def sharded_leg(smoke: Smoke, ref: Reference, rmse: float, dense: dict,
                 users: list[int], sample, num_users: int, num_items: int) -> None:
     """Four devices: the same events through `--mesh data=4` training and
     sharded serving (item rows stationary on the four devices: the exact
     program, then two-stage retrieval over the shards with the threshold
     under the catalog), held to the one-chip run."""
-    variant = json.loads(json.dumps(VARIANT))
+    variant = copy.deepcopy(VARIANT)
     variant["id"] = "chip-smoke-sharded"
     variant["algorithms"][0]["params"].update(
         sharded_train=True, sharded_serving=True

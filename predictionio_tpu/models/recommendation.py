@@ -293,19 +293,32 @@ class ALSModel:
 
     def device_factors(self):
         """(U_dev, V_dev) cached on current default device; quantized
-        tables stay (values, scales) pairs on device."""
+        tables stay (values, scales) pairs on device. A table goes up
+        as it is stored — int8 values stay int8 on the host and on the
+        chip — and one that spans model files a part at a time
+        (``retrieval.put_rows``): never one host array."""
         if self._device is None:
+            import jax
             import jax.numpy as jnp
 
-            def put(values, scales):
-                if scales is not None:
-                    return (jnp.asarray(values), jnp.asarray(scales))
-                return jnp.asarray(values)
+            from predictionio_tpu.obs import trace as obs_trace
+            from predictionio_tpu.ops import retrieval
 
-            self._device = (
-                put(self.user_factors, self.user_scales),
-                put(self.item_factors, self.item_scales),
-            )
+            def put(values, scales):
+                rows = retrieval.put_rows(values)
+                return rows if scales is None else (rows, jnp.asarray(scales))
+
+            with obs_trace.region(
+                "model.stage_table",
+                hist=retrieval._m_load["stage_to_device"],
+            ):
+                users, items = jax.block_until_ready((
+                    put(self.user_factors, self.user_scales),
+                    put(self.item_factors, self.item_scales),
+                ))
+            values, scales = items if isinstance(items, tuple) else (items, None)
+            retrieval.set_resident(users=users, table=values, table_scales=scales)
+            self._device = (users, items)
         return self._device
 
     def sharded_catalog(self):
@@ -328,9 +341,18 @@ class ALSModel:
         shortlist pass (ops/retrieval.py), cached — only built once a
         catalog crosses ``PIO_RETRIEVAL_THRESHOLD``."""
         if self._coarse is None:
-            from predictionio_tpu.ops.retrieval import CoarseCatalog
+            from predictionio_tpu.obs import trace as obs_trace
+            from predictionio_tpu.ops import retrieval
 
-            self._coarse = CoarseCatalog(self.item_table())
+            # an int8 pair is tiled from the resident copy, on the device
+            table = (
+                self.item_table() if self.item_scales is None
+                else self.device_factors()[1]
+            )
+            with obs_trace.region(
+                "model.coarse_build", hist=retrieval._m_load["coarse_build"]
+            ):
+                self._coarse = retrieval.CoarseCatalog(table)
         return self._coarse
 
     def __getstate__(self):

@@ -163,7 +163,12 @@ class _Stripe:
     __slots__ = ("lock", "counts", "sum", "count")
 
     def __init__(self, n_cells: int = _N_CELLS) -> None:
-        self.lock = threading.Lock()
+        # re-entrant: a collection can start on the thread that holds this
+        # lock in ``merged`` (it allocates), and the collector's hook
+        # (obs/runtime.py ``_on_gc``) observes into a histogram — the one
+        # being merged, on this thread's stripe, would wait for itself
+        # for good (what made ``bench.py --smoke`` hang now and then)
+        self.lock = threading.RLock()
         self.counts = [0] * n_cells
         self.sum = 0.0
         self.count = 0

@@ -15,10 +15,11 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    score as ``(q @ values^T) * scale`` (the per-row scale factors out
    of the within-row dot and multiplies back scalar-per-column);
    ``int8_dot`` additionally quantizes the queries and accumulates in
-   int32 (the MXU-native form — auto-selected on TPU); dense catalogs
-   carry a bf16 coarse copy. On a mesh each device runs this same
-   scan over the rows it holds (parallel/shard_topk.py: stationary
-   shards, the query replicated).
+   int32 (the MXU-native form; never picked by rule — on a v5e it is no
+   faster for a batch and 2.5x slower for one query: PERF.md section 6,
+   PR 41); dense catalogs carry a bf16 coarse copy. On a mesh each
+   device runs this same scan over the rows it holds
+   (parallel/shard_topk.py: stationary shards, the query replicated).
    A scan step never selects over its whole tile where the tile is
    large: it takes the maximum of each group of G scores, the k' best
    groups, and the k' best of those groups' scores — exactly the
@@ -69,9 +70,9 @@ Engagement is catalog-size gated: a catalog under
 fixture — is served by the form's exact op, bit for bit what it was
 before this module existed. The oversampling factor is 8 (recall@num
 >= 0.999 holds with margin); the coarse representation follows the
-table and the platform (int8 catalogs stay int8, ``int8_dot`` on TPU;
-dense catalogs get a bf16 copy). Knobs (read per call, so tests and
-operators can flip them live):
+table (int8 catalogs stay int8 and are scanned in mode ``int8`` on every
+backend; dense catalogs get a bf16 copy). Knobs (read per call, so tests
+and operators can flip them live):
 
 - ``PIO_RETRIEVAL_THRESHOLD``: catalog rows below which serving stays
   exact (default 100000; <= 0 disables two-stage entirely).
@@ -233,10 +234,11 @@ _m_shards = obs_metrics.gauge(
 _m_load = {
     stage: obs_metrics.histogram(
         "pio_model_load_seconds",
-        "staging one shard of a sharded catalog, by stage: read = its rows "
-        "out of the model's segments into one host block; stage_to_device = "
-        "the block's upload to its device; coarse_build = the bf16 tiles "
-        "made of it there",
+        "staging a served catalog (one observation a shard of a sharded "
+        "one), by stage: read = a shard's rows out of the model's segments "
+        "into one host block; stage_to_device = the upload (a shard's "
+        "block; on one chip the user and item tables as stored, a spanned "
+        "one a part at a time); coarse_build = the coarse tiles made",
         stage=stage,
     )
     for stage in ("read", "stage_to_device", "coarse_build")
@@ -307,16 +309,59 @@ _m_score_form = {
     for form in ("dot", "rows")
 }
 
+_m_coarse_mode = {
+    mode: obs_metrics.counter(
+        "pio_retrieval_coarse_mode_total",
+        "shortlist calls by the coarse catalog's storage mode: bf16 = a "
+        "bf16 copy of a dense table; int8 = stored int8 values against the "
+        "f32 query, the row's scale multiplied back; int8_dot = the query "
+        "quantized too, int8 x int8 accumulated in int32",
+        mode=mode,
+    )
+    for mode in ("bf16", "int8", "int8_dot")
+}
+
+# what a served factor model keeps on the device, by part: the exact
+# table's values (and the f32 scales of an int8 pair), the coarse tiles
+# with their scales and row ids, the user table. Set where each is put
+# up (``CoarseCatalog``; a template's ``device_factors``).
+RESIDENT_PARTS = (
+    "table", "table_scales", "coarse", "coarse_scales", "coarse_ids", "users",
+)
+_m_resident = {
+    part: obs_metrics.gauge(
+        "pio_model_resident_bytes",
+        "device bytes the served model keeps resident, by part: table / "
+        "table_scales = the exact item table (int8 values and their f32 "
+        "scales, or the dense rows and 0); coarse / coarse_scales / "
+        "coarse_ids = the tiled coarse catalog; users = the user table",
+        part=part,
+    )
+    for part in RESIDENT_PARTS
+}
+
+
+def set_resident(**parts) -> None:
+    """Publish device-resident bytes by part (``RESIDENT_PARTS``): each
+    value a device array, a tuple of them, or None (0 bytes)."""
+    for part, held in parts.items():
+        arrays = held if isinstance(held, tuple) else (held,)
+        _m_resident[part].set(float(sum(
+            a.size * a.dtype.itemsize for a in arrays if a is not None
+        )))
+
+
 _probe_clock = itertools.count(1)
 
 
 def _count_scan(b: int, nt: int, t: int, k: int, d: int, mode: str) -> None:
     """One shortlist call, by the two rules its program was traced
-    with: where it selects (``scan_select``) and how it scores
-    (``score_form``)."""
+    with — where it selects (``scan_select``) and how it scores
+    (``score_form``) — and by the catalog's coarse mode."""
     _m_shortlist_size.observe(float(k))
     _m_tile_select[scan_select(b, nt, t, k, d, mode)].inc()
     _m_score_form[score_form(b, d, mode)].inc()
+    _m_coarse_mode[mode].inc()
 
 
 def probe_recall(two_stage_ids, exact_ids) -> float:
@@ -359,6 +404,8 @@ def stats_block() -> dict:
         "uploads": _m_uploads.value(),
         "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
         "score_form": {f: m.value() for f, m in _m_score_form.items()},
+        "coarse_mode": {c: m.value() for c, m in _m_coarse_mode.items()},
+        "resident_bytes": {p: m.value() for p, m in _m_resident.items()},
         "rescore_temp_bytes": {
             p.name: p.temp_bytes() for p in _RESCORE_PROGRAMS
             if p._cache_size()
@@ -612,10 +659,11 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
             hit,
         )
     if mode == "int8_dot":
-        qs = jnp.max(jnp.abs(q), axis=1, keepdims=True) / 127.0
-        qi = jnp.clip(
-            jnp.round(q / jnp.maximum(qs, 1e-12)), -127, 127
-        ).astype(jnp.int8)
+        with jax.named_scope("retrieval.shortlist.quantize_query"):
+            qs = jnp.max(jnp.abs(q), axis=1, keepdims=True) / 127.0
+            qi = jnp.clip(
+                jnp.round(q / jnp.maximum(qs, 1e-12)), -127, 127
+            ).astype(jnp.int8)
     elif dot:
         q3 = _split_bf16(q)
 
@@ -852,16 +900,42 @@ class Scan(NamedTuple):
     layout: Layout | None = None  # of ``queries``, where they are packed
 
 
+def put_rows(values):
+    """A [rows, ...] factor array as ONE device array: what is there
+    already as it lies, a host array as ``jnp.asarray`` puts it, and the
+    ``SpannedArray`` of a model that spans files a part at a time,
+    joined on the device — the table is never one host array."""
+    parts = getattr(values, "parts", None)
+    if parts is None or len(parts) == 1:
+        return jnp.asarray(values if parts is None else parts[0])
+    return jnp.concatenate([jnp.asarray(p) for p in parts])
+
+
+@functools.partial(jax.jit, static_argnames=("nt", "t"))
+def _quantized_tiles(values, scales, nt: int, t: int):
+    """The coarse form of a resident int8 pair, made where it lies: the
+    [I, D] values and [I] scales padded to ``nt`` whole tiles (zero
+    rows of scale 1; their ids say -1) -> ([nt, t, D], [nt, t])."""
+    pad = nt * t - values.shape[0]
+    return (
+        jnp.pad(values, ((0, pad), (0, 0))).reshape(nt, t, values.shape[1]),
+        jnp.pad(scales, (0, pad), constant_values=1.0).reshape(nt, t),
+    )
+
+
 class CoarseCatalog:
     """A catalog staged in tiled coarse form for the shortlist pass.
 
     Built once per (model, weights) from the serving factor table —
-    dense [I, D] f32/bf16 or the int8 (values, scales) pair — and cached
+    dense [I, D] f32/bf16 or the int8 (values, scales) pair, on the host
+    or (the pair) resident on the device already — and cached
     by the templates next to their device tables. int8 catalogs keep
     their existing quantized values (no re-quantization error on top of
-    storage); dense catalogs get an int8 or bf16 coarse COPY whose
-    quantization error only ever costs shortlist coverage, never final
-    score accuracy (the rescore reads the original table).
+    storage; the pair goes up once and is tiled on the device,
+    ``_quantized_tiles``: no padded or dequantized host copy); dense
+    catalogs get an int8 or bf16 coarse COPY whose quantization error
+    only ever costs shortlist coverage, never final score accuracy (the
+    rescore reads the original table).
 
     Tiles are [NT, T, D] with row ids [NT, T] (-1 marks padding past the
     catalog), so one scan step's working set is a T-row slab regardless
@@ -875,12 +949,13 @@ class CoarseCatalog:
         self.num_rows = int(vals.shape[0])
         self.dim = int(vals.shape[1])
         if mode is None:
-            if quantized:
-                mode = (
-                    "int8_dot" if jax.default_backend() == "tpu" else "int8"
-                )
-            else:
-                mode = "bf16"
+            # an int8 pair is scanned as stored, against the f32 query: on
+            # a TPU v5e a single's scan of 184 tiles is 9.2 ms so (its
+            # three-row dot reads a tile in 23 us) and 22.6 ms with the
+            # query quantized too ("int8_dot": a one-row s8 product has no
+            # dot form and costs 96 us a tile), and from two queries on
+            # the two are one speed within 2 % (PERF.md section 6, PR 41)
+            mode = "int8" if quantized else "bf16"
         if mode not in ("int8", "int8_dot", "bf16"):
             raise ValueError(f"unknown coarse mode {mode!r}")
         self.mode = mode
@@ -901,25 +976,23 @@ class CoarseCatalog:
             )
             self._scales = None
         else:
-            if quantized:
-                vq = np.asarray(item_table[0], dtype=np.int8)
-                vs = np.asarray(item_table[1], dtype=np.float32)
+            if quantized:  # the stored values are the tiles: as they lie
+                vq, vs = item_table
             else:
                 f = np.asarray(item_table, dtype=np.float32)
                 s = np.max(np.abs(f), axis=1) / 127.0
-                s = np.where(s > 0, s, 1.0).astype(np.float32)
-                vq = np.rint(f / s[:, None]).astype(np.int8)
-                vs = s
-            if pad:
-                vq = np.concatenate([vq, np.zeros((pad, self.dim), np.int8)])
-                vs = np.concatenate([vs, np.ones(pad, np.float32)])
-            self._tiles = jnp.asarray(vq.reshape(nt, T, self.dim))
-            self._scales = jnp.asarray(vs.reshape(nt, T))
+                vs = np.where(s > 0, s, 1.0).astype(np.float32)
+                vq = np.rint(f / vs[:, None]).astype(np.int8)
+            self._tiles, self._scales = _quantized_tiles(
+                put_rows(vq), jnp.asarray(vs), nt, T
+            )
         ids = np.concatenate(
             [np.arange(self.num_rows, dtype=np.int32),
              np.full(pad, -1, np.int32)]
         )
         self._ids = jnp.asarray(ids.reshape(nt, T))
+        set_resident(coarse=self._tiles, coarse_scales=self._scales,
+                     coarse_ids=self._ids)
 
     def nbytes(self) -> int:
         """Device-resident coarse bytes (tiles + scales + ids)."""
@@ -1018,7 +1091,8 @@ def _table_rows(table, ixs):
     or the int8 ``(values, scales)`` pair."""
     if isinstance(table, tuple):
         values, scales = table
-        return _gather_rows(values, ixs) * scales[ixs][..., None]
+        with jax.named_scope("retrieval.rescore.dequant"):
+            return _gather_rows(values, ixs) * scales[ixs][..., None]
     return _gather_rows(table, ixs)
 
 

@@ -49,32 +49,45 @@ from xplane import newest_xplane, op_label  # noqa: E402
 
 TILE, KP = 1 << 18, 128  # the cells' tile and k' (num 10 -> 8 x 16)
 
-# what ONE chip scans in each of the benchmark's four configurations
+# what ONE chip scans in each of the benchmark's five configurations
 SHAPES = {
     "retrieval-yambda": dict(rows=9_390_000, rank=64, rules=False),
     "ecommerce-taobao": dict(rows=4_162_024, rank=128, rules=True),
     "similarproduct-taobao": dict(rows=4_162_024, rank=128, rules=True),
     "recommendation-amazon23": dict(rows=12_047_500, rank=64, rules=False),
+    # the whole catalog stored int8 on ONE chip: both int8 coarse modes
+    "recommendation-amazon23-int8": dict(rows=48_190_000, rank=64, rules=False,
+                                         modes=("int8", "int8_dot")),
 }
 BODIES = ("two_level", "deferred")
 
 
-def _scan(k, select):
-    def run(q, tiles, ids, rules=None):  # the trace names it jit_run
-        return retrieval._coarse_scan(
-            q, tiles, None, ids, k, "bf16", rules, select=select
-        )
+def _scan(k, select, mode="bf16"):
+    if mode == "bf16":
+        def run(q, tiles, ids, rules=None):  # the trace names it jit_run
+            return retrieval._coarse_scan(
+                q, tiles, None, ids, k, mode, rules, select=select
+            )
+    else:
+        def run(q, tiles, scales, ids):
+            return retrieval._coarse_scan(
+                q, tiles, scales, ids, k, mode, select=select
+            )
     return jax.jit(run)
 
 
-def _arguments(shape, b, make):
-    """(q, tiles, ids[, rules]) through ``make(shape, dtype, fill)``."""
+def _arguments(shape, b, make, mode="bf16"):
+    """(q, tiles, ids[, rules]) through ``make(shape, dtype, fill)``; in an
+    int8 mode (q, int8 tiles, f32 row scales, ids)."""
     nt = -(-shape["rows"] // TILE)
     args = [
         make((b, shape["rank"]), jnp.float32, "normal"),
         make((nt, TILE, shape["rank"]), jnp.bfloat16, "normal"),
         make((nt, TILE), jnp.int32, ("ids", shape["rows"])),
     ]
+    if mode != "bf16":
+        args[1:2] = [make((nt, TILE, shape["rank"]), jnp.int8, "int8"),
+                     make((nt, TILE), jnp.float32, "scales")]
     if shape["rules"]:
         args.append(Rules(
             avail=make((nt * TILE,), jnp.uint8, "avail"),
@@ -92,6 +105,14 @@ def _device_array(shape, dtype, fill):
     if fill == "normal":
         return jax.jit(
             lambda k: jax.random.normal(k, shape, jnp.float32).astype(dtype)
+        )(key)
+    if fill == "int8":  # stored values: whole numbers in [-127, 127]
+        return jax.jit(
+            lambda k: jax.random.randint(k, shape, -127, 128, jnp.int8)
+        )(key)
+    if fill == "scales":  # a Gaussian row's largest value over 127, about
+        return jax.jit(
+            lambda k: jax.random.uniform(k, shape, jnp.float32, 0.006, 0.012)
         )(key)
     if isinstance(fill, tuple):  # row ids, -1 past the catalog
         ids = jnp.arange(n, dtype=jnp.int32)
@@ -194,20 +215,22 @@ def main(argv=None) -> int:
     for name in a.shapes.split(","):
         shape = SHAPES[name]
         nt = -(-shape["rows"] // TILE)
-        key = (nt, shape["rank"], shape["rules"])
+        key = (nt, shape["rank"], shape["rules"], shape.get("modes"))
         if key in done:  # the two Taobao configurations scan one shape
             continue
         done.add(key)
-        for b in (int(x) for x in a.batches.split(",")):
-            args = _arguments(shape, b, make)
+        for mode, b in ((m, int(x)) for m in shape.get("modes", ("bf16",))
+                        for x in a.batches.split(",")):
+            args = _arguments(shape, b, make, mode)
             row = {
                 "shape": name, "tiles": nt, "rank": shape["rank"],
-                "rules": shape["rules"], "b": b,
-                "served": retrieval.scan_select(b, nt, TILE, KP, shape["rank"]),
+                "rules": shape["rules"], "mode": mode, "b": b,
+                "served": retrieval.scan_select(b, nt, TILE, KP, shape["rank"], mode),
+                "score_form": retrieval.score_form(b, shape["rank"], mode),
             }
             outs = {}
             for body in BODIES:
-                fn = _scan(KP, body).lower(*args).compile()
+                fn = _scan(KP, body, mode).lower(*args).compile()
                 mem = fn.memory_analysis()
                 row[body] = {"temp_mb": mem.temp_size_in_bytes / 1e6}
                 if not a.compile_only:

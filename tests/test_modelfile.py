@@ -372,6 +372,28 @@ class TestSpanningModel:
         np.testing.assert_array_equal(got.user_factors, m.user_factors)
         assert got.user_index == m.user_index and len(got.item_index) == 50_000
 
+    def test_an_int8_pair_spans_as_values_in_parts_and_scales_whole(self, tmp_path):
+        """A quarter of the f32 model: int8 values cut row-wise over the
+        segments, one f32 scale a row in a block of its own — both loaded
+        as stored, no dequantized copy anywhere."""
+        m = _als("int8", n_users=300, n_items=100_000, rank=32)  # values 3.2 MB
+        (tmp_path / "f32").mkdir()
+        f32 = _span(tmp_path / "f32", _als(n_users=300, n_items=100_000, rank=32))[1]
+        head, info = _span(tmp_path, m)
+        assert info["bytes"] < f32["bytes"] / 2
+        got = modelfile.load_path(head).entries()[0][1]
+        assert isinstance(got.item_factors, modelfile.SpannedArray)
+        assert got.item_factors.dtype == np.int8 and len(got.item_factors.parts) == 4
+        assert all(p.dtype == np.int8 and p.nbytes <= MIB for p in got.item_factors.parts)
+        assert isinstance(got.item_scales, np.ndarray) and got.item_scales.dtype == np.float32
+        assert got.user_factors.dtype == np.int8 and got.user_scales.shape == (300,)
+        np.testing.assert_array_equal(np.asarray(got.item_factors), m.item_factors)
+        np.testing.assert_array_equal(got.item_scales, m.item_scales)
+        ix = np.array([0, 99_999, 32_768, 32_767])
+        np.testing.assert_array_equal(got.item_factors[ix], m.item_factors[ix])
+        np.testing.assert_array_equal(got.user_rows(ix[:1]), m.user_rows(ix[:1]))
+        assert got.item_table()[0] is got.item_factors  # the scorer's pair, as stored
+
     def test_spanned_array_reads_rows_where_they_lie(self, tmp_path):
         m = _als(n_users=4, n_items=50_000, rank=16)
         V = modelfile.load_path(_span(tmp_path, m)[0]).fields(0)["item_factors"]

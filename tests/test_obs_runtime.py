@@ -40,6 +40,7 @@ with open(os.path.join(ROOT, "BENCHMARK.json")) as _fh:
 STEADY = "retrieval-yambda.serve-steady"
 ITEMPAGE = "similarproduct-taobao.serve-itempage"
 SATURATED = "retrieval-yambda.serve-saturated"
+INT8 = "recommendation-amazon23-int8.serve-onechip-steady"  # PR 41 joined two lists
 
 
 @pytest.fixture()
@@ -492,8 +493,8 @@ LAYER = {
     "gc_pause_ms_sum": "dispatch", "proc_stall_ms_max": "HTTP and batcher",
 }
 CELLS = {
-    "worker_busy_share": [STEADY, ITEMPAGE], "worker_turnaround_ms": [STEADY],
-    "enqueue_offcpu_ms": [STEADY, ITEMPAGE], "dispatch_cpu_ms": [STEADY],
+    "worker_busy_share": [STEADY, ITEMPAGE, INT8], "worker_turnaround_ms": [STEADY],
+    "enqueue_offcpu_ms": [STEADY, ITEMPAGE], "dispatch_cpu_ms": [STEADY, INT8],
     "gc_pause_ms_sum": [STEADY, ITEMPAGE], "proc_stall_ms_max": [STEADY, ITEMPAGE],
 }
 NAMES = [n + sfx for sfx in ("", ".saturated") for n in EXPECT]
@@ -546,3 +547,17 @@ def test_the_manifest_is_clean_and_each_cell_lists_its_own():
         assert traced & set(NAMES) == want, cell
         assert not set(NAMES) & {d["name"] for d in bench_run.metrics_for(MANIFEST, cell, False)}
     assert len([n for n in NAMES if n.endswith(".saturated")]) == 6
+
+
+def test_a_collection_inside_a_read_of_its_own_histogram_does_not_deadlock():
+    """``Histogram.merged`` allocates while it holds a stripe's lock; a
+    collection that starts there runs the ``gc.callbacks`` hook on the same
+    thread, which observes into a histogram — with a plain lock and the
+    histogram being read, that thread waited for itself (the flicker of
+    ``test_bench_smoke.py``). The stripe's lock is re-entrant."""
+    h = metrics.Histogram("t_gc_reentrant_seconds", "test")
+    h.observe(0.001)  # this thread's stripe
+    stripe = h._stripes[metrics._tls.stripe]
+    with stripe.lock:  # as merged() holds it
+        h.observe(0.002)  # as _on_gc would, on the same thread
+    assert h.merged()[2] == 2

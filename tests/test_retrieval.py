@@ -604,6 +604,26 @@ class TestSubThresholdParity:
         block = retrieval.stats_block()
         assert {"threshold", "oversample", "two_stage_queries",
                 "exact_queries", "shortlist_size", "probe_recall"} <= set(block)
+        assert set(block["coarse_mode"]) == {"bf16", "int8", "int8_dot"}
+        assert tuple(block["resident_bytes"]) == retrieval.RESIDENT_PARTS
+
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_a_shortlist_call_counts_its_coarse_mode(self, mode):
+        v, s = _int8(2048, 16, seed=31)
+        cat = retrieval.CoarseCatalog((v, s), tile=512, mode=mode)
+        before = dict(retrieval.stats_block()["coarse_mode"])
+        cat.shortlist(_dense(3, 16, seed=32), 8)
+        after = retrieval.stats_block()["coarse_mode"]
+        assert {m: after[m] - before[m] for m in after} == {
+            m: float(m == mode) for m in after}
+
+    @pytest.mark.parametrize("mode", ["bf16", "int8"])
+    def test_a_catalog_publishes_its_resident_bytes(self, mode):
+        cat = retrieval.CoarseCatalog(_int8(1000, 16, seed=33), tile=512, mode=mode)
+        got = retrieval.stats_block()["resident_bytes"]
+        assert got["coarse"] + got["coarse_scales"] + got["coarse_ids"] == cat.nbytes()
+        assert got["coarse"] == 1024 * 16 * (2 if mode == "bf16" else 1)
+        assert got["coarse_scales"] == (0 if mode == "bf16" else 1024 * 4)
 
 
 class TestStageSpans:
