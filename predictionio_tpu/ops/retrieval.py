@@ -42,6 +42,11 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    the three partial scores added back ("dot": the same f32 product to
    summation order, through the form of it that the chip reads at the
    memory's speed). Everything after the score is one row either way.
+   Beside the tiles lie one value a row in two side arrays, the row ids
+   (-1: padding, guarded to a score that cannot win) and an int8 pair's
+   row scales, stored [NT, T/128, 128] (``side_shape``) so that the one
+   tile's worth a step reads of each is a dense block of the chip's
+   memory and not a sublane of every memory tile of an [NT, T] array.
 2. **Exact rescore** — gather the [B, S] shortlisted rows and rescore
    them in f32 through shortlist-gather variants of the fused ops
    (``rescore_*_top_k_batch`` below). The rescore builds its query
@@ -182,6 +187,25 @@ def engaged(num_rows: int) -> bool:
 
 def _pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
+
+
+def side_shape(nt: int, t: int) -> tuple[int, int, int]:
+    """The shape in which ``nt`` tiles of ``t`` rows store a per-row
+    side array (the row ids; an int8 pair's row scales): [nt, t/L, L],
+    L the 128 lanes wherever they divide the tile (every catalog at
+    retrieval scale: a tile is a power of two) and the whole tile
+    otherwise (the small tiles of the CPU tests). Decided from the
+    tile's shape alone. Why not [nt, t]: a scan step reads ONE tile's
+    row of each, and a TPU lays a 2-D array out in memory tiles of 8
+    rows x 128 columns — row i of [nt, t] is one sublane of every tile,
+    512 B out of every 4 KB spread over 8 x t x 4 bytes, and on a v5e a
+    2^18-row step's 1 MB of ids cost a batch 18.2 us and its 1 MB of
+    scales 11–12 us at every batch size, where the memory moves 1 MB in
+    1.3. In [nt, t/128, 128] the step's slice is one dense block of
+    whole memory tiles — 2.0 us a pass — in the shape the step's scores
+    end in (PERF.md section 6, PR 42)."""
+    lanes = _LANES if t % _LANES == 0 else t
+    return nt, t // lanes, lanes
 
 
 def shortlist_k(k: int, num_rows: int) -> int:
@@ -554,15 +578,17 @@ def scan_select(b: int, nt: int, t: int, k: int, d: int,
 
 def _select_deferred(scores, maxima, ids, k: int):
     """The k best of each query over ALL tiles: [NT, B, T/G, G] stored
-    scores, their [NT, B, T/G] group maxima and the [NT, T] row ids ->
-    ([B, k] scores, [B, k] ids). ``_two_level_top_k`` with the catalog
-    in the tile's place; the values are read, not recomputed."""
+    scores, their [NT, B, T/G] group maxima and the row ids as stored
+    (``side_shape``) -> ([B, k] scores, [B, k] ids).
+    ``_two_level_top_k`` with the catalog in the tile's place; the
+    values are read, not recomputed."""
     nt, b, per, g = scores.shape
     mx = maxima.transpose(1, 0, 2).reshape(b, nt * per)
     _, gix = _tile_top_k(mx, k)  # groups, numbered tile-major
     cand = scores[gix // per, jnp.arange(b)[:, None], gix % per]
     best_s, pos = _best_of_groups(cand, gix)  # rows of the stored catalog
-    return best_s, ids[pos // (per * g), pos % (per * g)]
+    row = jnp.unravel_index(pos % (per * g), ids.shape[1:])
+    return best_s, ids[(pos // (per * g), *row)]
 
 
 # One query against a [T, D] tile: XLA:TPU turns the one-row product
@@ -618,16 +644,26 @@ def _split_bf16(q):
 def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
                  select: str | None = None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
-    tile scores [B, T] in the catalog's storage precision, takes the
-    tile's top-k', and merges into the running best — the [B, I] score
-    matrix and the full-catalog top-k never materialize, which is where
-    the win over the exact path comes from once I outgrows cache. Where
-    the stored scores fit (``scan_select`` -> "deferred") the selection
-    is left to the end: a step scores the tile and keeps the scores and
-    their group maxima, and the k' best come from one selection over
-    all tiles — the same shortlist, its values bit-equal. ``select``
-    overrides the rule: for the tests and measurements that compare the
-    two bodies on one input; nothing served passes it.
+    tile scores [B, T] in the catalog's storage precision, multiplies
+    an int8 pair's row scales back and guards the padding (id -1 ->
+    NEG_INF) — the [B, I] score matrix and a full-catalog top-k never
+    materialize. Where the stored scores fit (``scan_select`` ->
+    "deferred": every batch a benchmark cell dispatches) that is all a
+    step does: it keeps the tile's scores and their group maxima, and
+    the k' best come from ONE selection over all tiles after the loop
+    (``_select_deferred``). A batch beyond the bound selects in the
+    step — its tile's top-k', merged into a running best — the same
+    shortlist, its values bit-equal. ``select`` overrides the rule: for
+    the tests and measurements that compare the bodies on one input;
+    nothing served passes it.
+
+    ``ids`` and ``scales`` are [NT, ...] arrays of T values a tile, taken
+    as they lie: a catalog stores them ``side_shape`` ([NT, T/128, 128]:
+    a step's slice is one dense block of the chip's memory), and the
+    same values [NT, T] give the same answer bit for bit (the tests'
+    and ``scan_alone.py``'s comparison; a row of [NT, T] is a sublane
+    of every memory tile and costs a step 11–18 us a side array where a
+    dense block costs 2).
 
     ``mode``: "int8" (values*scale columns, f32 GEMM on cast values),
     "int8_dot" (int8 x int8 -> int32 accumulation, quantized queries —
@@ -642,7 +678,7 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
     one it was before rules existed, op for op."""
     B = q.shape[0]
     dot = score_form(B, q.shape[1], mode) == "dot"
-    nt, t = ids.shape
+    nt, t = ids.shape[0], ids.size // ids.shape[0]
     deferred = (
         select or scan_select(B, nt, t, k, q.shape[1], mode)
     ) == "deferred"
@@ -681,23 +717,30 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
                 sc = jax.lax.dot_general(
                     qi, v, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.int32,
-                ).astype(jnp.float32) * s[None, :]
+                ).astype(jnp.float32)
+            elif dot:
+                # int8 values are whole numbers under 2^7: exact in bf16
+                p = jax.lax.dot_general(
+                    q3, v.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                # one row: everything after it in the side arrays' own
+                # shape, where a select costs a quarter of what it does
+                # over [1, T] (the reshape follows the three rows' sum,
+                # not a dot, whose operand XLA would re-lay for it)
+                sc = ((p[2:3] + p[1:2]) + p[:1]).reshape(1, *tid.shape)
             else:
-                if dot:
-                    # int8 values are whole numbers under 2^7: exact in bf16
-                    p = jax.lax.dot_general(
-                        q3, v.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    sc = (p[2:3] + p[1:2]) + p[:1]
-                else:
-                    sc = jnp.matmul(
-                        q, v.T.astype(jnp.float32),
-                        preferred_element_type=jnp.float32,
-                    )
-                if scales is not None:
-                    sc = sc * s[None, :]
-            sc = jnp.where(tid[None, :] >= 0, sc, NEG_INF)
+                sc = jnp.matmul(
+                    q, v.T.astype(jnp.float32),
+                    preferred_element_type=jnp.float32,
+                )
+            # scale and guard where ``sc`` lies: a single's summed row
+            # [1, T/128, 128] against the step's slices as stored, a
+            # batch's [B, T] against flat views of them (bitcasts)
+            side = (1, *sc.shape[1:])
+            if scales is not None:
+                sc = sc * s.reshape(side)
+            sc = jnp.where(tid.reshape(side) >= 0, sc, NEG_INF).reshape(B, t)
         if rules is not None:
             with jax.named_scope("retrieval.shortlist.mask"):
                 ok = rows_allowed(av, cs, ht, rules.qcat, rules.has_cat)
@@ -709,7 +752,7 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
         with jax.named_scope("retrieval.shortlist.tile_topk"):
             ts, tix = _tile_top_k(sc, k)
             ti = jnp.take_along_axis(
-                jnp.broadcast_to(tid[None, :], sc.shape), tix, axis=1
+                jnp.broadcast_to(tid.reshape(1, t), sc.shape), tix, axis=1
             )
             if rules is not None:
                 ti = jnp.where(ts > NEG_INF / 2, ti, -1)
@@ -915,11 +958,14 @@ def put_rows(values):
 def _quantized_tiles(values, scales, nt: int, t: int):
     """The coarse form of a resident int8 pair, made where it lies: the
     [I, D] values and [I] scales padded to ``nt`` whole tiles (zero
-    rows of scale 1; their ids say -1) -> ([nt, t, D], [nt, t])."""
+    rows of scale 1; their ids say -1) -> ([nt, t, D], the scales in
+    ``side_shape(nt, t)``)."""
     pad = nt * t - values.shape[0]
     return (
         jnp.pad(values, ((0, pad), (0, 0))).reshape(nt, t, values.shape[1]),
-        jnp.pad(scales, (0, pad), constant_values=1.0).reshape(nt, t),
+        jnp.pad(scales, (0, pad), constant_values=1.0).reshape(
+            side_shape(nt, t)
+        ),
     )
 
 
@@ -937,9 +983,12 @@ class CoarseCatalog:
     only ever costs shortlist coverage, never final score accuracy (the
     rescore reads the original table).
 
-    Tiles are [NT, T, D] with row ids [NT, T] (-1 marks padding past the
-    catalog), so one scan step's working set is a T-row slab regardless
-    of I.
+    Tiles are [NT, T, D], so one scan step's working set is a T-row slab
+    regardless of I. The per-row side arrays — the row ids (-1 marks
+    padding past the catalog) and an int8 pair's row scales — are
+    stored [NT, T/128, 128] (``side_shape``): a step's slice of each is
+    one dense block of the device's memory, where a row of [NT, T] is a
+    sublane of every memory tile.
     """
 
     def __init__(self, item_table, tile: int | None = None,
@@ -990,7 +1039,7 @@ class CoarseCatalog:
             [np.arange(self.num_rows, dtype=np.int32),
              np.full(pad, -1, np.int32)]
         )
-        self._ids = jnp.asarray(ids.reshape(nt, T))
+        self._ids = jnp.asarray(ids.reshape(side_shape(nt, T)))
         set_resident(coarse=self._tiles, coarse_scales=self._scales,
                      coarse_ids=self._ids)
 

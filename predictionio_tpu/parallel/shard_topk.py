@@ -191,6 +191,7 @@ class ShardedCatalog:
         self.tile = t = min(retrieval.tile_size(), retrieval._pow2(max(1, r)))
         self.tiles_per_shard = nt = -(-r // t)
         stored = nt * t  # rows a device holds, padding included
+        sides = retrieval.side_shape(nt, t)  # of a device's row ids
         devices = list(mesh.devices.flat)
         uploading = threading.Lock()
 
@@ -207,7 +208,7 @@ class ShardedCatalog:
             with uploading:
                 t1 = time.perf_counter()
                 rows = jax.device_put(block, devices[i])
-                dev_ids = jax.device_put(ids.reshape(nt, t), devices[i])
+                dev_ids = jax.device_put(ids.reshape(sides), devices[i])
                 rows.block_until_ready()
                 t2 = time.perf_counter()
             tiles = _coarse_copy(rows, nt, t)
@@ -229,7 +230,7 @@ class ShardedCatalog:
 
         self._rows = whole((s[0] for s in staged), (n * stored, self.dim))
         self._tiles = whole((s[1] for s in staged), (n * nt, t, self.dim))
-        self._ids = whole((s[2] for s in staged), (n * nt, t))
+        self._ids = whole((s[2] for s in staged), (n * nt, *sides[1:]))
         self._replicated = NamedSharding(mesh, P())
         retrieval._m_shards.set(float(n))
 

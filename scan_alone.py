@@ -1,26 +1,35 @@
 #!/usr/bin/env python3
 """scan_alone.py — the coarse scan alone, on the chip: both places a
-scan can select its k' best, on one input, at the benchmark's shapes.
+scan can select its k' best, and both forms its per-row side arrays can
+lie in, on one input, at the benchmark's shapes.
 
     chiprun -- python3 scan_alone.py                     # every shape, B = 1 .. 64
     chiprun -- python3 scan_alone.py --shapes retrieval-yambda --batches 8,16
+    chiprun -- python3 scan_alone.py --sides lanes     # the stored form alone
     python3 scan_alone.py --compile-only                 # here: no chip needed
 
-For each shape and batch size it jits ``ops.retrieval._coarse_scan`` twice
-— ``select="deferred"`` (one selection after the tile loop) and
-``select="two_level"`` (every step its tile's, merged) — and reports, a body:
-``temp_mb`` (``memory_analysis()`` temporaries of the compiled program),
+For each shape and batch size it jits ``ops.retrieval._coarse_scan`` once a
+body — ``select="deferred"`` (one selection after the tile loop) and
+``select="two_level"`` (every step its tile's, merged) — and a side form —
+``lanes`` (the row ids and an int8 pair's row scales as a catalog stores
+them, ``retrieval.side_shape``: [NT, T/128, 128]) and ``flat`` ([NT, T],
+as they were stored before PR 42: the same values, the scan takes either)
+— and reports, for each: ``temp_mb`` (``memory_analysis()`` temporaries of
+the compiled program),
 ``wall_ms`` (median host time of a call that ends in ``block_until_ready``),
 ``device_ms`` (the program's mean device time in a profiler trace) and
 ``step_us`` (the loop's operations, device us a tile, largest first). It
-says which body the rule serves (``scan_select``) and holds the two bodies'
-answers against each other: scores bit-equal, ids equal.
+says which body the rule serves (``scan_select``) and holds all answers
+against the first: scores bit-equal, ids equal. A row's ``two_level`` /
+``deferred`` entries are the ``lanes`` form's; the ``flat`` form's are
+under ``flat``.
 
 ``--compile-only`` compiles for a DESCRIBED v5e and prints the temporaries
 alone: nothing runs, so it gives no time. With a chip, a platform other
 than ``tpu`` is refused: a CPU's time is nobody's number.
 
-This is the program behind the tables of PERF.md section 6 (PR 33, PR 36).
+This is the program behind the tables of PERF.md section 6 (PR 33, PR 36,
+PR 41, PR 42).
 No benchmark cell runs it; results go to ``chiprun_out/scan_alone.json``.
 """
 
@@ -60,6 +69,7 @@ SHAPES = {
                                          modes=("int8", "int8_dot")),
 }
 BODIES = ("two_level", "deferred")
+SIDES = ("lanes", "flat")
 
 
 def _scan(k, select, mode="bf16"):
@@ -76,18 +86,21 @@ def _scan(k, select, mode="bf16"):
     return jax.jit(run)
 
 
-def _arguments(shape, b, make, mode="bf16"):
+def _arguments(shape, b, make, mode="bf16", sides="lanes"):
     """(q, tiles, ids[, rules]) through ``make(shape, dtype, fill)``; in an
-    int8 mode (q, int8 tiles, f32 row scales, ids)."""
+    int8 mode (q, int8 tiles, f32 row scales, ids). ``sides``: the form
+    of ids and scales — "lanes" as a catalog stores them, "flat" [NT, T];
+    the values do not depend on it."""
     nt = -(-shape["rows"] // TILE)
+    side = retrieval.side_shape(nt, TILE) if sides == "lanes" else (nt, TILE)
     args = [
         make((b, shape["rank"]), jnp.float32, "normal"),
         make((nt, TILE, shape["rank"]), jnp.bfloat16, "normal"),
-        make((nt, TILE), jnp.int32, ("ids", shape["rows"])),
+        make(side, jnp.int32, ("ids", shape["rows"])),
     ]
     if mode != "bf16":
         args[1:2] = [make((nt, TILE, shape["rank"]), jnp.int8, "int8"),
-                     make((nt, TILE), jnp.float32, "scales")]
+                     make(side, jnp.float32, "scales")]
     if shape["rules"]:
         args.append(Rules(
             avail=make((nt * TILE,), jnp.uint8, "avail"),
@@ -100,8 +113,10 @@ def _arguments(shape, b, make, mode="bf16"):
 
 
 def _device_array(shape, dtype, fill):
-    key = jax.random.PRNGKey(zlib.crc32(repr((shape, fill)).encode()))
     n = int(np.prod(shape))
+    # a side array is drawn [NT, T] in either form: the same values
+    drawn = (shape[0], n // shape[0]) if fill == "scales" else shape
+    key = jax.random.PRNGKey(zlib.crc32(repr((drawn, fill)).encode()))
     if fill == "normal":
         return jax.jit(
             lambda k: jax.random.normal(k, shape, jnp.float32).astype(dtype)
@@ -112,7 +127,9 @@ def _device_array(shape, dtype, fill):
         )(key)
     if fill == "scales":  # a Gaussian row's largest value over 127, about
         return jax.jit(
-            lambda k: jax.random.uniform(k, shape, jnp.float32, 0.006, 0.012)
+            lambda k: jax.random.uniform(
+                k, drawn, jnp.float32, 0.006, 0.012
+            ).reshape(shape)
         )(key)
     if isinstance(fill, tuple):  # row ids, -1 past the catalog
         ids = jnp.arange(n, dtype=jnp.int32)
@@ -193,6 +210,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batches", default="1,2,4,8,16,32,64")
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--traced", type=int, default=10)
+    ap.add_argument("--sides", default=",".join(SIDES))
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default="chiprun_out/scan_alone.json")
     a = ap.parse_args(argv)
@@ -221,27 +239,32 @@ def main(argv=None) -> int:
         done.add(key)
         for mode, b in ((m, int(x)) for m in shape.get("modes", ("bf16",))
                         for x in a.batches.split(",")):
-            args = _arguments(shape, b, make, mode)
             row = {
                 "shape": name, "tiles": nt, "rank": shape["rank"],
                 "rules": shape["rules"], "mode": mode, "b": b,
                 "served": retrieval.scan_select(b, nt, TILE, KP, shape["rank"], mode),
                 "score_form": retrieval.score_form(b, shape["rank"], mode),
             }
-            outs = {}
-            for body in BODIES:
-                fn = _scan(KP, body, mode).lower(*args).compile()
-                mem = fn.memory_analysis()
-                row[body] = {"temp_mb": mem.temp_size_in_bytes / 1e6}
-                if not a.compile_only:
-                    outs[body], timed = measure(fn, args, a.calls, a.traced)
-                    row[body].update(timed)
+            outs = []
+            for sides in a.sides.split(","):
+                args = _arguments(shape, b, make, mode, sides)
+                into = row if sides == "lanes" else row.setdefault(sides, {})
+                for body in BODIES:
+                    fn = _scan(KP, body, mode).lower(*args).compile()
+                    mem = fn.memory_analysis()
+                    into[body] = {"temp_mb": mem.temp_size_in_bytes / 1e6}
+                    if not a.compile_only:
+                        out, timed = measure(fn, args, a.calls, a.traced)
+                        outs.append(jax.device_get(out))
+                        into[body].update(timed)
+                del args  # one form's side arrays on the chip at a time
             if outs:
-                (s0, i0), (s1, i1) = (jax.device_get(outs[x]) for x in BODIES)
-                row["scores_bit_equal"] = bool(
-                    (s0.view(np.uint32) == s1.view(np.uint32)).all()
+                s0, i0 = outs[0]
+                row["scores_bit_equal"] = all(
+                    (s0.view(np.uint32) == s.view(np.uint32)).all()
+                    for s, _ in outs[1:]
                 )
-                row["ids_equal"] = bool((i0 == i1).all())
+                row["ids_equal"] = all((i0 == i).all() for _, i in outs[1:])
             rows.append(row)
             print(json.dumps(row), flush=True)
     if not a.compile_only:

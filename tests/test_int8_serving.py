@@ -188,6 +188,7 @@ class TestPutRowsAndTiles:
         vq, vs = _pair(factors.STREAM_ITEM_FACTORS, 1000)
         tiles, scales = retrieval._quantized_tiles(jnp.asarray(vq), jnp.asarray(vs), nt=2, t=512)
         assert tiles.shape == (2, 512, RANK) and tiles.dtype == jnp.int8
+        assert scales.shape == (2, 4, 128) == retrieval.side_shape(2, 512)
         flat = np.asarray(tiles).reshape(-1, RANK)
         np.testing.assert_array_equal(flat[:1000], vq)
         assert not flat[1000:].any()
@@ -202,6 +203,7 @@ class TestPutRowsAndTiles:
         for x, y in ((a._tiles, b._tiles), (a._scales, b._scales), (a._ids, b._ids)):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
         assert a.nbytes() == 3 * 1024 * (RANK + 8) and int(np.asarray(a._ids).min()) == -1
+        assert a._ids.shape == a._scales.shape == (3, 8, 128) and a.stored_rows == 3 * 1024
 
     def test_a_dense_table_in_an_int8_mode_is_quantized_once(self):
         f = factors.factor_table(SEED, 9, 700, RANK)
@@ -209,6 +211,7 @@ class TestPutRowsAndTiles:
         vq, vs = reference_int8.quantize_rows(f)
         np.testing.assert_array_equal(np.asarray(cat._tiles).reshape(-1, RANK)[:700], vq)
         np.testing.assert_array_equal(np.asarray(cat._scales).reshape(-1)[:700], vs)
+        assert cat._scales.shape == cat._ids.shape == (3, 2, 128)
 
 
 class TestCountersAndScopes:

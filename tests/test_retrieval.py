@@ -11,6 +11,7 @@ all (the byte-parity regression).
 
 from __future__ import annotations
 
+import functools
 from types import SimpleNamespace
 
 import numpy as np
@@ -697,8 +698,10 @@ def _scan_args(b, nt, t, d, mode="bf16"):
     return (
         jnp.ones((b, d), jnp.float32),
         jnp.ones((nt, t, d), jnp.bfloat16 if mode == "bf16" else jnp.int8),
-        None if mode == "bf16" else jnp.ones((nt, t), jnp.float32),
-        jnp.arange(nt * t, dtype=jnp.int32).reshape(nt, t),
+        None if mode == "bf16" else jnp.ones(
+            retrieval.side_shape(nt, t), jnp.float32
+        ),
+        jnp.arange(nt * t, dtype=jnp.int32).reshape(retrieval.side_shape(nt, t)),
     )
 
 
@@ -1213,7 +1216,7 @@ class TestDeferredSelect:
         module had before any selection was split, which keeps every
         score of its one tile. Positions of rows that may not be served
         hold ``NEG_INF``."""
-        nt, t = cat._ids.shape
+        nt, t = cat._ids.shape[0], cat.tile
         row = np.full((len(q), nt * t), retrieval.NEG_INF, np.float32)
         for i in range(nt):
             one = SimpleNamespace(
@@ -1222,7 +1225,7 @@ class TestDeferredSelect:
                 # positions, not ids: padding keeps its place in the row
                 _ids=np.arange(i * t, (i + 1) * t, dtype=np.int32)[None],
             )
-            guard = np.asarray(cat._ids[i]) >= 0
+            guard = np.asarray(cat._ids[i]).reshape(-1) >= 0
             s, pos = self._scan(
                 one, q, t, None if rules is None else _tile_rules(rules, i, t),
                 "plain",
@@ -1491,6 +1494,127 @@ class TestDeferredSelect:
 
 
 # -- the chain: one owner of "exact or two-stage, shortlist -> rescore, probe" --
+
+
+# -- the per-row side arrays lie [NT, T/128, 128] --------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _sides_catalog(mode, tile):
+    """A catalog of rank 64 whose third tile holds 300 rows and
+    padding, its table, and NumPy's coarse scores' inputs."""
+    table = _int8(2 * tile + min(300, tile // 3), 64, seed=91)
+    return CoarseCatalog(table, tile=tile, mode=mode), table
+
+
+class TestSideArrays:
+    """A catalog stores its row ids and an int8 pair's row scales
+    [NT, T/L, L] (``side_shape``: L = 128 lanes where they divide the
+    tile, else the tile), and the scan takes them as they lie: against
+    NumPy's selection over NumPy's coarse scores, and against the SAME
+    scan handed the same values [NT, T] — the form every catalog stored
+    before PR 42 — bit for bit."""
+
+    K = 16
+    # body -> the tile that runs it: 2^14 rows split into 128-lane groups
+    # at k' = 16 (the served structure: g = L = 128); 96 rows split
+    # nothing and are no whole number of lanes (L = T)
+    TILES = {"deferred": 1 << 14, "two_level": 1 << 14, "plain": 96}
+
+    @pytest.mark.parametrize("t,want", [
+        (1 << 18, (5, 2048, 128)), (1 << 14, (5, 128, 128)),
+        (128, (5, 1, 128)), (96, (5, 1, 96)), (200, (5, 1, 200)),
+        (1, (5, 1, 1)),
+    ])
+    def test_the_shape_comes_from_the_tile_alone(self, t, want):
+        assert retrieval.side_shape(5, t) == want
+
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    @pytest.mark.parametrize("tile", [1 << 14, 96])
+    def test_a_catalog_stores_them_so(self, mode, tile):
+        cat, (vals, scales) = _sides_catalog(mode, tile)
+        shape = retrieval.side_shape(3, tile)
+        assert shape[2] == (128 if tile % 128 == 0 else tile)
+        assert cat._ids.shape == shape and cat.stored_rows == 3 * tile
+        flat = np.asarray(cat._ids).reshape(-1)
+        np.testing.assert_array_equal(flat[: len(vals)], np.arange(len(vals)))
+        assert (flat[len(vals):] == -1).all() and len(vals) < 2.4 * tile
+        if mode == "bf16":
+            assert cat._scales is None
+            assert cat.nbytes() == 3 * tile * (64 * 2 + 4)
+            return
+        assert cat._scales.shape == shape
+        got = np.asarray(cat._scales).reshape(-1)
+        np.testing.assert_array_equal(got[: len(vals)], scales)
+        assert (got[len(vals):] == 1.0).all()
+        assert cat.nbytes() == 3 * tile * (64 + 4 + 4)
+
+    @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
+    @pytest.mark.parametrize("body", ["deferred", "two_level", "plain"])
+    @pytest.mark.parametrize("b", [1, 2, 8, 16, 32])
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_parity(self, mode, b, body, ruled):
+        """32 queries are beyond the bound of every mode at rank 64
+        (``_stored_scores_fit``); each body is forced on every batch,
+        as ``select`` allows, so all three run at all five."""
+        import jax
+
+        t, k = self.TILES[body], self.K
+        cat, table = _sides_catalog(mode, t)
+        rows, stored = len(table[0]), cat.stored_rows
+        assert retrieval.scan_select(32, 3, t, k, 64, mode) == (
+            "plain" if body == "plain" else "two_level"
+        )
+        assert bool(retrieval.tile_select_group(t, k)) == (body != "plain")
+        q = _dense(b, 64, seed=92 + b)
+        sc = _coarse_scores(cat, table, q)
+        allowed = np.ones((b, rows), bool)
+        rules = None
+        if ruled:
+            small = np.random.default_rng(93).permutation(rows)[:10]
+            ex = np.full((b, 4), -1, np.int32)
+            ex[-1, :3] = np.argsort(-sc[-1])[:3]  # the last query's own best
+            ex[0, 3] = rows - 1  # a row of the mostly padded tile
+            qcat = np.full((b, 1), -2, np.int32)
+            qcat[b // 2] = 1
+            rules = _rules(stored, b, small_cat=small, ex=ex, qcat=qcat)
+            allowed[:, ::97] = False
+            in_small = np.zeros(rows, bool)
+            in_small[small] = True
+            allowed[b // 2] &= in_small
+            allowed[-1, ex[-1, :3]] = False
+            allowed[0, rows - 1] = False
+
+        def scan(ids, scales):
+            return jax.device_get(jax.jit(
+                lambda q, tiles, scales, ids, rules: retrieval._coarse_scan(
+                    q, tiles, scales, ids, k, mode, rules,
+                    select=None if body == "plain" else body,
+                )
+            )(q, cat._tiles, scales, ids, rules))
+
+        s, ids = scan(cat._ids, cat._scales)
+        flat_s, flat_ids = scan(
+            cat._ids.reshape(3, t),
+            None if cat._scales is None else cat._scales.reshape(3, t),
+        )
+        np.testing.assert_array_equal(s.view(np.uint32), flat_s.view(np.uint32))
+        np.testing.assert_array_equal(ids, flat_ids)
+        for r in range(b):
+            n = min(k, int(allowed[r].sum()))
+            got = ids[r][:n]
+            assert (ids[r][n:] == -1).all() and (got >= 0).all()
+            assert len(set(got.tolist())) == n and allowed[r][got].all()
+            np.testing.assert_allclose(
+                s[r][:n], sc[r][got], rtol=1e-5, atol=1e-5
+            )
+            # nothing better was left out: NumPy's selection, to rounding
+            rest = allowed[r].copy()
+            rest[got] = False
+            if rest.any():
+                assert sc[r][rest].max() <= s[r][:n].min() + 1e-4
+        if ruled:
+            assert 0 < int((ids[b // 2] >= 0).sum()) == int(allowed[b // 2].sum()) < k
 
 
 class TestServingChain:

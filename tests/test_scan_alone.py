@@ -1,6 +1,7 @@
 """scan_alone.py (the chip measurement behind PERF.md's scan tables):
-it takes no time off a TPU, and at toy shapes its two bodies run on one
-input and agree — so the script cannot rot between the PRs that use it."""
+it takes no time off a TPU, and at toy shapes its two bodies and its two
+side forms run on one input and agree — so the script cannot rot between
+the PRs that use it."""
 
 import jax
 import numpy as np
@@ -30,35 +31,63 @@ def test_the_five_configurations_scan_four_shapes():
         scan_alone.SHAPES["similarproduct-taobao"]
 
 
+T = 1 << 13
+
+
+def _answers(monkeypatch, shape, b, mode="bf16"):
+    """{(sides, body): host (scores, ids)} of every program a row of
+    the table runs, at a toy tile."""
+    monkeypatch.setattr(scan_alone, "TILE", T)
+    out = {}
+    for sides in scan_alone.SIDES:
+        args = scan_alone._arguments(
+            shape, b, scan_alone._device_array, mode, sides
+        )
+        side = args[-2] if shape["rules"] else args[-1]  # the row ids
+        assert side.shape == {"lanes": (3, T // 128, 128), "flat": (3, T)}[sides]
+        assert args[1].shape == (3, T, 64) and int(side.min()) == -1
+        if mode != "bf16":
+            assert args[1].dtype == np.int8 and args[2].shape == side.shape
+        for body in scan_alone.BODIES:
+            out[sides, body] = jax.device_get(
+                scan_alone._scan(128, body, mode)(*args)
+            )
+    return out
+
+
+def _all_agree(answers):
+    (s0, i0), *rest = answers.values()
+    for s, i in rest:
+        np.testing.assert_array_equal(s0.view(np.uint32), s.view(np.uint32))
+        np.testing.assert_array_equal(i0, i)
+    return i0
+
+
 @pytest.mark.parametrize("rules", [False, True])
 @pytest.mark.parametrize("b", [1, 8])
-def test_both_bodies_on_one_input_agree(monkeypatch, b, rules):
-    monkeypatch.setattr(scan_alone, "TILE", 1 << 13)
-    shape = dict(rows=2 * (1 << 13) + 1000, rank=64, rules=rules)
-    args = scan_alone._arguments(shape, b, scan_alone._device_array)
-    assert args[1].shape == (3, 1 << 13, 64) and int(args[2].min()) == -1
-    assert retrieval.scan_select(b, 3, 1 << 13, 128, 64) == "deferred"
-    (s0, i0), (s1, i1) = (
-        jax.device_get(scan_alone._scan(128, body)(*args))
-        for body in scan_alone.BODIES
-    )
-    np.testing.assert_array_equal(s0.view(np.uint32), s1.view(np.uint32))
-    np.testing.assert_array_equal(i0, i1)
-    assert i0.max() < shape["rows"]
+def test_both_bodies_and_both_side_forms_on_one_input_agree(monkeypatch, b, rules):
+    shape = dict(rows=2 * T + 1000, rank=64, rules=rules)
+    assert retrieval.scan_select(b, 3, T, 128, 64) == "deferred"
+    ids = _all_agree(_answers(monkeypatch, shape, b))
+    assert ids.max() < shape["rows"]
 
 
 @pytest.mark.parametrize("mode", ["int8", "int8_dot"])
 @pytest.mark.parametrize("b", [1, 8])
-def test_both_bodies_agree_over_int8_tiles(monkeypatch, b, mode):
-    monkeypatch.setattr(scan_alone, "TILE", 1 << 13)
-    shape = dict(rows=2 * (1 << 13) + 1000, rank=64, rules=False)
-    args = scan_alone._arguments(shape, b, scan_alone._device_array, mode)
-    assert args[1].dtype == np.int8 and args[2].shape == (3, 1 << 13)
-    assert retrieval.scan_select(b, 3, 1 << 13, 128, 64, mode) == "deferred"
-    (s0, i0), (s1, i1) = (
-        jax.device_get(scan_alone._scan(128, body, mode)(*args))
-        for body in scan_alone.BODIES
+def test_they_agree_over_int8_tiles(monkeypatch, b, mode):
+    shape = dict(rows=2 * T + 1000, rank=64, rules=False)
+    assert retrieval.scan_select(b, 3, T, 128, 64, mode) == "deferred"
+    ids = _all_agree(_answers(monkeypatch, shape, b, mode))
+    assert 0 <= ids.min() and ids.max() < shape["rows"]
+
+
+@pytest.mark.parametrize("fill,dtype", [
+    ("scales", np.float32), (("ids", 2 * T + 1000), np.int32),
+])
+def test_a_side_array_holds_the_same_values_in_either_form(fill, dtype):
+    lanes = scan_alone._device_array((3, T // 128, 128), dtype, fill)
+    flat = scan_alone._device_array((3, T), dtype, fill)
+    np.testing.assert_array_equal(
+        np.asarray(lanes).reshape(3, T), np.asarray(flat)
     )
-    np.testing.assert_array_equal(s0.view(np.uint32), s1.view(np.uint32))
-    np.testing.assert_array_equal(i0, i1)
-    assert 0 <= i0.min() and i0.max() < shape["rows"]
+    assert lanes.shape == retrieval.side_shape(3, T)

@@ -96,6 +96,7 @@ class TestShardedChain:
         cat = ShardedCatalog(V, mesh4)
         kp = retrieval.two_stage_k(16, len(V))
         assert cat.tile == 8192 and cat.tiles_per_shard == -(-items // 4 // 8192)
+        assert cat._ids.shape == (4 * cat.tiles_per_shard, 64, 128)
         assert retrieval.scan_select(
             batch, cat.tiles_per_shard, cat.tile, kp, cat.dim, cat.mode
         ) == path
@@ -175,6 +176,29 @@ class TestShardedChain:
         s, ids = tiny.exact_top_k(U[:2], 16)
         assert (np.sort(ids, axis=1)[:, -6:] == np.arange(6)).all()
         assert (ids[:, 6:] == -1).all()
+
+    @pytest.mark.parametrize("tile,lanes", [(8192, 128), (200, 200)])
+    def test_a_shards_ids_lie_as_the_one_chip_catalogs(
+        self, mesh4, monkeypatch, tile, lanes
+    ):
+        """[tiles, T/128, 128] a shard (``retrieval.side_shape``; the
+        whole tile where 128 lanes do not divide it), split over the
+        mesh on the leading axis: each device holds its own tiles' ids,
+        global, -1 in the padding."""
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", str(tile))
+        _, V = _tables(4 * 2 * tile - 3 * tile // 2, seed=4)
+        cat = ShardedCatalog(V, mesh4)
+        nt = cat.tiles_per_shard
+        assert cat.tile == tile and nt == 2
+        assert cat._ids.shape == (4 * nt, tile // lanes, lanes)
+        assert cat._ids.shape[1:] == retrieval.side_shape(nt, tile)[1:]
+        for i, shard in enumerate(cat._ids.addressable_shards):
+            got = np.asarray(shard.data).reshape(-1)
+            assert shard.data.shape == retrieval.side_shape(nt, tile)
+            lo = i * cat.rows_per_shard
+            n = min(cat.rows_per_shard, len(V) - lo)
+            np.testing.assert_array_equal(got[:n], np.arange(lo, lo + n))
+            assert (got[n:] == -1).all()
 
     def test_a_shortlist_wider_than_a_tile_clamps(self, mesh4, two_stage, monkeypatch):
         """k' = 8 * pow2(k) = 1,024 against tiles of 128 rows: a shard

@@ -35,19 +35,29 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compiled(chip, b, nt, d):
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+def _on(sharding):
+    """(shape, dtype) -> an argument described, not made, there."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
 
+
+def _compiled(chip, b, nt, d, mode="bf16", sides=None):
+    """``_coarse_topk`` for ``b`` queries over ``nt`` tiles, the side
+    arrays as a catalog stores them (``sides``: another shape)."""
+    arg = _on(chip)
+    sides = sides or retrieval.side_shape(nt, TILE)
     return retrieval._coarse_topk.lower(
-        arg((b, d), jnp.float32), arg((nt, TILE, d), jnp.bfloat16), None,
-        arg((nt, TILE), jnp.int32), k=KP, mode="bf16",
+        arg((b, d), jnp.float32),
+        arg((nt, TILE, d), jnp.bfloat16 if mode == "bf16" else jnp.int8),
+        None if mode == "bf16" else arg(sides, jnp.float32),
+        arg(sides, jnp.int32), k=KP, mode=mode,
     ).compile()
 
 
 def _loop_body(text):
     """The instructions of the scan's ``while`` body in a compiled
-    module's text: [(opcode, result shape as text)]."""
+    module's text: [(result shape as text, opcode)]."""
     name = re.search(r"while\(.*?body=%?([\w.\-]+)", text).group(1)
     start = text.index(f"\n%{name} ")
     body = text[start: text.index("\n}\n", start)]
@@ -101,10 +111,7 @@ def test_the_packed_masked_scan_keeps_its_loop(one_chip, b):
     from predictionio_tpu.ops.topk import Rules
 
     nt, d, e = 16, 128, 128  # a storefront query's seen list: 65-128 rows
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    arg = _on(one_chip)
     resident = Rules(
         arg((nt * TILE,), jnp.uint8), (arg((nt * TILE,), jnp.int32),),
         None, None, None,
@@ -114,7 +121,8 @@ def test_the_packed_masked_scan_keeps_its_loop(one_chip, b):
         ex=arg((b, e), jnp.int32),
     )
     layout = retrieval.Layout(d, 1, e)
-    catalog = (arg((nt, TILE, d), jnp.bfloat16), None, arg((nt, TILE), jnp.int32))
+    catalog = (arg((nt, TILE, d), jnp.bfloat16), None,
+               arg(retrieval.side_shape(nt, TILE), jnp.int32))
     was = retrieval._coarse_topk_masked.lower(
         arg((b, d), jnp.float32), *catalog, separate, k=KP, mode="bf16",
     ).compile()
@@ -129,3 +137,107 @@ def test_the_packed_masked_scan_keeps_its_loop(one_chip, b):
         packed.memory_analysis().temp_size_in_bytes
         - was.memory_analysis().temp_size_in_bytes
     ) <= 1 << 20
+
+
+# -- the per-row side arrays: a step's slice is one dense block (PR 42) ---------
+
+# what a step costs beyond bookkeeping: its passes over memory
+_PASSES = ("fusion", "reduce", "copy", "convolution", "sort", "dynamic-slice",
+           "dynamic-update-slice", "select", "transpose")
+
+
+def _passes(text):
+    return [(shape, op) for shape, op in _loop_body(text) if op in _PASSES]
+
+
+@pytest.mark.parametrize("b", [1, 8])
+@pytest.mark.parametrize("nt,mode", [
+    (36, "bf16"),    # yambda
+    (46, "bf16"),    # a chip of the sharded catalog
+    (184, "int8"),   # the whole marketplace stored int8
+])
+def test_a_step_reads_its_side_arrays_as_one_dense_block(one_chip, nt, mode, b):
+    """The scans of the cells' shapes: the program's side arrays are
+    ``[NT,2048,128]`` in whole memory tiles of 8 x 128, a step slices
+    ``[1,2048,128]`` out of them, nothing in the loop is
+    ``[NT,262144]`` (where a step's row is one sublane of every tile),
+    and the body holds no ``copy`` of a tile or of a side array."""
+    text = _compiled(one_chip, b, nt, 64, mode).as_text()
+    entry = text[text.index("ENTRY "):]
+    sides = 1 if mode == "bf16" else 2
+    assert len(re.findall(
+        rf"[sf]32\[{nt},2048,128\]{{2,1,0:T\(8,128\)}} parameter\(", entry
+    )) == sides
+    assert f"[{nt},262144]" not in text
+    whole = text[text.index("\n%"):]  # every computation: the fused ones too
+    slices = re.findall(
+        r"= ([sf]32)\[1,2048,128\]{2,1,0:T\(8,128\)} dynamic-slice\(", whole
+    )
+    assert {"s32"} <= set(slices) and ("f32" in slices) == (mode == "int8")
+    ops = _passes(text)
+    assert ops and not [o for o in ops if o[1] in ("copy", "sort")], ops
+
+
+@pytest.mark.parametrize("nt,mode,parents", [(36, "bf16", 5), (184, "int8", 5)])
+def test_a_singles_step_has_no_more_passes_than_the_flat_forms(
+        one_chip, nt, mode, parents):
+    """B = 1: the score, the three rows' sum, the maxima and the two
+    stores — five passes a step, as many as the same scan compiled over
+    ``[NT, T]`` side arrays (the parent's program: ``parents``), with the
+    scale and the guard fused into the maxima and the store."""
+    lanes = _passes(_compiled(one_chip, 1, nt, 64, mode).as_text())
+    flat = _passes(_compiled(one_chip, 1, nt, 64, mode, (nt, TILE)).as_text())
+    assert len(flat) == parents and len(lanes) <= len(flat), (lanes, flat)
+
+
+def test_the_masked_scans_side_array_is_dense_too(one_chip):
+    """Both Taobao cells' masked single (16 tiles, rank 128): the ids'
+    slice is the dense block; the rules' vectors stay the compiler's."""
+    from predictionio_tpu.ops.topk import Rules
+
+    nt, d = 16, 128
+    arg = _on(one_chip)
+    rules = Rules(
+        arg((nt * TILE,), jnp.uint8), (arg((nt * TILE,), jnp.int32),),
+        arg((1, 1), jnp.int32), arg((1,), jnp.bool_), arg((1, 128), jnp.int32),
+    )
+    text = retrieval._coarse_topk_masked.lower(
+        arg((1, d), jnp.float32), arg((nt, TILE, d), jnp.bfloat16), None,
+        arg(retrieval.side_shape(nt, TILE), jnp.int32), rules, k=KP, mode="bf16",
+    ).compile().as_text()
+    assert re.search(
+        r"= s32\[1,2048,128\]{2,1,0:T\(8,128\)} dynamic-slice\(", text
+    )
+    assert f"[{nt},262144]{{1,0:T(8,128)}} parameter(" not in text
+    assert f"s32[{nt},2048,128]{{2,1,0:T(8,128)}} parameter(" in text
+
+
+def test_the_four_chip_chain_runs_the_same_step(one_chip):
+    """``_sharded_topk`` for the sharded cell's four chips (46 tiles a
+    chip, one query): each device's ids are ``s32[46,2048,128]``, its
+    loop body the one-chip single's — the three rows' sum, no
+    ``compare_select_fusion`` over a strided row — and the program's one
+    collective is the all-gather."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from predictionio_tpu.parallel import shard_topk
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n, nt, d = 4, 46, 64
+    whole, split = _on(NamedSharding(mesh, P())), _on(NamedSharding(mesh, P("data")))
+    text = shard_topk._sharded_topk.lower(
+        whole((1, d), jnp.float32),
+        split((n * nt * TILE, d), jnp.float32),
+        split((n * nt, TILE, d), jnp.bfloat16),
+        split((n * nt, *retrieval.side_shape(nt, TILE)[1:]), jnp.int32),
+        r=12_047_500, kp=KP, k=16, mode="bf16", mesh=mesh, axis="data",
+    ).compile().as_text()
+    assert f"s32[{nt},2048,128]{{2,1,0:T(8,128)}} parameter(" in text
+    assert f"[{nt},262144]" not in text
+    ops = _passes(text)
+    assert len(ops) == 5 and not [o for o in ops if o[1] in ("copy", "sort")], ops
+    assert set(re.findall(r"(all-gather|all-reduce|all-to-all|collective-permute)", text)) \
+        == {"all-gather"}
