@@ -41,7 +41,9 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    three bf16 rows whose sum it is, one dot against the tile as stored,
    the three partial scores added back ("dot": the same f32 product to
    summation order, through the form of it that the chip reads at the
-   memory's speed). Everything after the score is one row either way.
+   memory's speed). Everything after the score is one row either way,
+   and a single's scaled and guarded scores and their group maxima are
+   the two results of ONE pass over it (``_kept_once``).
    Beside the tiles lie one value a row in two side arrays, the row ids
    (-1: padding, guarded to a score that cannot win) and an int8 pair's
    row scales, stored [NT, T/128, 128] (``side_shape``) so that the one
@@ -641,6 +643,20 @@ def _split_bf16(q):
     return jnp.concatenate([hi, mid, lo]).astype(jnp.bfloat16)
 
 
+def _kept_once(kept):
+    """A deferred step's ``(scores, group maxima)`` as the two results
+    of one computation. Both are functions of the step's scaled and
+    guarded scores, and where nothing says otherwise XLA:TPU computes
+    those twice — once inside the fusion that stores the scores and
+    once inside the one that reduces them, each reading the summed row,
+    the 1 MB of scales and the 1 MB of ids again (3.9 + 5.2 us a
+    2^18-row tile beside a 23.4 us score: PERF.md section 6, PR 42).
+    Behind one barrier the pair has one producer, which the compiler
+    emits as a single two-result fusion: every value after the score
+    is read once and written once. The values are untouched."""
+    return jax.lax.optimization_barrier(kept)
+
+
 def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
                  select: str | None = None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
@@ -748,7 +764,9 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
         if deferred:
             with jax.named_scope("retrieval.shortlist.group_max"):
                 groups = sc.reshape(B, t // g, g)
-                return None, (groups, groups.max(axis=2))
+                kept = groups, groups.max(axis=2)
+                # (a batch's maxima ride its dot: nothing to keep once)
+                return None, (_kept_once(kept) if dot else kept)
         with jax.named_scope("retrieval.shortlist.tile_topk"):
             ts, tix = _tile_top_k(sc, k)
             ti = jnp.take_along_axis(

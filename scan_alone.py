@@ -1,11 +1,13 @@
 #!/usr/bin/env python3
 """scan_alone.py — the coarse scan alone, on the chip: both places a
-scan can select its k' best, and both forms its per-row side arrays can
-lie in, on one input, at the benchmark's shapes.
+scan can select its k' best, both forms its per-row side arrays can lie
+in, and the forms of a single's step after its score, on one input, at
+the benchmark's shapes.
 
     chiprun -- python3 scan_alone.py                     # every shape, B = 1 .. 64
     chiprun -- python3 scan_alone.py --shapes retrieval-yambda --batches 8,16
     chiprun -- python3 scan_alone.py --sides lanes     # the stored form alone
+    chiprun -- python3 scan_alone.py --batches 1 --steps twice,after
     python3 scan_alone.py --compile-only                 # here: no chip needed
 
 For each shape and batch size it jits ``ops.retrieval._coarse_scan`` once a
@@ -22,14 +24,18 @@ the compiled program),
 says which body the rule serves (``scan_select``) and holds all answers
 against the first: scores bit-equal, ids equal. A row's ``two_level`` /
 ``deferred`` entries are the ``lanes`` form's; the ``flat`` form's are
-under ``flat``.
+under ``flat``. Where the served scan is a ``dot``-form single (one query
+under rank 128, no rules) the row also runs that step's other forms
+(``--steps``, under ``steps``; ``_single`` says what each is): ``twice`` is
+the step of PR 42 — the served program less its one barrier, text for
+text — and the served step is the row's ``deferred``.
 
 ``--compile-only`` compiles for a DESCRIBED v5e and prints the temporaries
 alone: nothing runs, so it gives no time. With a chip, a platform other
 than ``tpu`` is refused: a CPU's time is nobody's number.
 
 This is the program behind the tables of PERF.md section 6 (PR 33, PR 36,
-PR 41, PR 42).
+PR 41, PR 42, PR 43).
 No benchmark cell runs it; results go to ``chiprun_out/scan_alone.json``.
 """
 
@@ -70,6 +76,9 @@ SHAPES = {
 }
 BODIES = ("two_level", "deferred")
 SIDES = ("lanes", "flat")
+# what a single's deferred step does after its score (``score_form`` "dot"
+# rows alone), beside the served step, which is the row's ``deferred``
+STEPS = ("twice", "scores_once", "after")
 
 
 def _scan(k, select, mode="bf16"):
@@ -83,6 +92,54 @@ def _scan(k, select, mode="bf16"):
             return retrieval._coarse_scan(
                 q, tiles, scales, ids, k, mode, select=select
             )
+    return jax.jit(run)
+
+
+def _single(k, form, mode="bf16"):
+    """A single's deferred scan (``score_form`` "dot") with another step
+    after the score than the served one, from the package's own pieces:
+    the same three-row dot, sum, scale and guard, the same selection
+    after the loop. ``form``: "twice" — the scores and their maxima as
+    two consumers of the scaled and guarded row, which XLA:TPU computes
+    twice (the step of PR 42, this PR's parent); "scores_once" — the
+    scores behind a barrier of their own, stored by one pass and reduced
+    by another; "after" — the step keeps the scores alone and the maxima
+    are one reduce over the stored scores behind the loop. The served
+    step (one barrier around the pair) is ``retrieval._coarse_scan``'s."""
+    def scan(q, tiles, scales, ids):
+        t = ids.size // ids.shape[0]
+        g = retrieval.tile_select_group(t, k)
+        q3 = retrieval._split_bf16(q)
+
+        def step(_, xs):
+            v, s, tid = xs if scales is not None else (xs[0], None, xs[1])
+            p = jax.lax.dot_general(
+                q3, v.astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            side = (1, *tid.shape)
+            sc = ((p[2:3] + p[1:2]) + p[:1]).reshape(side)
+            if s is not None:
+                sc = sc * s.reshape(side)
+            sc = jnp.where(tid.reshape(side) >= 0, sc, retrieval.NEG_INF)
+            groups = sc.reshape(1, t).reshape(1, t // g, g)
+            if form == "after":
+                return None, groups
+            if form == "scores_once":
+                groups = jax.lax.optimization_barrier(groups)
+            return None, (groups, groups.max(axis=2))
+
+        xs = (tiles, ids) if scales is None else (tiles, scales, ids)
+        kept = jax.lax.scan(step, None, xs)[1]
+        scores, maxima = (kept, kept.max(axis=3)) if form == "after" else kept
+        return retrieval._select_deferred(scores, maxima, ids, k)
+
+    if mode == "bf16":
+        def run(q, tiles, ids):  # the trace names it jit_run
+            return scan(q, tiles, None, ids)
+    else:
+        def run(q, tiles, scales, ids):
+            return scan(q, tiles, scales, ids)
     return jax.jit(run)
 
 
@@ -211,6 +268,7 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--traced", type=int, default=10)
     ap.add_argument("--sides", default=",".join(SIDES))
+    ap.add_argument("--steps", default=",".join(STEPS))
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default="chiprun_out/scan_alone.json")
     a = ap.parse_args(argv)
@@ -249,14 +307,21 @@ def main(argv=None) -> int:
             for sides in a.sides.split(","):
                 args = _arguments(shape, b, make, mode, sides)
                 into = row if sides == "lanes" else row.setdefault(sides, {})
-                for body in BODIES:
-                    fn = _scan(KP, body, mode).lower(*args).compile()
+                programs = [(into, body, _scan(KP, body, mode)) for body in BODIES]
+                if (sides == "lanes" and row["score_form"] == "dot"
+                        and not shape["rules"]):
+                    programs += [
+                        (row.setdefault("steps", {}), step, _single(KP, step, mode))
+                        for step in a.steps.split(",") if step
+                    ]
+                for entries, program, jitted in programs:
+                    fn = jitted.lower(*args).compile()
                     mem = fn.memory_analysis()
-                    into[body] = {"temp_mb": mem.temp_size_in_bytes / 1e6}
+                    entries[program] = {"temp_mb": mem.temp_size_in_bytes / 1e6}
                     if not a.compile_only:
                         out, timed = measure(fn, args, a.calls, a.traced)
                         outs.append(jax.device_get(out))
-                        into[body].update(timed)
+                        entries[program].update(timed)
                 del args  # one form's side arrays on the chip at a time
             if outs:
                 s0, i0 = outs[0]

@@ -1236,13 +1236,13 @@ class TestDeferredSelect:
             row[:, i * t: (i + 1) * t][:, ~guard] = retrieval.NEG_INF
         return row
 
-    def _catalog(self, mode, d, rows, seed, twin_tiles=False):
+    def _catalog(self, mode, d, rows, seed, twin_tiles=False, tile=T):
         table = _int8(rows, d, seed=seed)
         if twin_tiles:  # tile 1 repeats tile 0: every score comes twice
             vals, scales = table = tuple(np.array(a) for a in table)
-            vals[self.T: 2 * self.T] = vals[: self.T]
-            scales[self.T: 2 * self.T] = scales[: self.T]
-        return CoarseCatalog(table, tile=self.T, mode=mode)
+            vals[tile: 2 * tile] = vals[:tile]
+            scales[tile: 2 * tile] = scales[:tile]
+        return CoarseCatalog(table, tile=tile, mode=mode)
 
     # a batched case: (queries, what the single's case of that name has)
     BATCHED = {
@@ -1260,6 +1260,9 @@ class TestDeferredSelect:
         "whole_tiles", "padded_last_tile", "one_tile", "five_tiles",
         "small_category", "own_rows_excluded", "unavailable_rows",
         "equal_scores_across_tiles", *BATCHED,
+        # PR 43: what a single's one pass after its score has to keep
+        "minus_one_ids_inside_a_tile", "a_tile_of_padding",
+        "a_shortlist_of_1024",
     ])
     @pytest.mark.parametrize("d", [64, 128])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
@@ -1270,15 +1273,20 @@ class TestDeferredSelect:
         and against the whole row."""
         import jax
 
-        t, k = self.T, self.K
+        # k' = 1,024 over tiles of 2^16 rows: groups of 8, as here at 128
+        t, k = (1 << 16, 1024) if case == "a_shortlist_of_1024" else (
+            self.T, self.K
+        )
         padded = case == "sixteen_unavailable_padded"
         b, case = self.BATCHED.get(case, (1, case))
         rows = {"padded_last_tile": 2 * t + 1000, "one_tile": t,
-                "five_tiles": 5 * t}.get(case, 3 * t - 1000 * padded)
-        cat = self._catalog(mode, d, rows, seed=61,
+                "five_tiles": 5 * t, "a_shortlist_of_1024": 2 * t + 1000,
+                }.get(case, 3 * t - 1000 * padded)
+        cat = self._catalog(mode, d, rows, seed=61, tile=t,
                             twin_tiles=case == "equal_scores_across_tiles")
         nt = cat._ids.shape[0]
         assert nt == -(-rows // t) and cat.tile == t
+        assert retrieval.tile_select_group(t, k) == 8
         # the rule serves every case here but sixteen queries over
         # rank-64 int8 values (more than half of what a step reads):
         # there the deferred body is forced, as PR 33 forced its batch
@@ -1291,6 +1299,17 @@ class TestDeferredSelect:
         )
         q = _dense(b, d, seed=62)
         rules = None
+        gone = np.zeros(0, np.int64)  # rows whose stored id is made -1
+        if case == "minus_one_ids_inside_a_tile":
+            # the eight best rows and one row in 53, all over the tiles
+            first = self._scan(cat, q, k, None, "two_level")[1]
+            gone = np.union1d(first[0, :8], np.arange(5, rows, 53))
+        elif case == "a_tile_of_padding":  # its group maxima: all NEG_INF
+            gone = np.arange(t, 2 * t)
+        if len(gone):
+            ids = np.array(cat._ids)
+            ids.reshape(-1)[gone] = -1
+            cat._ids = jax.numpy.asarray(ids)
         if case == "small_category":
             small = np.random.default_rng(63).permutation(rows)[:40]
             rules = _rules(cat.stored_rows, b, small_cat=small,
@@ -1332,7 +1351,8 @@ class TestDeferredSelect:
         if case == "equal_scores_across_tiles":
             assert twins == b
         else:
-            assert twins == 0 or (b > 1 and mode == "int8_dot")
+            # (1,024 of 132,072 scores hold a pair of equals now and then)
+            assert twins == 0 or (b > 1 and mode == "int8_dot") or k == 1024
         if case == "small_category":
             for r in range(b):
                 live = int((got_i[r] >= 0).sum())
@@ -1343,8 +1363,12 @@ class TestDeferredSelect:
                 assert not set(got_i[r].tolist()) & set(first[r, :4].tolist())
         elif case == "unavailable_rows":
             assert (got_i % 97 != 0).all() and got_i.max() < rows
-        elif case == "padded_last_tile":
+        elif case in ("padded_last_tile", "a_shortlist_of_1024"):
             assert (got_i >= 0).all() and got_i.max() < rows
+        elif len(gone):
+            assert (got_i >= 0).all() and not set(got_i[0].tolist()) & set(
+                gone.tolist()
+            )
 
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
     def test_a_shortlist_too_wide_to_split_keeps_the_step_it_had(self, mode):

@@ -1,7 +1,10 @@
 """scan_alone.py (the chip measurement behind PERF.md's scan tables):
-it takes no time off a TPU, and at toy shapes its two bodies and its two
-side forms run on one input and agree — so the script cannot rot between
-the PRs that use it."""
+it takes no time off a TPU, and at toy shapes its two bodies, its two
+side forms and a single's step forms run on one input and agree — so the
+script cannot rot between the PRs that use it."""
+
+import json
+import re
 
 import jax
 import numpy as np
@@ -91,3 +94,71 @@ def test_a_side_array_holds_the_same_values_in_either_form(fill, dtype):
         np.asarray(lanes).reshape(3, T), np.asarray(flat)
     )
     assert lanes.shape == retrieval.side_shape(3, T)
+
+
+# -- a single's step forms (PR 43) ----------------------------------------------
+
+
+def _single_arguments(monkeypatch, mode):
+    monkeypatch.setattr(scan_alone, "TILE", T)
+    shape = dict(rows=2 * T + 1000, rank=64, rules=False)
+    assert retrieval.score_form(1, 64, mode) == "dot"
+    return scan_alone._arguments(shape, 1, scan_alone._device_array, mode)
+
+
+@pytest.mark.parametrize("mode", ["bf16", "int8"])
+@pytest.mark.parametrize("step", scan_alone.STEPS)
+def test_a_singles_step_forms_answer_as_the_served_step(monkeypatch, step, mode):
+    """Every candidate step on the served step's input: scores
+    bit-equal, ids equal — a padded last tile among the three."""
+    args = _single_arguments(monkeypatch, mode)
+    served = jax.device_get(scan_alone._scan(128, "deferred", mode)(*args))
+    got = jax.device_get(scan_alone._single(128, step, mode)(*args))
+    ids = _all_agree({"served": served, step: got})
+    assert 0 <= ids.min() and ids.max() < 2 * T + 1000
+
+
+@pytest.mark.parametrize("mode", ["bf16", "int8"])
+def test_the_twice_form_is_the_served_step_without_its_barrier(monkeypatch, mode):
+    """``twice`` — the parent's step — is no copy that can drift: it
+    lowers to the text of ``_coarse_scan`` itself once ``_kept_once``
+    lets the pair through untouched, and the served step differs from
+    it by one ``optimization_barrier`` in the loop."""
+    def text(jitted):  # private functions are numbered as they are traced
+        return re.sub(r"(@\w+?)_\d+\b", r"\1", jitted.lower(*args).as_text())
+
+    args = _single_arguments(monkeypatch, mode)
+    twice = text(scan_alone._single(128, "twice", mode))
+    served = text(scan_alone._scan(128, "deferred", mode))
+    assert served.count("optimization_barrier") == 1
+    assert "optimization_barrier" not in twice
+    monkeypatch.setattr(retrieval, "_kept_once", lambda kept: kept)
+    bare = text(scan_alone._scan(128, "deferred", mode))
+    assert bare == twice
+
+
+def test_the_step_axis_is_for_dot_form_singles_alone(monkeypatch, capsys):
+    """A row gets its ``steps`` where the served scan is a ``dot``-form
+    single without rules; a batch, rank 128 and ``int8_dot`` rows have
+    none (their step is not the one the forms vary)."""
+    assert scan_alone.STEPS == ("twice", "scores_once", "after")
+    seen = []
+    real = scan_alone._single
+    monkeypatch.setattr(
+        scan_alone, "_single",
+        lambda k, step, mode="bf16": seen.append((step, mode)) or real(k, step, mode),
+    )
+    monkeypatch.setattr(scan_alone, "TILE", T)
+    monkeypatch.setattr(scan_alone, "described_chip", lambda: None)
+    monkeypatch.setitem(scan_alone.SHAPES, "toy", dict(
+        rows=2 * T + 1000, rank=64, rules=False, modes=("int8", "int8_dot")))
+    monkeypatch.setitem(scan_alone.SHAPES, "toy128", dict(
+        rows=2 * T + 1000, rank=128, rules=True))
+    assert scan_alone.main(["--compile-only", "--shapes", "toy,toy128",
+                            "--batches", "1,2", "--steps", "twice,after"]) == 0
+    assert seen == [("twice", "int8"), ("after", "int8")]
+    rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+            if line.startswith("{")]
+    assert [set(r.get("steps", ())) for r in rows] == [
+        {"twice", "after"}, set(), set(), set(), set(), set(),
+    ]

@@ -154,6 +154,26 @@ class TestShardedChain:
         assert loop_sorts(2) == 0
         assert loop_sorts(16) == 3
 
+    @pytest.mark.parametrize("batch,barriers", [(1, 1), (2, 0)])
+    def test_the_four_chip_chain_runs_the_one_chip_step(self, mesh4, batch, barriers):
+        """Each chip of the sharded chain runs ``_coarse_scan``'s own
+        step: a single's scores and their group maxima are the two
+        results of one computation (one ``optimization_barrier`` in the
+        shard's loop, PR 43), a pair's ride its dot as they did."""
+        nt, t, d = 2, 8192, 16
+        jaxpr = jax.make_jaxpr(
+            lambda *a: shard_topk._sharded_topk(
+                *a, r=nt * t, kp=128, k=16, mode="bf16", mesh=mesh4, axis="data",
+            )
+        )(
+            jax.ShapeDtypeStruct((batch, d), np.float32),
+            jax.ShapeDtypeStruct((4 * nt * t, d), np.float32),
+            jax.ShapeDtypeStruct((4 * nt, t, d), jax.numpy.bfloat16),
+            jax.ShapeDtypeStruct((4 * nt, t // 128, 128), np.int32),
+        )
+        assert retrieval.score_form(batch, d) == ("dot" if batch == 1 else "rows")
+        assert str(jaxpr).count("optimization_barrier") == barriers
+
     def test_eight_shards(self, mesh8, two_stage):
         U, V = _tables(9000, seed=2)
         s, ids = _served(ShardedCatalog(V, mesh8), U, [3, 4, 5], len(V), 8)
