@@ -10,8 +10,9 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
 1. **Coarse shortlist** — score the catalog in its low-precision
    storage form *without materializing a dequantized f32 copy*, tiled
    so neither the [B, I] score matrix nor a full-catalog top-k ever
-   exists: a ``lax.scan`` over ``[NT, T, D]`` tiles keeps a running
-   per-query top-k' merge (working set [B, k' + T]). int8 catalogs
+   is selected from whole: a ``lax.scan`` over ``[NT, T, D]`` tiles
+   scores a tile a step and keeps the scores and their group maxima,
+   and one selection after the loop picks the k' best. int8 catalogs
    score as ``(q @ values^T) * scale`` (the per-row scale factors out
    of the within-row dot and multiplies back scalar-per-column);
    ``int8_dot`` additionally quantizes the queries and accumulates in
@@ -20,23 +21,22 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    PR 41); dense catalogs carry a bf16 coarse copy. On a mesh each
    device runs this same scan over the rows it holds
    (parallel/shard_topk.py: stationary shards, the query replicated).
-   A scan step never selects over its whole tile where the tile is
-   large: it takes the maximum of each group of G scores, the k' best
-   groups, and the k' best of those groups' scores — exactly the
-   tile's top-k', from selections over T/G + k'G elements instead of T
-   (``tile_select_group`` decides from T and k' alone; every mode and
-   the masked scan share ``_tile_top_k``). Where the stored scores fit
-   the step does not select at all (``scan_select`` decides from B, NT,
-   T, k', D and the storage mode alone): it scores the tile and writes
-   the [B, T] scores and their [B, T/G] group maxima to the scan's
-   stacked outputs, and the k' best come once, after the loop, from the
-   same two levels over ALL tiles' groups (``_select_deferred``) — the
-   same shortlist, its values bit-equal; a batch whose scores would
-   outweigh half the coarse tiles (more than 16 queries at rank 64 in
-   bf16) keeps the per-tile selection and the running merge. And a
-   step scores its tile in one of two forms (``score_form`` decides
-   from B and D alone): the batch's f32 rows against the tile cast to
-   f32 ("rows": two or more queries, or D >= 128), or — ONE query
+   A step selects nothing: it scores the tile, scales, guards, masks
+   and writes the [B, T] scores and the maximum of each group of G of
+   them to the scan's stacked outputs, and the k' best come once, after
+   the loop, in two exact levels over ALL tiles' groups (``_select``):
+   the k' best groups by maximum, then the k' best of those groups'
+   scores — exactly the catalog's top-k', from selections over
+   NT x T/G + k'G elements instead of NT x T (``select_group`` decides
+   G from NT, T and k' alone; a catalog too small to split is one
+   ``lax.top_k`` of the stored row). The stored scores are a call's
+   one large temporary, so a pass takes at most as many queries as
+   store half the coarse tiles' bytes (``scan_chunk``: 16 at rank 64 in
+   bf16) or 604 MB, whichever is more, and a larger batch is scanned a
+   chunk at a time inside the one program. A step scores its tile in
+   one of two forms (``score_form`` decides from B and D alone): the
+   batch's f32 rows against the tile cast to f32 ("rows": two or more
+   queries, or D >= 128), or — ONE query
    whose rank leaves the 128 lanes unfilled — the query split into
    three bf16 rows whose sum it is, one dot against the tile as stored,
    the three partial scores added back ("dot": the same f32 product to
@@ -108,11 +108,8 @@ non-default layout reports the default one (jax 0.9.0, v5e), so the
 next program is compiled for a layout the buffer does not have.)
 
 Observability: ``pio_retrieval_*`` metrics (docs/observability.md);
-``pio_retrieval_tile_select_total{path}`` says which selection a
-shortlist call's program holds (``deferred`` / ``two_level`` /
-``plain``) and
-``pio_retrieval_score_form_total{form}`` which score (``dot`` /
-``rows``); each
+``pio_retrieval_score_form_total{form}`` says which score a shortlist
+call's program holds (``dot`` / ``rows``); each
 rescore program publishes the temporary bytes its compiled form needs
 (``pio_retrieval_rescore_temp_bytes``: a table-sized number means a
 re-layout came back); the
@@ -126,11 +123,9 @@ the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
 one a dispatch through ``top_k``, and ``pio_retrieval_uploads_total``
 the transfers the other way: one a dispatch under rules — ``pack``'s
 buffer — two for ``UserRows``), and the two serving programs
-carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a deferred
-step is ``score`` / ``mask`` / ``group_max`` and ``select`` follows
-the loop; a per-tile one is ``score`` / ``mask`` / ``tile_topk`` /
-``merge`` — and ``retrieval.rescore.*``) that name their ops in a
-trace viewer.
+carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a step is
+``score`` / ``mask`` / ``group_max`` and ``select`` follows the loop —
+and ``retrieval.rescore.*``) that name their ops in a trace viewer.
 """
 
 from __future__ import annotations
@@ -312,18 +307,6 @@ _m_probes = obs_metrics.counter(
     "pio_retrieval_probes_total", "live recall probes run",
 )
 
-_m_tile_select = {
-    path: obs_metrics.counter(
-        "pio_retrieval_tile_select_total",
-        "shortlist calls by where the scan selects its k' best: deferred = "
-        "once, after the tile loop (while the stored scores are at most "
-        "half the coarse tiles' bytes); two_level / plain = every step "
-        "its tile's, by group maxima or one top_k",
-        path=path,
-    )
-    for path in ("deferred", "two_level", "plain")
-}
-
 _m_score_form = {
     form: obs_metrics.counter(
         "pio_retrieval_score_form_total",
@@ -380,13 +363,12 @@ def set_resident(**parts) -> None:
 _probe_clock = itertools.count(1)
 
 
-def _count_scan(b: int, nt: int, t: int, k: int, d: int, mode: str) -> None:
-    """One shortlist call, by the two rules its program was traced
-    with — where it selects (``scan_select``) and how it scores
-    (``score_form``) — and by the catalog's coarse mode."""
+def _count_scan(b: int, k: int, d: int, mode: str, rows: int) -> None:
+    """One shortlist call, by how its program scores (``score_form`` of
+    the queries a pass over its ``rows`` stored rows takes) and by the
+    catalog's coarse mode."""
     _m_shortlist_size.observe(float(k))
-    _m_tile_select[scan_select(b, nt, t, k, d, mode)].inc()
-    _m_score_form[score_form(b, d, mode)].inc()
+    _m_score_form[score_form(scan_chunk(b, d, mode, rows), d, mode)].inc()
     _m_coarse_mode[mode].inc()
 
 
@@ -428,7 +410,6 @@ def stats_block() -> dict:
         "fetch_seconds": _m_fetch_secs.summary(),
         "host_reads": _m_host_reads.value(),
         "uploads": _m_uploads.value(),
-        "tile_select": {p: m.value() for p, m in _m_tile_select.items()},
         "score_form": {f: m.value() for f, m in _m_score_form.items()},
         "coarse_mode": {c: m.value() for c, m in _m_coarse_mode.items()},
         "resident_bytes": {p: m.value() for p, m in _m_resident.items()},
@@ -444,40 +425,41 @@ def stats_block() -> dict:
 # -- coarse shortlist kernel -------------------------------------------------
 
 
-# A [B, T] tile's k' best in two exact levels: the T scores of a row in
-# T/G groups of G, each group's maximum (the one pass that still touches
-# all T values), ``top_k`` of the [B, T/G] maxima, the chosen groups'
+# The k' best of a row of N scores in two exact levels: the row in N/G
+# groups of G, each group's maximum (the one pass that touches all N
+# values), ``top_k`` of the [B, N/G] maxima, the chosen groups'
 # [B, k', G] scores read out of the same array, and the k' best of those
 # [B, k'G] candidates. An element among the k' largest has fewer than k'
 # elements above it, and every group whose maximum exceeds its own
 # group's holds one of them: its group is among the k' best by maximum.
 # On a TPU v5e a ``top_k`` of 262,144 scores with k' = 128 is 115 us at
 # B = 1 (two sorts) and 1,620 us at B = 16 (the ``TopK`` custom call)
-# against 92 / 46 us to score the tile; a sort of [B, 2048] is 6 / 14 us
-# and one of 1,024 costs hardly less (PERF.md section 6, PR 27). So
-# nothing under _MIN_SPLIT is split, a group is a 128-lane row of the
-# tile where that pays (the reshape then follows the tile's own layout),
-# and the candidates go through the same helper again: a 2^18 tile at
-# k' = 128 sorts [B, 2048] maxima, then its [B, 16384] candidates as
-# [B, 1024] maxima and [B, 2048] candidates.
+# against 92 / 46 us to score as many rows; a sort of [B, 2048] is
+# 6 / 14 us and one of 1,024 costs hardly less (PERF.md section 6,
+# PR 27). So nothing under _MIN_SPLIT is split, a group is a row of 128
+# lanes where that pays (the reshape then follows the array's own
+# layout), and the candidates go through the same helper again: 2^18
+# scores at k' = 128 sort [B, 2048] maxima, then their [B, 16384]
+# candidates as [B, 1024] maxima and [B, 2048] candidates.
 _MIN_SPLIT = 1 << 13
 
 
-def tile_select_group(t: int, k: int) -> int:
+def select_group(t: int, k: int, nt: int = 1) -> int:
     """Group width G of the two-level selection of the k' = ``k`` best
-    of T = ``t`` scores, or 0 where the plain ``lax.top_k`` stays: T
-    under _MIN_SPLIT (the tiles of the CPU tests) or no whole number of
-    groups, fewer groups than k', or the two selections' T/G + k'G
-    elements more than a quarter of T (a k' that nears the tile). G is
-    a row of 128 lanes where that passes, else the power of two at or
-    above sqrt(T/k'), which balances the two. Decided from the two
-    shapes alone: at trace time, and on the host for the counter
-    (``scan_select``, which also says WHERE the selection runs: in
-    every step, or — one query — once after the loop, with this G)."""
-    if t < _MIN_SPLIT:
+    of a row of N = ``nt`` x ``t`` scores that lies in ``nt`` pieces of
+    ``t`` (a scan's tiles; one piece: any [B, T] array), or 0 where one
+    ``lax.top_k`` of the row stays: N under _MIN_SPLIT (the catalogs of
+    the CPU tests), no whole number of groups a piece, fewer groups
+    than k', or the two selections' N/G + k'G elements more than a
+    quarter of N (a k' that nears the row). G is a row of 128 lanes
+    where that passes, else the power of two at or above sqrt(N/k'),
+    which balances the two. Decided from the shapes alone, at trace
+    time."""
+    n = nt * t
+    if n < _MIN_SPLIT:
         return 0
-    for g in (_LANES, _pow2(int(np.ceil(np.sqrt(t / k))))):
-        if t % g == 0 and t // g >= k and 4 * (t // g + k * g) <= t:
+    for g in (_LANES, _pow2(int(np.ceil(np.sqrt(n / k))))):
+        if t % g == 0 and n // g >= k and 4 * (n // g + k * g) <= n:
             return g
     return 0
 
@@ -513,84 +495,82 @@ def _best_of_groups(cand, gix):
 
 
 def _tile_top_k(sc, k: int):
-    """The k best of each row of a [B, T] array of coarse scores, and
-    their positions in it."""
-    g = tile_select_group(sc.shape[1], k)
+    """The k best of each row of a [B, T] array of scores, and their
+    positions in it."""
+    g = select_group(sc.shape[1], k)
     if not g:
         return jax.lax.top_k(sc, k)
     return _two_level_top_k(sc, k, g)
 
 
-# A scan selects once, after the tile loop. A step's selection is thrown
-# away almost whole — of the 36 x 128 candidates that a 36-tile scan
-# sorts, merges and looks up ids for, 128 a query survive — and it was a
-# fifth to a quarter of a single's loop and over two fifths of a batch
-# of 8 or 16's (PERF.md section 6, PR 33 and PR 36). The argument above
-# ``_MIN_SPLIT`` never needed the tile: read "catalog" for "tile" and the
-# k' groups with the largest maxima among ALL tiles' groups hold the
-# catalog's k' best. So a step only scores its tile and writes the
-# [B, T] scores and their [B, T/G] group maxima to the scan's stacked
-# outputs, and ``_select_deferred`` picks once: the k' best groups of
-# NT x T/G maxima, their [B, k'G] scores read back out of the stored
-# array, the k' best of those, B x k' ids looked up.
+# A scan step scores, scales, guards, masks and KEEPS: its tile's [B, T]
+# scores and their [B, T/G] group maxima go to the scan's stacked
+# outputs, and the k' best come from ONE selection after the loop
+# (``_select``). A selection in the step is thrown away almost whole —
+# of the 36 x 128 candidates a query that a 36-tile scan would sort,
+# merge and look up ids for, 128 survive — and cost a fifth to two
+# fifths of the loop (PERF.md section 6, PR 33 and PR 36). The argument
+# above ``_MIN_SPLIT`` asks nothing of a tile, so G is ``select_group``
+# of the CATALOG's NT x T; where that splits nothing a step keeps the
+# scores alone and the selection is one ``lax.top_k`` of the stored row.
 #
-# What that costs is memory: the stored scores are B x NT x T x 4 bytes a
-# call (38 MB for one query over 36 tiles of 2^18 rows, 604 MB for 16;
-# from B = 4 a temporary in the chip's main memory, written 8 or 16 MB a
-# step at ~600 GB/s), on top of everything resident, for the length of
-# the call. On a TPU v5e the deferred body is the faster one at every
-# batch measured, B = 1 .. 64 (the scan 1.4x shorter at B = 1 .. 4, 1.7x
-# at 8 and 16, 1.8 .. 1.9x at 32 and 64: PERF.md section 6, PR 36), so
-# the bound is not about speed. The bound: A CALL'S STORED SCORES MAY BE
-# AT MOST HALF THE COARSE TILES THEY SCORE, B x 4 <= D x itemsize / 2 —
-# 16 queries over a rank-64 bf16 copy, 32 over rank 128, 8 over rank-64
-# int8: a temporary that scales with what the deployment already keeps
-# resident for this scan (0.6 of 1.2 GB on 9.39 M x 64), whatever the
-# catalog's size, and covers every batch a benchmark cell dispatches. A
-# batch beyond it (``_MicroBatcher`` collects up to 64: 2.4 GB of scores
-# at rank 64) keeps the selection in the step and the running merge, as
-# every batch did before PR 36; widening the bound wants a cell that
-# sends such batches (PERF.md section 7).
+# Keeping costs memory: B x NT x T x 4 bytes a call (38 MB for one query
+# over 36 tiles of 2^18 rows, 604 MB for 16), on top of everything
+# resident. So a pass stores at most half the bytes of the coarse tiles
+# it scores, B x 4 <= D x itemsize / 2 — a temporary that scales with
+# what the deployment keeps resident for this scan — or ``_UNCUT``
+# bytes, whichever is more: a low rank makes the tiles small, not the
+# scores large (at the ALS templates' default rank 10 half the tiles is
+# two queries' scores, one over int8 values), and the 604 MB that every
+# run of yambda's cells has stored is a temporary no catalog is cut
+# under. A batch beyond that (``_MicroBatcher`` collects up to 64) is
+# scanned a chunk at a time inside the one program: slower than one
+# pass that stored everything (9.8 against 6.2 ms for 32 queries at
+# rank 64, with 1.2 GB) and faster than a step that selects and stores
+# nothing (12.4: PERF.md section 6, PR 44). A loop a chunk, not a loop
+# of loops: under an outer ``while`` XLA:TPU copies the resident tiles
+# into the loop's state (3.0 GB of temporaries where the unrolled
+# chunks reuse one chunk's 605 MB).
+_UNCUT = 16 * 36 * 4 << 18  # 16 queries' scores of 36 tiles of 2^18 rows
 
 
-def _stored_scores_fit(b: int, d: int, mode: str) -> bool:
-    """The bound above: ``b`` queries' f32 scores of a row against
-    half the row's ``d`` stored coarse values (bf16, or int8 in both
-    int8 modes)."""
-    return 8 * b <= d * (2 if mode == "bf16" else 1)
+def scan_chunk(b: int, d: int, mode: str, rows: int) -> int:
+    """How many of ``b`` queries of rank ``d`` one pass over ``rows``
+    stored rows scores: all of them where their f32 scores weigh at
+    most half the rows' stored coarse values (bf16, or int8 in both int8
+    modes: 16 queries at rank 64 in bf16, 32 at rank 128, 8 over
+    rank-64 int8) or at most ``_UNCUT`` bytes (64 queries of any rank
+    over 2.3 M rows, 16 over 9.4 M), else the largest power of two
+    within the larger of the two bounds — at least one query; a batch
+    that is no multiple of it (no served caller sends one) ends in a
+    pass of what is left. Decided from the shapes alone: at trace time,
+    and on the host for the counters."""
+    bound = max(d * (2 if mode == "bf16" else 1) // 8, _UNCUT // (4 * rows), 1)
+    return b if b <= bound else 1 << (bound.bit_length() - 1)
 
 
-def scan_select(b: int, nt: int, t: int, k: int, d: int,
-                mode: str = "bf16") -> str:
-    """Where a scan of ``nt`` tiles of ``t`` rows of rank ``d`` for
-    ``b`` queries selects its k' = ``k`` best: "deferred" — once, after
-    the loop, from the stored scores by their group maxima — where the
-    tile splits into groups (``tile_select_group`` leaves every tile at
-    least k' of them, so the ``nt`` tiles have k' groups to pick from
-    whatever ``nt`` is) and the stored scores fit
-    (``_stored_scores_fit``: at most half the coarse tiles' bytes);
-    "two_level" — every step its own tile's, merged into a running best
-    — for a batch beyond that bound; "plain" — one ``lax.top_k`` a step
-    — where ``tile_select_group`` splits nothing. Decided from the
-    shapes alone: at trace time, and on the host for the counter."""
-    if not tile_select_group(t, k):
-        return "plain"
-    return "deferred" if _stored_scores_fit(b, d, mode) else "two_level"
-
-
-def _select_deferred(scores, maxima, ids, k: int):
-    """The k best of each query over ALL tiles: [NT, B, T/G, G] stored
-    scores, their [NT, B, T/G] group maxima and the row ids as stored
-    (``side_shape``) -> ([B, k] scores, [B, k] ids).
-    ``_two_level_top_k`` with the catalog in the tile's place; the
-    values are read, not recomputed."""
-    nt, b, per, g = scores.shape
-    mx = maxima.transpose(1, 0, 2).reshape(b, nt * per)
-    _, gix = _tile_top_k(mx, k)  # groups, numbered tile-major
-    cand = scores[gix // per, jnp.arange(b)[:, None], gix % per]
-    best_s, pos = _best_of_groups(cand, gix)  # rows of the stored catalog
-    row = jnp.unravel_index(pos % (per * g), ids.shape[1:])
-    return best_s, ids[(pos // (per * g), *row)]
+def _select(scores, maxima, ids, k: int):
+    """The k best of each query over ALL tiles, from what the steps
+    kept: [NT, B, T/G, G] scores and their [NT, B, T/G] group maxima —
+    ``_two_level_top_k`` with the catalog in the tile's place, the
+    values read, not recomputed — or, ``maxima`` None, [NT, B, T]
+    scores and one ``lax.top_k`` of the row; and the row ids as stored
+    (``side_shape``) -> ([B, k] scores, [B, k] ids)."""
+    nt, b = scores.shape[:2]
+    if maxima is None:
+        t = scores.shape[2]
+        best_s, pos = jax.lax.top_k(
+            scores.transpose(1, 0, 2).reshape(b, nt * t), k
+        )
+    else:
+        per, g = scores.shape[2:]
+        t = per * g
+        mx = maxima.transpose(1, 0, 2).reshape(b, nt * per)
+        _, gix = _tile_top_k(mx, k)  # groups, numbered tile-major
+        cand = scores[gix // per, jnp.arange(b)[:, None], gix % per]
+        best_s, pos = _best_of_groups(cand, gix)  # rows of the stored catalog
+    row = jnp.unravel_index(pos % t, ids.shape[1:])
+    return best_s, ids[(pos // t, *row)]
 
 
 # One query against a [T, D] tile: XLA:TPU turns the one-row product
@@ -644,7 +624,7 @@ def _split_bf16(q):
 
 
 def _kept_once(kept):
-    """A deferred step's ``(scores, group maxima)`` as the two results
+    """A single's step's ``(scores, group maxima)`` as the two results
     of one computation. Both are functions of the step's scaled and
     guarded scores, and where nothing says otherwise XLA:TPU computes
     those twice — once inside the fusion that stores the scores and
@@ -657,29 +637,22 @@ def _kept_once(kept):
     return jax.lax.optimization_barrier(kept)
 
 
-def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
-                 select: str | None = None):
+def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None):
     """Tiled coarse top-k' over a [NT, T, D] catalog: one scan step per
     tile scores [B, T] in the catalog's storage precision, multiplies
-    an int8 pair's row scales back and guards the padding (id -1 ->
-    NEG_INF) — the [B, I] score matrix and a full-catalog top-k never
-    materialize. Where the stored scores fit (``scan_select`` ->
-    "deferred": every batch a benchmark cell dispatches) that is all a
-    step does: it keeps the tile's scores and their group maxima, and
-    the k' best come from ONE selection over all tiles after the loop
-    (``_select_deferred``). A batch beyond the bound selects in the
-    step — its tile's top-k', merged into a running best — the same
-    shortlist, its values bit-equal. ``select`` overrides the rule: for
-    the tests and measurements that compare the bodies on one input;
-    nothing served passes it.
+    an int8 pair's row scales back, guards the padding (id -1 ->
+    NEG_INF) and keeps the tile's scores and their group maxima; the k'
+    best come from ONE selection over all tiles after the loop
+    (``_select``) — the [B, I] score matrix is never selected from
+    whole, and a batch beyond ``scan_chunk`` is that loop and selection
+    a chunk of the queries at a time, in this one program.
 
     ``ids`` and ``scales`` are [NT, ...] arrays of T values a tile, taken
     as they lie: a catalog stores them ``side_shape`` ([NT, T/128, 128]:
     a step's slice is one dense block of the chip's memory), and the
-    same values [NT, T] give the same answer bit for bit (the tests'
-    and ``scan_alone.py``'s comparison; a row of [NT, T] is a sublane
-    of every memory tile and costs a step 11–18 us a side array where a
-    dense block costs 2).
+    same values [NT, T] give the same answer bit for bit (a row of
+    [NT, T] is a sublane of every memory tile and costs a step 11–18 us
+    a side array where a dense block costs 2).
 
     ``mode``: "int8" (values*scale columns, f32 GEMM on cast values),
     "int8_dot" (int8 x int8 -> int32 accumulation, quantized queries —
@@ -687,18 +660,39 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
     within-row ranking), or "bf16" (scales is None).
 
     ``rules`` (ops/topk.py ``Rules`` over the NT * T stored rows): rows
-    a query may not be served score NEG_INF BEFORE the tile's top-k and
-    come out as id -1, so exclusions cost no headroom in k. The queries'
-    own lists are scattered once per call into a [NT, B, T] mask that
-    the scan slices like the tiles. Without ``rules`` the program is the
-    one it was before rules existed, op for op."""
+    a query may not be served score NEG_INF BEFORE the selection and
+    come out as id -1, so exclusions cost no headroom in k. A chunk's
+    own lists are scattered once into a [NT, B, T] mask that the scan
+    slices like the tiles. Without ``rules`` the program is the one it
+    was before rules existed, op for op."""
+    B = q.shape[0]
+    c = scan_chunk(B, q.shape[1], mode, ids.size)
+    if c == B:
+        return _scan_pass(q, tiles, scales, ids, k, mode, rules)
+    chunks = [
+        _scan_pass(q[lo: lo + c], tiles, scales, ids, k, mode,
+                   _query_rows(rules, lo, lo + c))
+        for lo in range(0, B, c)
+    ]
+    return tuple(jnp.concatenate(part) for part in zip(*chunks))
+
+
+def _query_rows(rules, lo: int, hi: int):
+    """``rules`` (or None) for the queries ``lo`` .. ``hi`` of its
+    batch: the per-query parts cut, the catalog's vectors shared."""
+    return rules and rules._replace(
+        qcat=rules.qcat[lo:hi], has_cat=rules.has_cat[lo:hi],
+        ex=rules.ex[lo:hi],
+    )
+
+
+def _scan_pass(q, tiles, scales, ids, k: int, mode: str, rules):
+    """``_coarse_scan`` for queries within ``scan_chunk``: the tile loop
+    and the selection after it."""
     B = q.shape[0]
     dot = score_form(B, q.shape[1], mode) == "dot"
     nt, t = ids.shape[0], ids.size // ids.shape[0]
-    deferred = (
-        select or scan_select(B, nt, t, k, q.shape[1], mode)
-    ) == "deferred"
-    g = tile_select_group(t, k)  # a deferred step keeps a maximum a group
+    g = select_group(t, k, nt)  # a step keeps a maximum a group
     if rules is not None:
         with jax.named_scope("retrieval.shortlist.mask"):
             ex = jnp.where(rules.ex >= 0, rules.ex, nt * t)  # pads drop
@@ -719,7 +713,7 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
     elif dot:
         q3 = _split_bf16(q)
 
-    def step(carry, xs):
+    def step(_, xs):
         if rules is not None:
             xs, (av, cs, ht) = xs
         if scales is None:
@@ -761,42 +755,22 @@ def _coarse_scan(q, tiles, scales, ids, k: int, mode: str, rules=None,
             with jax.named_scope("retrieval.shortlist.mask"):
                 ok = rows_allowed(av, cs, ht, rules.qcat, rules.has_cat)
                 sc = jnp.where(ok, sc, NEG_INF)
-        if deferred:
-            with jax.named_scope("retrieval.shortlist.group_max"):
-                groups = sc.reshape(B, t // g, g)
-                kept = groups, groups.max(axis=2)
-                # (a batch's maxima ride its dot: nothing to keep once)
-                return None, (_kept_once(kept) if dot else kept)
-        with jax.named_scope("retrieval.shortlist.tile_topk"):
-            ts, tix = _tile_top_k(sc, k)
-            ti = jnp.take_along_axis(
-                jnp.broadcast_to(tid.reshape(1, t), sc.shape), tix, axis=1
-            )
-            if rules is not None:
-                ti = jnp.where(ts > NEG_INF / 2, ti, -1)
-        with jax.named_scope("retrieval.shortlist.merge"):
-            best_s, best_i = carry
-            cs = jnp.concatenate([best_s, ts], axis=1)
-            ci = jnp.concatenate([best_i, ti], axis=1)
-            best_s, ix = jax.lax.top_k(cs, k)
-            best_i = jnp.take_along_axis(ci, ix, axis=1)
-        return (best_s, best_i), None
+        if not g:
+            return None, (sc, None)
+        with jax.named_scope("retrieval.shortlist.group_max"):
+            groups = sc.reshape(B, t // g, g)
+            kept = groups, groups.max(axis=2)
+            # (a batch's maxima ride its dot: nothing to keep once)
+            return None, (_kept_once(kept) if dot else kept)
 
     xs = (tiles, ids) if scales is None else (tiles, scales, ids)
     if rules is not None:
         xs = (xs, masks)
-    if deferred:
-        _, (scores, maxima) = jax.lax.scan(step, None, xs)
-        with jax.named_scope("retrieval.shortlist.select"):
-            best_s, best_i = _select_deferred(scores, maxima, ids, k)
-            if rules is not None:
-                best_i = jnp.where(best_s > NEG_INF / 2, best_i, -1)
-        return best_s, best_i
-    init = (
-        jnp.full((B, k), NEG_INF, jnp.float32),
-        jnp.full((B, k), -1, jnp.int32),
-    )
-    (best_s, best_i), _ = jax.lax.scan(step, init, xs)
+    _, (scores, maxima) = jax.lax.scan(step, None, xs)
+    with jax.named_scope("retrieval.shortlist.select"):
+        best_s, best_i = _select(scores, maxima, ids, k)
+        if rules is not None:
+            best_i = jnp.where(best_s > NEG_INF / 2, best_i, -1)
     return best_s, best_i
 
 
@@ -1107,8 +1081,7 @@ class CoarseCatalog:
                     q, self._tiles, self._scales, self._ids, rules, k,
                     self.mode, layout,
                 )
-        _count_scan(len(q), self._ids.shape[0], self.tile, k, self.dim,
-                    self.mode)
+        _count_scan(len(q), k, self.dim, self.mode, self.stored_rows)
         return Scan(q, s, ids, layout)
 
     def shortlist(self, queries, k: int, rules: Rules | None = None):
@@ -1560,8 +1533,8 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
             _m_exact.inc(n)
         return s, ids
     _m_sharded.inc(n)
-    _count_scan(len(q), catalog.tiles_per_shard, catalog.tile,
-                min(kp, catalog.tile), catalog.dim, catalog.mode)
+    _count_scan(len(q), min(kp, catalog.tile), catalog.dim, catalog.mode,
+                catalog.tiles_per_shard * catalog.tile)
     probe(
         ids[0, :probe_n],
         lambda: _fetch(catalog.launch_exact(q[:1], k), 1)[1][0, :probe_n],

@@ -16,10 +16,10 @@ the item rows stand still and the query travels:
   64) and runs ONE program under ``shard_map``: each device scans its
   own tiles with the one-chip scan (``retrieval._coarse_scan``, not a
   copy — so a step only scores its tile and keeps the scores and their
-  group maxima, and each device selects its k' best once, after its
-  own loop, as ``retrieval.scan_select`` says from the local shapes;
-  a batch whose stored scores would outweigh half a shard's tiles
-  selects in every step, as on one chip), rescores its own shortlist
+  group maxima, each device selects its k' best once, after its own
+  loop, and a batch whose stored scores would outweigh half a shard's
+  tiles, and 604 MB, is scanned in chunks, ``retrieval.scan_chunk``, as
+  on one chip), rescores its own shortlist
   against its own f32 rows (``retrieval._score_candidates``,
   ``precision=HIGHEST``), keeps its k best, and one ``all_gather`` of
   [B, k] scores and ids — ``shards x B x k x 8`` bytes — feeds the

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
-"""scan_alone.py — the coarse scan alone, on the chip: both places a
-scan can select its k' best, both forms its per-row side arrays can lie
-in, and the forms of a single's step after its score, on one input, at
-the benchmark's shapes.
+"""scan_alone.py — the coarse scan alone, on the chip: both forms its
+per-row side arrays can lie in, and the forms of a single's step after
+its score, on one input, at the benchmark's shapes and at five of a
+lower rank that no cell sends.
 
     chiprun -- python3 scan_alone.py                     # every shape, B = 1 .. 64
     chiprun -- python3 scan_alone.py --shapes retrieval-yambda --batches 8,16
@@ -10,32 +10,34 @@ the benchmark's shapes.
     chiprun -- python3 scan_alone.py --batches 1 --steps twice,after
     python3 scan_alone.py --compile-only                 # here: no chip needed
 
-For each shape and batch size it jits ``ops.retrieval._coarse_scan`` once a
-body — ``select="deferred"`` (one selection after the tile loop) and
-``select="two_level"`` (every step its tile's, merged) — and a side form —
-``lanes`` (the row ids and an int8 pair's row scales as a catalog stores
-them, ``retrieval.side_shape``: [NT, T/128, 128]) and ``flat`` ([NT, T],
-as they were stored before PR 42: the same values, the scan takes either)
-— and reports, for each: ``temp_mb`` (``memory_analysis()`` temporaries of
-the compiled program),
+For each shape and batch size it jits ``ops.retrieval._coarse_scan``
+once a side form — ``lanes`` (the row ids and an int8 pair's row scales
+as a catalog stores them, ``retrieval.side_shape``: [NT, T/128, 128]) and
+``flat`` ([NT, T], as they were stored before PR 42: the same values, the
+scan takes either) — and reports, for each: ``temp_mb``
+(``memory_analysis()`` temporaries of the compiled program),
 ``wall_ms`` (median host time of a call that ends in ``block_until_ready``),
 ``device_ms`` (the program's mean device time in a profiler trace) and
-``step_us`` (the loop's operations, device us a tile, largest first). It
-says which body the rule serves (``scan_select``) and holds all answers
-against the first: scores bit-equal, ids equal. A row's ``two_level`` /
-``deferred`` entries are the ``lanes`` form's; the ``flat`` form's are
-under ``flat``. Where the served scan is a ``dot``-form single (one query
-under rank 128, no rules) the row also runs that step's other forms
-(``--steps``, under ``steps``; ``_single`` says what each is): ``twice`` is
-the step of PR 42 — the served program less its one barrier, text for
-text — and the served step is the row's ``deferred``.
+``step_us`` (the program's operations, device us a tile, largest first:
+a chunk's loop has its own, and what runs once a chunk — the fill of its
+stored scores, its selection — is among them, divided by NT). It
+says how many chunks of the queries the program scans one after another
+(``chunks``: ``retrieval.scan_chunk``), gives a checksum of the answer
+(``scores_crc`` / ``ids_crc``: the same input on another commit gives the
+same) and holds all answers of a row against the first: scores bit-equal,
+ids equal. A row's ``scan`` entry is the ``lanes`` form's; the ``flat``
+form's is under ``flat``. Where the scan is a ``dot``-form single (one
+query under rank 128, no rules) the row also runs that step's other forms
+(``--steps``, under ``steps``; ``_single`` says what each is): ``twice``
+is the step of PR 42 — the served program less its one barrier, text for
+text — and the served step is the row's ``scan``.
 
 ``--compile-only`` compiles for a DESCRIBED v5e and prints the temporaries
 alone: nothing runs, so it gives no time. With a chip, a platform other
 than ``tpu`` is refused: a CPU's time is nobody's number.
 
 This is the program behind the tables of PERF.md section 6 (PR 33, PR 36,
-PR 41, PR 42, PR 43).
+PR 41, PR 42, PR 43, PR 44).
 No benchmark cell runs it; results go to ``chiprun_out/scan_alone.json``.
 """
 
@@ -64,7 +66,12 @@ from xplane import newest_xplane, op_label  # noqa: E402
 
 TILE, KP = 1 << 18, 128  # the cells' tile and k' (num 10 -> 8 x 16)
 
-# what ONE chip scans in each of the benchmark's five configurations
+# what ONE chip scans in each of the benchmark's five configurations, and
+# below them what no cell sends: the ALS templates' default rank 10 and
+# bench.py's rank 32 (its ``retrieval`` section, int8), over bench.py's
+# 1 M rows — stored scores that ``retrieval.scan_chunk`` never cuts —
+# over yambda's 9.39 M, where a pass takes 16 queries, and rank 10 over
+# the int8 cell's 48.19 M, where it takes two
 SHAPES = {
     "retrieval-yambda": dict(rows=9_390_000, rank=64, rules=False),
     "ecommerce-taobao": dict(rows=4_162_024, rank=128, rules=True),
@@ -73,31 +80,33 @@ SHAPES = {
     # the whole catalog stored int8 on ONE chip: both int8 coarse modes
     "recommendation-amazon23-int8": dict(rows=48_190_000, rank=64, rules=False,
                                          modes=("int8", "int8_dot")),
+    "rank10-1m": dict(rows=1_000_000, rank=10, rules=False),
+    "rank10-9m": dict(rows=9_390_000, rank=10, rules=False),
+    "rank32-int8-1m": dict(rows=1_000_000, rank=32, rules=False,
+                           modes=("int8",)),
+    "rank32-int8-9m": dict(rows=9_390_000, rank=32, rules=False,
+                           modes=("int8",)),
+    "rank10-48m": dict(rows=48_190_000, rank=10, rules=False),
 }
-BODIES = ("two_level", "deferred")
 SIDES = ("lanes", "flat")
-# what a single's deferred step does after its score (``score_form`` "dot"
-# rows alone), beside the served step, which is the row's ``deferred``
+# what a single's step does after its score (``score_form`` "dot" rows
+# alone), beside the served step, which is the row's ``scan``
 STEPS = ("twice", "scores_once", "after")
 
 
-def _scan(k, select, mode="bf16"):
+def _scan(k, mode="bf16"):
     if mode == "bf16":
         def run(q, tiles, ids, rules=None):  # the trace names it jit_run
-            return retrieval._coarse_scan(
-                q, tiles, None, ids, k, mode, rules, select=select
-            )
+            return retrieval._coarse_scan(q, tiles, None, ids, k, mode, rules)
     else:
         def run(q, tiles, scales, ids):
-            return retrieval._coarse_scan(
-                q, tiles, scales, ids, k, mode, select=select
-            )
+            return retrieval._coarse_scan(q, tiles, scales, ids, k, mode)
     return jax.jit(run)
 
 
 def _single(k, form, mode="bf16"):
-    """A single's deferred scan (``score_form`` "dot") with another step
-    after the score than the served one, from the package's own pieces:
+    """A single's scan (``score_form`` "dot") with another step after
+    the score than the served one, from the package's own pieces:
     the same three-row dot, sum, scale and guard, the same selection
     after the loop. ``form``: "twice" — the scores and their maxima as
     two consumers of the scaled and guarded row, which XLA:TPU computes
@@ -107,8 +116,8 @@ def _single(k, form, mode="bf16"):
     are one reduce over the stored scores behind the loop. The served
     step (one barrier around the pair) is ``retrieval._coarse_scan``'s."""
     def scan(q, tiles, scales, ids):
-        t = ids.size // ids.shape[0]
-        g = retrieval.tile_select_group(t, k)
+        nt, t = ids.shape[0], ids.size // ids.shape[0]
+        g = retrieval.select_group(t, k, nt)
         q3 = retrieval._split_bf16(q)
 
         def step(_, xs):
@@ -132,7 +141,7 @@ def _single(k, form, mode="bf16"):
         xs = (tiles, ids) if scales is None else (tiles, scales, ids)
         kept = jax.lax.scan(step, None, xs)[1]
         scores, maxima = (kept, kept.max(axis=3)) if form == "after" else kept
-        return retrieval._select_deferred(scores, maxima, ids, k)
+        return retrieval._select(scores, maxima, ids, k)
 
     if mode == "bf16":
         def run(q, tiles, ids):  # the trace names it jit_run
@@ -297,19 +306,21 @@ def main(argv=None) -> int:
         done.add(key)
         for mode, b in ((m, int(x)) for m in shape.get("modes", ("bf16",))
                         for x in a.batches.split(",")):
+            chunk = retrieval.scan_chunk(b, shape["rank"], mode, nt * TILE)
             row = {
                 "shape": name, "tiles": nt, "rank": shape["rank"],
-                "rules": shape["rules"], "mode": mode, "b": b,
-                "served": retrieval.scan_select(b, nt, TILE, KP, shape["rank"], mode),
-                "score_form": retrieval.score_form(b, shape["rank"], mode),
+                "rules": shape["rules"], "mode": mode, "b": b, "k": KP,
+                "chunks": -(-b // chunk),
+                "group": retrieval.select_group(TILE, KP, nt),
+                "score_form": retrieval.score_form(chunk, shape["rank"], mode),
             }
             outs = []
             for sides in a.sides.split(","):
                 args = _arguments(shape, b, make, mode, sides)
                 into = row if sides == "lanes" else row.setdefault(sides, {})
-                programs = [(into, body, _scan(KP, body, mode)) for body in BODIES]
+                programs = [(into, "scan", _scan(KP, mode))]
                 if (sides == "lanes" and row["score_form"] == "dot"
-                        and not shape["rules"]):
+                        and not shape["rules"] and row["group"]):
                     programs += [
                         (row.setdefault("steps", {}), step, _single(KP, step, mode))
                         for step in a.steps.split(",") if step
@@ -325,6 +336,8 @@ def main(argv=None) -> int:
                 del args  # one form's side arrays on the chip at a time
             if outs:
                 s0, i0 = outs[0]
+                row["scores_crc"] = zlib.crc32(np.ascontiguousarray(s0).tobytes())
+                row["ids_crc"] = zlib.crc32(np.ascontiguousarray(i0).tobytes())
                 row["scores_bit_equal"] = all(
                     (s0.view(np.uint32) == s.view(np.uint32)).all()
                     for s, _ in outs[1:]

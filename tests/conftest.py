@@ -25,6 +25,15 @@ import jax  # noqa: E402,F401
 import pytest  # noqa: E402
 
 from predictionio_tpu.data.storage import set_storage, test_storage  # noqa: E402
+from predictionio_tpu.ops import retrieval  # noqa: E402
+
+# The CPU fixtures stand for catalogs whose stored scores are past the
+# bytes no pass is cut under (``retrieval._UNCUT``: a tile of 96 or 2^14
+# rows is not): set once, before anything traces, so the rule that cuts
+# a served catalog's batch cuts theirs. The bytes themselves are held by
+# ``scan_chunk``'s own cases (tests/test_retrieval.py) and the
+# subprocesses (``bench.py --smoke``, the benchmark's) keep them.
+retrieval._UNCUT = 0
 
 
 @pytest.fixture(autouse=True, scope="session")

@@ -27,6 +27,7 @@ from predictionio_tpu.obs import metrics as obs_metrics  # noqa: E402
 from predictionio_tpu.ops import retrieval  # noqa: E402
 
 SEED, USERS, ITEMS, RANK, TILE, K = 41, 300, 20_000, 64, 8192, 16
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
 LIMIT = 1e-4  # the cell's score_gap_max (benchmark/configs/recommendation-amazon23-int8.json)
 CLS = ("predictionio_tpu.models.recommendation", "ALSModel")
 
@@ -77,10 +78,12 @@ def _serve(model, uixs, mode):
 
 class TestServedAgainstTheReference:
     @pytest.mark.parametrize("mode", ["int8", "int8_dot"])
-    @pytest.mark.parametrize("b,body", [(1, "deferred"), (8, "deferred"), (16, "two_level")])
-    def test_top_k_of_user_rows(self, model, mode, b, body):
-        nt = -(-ITEMS // TILE)
-        assert retrieval.scan_select(b, nt, TILE, 128, RANK, mode) == body
+    @pytest.mark.parametrize("b,chunk", [(1, 1), (8, 8), (16, 8)])
+    def test_top_k_of_user_rows(self, model, mode, b, chunk):
+        """Sixteen queries over rank-64 int8 values are two chunks of
+        eight, in one program."""
+        assert retrieval.scan_chunk(b, RANK, mode, PAST) == chunk
+        assert retrieval.select_group(TILE, 128, -(-ITEMS // TILE))
         uixs = np.arange(7, 7 + b)
         s, ids = _serve(model, uixs, mode)
         q = reference_int8.table_rows(SEED, factors.STREAM_USER_FACTORS, USERS, RANK, uixs)

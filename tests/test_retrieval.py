@@ -29,6 +29,8 @@ from predictionio_tpu.ops.topk import (
     top_k_similar,
 )
 
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
+
 
 def _dense(i, d, seed=0):
     rng = np.random.default_rng(seed)
@@ -705,12 +707,12 @@ def _scan_args(b, nt, t, d, mode="bf16"):
     )
 
 
-def _scan_jaxpr(b, nt, t, d, k, rules=None, mode="bf16", select=None):
+def _scan_jaxpr(b, nt, t, d, k, rules=None, mode="bf16"):
     import jax
 
     return jax.make_jaxpr(
         lambda q, tiles, scales, ids: retrieval._coarse_scan(
-            q, tiles, scales, ids, k, mode, rules, select=select
+            q, tiles, scales, ids, k, mode, rules
         )
     )(*_scan_args(b, nt, t, d, mode)).jaxpr
 
@@ -777,62 +779,76 @@ class TestTileSelect:
         )
         assert all(len(set(r.tolist())) == k for r in got_i)
 
-    @pytest.mark.parametrize("t,k,path", [
-        (1 << 18, 128, "two_level"),   # both benchmark configurations
-        (1 << 18, 16, "two_level"),
-        (1 << 18, 1024, "two_level"),
-        (1 << 18, 1 << 14, "plain"),   # k' nears the tile
-        (1 << 18, 1 << 18, "plain"),
-        (1 << 16, 128, "two_level"),
-        (1 << 13, 128, "two_level"),
-        (1 << 12, 16, "plain"),        # under _MIN_SPLIT
-        (256, 64, "plain"),            # the tiles of the tests above
-        (16, 4, "plain"),
-        (3 * (1 << 16) + 1, 128, "plain"),  # no whole number of groups
+    @pytest.mark.parametrize("t,k,nt,width", [
+        (1 << 18, 128, 1, 128),      # every benchmark configuration's tile
+        (1 << 18, 16, 1, 128),
+        (1 << 18, 1024, 1, 16),
+        (1 << 18, 1 << 14, 1, 0),    # k' nears the row
+        (1 << 18, 1 << 18, 1, 0),
+        (1 << 16, 128, 1, 32),
+        (1 << 13, 128, 1, 8),
+        (1 << 12, 16, 1, 0),         # under _MIN_SPLIT
+        (256, 64, 1, 0),             # the tiles of the tests above
+        (16, 4, 1, 0),
+        (3 * (1 << 16) + 1, 128, 1, 0),  # no whole number of groups
+        # a scan's row is the CATALOG's: NT pieces of T
+        (1 << 18, 128, 36, 128),     # yambda; 16 / 46 / 184 tiles likewise
+        (1 << 18, 1024, 36, 128),    # one tile would take groups of 16
+        (1 << 18, 1 << 13, 36, 128),  # ... and split nothing from here on
+        (1 << 18, 1 << 16, 36, 16),
+        (1 << 18, 1 << 18, 36, 0),
+        (1 << 12, 16, 3, 128),       # three tiles under _MIN_SPLIT make a row over it
+        (256, 16, 40, 128),
+        (256, 64, 3, 0),
+        (96, 16, 3, 0),              # the CPU fixtures'
+        (64, 4, 1 << 10, 0),         # groups wider than a tile divide none
     ])
-    def test_shape_rule(self, t, k, path):
-        g = retrieval.tile_select_group(t, k)
-        assert ("two_level" if g else "plain") == path
+    def test_shape_rule(self, t, k, nt, width):
+        g = retrieval.select_group(t, k, nt)
+        assert g == width
+        n = nt * t
         if g:
-            assert t % g == 0 and t // g >= k
-            assert 4 * (t // g + k * g) <= t
+            assert t % g == 0 and n // g >= k
+            assert 4 * (n // g + k * g) <= n
+        if nt == 1:
+            assert g == retrieval.select_group(t, k)
+        elif retrieval.select_group(t, k) == 128:
+            assert g == 128  # what a tile takes in lanes its catalog does too
 
     @pytest.mark.parametrize("masked", [False, True])
     def test_engaged_scan_never_sorts_a_whole_tile(self, masked):
         """The jaxpr of an engaged scan holds no ``top_k`` whose operand
-        is T wide: the group maxima, the candidates, the merge. (Eight
-        queries of rank 8: more stored scores than tile, so the rule
-        keeps the selection in the step.)"""
+        is T wide: the group maxima of all tiles and the candidates.
+        (Eight queries of rank 8: four chunks of two.)"""
         b, nt, t, d, k = 8, 2, 1 << 13, 8, 16
-        g = retrieval.tile_select_group(t, k)
-        assert g and retrieval.scan_select(b, nt, t, k, d) == "two_level"
+        g = retrieval.select_group(t, k, nt)
+        c = retrieval.scan_chunk(b, d, "bf16", PAST)
+        assert g and c == 2
         rules = None
         if masked:
             rules = _rules(nt * t, b)
         shapes = _top_k_eqns(_scan_jaxpr(b, nt, t, d, k, rules))
-        assert sorted(shapes) == sorted(
-            [(b, t // g), (b, k * g), (b, 2 * k)]
-        )
+        assert sorted(shapes) == sorted([(c, nt * t // g), (c, k * g)] * 4)
 
     def test_the_benchmark_tile_is_selected_in_small_sorts(self):
         """2^18 rows at k' = 128, both configurations' shape: [B, 2048]
         group maxima, then the [B, 16384] candidates through the same
-        helper ([B, 1024] maxima, [B, 2048] candidates), then the merge."""
+        helper ([B, 1024] maxima, [B, 2048] candidates)."""
         b, t, k = 16, 1 << 18, 128
-        assert retrieval.tile_select_group(t, k) == 128
-        assert retrieval.tile_select_group(k * 128, k) == 16
-        assert sorted(_top_k_eqns(_scan_jaxpr(b, 1, t, 8, k))) == [
-            (b, 2 * k), (b, 1024), (b, 2048), (b, 2048)
+        assert retrieval.select_group(t, k) == 128
+        assert retrieval.select_group(k * 128, k) == 16
+        assert sorted(_top_k_eqns(_scan_jaxpr(b, 1, t, 64, k))) == [
+            (b, 1024), (b, 2048), (b, 2048)
         ]
 
-    def test_plain_scan_is_the_program_it_was(self):
-        """Where two levels do not pay the step holds exactly the one
-        ``top_k`` over the tile, and the merge's."""
+    def test_a_catalog_that_splits_nothing_is_one_top_k_of_the_row(self):
+        """Three tiles of 256 rows: the steps keep their scores alone
+        and the one ``top_k`` stands after the loop, over [B, NT x T]."""
         b, nt, t, d, k = 2, 3, 256, 8, 64
-        assert not retrieval.tile_select_group(t, k)
-        assert sorted(_top_k_eqns(_scan_jaxpr(b, nt, t, d, k))) == [
-            (b, 2 * k), (b, t)
-        ]
+        assert not retrieval.select_group(t, k, nt)
+        jaxpr = _scan_jaxpr(b, nt, t, d, k)
+        assert _top_k_eqns(jaxpr) == [(b, nt * t)]
+        assert not _top_k_eqns(_scan_body(jaxpr))
 
     @pytest.mark.parametrize("b", [1, 2])
     def test_the_programs_keep_their_names(self, b):
@@ -899,13 +915,13 @@ class TestTwoLevelShortlist:
     @pytest.mark.parametrize("masked", [False, True])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
     def test_shortlist_is_the_numpy_selection(self, mode, masked, n):
-        """Four queries of rank 16 over a bf16 copy select once after
-        the loop; sixteen, or four over int8 values, would store more
-        than half of what a step reads and select in every step, merged
-        (``scan_select``). Either way the numpy selection."""
-        assert retrieval.tile_select_group(self.T, self.K)
-        path = retrieval.scan_select(n, 3, self.T, self.K, self.D, mode)
-        assert path == ("deferred" if (n, mode) == (4, "bf16") else "two_level")
+        """Four queries of rank 16 over a bf16 copy are one pass over
+        the tiles; sixteen, or four over int8 values, would store more
+        than half of what a step reads and are scanned in chunks of four
+        or two (``scan_chunk``). Either way the numpy selection."""
+        assert retrieval.select_group(self.T, self.K, 3)
+        chunk = retrieval.scan_chunk(n, self.D, mode, PAST)
+        assert chunk == (4 if mode == "bf16" else 2)
         table = _int8(self.I, self.D, seed=31)
         q = _dense(n, self.D, seed=32)
         cat = CoarseCatalog(table, tile=self.T, mode=mode)
@@ -929,11 +945,7 @@ class TestTwoLevelShortlist:
             allowed[3] &= ~in_small
             allowed[2, ex[2, :3]] = False
             assert 0 < allowed[1].sum() < self.K
-        before = retrieval.stats_block()["tile_select"]
         s, ids = cat.shortlist(q, self.K, rules)
-        after = retrieval.stats_block()["tile_select"]
-        for p in after:
-            assert after[p] == before[p] + (p == path)
         for b in range(n):
             ranked = np.argsort(-np.where(allowed[b], sc[b], -np.inf),
                                 kind="stable")
@@ -948,20 +960,19 @@ class TestTwoLevelShortlist:
                 rtol=1e-5, atol=1e-5,
             )
 
-    def test_a_small_tile_counts_as_plain(self):
-        from predictionio_tpu.obs import metrics as obs_metrics
-
-        cat = CoarseCatalog(_dense(600, 8, seed=34), tile=256)
-        before = retrieval.stats_block()["tile_select"]
-        cat.shortlist(_dense(2, 8, seed=35), 32)
-        after = retrieval.stats_block()["tile_select"]
-        assert after["plain"] == before["plain"] + 1
-        assert after["two_level"] == before["two_level"]
-        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
-        for path, n in after.items():
-            assert scraped[
-                f'pio_retrieval_tile_select_total{{path="{path}"}}'
-            ] == n
+    def test_a_small_tile_is_the_numpy_selection_too(self):
+        """Three tiles of 256 rows split nothing: one ``lax.top_k`` of
+        the stored row."""
+        table = _int8(600, 8, seed=34)
+        cat = CoarseCatalog(table, tile=256, mode="bf16")
+        assert not retrieval.select_group(256, 32, 3)
+        q = _dense(2, 8, seed=35)
+        sc = _coarse_scores(cat, table, q)
+        s, ids = cat.shortlist(q, 32)
+        for b in range(2):
+            want = np.argsort(-sc[b], kind="stable")[:32]
+            assert set(ids[b].tolist()) == set(want.tolist())
+            np.testing.assert_allclose(s[b], sc[b][ids[b]], rtol=1e-5, atol=1e-5)
 
 
 # -- a single's score through the batched scans' dot ---------------------------
@@ -1058,7 +1069,7 @@ class TestScoreForm:
         self, mode, masked
     ):
         assert retrieval.score_form(1, self.D) == "dot"
-        assert retrieval.tile_select_group(self.T, self.K)
+        assert retrieval.select_group(self.T, self.K)
         table = _int8(self.I, self.D, seed=41)
         q = _dense(1, self.D, seed=42)
         cat = CoarseCatalog(table, tile=self.T, mode=mode)
@@ -1097,7 +1108,7 @@ class TestScoreForm:
         import jax.numpy as jnp
 
         nt, t, d, k = 2, 1 << 13, 64, 16
-        g = retrieval.tile_select_group(t, k)
+        g = retrieval.select_group(t, k, nt)
         rules = _rules(nt * t, 1) if masked else None
         jaxpr = _scan_jaxpr(1, nt, t, d, k, rules, mode)
         (query,) = _operands(jaxpr, "dot_general")
@@ -1112,24 +1123,24 @@ class TestScoreForm:
     @pytest.mark.parametrize("masked", [False, True])
     def test_every_other_shape_keeps_its_score(self, b, d, masked):
         """B >= 2 and D >= 128: the f32 queries as they are against the
-        tile cast to f32. Where the selection stands is another rule's
-        (``scan_select``): after the loop for every batch whose stored
-        scores fit, in the step (PR 27's three ``top_k`` shapes) for 64
-        queries of rank 64."""
+        tile cast to f32 — all of them, or a chunk at a time
+        (``scan_chunk``: 64 queries of rank 64 are four chunks of 16) —
+        and the selections after the loop, of as many rows."""
         import jax.numpy as jnp
 
         nt, t, k = 2, 1 << 13, 16
         assert retrieval.score_form(b, d) == "rows"
-        g = retrieval.tile_select_group(t, k)
+        g = retrieval.select_group(t, k, nt)
         rules = _rules(nt * t, b) if masked else None
         jaxpr = _scan_jaxpr(b, nt, t, d, k, rules)
-        (query,) = _operands(jaxpr, "dot_general")
-        assert query.shape == (b, d) and query.dtype == jnp.float32
-        deferred = retrieval.scan_select(b, nt, t, k, d) == "deferred"
-        assert deferred == (b < 64)
+        c = retrieval.scan_chunk(b, d, "bf16", PAST)
+        assert c == min(b, 16 if d == 64 else 32)
+        queries = _operands(jaxpr, "dot_general")
+        assert len(queries) == b // c
+        for query in queries:
+            assert query.shape == (c, d) and query.dtype == jnp.float32
         assert sorted(_top_k_eqns(jaxpr)) == sorted(
-            [(b, nt * t // g), (b, k * g)] if deferred else
-            [(b, t // g), (b, k * g), (b, 2 * k)]
+            [(c, nt * t // g), (c, k * g)] * (b // c)
         )
 
     def test_int8_dot_has_no_f32_query_to_split(self):
@@ -1170,10 +1181,20 @@ class TestScoreForm:
 # -- one query selects once, after the tile loop -------------------------------
 
 
+def _tile_loops(jaxpr):
+    """The ``scan`` s over the tiles in a ``_coarse_scan``'s ``jaxpr``:
+    one a chunk, none inside another."""
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert loops and not [
+        e for loop in loops for e in _eqns(loop.params["jaxpr"].jaxpr)
+        if e.primitive.name in ("scan", "while")
+    ]
+    return loops
+
+
 def _scan_body(jaxpr):
-    """The body of the one ``scan`` in ``jaxpr``."""
-    (scan,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
-    return scan.params["jaxpr"].jaxpr
+    """The body of the (first) tile loop in ``jaxpr``."""
+    return _tile_loops(jaxpr)[0].params["jaxpr"].jaxpr
 
 
 def _tile_rules(rules, i, t):
@@ -1191,31 +1212,29 @@ def _tile_rules(rules, i, t):
 
 
 class TestDeferredSelect:
-    """A scan selects once, after the loop (``scan_select`` ->
-    "deferred": one query since PR 33, every batch whose stored scores
-    fit since PR 36): against the per-tile body on the same inputs
-    (forced through ``_coarse_scan``'s ``select``) and against
+    """A scan selects once, after the loop (one query since PR 33, a
+    batch since PR 36, every batch and every tile since PR 44): against
     ``lax.top_k`` over the whole guarded score row — the scores
     bit-equal, the ids equal wherever the scores are distinct."""
 
     T, K = 1 << 13, 128
 
     @staticmethod
-    def _scan(cat, q, k, rules, select):
+    def _scan(cat, q, k, rules):
         import jax
 
         return jax.device_get(jax.jit(
             lambda q, tiles, scales, ids, rules: retrieval._coarse_scan(
-                q, tiles, scales, ids, k, cat.mode, rules, select=select
+                q, tiles, scales, ids, k, cat.mode, rules
             )
         )(q, cat._tiles, cat._scales, cat._ids, rules))
 
     def _row(self, cat, q, rules):
         """The whole guarded (and masked) [B, stored] score row, a tile
-        at a time through the plain body at k = T: the program this
-        module had before any selection was split, which keeps every
-        score of its one tile. Positions of rows that may not be served
-        hold ``NEG_INF``."""
+        at a time: a one-tile catalog at k' = T splits nothing, so its
+        scan is the step and one ``lax.top_k`` that keeps every score of
+        the tile. Positions of rows that may not be served hold
+        ``NEG_INF``."""
         nt, t = cat._ids.shape[0], cat.tile
         row = np.full((len(q), nt * t), retrieval.NEG_INF, np.float32)
         for i in range(nt):
@@ -1228,7 +1247,6 @@ class TestDeferredSelect:
             guard = np.asarray(cat._ids[i]).reshape(-1) >= 0
             s, pos = self._scan(
                 one, q, t, None if rules is None else _tile_rules(rules, i, t),
-                "plain",
             )
             for b in range(len(q)):
                 keep = pos[b] >= 0
@@ -1267,10 +1285,8 @@ class TestDeferredSelect:
     @pytest.mark.parametrize("d", [64, 128])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
     def test_the_shortlist_it_was(self, mode, d, case):
-        """The scan as the RULE serves it (``select`` left alone) — a
-        single and batches of 2, 4, 8 and 16, with and without
-        ``Rules`` — against the per-tile body forced on the same input
-        and against the whole row."""
+        """The scan — a single and batches of 2, 4, 8 and 16, with and
+        without ``Rules`` — against the whole row."""
         import jax
 
         # k' = 1,024 over tiles of 2^16 rows: groups of 8, as here at 128
@@ -1286,14 +1302,11 @@ class TestDeferredSelect:
                             twin_tiles=case == "equal_scores_across_tiles")
         nt = cat._ids.shape[0]
         assert nt == -(-rows // t) and cat.tile == t
-        assert retrieval.tile_select_group(t, k) == 8
-        # the rule serves every case here but sixteen queries over
-        # rank-64 int8 values (more than half of what a step reads):
-        # there the deferred body is forced, as PR 33 forced its batch
+        assert retrieval.select_group(t, k, nt) == {1: 8, 5: 32}.get(nt, 16)
+        # one pass in every case here but sixteen queries over rank-64
+        # int8 values (more than half of what a step reads): two of 8
         beyond = b == 16 and d == 64 and mode != "bf16"
-        assert retrieval.scan_select(b, nt, t, k, d, mode) == (
-            "two_level" if beyond else "deferred"
-        )
+        assert retrieval.scan_chunk(b, d, mode, PAST) == (8 if beyond else b)
         assert retrieval.score_form(b, d, mode) == (
             "dot" if b == 1 and d == 64 and mode != "int8_dot" else "rows"
         )
@@ -1302,7 +1315,7 @@ class TestDeferredSelect:
         gone = np.zeros(0, np.int64)  # rows whose stored id is made -1
         if case == "minus_one_ids_inside_a_tile":
             # the eight best rows and one row in 53, all over the tiles
-            first = self._scan(cat, q, k, None, "two_level")[1]
+            first = self._scan(cat, q, k, None)[1]
             gone = np.union1d(first[0, :8], np.arange(5, rows, 53))
         elif case == "a_tile_of_padding":  # its group maxima: all NEG_INF
             gone = np.arange(t, 2 * t)
@@ -1315,16 +1328,11 @@ class TestDeferredSelect:
             rules = _rules(cat.stored_rows, b, small_cat=small,
                            qcat=np.full((b, 1), 1, np.int32))
         elif case == "own_rows_excluded":
-            first = self._scan(cat, q, k, None, "two_level")[1]
+            first = self._scan(cat, q, k, None)[1]
             rules = _rules(cat.stored_rows, b, ex=first[:, :4].astype(np.int32))
         elif case == "unavailable_rows":
             rules = _rules(cat.stored_rows, b)
-        got_s, got_i = self._scan(cat, q, k, rules,
-                                  "deferred" if beyond else None)
-        per_s, per_i = self._scan(cat, q, k, rules, "two_level")
-        np.testing.assert_array_equal(
-            got_s.view(np.uint32), per_s.view(np.uint32)
-        )
+        got_s, got_i = self._scan(cat, q, k, rules)
         row = self._row(cat, q, rules)
         ref_s, ref_pos = jax.device_get(jax.lax.top_k(row, k))
         np.testing.assert_array_equal(
@@ -1338,7 +1346,6 @@ class TestDeferredSelect:
         for r in range(b):
             live = got_s[r] > retrieval.NEG_INF / 2
             if len(np.unique(got_s[r][live])) == live.sum():
-                np.testing.assert_array_equal(got_i[r], per_i[r])
                 np.testing.assert_array_equal(got_i[r], ref_i[r])
                 continue
             # another choice among equals (tiles that repeat; a batch's
@@ -1371,21 +1378,17 @@ class TestDeferredSelect:
             )
 
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
-    def test_a_shortlist_too_wide_to_split_keeps_the_step_it_had(self, mode):
-        """k' = T/2: ``tile_select_group`` splits nothing, the rule
-        says "plain" for a single too, and the served scan is the
-        ``lax.top_k`` of the row."""
+    def test_a_shortlist_too_wide_to_split_is_one_top_k_of_the_row(self, mode):
+        """k' = T/2 over three tiles: ``select_group`` splits nothing,
+        the steps keep their scores alone and the served selection is
+        the ``lax.top_k`` of the stored row."""
         import jax
 
         t, k = self.T, self.T // 2
-        assert retrieval.scan_select(1, 3, t, k, 64, mode) == "plain"
+        assert retrieval.select_group(t, k, 3) == 0
         cat = self._catalog(mode, 64, 2 * t + 1000, seed=64)
         q = _dense(1, 64, seed=65)
-        before = retrieval.stats_block()["tile_select"]
         s, ids = cat.shortlist(q, k)
-        after = retrieval.stats_block()["tile_select"]
-        assert after["plain"] == before["plain"] + 1
-        assert after["deferred"] == before["deferred"]
         ref_s, ref_pos = jax.device_get(
             jax.lax.top_k(self._row(cat, q, None), k)
         )
@@ -1394,81 +1397,158 @@ class TestDeferredSelect:
             ids, np.asarray(cat._ids).reshape(-1)[ref_pos]
         )
 
-    @pytest.mark.parametrize("b,nt,t,k,d,mode,want", [
-        (1, 36, 1 << 18, 128, 64, "bf16", "deferred"),    # yambda
-        (1, 16, 1 << 18, 128, 128, "bf16", "deferred"),   # both Taobao ones
-        (1, 46, 1 << 18, 128, 64, "bf16", "deferred"),    # a sharded chip
-        (1, 1, 1 << 18, 128, 64, "bf16", "deferred"),
-        (1, 3, 1 << 13, 16, 64, "bf16", "deferred"),
-        (1, 36, 1 << 18, 1024, 64, "bf16", "deferred"),
-        # every batch the cells dispatch (B = 2 .. 16), all four shapes
-        (2, 36, 1 << 18, 128, 64, "bf16", "deferred"),
-        (4, 36, 1 << 18, 128, 64, "bf16", "deferred"),
-        (8, 36, 1 << 18, 128, 64, "bf16", "deferred"),
-        (16, 36, 1 << 18, 128, 64, "bf16", "deferred"),
-        (2, 16, 1 << 18, 128, 128, "bf16", "deferred"),
-        (8, 16, 1 << 18, 128, 128, "bf16", "deferred"),
-        (16, 16, 1 << 18, 128, 128, "bf16", "deferred"),
-        (2, 46, 1 << 18, 128, 64, "bf16", "deferred"),
-        (16, 46, 1 << 18, 128, 64, "bf16", "deferred"),
-        # the bound: B x 4 <= D x itemsize / 2, whatever the tiles' number
-        (32, 36, 1 << 18, 128, 64, "bf16", "two_level"),  # 1.2 GB of scores
-        (64, 36, 1 << 18, 128, 64, "bf16", "two_level"),
-        (32, 46, 1 << 18, 128, 64, "bf16", "two_level"),
-        (32, 16, 1 << 18, 128, 128, "bf16", "deferred"),
-        (64, 16, 1 << 18, 128, 128, "bf16", "two_level"),
-        (8, 36, 1 << 18, 128, 64, "int8", "deferred"),    # a byte a value
-        (16, 36, 1 << 18, 128, 64, "int8", "two_level"),
-        (16, 36, 1 << 18, 128, 64, "int8_dot", "two_level"),
-        (2, 3, 1 << 13, 16, 8, "bf16", "deferred"),
-        (4, 3, 1 << 13, 16, 8, "bf16", "two_level"),
-        (1, 36, 1 << 18, 1 << 14, 64, "bf16", "plain"),   # k' nears the tile
-        (1, 3, 1 << 12, 16, 64, "bf16", "plain"),         # under _MIN_SPLIT
-        (1, 3, 256, 64, 64, "bf16", "plain"),             # the CPU fixtures'
-        (16, 3, 256, 64, 64, "bf16", "plain"),
+    @pytest.mark.parametrize("b", [1, 4])
+    @pytest.mark.parametrize("mode", ["bf16", "int8"])
+    @pytest.mark.parametrize("t,nt,k,group", [
+        (1 << 13, 8, 96, 128),    # a tile alone has 64 groups of 128 lanes
+        (1 << 13, 16, 128, 128),
+        (1 << 12, 8, 64, 32),     # a tile alone is under _MIN_SPLIT
+        (1 << 12, 3, 16, 128),
     ])
-    def test_the_rule(self, b, nt, t, k, d, mode, want):
-        assert retrieval.scan_select(b, nt, t, k, d, mode) == want
-        assert (want == "plain") == (not retrieval.tile_select_group(t, k))
-        if want != "plain":  # the stored scores against the tiles' bytes
-            tiles = nt * t * d * (2 if mode == "bf16" else 1)
-            assert (want == "deferred") == (b * nt * t * 4 <= tiles / 2)
+    def test_the_catalog_offers_the_groups_a_tile_alone_would_not(
+            self, t, nt, k, group, mode, b):
+        """k' above T/128 (or a tile too small to split) over several
+        tiles: the group width is the catalog's (``select_group`` of
+        NT x T), the selection still the ``lax.top_k`` of the row."""
+        import jax
+
+        assert retrieval.select_group(t, k, nt) == group
+        assert retrieval.select_group(t, k) != group
+        rows = nt * t - 700
+        cat = self._catalog(mode, 64, rows, seed=68, tile=t)
+        assert cat._ids.shape[0] == nt
+        q = _dense(b, 64, seed=69)
+        rules = _rules(cat.stored_rows, b) if b > 1 else None
+        got_s, got_i = self._scan(cat, q, k, rules)
+        row = self._row(cat, q, rules)
+        ref_s, ref_pos = jax.device_get(jax.lax.top_k(row, k))
+        np.testing.assert_array_equal(
+            got_s.view(np.uint32), ref_s.view(np.uint32)
+        )
+        ref_i = np.asarray(cat._ids).reshape(-1)[ref_pos]
+        for r in range(b):
+            if len(np.unique(got_s[r])) == k:
+                np.testing.assert_array_equal(got_i[r], ref_i[r])
+        assert (got_i >= 0).all() and got_i.max() < rows
+
+    @pytest.mark.parametrize("b,nt,t,k,d,mode,chunk,group", [
+        (1, 36, 1 << 18, 128, 64, "bf16", 1, 128),    # yambda
+        (1, 16, 1 << 18, 128, 128, "bf16", 1, 128),   # both Taobao ones
+        (1, 46, 1 << 18, 128, 64, "bf16", 1, 128),    # a sharded chip
+        (1, 1, 1 << 18, 128, 64, "bf16", 1, 128),
+        (1, 3, 1 << 13, 16, 64, "bf16", 1, 128),
+        (1, 36, 1 << 18, 1024, 64, "bf16", 1, 128),
+        # every batch the cells dispatch (B = 2 .. 16), all four shapes
+        (2, 36, 1 << 18, 128, 64, "bf16", 2, 128),
+        (4, 36, 1 << 18, 128, 64, "bf16", 4, 128),
+        (8, 36, 1 << 18, 128, 64, "bf16", 8, 128),
+        (16, 36, 1 << 18, 128, 64, "bf16", 16, 128),
+        (2, 16, 1 << 18, 128, 128, "bf16", 2, 128),
+        (8, 16, 1 << 18, 128, 128, "bf16", 8, 128),
+        (16, 16, 1 << 18, 128, 128, "bf16", 16, 128),
+        (2, 46, 1 << 18, 128, 64, "bf16", 2, 128),
+        (16, 46, 1 << 18, 128, 64, "bf16", 16, 128),
+        # the bound: B x 4 <= D x itemsize / 2, whatever the tiles' number
+        (32, 36, 1 << 18, 128, 64, "bf16", 16, 128),  # 1.2 GB of scores
+        (64, 36, 1 << 18, 128, 64, "bf16", 16, 128),
+        (32, 46, 1 << 18, 128, 64, "bf16", 16, 128),
+        (32, 16, 1 << 18, 128, 128, "bf16", 32, 128),
+        (64, 16, 1 << 18, 128, 128, "bf16", 32, 128),
+        (8, 184, 1 << 18, 128, 64, "int8", 8, 128),   # a byte a value
+        (16, 184, 1 << 18, 128, 64, "int8", 8, 128),
+        (16, 184, 1 << 18, 128, 64, "int8_dot", 8, 128),
+        (2, 3, 1 << 13, 16, 8, "bf16", 2, 128),
+        (4, 3, 1 << 13, 16, 8, "bf16", 2, 128),
+        (1, 36, 1 << 18, 1 << 14, 64, "bf16", 1, 128),  # k' nears the TILE
+        (1, 3, 1 << 12, 16, 64, "bf16", 1, 128),      # tiles under _MIN_SPLIT
+        (1, 3, 256, 64, 64, "bf16", 1, 0),            # the CPU fixtures'
+        (16, 3, 256, 64, 64, "bf16", 16, 0),
+        # the smallest chunk is one query, whatever the rank (at the
+        # parent a single of rank 2 "did not fit")
+        (1, 3, 256, 16, 2, "bf16", 1, 0),
+        (4, 3, 256, 16, 2, "bf16", 1, 0),
+        (1, 3, 256, 16, 4, "int8", 1, 0),
+        (2, 3, 256, 16, 7, "int8_dot", 1, 0),
+        # a bound that is no power of two; a batch that is none (its
+        # last chunk is what is left)
+        (25, 3, 256, 16, 100, "bf16", 25, 0),
+        (32, 3, 256, 16, 100, "bf16", 16, 0),
+        (24, 3, 256, 16, 64, "bf16", 16, 0),
+        (17, 3, 256, 16, 64, "bf16", 16, 0),
+        # under rank 64 the tiles are small, not the scores large: what
+        # stores at most _UNCUT is one pass whatever the rank — the ALS
+        # templates' default rank 10 and bench.py's rank 32 over int8
+        # values at its 1 M rows, any rank up to 9 tiles' 2.36 M rows
+        (64, 4, 1 << 18, 128, 10, "bf16", 64, 128),
+        (8, 4, 1 << 18, 128, 32, "int8", 8, 128),
+        (64, 4, 1 << 18, 128, 32, "int8", 64, 128),
+        (64, 9, 1 << 18, 128, 10, "int8", 64, 128),
+        (64, 10, 1 << 18, 128, 10, "int8", 32, 128),
+        # 16 queries over yambda's 36 tiles at any rank, 8 up to 72 tiles
+        (16, 36, 1 << 18, 128, 10, "bf16", 16, 128),
+        (64, 36, 1 << 18, 128, 10, "bf16", 16, 128),
+        (64, 36, 1 << 18, 128, 32, "int8", 16, 128),
+        (64, 72, 1 << 18, 128, 10, "bf16", 8, 128),
+        (64, 73, 1 << 18, 128, 10, "bf16", 4, 128),
+        # rank 10 over the int8 cell's 184 tiles: the first bound's two
+        (1, 184, 1 << 18, 128, 10, "bf16", 1, 128),
+        (8, 184, 1 << 18, 128, 10, "bf16", 2, 128),
+        (8, 184, 1 << 18, 128, 10, "int8", 2, 128),    # three fit _UNCUT
+    ])
+    def test_the_rule(self, monkeypatch, b, nt, t, k, d, mode, chunk, group):
+        """How many queries a pass takes (``scan_chunk``) and the group
+        width its steps keep maxima of (``select_group``). The tiles of
+        2^18 rows are read with the bytes no pass is cut under
+        (``_UNCUT``: 604 MB, what 16 queries store over 36 such tiles);
+        the small ones stand for a catalog past them, as everywhere in
+        the tests (conftest.py)."""
+        uncut = 16 * 36 * (1 << 18) * 4
+        monkeypatch.setattr(retrieval, "_UNCUT", uncut)
+        rows = nt * t if t == 1 << 18 else PAST
+        assert retrieval.scan_chunk(b, d, mode, rows) == chunk
+        assert retrieval.select_group(t, k, nt) == group
+        # the stored scores against the tiles' bytes and the floor
+        half = rows * d * (2 if mode == "bf16" else 1) / 2
+        assert chunk * rows * 4 <= max(half, uncut) or chunk == 1
+        if chunk < b:  # a power of two, and twice it would fit neither
+            assert chunk & (chunk - 1) == 0
+            assert 2 * chunk * rows * 4 > max(half, uncut)
 
     @pytest.mark.parametrize("masked", [False, True])
     @pytest.mark.parametrize("mode,d", [
         ("bf16", 64), ("bf16", 128), ("int8", 64), ("int8_dot", 64),
     ])
-    def test_a_step_selects_nothing_until_the_scores_outweigh_half_the_tile(
+    def test_a_step_selects_nothing_and_carries_nothing_at_any_batch(
         self, mode, d, masked
     ):
-        """The jaxpr: the scan body of a single, a pair and a batch of
-        eight holds no ``sort`` / ``top_k`` — score, guard, mask, one
-        maximum a group — and every selection stands after the loop;
-        the body of the first batch beyond the bound holds PR 27's
-        three."""
+        """The jaxpr: the tile loop of a single, a pair, a batch of
+        eight, the batch at the bound's edge and the loops of the two
+        beyond it (a loop a chunk) have no carry and their bodies no
+        ``sort`` / ``top_k`` — score, guard, mask, one maximum a group —
+        and every selection stands after its loop, a chunk's rows wide."""
         nt, t, k = 2, 1 << 13, 16
-        g = retrieval.tile_select_group(t, k)
+        g = retrieval.select_group(t, k, nt)
         groups = nt * t // g
-        g2 = retrieval.tile_select_group(groups, k)
-        assert not g2 and retrieval.tile_select_group(k * g, k) == 0
-        for b in (1, 2, 8):
-            assert retrieval.scan_select(b, nt, t, k, d, mode) == "deferred"
+        assert g == 128 and not retrieval.select_group(groups, k)
+        assert retrieval.select_group(k * g, k) == 0
+        edge = d // 4 if mode == "bf16" else d // 8  # the bound's edge
+        for b in (1, 2, 8, edge, 2 * edge, 4 * edge):
+            c = retrieval.scan_chunk(b, d, mode, PAST)
+            assert c == min(b, edge)
             jaxpr = _scan_jaxpr(b, nt, t, d, k, _rules(nt * t, b) if masked
                                 else None, mode)
-            inside = [e.primitive.name for e in _eqns(_scan_body(jaxpr))]
-            assert not {"sort", "top_k", "gather", "concatenate"} & set(inside)
-            assert inside.count("reduce_max") == 1
-            assert sorted(_top_k_eqns(jaxpr)) == [(b, k * g), (b, groups)]
-        b = d // 4 if mode == "bf16" else d // 8  # the bound's edge
-        assert retrieval.scan_select(b, nt, t, k, d, mode) == "deferred"
-        b *= 2
-        assert retrieval.scan_select(b, nt, t, k, d, mode) == "two_level"
-        beyond = _scan_jaxpr(b, nt, t, d, k, _rules(nt * t, b) if masked
-                             else None, mode)
-        assert sorted(_top_k_eqns(_scan_body(beyond))) == sorted(
-            [(b, t // g), (b, k * g), (b, 2 * k)]
-        )
-        assert _top_k_eqns(beyond) == _top_k_eqns(_scan_body(beyond))
+            loops = _tile_loops(jaxpr)
+            assert len(loops) == b // c  # one after another, in one program
+            for loop in loops:
+                assert loop.params["num_carry"] == 0
+                assert loop.params["length"] == nt
+                body = loop.params["jaxpr"].jaxpr
+                inside = [e.primitive.name for e in _eqns(body)]
+                assert not {"sort", "top_k", "gather", "concatenate"} & set(inside)
+                assert inside.count("reduce_max") == 1
+            assert sorted(_top_k_eqns(jaxpr)) == sorted(
+                [(c, k * g), (c, groups)] * (b // c)
+            )
 
     @pytest.mark.parametrize("b", [1, 2, 8, 16])
     def test_the_scores_are_stored_query_major_at_every_batch(self, b):
@@ -1478,11 +1558,11 @@ class TestDeferredSelect:
         group; storing it in that order was tried and lost: PERF.md
         section 6, PR 36.)"""
         nt, t, k = 2, 1 << 13, 128
-        assert retrieval.tile_select_group(t, k) == 8
+        assert retrieval.select_group(t, k, nt) == 16
         (scan,) = [e for e in _scan_jaxpr(b, nt, t, 64, k).eqns
                    if e.primitive.name == "scan"]
         scores, maxima = (v.aval.shape for v in scan.outvars)
-        assert scores == (nt, b, 1024, 8) and maxima == (nt, b, 1024)
+        assert scores == (nt, b, 512, 16) and maxima == (nt, b, 512)
 
     def test_the_benchmark_shapes_select_in_small_sorts_once(self):
         """36 tiles of 2^18 at k' = 128: [1, 73728] maxima through the
@@ -1495,26 +1575,38 @@ class TestDeferredSelect:
             (1, 576), (1, 1024), (1, 1024), (1, 2048), (1, 2048)
         ]
 
-    @pytest.mark.parametrize("b,path", [(1, "deferred"), (2, "deferred"),
-                                        (3, "deferred"), (4, "deferred"),
-                                        (5, "two_level")])
-    def test_the_counter_counts_one_a_call(self, b, path):
-        """Rank 16 in bf16: the stored scores of up to 4 queries fit; 5
-        pad to 8 and select in the step."""
+    @pytest.mark.parametrize("b,chunks", [(1, 1), (2, 1), (3, 1), (4, 1),
+                                          (5, 2), (16, 4)])
+    def test_a_call_is_one_upload_and_one_read_whatever_its_chunks(
+            self, b, chunks):
+        """Rank 16 in bf16: up to 4 queries are one pass; 5 pad to 8 and
+        are two chunks, 16 are four — of ONE program: one upload, one
+        blocking read, one count a call, and no counter of the choice."""
         from predictionio_tpu.obs import metrics as obs_metrics
 
         cat = self._catalog("bf16", 16, 2 * self.T + 5, seed=66)
-        before = retrieval.stats_block()["tile_select"]
-        cat.shortlist(_dense(b, 16, seed=67), 64)
-        after = retrieval.stats_block()["tile_select"]
-        assert set(after) == {"deferred", "two_level", "plain"}
-        for p in after:
-            assert after[p] == before[p] + (p == path)
-        scraped = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
-        for p, n in after.items():
-            assert scraped[
-                f'pio_retrieval_tile_select_total{{path="{p}"}}'
-            ] == n
+        bp = retrieval._pow2(b)
+        assert bp // retrieval.scan_chunk(bp, 16, "bf16", PAST) == chunks
+        q = _dense(b, 16, seed=67)
+        cat.shortlist(q, 64)  # compiled
+        before = retrieval.stats_block()
+        s, ids = cat.shortlist(q, 64)
+        after = retrieval.stats_block()
+        assert after["uploads"] == before["uploads"] + 1
+        assert after["host_reads"] == before["host_reads"] + 1
+        form = "dot" if b == 1 else "rows"
+        assert after["score_form"][form] == before["score_form"][form] + 1
+        assert "tile_select" not in after
+        assert b"tile_select" not in obs_metrics.render_prometheus()
+        # a chunk's rows are the rows of the same queries sent alone
+        for lo in range(0, b if chunks > 1 else 0, 4):
+            n = min(4, b - lo)  # the last chunk's other rows: copies of row 0
+            piece = np.concatenate([q[lo: lo + n], np.repeat(q[:1], 4 - n, 0)])
+            one_s, one_i = cat.shortlist(piece, 64)
+            np.testing.assert_array_equal(
+                s[lo: lo + n].view(np.uint32), one_s[:n].view(np.uint32)
+            )
+            np.testing.assert_array_equal(ids[lo: lo + n], one_i[:n])
 
 
 # -- the chain: one owner of "exact or two-stage, shortlist -> rescore, probe" --
@@ -1540,10 +1632,10 @@ class TestSideArrays:
     before PR 42 — bit for bit."""
 
     K = 16
-    # body -> the tile that runs it: 2^14 rows split into 128-lane groups
-    # at k' = 16 (the served structure: g = L = 128); 96 rows split
-    # nothing and are no whole number of lanes (L = T)
-    TILES = {"deferred": 1 << 14, "two_level": 1 << 14, "plain": 96}
+    # the catalog's shape -> its tile: 2^14 rows split into 128-lane
+    # groups at k' = 16 (the served structure: g = L = 128); 96 rows
+    # split nothing and are no whole number of lanes (L = T)
+    TILES = {"lane_groups": 1 << 14, "no_split": 96}
 
     @pytest.mark.parametrize("t,want", [
         (1 << 18, (5, 2048, 128)), (1 << 14, (5, 128, 128)),
@@ -1574,22 +1666,22 @@ class TestSideArrays:
         assert cat.nbytes() == 3 * tile * (64 + 4 + 4)
 
     @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
-    @pytest.mark.parametrize("body", ["deferred", "two_level", "plain"])
+    @pytest.mark.parametrize("shape", ["lane_groups", "no_split"])
     @pytest.mark.parametrize("b", [1, 2, 8, 16, 32])
     @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
-    def test_parity(self, mode, b, body, ruled):
+    def test_parity(self, mode, b, shape, ruled):
         """32 queries are beyond the bound of every mode at rank 64
-        (``_stored_scores_fit``); each body is forced on every batch,
-        as ``select`` allows, so all three run at all five."""
+        (``scan_chunk``: two chunks of 16, four of 8), 16 beyond the
+        int8 modes'."""
         import jax
 
-        t, k = self.TILES[body], self.K
+        t, k = self.TILES[shape], self.K
         cat, table = _sides_catalog(mode, t)
         rows, stored = len(table[0]), cat.stored_rows
-        assert retrieval.scan_select(32, 3, t, k, 64, mode) == (
-            "plain" if body == "plain" else "two_level"
+        assert retrieval.scan_chunk(32, 64, mode, PAST) == (16 if mode == "bf16" else 8)
+        assert retrieval.select_group(t, k, 3) == (
+            128 if shape == "lane_groups" else 0
         )
-        assert bool(retrieval.tile_select_group(t, k)) == (body != "plain")
         q = _dense(b, 64, seed=92 + b)
         sc = _coarse_scores(cat, table, q)
         allowed = np.ones((b, rows), bool)
@@ -1613,7 +1705,6 @@ class TestSideArrays:
             return jax.device_get(jax.jit(
                 lambda q, tiles, scales, ids, rules: retrieval._coarse_scan(
                     q, tiles, scales, ids, k, mode, rules,
-                    select=None if body == "plain" else body,
                 )
             )(q, cat._tiles, scales, ids, rules))
 
@@ -1639,6 +1730,188 @@ class TestSideArrays:
                 assert sc[r][rest].max() <= s[r][:n].min() + 1e-4
         if ruled:
             assert 0 < int((ids[b // 2] >= 0).sum()) == int(allowed[b // 2].sum()) < k
+
+
+class TestChunkedBatches:
+    """A batch beyond the stored-scores bound is scanned a chunk of the
+    queries at a time inside the one program (``scan_chunk``): the
+    answer of the same queries sent as calls within the bound, bit for
+    bit, with ``Rules`` whose per-query rows differ from chunk to
+    chunk; and the smallest chunk is one query."""
+
+    K = 16
+    TILES = TestSideArrays.TILES
+
+    @staticmethod
+    def _scan(cat, q, k, rules):
+        import jax
+
+        return jax.device_get(jax.jit(
+            lambda q, tiles, scales, ids, rules: retrieval._coarse_scan(
+                q, tiles, scales, ids, k, cat.mode, rules
+            )
+        )(q, cat._tiles, cat._scales, cat._ids, rules))
+
+    @staticmethod
+    def _own_rules(stored, rows, b, seed):
+        """Rules whose every per-query row is its own: a category for
+        every third query, two excluded rows a query."""
+        rng = np.random.default_rng(seed)
+        qcat = np.full((b, 1), -2, np.int32)
+        qcat[::3] = 1
+        ex = np.full((b, 4), -1, np.int32)
+        ex[:, :2] = rng.integers(0, rows, (b, 2))
+        return _rules(stored, b, small_cat=rng.permutation(rows)[: rows // 3],
+                      ex=ex, qcat=qcat)
+
+    _rows = staticmethod(retrieval._query_rows)
+
+    @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
+    @pytest.mark.parametrize("shape", ["lane_groups", "no_split"])
+    @pytest.mark.parametrize("chunks", [2, 4])
+    @pytest.mark.parametrize("mode", ["bf16", "int8", "int8_dot"])
+    def test_a_batch_beyond_the_bound_answers_as_calls_within_it(
+            self, mode, chunks, shape, ruled):
+        """B = 32 and 64 in bf16, 16 and 32 over int8 values, at rank 64."""
+        t = self.TILES[shape]
+        cat, table = _sides_catalog(mode, t)
+        c = 16 if mode == "bf16" else 8
+        b = chunks * c
+        assert retrieval.scan_chunk(b, 64, mode, PAST) == c
+        q = _dense(b, 64, seed=95 + b)
+        rules = self._own_rules(cat.stored_rows, len(table[0]), b, 96) if ruled \
+            else None
+        s, ids = self._scan(cat, q, self.K, rules)
+        assert s.shape == ids.shape == (b, self.K)
+        for lo in range(0, b, c):
+            one_s, one_i = self._scan(
+                cat, q[lo: lo + c], self.K, self._rows(rules, lo, lo + c)
+            )
+            np.testing.assert_array_equal(
+                s[lo: lo + c].view(np.uint32), one_s.view(np.uint32)
+            )
+            np.testing.assert_array_equal(ids[lo: lo + c], one_i)
+        if ruled:  # the rows differ between the chunks, and they bind
+            assert (ids % 97 != 0).all()
+            assert not (ids[:, :, None] == np.asarray(rules.ex)[:, None, :]).any()
+            assert len({tuple(r) for r in ids.tolist()}) == b
+
+    @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
+    @pytest.mark.parametrize("b", [17, 33])
+    def test_chunks_of_padding_cost_no_row_its_answer(self, b, ruled):
+        """17 queries pad to 32 and 33 to 64 with copies of row 0: the
+        last chunk of 64 is ALL copies. Every served row is the row of
+        the same query in a call within the bound."""
+        cat, table = _sides_catalog("bf16", 1 << 14)
+        bp = retrieval._pow2(b)
+        assert retrieval.scan_chunk(bp, 64, "bf16", PAST) == 16 and (bp - b) >= 15
+        q = retrieval._pad_rows(_dense(b, 64, seed=97), bp)
+        rules = self._own_rules(cat.stored_rows, len(table[0]), b, 98) if ruled \
+            else None
+        if ruled:
+            rules = rules._replace(**{
+                part: retrieval._pad_rows(np.asarray(getattr(rules, part)), bp)
+                for part in ("qcat", "has_cat", "ex")
+            })
+            rules = retrieval.device_rules(rules)
+        before = retrieval.stats_block()
+        s, ids = cat.shortlist(q[:b] if not ruled else q, self.K, rules)
+        after = retrieval.stats_block()
+        assert after["host_reads"] == before["host_reads"] + 1
+        assert after["uploads"] == before["uploads"] + 1
+        for lo in range(0, b, 16):
+            one_s, one_i = self._scan(
+                cat, q[lo: lo + 16], self.K, self._rows(rules, lo, lo + 16)
+            )
+            n = min(16, b - lo)
+            np.testing.assert_array_equal(
+                s[lo: lo + n].view(np.uint32), one_s[:n].view(np.uint32)
+            )
+            np.testing.assert_array_equal(ids[lo: lo + n], one_i[:n])
+
+    @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
+    @pytest.mark.parametrize("b", [17, 24, 40])
+    def test_a_batch_that_is_no_power_of_two_ends_in_what_is_left(
+            self, b, ruled):
+        """No served caller sends one (``shortlist``, ``pack`` and the
+        sharded path pad to a power of two); a direct call is cut into
+        chunks of 16 and a last one of 1 or 8, each the answer of the
+        same queries alone."""
+        cat, table = _sides_catalog("bf16", 1 << 14)
+        assert retrieval.scan_chunk(b, 64, "bf16", PAST) == 16
+        q = _dense(b, 64, seed=102 + b)
+        rules = self._own_rules(cat.stored_rows, len(table[0]), b, 103) if ruled \
+            else None
+        s, ids = self._scan(cat, q, self.K, rules)
+        assert s.shape == ids.shape == (b, self.K)
+        for lo in range(0, b, 16):
+            one_s, one_i = self._scan(
+                cat, q[lo: lo + 16], self.K, self._rows(rules, lo, lo + 16)
+            )
+            np.testing.assert_array_equal(
+                s[lo: lo + 16].view(np.uint32), one_s.view(np.uint32)
+            )
+            np.testing.assert_array_equal(ids[lo: lo + 16], one_i)
+
+    @pytest.mark.parametrize("mode,d,b", [
+        ("bf16", 10, 64), ("int8", 16, 64), ("int8", 32, 8), ("int8_dot", 32, 16),
+    ])
+    def test_a_catalog_under_the_uncut_bytes_is_one_pass_at_any_rank(
+            self, monkeypatch, mode, d, b):
+        """The ALS templates' default rank 10 and bench.py's rank 32:
+        beyond the first bound (2 queries at rank 10, and at rank 16
+        over int8 values, 4 at rank 32), and ONE loop over the tiles wherever the
+        stored scores are under ``_UNCUT`` — 64 queries of a 5,000-row
+        catalog are 1.5 MB — with the answer of the chunks."""
+        rows = 5_000
+        table = _int8(rows, d, seed=104)
+        cat = CoarseCatalog(table, tile=1 << 11, mode=mode)
+        q = _dense(b, d, seed=105)
+        c = retrieval.scan_chunk(b, d, mode, PAST)
+        assert c == max(1, d * (2 if mode == "bf16" else 1) // 8) < b
+        cut = self._scan(cat, q, self.K, None)  # a loop a chunk (conftest)
+        monkeypatch.setattr(retrieval, "_UNCUT", 16 * 36 * (1 << 18) * 4)
+        assert retrieval.scan_chunk(b, d, mode, cat.stored_rows) == b
+        nt, t, _ = cat._tiles.shape
+        jaxpr = _scan_jaxpr(b, nt, t, d, self.K, None, mode)
+        assert len(_tile_loops(jaxpr)) == 1
+        s, ids = self._scan(cat, q, self.K, None)
+        np.testing.assert_array_equal(s.view(np.uint32), cut[0].view(np.uint32))
+        np.testing.assert_array_equal(ids, cut[1])
+
+    @pytest.mark.parametrize("ruled", [False, True], ids=["open", "rules"])
+    @pytest.mark.parametrize("mode,d", [("bf16", 2), ("int8", 4), ("int8_dot", 7)])
+    def test_the_smallest_chunk_is_one_query(self, mode, d, ruled):
+        """Rank 2 in bf16 (4, 7 over int8 values): four bytes of stored
+        values a row against four of one query's score — at the parent
+        a SINGLE "did not fit". One query is a pass; four are four
+        passes of one, each the single's answer bit for bit."""
+        assert retrieval.scan_chunk(1, d, mode, PAST) == 1
+        assert retrieval.scan_chunk(4, d, mode, PAST) == 1
+        rows = 2 * 96 + 30
+        table = _int8(rows, d, seed=99)
+        cat = CoarseCatalog(table, tile=96, mode=mode)
+        q = _dense(4, d, seed=100)
+        rules = self._own_rules(cat.stored_rows, rows, 4, 101) if ruled else None
+        s, ids = self._scan(cat, q, self.K, rules)
+        sc = _coarse_scores(cat, table, q)
+        for r in range(4):
+            one_s, one_i = self._scan(
+                cat, q[r: r + 1], self.K, self._rows(rules, r, r + 1)
+            )
+            np.testing.assert_array_equal(
+                s[r: r + 1].view(np.uint32), one_s.view(np.uint32)
+            )
+            np.testing.assert_array_equal(ids[r: r + 1], one_i)
+            live = ids[r] >= 0
+            assert live.sum() == len(set(ids[r][live].tolist())) > 0
+            np.testing.assert_allclose(
+                s[r][live], sc[r][ids[r][live]], rtol=1e-5, atol=1e-5
+            )
+            if not ruled:  # NumPy's selection, to rounding
+                rest = np.ones(rows, bool)
+                rest[ids[r]] = False
+                assert live.all() and sc[r][rest].max() <= s[r].min() + 1e-4
 
 
 class TestServingChain:
@@ -2187,17 +2460,21 @@ class TestPackedDispatch:
         else:  # a category case's odd rows are held to the small one
             assert (ids[:: 2 if case == "category" else 1] >= 0).all()
 
+    @pytest.mark.parametrize("b", [3, 16])
     @pytest.mark.parametrize("form,uploads", [
         ("user_rows", 2), ("vectors", 1), ("vectors_rules", 1), ("sum_rows", 1),
     ])
-    def test_uploads_a_dispatch(self, chain, form, uploads):
+    def test_uploads_a_dispatch(self, chain, form, uploads, b):
         """A form without rules goes up as it always did — its vectors,
         then (``UserRows``) its indices behind the running scan — and a
         form under rules in one buffer; the reference path on separate
-        arrays counts each of its own."""
+        arrays counts each of its own. Sixteen queries are scanned in
+        chunks (``scan_chunk``) of the one program: as many uploads."""
         host, table, coarse = chain
+        assert retrieval.scan_chunk(retrieval._pow2(b), self.D, "bf16", PAST) == min(
+            4, self.D // 4)
         one = TestOneCrossing()
-        query, host_rescore = one._form(form, 3, table, host, coarse.stored_rows)
+        query, host_rescore = one._form(form, b, table, host, coarse.stored_rows)
         before = retrieval.stats_block()["uploads"]
         retrieval.top_k(query, table, self.I, coarse, self.K)
         assert retrieval.stats_block()["uploads"] == before + uploads

@@ -1,7 +1,7 @@
 """scan_alone.py (the chip measurement behind PERF.md's scan tables):
-it takes no time off a TPU, and at toy shapes its two bodies, its two
-side forms and a single's step forms run on one input and agree — so the
-script cannot rot between the PRs that use it."""
+it takes no time off a TPU, and at toy shapes its two side forms, a
+batch in chunks and a single's step forms run on one input and agree —
+so the script cannot rot between the PRs that use it."""
 
 import json
 import re
@@ -27,19 +27,41 @@ def test_the_five_configurations_scan_four_shapes():
     assert tiles == {"retrieval-yambda": 36, "ecommerce-taobao": 16,
                      "similarproduct-taobao": 16,
                      "recommendation-amazon23": 46,
-                     "recommendation-amazon23-int8": 184}
+                     "recommendation-amazon23-int8": 184,
+                     "rank10-1m": 4, "rank10-9m": 36, "rank32-int8-1m": 4,
+                     "rank32-int8-9m": 36, "rank10-48m": 184}
     assert scan_alone.SHAPES["recommendation-amazon23-int8"]["modes"] == \
         ("int8", "int8_dot")
     assert scan_alone.SHAPES["ecommerce-taobao"] == \
         scan_alone.SHAPES["similarproduct-taobao"]
 
 
+@pytest.mark.parametrize("name,chunk", [
+    ("retrieval-yambda", 16), ("ecommerce-taobao", 32),
+    ("recommendation-amazon23", 16), ("recommendation-amazon23-int8", 8),
+    ("rank10-1m", 64), ("rank10-9m", 16), ("rank32-int8-1m", 64),
+    ("rank32-int8-9m", 16), ("rank10-48m", 2),
+])
+def test_what_a_pass_takes_of_64_queries_at_each_shape(monkeypatch, name, chunk):
+    """With the bytes no pass is cut under (conftest.py takes them away
+    for the CPU fixtures): the cells' shapes keep the chunk the first
+    bound gives them, and below rank 64 only a catalog of tens of
+    millions of rows is cut into small ones."""
+    monkeypatch.setattr(retrieval, "_UNCUT", 16 * 36 * (1 << 18) * 4)
+    shape = scan_alone.SHAPES[name]
+    nt = -(-shape["rows"] // scan_alone.TILE)
+    for mode in shape.get("modes", ("bf16",)):
+        assert retrieval.scan_chunk(
+            64, shape["rank"], mode, nt * scan_alone.TILE) == chunk
+
+
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
 T = 1 << 13
 
 
 def _answers(monkeypatch, shape, b, mode="bf16"):
-    """{(sides, body): host (scores, ids)} of every program a row of
-    the table runs, at a toy tile."""
+    """{sides: host (scores, ids)} of every program a row of the table
+    runs, at a toy tile."""
     monkeypatch.setattr(scan_alone, "TILE", T)
     out = {}
     for sides in scan_alone.SIDES:
@@ -51,10 +73,7 @@ def _answers(monkeypatch, shape, b, mode="bf16"):
         assert args[1].shape == (3, T, 64) and int(side.min()) == -1
         if mode != "bf16":
             assert args[1].dtype == np.int8 and args[2].shape == side.shape
-        for body in scan_alone.BODIES:
-            out[sides, body] = jax.device_get(
-                scan_alone._scan(128, body, mode)(*args)
-            )
+        out[sides] = jax.device_get(scan_alone._scan(128, mode)(*args))
     return out
 
 
@@ -66,21 +85,43 @@ def _all_agree(answers):
     return i0
 
 
+def _chunks_alone(monkeypatch, shape, b, mode="bf16"):
+    """The batch a chunk of the queries (and of the rules' per-query
+    rows) at a time, each a call of its own within the bound."""
+    monkeypatch.setattr(scan_alone, "TILE", T)
+    args = scan_alone._arguments(shape, b, scan_alone._device_array, mode)
+    c = retrieval.scan_chunk(b, shape["rank"], mode, PAST)
+    parts = []
+    for lo in range(0, b, c):
+        own = [args[0][lo: lo + c], *args[1:]]
+        if shape["rules"]:
+            own[-1] = retrieval._query_rows(args[-1], lo, lo + c)
+        parts.append(jax.device_get(scan_alone._scan(128, mode)(*own)))
+    return tuple(np.concatenate(p) for p in zip(*parts))
+
+
 @pytest.mark.parametrize("rules", [False, True])
-@pytest.mark.parametrize("b", [1, 8])
-def test_both_bodies_and_both_side_forms_on_one_input_agree(monkeypatch, b, rules):
+@pytest.mark.parametrize("b", [1, 8, 32])
+def test_both_side_forms_and_the_chunks_alone_on_one_input_agree(
+        monkeypatch, b, rules):
+    """32 queries of rank 64 are two chunks of 16 in one program: the
+    answer of two calls of 16."""
     shape = dict(rows=2 * T + 1000, rank=64, rules=rules)
-    assert retrieval.scan_select(b, 3, T, 128, 64) == "deferred"
-    ids = _all_agree(_answers(monkeypatch, shape, b))
+    assert retrieval.scan_chunk(b, 64, "bf16", PAST) == min(b, 16)
+    answers = _answers(monkeypatch, shape, b)
+    answers["alone"] = _chunks_alone(monkeypatch, shape, b)
+    ids = _all_agree(answers)
     assert ids.max() < shape["rows"]
 
 
 @pytest.mark.parametrize("mode", ["int8", "int8_dot"])
-@pytest.mark.parametrize("b", [1, 8])
+@pytest.mark.parametrize("b", [1, 8, 16])
 def test_they_agree_over_int8_tiles(monkeypatch, b, mode):
     shape = dict(rows=2 * T + 1000, rank=64, rules=False)
-    assert retrieval.scan_select(b, 3, T, 128, 64, mode) == "deferred"
-    ids = _all_agree(_answers(monkeypatch, shape, b, mode))
+    assert retrieval.scan_chunk(b, 64, mode, PAST) == min(b, 8)
+    answers = _answers(monkeypatch, shape, b, mode)
+    answers["alone"] = _chunks_alone(monkeypatch, shape, b, mode)
+    ids = _all_agree(answers)
     assert 0 <= ids.min() and ids.max() < shape["rows"]
 
 
@@ -112,7 +153,7 @@ def test_a_singles_step_forms_answer_as_the_served_step(monkeypatch, step, mode)
     """Every candidate step on the served step's input: scores
     bit-equal, ids equal — a padded last tile among the three."""
     args = _single_arguments(monkeypatch, mode)
-    served = jax.device_get(scan_alone._scan(128, "deferred", mode)(*args))
+    served = jax.device_get(scan_alone._scan(128, mode)(*args))
     got = jax.device_get(scan_alone._single(128, step, mode)(*args))
     ids = _all_agree({"served": served, step: got})
     assert 0 <= ids.min() and ids.max() < 2 * T + 1000
@@ -129,11 +170,11 @@ def test_the_twice_form_is_the_served_step_without_its_barrier(monkeypatch, mode
 
     args = _single_arguments(monkeypatch, mode)
     twice = text(scan_alone._single(128, "twice", mode))
-    served = text(scan_alone._scan(128, "deferred", mode))
+    served = text(scan_alone._scan(128, mode))
     assert served.count("optimization_barrier") == 1
     assert "optimization_barrier" not in twice
     monkeypatch.setattr(retrieval, "_kept_once", lambda kept: kept)
-    bare = text(scan_alone._scan(128, "deferred", mode))
+    bare = text(scan_alone._scan(128, mode))
     assert bare == twice
 
 
@@ -162,3 +203,26 @@ def test_the_step_axis_is_for_dot_form_singles_alone(monkeypatch, capsys):
     assert [set(r.get("steps", ())) for r in rows] == [
         {"twice", "after"}, set(), set(), set(), set(), set(),
     ]
+    assert all(r["chunks"] == 1 and r["k"] == 128 and "scan" in r for r in rows)
+
+
+@pytest.mark.parametrize("kp,group", [(128, 16), (8192, 0)])
+def test_a_row_says_its_chunks_and_its_shortlist_size(
+        monkeypatch, capsys, kp, group):
+    """A batch beyond the bound: the row's ``chunks``, its ``k`` and its
+    group width (none at a k' that nears the catalog), and one
+    program's temporaries."""
+    monkeypatch.setattr(scan_alone, "TILE", T)
+    monkeypatch.setattr(scan_alone, "KP", kp)
+    monkeypatch.setattr(scan_alone, "described_chip", lambda: None)
+    monkeypatch.setitem(scan_alone.SHAPES, "toy", dict(
+        rows=2 * T + 1000, rank=64, rules=False, modes=("bf16", "int8")))
+    assert scan_alone.main(["--compile-only", "--shapes", "toy", "--sides",
+                            "lanes", "--batches", "16,64", "--steps", ""]) == 0
+    rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+            if line.startswith("{")]
+    assert [(r["mode"], r["k"], r["b"], r["chunks"], r["group"]) for r in rows] == [
+        ("bf16", kp, 16, 1, group), ("bf16", kp, 64, 4, group),
+        ("int8", kp, 16, 2, group), ("int8", kp, 64, 8, group),
+    ]
+    assert all(set(r["scan"]) == {"temp_mb"} for r in rows)

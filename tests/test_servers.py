@@ -49,6 +49,7 @@ def event_server(storage):
     server.stop()
 
 
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
 EVENT = {
     "event": "rate",
     "entityType": "user",
@@ -1369,14 +1370,19 @@ class TestQueryCacheServing:
         assert status == 200
         assert body["cache"] == {"enabled": False}
 
-    def test_stats_route_carries_the_tile_select_counter(self, deployed_engine):
+    def test_stats_route_carries_the_retrieval_block_and_no_choice_of_body(
+            self, deployed_engine):
+        """The block is ``retrieval.stats_block()``'s; a scan has one
+        step body, so nothing counts a choice (``tile_select`` is gone)."""
         from predictionio_tpu.ops import retrieval
 
         status, body = http("GET", deployed_engine["base"] + "/stats.json")
         assert status == 200
-        block = body["retrieval"]["tile_select"]
-        assert set(block) == {"deferred", "two_level", "plain"}
-        assert block == retrieval.stats_block()["tile_select"]
+        assert set(body["retrieval"]) == set(retrieval.stats_block())
+        assert "tile_select" not in body["retrieval"]
+        with urllib.request.urlopen(
+                deployed_engine["base"] + "/metrics", timeout=10) as r:
+            assert b"tile_select" not in r.read()
 
     def test_stats_route_carries_the_upload_counter(self, deployed_engine):
         """``retrieval.uploads`` beside ``retrieval.host_reads``: the
@@ -1407,17 +1413,17 @@ class TestQueryCacheServing:
         cat.shortlist(rng.normal(size=(3, 8)).astype(np.float32), 16)
         assert read() == before + 1
 
-    @pytest.mark.parametrize("batch,path", [
-        (1, "deferred"), (2, "deferred"), (4, "two_level"),
+    @pytest.mark.parametrize("batch,form", [
+        (1, "dot"), (2, "rows"), (4, "rows"),
     ])
-    def test_a_shortlist_call_moves_its_path_by_one_on_both_routes(
-        self, deployed_engine, batch, path
+    def test_a_shortlist_call_moves_its_counters_by_one_on_both_routes(
+        self, deployed_engine, batch, form
     ):
         """``/stats.json`` and ``/metrics`` of the engine server read the
-        counter that the shortlist call of its process counts: a single
-        query's call (tiles wide enough to split) is ``deferred``, and
-        so is a batched dispatch's while its stored scores fit (rank 8
-        in bf16: two queries)."""
+        counters that the shortlist call of its process counts: its
+        score form, ONE upload and ONE blocking read — also where the
+        batch is scanned in chunks (rank 8 in bf16: two queries a pass,
+        so four are two chunks of one program)."""
         import numpy as np
 
         from predictionio_tpu.obs import metrics as obs_metrics
@@ -1430,22 +1436,26 @@ class TestQueryCacheServing:
             assert status == 200
             with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
                 scraped = obs_metrics.parse_prometheus(r.read().decode())
-            block = body["retrieval"]["tile_select"]
-            for p, n in block.items():
+            block = body["retrieval"]
+            for f, n in block["score_form"].items():
                 assert scraped[
-                    f'pio_retrieval_tile_select_total{{path="{p}"}}'
+                    f'pio_retrieval_score_form_total{{form="{f}"}}'
                 ] == n
-            return block
+            assert scraped["pio_retrieval_uploads_total"] == block["uploads"]
+            assert scraped["pio_retrieval_host_reads_total"] == block["host_reads"]
+            return {**block["score_form"], "uploads": block["uploads"],
+                    "host_reads": block["host_reads"]}
 
         rng = np.random.default_rng(7)
         cat = retrieval.CoarseCatalog(
             rng.normal(size=(2 * 8192 + 3, 8)).astype(np.float32), tile=8192
         )
+        assert batch // retrieval.scan_chunk(batch, 8, "bf16", PAST) == (2 if batch == 4 else 1)
         before = read()
         cat.shortlist(rng.normal(size=(batch, 8)).astype(np.float32), 16)
         after = read()
         for p in after:
-            assert after[p] == before[p] + (p == path)
+            assert after[p] == before[p] + (p in (form, "uploads", "host_reads"))
 
     def test_stats_route_carries_the_score_form_counter(self, deployed_engine):
         from predictionio_tpu.ops import retrieval

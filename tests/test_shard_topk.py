@@ -16,6 +16,8 @@ from predictionio_tpu.parallel import shard_topk
 from predictionio_tpu.parallel.mesh import make_mesh, parse_axes, serving_mesh
 from predictionio_tpu.parallel.shard_topk import ShardedCatalog
 
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
+
 
 @pytest.fixture(scope="module")
 def mesh4():
@@ -78,34 +80,31 @@ class TestShardedChain:
         np.testing.assert_array_equal(ids, one[1])
         np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
 
-    @pytest.mark.parametrize("batch,path", [
-        (1, "deferred"), (2, "deferred"), (4, "deferred"), (8, "two_level"),
-    ])
+    @pytest.mark.parametrize("batch,chunks", [(1, 1), (2, 1), (4, 1), (8, 2)])
     @pytest.mark.parametrize("items", [4 * 2 * 8192, 70_001])
     def test_tiles_wide_enough_to_split_give_the_one_chip_answer(
-        self, mesh4, two_stage, monkeypatch, items, batch, path
+        self, mesh4, two_stage, monkeypatch, items, batch, chunks
     ):
         """Tiles of 8,192 rows, two or three a shard: a scan under
-        ``shard_map`` selects once after each shard's loop
-        (``scan_select`` -> "deferred") — a single's, a pair's, a batch
-        of four's; eight queries of this rank would store more than
-        half of what a step reads and select in every step. All serve
-        the one-chip chain's answer and the plain reference's."""
+        ``shard_map`` selects once after each shard's loop — a
+        single's, a pair's, a batch of four's; eight queries of this
+        rank would store more than half of what a step reads and are
+        two chunks of four on every shard, in the one program. All
+        serve the one-chip chain's answer and the plain reference's."""
         monkeypatch.setenv("PIO_RETRIEVAL_TILE", "8192")
         U, V = _tables(items, seed=12)
         cat = ShardedCatalog(V, mesh4)
         kp = retrieval.two_stage_k(16, len(V))
         assert cat.tile == 8192 and cat.tiles_per_shard == -(-items // 4 // 8192)
         assert cat._ids.shape == (4 * cat.tiles_per_shard, 64, 128)
-        assert retrieval.scan_select(
-            batch, cat.tiles_per_shard, cat.tile, kp, cat.dim, cat.mode
-        ) == path
+        assert retrieval.select_group(cat.tile, kp, cat.tiles_per_shard)
+        assert batch // retrieval.scan_chunk(batch, cat.dim, cat.mode, PAST) == chunks
         uix = np.arange(batch)
-        before = retrieval.stats_block()["tile_select"]
+        before = retrieval.stats_block()
         s, ids = _served(cat, U, uix, len(V), 16)
-        after = retrieval.stats_block()["tile_select"]
-        for p_ in after:
-            assert after[p_] == before[p_] + (p_ == path)
+        after = retrieval.stats_block()
+        assert after["host_reads"] == before["host_reads"] + 1
+        assert after["uploads"] == before["uploads"] + 1
         rs, ri = _reference(U[:batch], V, 16)
         np.testing.assert_array_equal(ids, ri)
         np.testing.assert_allclose(s, rs, rtol=0, atol=4e-6 * np.abs(rs).max())
@@ -117,9 +116,9 @@ class TestShardedChain:
         np.testing.assert_allclose(s, one[0], rtol=0, atol=4e-6)
 
     def test_the_sharded_singles_scan_body_selects_nothing(self, mesh4):
-        """The lowered sharded program at B = 1 and B = 2: no ``sort``
-        inside the scan's ``while``; at B = 16 (rank 16: beyond the
-        bound) the step still sorts."""
+        """The traced sharded program at B = 1, B = 2 and B = 16 (rank
+        16: beyond the bound, four chunks): no ``sort`` / ``top_k``
+        inside the loop over the tiles; the selections follow it."""
         nt, t, d = 2, 8192, 16
         shapes = lambda b: (  # noqa: E731
             jax.ShapeDtypeStruct((b, d), np.float32),
@@ -129,18 +128,22 @@ class TestShardedChain:
         )
 
         def selections(jp, in_loop=False):
-            """``sort`` / ``top_k`` equations under a ``scan``."""
-            n = 0
+            """(``sort`` / ``top_k`` equations under the scan over the
+            ``nt`` tiles, those anywhere else)."""
+            inside = outside = 0
             for e in jp.eqns:
-                n += in_loop and e.primitive.name in ("sort", "top_k")
+                hit = e.primitive.name in ("sort", "top_k")
+                inside, outside = inside + (hit and in_loop), outside + (
+                    hit and not in_loop)
+                tiles = (e.primitive.name == "scan"
+                         and e.params["length"] == nt)
                 for v in e.params.values():
                     for sub in v if isinstance(v, (list, tuple)) else (v,):
                         inner = getattr(sub, "jaxpr", sub)
                         if hasattr(inner, "eqns"):
-                            n += selections(
-                                inner, in_loop or e.primitive.name == "scan"
-                            )
-            return n
+                            i, o = selections(inner, in_loop or tiles)
+                            inside, outside = inside + i, outside + o
+            return inside, outside
 
         def loop_sorts(b):
             return selections(jax.make_jaxpr(
@@ -150,9 +153,12 @@ class TestShardedChain:
                 )
             )(*shapes(b)).jaxpr)
 
-        assert loop_sorts(1) == 0
-        assert loop_sorts(2) == 0
-        assert loop_sorts(16) == 3
+        # after the loop: the maxima's, the candidates', the rescore's
+        # and the merge's
+        assert loop_sorts(1) == (0, 4)
+        assert loop_sorts(2) == (0, 4)
+        assert retrieval.scan_chunk(16, d, "bf16", PAST) == 4
+        assert loop_sorts(16) == (0, 2 * 4 + 2)  # a loop and two a chunk
 
     @pytest.mark.parametrize("batch,barriers", [(1, 1), (2, 0)])
     def test_the_four_chip_chain_runs_the_one_chip_step(self, mesh4, batch, barriers):

@@ -59,14 +59,23 @@ def _compiled(chip, b, nt, d, mode="bf16", sides=None, program=None):
     return _COMPILED[key]
 
 
+PAST = 1 << 30  # rows of a catalog whose stored scores are past retrieval._UNCUT
 _COMPILED = {}
 
 
+def _loop_texts(text):
+    """The ``while`` bodies in a compiled module's text: the scan's, one
+    a chunk."""
+    bodies = []
+    for name in re.findall(r"while\(.*?body=%?([\w.\-]+)", text):
+        start = text.index(f"\n%{name} ")
+        bodies.append(text[start: text.index("\n}\n", start)])
+    return bodies
+
+
 def _loop_text(text):
-    """The scan's ``while`` body in a compiled module's text."""
-    name = re.search(r"while\(.*?body=%?([\w.\-]+)", text).group(1)
-    start = text.index(f"\n%{name} ")
-    return text[start: text.index("\n}\n", start)]
+    """The (first) scan's ``while`` body in a compiled module's text."""
+    return _loop_texts(text)[0]
 
 
 def _loop_body(text):
@@ -88,8 +97,8 @@ def test_a_batchs_step_selects_nothing_and_stores_its_scores(one_chip, b, nt, d)
     """The compiled loop body of a served batch holds no ``sort`` (no
     selection, no merge), and the program's temporaries are the stored
     scores (B x NT x T x 4 bytes) and a little: what the bound of
-    ``scan_select`` reckons with."""
-    assert retrieval.scan_select(b, nt, TILE, KP, d) == "deferred"
+    ``scan_chunk`` reckons with."""
+    assert retrieval.scan_chunk(b, d, "bf16", PAST) == b
     compiled = _compiled(one_chip, b, nt, d)
     ops = _loop_body(compiled.as_text())
     assert ops and not [o for o in ops if o[1] == "sort"]
@@ -106,13 +115,54 @@ def test_a_singles_temporaries_stay_where_they_were(one_chip):
     assert not [o for o in _loop_body(compiled.as_text()) if o[1] == "sort"]
 
 
-def test_a_batch_beyond_the_bound_stores_nothing(one_chip):
-    """64 queries at rank 64 (2.4 GB of scores against 1.2 GB of tiles):
-    the per-tile body, whose temporaries are the merge's."""
-    assert retrieval.scan_select(64, 36, TILE, KP, 64) == "two_level"
-    compiled = _compiled(one_chip, 64, 36, 64)
-    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
-    assert "f32[36," not in compiled.as_text()  # no scores stacked by tile
+@pytest.mark.parametrize("b", [32, 64])
+def test_a_batch_beyond_the_bound_is_chunks_within_it(one_chip, b):
+    """32 and 64 queries at rank 64 (1.2 / 2.4 GB of scores against
+    1.2 GB of tiles): two and four loops of 16 queries, one after
+    another in the one program — no ``sort`` in any of them, no copy of
+    the tiles, and the temporaries of ONE chunk's stored scores: B = 16's
+    and under 64 MB more."""
+    assert retrieval.scan_chunk(b, 64, "bf16", PAST) == 16
+    compiled = _compiled(one_chip, b, 36, 64)
+    text = compiled.as_text()
+    loops = _loop_texts(text)
+    assert len(loops) == b // 16
+    for body in loops:
+        assert " sort(" not in body and "f32[36,16,2048,128]" in body
+    assert f"f32[36,{b}," not in text  # no more than a chunk's scores stacked
+    assert not re.search(r"bf16\[36,262144,64\]\S* copy\(", text)
+    sixteen = _compiled(one_chip, 16, 36, 64).memory_analysis().temp_size_in_bytes
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert sixteen <= temp <= sixteen + (64 << 20)
+
+
+@pytest.mark.parametrize("nt,d,mode,loops", [
+    (4, 10, "bf16", 1),    # the templates' default rank over 1 M rows
+    (4, 32, "int8", 1),    # bench.py's retrieval rung
+    (36, 10, "bf16", 4),   # the default rank over yambda's rows
+    (36, 32, "int8", 4),
+])
+def test_below_rank_64_a_pass_takes_what_stores_604_mb(
+        one_chip, monkeypatch, nt, d, mode, loops):
+    """64 queries under rank 64, where the first bound alone would make
+    32, 16 or 64 loops of them: ONE loop over 1 M rows, four of 16 over
+    9.4 M, no ``sort`` in any, and — the rank-10 tiles are re-laid, 16
+    values a row — one copy of the tiles a call, not one a chunk: the
+    temporaries of 16 queries and under 64 MB more."""
+    monkeypatch.setattr(retrieval, "_UNCUT", 16 * 36 * TILE * 4)
+    assert retrieval.scan_chunk(64, d, mode, nt * TILE) == 64 // loops
+    # these shapes are no other test's: nothing traced without the bytes
+    text = _compiled(one_chip, 64, nt, d, mode).as_text()
+    bodies = _loop_texts(text)
+    assert len(bodies) == loops
+    assert not [body for body in bodies if " sort(" in body]
+    assert f"f32[{nt},64," not in text or loops == 1
+    if loops > 1:
+        sixteen = _compiled(one_chip, 16, nt, d, mode)
+        assert len(_loop_texts(sixteen.as_text())) == 1
+        temp = _compiled(one_chip, 64, nt, d, mode).memory_analysis()
+        assert temp.temp_size_in_bytes <= (
+            sixteen.memory_analysis().temp_size_in_bytes + (64 << 20))
 
 
 @pytest.mark.parametrize("b", [1, 8])

@@ -442,9 +442,20 @@ def test_serving_programs_carry_their_scopes():
     hlo = retrieval._coarse_topk.lower(
         jnp.ones((2, 8)), tiles, None, ids, k=4, mode="bf16"
     ).compile().as_text()
+    # a catalog too small to split into groups: the step scores and
+    # keeps, one ``top_k`` of the row follows the loop
     assert set(re.findall(r"retrieval\.shortlist\.\w+", hlo)) == {
-        "retrieval.shortlist.score", "retrieval.shortlist.tile_topk",
-        "retrieval.shortlist.merge",
+        "retrieval.shortlist.score", "retrieval.shortlist.select",
+    }
+    hlo = retrieval._coarse_topk.lower(
+        jnp.ones((2, 8)), jnp.ones((3, 4096, 8), jnp.bfloat16), None,
+        jnp.arange(3 * 4096, dtype=jnp.int32).reshape(3, 32, 128),
+        k=16, mode="bf16",
+    ).compile().as_text()
+    assert retrieval.select_group(4096, 16, 3) == 128
+    assert set(re.findall(r"retrieval\.shortlist\.\w+", hlo)) == {
+        "retrieval.shortlist.score", "retrieval.shortlist.group_max",
+        "retrieval.shortlist.select",
     }
     hlo = retrieval._rescore_gather.lower(
         jnp.zeros((2,), jnp.int32), jnp.ones((4, 8)), jnp.ones((48, 8)),
