@@ -69,6 +69,13 @@ class BiMap(Generic[K, V]):
         """The value->key view (reference BiMap.inverse)."""
         return self._inverse
 
+    def appended(self, keys: Sequence[K]) -> "BiMap[K, V]":
+        """This dense ``key -> index`` map plus ``keys`` at the next
+        indices (len, len + 1, ...), as a NEW map; this one is unchanged.
+        The cost is the appended keys', never the map's: a served model's
+        million-id index gains a user a patch without being copied."""
+        return _Appended(self, keys)
+
     def take(self, keys: Iterable[K]) -> "BiMap[K, V]":
         return BiMap({k: self._m[k] for k in keys if k in self._m})
 
@@ -98,6 +105,77 @@ class BiMap(Generic[K, V]):
         return BiMap({k: i for i, k in enumerate(ids)})
 
     # -- vectorized paths --------------------------------------------------
+    def index_of(self, keys: Sequence[K]) -> np.ndarray:
+        """[len(keys)] int64 indices of ``keys`` in a dense ``key ->
+        index`` map, -1 for a key it does not hold. What a caller with a
+        handful of keys and a map of tens of millions asks (a fold-in's
+        item ids): a map over an encoded dictionary answers it without
+        decoding the dictionary (``modelfile._LazyDenseBiMap``)."""
+        get = self._m.get
+        return np.fromiter((get(k, -1) for k in keys), np.int64, len(keys))
+
     def to_index_array(self, keys: Sequence[K]) -> np.ndarray:
         """Bulk key->index conversion to an int32 numpy array."""
         return np.fromiter((self._m[k] for k in keys), dtype=np.int32, count=len(keys))
+
+
+class _Appended(BiMap):
+    """``BiMap.appended``: a dense map read through, plus the few keys
+    appended since, in a dictionary of their own. Point reads (``[]``,
+    ``get``, ``in``, ``len``) touch the base map or that dictionary; only
+    a caller that walks the whole mapping pays for one merged dictionary.
+    Never calls ``BiMap.__init__`` (as ``modelfile._LazyDenseBiMap``)."""
+
+    def __init__(self, base: BiMap, keys: Sequence, _forward: "_Appended | None" = None):
+        if _forward is not None:  # the inverse view of ``_forward``
+            self._base, self._inv = _forward._base.inverse, _forward
+            self._extra = {v: k for k, v in _forward._extra.items()}
+            self._merged = None
+            return
+        extra = {}
+        if isinstance(base, _Appended):
+            base, extra = base._base, dict(base._extra)
+        n = len(base) + len(extra)
+        keys = list(keys)
+        held = base.index_of(keys) >= 0
+        for i, k in enumerate(keys):
+            if held[i] or k in extra:
+                raise BiMapError(f"{k!r} is in the map already")
+            extra[k] = n + i
+        self._base, self._extra = base, extra
+        self._inv = self._merged = None
+
+    @property
+    def _m(self) -> dict:
+        if self._merged is None:
+            self._merged = {**self._base._m, **self._extra}
+        return self._merged
+
+    @property
+    def _inverse(self) -> BiMap:
+        if self._inv is None:
+            self._inv = _Appended(None, (), _forward=self)
+        return self._inv
+
+    def __getitem__(self, key):
+        v = self._extra.get(key, self)
+        return self._base[key] if v is self else v
+
+    def get(self, key, default=None):
+        v = self._extra.get(key, self)
+        return self._base.get(key, default) if v is self else v
+
+    def __contains__(self, key) -> bool:
+        return key in self._extra or key in self._base
+
+    def __len__(self) -> int:
+        return len(self._base) + len(self._extra)
+
+    def index_of(self, keys: Sequence) -> np.ndarray:
+        out = self._base.index_of(keys)
+        for j in np.flatnonzero(out < 0).tolist():
+            out[j] = self._extra.get(keys[j], -1)
+        return out
+
+    def __reduce__(self):
+        return (BiMap, (self._m,))

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Sequence
@@ -41,6 +42,7 @@ from predictionio_tpu.core import (
 )
 from predictionio_tpu.data import store
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.obs import metrics as obs_metrics
 from predictionio_tpu.ops import als as als_ops
 
 logger = logging.getLogger(__name__)
@@ -253,6 +255,15 @@ class ALSAlgorithmParams(Params):
     sharded_gather_budget_bytes: int | None = None
 
 
+# one staging at a time: a table goes up once, whichever thread asks first
+_STAGING = threading.RLock()
+_m_capacity = obs_metrics.gauge(
+    "pio_model_user_capacity_rows",
+    "Rows the resident user table has room for (the rows held, or the "
+    "power of two above them that reserve_user_rows set)",
+)
+
+
 @dataclass
 class ALSModel:
     """Host-persistable factor model; device arrays materialized lazily.
@@ -271,10 +282,18 @@ class ALSModel:
     user_scales: np.ndarray | None = None  # [U] float32 when int8
     item_scales: np.ndarray | None = None  # [I] float32 when int8
 
+    _user_capacity = 0  # (a model pickled before PR 45 has no such entry)
+    # bytes a fold-in sent up to make this model of the one before it
+    # (``patched``); None for a model that was loaded or built whole
+    patch_h2d_bytes = None
+
     def __post_init__(self):
         self._device = None
         self._sharded = None
         self._coarse = None
+        # rows the resident user table has room for: 0 = as many as are
+        # held (``reserve_user_rows``: a deployment with --realtime)
+        self._user_capacity = 0
 
     def user_rows(self, ixs):
         """Dense f32 user vectors for the given indices (dequantizes
@@ -296,30 +315,92 @@ class ALSModel:
         tables stay (values, scales) pairs on device. A table goes up
         as it is stored — int8 values stay int8 on the host and on the
         chip — and one that spans model files a part at a time
-        (``retrieval.put_rows``): never one host array."""
+        (``retrieval.put_rows``): never one host array. Staged once,
+        whichever thread asks first (the batch worker, the fold)."""
         if self._device is None:
-            import jax
-            import jax.numpy as jnp
-
-            from predictionio_tpu.obs import trace as obs_trace
-            from predictionio_tpu.ops import retrieval
-
-            def put(values, scales):
-                rows = retrieval.put_rows(values)
-                return rows if scales is None else (rows, jnp.asarray(scales))
-
-            with obs_trace.region(
-                "model.stage_table",
-                hist=retrieval._m_load["stage_to_device"],
-            ):
-                users, items = jax.block_until_ready((
-                    put(self.user_factors, self.user_scales),
-                    put(self.item_factors, self.item_scales),
-                ))
-            values, scales = items if isinstance(items, tuple) else (items, None)
-            retrieval.set_resident(users=users, table=values, table_scales=scales)
-            self._device = (users, items)
+            with _STAGING:
+                if self._device is None:
+                    self._device = self._stage_factors()
         return self._device
+
+    def _stage_factors(self):
+        import jax
+
+        from predictionio_tpu.obs import trace as obs_trace
+        from predictionio_tpu.ops import retrieval
+
+        with obs_trace.region(
+            "model.stage_table",
+            hist=retrieval._m_load["stage_to_device"],
+        ):
+            users, items = jax.block_until_ready((
+                self._stage_users(),
+                retrieval.put_padded(self.item_factors, self.item_scales, 0),
+            ))
+        values, scales = items if isinstance(items, tuple) else (items, None)
+        retrieval.set_resident(users=users, table=values, table_scales=scales)
+        return users, items
+
+    def _stage_users(self):
+        from predictionio_tpu.ops import retrieval
+
+        return retrieval.put_padded(
+            self.user_factors, self.user_scales, self._user_capacity
+        )
+
+    def user_capacity(self) -> int:
+        """Rows the resident user table has room for."""
+        return max(self._user_capacity, int(self.user_factors.shape[0]))
+
+    def reserve_user_rows(self, rows: int = 0) -> int:
+        """Give the resident user table room to grow: the next power of
+        two ABOVE the rows held (or above ``rows``), so that a user
+        appended by a fold-in changes no shape and compiles nothing —
+        doubled again by the fold that finds it full. Where the table is
+        on the device already its users go up again at the new size; the
+        item side is not touched. Returns the capacity."""
+        want = max(int(self.user_factors.shape[0]), int(rows))
+        capacity = 1 << want.bit_length()  # 2^k > want
+        if capacity <= self._user_capacity:
+            return self._user_capacity
+        with _STAGING:
+            self._user_capacity = capacity
+            if self._device is not None:
+                from predictionio_tpu.ops import retrieval
+
+                users = self._stage_users()
+                retrieval.set_resident(users=users)
+                self._device = (users, self._device[1])
+        _m_capacity.set(float(capacity))
+        return capacity
+
+    def resident_parts(self) -> dict:
+        """What of the ITEM side is on the device, by part (None: not
+        yet): the server compares a patched model's with the served
+        model's — the same objects, or the patch will stage them again."""
+        return {
+            "table": None if self._device is None else self._device[1],
+            "coarse": self._coarse,
+            "sharded": self._sharded,
+        }
+
+    def patched(self, user_index, user_factors, user_scales, users_device):
+        """A model with another user side and THIS model's item side —
+        the host arrays and whatever of them is on the device (the exact
+        table and its scales, the coarse catalog, a sharded catalog), by
+        reference: nothing item-side is staged, built or copied again.
+        ``users_device`` is the new resident user table (or None where
+        this model has none yet)."""
+        m = ALSModel(
+            user_index=user_index, item_index=self.item_index,
+            user_factors=user_factors, item_factors=self.item_factors,
+            user_scales=user_scales, item_scales=self.item_scales,
+        )
+        m._user_capacity = self._user_capacity
+        m._sharded, m._coarse = self._sharded, self._coarse
+        if self._device is not None and users_device is not None:
+            m._device = (users_device, self._device[1])
+        return m
 
     def sharded_catalog(self):
         """The item rows staged over the serving mesh, shard ``i`` read
@@ -360,6 +441,8 @@ class ALSModel:
         state["_device"] = None
         state["_sharded"] = None
         state["_coarse"] = None
+        state["_user_capacity"] = 0  # a deployment's, not the model's
+        state.pop("patch_h2d_bytes", None)
         return state
 
 

@@ -47,6 +47,7 @@ logger = logging.getLogger(__name__)
 __all__ = [
     "track_jit",
     "count_transfer",
+    "count_restage",
     "transfer_totals",
     "compile_snapshot",
     "ensure_device_gauges",
@@ -219,6 +220,20 @@ def count_transfer(direction: str, op: str, nbytes: int) -> None:
     with _transfer_lock:
         key = (direction, op)
         _transfer_totals[key] = _transfer_totals.get(key, 0) + int(nbytes)
+
+
+def count_restage(part: str) -> None:
+    """A resident part of a served model staged or built AGAIN because of
+    a fold-in patch: "users" when the user table outgrew its capacity and
+    went up at twice the size (realtime/foldin.py); an item-side part
+    ("table", "coarse", "sharded") where a patched model does not hold
+    the served model's (``engine_server`` ``apply_patch``) — never, in a
+    sound run."""
+    _metrics.counter(
+        "pio_foldin_restage_total",
+        "Resident parts staged or built again because of a patch",
+        part=part,
+    ).inc()
 
 
 def transfer_totals() -> dict[str, int]:

@@ -16,28 +16,106 @@ rating events:
 - solved rows are written back in the model's storage dtype: f32/bf16
   cast, or int8 requantized with a fresh per-row scale
   (:func:`ops.als.quantize_rows` semantics);
-- brand-new users are appended to the factor table and the id index;
+- brand-new users are appended to the factor table and the id index
+  (``BiMap.appended``: the new ids' cost, not the index's);
 - events naming items unseen at train time can't be solved against (no
   factor row) — they accumulate in ``cold_items`` (count + rating sum)
   as cold-start stats for the next retrain to pick up.
 
-The patched model SHARES the item arrays with the old model and never
-mutates served state — the server swap is a pointer flip under its lock.
+The patched model SHARES the item side with the old model — the host
+arrays AND what is resident of them on the device (the exact table, the
+coarse catalog, a sharded catalog: ``ALSModel.patched``) — and the fold
+gathers its rows from that same resident table: a patch stages, builds
+and copies nothing item-side. The solved rows go up as [B, D] (+ [B]
+scales) and are written into the resident user table by one small jitted
+update (``retrieval.patch_rows``) that leaves the old table whole for a
+query in flight; the table has room to grow
+(``ALSModel.reserve_user_rows``), so an appended user changes no shape.
+Served state is never mutated — the server swap is a pointer flip under
+its lock.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.models.recommendation import ALSModel
+from predictionio_tpu.obs import device as obs_device
+from predictionio_tpu.obs import metrics as obs_metrics
+from predictionio_tpu.obs import trace as obs_trace
 from predictionio_tpu.ops import als as als_ops
+from predictionio_tpu.ops import retrieval
 
 logger = logging.getLogger(__name__)
+
+_m_rows = obs_metrics.counter(
+    "pio_foldin_rows_patched_total",
+    "User rows a fold-in solved and wrote into the resident user table",
+)
+_m_added = obs_metrics.counter(
+    "pio_foldin_users_added_total",
+    "Users a fold-in appended (an id the model did not hold)",
+)
+_m_h2d = obs_metrics.counter(
+    "pio_foldin_patch_h2d_bytes_total",
+    "Bytes a fold-in sent up to patch the resident user table: the "
+    "solved rows, their scales and their indices",
+)
+
+
+@obs_device.track_jit("foldin.solve")
+@functools.partial(jax.jit, static_argnames=("weighted_reg",))
+def _solve_rows(table, col_ids, ratings, mask, reg, weighted_reg: bool):
+    """The fold's one solve program, under the fold's own name so that a
+    device trace tells it from a training half-step: the rows of
+    ``col_ids`` [B, K] gathered from the resident ``table`` (an int8 pair
+    dequantized there), then per user the explicit-feedback half-step
+    ``x = (V^T V + lam I)^-1 V^T r``, lam = reg * n under ``weighted_reg``.
+
+    A user with fewer ratings than the rank (K < D: nearly every fold) is
+    solved in the K x K form of the same equations,
+    ``x = V^T (V V^T + lam I)^-1 r``: V^T V then has D - n eigenvalues of
+    nothing but lam, and an f32 Cholesky of it loses a digit for every
+    factor of ten between lam and |v|^2, which a row stored int8 shows at
+    once (the scale is the row's largest value); V V^T + lam I has no
+    such eigenvalue. On a TPU v5e, 16 users of 1 to 32 ratings at rank 64
+    and lam = 0.05 n, against float64: 4.2e-7 of the row's largest value
+    so, 9.0e-6 in the D x D form (PERF.md section 6, PR 45). K >= D is
+    solved D x D, ``ops/als.py solve_bucket_explicit``'s arithmetic.
+
+    Precision: every product of f32 operands here states HIGHEST, as
+    ``_gramian_rhs`` does for its own and XLA's Cholesky and
+    triangular-solve expanders for theirs. Left to the default a TPU runs
+    them in bf16 passes: the same 16 rows then read 0.13 of their largest
+    value off, and 596 of their 1,024 int8 codes differ (same run)."""
+    with jax.named_scope("foldin.solve"):
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+        # the rescore's gather: in place through the [rows, D/32, 32]
+        # view, where a plain gather has the whole table re-laid a call
+        # (6.2 GB of temporaries for the int8 catalog, compiled for a v5e)
+        v = retrieval._table_rows(table, col_ids) * mask[:, :, None]
+        r = (ratings * mask).astype(f32)
+        n = mask.sum(axis=1)
+        lam = reg * (n if weighted_reg else jnp.ones_like(n))
+        lam = jnp.where(n > 0, lam, 1.0)  # a padded user: x = 0
+        k, d = col_ids.shape[1], v.shape[2]
+        if k >= d:  # the D x D form, as ``solve_bucket_explicit``
+            a, b = als_ops._gramian_rhs(v, mask.astype(f32), r)
+            a = a + lam[:, None, None] * jnp.eye(d, dtype=f32)
+            return als_ops._psd_solve(a, b)
+        g = jnp.einsum("bkd,bjd->bkj", v, v, precision=hi,
+                       preferred_element_type=f32)
+        g = g + lam[:, None, None] * jnp.eye(k, dtype=f32)
+        alpha = als_ops._psd_solve(g, r)
+        return jnp.einsum("bk,bkd->bd", alpha, v, precision=hi,
+                          preferred_element_type=f32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,10 +170,6 @@ class ALSFoldIn:
         self.config = config or FoldInConfig()
         # item id -> [event count, rating sum]; unseen-at-train items
         self.cold_items: dict[str, list] = {}
-        # device copy of the item table, keyed by the identity of the
-        # host array so a /reload (new model object) invalidates it
-        self._item_dev = None
-        self._item_dev_key = None
 
     # -- rating extraction (mirrors base.Events.scan_ratings) ---------------
 
@@ -199,12 +273,15 @@ class ALSFoldIn:
     def _collect_events(
         self, model, events, stats, touched, touched_set
     ) -> None:
-        for e in events:
-            v = self._rating_of(e)
-            if v is None:
-                continue
+        rated = [(e, v) for e in events if (v := self._rating_of(e)) is not None]
+        # one look-up for the batch: ``index_of`` never decodes a
+        # catalog's id dictionary for a handful of ids
+        known = model.item_index.index_of(
+            [e.target_entity_id for e, _ in rated]
+        ) >= 0
+        for (e, v), held in zip(rated, known.tolist()):
             stats.rating_events += 1
-            if e.target_entity_id not in model.item_index:
+            if not held:
                 acc = self.cold_items.setdefault(e.target_entity_id, [0, 0.0])
                 acc[0] += 1
                 acc[1] += v
@@ -227,8 +304,9 @@ class ALSFoldIn:
             tail.item_idx, weights=tail.ratings,
             minlength=len(tail.item_ids),
         )
+        known = model.item_index.index_of(tail.item_ids) >= 0
         for j, iid in enumerate(tail.item_ids):
-            if iid in model.item_index:
+            if known[j]:
                 continue
             acc = self.cold_items.setdefault(iid, [0, 0.0])
             acc[0] += int(counts[j])
@@ -246,7 +324,12 @@ class ALSFoldIn:
         shared tail of :meth:`fold` and :meth:`fold_in_columnar` — the
         solve is exact against full histories, so results can't depend
         on which decode path delivered the triggering events)."""
-        histories = self._histories(touched)
+        with obs_trace.region("foldin.history_read"):
+            histories = self._histories(touched)
+        item_ids = list({
+            e.target_entity_id for evs in histories.values() for e in evs
+        })
+        row_of = dict(zip(item_ids, model.item_index.index_of(item_ids).tolist()))
         users: list[str] = []
         pairs: list[list[tuple[int, float]]] = []
         for uid in touched:
@@ -255,8 +338,8 @@ class ALSFoldIn:
                 v = self._rating_of(e)
                 if v is None:
                     continue
-                ix = model.item_index.get(e.target_entity_id)
-                if ix is None:
+                ix = row_of[e.target_entity_id]
+                if ix < 0:
                     continue  # cold item: no factor row to solve against
                 seen[ix] = v  # replay order: last write wins
             if not seen:
@@ -268,46 +351,31 @@ class ALSFoldIn:
         if not users:
             return None, stats
 
-        solved = self._solve(model, pairs)
-        patched = self._patch(model, users, solved, stats)
+        with obs_trace.region("foldin.solve"):  # launch to read
+            solved = self._solve(model, pairs)
+        with obs_trace.region("foldin.patch_rows"):
+            patched = self._patch(model, users, solved, stats)
         return patched, stats
 
     def _solve(self, model: ALSModel, pairs) -> np.ndarray:
-        """Closed-form f32 solve of the touched rows, padded to stable
-        (B, K) program shapes so repeat folds reuse the jit cache."""
-        import jax.numpy as jnp
-
-        B = _pow2(len(pairs))
+        """Closed-form f32 solve of the touched rows against the item
+        table the server serves (``model.device_factors()``: no copy of
+        the fold's own), padded to stable (B, K) program shapes — B a
+        power of two from 8, K from 8 — so repeat folds reuse the jit
+        cache."""
+        B = _pow2(len(pairs), floor=8)
         K = _pow2(max(len(p) for p in pairs), floor=8)
         col_ids = np.zeros((B, K), dtype=np.int32)
         ratings = np.zeros((B, K), dtype=np.float32)
         mask = np.zeros((B, K), dtype=np.float32)
         for i, p in enumerate(pairs):
-            for j, (ix, v) in enumerate(p):
-                col_ids[i, j] = ix
-                ratings[i, j] = v
-                mask[i, j] = 1.0
-        item_host = model.item_table()
-        key = id(
-            item_host[0] if isinstance(item_host, tuple) else item_host
-        )
-        if self._item_dev_key != key:
-            if isinstance(item_host, tuple):
-                self._item_dev = (
-                    jnp.asarray(item_host[0]),
-                    jnp.asarray(item_host[1]),
-                )
-            else:
-                self._item_dev = jnp.asarray(item_host)
-            self._item_dev_key = key
-        x = als_ops.solve_bucket_explicit(
-            self._item_dev,
-            jnp.asarray(col_ids),
-            jnp.asarray(ratings),
-            jnp.asarray(mask),
-            reg=self.config.reg,
-            weighted_reg=self.config.weighted_reg,
-            compute_dtype="float32",
+            ixs, vals = zip(*p)
+            col_ids[i, : len(p)] = ixs
+            ratings[i, : len(p)] = vals
+            mask[i, : len(p)] = 1.0
+        x = _solve_rows(
+            model.device_factors()[1], col_ids, ratings, mask,
+            reg=self.config.reg, weighted_reg=self.config.weighted_reg,
         )
         return np.asarray(x)[: len(pairs)]
 
@@ -319,61 +387,60 @@ class ALSFoldIn:
         stats: FoldInStats,
     ) -> ALSModel:
         """New ALSModel with the solved rows written back (appending
-        brand-new users); item arrays are shared, nothing is mutated."""
-        index = model.user_index.to_dict()
-        n_existing = len(index)
-        new_ids = [u for u in users if u not in index]
-        for uid in new_ids:
-            index[uid] = len(index)
+        brand-new users): the host tables copied with the rows in, the
+        resident user table patched where it lies, the item side shared
+        — nothing of ``model`` is mutated."""
+        new_ids = [u for u in users if u not in model.user_index]
         stats.users_added = len(new_ids)
         user_index = (
-            model.user_index if not new_ids else BiMap(index)
+            model.user_index.appended(new_ids) if new_ids else model.user_index
         )
-
+        ixs = np.fromiter(
+            (user_index[u] for u in users), np.int32, len(users)
+        )
         uf = model.user_factors
+        scales = rows_s = None
         if model.user_scales is not None:
             # int8 storage: requantize each solved row with a fresh
             # per-row scale (quantize_rows semantics, host-side)
-            sc = np.max(np.abs(solved), axis=1) / 127.0
-            sc[sc <= 0] = 1.0
-            q = np.round(solved / sc[:, None]).astype(np.int8)
-            values = np.concatenate(
-                [uf, np.zeros((len(new_ids), uf.shape[1]), dtype=uf.dtype)]
-            )
-            scales = np.concatenate(
-                [
-                    model.user_scales,
-                    np.ones(len(new_ids), dtype=model.user_scales.dtype),
-                ]
-            )
-            for i, uid in enumerate(users):
-                ix = index[uid]
-                values[ix] = q[i]
-                scales[ix] = sc[i]
-            return ALSModel(
-                user_index=user_index,
-                item_index=model.item_index,
-                user_factors=values,
-                item_factors=model.item_factors,
-                user_scales=scales,
-                item_scales=model.item_scales,
-            )
+            rows_s = (np.max(np.abs(solved), axis=1) / 127.0).astype(np.float32)
+            rows_s[rows_s <= 0] = 1.0
+            rows = np.round(solved / rows_s[:, None]).astype(np.int8)
+            scales = np.concatenate([
+                model.user_scales,
+                np.ones(len(new_ids), dtype=model.user_scales.dtype),
+            ])
+            scales[ixs] = rows_s
+        else:
+            rows = solved.astype(uf.dtype)
         values = np.concatenate(
             [uf, np.zeros((len(new_ids), uf.shape[1]), dtype=uf.dtype)]
         )
-        rows = solved.astype(uf.dtype)
-        for i, uid in enumerate(users):
-            values[index[uid]] = rows[i]
-        if n_existing == 0 and not new_ids:  # pragma: no cover - guard
-            raise AssertionError("patch with no rows")
-        return ALSModel(
-            user_index=user_index,
-            item_index=model.item_index,
-            user_factors=values,
-            item_factors=model.item_factors,
-            user_scales=None,
-            item_scales=model.item_scales,
+        values[ixs] = rows
+
+        # the resident table: the rows and their indices go up, padded to
+        # the solve's B with copies of the first (the same row written
+        # twice), and one small program writes them in
+        if len(user_index) > model.user_capacity():
+            model.reserve_user_rows(len(user_index))
+            obs_device.count_restage("users")
+        pad = _pow2(len(users), floor=8) - len(users)
+
+        def up(a):
+            return np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
+
+        sent = (up(ixs), up(rows)) + (() if rows_s is None else (up(rows_s),))
+        users_dev = retrieval.patch_rows(
+            model.device_factors()[0], sent[0],
+            sent[1] if rows_s is None else sent[1:],
         )
+        retrieval.set_resident(users=users_dev)
+        patched = model.patched(user_index, values, scales, users_dev)
+        patched.patch_h2d_bytes = sum(a.nbytes for a in sent)
+        _m_rows.inc(len(users))
+        _m_added.inc(len(new_ids))
+        _m_h2d.inc(patched.patch_h2d_bytes)
+        return patched
 
     def cold_start_stats(self) -> dict[str, dict]:
         """Accumulated unseen-item stats: id -> {events, mean_rating}."""

@@ -40,7 +40,7 @@ logger = logging.getLogger(__name__)
 
 _m_fold = obs_metrics.histogram(
     "pio_foldin_solve_seconds",
-    "Fold+patch time per speed-layer cycle that saw events",
+    "Poll+fold+patch time per speed-layer cycle that saw events",
 )
 _m_poll = obs_metrics.histogram(
     "pio_tailer_poll_seconds", "Event-tailer poll time per cycle"
@@ -124,6 +124,11 @@ class SpeedLayer:
             columnar_config=columnar_config,
         )
         self.foldin = ALSFoldIn(events, app_id, channel_id, config=self._config)
+        # room for the users a fold appends, before the first query
+        # compiles the user-row gather for the table's shape
+        for m in server.model_snapshot()[1]:
+            if _is_als_model(m) and hasattr(m, "reserve_user_rows"):
+                m.reserve_user_rows()
         # the instance this layer's fold-in state belongs to; a snapshot
         # naming a different instance means a retrain superseded us
         self._instance_id = server.instance.id
@@ -173,7 +178,8 @@ class SpeedLayer:
         "skipped" | "breaker_open" | "fold_failed".
 
         Each cycle that reaches the fold carries a ``speedlayer.fold``
-        trace (spans: tail.poll, foldin.fold, server.patch) offered to
+        trace (spans: tail.poll, foldin.fold with foldin.history_read /
+        foldin.solve / foldin.patch_rows under it, server.patch) offered to
         the slow-trace ring, and the trace id is exported in
         :meth:`gauges` — fold latency visible in /traces.json is
         attributable to the exact cycle /stats.json reported (PR 7 left
@@ -230,7 +236,7 @@ class SpeedLayer:
             return "idle"
         _m_tailed.inc(n_events)
 
-        t0 = time.perf_counter()
+        t0 = t_p0  # a cycle that saw events: its poll, its folds, its patch
         for _attempt in range(3):
             patched_any = False
             new_models = []
@@ -239,13 +245,11 @@ class SpeedLayer:
                 if _is_als_model(m):
                     try:
                         faults.fault_point("foldin.fold")
-                        t_f0 = time.perf_counter()
-                        patched, stats = self.foldin.fold_in_columnar(
-                            m, batch
-                        )
-                        if tr is not None:
-                            tr.add_span(
-                                "foldin.fold", t_f0, time.perf_counter()
+                        # a region, so that the fold's own regions
+                        # (history_read, solve, patch_rows) name it parent
+                        with obs_trace.region("foldin.fold"):
+                            patched, stats = self.foldin.fold_in_columnar(
+                                m, batch
                             )
                     except Exception:
                         # the poll already persisted the cursor, so this

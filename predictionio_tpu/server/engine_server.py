@@ -97,6 +97,29 @@ def _model_bytes(obj: Any, _depth: int = 0) -> int:
     return 0
 
 
+def _patch_cost(old: Any, new: Any) -> tuple[int, list[str]]:
+    """(bytes a patch of ``old`` into ``new`` sends to the device, the
+    item-side parts it will stage or build AGAIN). A patched model that
+    holds the served model's resident parts by reference
+    (``resident_parts``) sent the rows it says it sent
+    (``patch_h2d_bytes``); any other model is new to the device and goes
+    up whole the next time a query asks for it."""
+    if new is old:
+        return 0, []
+    parts = getattr(old, "resident_parts", None)
+    if parts is None or not hasattr(new, "resident_parts"):
+        return _model_bytes(new), []
+    after = new.resident_parts()
+    lost = [
+        part for part, held in parts().items()
+        if held is not None and after.get(part) is not held
+    ]
+    sent = getattr(new, "patch_h2d_bytes", None)
+    if lost or sent is None:
+        return _model_bytes(new), lost
+    return int(sent), []
+
+
 def _query_from_json(query_class: type | None, data: dict[str, Any]) -> Any:
     """JSON -> query object (reference JsonExtractor.extract on
     algo.queryClass, CreateServer.scala:479-485)."""
@@ -477,13 +500,19 @@ class _Variant:
         with self._lock:
             if expected_epoch != self._epoch:
                 return False
-            self.models = models
+            served, self.models = self.models, models
             self._epoch += 1
             self._foldin_epoch += 1
             epoch = self._epoch
-        obs_device.count_transfer(
-            "h2d", "serve.model_patch", _model_bytes(models)
-        )
+        # book what went up: a patched model that holds the served one's
+        # resident item side sent its rows; one that does not goes up whole
+        sent = 0
+        for old, new in zip(served, models):
+            nbytes, restaged = _patch_cost(old, new)
+            sent += nbytes
+            for part in restaged:
+                obs_device.count_restage(part)
+        obs_device.count_transfer("h2d", "serve.model_patch", sent)
         if self.query_cache is not None:
             self.query_cache.sweep(epoch, variant=self.name)
         return True

@@ -35,7 +35,8 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _fh:
 
 FETCH = {  # metric -> (the end-to-end metric it moves, the cells that list it)
     "fetch_ms": ("query_p50_ms", ["retrieval-yambda.serve-steady",
-                                  "recommendation-amazon23-int8.serve-onechip-steady"]),  # PR 41
+                                  "recommendation-amazon23-int8.serve-onechip-steady",  # PR 41
+                                  "recommendation-amazon23-int8-live.serve-foldin-steady"]),  # PR 45
     "fetch_ms.saturated": ("serve_qps", ["retrieval-yambda.serve-saturated"]),
     "fetch_ms.storefront": ("query_p50_ms", ["ecommerce-taobao.serve-storefront"]),
     "fetch_ms.itempage": ("query_p50_ms", ["similarproduct-taobao.serve-itempage"]),  # PR 30

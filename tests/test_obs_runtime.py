@@ -41,6 +41,7 @@ STEADY = "retrieval-yambda.serve-steady"
 ITEMPAGE = "similarproduct-taobao.serve-itempage"
 SATURATED = "retrieval-yambda.serve-saturated"
 INT8 = "recommendation-amazon23-int8.serve-onechip-steady"  # PR 41 joined two lists
+LIVE = "recommendation-amazon23-int8-live.serve-foldin-steady"  # PR 45 joined two lists
 
 
 @pytest.fixture()
@@ -493,9 +494,9 @@ LAYER = {
     "gc_pause_ms_sum": "dispatch", "proc_stall_ms_max": "HTTP and batcher",
 }
 CELLS = {
-    "worker_busy_share": [STEADY, ITEMPAGE, INT8], "worker_turnaround_ms": [STEADY],
+    "worker_busy_share": [STEADY, ITEMPAGE, INT8, LIVE], "worker_turnaround_ms": [STEADY],
     "enqueue_offcpu_ms": [STEADY, ITEMPAGE], "dispatch_cpu_ms": [STEADY, INT8],
-    "gc_pause_ms_sum": [STEADY, ITEMPAGE], "proc_stall_ms_max": [STEADY, ITEMPAGE],
+    "gc_pause_ms_sum": [STEADY, ITEMPAGE], "proc_stall_ms_max": [STEADY, ITEMPAGE, LIVE],
 }
 NAMES = [n + sfx for sfx in ("", ".saturated") for n in EXPECT]
 

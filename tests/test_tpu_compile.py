@@ -373,3 +373,27 @@ def test_the_four_chip_chain_runs_the_same_step(one_chip):
     assert len(ops) == 5 and not [o for o in ops if o[1] in ("copy", "sort")], ops
     assert set(re.findall(r"(all-gather|all-reduce|all-to-all|collective-permute)", text)) \
         == {"all-gather"}
+
+
+@pytest.mark.parametrize("b,k", [(8, 8), (16, 32)])
+def test_the_folds_solve_gathers_from_the_resident_table_in_place(one_chip, b, k):
+    """PR 45: the fold-in's solve program over the int8 catalog's resident
+    pair (48.19 M x 64 values, their scales) needs no temporary to speak
+    of. Gathered plainly (``table[ids]``) the compiler re-lays the whole
+    table a call: 6.2 GB. And the update of the resident user table is a
+    copy of that table, not of anything else."""
+    from predictionio_tpu.realtime import foldin
+
+    arg = _on(one_chip)
+    table = (arg((48_190_000, 64), jnp.int8), arg((48_190_000,), jnp.float32))
+    solve = foldin._solve_rows.lower(
+        table, arg((b, k), jnp.int32), arg((b, k), jnp.float32),
+        arg((b, k), jnp.float32), reg=0.05, weighted_reg=True,
+    ).compile()
+    assert solve.memory_analysis().temp_size_in_bytes < 1 << 20
+    users = (arg((1 << 20, 64), jnp.int8), arg((1 << 20,), jnp.float32))
+    patch = retrieval.patch_rows.lower(
+        users, arg((b,), jnp.int32), (arg((b, 64), jnp.int8), arg((b,), jnp.float32)),
+    ).compile().memory_analysis()
+    assert (1 << 20) * 68 <= patch.output_size_in_bytes < (1 << 20) * 69
+    assert patch.temp_size_in_bytes <= 2 * (1 << 20) * 68
