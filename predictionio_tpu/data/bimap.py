@@ -9,6 +9,7 @@ numpy paths for bulk conversion (the RDD ``zipWithUniqueId`` analog).
 
 from __future__ import annotations
 
+import itertools
 from typing import Generic, Hashable, Iterable, Iterator, Mapping, Sequence, TypeVar
 
 import numpy as np
@@ -111,8 +112,9 @@ class BiMap(Generic[K, V]):
         handful of keys and a map of tens of millions asks (a fold-in's
         item ids): a map over an encoded dictionary answers it without
         decoding the dictionary (``modelfile._LazyDenseBiMap``)."""
-        get = self._m.get
-        return np.fromiter((get(k, -1) for k in keys), np.int64, len(keys))
+        return np.fromiter(
+            map(self._m.get, keys, itertools.repeat(-1)), np.int64, len(keys)
+        )
 
     def to_index_array(self, keys: Sequence[K]) -> np.ndarray:
         """Bulk key->index conversion to an int32 numpy array."""
@@ -173,8 +175,9 @@ class _Appended(BiMap):
 
     def index_of(self, keys: Sequence) -> np.ndarray:
         out = self._base.index_of(keys)
-        for j in np.flatnonzero(out < 0).tolist():
-            out[j] = self._extra.get(keys[j], -1)
+        for j, i in enumerate(out.tolist()):
+            if i < 0:
+                out[j] = self._extra.get(keys[j], -1)
         return out
 
     def __reduce__(self):

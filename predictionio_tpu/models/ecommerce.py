@@ -61,6 +61,7 @@ from predictionio_tpu.models.filters import (
     availability_vector,
     candidate_lists,
     category_vectors,
+    held_rows,
     padded_rows,
     query_rules,
 )
@@ -434,15 +435,7 @@ class ECommAlgorithm(Algorithm):
             rows = cache["seen"].get(user)
             if rows is not None:
                 return rows
-        index = model.item_index
-        rows = np.fromiter(
-            (
-                ix
-                for ix in map(index.get, self._seen_items(user, cache))
-                if ix is not None
-            ),
-            np.int32,
-        )
+        rows = held_rows(model.item_index, self._seen_items(user, cache))
         if cache is not None:
             if len(cache["seen"]) >= _SEEN_CACHE_USERS:
                 cache["seen"].clear()
@@ -473,10 +466,7 @@ class ECommAlgorithm(Algorithm):
             if events
             else []
         )
-        index = model.item_index
-        rows = np.unique(np.fromiter(
-            (ix for ix in map(index.get, items) if ix is not None), np.int32,
-        ))
+        rows = np.unique(held_rows(model.item_index, items))
         if cache is not None:
             cache["unavail"] = rows
         return rows
@@ -496,11 +486,9 @@ class ECommAlgorithm(Algorithm):
             )
         except Exception:
             return None
-        ixs = [
-            model.item_index[e.target_entity_id]
-            for e in events
-            if e.target_entity_id in model.item_index
-        ]
+        ixs = held_rows(
+            model.item_index, [e.target_entity_id for e in events]
+        ).tolist()
         if not ixs:
             return None
         return model.item_rows(ixs).mean(axis=0)
@@ -565,9 +553,7 @@ class ECommAlgorithm(Algorithm):
                 weights = np.ones(n, dtype=np.float32)
                 for group in self.params.weights:
                     w = float(group.get("weight", 1.0))
-                    for iid in group.get("items", []):
-                        if iid in model.item_index:
-                            weights[model.item_index[iid]] = w
+                    weights[held_rows(model.item_index, group.get("items", []))] = w
                 if isinstance(V, tuple):
                     # per-row weight folds into the per-row scale: the
                     # weighted catalog stays int8
@@ -621,23 +607,17 @@ class ECommAlgorithm(Algorithm):
         """One query's own rules as index lists: (excluded catalog rows,
         category ids or None, whiteList rows or None)."""
         index = model.item_index
-        excluded = [
-            ix for ix in map(index.get, q.blackList or ()) if ix is not None
-        ]
+        excluded = held_rows(index, q.blackList or ())
         if self.params.unseen_only:
             excluded = np.union1d(
-                self._seen_rows(model, q.user, cache),
-                np.asarray(excluded, np.int32),
+                self._seen_rows(model, q.user, cache), excluded
             )
         else:
-            excluded = np.unique(np.asarray(excluded, np.int32))
+            excluded = np.unique(excluded)
         cats = model.category_ids(q.categories)
         white = None
         if q.whiteList is not None:
-            white = np.unique(np.fromiter(
-                (ix for ix in map(index.get, q.whiteList) if ix is not None),
-                np.int32,
-            ))
+            white = np.unique(held_rows(index, q.whiteList))
         return excluded, cats, white
 
     def batch_predict(
@@ -673,8 +653,9 @@ class ECommAlgorithm(Algorithm):
             vecs, excluded, qcats, whites = [], [], [], []
             for qi, (_, q) in enumerate(queries):
                 _m_queries[_query_kind(q)].inc()
-                if q.user in model.user_index:
-                    vec = np.asarray(model.user_rows(model.user_index[q.user]))
+                uix = model.user_index.get(q.user)
+                if uix is not None:
+                    vec = np.asarray(model.user_rows(uix))
                 else:
                     recent = self._recent_item_vector(model, q.user)
                     if recent is None:

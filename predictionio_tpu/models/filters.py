@@ -90,9 +90,9 @@ class ItemCategories:
             )
             width = max([1] + [len(cs) for cs in cats.values()])
             table = np.full((len(self.item_index), width), -1, np.int32)
-            for iid, cs in cats.items():
-                ix = self.item_index.get(iid)
-                if ix is not None:
+            rows = self.item_index.index_of(list(cats)).tolist()  # ONE look-up
+            for ix, cs in zip(rows, cats.values()):
+                if ix >= 0:
                     table[ix, : len(cs)] = [self.category_index[c] for c in cs]
             self.item_categories = table
         self.categories = None
@@ -148,6 +148,16 @@ def availability_vector(num_items: int, rows: int, unavailable=None):
     if unavailable is not None:
         avail[unavailable] = 0
     return jnp.asarray(avail)
+
+
+def held_rows(index: BiMap, keys) -> np.ndarray:
+    """The rows of those of ``keys`` that ``index`` holds, in the keys'
+    order ([n] int32): ONE look-up a list, which a map over a model
+    file's encoded dictionary answers without decoding it."""
+    if not keys:  # most queries list nothing
+        return np.zeros(0, np.int32)
+    rows = index.index_of(list(keys)).tolist()
+    return np.asarray([ix for ix in rows if ix >= 0], np.int32)
 
 
 def padded_rows(rows: list[int]) -> list[int]:
@@ -353,7 +363,7 @@ def score_similar_batch(
         for qi, q in enumerate(queries):
             qc = categories(q)
             _m_queries[_query_kind(q, qc)].inc()
-            known = [ix for ix in map(index.get, entities(q)) if ix is not None]
+            known = held_rows(index, entities(q)).tolist()
             if not known:
                 logger.info(
                     "no query entities with factors; returning empty result"
@@ -361,17 +371,15 @@ def score_similar_batch(
                 results[qi] = result([])
                 continue
             _m_query_rows.observe(float(len(known)))
-            black = [
-                ix for ix in map(index.get, q.blackList or ()) if ix is not None
-            ]
+            black = held_rows(index, q.blackList or ()).tolist()
             scored.append(qi)
             knowns.append(known)
             excluded.append(np.unique(np.asarray(known + black, np.int32)))
             qcats.append(qc)
-            whites.append(None if q.whiteList is None else np.unique(np.fromiter(
-                (ix for ix in map(index.get, q.whiteList) if ix is not None),
-                np.int32,
-            )))
+            whites.append(
+                None if q.whiteList is None
+                else np.unique(held_rows(index, q.whiteList))
+            )
 
         def batch_for(rows: list[int]):
             """(SumRows' ixs and weights, the rules) of ``scored[r] for r

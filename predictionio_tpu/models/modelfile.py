@@ -42,6 +42,7 @@ model object — the marginal RSS of tenant N+1 is bookkeeping, not factors.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import importlib
 import io
@@ -606,29 +607,143 @@ def _verify_blocks() -> bool:
     return os.environ.get("PIO_MODEL_VERIFY", "").strip() == "1"
 
 
-_HASH_MUL = np.uint64(1099511628211)  # the FNV prime, as a polynomial's base
+# -- an encoded dictionary's id -> index look-ups ---------------------------
+#
+# An id's hash folds its bytes a little-endian 8-byte word at a time, the
+# LAST word (zero-filled) first: h = (h ^ word) * _HASH_MUL mod 2^64. From
+# the end, the zero words past a short id's end leave h at 0, so ids of
+# every length fold in the same passes with no length test. The bulk side
+# (`_hash_ids`) gathers a word of every id of a chunk per pass; a look-up
+# reads the key's own bytes (`_hash_key` one key, `_find_all` a list's as
+# one matrix); the tests hold the three equal bit for bit.
+
+_HASH_MUL = np.uint64(0x9E3779B97F4A7C15)  # odd: a word -> its product is one-to-one
+_HASH_CHUNK = 1 << 18  # ids hashed at a time: every array of a pass stays in cache
+_VECTOR_FROM = 32  # keys from which one NumPy pass beats a Python loop a key (3 us a key against ~60 + 0.5 a key)
+_VECTOR_WIDTH = 64  # ... while the list's longest key is no longer than this
+_VECTOR_MOST = 1 << 16  # keys a pass: a warm start's million ids go a part at a time
+_BUCKET_BITS = 3  # the directory has a bucket for about every 2^3 ids
+_BUCKET_MOST = 64  # a list's pass scans whole buckets while none holds more than this
+_U64 = (1 << 64) - 1
+_WORD_MASKS = np.array([(1 << (8 * k)) - 1 for k in range(9)], np.uint64)
+
+
+def _hash_key(raw: bytes) -> int:
+    mul, h = int(_HASH_MUL), 0
+    for r in range(max(len(raw) - 1, 0) // 8 * 8, -1, -8):
+        h = ((h ^ int.from_bytes(raw[r: r + 8], "little")) * mul) & _U64
+    return h
+
+
+def _hash_ids(blob: np.ndarray, offs: np.ndarray, chunk: int = _HASH_CHUNK) -> np.ndarray:
+    """[n] uint64 hashes of the ids of an encoded dictionary, made from
+    the blob ``chunk`` ids at a time: no id becomes a Python string, and
+    no pass streams an n-wide array through main memory."""
+    n = len(offs) - 1
+    out = np.empty(n, np.uint64)
+    for a in range(0, n, chunk):
+        o = np.asarray(offs[a: a + chunk + 1], np.int64)
+        starts, lens, size = o[:-1] - o[0], np.diff(o), int(o[-1] - o[0])
+        part = np.zeros(size + 8, np.uint8)  # a last word may read past the last id
+        part[:size] = blob[o[0]: o[-1]]
+        # the word that starts at every byte: 8-byte reads a byte apart
+        words = np.lib.stride_tricks.as_strided(part[:8].view("<u8"), (size + 1,), (1,))
+        h = out[a: a + chunk]
+        h[:] = 0
+        for r in range(max(int(lens.max()) - 1, 0) // 8 * 8, -1, -8):
+            word = words[np.minimum(starts + r, size)]
+            word &= _WORD_MASKS[np.clip(lens - r, 0, 8)]  # nothing left of a shorter id: 0
+            h ^= word
+            h *= _HASH_MUL
+    return out
+
+
+class _IdIndex(NamedTuple):
+    """What a look-up searches: one sorted uint64 word an id, the hash's
+    high ``64 - shift`` bits over the id's index in the low ``shift`` (so
+    ONE in-place sort orders hashes and indices together, where an argsort
+    of 48 M hashes and the gather after it took four times the hashing).
+    ``first[b]`` is the position of the first word whose top ``bits`` bits
+    are ``b`` or more — the words are hashes, so evenly spread: a search
+    reads its bucket (``most`` words at most) where a binary search over
+    all the words would miss the cache at every level. ``cells`` /
+    ``firsts`` / ``starts`` / ``raw`` are the words, the directory, the
+    offsets and the blob again as memoryviews: a single key's search
+    stays Python ints."""
+
+    keys: np.ndarray
+    shift: int
+    bits: int
+    first: np.ndarray
+    most: int
+    cells: memoryview
+    firsts: memoryview
+    starts: memoryview
+    raw: memoryview
+
+
+_id_metrics = None  # lazy, as the counters below: (hashed, decoded, builds, decodes)
+
+
+def _id_counters():
+    global _id_metrics
+    if _id_metrics is None:
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        looked = "keys looked up in a model file's id dictionary, by what answered"
+        _id_metrics = (
+            obs_metrics.counter("pio_model_id_lookups_total", looked, path="hashed"),
+            obs_metrics.counter("pio_model_id_lookups_total", looked, path="decoded"),
+            obs_metrics.histogram(
+                "pio_model_id_index_build_seconds",
+                "hashing and sorting one encoded id dictionary for look-ups",
+            ),
+            obs_metrics.counter(
+                "pio_model_id_decodes_total",
+                "encoded id dictionaries decoded whole into Python strings",
+            ),
+        )
+    return _id_metrics
+
+
+def id_stats_block() -> dict:
+    """``/stats.json``'s ``model_ids``: the id look-ups of this process's
+    model files, by what answered them."""
+    hashed, decoded, builds, decodes = _id_counters()
+    _, seconds, built = builds.merged()
+    return {
+        "lookups": {"hashed": hashed.value(), "decoded": decoded.value()},
+        "index_builds": built,
+        "index_build_seconds": round(seconds, 3),
+        "decodes": decodes.value(),
+    }
 
 
 class _LazyDenseBiMap(BiMap):
-    """A BiMap over an encoded dense id dictionary, decoded on FIRST
-    dictionary access instead of at load. Keeps the cold model-file load
-    O(pages touched): a million-id index costs two array views at load
-    and pays its one-time decode at warmup (or the first query), off the
-    deploy critical path — and only once per process, since co-tenant
-    mounts share the decoded entries.
+    """A BiMap over an encoded dense id dictionary that no point look-up
+    decodes: ``[]`` / ``get`` / ``in`` / ``index_of`` hash the key, search
+    the ids' sorted hashes (built from the blob at the first look-up, a
+    chunk at a time) and compare the bytes of the ids that share the
+    hash. A million-id index costs two array views at load, 9 bytes an id
+    once something is looked up (a word an id and the directory over the
+    words), and no Python string ever. Only a caller
+    that WALKS the mapping (``items``, iteration, ``to_dict``, ``==``,
+    pickling, ``_Appended._m``) decodes the dictionary — counted, and
+    from then on the dictionary answers.
 
     Never calls ``BiMap.__init__``; ``_m``/``_inverse`` are materializing
     properties shadowing the base class's instance attributes, so every
     inherited accessor works unchanged once touched. The inverse
-    (index -> id) decodes nothing: ``_OffsetInverse`` reads one id at a
-    time from the blob and its offsets."""
+    (index -> id) decodes nothing either: ``_OffsetInverse`` reads one id
+    at a time from the blob and its offsets."""
 
     def __init__(self, blob: np.ndarray, offs: np.ndarray):
         self._blob = blob
         self._offs = offs
         self._fwd: dict | None = None
         self._inv: BiMap | None = None
-        self._hashes = None
+        self._index: _IdIndex | None = None
+        self._index_lock = threading.Lock()  # the speed layer's thread and the batch worker may both ask first
 
     def _ids(self) -> list[str]:
         raw = self._blob.tobytes()
@@ -641,7 +756,10 @@ class _LazyDenseBiMap(BiMap):
     @property
     def _m(self) -> dict:
         if self._fwd is None:
-            self._fwd = {k: i for i, k in enumerate(self._ids())}
+            with self._index_lock:
+                if self._fwd is None:
+                    self._fwd = {k: i for i, k in enumerate(self._ids())}
+                    _id_counters()[3].inc()
         return self._fwd
 
     @property
@@ -661,45 +779,137 @@ class _LazyDenseBiMap(BiMap):
     def __len__(self) -> int:  # cheap without decoding
         return len(self._offs) - 1
 
-    def _hashed(self):
-        """(the ids' 64-bit hashes sorted, the index of each): what
-        ``index_of`` searches. Made from the blob a byte position at a
-        time — no id becomes a Python string — and once: 48 M ids cost
-        ~0.6 GB and seconds, where the dictionary costs gigabytes and
-        minutes."""
-        if self._hashes is None:
-            offs = np.asarray(self._offs, np.int64)
-            blob = np.asarray(self._blob).view(np.uint8)
-            starts, lens = offs[:-1], np.diff(offs)
-            h = np.zeros(len(starts), np.uint64)
-            last = max(len(blob) - 1, 0)
-            for pos in range(int(lens.max()) if len(lens) else 0):
-                byte = blob[np.minimum(starts + pos, last)]
-                h = np.where(lens > pos, h * _HASH_MUL + byte + np.uint64(1), h)
-            order = np.argsort(h, kind="stable")
-            self._hashes = (h[order], order)
-        return self._hashes
+    def _hashed(self) -> _IdIndex:
+        """The sorted hash words of the ids and the directory over them,
+        built once (two threads asking at once wait for one build)."""
+        if self._index is None:
+            with self._index_lock:
+                if self._index is None:
+                    t0 = time.perf_counter()
+                    n = len(self)
+                    shift = max(n - 1, 0).bit_length()
+                    blob = self._blob = np.asarray(self._blob).view(np.uint8)
+                    keys = _hash_ids(blob, self._offs)
+                    keys >>= np.uint64(shift)
+                    keys <<= np.uint64(shift)
+                    for a in range(0, n, _HASH_CHUNK):
+                        keys[a: a + _HASH_CHUNK] |= np.arange(
+                            a, min(a + _HASH_CHUNK, n), dtype=np.uint64
+                        )
+                    keys.sort()
+                    bits = max(n.bit_length() - _BUCKET_BITS, 1)
+                    first = np.empty((1 << bits) + 1, np.int64)
+                    first[:-1] = keys.searchsorted(
+                        np.arange(1 << bits, dtype=np.uint64) << np.uint64(64 - bits)
+                    )
+                    first[-1] = n
+                    self._index = _IdIndex(
+                        keys, shift, bits, first, int(np.diff(first).max()),
+                        memoryview(keys), memoryview(first),
+                        memoryview(self._offs), memoryview(blob),
+                    )
+                    took = time.perf_counter() - t0
+                    _id_counters()[2].observe(took)
+                    if took >= 1.0:
+                        logger.info("id index: %d ids hashed and sorted in %.2f s", n, took)
+        return self._index
+
+    def _find(self, key) -> int:
+        """The index of the id ``key``, or -1 (not held, or not a
+        string): one key, Python ints all the way."""
+        if not isinstance(key, str):
+            return -1
+        raw = key.encode("utf-8")
+        ix = self._hashed()
+        cells, starts, low = ix.cells, ix.starts, (1 << ix.shift) - 1
+        word = _hash_key(raw) & ~low
+        b = word >> 64 - ix.bits
+        at = bisect.bisect_left(cells, word, ix.firsts[b], ix.firsts[b + 1])
+        while at < len(cells) and cells[at] & ~low == word:  # equal hashes: compare the bytes
+            i = cells[at] & low
+            if ix.raw[starts[i]: starts[i + 1]] == raw:
+                return i
+            at += 1
+        return -1
+
+    def _find_all(self, keys: list[str]) -> np.ndarray | None:
+        """``_find`` of every key of a list in one NumPy pass ([n] int64),
+        for keys that are their own bytes (ASCII) and none too long for a
+        matrix of the list: None for any other list."""
+        n, width = len(keys), max(map(len, keys))
+        if not 0 < width <= _VECTOR_WIDTH or not len(self._blob):
+            return None
+        span = -(-width // 8) * 8
+        flat = "".join([k.ljust(span, "\0") for k in keys])
+        if not flat.isascii():
+            return None
+        mat = np.frombuffer(flat.encode("ascii"), np.uint8).reshape(n, span)
+        words = mat.view("<u8")  # [n, span / 8]: a key's words, zero-filled
+        h = np.zeros(n, np.uint64)
+        for r in reversed(range(span // 8)):
+            h ^= words[:, r]
+            h *= _HASH_MUL
+        index = self._hashed()
+        low, last = np.uint64((1 << index.shift) - 1), len(self) - 1
+        want = h & ~low
+        if index.most <= _BUCKET_MOST:  # the first word >= want lies in want's bucket, or opens the next
+            at = index.first[(want >> np.uint64(64 - index.bits)).astype(np.int64)]
+            bucket = index.keys[np.minimum(at[:, None] + np.arange(index.most), last)]
+            at += (bucket < want[:, None]).sum(axis=1)
+        else:  # hashes that crowd one bucket (ids made to collide): search all the words
+            at = index.keys.searchsorted(want)
+        cell = index.keys[np.minimum(at, last)]
+        same = cell & ~low == want
+        found = (cell & low).astype(np.int64)
+        lens = np.fromiter(map(len, keys), np.int64, n)
+        lo = np.asarray(self._offs[found], np.int64)
+        at = np.arange(span)
+        got = self._blob[np.minimum(lo[:, None] + at, len(self._blob) - 1)]
+        hit = (
+            same & (self._offs[found + 1] - lo == lens)
+            & ((got == mat) | (at >= lens[:, None])).all(axis=1)
+        )
+        found[~hit] = -1
+        for j in np.flatnonzero(same & ~hit).tolist():  # a hash's first id was another's: walk its ids (rare)
+            found[j] = self._find(keys[j])
+        return found
 
     def index_of(self, keys) -> np.ndarray:
-        if self._fwd is not None:  # decoded already: the dictionary answers
+        if self._fwd is not None:  # walked already: the dictionary answers
+            _id_counters()[1].inc(len(keys))
             return super().index_of(keys)
-        hashes, order = self._hashed()
-        out = np.full(len(keys), -1, np.int64)
-        for j, key in enumerate(keys):
-            if not isinstance(key, str):
-                continue
-            raw = key.encode("utf-8")
-            h = 0
-            for b in raw:
-                h = (h * int(_HASH_MUL) + b + 1) & 0xFFFFFFFFFFFFFFFF
-            at = int(np.searchsorted(hashes, np.uint64(h)))
-            while at < len(hashes) and int(hashes[at]) == h:  # equal hashes: compare the bytes
-                i = int(order[at])
-                if self._blob[self._offs[i]: self._offs[i + 1]].tobytes() == raw:
-                    out[j] = i
-                    break
-                at += 1
-        return out
+        _id_counters()[0].inc(len(keys))
+        if len(keys) <= _VECTOR_MOST:
+            return self._found(keys)
+        return np.concatenate([
+            self._found(keys[a: a + _VECTOR_MOST])
+            for a in range(0, len(keys), _VECTOR_MOST)
+        ])
+
+    def _found(self, keys) -> np.ndarray:
+        """``index_of`` of at most ``_VECTOR_MOST`` keys."""
+        if len(keys) >= _VECTOR_FROM and set(map(type, keys)) == {str}:
+            found = self._find_all(keys)
+            if found is not None:
+                return found
+        return np.fromiter(map(self._find, keys), np.int64, len(keys))
+
+    def get(self, key, default=None):
+        if self._fwd is not None:
+            _id_counters()[1].inc()
+            return self._fwd.get(key, default)
+        _id_counters()[0].inc()
+        i = self._find(key)
+        return default if i < 0 else i
+
+    def __getitem__(self, key):
+        i = self.get(key, -1)
+        if i == -1:
+            raise KeyError(key)
+        return i
+
+    def __contains__(self, key) -> bool:
+        return self.get(key, -1) != -1
 
     def __reduce__(self):
         # pickle as a plain BiMap: the mmap-backed views must not leak

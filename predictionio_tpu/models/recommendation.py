@@ -596,9 +596,7 @@ class ALSAlgorithm(Algorithm):
 
         def align(ids, index, take):
             out = np.full((len(ids), prev_rank), np.nan, np.float32)
-            ix = np.fromiter(
-                (index.get(i, -1) for i in ids), np.int64, len(ids)
-            )
+            ix = index.index_of(ids)
             m = ix >= 0
             if m.any():
                 out[np.flatnonzero(m)] = take(ix[m])
@@ -755,16 +753,20 @@ class ALSAlgorithm(Algorithm):
         mesh in the table's place: the same decision, on the shards."""
         from predictionio_tpu.ops import retrieval
 
-        known = [(ix, q) for ix, q in queries if q.user in model.user_index]
+        # a dispatch's users one by one: a map over a model file's encoded
+        # dictionary answers `get` in Python ints, decoding nothing — and
+        # with no NumPy call, which inside a server costs a dispatch more
+        # than the search (PR 46: `index_of` here read +0.24 ms a batch of 8)
+        get = model.user_index.get
+        rows = [get(q.user, -1) for _, q in queries]
+        known = [(ix, q) for (ix, q), row in zip(queries, rows) if row >= 0]
         out: list[tuple[int, PredictedResult]] = [
             (ix, PredictedResult(itemScores=[]))
-            for ix, q in queries
-            if q.user not in model.user_index
+            for (ix, _), row in zip(queries, rows)
+            if row < 0
         ]
         if known:
-            uixs = np.asarray(
-                [model.user_index[q.user] for _, q in known], dtype=np.int32
-            )
+            uixs = np.asarray([row for row in rows if row >= 0], dtype=np.int32)
             # power-of-two k: the jitted batch top-k specializes on k,
             # and micro-batched serving would otherwise recompile per
             # distinct max(num) in a batch (results slice to q.num;
@@ -824,12 +826,10 @@ class ALSAlgorithm(Algorithm):
         qn = len(queries)
         ids = np.full((qn, kr), -1, dtype=np.int32)
         scores = np.zeros((qn, kr), dtype=np.float32)
-        known = [qi for qi, q in enumerate(queries) if q.user in model.user_index]
-        if known:
-            uixs = np.asarray(
-                [model.user_index[queries[qi].user] for qi in known],
-                dtype=np.int32,
-            )
+        rows = model.user_index.index_of([q.user for q in queries])
+        known = np.flatnonzero(rows >= 0)
+        if len(known):
+            uixs = rows[known].astype(np.int32)
             if self.params.sharded_serving:
                 s, i = model.sharded_catalog().exact_top_k(
                     model.user_rows(uixs), kr

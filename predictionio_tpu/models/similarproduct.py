@@ -51,6 +51,7 @@ from predictionio_tpu.models.columnar import (
 from predictionio_tpu.models.filters import (
     CosineCatalog,
     ItemCategories,
+    held_rows,
     score_similar_batch,
 )
 from predictionio_tpu.ops import als as als_ops
@@ -280,7 +281,8 @@ class CosineAlgorithm(Algorithm):
         )
 
     def predict(self, model: CosineModel, query: Query) -> PredictedResult:
-        known = [model.item_index[i] for i in query.items if i in model.item_index]
+        index, inv = model.item_index, model.item_index.inverse
+        known = held_rows(index, query.items).tolist()
         if not known:
             return PredictedResult(itemScores=[])
         combined: dict[int, float] = defaultdict(float)
@@ -288,12 +290,11 @@ class CosineAlgorithm(Algorithm):
             for score, jx in zip(model.sim_scores[ix], model.sim_ids[ix]):
                 if np.isfinite(score):
                     combined[int(jx)] += float(score)
-        index, inv = model.item_index, model.item_index.inverse
         # the neighbour lists live on the host: set look-ups are enough
-        dropped = {index.get(i) for i in (*query.items, *(query.blackList or ()))}
+        dropped = {*known, *held_rows(index, query.blackList or ()).tolist()}
         white = (
             None if query.whiteList is None
-            else {index.get(i) for i in query.whiteList}
+            else set(held_rows(index, query.whiteList).tolist())
         )
         wanted = None if query.categories is None else set(query.categories)
 

@@ -390,14 +390,13 @@ class ALSFoldIn:
         brand-new users): the host tables copied with the rows in, the
         resident user table patched where it lies, the item side shared
         — nothing of ``model`` is mutated."""
-        new_ids = [u for u in users if u not in model.user_index]
+        held = model.user_index.index_of(users) >= 0
+        new_ids = [u for u, h in zip(users, held.tolist()) if not h]
         stats.users_added = len(new_ids)
         user_index = (
             model.user_index.appended(new_ids) if new_ids else model.user_index
         )
-        ixs = np.fromiter(
-            (user_index[u] for u in users), np.int32, len(users)
-        )
+        ixs = user_index.index_of(users).astype(np.int32)
         uf = model.user_factors
         scales = rows_s = None
         if model.user_scales is not None:

@@ -1445,9 +1445,11 @@ class EngineServer:
             body["freshness"] = obs_freshness.block()
             body["runtime"] = obs_runtime.block()
             try:
+                from predictionio_tpu.models import modelfile as _modelfile
                 from predictionio_tpu.ops import retrieval as _retrieval
 
                 body["retrieval"] = _retrieval.stats_block()
+                body["model_ids"] = _modelfile.id_stats_block()
             except Exception:  # pragma: no cover - stats must never 500
                 pass
             return Response.json(body)

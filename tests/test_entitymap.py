@@ -37,3 +37,27 @@ class TestEntityMap:
         bm = emap.id_index
         assert len(bm) == 3
         assert bm.inverse[bm["u1"]] == "u1"
+
+
+class TestIdIndexLookups:
+    """``id_index`` is the BiMap a model file stores: its vectorised
+    ``index_of`` and the same look-ups over the stored (encoded) form."""
+
+    @pytest.mark.parametrize("keys,want", [
+        (["u1", "u3"], [0, 2]),
+        (["u2"], [1]),
+        ([], []),
+        (["u9", "u1", "", "u"], [-1, 0, -1, -1]),
+        (["u1", 7, None], [0, -1, -1]),
+    ], ids=["list", "single", "empty", "absent", "non-strings"])
+    def test_index_of(self, emap, keys, want):
+        from predictionio_tpu.models import modelfile
+
+        bm = emap.id_index
+        got = bm.index_of(keys)
+        assert got.dtype.kind == "i" and got.tolist() == want
+        stored = modelfile._LazyDenseBiMap(*modelfile._encode_ids(list(bm.inverse[i] for i in range(len(bm)))))
+        assert stored.index_of(keys).tolist() == want
+        assert [stored.get(k) for k in keys] == [bm.get(k) for k in keys]
+        assert [k in stored for k in keys] == [k in bm for k in keys]
+        assert stored._fwd is None

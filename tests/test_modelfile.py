@@ -154,13 +154,14 @@ class TestRoundTrip:
         assert idx._fwd is None
         assert len(idx) == 100
         assert idx._fwd is None
-        assert idx["u42"] == 42  # first lookup materializes
-        assert idx._fwd is not None
+        assert idx["u42"] == 42  # a look-up searches the hashes: nothing materializes
+        assert idx._fwd is None
         assert idx.inverse[42] == "u42"
         # repickling must yield a plain BiMap, never leak mmap views
         clone = pickle.loads(pickle.dumps(idx))
         assert type(clone) is BiMap
         assert clone["u42"] == 42 and len(clone) == 100
+        assert idx._fwd is not None  # a pickle walks the mapping: the one decode left
 
 
 class TestValidation:
@@ -544,24 +545,22 @@ class TestIdsFromTheBlob:
 
     def test_a_served_answer_builds_no_id_list(self, monkeypatch):
         """batch_predict over a model loaded from a file: the item index's
-        inverse answers k ids from the blob (the USER index still decodes:
-        a query names its user by string)."""
+        inverse answers k ids from the blob, and the USER index finds the
+        query's user by the hashes of its blob — neither id list is built."""
         from predictionio_tpu.models import recommendation as rec
 
         m = _als(n_users=8, n_items=64, rank=4)
         got = modelfile.deserialize(modelfile.serialize([("arrays", m)], "x"))[0][1]
-        sound = modelfile._LazyDenseBiMap._ids
         monkeypatch.setattr(
             modelfile._LazyDenseBiMap, "_ids",
-            lambda self: pytest.fail("the item id list was built")
-            if self is got.item_index else sound(self),
+            lambda self: pytest.fail("an id list was built"),
         )
         algo = rec.ALSAlgorithm(rec.ALSAlgorithmParams(rank=4))
         res = algo.predict(got, rec.Query(user="u3", num=5))
         want = rec.ALSAlgorithm(rec.ALSAlgorithmParams(rank=4)).predict(
             m, rec.Query(user="u3", num=5))
         assert [s.item for s in res.itemScores] == [s.item for s in want.itemScores]
-        assert got.item_index._fwd is None
+        assert got.item_index._fwd is None and got.user_index._fwd is None
 
     def test_walking_the_whole_mapping_still_works(self):
         blob, offs = modelfile._encode_ids(["a", "b", "c"])
@@ -569,3 +568,311 @@ class TestIdsFromTheBlob:
         assert dict(inv.items()) == {0: "a", 1: "b", 2: "c"}
         assert inv[2] == "c" and list(inv) == [0, 1, 2]
         assert inv == BiMap({0: "a", 1: "b", 2: "c"})
+
+
+# ids of mixed lengths: 1 byte to 40, multi-byte UTF-8, an id that is a
+# prefix of another, NUL bytes, ids a word (8 bytes) and a word + 1 long
+_MIXED_IDS = (
+    ["a", "ab", "abc", "abcdefg", "abcdefgh", "abcdefghi", "x" * 40, "é",
+     "ünï-" * 9, "日本語のID", "a\0", "ab\0\0", "i1", "i10", "i100", "i1000000"]
+    + [f"item-{n}" for n in range(300)]
+)
+_ASKED = _MIXED_IDS[:20] + [
+    "nope", "", "abcd", "a\0\0", "abcdefgh\0", "x" * 41, "É", 7, None, 2.5,
+    ("a",), "item-299", "item-300",
+]
+
+
+def _lazy(ids=_MIXED_IDS):
+    return modelfile._LazyDenseBiMap(*modelfile._encode_ids(list(ids)))
+
+
+def _id_counts():
+    b = modelfile.id_stats_block()
+    return b["lookups"]["hashed"], b["lookups"]["decoded"], b["index_builds"], b["decodes"]
+
+
+class TestHashedLookups:
+    """Every point look-up of a map over an encoded dictionary is answered
+    from the blob's sorted hashes; nothing is decoded."""
+
+    @pytest.mark.parametrize("ask", [
+        pytest.param(lambda m, k: m[k] if k in m else None, id="getitem+in"),
+        pytest.param(lambda m, k: m.get(k), id="get"),
+        pytest.param(lambda m, k: m.get(k, "dflt"), id="get-default"),
+        pytest.param(lambda m, k: k in m, id="in"),
+        pytest.param(lambda m, k: m.index_of([k]).tolist(), id="index_of-single"),
+    ])
+    def test_point_lookups_equal_a_plain_bimaps(self, ask):
+        lazy, plain = _lazy(), BiMap.from_dense(_MIXED_IDS)
+        for key in _ASKED:
+            assert ask(lazy, key) == ask(plain, key), key
+        with pytest.raises(KeyError):
+            lazy["nope"]
+        with pytest.raises(KeyError):
+            lazy[7]
+        assert type(lazy["abc"]) is int and lazy._fwd is None
+
+    @pytest.mark.parametrize("keys", [
+        pytest.param(_ASKED, id="mixed"),  # non-strings: the Python loop
+        pytest.param([k for k in _ASKED if isinstance(k, str)], id="strings"),
+        pytest.param([f"item-{n}" for n in range(280, 320)], id="ascii"),  # one NumPy pass
+        pytest.param(["abc"], id="single"),
+        pytest.param([], id="empty"),
+        pytest.param(["item-5"] * 30, id="repeated"),
+    ])
+    def test_index_of_equals_a_plain_bimaps(self, keys):
+        lazy, plain = _lazy(), BiMap.from_dense(_MIXED_IDS)
+        for _ in range(2):
+            got = lazy.index_of(keys)
+            assert got.dtype == np.int64
+            assert got.tolist() == plain.index_of(keys).tolist()
+        assert lazy._fwd is None
+
+    def test_the_numpy_pass_equals_the_python_loop(self):
+        lazy = _lazy()
+        ascii_keys = [f"item-{n}" for n in range(250, 350)] + ["a", "abcdefghi", "i100"]
+        assert lazy._find_all(ascii_keys).tolist() == [
+            lazy._find(k) for k in ascii_keys
+        ]
+        # keys that are not their own bytes are left to the loop
+        assert lazy._find_all(ascii_keys + ["é"]) is None
+        nul = ascii_keys + ["a\0", "ab\0\0", "a\0\0", "abcdefgh\0"]  # NULs are bytes like any other
+        assert lazy._find_all(nul).tolist() == [lazy._find(k) for k in nul]
+        assert lazy._find_all(nul)[-4:].tolist() == [10, 11, -1, -1]
+        assert lazy._find_all(ascii_keys + ["x" * 100]) is None
+        assert lazy.index_of(ascii_keys + ["é", "a\0"]).tolist()[-2:] == [7, 10]
+
+    @pytest.mark.parametrize("mul", [0, 1], ids=["all-equal", "xor-of-words"])
+    def test_ids_forced_onto_one_hash_are_told_apart_by_their_bytes(self, monkeypatch, mul):
+        monkeypatch.setattr(modelfile, "_HASH_MUL", np.uint64(mul))
+        lazy, plain = _lazy(), BiMap.from_dense(_MIXED_IDS)
+        words = lazy._hashed().keys >> np.uint64(lazy._hashed().shift)
+        assert len(np.unique(words)) < len(_MIXED_IDS)  # they do collide
+        keys = [k for k in _ASKED if isinstance(k, str)]
+        assert lazy.index_of(keys).tolist() == plain.index_of(keys).tolist()
+        ascii_keys = [f"item-{n}" for n in range(290, 310)]
+        assert lazy._find_all(ascii_keys).tolist() == plain.index_of(ascii_keys).tolist()
+        assert all(lazy.get(k) == plain.get(k) for k in keys)
+
+    @pytest.mark.parametrize("chunk", [1, 7, 100, len(_MIXED_IDS), 1 << 18])
+    def test_chunked_hashes_equal_the_whole_arrays_bit_for_bit(self, chunk):
+        blob, offs = modelfile._encode_ids(list(_MIXED_IDS))
+        assert len(_MIXED_IDS) % 7 and len(_MIXED_IDS) % 100  # uneven last chunks
+        whole = modelfile._hash_ids(blob, offs, len(_MIXED_IDS) + 1)
+        assert whole.dtype == np.uint64
+        np.testing.assert_array_equal(modelfile._hash_ids(blob, offs, chunk), whole)
+        assert whole.tolist() == [
+            modelfile._hash_key(s.encode("utf-8")) for s in _MIXED_IDS
+        ]
+
+    def test_the_index_is_one_sorted_word_an_id(self):
+        lazy = _lazy()
+        index = lazy._hashed()
+        keys, shift, cells = index.keys, index.shift, index.cells
+        assert keys.dtype == np.uint64 and len(keys) == len(_MIXED_IDS) == len(cells)
+        assert (np.diff(keys.astype(object)) > 0).all()
+        low = (1 << shift) - 1
+        assert sorted(int(k) & low for k in keys) == list(range(len(_MIXED_IDS)))
+        blob, offs = modelfile._encode_ids(list(_MIXED_IDS))
+        h = modelfile._hash_ids(blob, offs)
+        for k in keys.tolist():
+            assert k >> shift == int(h[k & low]) >> shift
+
+    def test_an_empty_map_and_a_map_of_one(self):
+        empty, one = _lazy([]), _lazy(["only"])
+        assert empty.get("a") is None and "a" not in empty and len(empty) == 0
+        assert empty.index_of(["a", 3]).tolist() == [-1, -1]
+        assert one["only"] == 0 and one.get("other") is None
+        assert one.index_of(["only", "x"] * 6).tolist() == [0, -1] * 6
+        blank = _lazy([""])  # one id, no bytes at all
+        assert blank[""] == 0 and blank.index_of(["x", ""] * 6).tolist() == [-1, 0] * 6
+
+    def test_two_threads_asking_at_once_build_once(self, monkeypatch):
+        import sys as _sys
+        import threading
+        import time
+
+        built = []
+        real = modelfile._hash_ids
+
+        def slow(blob, offs, *a):
+            built.append(threading.get_ident())
+            time.sleep(0.05)  # hold the build open while the others arrive
+            return real(blob, offs, *a)
+
+        monkeypatch.setattr(modelfile, "_hash_ids", slow)
+        lazy, got = _lazy(), []
+        _, _, builds, _ = _id_counts()
+        start = threading.Barrier(8)
+
+        def ask(n):
+            start.wait(timeout=10)
+            got.append((lazy.get(f"item-{n}"), lazy.index_of([f"item-{n}", "nope"]).tolist()))
+
+        old = _sys.getswitchinterval()
+        _sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=ask, args=(n,)) for n in range(8)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=20)
+        finally:
+            _sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert len(built) == 1
+        assert sorted(got) == [(16 + n, [16 + n, -1]) for n in range(8)]
+        assert _id_counts()[2] == builds + 1
+
+    @pytest.mark.parametrize("n", [1, 2, 7, 8, 9, 64, 1000])
+    def test_the_directory_opens_every_words_bucket(self, n):
+        """``first[b]`` is where the words whose top ``bits`` bits are b
+        begin, no bucket holds more than ``most``, and a search that
+        starts there finds every id — at sizes around the bucket's."""
+        ids = [f"id-{k}" for k in range(n)]
+        lazy = _lazy(ids)
+        index = lazy._hashed()
+        top = (index.keys >> np.uint64(64 - index.bits)).astype(np.int64)
+        assert len(index.first) == (1 << index.bits) + 1
+        assert index.first[0] == 0 and index.first[-1] == n
+        np.testing.assert_array_equal(
+            index.first[:-1], np.searchsorted(top, np.arange(1 << index.bits))
+        )
+        assert index.most == np.bincount(top).max() and (np.diff(top) >= 0).all()
+        assert [lazy._find(k) for k in ids] == list(range(n))
+        asked = ids + ["id-x", "nope"] * 4
+        assert lazy.index_of(asked).tolist() == list(range(n)) + [-1] * 8
+        assert lazy._find_all(asked).tolist() == lazy.index_of(asked).tolist()
+
+    def test_a_long_list_is_looked_up_a_part_at_a_time(self, monkeypatch):
+        monkeypatch.setattr(modelfile, "_VECTOR_MOST", 16)
+        monkeypatch.setattr(modelfile, "_VECTOR_FROM", 8)  # a part is a NumPy pass where it can be
+        lazy, plain = _lazy(), BiMap.from_dense(_MIXED_IDS)
+        keys = [f"item-{n}" for n in range(290, 330)] + ["é", 7] + ["abc"] * 11  # 53: parts of 16, 16, 16, 5
+        got = lazy.index_of(keys)
+        assert got.dtype == np.int64 and got.tolist() == plain.index_of(keys).tolist()
+
+    def test_appended_maps_read_through_to_the_hashes(self):
+        lazy = _lazy()
+        grown = lazy.appended(["new-a", "new-b"])
+        n = len(_MIXED_IDS)
+        assert grown["new-b"] == n + 1 and grown["abc"] == 2 and "nope" not in grown
+        assert grown.index_of(["new-a", "item-7", "nope"]).tolist() == [n, 23, -1]
+        assert lazy._fwd is None
+        with pytest.raises(Exception):
+            lazy.appended(["abc"])
+
+
+class TestIdCounters:
+    def test_lookups_count_by_what_answered_and_a_walk_is_the_one_decode(self):
+        lazy = _lazy()
+        h0, d0, b0, w0 = _id_counts()
+        assert lazy["abc"] == 2 and "nope" not in lazy
+        lazy.index_of(["a", "ab", 7])
+        assert _id_counts() == (h0 + 5, d0, b0 + 1, w0)
+        assert dict(lazy.items())["abc"] == 2  # a walk: the dictionary is decoded, once
+        assert list(lazy)[:2] == ["a", "ab"] and lazy == BiMap.from_dense(_MIXED_IDS)
+        assert _id_counts() == (h0 + 5, d0, b0 + 1, w0 + 1)
+        assert lazy["abc"] == 2 and lazy.get("nope") is None  # ... and answers from then on
+        lazy.index_of(["a", "ab", 7])
+        assert _id_counts() == (h0 + 5, d0 + 5, b0 + 1, w0 + 1)
+
+    def test_the_counters_are_in_metrics_and_stats(self):
+        from predictionio_tpu.obs import metrics as obs_metrics
+
+        _lazy()["abc"]
+        series = obs_metrics.parse_prometheus(obs_metrics.render_prometheus())
+        assert series['pio_model_id_lookups_total{path="hashed"}'] >= 1
+        assert 'pio_model_id_lookups_total{path="decoded"}' in series
+        assert series["pio_model_id_index_build_seconds_count"] >= 1
+        assert "pio_model_id_decodes_total" in series
+        block = modelfile.id_stats_block()
+        assert set(block) == {"lookups", "index_builds", "index_build_seconds", "decodes"}
+
+
+def _template_cases():
+    """(name, model, algorithm, queries) of each of the four ALS templates,
+    small enough for the exact path."""
+    from predictionio_tpu.models import ecommerce as ec
+    from predictionio_tpu.models import recommendation as rec
+    from predictionio_tpu.models import recommendeduser as ru
+    from predictionio_tpu.models import similarproduct as sp
+
+    rng = np.random.default_rng(11)
+    users = BiMap.from_dense([f"u{n}" for n in range(12)])
+    items = BiMap.from_dense([f"i{n}" for n in range(48)])
+    U = rng.standard_normal((12, 8), dtype=np.float32)
+    V = rng.standard_normal((48, 8), dtype=np.float32)
+    cats = {f"i{n}": [f"c{n % 3}"] for n in range(48)}
+    yield (
+        "recommendation",
+        rec.ALSModel(user_index=users, item_index=items, user_factors=U, item_factors=V),
+        rec.ALSAlgorithm(rec.ALSAlgorithmParams(rank=8)),
+        [rec.Query(user="u3", num=5), rec.Query(user="stranger", num=5)],
+    )
+    yield (
+        "ecommerce",
+        ec.ECommModel(user_index=users, item_index=items, user_factors=U,
+                      item_factors=V, categories=cats),
+        ec.ECommAlgorithm(ec.ECommAlgorithmParams(app_name="IdsApp")),
+        [ec.Query(user="u3", num=5),
+         ec.Query(user="u4", num=5, categories=["c1"], blackList=["i1", "nope"]),
+         ec.Query(user="u5", num=4, whiteList=["i2", "i3", "i5", "i8", "i13", "zz"]),
+         ec.Query(user="newcomer", num=3)],
+    )
+    yield (
+        "similarproduct",
+        sp.SimilarProductModel(item_index=items, item_factors=V, categories=cats),
+        sp.ALSAlgorithm(sp.ALSAlgorithmParams(rank=8)),
+        [sp.Query(items=["i3"], num=5),
+         sp.Query(items=["i4", "i9", "nope"], num=5, blackList=["i1"], categories=["c2"]),
+         sp.Query(items=["i6"], num=3, whiteList=["i2", "i3", "i5", "zz"]),
+         sp.Query(items=["nope"], num=3)],
+    )
+    yield (
+        "recommendeduser",
+        ru.RecommendedUserModel(followed_index=users, followed_factors=U),
+        ru.ALSAlgorithm(ru.ALSAlgorithmParams(rank=8)),
+        [ru.Query(users=["u3"], num=4),
+         ru.Query(users=["u4", "nope"], num=4, blackList=["u1"]),
+         ru.Query(users=["u5"], num=2, whiteList=["u2", "u7", "zz"])],
+    )
+
+
+@pytest.mark.parametrize("name", ["recommendation", "ecommerce", "similarproduct", "recommendeduser"])
+def test_a_template_served_from_a_model_file_decodes_no_dictionary(name, storage, monkeypatch):
+    """``predict`` of each of the four ALS templates over a model loaded
+    from a file: the in-memory model's answers, no id list ever built,
+    ``pio_model_id_decodes_total`` still, ``{path="hashed"}`` risen."""
+    from predictionio_tpu.data.event import Event
+    from predictionio_tpu.data.storage import App
+
+    app_id = storage.get_metadata_apps().insert(App(0, "IdsApp"))
+    storage.get_events().init(app_id)
+    storage.get_events().batch_insert(
+        [Event(event="view", entity_type="user", entity_id=u,
+               target_entity_type="item", target_entity_id=f"i{n}")
+         for u in ("u3", "u4", "newcomer") for n in (2, 7, 11)]
+        + [Event(event="$set", entity_type="constraint", entity_id="unavailableItems",
+                 properties={"items": ["i20", "i21", "gone"]})],
+        app_id,
+    )
+    _, model, algo, queries = next(c for c in _template_cases() if c[0] == name)
+    loaded = modelfile.deserialize(modelfile.serialize([("arrays", model)], "x"))[0][1]
+    want = [algo.predict(model, q) for q in queries]
+    monkeypatch.setattr(
+        modelfile._LazyDenseBiMap, "_ids",
+        lambda self: pytest.fail("an id list was built"),
+    )
+    hashed, _, _, decodes = _id_counts()
+    got = [type(algo)(algo.params).predict(loaded, q) for q in queries]
+    assert got == want and any(
+        getattr(r, "itemScores", None) or getattr(r, "userScores", None)
+        for r in got
+    )
+    assert _id_counts()[3] == decodes and _id_counts()[0] > hashed
+    assert all(
+        v._fwd is None for v in vars(loaded).values()
+        if isinstance(v, modelfile._LazyDenseBiMap)
+    )

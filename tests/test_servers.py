@@ -1384,6 +1384,28 @@ class TestQueryCacheServing:
                 deployed_engine["base"] + "/metrics", timeout=10) as r:
             assert b"tile_select" not in r.read()
 
+    def test_stats_route_carries_the_id_lookup_block(self, deployed_engine):
+        """``model_ids`` beside ``retrieval``: the look-ups of the model
+        files' id dictionaries by what answered them, as ``/metrics`` has
+        them under ``pio_model_id_*``; a served query moves ``hashed``
+        (the deployed model is loaded from its file) and decodes nothing."""
+        from predictionio_tpu.models import modelfile
+
+        base = deployed_engine["base"]
+        status, before = http("GET", base + "/stats.json")
+        assert status == 200
+        assert set(before["model_ids"]) == set(modelfile.id_stats_block())
+        _raw_post(base + "/queries.json", {"user": "u1", "num": 3})
+        _, after = http("GET", base + "/stats.json")
+        a, b = after["model_ids"], before["model_ids"]
+        assert a["decodes"] == b["decodes"]
+        assert a["lookups"]["decoded"] == b["lookups"]["decoded"]
+        assert a["lookups"]["hashed"] > b["lookups"]["hashed"]
+        with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
+            text = r.read()
+        assert b"pio_model_id_decodes_total" in text
+        assert b'pio_model_id_lookups_total{path="hashed"}' in text
+
     def test_stats_route_carries_the_upload_counter(self, deployed_engine):
         """``retrieval.uploads`` beside ``retrieval.host_reads``: the
         host-to-device transfers of the serving chain, as ``/metrics``
