@@ -34,7 +34,7 @@ import threading
 import time
 import urllib.request
 import uuid
-from concurrent.futures import InvalidStateError
+from concurrent.futures import Future, InvalidStateError
 from concurrent.futures import TimeoutError as FuturesTimeout
 from typing import Any, NamedTuple
 
@@ -142,20 +142,36 @@ class _MicroBatcher:
     LOAD-AWARE: the batcher is ALWAYS engaged — the engage decision
     moved from deploy time (the retired ``MIN_DISPATCH_S`` floor, which
     disengaged every local attachment and was exactly why an early
-    driver bench measured batching LOSING) to per-batch time, where
-    queue depth is known:
+    driver bench measured batching LOSING) to per-request time, where
+    the queue and the dispatch in flight are known.
 
-    - queue depth 1 (idle server): the collected "batch" takes the
-      single-item FAST PATH — straight to ``predict``, no padding, no
-      coalescing — so a lone query pays only the queue hop (~0.1 ms),
-      never the window.
-    - queue depth > 1 (amortization wins by construction): ONE padded
-      ``batch_predict`` per algorithm scores the whole batch. Depth is
-      created by load itself: requests queue behind the in-flight
-      device call and coalesce into the next one.
+    The right to dispatch is a SLOT (``_slot``, a lock) that one thread
+    holds at a time: the worker around its drain-and-dispatch turn, or a
+    request thread for one query of its own.
+
+    - nothing queued, nothing in flight (idle server): ``submit`` takes
+      the slot and the request thread scores its OWN query — straight to
+      ``predict``, no padding, no queue item, no ``Future``, no thread
+      hop either way (``EngineServer._score_inline``). Only where a lone
+      item would be dispatched at once anyway: not on a window-waiting
+      batcher, and not under a per-query deadline (whose early 503 needs
+      the scoring on another thread).
+    - a dispatch in flight, or anything queued: the request enqueues — it
+      never overtakes a queued one. The worker takes its first item,
+      then the slot, then whatever else queued meanwhile: depth > 1
+      (amortization wins by construction) is ONE padded ``batch_predict``
+      per algorithm; depth 1 is the same single-item fast path, on the
+      worker, at the cost of the two hops (request -> worker -> request).
+      Depth is created by load itself: requests queue behind the
+      in-flight device call, inline or the worker's, and coalesce into
+      the next one.
     - ``dispatch > window`` (a slow attachment): the worker
       additionally waits up to the window to grow the batch — added
       latency bounded by the window, itself below one dispatch.
+
+    ``pio_batch_dispatch_path_total{path}`` counts dispatches by the
+    thread that made them, ``pio_batch_enqueued_total{reason}`` why a
+    request was not taken inline.
 
     Batches pad to power-of-two sizes (1,2,4,...,``max_batch``) so the
     jitted scoring programs specialize on at most log2(max_batch)+1
@@ -165,8 +181,8 @@ class _MicroBatcher:
     ``batch_predict`` (the default loops ``predict``), and
     serving/plugins/feedback still run per query. Queries are parsed on
     their REQUEST thread (a malformed body 400s without occupying a
-    batch slot), and the serving/feedback/plugin tail also runs on the
-    request thread — the worker only collects and dispatches, so the
+    place in a batch), and the serving/feedback/plugin tail also runs on
+    the request thread — the worker only collects and dispatches, so the
     JSON/serving work of batchmates overlaps. A failing batch retries
     its items individually so one bad query can't poison its
     batchmates."""
@@ -181,6 +197,11 @@ class _MicroBatcher:
         self._q: "queue.Queue" = queue.Queue()
         self._stopped = False
         self._lock = threading.Lock()
+        # the right to dispatch; _waiting counts the items enqueued whose
+        # turn has not begun (under _lock): the queue alone reads empty
+        # between the worker's first get and its taking the slot
+        self._slot = threading.Lock()
+        self._waiting = 0
         self.dispatch_cost_s = (
             self._measure_dispatch() if dispatch_cost_s is None
             else dispatch_cost_s
@@ -208,10 +229,29 @@ class _MicroBatcher:
         # costs are counted per dispatch by the server (_dispatch)
         self._m_queue_wait = obs_metrics.histogram(
             "pio_batch_queue_wait_seconds",
-            "Per-query wait from submit to batch collection",
+            "Per-query wait from submit to the start of its dispatch turn",
         )
+        self._m_path = {
+            path: obs_metrics.counter(
+                "pio_batch_dispatch_path_total",
+                "Device dispatches by the thread that made them (inline: "
+                "the request's own; worker: the batch worker)",
+                path=path,
+            )
+            for path in ("inline", "worker")
+        }
+        self._m_enqueued = {
+            reason: obs_metrics.counter(
+                "pio_batch_enqueued_total",
+                "Queries handed to the batch worker, by what kept them "
+                "off their own thread",
+                reason=reason,
+            )
+            for reason in ("deadline", "window", "queued", "slot_busy")
+        }
         # the worker's time by state (idle / collect / dispatch /
-        # resolve); only the worker thread moves it
+        # resolve); only the worker thread moves it — an inline dispatch
+        # is not the worker's time
         self.clock = obs_runtime.WorkerClock()
         obs_metrics.gauge(
             "pio_batch_engaged",
@@ -249,29 +289,69 @@ class _MicroBatcher:
         return not self._stopped
 
     def submit(self, body: dict, variant=None) -> "_Submitted":
-        """Parse on the request thread, enqueue for the worker. Returns
-        the pending future (resolving to the per-algorithm predictions)
-        plus the parsed context the request thread needs to finish the
-        query itself. Parse errors raise here — a malformed body 400s
-        without ever occupying a batch slot."""
-        from concurrent.futures import Future
-
+        """Parse on the request thread, then either take the dispatch
+        slot for it (``fut`` None: the caller scores the query itself and
+        MUST ``release()``) or enqueue for the worker (``fut`` pending,
+        resolving to the per-algorithm predictions). Either way the
+        parsed context the request thread needs to finish the query
+        comes back. Parse errors raise here — a malformed body 400s
+        without ever occupying a place in a batch."""
         server = self._server
         v = variant if variant is not None else server._default_variant
         with server._lock:
             algorithms, serving = v.algorithms, v.serving
         query, sup = server._parse_query(body, algorithms, serving)
-        f: Future = Future()
         t0 = time.perf_counter()
+        # what a lone item would NOT be dispatched at once for
+        if server.query_deadline_s is not None:
+            reason = "deadline"
+        elif self._window_wait:
+            reason = "window"
+        else:
+            reason = None
         # the stopped check and the put share stop()'s lock: stop() can
-        # never drain between them and strand this future in a dead queue
+        # never drain between them and strand this future in a dead
+        # queue. The slot is judged under it too: of two requests that
+        # find the server idle one takes the slot and the other queues
         with self._lock:
             if self._stopped:
                 raise RuntimeError("server stopping")
-            # the request thread's trace rides the queue item — the
-            # worker thread can't see this thread's thread-local
-            self._q.put((f, t0, obs_trace.current_trace(), sup, v))
-        return _Submitted(f, query, serving, t0)
+            if reason is None:
+                if self._waiting:
+                    reason = "queued"  # never overtake a queued request
+                elif not self._slot.acquire(blocking=False):
+                    reason = "slot_busy"
+            if reason is not None:
+                f: Future = Future()
+                self._waiting += 1
+                # the request thread's trace rides the queue item — the
+                # worker thread can't see this thread's thread-local
+                self._q.put((f, t0, obs_trace.current_trace(), sup, v))
+        if reason is None:
+            return _Submitted(None, query, serving, t0, sup)
+        self._m_enqueued[reason].inc()
+        return _Submitted(f, query, serving, t0, sup)
+
+    def release(self) -> None:
+        """Give the slot back: an inline dispatch has ended."""
+        self._slot.release()
+
+    def stats_block(self) -> dict:
+        """``batch`` in ``/stats.json``."""
+        inline, worker = (
+            self._m_path[p].value() for p in ("inline", "worker")
+        )
+        n = inline + worker
+        return {
+            "window_wait": self._window_wait,
+            "dispatch_cost_ms": round(self.dispatch_cost_s * 1e3, 4),
+            "dispatch_path": {
+                "inline": inline,
+                "worker": worker,
+                "inline_share": round(inline / n, 4) if n else None,
+            },
+            "enqueued": {r: c.value() for r, c in self._m_enqueued.items()},
+        }
 
     def stop(self) -> None:
         import queue
@@ -280,12 +360,15 @@ class _MicroBatcher:
             if self._stopped:
                 return
             self._stopped = True
-        # no submit can enqueue past this point (flag is set under the
-        # lock); let the worker finish its in-flight batch, then fail
+        # no submit can enqueue or take the slot past this point (flag
+        # is set under the lock); let the worker finish its in-flight
+        # batch and a request thread its inline dispatch, then fail
         # whatever is still queued rather than leaving clients blocked
         # on the future timeout
         if self._thread is not None:
             self._thread.join(timeout=5)
+        if self._slot.acquire(timeout=5):
+            self._slot.release()
         while True:
             try:
                 f, *_ = self._q.get_nowait()
@@ -295,7 +378,8 @@ class _MicroBatcher:
                 f.set_exception(RuntimeError("server stopping"))
 
     def _collect(self) -> list | None:
-        """Wait for the first item, then its window: the next batch, or
+        """Wait for the first item, then the slot, then the window: the
+        next batch — with the slot HELD, ``_loop`` gives it back — or
         None once the batcher has stopped. One ``batch.collect``
         annotation covers the whole wait, so a profile can put an idle
         device down to "no request was queued"."""
@@ -312,6 +396,11 @@ class _MicroBatcher:
                     clock.to("idle")
                     continue
                 clock.to("collect")
+                # the slot BEFORE the rest of the queue: the batch is
+                # everything that queued while the slot's holder (an
+                # inline dispatch; the previous batch was this thread's)
+                # was in flight
+                self._slot.acquire()
                 batch = [first]
                 deadline = time.perf_counter() + self._window
                 while len(batch) < self._max:
@@ -331,6 +420,8 @@ class _MicroBatcher:
                         batch.append(self._q.get(timeout=remaining))
                     except queue.Empty:
                         break
+                with self._lock:
+                    self._waiting -= len(batch)
                 return batch
         return None
 
@@ -346,19 +437,23 @@ class _MicroBatcher:
                 for f, *_ in batch:
                     if not f.done():
                         f.set_exception(RuntimeError("batch worker failed"))
+            finally:
+                self._slot.release()
             self.clock.to("idle")
 
 
 class _Submitted(NamedTuple):
     """What ``_MicroBatcher.submit`` hands back to the request thread:
-    the pending predictions future plus the context to finish the query
-    (serving/feedback/plugins run on the request thread, not the batch
-    worker)."""
+    the pending predictions future — or None: this thread holds the
+    dispatch slot and scores ``sup`` itself — plus the context to finish
+    the query (serving/feedback/plugins run on the request thread, not
+    the batch worker)."""
 
     fut: Any
     query: Any
     serving: Any
     t0: float
+    sup: Any
 
 
 class _Variant:
@@ -898,13 +993,15 @@ class EngineServer:
         t_in: float | None = None,
     ) -> bytes:
         """Score through the micro-batcher; returns the encoded
-        response. The worker resolves the future with the per-algorithm
-        predictions; serving/feedback/plugins (``_finish_query``) and
-        the JSON encode run HERE on the request thread (``serve.tail``),
-        so batchmates' response tails overlap instead of serializing on
-        the worker. Deadline expiry is a timer-wheel entry that fails
-        the future — the client gets its 503 AT the deadline even while
-        the device call is still in flight."""
+        response. A query the batcher gave the dispatch slot is scored
+        HERE (``_score_inline``); any other waits for the worker to
+        resolve its future with the per-algorithm predictions. Either
+        way serving/feedback/plugins (``_finish_query``) and the JSON
+        encode run HERE on the request thread (``serve.tail``), so
+        batchmates' response tails overlap instead of serializing on the
+        worker. Deadline expiry is a timer-wheel entry that fails the
+        future — the client gets its 503 AT the deadline even while the
+        device call is still in flight."""
         with obs_trace.region("serve.submit", hist=self._m_submit, start=t_in):
             # legacy single-arg call for the default mount (submit
             # defaults to it): solo-deploy wrappers/stubs of submit keep
@@ -913,7 +1010,28 @@ class EngineServer:
                 sub = self.batcher.submit(body)
             else:
                 sub = self.batcher.submit(body, variant)
-        fut = sub.fut
+        if sub.fut is None:
+            predictions, t_resolved = self._score_inline(sub, variant)
+        else:
+            predictions, t_resolved = self._await_worker(sub.fut)
+        t_resumed = time.perf_counter()
+        # the hop back from the dispatching thread: a thread switch
+        # behind the worker, two clock readings apart behind an inline
+        # dispatch (no start where a stub resolved the future)
+        if t_resolved is not None and obs_metrics.enabled():
+            self._m_wake.observe(t_resumed - t_resolved)
+            tr = obs_trace.current_trace()
+            if tr is not None:
+                tr.add_span("serve.wake", t_resolved, t_resumed, "serve")
+        with obs_trace.region("serve.tail", hist=self._m_tail, start=t_resumed):
+            return jsonx.dumps_bytes(self._finish_query(
+                body, sub.query, predictions, sub.serving, sub.t0,
+                variant=variant,
+            ))
+
+    def _await_worker(self, fut) -> tuple[Any, float | None]:
+        """Block on a queued query's future: (predictions, when the
+        worker resolved it)."""
         handle = None
         if self.query_deadline_s is not None:
             handle = self.app.call_later(
@@ -940,20 +1058,29 @@ class EngineServer:
         finally:
             if handle is not None:
                 handle.cancel()
-        t_resumed = time.perf_counter()
-        # start and end on different threads: the worker stamped the
-        # future as it resolved it (absent when a stub resolved it)
-        t_resolved = getattr(fut, "t_resolved", None)
-        if t_resolved is not None and obs_metrics.enabled():
-            self._m_wake.observe(t_resumed - t_resolved)
-            tr = obs_trace.current_trace()
-            if tr is not None:
-                tr.add_span("serve.wake", t_resolved, t_resumed, "serve")
-        with obs_trace.region("serve.tail", hist=self._m_tail, start=t_resumed):
-            return jsonx.dumps_bytes(self._finish_query(
-                body, sub.query, predictions, sub.serving, sub.t0,
-                variant=variant,
-            ))
+        # the worker stamped the future as it resolved it
+        return predictions, getattr(fut, "t_resolved", None)
+
+    def _score_inline(
+        self, sub: "_Submitted", variant: "_Variant | None"
+    ) -> tuple[Any, float]:
+        """The single-item fast path on the REQUEST's thread, which holds
+        the dispatch slot (``_MicroBatcher.submit``): the series and
+        spans of a worker's single, under the request's own trace, with
+        what the quantities are here — a queue wait and, in the caller,
+        a wake of microseconds. An exception leaves as it would through
+        the future. Returns (predictions, when the dispatch ended)."""
+        try:
+            algorithms, models = self._begin_turn(
+                variant if variant is not None else self._default_variant,
+                [(sub.t0, obs_trace.current_trace())],
+            )
+            predictions = self._predict_single(
+                algorithms, models, sub.sup, "inline"
+            )
+            return predictions, time.perf_counter()
+        finally:
+            self.batcher.release()
 
     @staticmethod
     def _count_deadline(path: str) -> None:
@@ -994,8 +1121,6 @@ class EngineServer:
         lost)."""
         if self.query_deadline_s is None:
             return self.handle_query(body, variant)
-        from concurrent.futures import Future
-
         fut: Future = Future()
         handle = self.app.call_later(
             self.query_deadline_s, lambda: self._expire_future(fut, "unbatched")
@@ -1059,20 +1184,26 @@ class EngineServer:
             body, query, predictions, serving, t0, variant=v
         )
 
-    def _dispatch(self, n_real: int, n_padded: int, call, clock=None):
+    def _dispatch(self, n_real: int, n_padded: int, call, path=None):
         """One device dispatch of ``n_real`` queries in ``n_padded`` rows:
         the ``batch.dispatch[n]`` span on the current trace(s), its
         histograms and the row counters — EVERY dispatch, single or
         batched, so ``pio_batch_dispatch_seconds`` and ``pio_batch_size``
         count the same events. The score layer records its stages
         (``dispatch.shortlist`` / ``dispatch.rescore`` / ``dispatch.fetch``:
-        two launches, then one read) as children. ``clock`` is the batch
-        worker's: in ``dispatch`` for the region, in ``resolve`` after."""
+        two launches, then one read) as children. ``path`` says which
+        thread of the micro-batcher dispatches (``inline`` / ``worker``;
+        None: no batcher) and is counted; the worker's clock is in
+        ``dispatch`` for the region, in ``resolve`` after."""
         self._m_batch_size.observe(float(n_real))
         self._m_rows_real.inc(n_real)
         self._m_rows_padded.inc(n_padded)
-        if clock is not None:
-            clock.to("dispatch")
+        clock = None
+        if path is not None:
+            self.batcher._m_path[path].inc()
+            if path == "worker":
+                clock = self.batcher.clock
+                clock.to("dispatch")
         try:
             with obs_trace.region(
                 f"batch.dispatch[{n_real}]", hist=self._m_dispatch,
@@ -1165,30 +1296,45 @@ class EngineServer:
         for vid, group in groups.items():
             self._score_batch_group(by_id[vid], group)
 
+    def _begin_turn(self, variant: "_Variant", waits) -> tuple[list, list]:
+        """What a dispatch turn of the micro-batcher starts with, on
+        whichever thread holds the slot: the mount's (algorithms, models)
+        under the lock, and each query's wait since its submit —
+        ``waits`` holds a (t0, trace) a query — observed and on its
+        trace."""
+        with self._lock:
+            algorithms, models = variant.algorithms, variant.models
+        queue_wait = self.batcher._m_queue_wait
+        t_collect = time.perf_counter()
+        for t0, tr in waits:
+            queue_wait.observe(t_collect - t0)
+            if tr is not None:
+                tr.add_span("batch.queue_wait", t0, t_collect, "serve")
+        return algorithms, models
+
+    def _predict_single(self, algorithms, models, sup, path: str):
+        """The single-item fast path: no padding, no index plumbing —
+        straight to ``predict``."""
+        return self._dispatch(1, 1, lambda: [
+            a.predict(m, sup) for a, m in zip(algorithms, models)
+        ], path=path)
+
     def _score_batch_group(self, variant: "_Variant", items) -> None:
         """Score one tenant's micro-batch: every algorithm runs ONE
         batch_predict over the whole batch; serving/feedback/plugins run
         per query on the REQUEST threads (the futures resolve to
-        predictions, not responses). A single-item batch — an idle
-        server's lone query — skips the padding/coalesce machinery and
-        goes straight to ``predict``. A failing batch retries its
-        queries individually so one bad request can't fail its
-        batchmates."""
-        with self._lock:
-            algorithms, models = variant.algorithms, variant.models
-        batcher = self.batcher
-        clock = batcher.clock if batcher is not None else None
-        t_collect = time.perf_counter()
-        for fut, t0, tr, _, _ in items:
-            if batcher is not None:
-                batcher._m_queue_wait.observe(t_collect - t0)
-            if tr is not None:
-                tr.add_span("batch.queue_wait", t0, t_collect, "serve")
+        predictions, not responses). A single-item batch — a lone query
+        that queued behind a dispatch in flight — skips the
+        padding/coalesce machinery and goes straight to ``predict``, as
+        ``_score_inline`` does for one that met none. A failing batch
+        retries its queries individually so one bad request can't fail
+        its batchmates."""
+        algorithms, models = self._begin_turn(
+            variant, [(t0, tr) for _, t0, tr, _, _ in items]
+        )
 
         def predict_one(sup):
-            return self._dispatch(1, 1, lambda: [
-                a.predict(m, sup) for a, m in zip(algorithms, models)
-            ], clock=clock)
+            return self._predict_single(algorithms, models, sup, "worker")
 
         # the worker has no trace of its own: for the dispatch it stands
         # in for every batchmate's, as a child of their ``serve`` spans
@@ -1230,7 +1376,7 @@ class EngineServer:
 
             with obs_trace.use_trace(fanout, parent="serve"):
                 per_algo = self._dispatch(
-                    n_real, pad_to, batch_call, clock=clock
+                    n_real, pad_to, batch_call, path="worker"
                 )
         except Exception:
             logger.exception("batched scoring failed; retrying per query")
@@ -1444,6 +1590,12 @@ class EngineServer:
             body["device"] = obs_device.device_block()
             body["freshness"] = obs_freshness.block()
             body["runtime"] = obs_runtime.block()
+            batcher = server.batcher
+            body["batch"] = (
+                {"enabled": True, **batcher.stats_block()}
+                if batcher is not None
+                else {"enabled": False}
+            )
             try:
                 from predictionio_tpu.models import modelfile as _modelfile
                 from predictionio_tpu.ops import retrieval as _retrieval

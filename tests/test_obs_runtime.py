@@ -63,6 +63,10 @@ def _state_seconds() -> dict[str, float]:
     }
 
 
+def _path_count(path: str) -> int:
+    return metrics.counter("pio_batch_dispatch_path_total", path=path).value()
+
+
 class _Ann:
     """In ``TraceAnnotation``'s place: what was entered and left, on which
     thread, appended to ``seen``."""
@@ -83,6 +87,8 @@ class _Ann:
 class _Algo:
     """What ``_score_batch_group`` asks of an algorithm, no device."""
 
+    query_class = None  # a query is its JSON body
+
     def __init__(self, sleep_s: float = 0.0):
         self.sleep_s = sleep_s
 
@@ -95,9 +101,14 @@ class _Algo:
         return [(i, ("p", sup)) for i, sup in indexed]
 
 
+class _Serving:
+    def supplement(self, query):
+        return query
+
+
 class _Variant:
     def __init__(self, algo):
-        self.algorithms, self.models = [algo], [None]
+        self.algorithms, self.models, self.serving = [algo], [None], _Serving()
 
 
 @pytest.fixture()
@@ -117,12 +128,21 @@ def worker():
     srv._m_dispatch_self = metrics.histogram("pio_batch_dispatch_self_seconds")
     srv._m_rows_real = metrics.counter("pio_batch_rows_total", kind="real")
     srv._m_rows_padded = metrics.counter("pio_batch_rows_total", kind="padded")
+    # and what ``_serve_batched`` touches around a dispatch
+    srv._m_submit = metrics.histogram("pio_serving_submit_seconds")
+    srv._m_wake = metrics.histogram("pio_serving_wake_seconds")
+    srv._m_tail = metrics.histogram("pio_serving_tail_seconds")
+    srv.query_deadline_s = None
+    srv._default_variant = _Variant(_Algo())
+    srv._finish_query = lambda body, query, predictions, *a, **kw: predictions
     srv.batcher = _MicroBatcher(srv, window_ms=1.0, dispatch_cost_s=0.0)
 
     def submit(variant, n=1):
         futs = [Future() for _ in range(n)]
         for f in futs:
-            srv.batcher._q.put((f, time.perf_counter(), None, "q", variant))
+            with srv.batcher._lock:  # as ``submit`` enqueues
+                srv.batcher._waiting += 1
+                srv.batcher._q.put((f, time.perf_counter(), None, "q", variant))
         return futs
 
     yield srv.batcher, submit
@@ -190,6 +210,150 @@ class TestWorkerClock:
             f.result(timeout=5)
         _settle(batcher)
         assert batcher.clock.state == "idle"
+
+    def test_inline_queries_alone_fill_every_series_and_leave_the_worker_idle(self, worker):
+        """ISSUE 47: a lone query is dispatched on its request thread. The
+        histograms the worker's path observes count it all the same, its
+        trace has the chain without a hole, and none of it is the worker's
+        time."""
+        batcher, _ = worker
+        srv = batcher._server
+        series = [
+            "pio_batch_queue_wait_seconds", "pio_serving_wake_seconds",
+            "pio_batch_size", "pio_batch_dispatch_seconds",
+            "pio_batch_dispatch_self_seconds", "pio_serving_submit_seconds",
+            "pio_serving_tail_seconds",
+        ]
+        count = lambda n: metrics.histogram(n).merged()[2]  # noqa: E731
+        _settle(batcher)
+        n0 = {n: count(n) for n in series}
+        rows0 = metrics.counter("pio_batch_rows_total", kind="real").value()
+        p0 = (_path_count("inline"), _path_count("worker"))
+        wake0 = metrics.histogram("pio_serving_wake_seconds").merged()[1]
+        s0, t0 = _state_seconds(), time.perf_counter()
+        tr = obs_trace.Trace("inline")
+        with obs_trace.use_trace(tr), obs_trace.region("serve"):
+            for i in range(7):
+                assert srv._serve_batched({"q": i}) == b'[["p",{"q":%d}]]' % i
+        _settle(batcher)
+        s1, wall = _state_seconds(), time.perf_counter() - t0
+        for n in series:
+            assert count(n) == n0[n] + 7, n
+        assert metrics.counter("pio_batch_rows_total", kind="real").value() == rows0 + 7
+        assert (_path_count("inline"), _path_count("worker")) == (p0[0] + 7, p0[1])
+        assert batcher.stats_block()["dispatch_path"]["inline"] == p0[0] + 7
+        # two clock readings apart, not a thread switch
+        assert metrics.histogram("pio_serving_wake_seconds").merged()[1] - wake0 < 0.05
+        spans = [(s[0], s[3]) for s in tr.spans]
+        assert spans[:5] == [
+            ("serve.submit", "serve"), ("batch.queue_wait", "serve"),
+            ("batch.dispatch[1]", "serve"), ("serve.wake", "serve"),
+            ("serve.tail", "serve"),
+        ]
+        assert len(spans) == 7 * 5 + 1
+        # the worker's clock is the worker's: idle all along, and whole
+        d = {k: s1[k] - s0[k] for k in s0}
+        assert d["collect"] == d["dispatch"] == d["resolve"] == 0.0
+        assert d["idle"] == pytest.approx(wall, abs=0.06)
+
+    def test_a_query_behind_an_inline_dispatch_is_the_workers(self, worker):
+        """... and its wait for the slot is the worker's ``collect``: the
+        four states still sum to the worker's wall time."""
+        batcher, _ = worker
+        srv = batcher._server
+        inside, gate = threading.Event(), threading.Event()
+
+        class Held(_Algo):
+            def predict(self, model, sup):
+                if not inside.is_set():  # the first, inline: holds the slot
+                    inside.set()
+                    assert gate.wait(timeout=5)
+                return ("p", sup)
+
+        srv._default_variant = _Variant(Held())
+        _settle(batcher)
+        s0, t0 = _state_seconds(), time.perf_counter()
+        out = []
+        threads = [
+            threading.Thread(target=lambda i=i: out.append(srv._serve_batched({"q": i})))
+            for i in range(2)
+        ]
+        threads[0].start()
+        assert inside.wait(timeout=5)
+        threads[1].start()
+        deadline = time.monotonic() + 5
+        while batcher.clock.state != "collect":  # the worker has the item, not the slot
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        time.sleep(0.05)
+        gate.set()
+        for t in threads:
+            t.join(timeout=5)
+            assert not t.is_alive()
+        _settle(batcher)
+        s1, wall = _state_seconds(), time.perf_counter() - t0
+        assert sorted(out) == [b'[["p",{"q":0}]]', b'[["p",{"q":1}]]']
+        d = {k: s1[k] - s0[k] for k in s0}
+        assert sum(d.values()) == pytest.approx(wall, rel=0.01, abs=0.06)
+        assert d["collect"] >= 0.045  # behind the first, then its own dispatch
+        assert 0.0 < d["dispatch"] < d["collect"]
+
+    def test_many_request_threads_share_one_slot(self, worker):
+        """More threads than cores, the interpreter switching every 10 us:
+        no two dispatches overlap, every query gets ITS answer, every row
+        is dispatched once, and the slot and the count of waiting items
+        come back to rest (a lost update of ``_waiting`` would keep every
+        later query off the inline path)."""
+        batcher, _ = worker
+        srv = batcher._server
+        inside, overlaps = [0], []
+
+        class Exclusive(_Algo):
+            def _enter(self):
+                inside[0] += 1
+                if inside[0] != 1:
+                    overlaps.append(inside[0])
+                time.sleep(0.0002)
+                inside[0] -= 1
+
+            def predict(self, model, sup):
+                self._enter()
+                return ("p", sup)
+
+            def batch_predict(self, model, indexed):
+                self._enter()
+                return [(i, ("p", sup)) for i, sup in indexed]
+
+        srv._default_variant = _Variant(Exclusive())
+        real = metrics.counter("pio_batch_rows_total", kind="real")
+        rows0, p0 = real.value(), (_path_count("inline"), _path_count("worker"))
+        n_threads, each = 4 * (os.cpu_count() or 4), 40
+        wrong = []
+
+        def client(k):
+            for i in range(each):
+                body = {"q": k * each + i}
+                got = json.loads(srv._serve_batched(body))
+                if got != [["p", body]]:
+                    wrong.append((body, got))
+
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=client, args=(k,)) for k in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(was)
+        _settle(batcher)
+        assert not wrong and not overlaps
+        assert real.value() == rows0 + n_threads * each
+        inline, by_worker = _path_count("inline") - p0[0], _path_count("worker") - p0[1]
+        assert inline >= 1 and by_worker >= 1  # both paths ran
+        assert batcher._waiting == 0 and not batcher._slot.locked()
 
     def test_resolve_is_an_annotation_while_a_profile_runs(self, worker, monkeypatch):
         batcher, submit = worker

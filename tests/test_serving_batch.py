@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -339,3 +340,306 @@ class TestPerQueryFiltersInBatch:
         assert {s.item for s in got[0].itemScores} != {
             s.item for s in got[1].itemScores
         }
+
+
+# -- the dispatch slot: a lone query is scored on its own request thread -------
+
+
+@pytest.fixture(scope="module")
+def trained():
+    """One tiny trained Recommendation engine for the slot's cases (they
+    stub ``predict`` / ``batch_predict`` or ask for one answer)."""
+    from predictionio_tpu.data.storage import set_storage, test_storage
+
+    s = test_storage()
+    set_storage(s)
+    try:
+        engine, inst = _train_rec(s)
+    finally:
+        set_storage(None)
+    return s, engine, inst
+
+
+class _Slot:
+    """A live server whose batcher does not window-wait (a lone item is
+    dispatched at once), its algorithm's ``predict`` / ``batch_predict``
+    wrapped: ``calls`` gets (kind, rows, thread) a call, and a call waits
+    for ``gate`` where the test cleared it."""
+
+    def __init__(self, trained, **kwargs):
+        from predictionio_tpu.obs import metrics
+        from predictionio_tpu.server.engine_server import EngineServer
+
+        storage, engine, inst = trained
+        kwargs.setdefault("batch_window_ms", 5.0)
+        kwargs.setdefault("dispatch_cost_s", 0.0)
+        self.server = EngineServer(
+            engine, inst, storage=storage, host="127.0.0.1", port=0, **kwargs
+        )
+        self.port = self.server.start()
+        self.batcher = self.server.batcher
+        self.calls: list[tuple[str, int, threading.Thread]] = []
+        self.entered = threading.Semaphore(0)
+        self.gate = threading.Event()
+        self.gate.set()
+        self.fail: Exception | None = None
+        self._cls = type(self.server.algorithms[0])
+        self._real = (self._cls.predict, self._cls.batch_predict)
+        real_predict, real_bp = self._real
+        me = self
+
+        def predict(self_, model, query):
+            me._called("predict", 1)
+            return real_predict(self_, model, query)
+
+        def batch_predict(self_, model, queries):
+            # ``predict`` delegates here: only the batcher's own call counts
+            if len(queries) > 1:
+                me._called("batch_predict", len(queries))
+            return real_bp(self_, model, queries)
+
+        self._cls.predict, self._cls.batch_predict = predict, batch_predict
+        self._metrics = metrics
+
+    def _called(self, kind, n):
+        self.calls.append((kind, n, threading.current_thread()))
+        self.entered.release()
+        assert self.gate.wait(timeout=20)
+        if self.fail is not None:
+            raise self.fail
+
+    def close(self):
+        self.gate.set()
+        self._cls.predict, self._cls.batch_predict = self._real
+        self.server.stop()
+
+    def path(self, which):
+        return self._metrics.counter(
+            "pio_batch_dispatch_path_total", path=which).value()
+
+    def enqueued(self, reason):
+        return self._metrics.counter(
+            "pio_batch_enqueued_total", reason=reason).value()
+
+    def ask(self, user="u1", variant=None):
+        """One query on a thread of its own, as a request thread makes
+        it: the thread, and a box that gets the answer or the exception."""
+        box: dict = {}
+
+        def run():
+            try:
+                box["answer"] = json.loads(self.server.serve_query_bytes(
+                    {"user": user, "num": 3}, variant))
+            except Exception as e:  # noqa: BLE001 - the test reads it
+                box["error"] = e
+
+        t = threading.Thread(target=run, name=f"request-{user}")
+        t.start()
+        return t, box
+
+    def waiting(self, n):
+        """Until ``n`` queries are enqueued and their turn has not begun."""
+        deadline = time.monotonic() + 10
+        while self.batcher._waiting != n:
+            assert time.monotonic() < deadline, self.batcher._waiting
+            time.sleep(0.002)
+
+
+@pytest.fixture()
+def slot(trained):
+    made = []
+
+    def make(**kwargs):
+        made.append(_Slot(trained, **kwargs))
+        return made[-1]
+
+    yield make
+    for s in made:
+        s.close()
+
+
+def _join(*threads):
+    for t in threads:
+        t.join(timeout=20)
+        assert not t.is_alive()
+
+
+class TestDispatchSlot:
+    def test_lone_query_runs_on_its_request_thread(self, slot):
+        s = slot()
+        inline0, worker0 = s.path("inline"), s.path("worker")
+        for user in ("u1", "u2", "u3"):
+            t, box = s.ask(user)
+            _join(t)
+            assert len(box["answer"]["itemScores"]) == 3
+            assert s.calls[-1] == ("predict", 1, t)
+        assert s.path("inline") == inline0 + 3 and s.path("worker") == worker0
+        assert not s.batcher._slot.locked() and s.batcher._waiting == 0
+        # over HTTP the thread is one of the front end's pool, not the worker's
+        status, _ = _post_raw(
+            f"http://127.0.0.1:{s.port}/queries.json", {"user": "u4", "num": 3})
+        assert status == 200
+        assert s.calls[-1][2] is not s.batcher._thread
+        assert s.path("inline") == inline0 + 4
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{s.port}/stats.json", timeout=15) as resp:
+            batch = json.loads(resp.read())["batch"]
+        assert batch["enabled"] and not batch["window_wait"]
+        assert set(batch["enqueued"]) == {"deadline", "window", "queued", "slot_busy"}
+        block = batch["dispatch_path"]
+        assert block["inline"] == s.path("inline")
+        assert block["inline_share"] == pytest.approx(
+            block["inline"] / (block["inline"] + block["worker"]), abs=1e-4)
+
+    @pytest.mark.parametrize("holder", ["inline", "worker"])
+    def test_what_queues_behind_a_dispatch_leaves_as_one_batch(self, slot, holder):
+        """N requests that arrive while a dispatch holds the slot — a
+        request thread's or the worker's — are ONE batch of N on the
+        worker, as they were when every dispatch was the worker's."""
+        s = slot()
+        worker = s.batcher._thread
+        busy0 = s.enqueued("slot_busy")
+        threads = []
+        if holder == "worker":
+            # a first query finds the slot taken and queues; the worker
+            # dispatches it once the slot is free, and holds it there
+            assert s.batcher._slot.acquire(blocking=False)
+            t, _ = s.ask("u0")
+            threads.append(t)
+            s.waiting(1)
+            s.gate.clear()
+            s.batcher._slot.release()
+        else:
+            s.gate.clear()
+            t, _ = s.ask("u0")
+            threads.append(t)
+        assert s.entered.acquire(timeout=10)
+        assert (s.calls[-1][2] is worker) == (holder == "worker")
+        boxes = []
+        for i in range(1, 6):
+            t, box = s.ask(f"u{i}")
+            threads.append(t)
+            boxes.append(box)
+            s.waiting(i)  # in order, each behind the one before
+        s.gate.set()
+        _join(*threads)
+        assert [c[:2] for c in s.calls[1:]] == [("batch_predict", 8)]
+        assert s.calls[-1][2] is worker
+        assert all(len(b["answer"]["itemScores"]) == 3 for b in boxes)
+        assert s.enqueued("slot_busy") - busy0 >= 1
+        assert not s.batcher._slot.locked() and s.batcher._waiting == 0
+
+    def test_no_request_overtakes_a_queued_one(self, slot):
+        """The worker has taken the one queued item and waits for the
+        slot: the queue reads empty, and the slot may come free any
+        moment — a newcomer still queues behind it."""
+        s = slot()
+        assert s.batcher._slot.acquire(blocking=False)  # a dispatch in flight
+        ta, _ = s.ask("u1")
+        s.waiting(1)
+        deadline = time.monotonic() + 10
+        while not s.batcher._q.empty():  # the worker holds the item now
+            assert time.monotonic() < deadline
+            time.sleep(0.002)
+        queued0, inline0 = s.enqueued("queued"), s.path("inline")
+        tb, _ = s.ask("u2")
+        s.waiting(2)
+        assert s.enqueued("queued") == queued0 + 1
+        s.batcher._slot.release()
+        _join(ta, tb)
+        assert s.path("inline") == inline0
+        assert [c[:2] for c in s.calls] == [("batch_predict", 2)]
+        assert s.calls[0][2] is s.batcher._thread
+
+    @pytest.mark.parametrize("why, kwargs", [
+        ("window", {"dispatch_cost_s": 10.0}),
+        ("deadline", {"query_deadline_ms": 2000.0}),
+    ])
+    def test_a_window_or_a_deadline_keeps_every_query_on_the_worker(
+            self, slot, why, kwargs):
+        s = slot(**kwargs)
+        inline0, worker0, why0 = s.path("inline"), s.path("worker"), s.enqueued(why)
+        for user in ("u1", "u2"):
+            status, _ = _post_raw(
+                f"http://127.0.0.1:{s.port}/queries.json", {"user": user, "num": 3})
+            assert status == 200
+        assert s.path("inline") == inline0
+        assert s.path("worker") == worker0 + 2
+        assert s.enqueued(why) == why0 + 2
+        assert all(c[2] is s.batcher._thread for c in s.calls)
+
+    def test_the_deadlines_503_arrives_at_the_deadline(self, slot):
+        """... while the device call is still in flight: what needs the
+        scoring on another thread, and keeps such a server off the
+        inline path."""
+        s = slot(query_deadline_ms=150.0)
+        s.gate.clear()
+        t0 = time.perf_counter()
+        status, body = _post_raw(
+            f"http://127.0.0.1:{s.port}/queries.json", {"user": "u1", "num": 3})
+        took = time.perf_counter() - t0
+        assert status == 503, body
+        assert 0.14 <= took < 5.0 and not s.gate.is_set()  # predict still waits
+        assert s.calls[0][2] is s.batcher._thread
+
+    @pytest.mark.parametrize("path", ["inline", "worker"])
+    @pytest.mark.parametrize("exc, status", [
+        (ValueError("no such field"), 400), (KeyError("user"), 400),
+        (RuntimeError("device lost"), 500),
+    ])
+    def test_a_failing_predict_maps_to_the_same_status(self, slot, path, exc, status):
+        s = slot(**({"dispatch_cost_s": 10.0} if path == "worker" else {}))
+        n0 = s.path(path)
+        s.fail = exc
+        got, body = _post_raw(
+            f"http://127.0.0.1:{s.port}/queries.json", {"user": "u1", "num": 3})
+        assert got == status, body
+        assert s.path(path) == n0 + 1
+        # the slot came back: the next query is served, on the same path
+        s.fail = None
+        got, _ = _post_raw(
+            f"http://127.0.0.1:{s.port}/queries.json", {"user": "u1", "num": 3})
+        assert got == 200 and s.path(path) == n0 + 2
+        assert not s.batcher._slot.locked()
+
+    def test_stop_waits_for_an_inline_dispatch(self, slot):
+        s = slot()
+        s.gate.clear()
+        t, box = s.ask("u1")
+        assert s.entered.acquire(timeout=10)
+        stopper = threading.Thread(target=s.batcher.stop)
+        stopper.start()
+        stopper.join(timeout=0.3)
+        assert stopper.is_alive()  # the query in flight is being served
+        s.gate.set()
+        _join(t, stopper)
+        assert len(box["answer"]["itemScores"]) == 3
+        assert not s.batcher.active
+        with pytest.raises(RuntimeError, match="server stopping"):
+            s.batcher.submit({"user": "u2", "num": 3})
+
+    def test_two_variants_share_the_slot(self, slot, trained):
+        from predictionio_tpu.models import recommendation as rec
+
+        _, _, inst = trained
+        s = slot(extra_variants=[("b", rec.engine(), inst)])
+        b = s.server.variants["b"]
+        inline0 = s.path("inline")
+        ta, box_a = s.ask("u1")
+        _join(ta)
+        tb, box_b = s.ask("u1", b)
+        _join(tb)
+        assert box_a["answer"] == box_b["answer"]
+        assert s.path("inline") == inline0 + 2
+        assert [c[2] for c in s.calls] == [ta, tb]
+        assert b.request_count == 1
+        # one mount's dispatch in flight: the other's query queues
+        s.gate.clear()
+        ta, _ = s.ask("u2")
+        assert s.entered.acquire(timeout=10)
+        tb, box_b = s.ask("u3", b)
+        s.waiting(1)
+        s.gate.set()
+        _join(ta, tb)
+        assert s.calls[-1] == ("predict", 1, s.batcher._thread)
+        assert len(box_b["answer"]["itemScores"]) == 3

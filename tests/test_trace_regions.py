@@ -190,10 +190,16 @@ def _counter(name, **labels):
     return metrics.counter(name, **labels).value()
 
 
+# which thread dispatches a lone query (ISSUE 47): the batch worker's
+# where the batcher window-waits, the request's own where it does not
+PATHS = pytest.mark.parametrize("served", ["worker", "inline"], indirect=True)
+
+
 @pytest.fixture()
-def served(storage, monkeypatch):
+def served(storage, monkeypatch, request):
     """A live EngineServer over a 48-item ALS model with two-stage
-    retrieval forced on and the batcher window-waiting (40 ms)."""
+    retrieval forced on and the batcher window-waiting (40 ms) — or,
+    asked for ``inline``, not: a lone query keeps its request thread."""
     from predictionio_tpu.cli import commands
     from predictionio_tpu.core import EngineParams
     from predictionio_tpu.core.workflow import run_train
@@ -226,10 +232,13 @@ def served(storage, monkeypatch):
     instance = storage.get_metadata_engine_instances().get_latest_completed(
         "trace-chain", "0", "default"
     )
+    lone_path = getattr(request, "param", "worker")
     server = EngineServer(
         engine, instance, storage=storage, host="127.0.0.1", port=0,
-        batch_window_ms=40.0, dispatch_cost_s=1.0,  # window-wait: batches form
+        batch_window_ms=40.0,
+        dispatch_cost_s=1.0 if lone_path == "worker" else 0.0,  # window-wait: batches form
     )
+    server.lone_path = lone_path
     port = server.start()
     try:
         yield server, port
@@ -278,8 +287,13 @@ def _burst(port, users, prefix):
 
 
 class TestServingChain:
+    @PATHS
     def test_one_request_yields_every_span_once(self, served):
-        _, port = served
+        """On either path: the same chain, the same parents, one
+        observation a histogram — no hole where no thread was switched."""
+        server, port = served
+        paths0 = {p: _counter("pio_batch_dispatch_path_total", path=p)
+                  for p in ("inline", "worker")}
         # compiles; its trace is not the one read, but its http.write is
         # recorded after the response left: wait for it, or it counts below
         _query(port, "u0", "feedc0de00000000")
@@ -323,6 +337,14 @@ class TestServingChain:
             assert _hist_count(n, lab) == before[n] + 1, n
         assert _counter("pio_batch_rows_total", kind="real") == rows[0] + 1
         assert _counter("pio_batch_rows_total", kind="padded") == rows[1] + 1
+        # both queries went the fixture's way, and only that way
+        for p, n0 in paths0.items():
+            assert _counter("pio_batch_dispatch_path_total", path=p) \
+                == n0 + 2 * (p == server.lone_path), p
+        if server.lone_path == "inline":
+            # no thread was switched: the two hops are clock readings apart
+            assert by_name["batch.queue_wait"]["durationMs"] < 1.0
+            assert by_name["serve.wake"]["durationMs"] < 1.0
 
     def test_every_dispatch_is_counted(self, served):
         """Singles and multi-item batches alike: the dispatch histogram
@@ -378,6 +400,7 @@ class TestServingChain:
             )) <= d["durationMs"] + 6e-3)
         assert batched >= 2  # the burst did coalesce
 
+    @PATHS
     def test_pio_obs_off_records_none(self, served):
         _, port = served
         _query(port, "u0")
