@@ -32,10 +32,20 @@ in k and every query kind shares the same batched programs:
   next power of two, counted), so the compiled shapes do not move with
   the traffic; a ``whiteList`` is scored as a candidate list;
 - no request builds a dense [num_items] host array.
+
+With ``sharded_serving`` (``pio deploy --mesh data=N``: a catalog one
+chip cannot hold) the item rows stand split over the mesh and the same
+rules are applied on every shard: the catalog-wide vectors sharded like
+the rows they guard, built a shard at a time; the per-query lists
+replicated, in global rows, each shard resolving the ones it holds
+(parallel/shard_topk.py). The item table is then never one host array
+nor one device array, and the user table stays on the host.
 """
 
 from __future__ import annotations
 
+import functools
+import json
 import logging
 import threading
 from dataclasses import dataclass, field
@@ -64,6 +74,9 @@ from predictionio_tpu.models.filters import (
     held_rows,
     padded_rows,
     query_rules,
+    sharded_availability_vector,
+    sharded_catalog,
+    sharded_category_vectors,
 )
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import metrics as obs_metrics
@@ -161,6 +174,9 @@ class ECommAlgorithmParams(Params):
     compute_dtype: str = "float32"
     storage_dtype: str = "float32"
     weights: list[dict] = field(default_factory=list)  # [{items, weight}]
+    # serve with the item rows split over the device mesh, the rules
+    # applied on every shard (models/filters.py sharded_catalog)
+    sharded_serving: bool = False
     sharded_train: bool = False  # train over the WorkflowContext mesh
     # per-chip budget for the sharded trainer's gathered opposite
     # factors; past it training auto-switches to the ppermute ring
@@ -204,6 +220,13 @@ class ECommModel(ItemCategories):
             return rows.astype(np.float32) * self.item_scales[ixs][..., None]
         return np.asarray(rows, dtype=np.float32)
 
+    def item_table(self):
+        """The item factor table in scorer form: the (int8 values, f32
+        scales) pair for quantized models, else the dense array."""
+        if self.item_scales is not None:
+            return (self.item_factors, self.item_scales)
+        return self.item_factors
+
     def device_factors(self):
         """(U_dev, V_dev); quantized tables stay (values, scales) pairs
         on device — ops.topk scores them without densifying."""
@@ -228,6 +251,7 @@ class ECommModel(ItemCategories):
         # unpickle
         state.pop("_weighted_V", None)
         state.pop("_coarse_V", None)
+        state.pop("_sharded_V", None)
         state.pop("_rules", None)
         return state
 
@@ -493,15 +517,18 @@ class ECommAlgorithm(Algorithm):
             return None
         return model.item_rows(ixs).mean(axis=0)
 
-    def _catalog_rules(self, model: ECommModel, rows: int,
-                       cache: dict | None):
+    def _catalog_rules(self, model: ECommModel, rows, cache: dict | None):
         """The resident catalog-wide rules over ``rows`` stored rows
         (the coarse catalog's, padding included; the catalog's own below
-        the retrieval threshold): (availability [rows] uint8, one
-        [rows] int32 category vector per category column), on the
-        device. The category vectors are built once; the availability
-        vector again only when the constraint's CONTENT has changed —
-        a view event moves the token and costs one point read here."""
+        the retrieval threshold) or — ``rows`` a ``ShardedCatalog`` —
+        over every shard's stored rows, sharded like them and built a
+        shard at a time: (availability uint8, one int32 category vector
+        per category column), on the device. The category vectors are
+        built once; the availability vector again only when the
+        constraint's CONTENT has changed — a view event moves the token
+        and costs one point read here."""
+        from predictionio_tpu.ops import retrieval
+
         unavail = self._unavailable_rows(model, cache)
         states = model.__dict__.setdefault("_rules", {})
         state = states.get(rows)
@@ -510,83 +537,108 @@ class ECommAlgorithm(Algorithm):
             or np.array_equal(state["unavail"], unavail)
         ):
             return state["avail"], state["cats"]
+        refresh = functools.partial(
+            obs_trace.region, "rules.refresh", hist=_m_refresh_secs
+        )
         with self._serve_lock:
-            if state is None:
-                cats = category_vectors(model.item_categories, rows)
-            else:
-                cats = state["cats"]
-            with obs_trace.region("rules.refresh", hist=_m_refresh_secs):
-                avail = availability_vector(
-                    len(model.item_index), rows, unavail
+            cats = None if state is None else state["cats"]
+            if isinstance(rows, int):
+                if cats is None:
+                    cats = category_vectors(model.item_categories, rows)
+                with refresh():
+                    avail = availability_vector(
+                        len(model.item_index), rows, unavail
+                    )
+                held = (avail, *cats)
+            else:  # one observation a shard's rebuild
+                if cats is None:
+                    cats = sharded_category_vectors(
+                        rows, model.item_categories
+                    )
+                avail = sharded_availability_vector(rows, unavail, refresh)
+                held = tuple(
+                    a.addressable_shards[0].data for a in (avail, *cats)
                 )
+            retrieval.set_resident(rules=held)
             _m_refresh.inc()
             states[rows] = {"unavail": unavail, "avail": avail, "cats": cats}
         return avail, cats
 
-    def _weighted_item_factors(self, model: ECommModel):
-        """Device-resident ``V * weights`` — weights are static per
-        deployment (params), so the [I, D] multiply runs once, not per
-        query. Keyed by the weight CONTENT: two algorithms with
-        different weight groups may serve the same model object, and an
-        instance-identity key would both defeat that sharing and go
-        stale when ids are recycled."""
-        import json as json_mod
+    def _item_weights(self, model: ECommModel) -> np.ndarray | None:
+        """[I] f32 row weights of the ``weights`` groups (1.0 outside
+        every group), or None where the deployment has none."""
+        if not self.params.weights:
+            return None
+        weights = np.ones(len(model.item_index), dtype=np.float32)
+        for group in self.params.weights:
+            w = float(group.get("weight", 1.0))
+            weights[held_rows(model.item_index, group.get("items", []))] = w
+        return weights
 
-        key = json_mod.dumps(self.params.weights, sort_keys=True)
-        # lock-free hit path: predicts must not stall behind the lock
-        # while another thread holds it across a full-store seen scan
-        cache = getattr(model, "_weighted_V", None)
+    def _by_weights(self, model: ECommModel, attr: str, build):
+        """``build()``'s result cached on ``model`` under ``attr``,
+        keyed by the weight CONTENT: two algorithms with different
+        weight groups may serve the same model object, and an
+        instance-identity key would both defeat that sharing and go
+        stale when ids are recycled. Lock-free on a hit: predicts must
+        not stall behind the lock while another thread holds it across
+        a full-store seen scan."""
+        key = json.dumps(self.params.weights, sort_keys=True)
+        cache = getattr(model, attr, None)
         if cache is not None and key in cache:
             return cache[key]
         with self._serve_lock:
-            cache = getattr(model, "_weighted_V", None)  # double-check
+            cache = getattr(model, attr, None)  # double-check
             if cache is None:
                 cache = {}
-                model._weighted_V = cache
-            if key in cache:
-                return cache[key]
+                setattr(model, attr, cache)
+            if key not in cache:
+                cache[key] = build()
+            return cache[key]
+
+    def _sharded_catalog(self, model: ECommModel):
+        """The WEIGHTED item rows staged over the serving mesh
+        (``sharded_serving``): exact rows, coarse copy and ids a shard,
+        the weights applied to a shard's block before it goes up."""
+        return self._by_weights(
+            model, "_sharded_V", lambda: sharded_catalog(
+                model.item_table(), self._item_weights(model)
+            ),
+        )
+
+    def _weighted_item_factors(self, model: ECommModel):
+        """Device-resident ``V * weights`` — weights are static per
+        deployment (params), so the [I, D] multiply runs once, not per
+        query (``_by_weights``)."""
+
+        def build():
             import jax.numpy as jnp
 
             _, V = model.device_factors()
-            if self.params.weights:
-                n = len(model.item_index)
-                weights = np.ones(n, dtype=np.float32)
-                for group in self.params.weights:
-                    w = float(group.get("weight", 1.0))
-                    weights[held_rows(model.item_index, group.get("items", []))] = w
-                if isinstance(V, tuple):
-                    # per-row weight folds into the per-row scale: the
-                    # weighted catalog stays int8
-                    weighted = (V[0], V[1] * jnp.asarray(weights))
-                else:
-                    weighted = V * jnp.asarray(weights)[:, None]
-            else:
-                weighted = V
-            cache[key] = weighted
-            return weighted
+            weights = self._item_weights(model)
+            if weights is None:
+                return V
+            if isinstance(V, tuple):
+                # per-row weight folds into the per-row scale: the
+                # weighted catalog stays int8
+                return (V[0], V[1] * jnp.asarray(weights))
+            return V * jnp.asarray(weights)[:, None]
+
+        return self._by_weights(model, "_weighted_V", build)
 
     def _coarse_catalog(self, model: ECommModel):
         """Tiled coarse copy of the WEIGHTED item table for the
         two-stage shortlist pass (ops/retrieval.py) — the business-rule
         weights bake into the coarse scores exactly like the exact
         path's, so the shortlist ranks what serving ranks. Cached by
-        weight content, like ``_weighted_item_factors``."""
-        import json as json_mod
-
+        weight content, like ``_weighted_item_factors`` (which a
+        dispatch asks for first: the lock is not re-entrant)."""
         from predictionio_tpu.ops.retrieval import CoarseCatalog
 
-        key = json_mod.dumps(self.params.weights, sort_keys=True)
-        cache = getattr(model, "_coarse_V", None)
-        if cache is not None and key in cache:
-            return cache[key]
-        with self._serve_lock:
-            cache = getattr(model, "_coarse_V", None)  # double-check
-            if cache is None:
-                cache = {}
-                model._coarse_V = cache
-            if key not in cache:
-                cache[key] = CoarseCatalog(self._weighted_item_factors(model))
-            return cache[key]
+        return self._by_weights(
+            model, "_coarse_V",
+            lambda: CoarseCatalog(self._weighted_item_factors(model)),
+        )
 
     def cacheable_query(self, query: Query) -> bool:
         """Never cacheable: predictions depend on LIVE event-store state
@@ -632,22 +684,31 @@ class ECommAlgorithm(Algorithm):
         scan and the exact rescore at retrieval scale, the masked exact
         top-k below it). k is pow2(num) — exclusions are applied before
         each top-k and need no headroom. ``whiteList`` queries score
-        their own candidate lists through the same rescore program."""
+        their own candidate lists through the same rescore program.
+        ``sharded_serving`` hands ``top_k`` the catalog split over the
+        mesh in the table's place, and the rules' vectors split like it:
+        the same decision and the same rules, on every shard."""
         from predictionio_tpu.ops import retrieval
         from predictionio_tpu.ops.topk import Rules
 
         results: list[PredictedResult | None] = [None] * len(queries)
         n_items = len(model.item_index)
-        V = self._weighted_item_factors(model)
+        sharded = self.params.sharded_serving
+        V = (self._sharded_catalog(model) if sharded
+             else self._weighted_item_factors(model))
         k = _pow2(max(int(q.num) for _, q in queries)) if queries else 1
-        two_stage = retrieval.two_stage_k(k, n_items)
+        two_stage = not sharded and retrieval.two_stage_k(k, n_items)
         with obs_trace.region("rules.build", hist=_m_rules):
             cache, _ = self._filter_cache()  # one token read per dispatch
             # the rules are vectors over the rows the scan will slice:
-            # the coarse catalog's (padding included) where one is used
+            # the coarse catalog's (padding included) where one is used,
+            # every shard's stored rows on a sharded catalog (which is
+            # its own coarse copy)
             coarse = self._coarse_catalog(model) if two_stage else None
             avail, cats = self._catalog_rules(
-                model, coarse.stored_rows if two_stage else n_items, cache
+                model,
+                V if sharded else coarse.stored_rows if two_stage else n_items,
+                cache,
             )
             scored: list[int] = []
             vecs, excluded, qcats, whites = [], [], [], []
@@ -718,9 +779,11 @@ class ECommAlgorithm(Algorithm):
             cand = candidate_lists(
                 [whites[r] for r in listed], len(listed_rules.ex), k
             )
+            # (the shards take the host's rules, packed with the vectors)
             scores, ids = retrieval.rescore_top_k_batch(
                 batch_for(listed), V, cand, k=k,
-                rules=retrieval.device_rules(listed_rules),
+                rules=listed_rules if sharded
+                else retrieval.device_rules(listed_rules),
             )
             publish(listed, scores, ids)
         return [(ix, r) for (ix, _), r in zip(queries, results)]

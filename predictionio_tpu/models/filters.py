@@ -113,6 +113,54 @@ class ItemCategories:
         return [c for c in map(self.category_index.get, names) if c is not None]
 
 
+def sharded_catalog(item_table, row_weights=None):
+    """The item rows of a model served with ``sharded_serving`` — an
+    algorithm parameter of the templates that take it (Recommendation,
+    E-Commerce Recommendation): serve with the item rows split over the
+    device mesh (``pio deploy --mesh data=N``), each device scanning and
+    rescoring the rows it holds and one small all-gather merging the
+    answers (parallel/shard_topk.py), for a catalog one chip cannot
+    hold. Shard ``i`` is read from ``item_table`` (an ndarray, a model
+    file's ``SpannedArray``, the int8 pair) onto device ``i``, times its
+    rows' ``row_weights`` where given: the table is never one host array
+    nor one device array, and the user table stays on the host (a query
+    reads one row of it). The caller caches what this returns."""
+    from predictionio_tpu.parallel.mesh import serving_mesh
+    from predictionio_tpu.parallel.shard_topk import ShardedCatalog
+
+    with obs_trace.region("model.load_segments"):
+        return ShardedCatalog(item_table, serving_mesh(), row_weights=row_weights)
+
+
+def sharded_category_vectors(catalog, item_categories) -> tuple:
+    """``category_vectors`` over a ``ShardedCatalog``'s stored rows: each
+    vector sharded like the rows it describes and put up a shard at a
+    time (``ShardedCatalog.row_vector``; -1 past a shard's rows)."""
+    if item_categories is None:
+        return ()
+    return tuple(
+        catalog.row_vector(
+            lambda lo, hi, w=w: item_categories[lo:hi, w], np.int32, -1
+        )
+        for w in range(item_categories.shape[1])
+    )
+
+
+def sharded_availability_vector(catalog, unavailable, each):
+    """``availability_vector`` over a ``ShardedCatalog``'s stored rows,
+    built and put up a shard at a time beside the rows it guards
+    (padding rows unavailable). ``unavailable``: sorted distinct catalog
+    rows; ``each()`` wraps a shard's rebuild (the caller's span)."""
+
+    def flags(lo: int, hi: int):
+        a, b = np.searchsorted(unavailable, (lo, hi))
+        out = np.ones(hi - lo, np.uint8)
+        out[unavailable[a:b] - lo] = 0
+        return out
+
+    return catalog.row_vector(flags, np.uint8, 0, each)
+
+
 # -- ``ops.topk.Rules`` from host lists ---------------------------------------
 #
 # The catalog-wide rules are resident device vectors over the ``rows``

@@ -229,10 +229,9 @@ class ALSAlgorithmParams(Params):
     # parity RMSE, "int8" quarters it (values + per-row f32 scale,
     # dequantized at gather; solves still accumulate float32; ops/als.py)
     storage_dtype: str = "float32"
-    # serve with the item rows split over the device mesh, each device
-    # scanning and rescoring the rows it holds and one small all-gather
-    # merging the answers (parallel/shard_topk.py) — the TPU answer to
-    # the reference's PAlgorithm "model bigger than one host" case, which
+    # serve with the item rows split over the device mesh
+    # (models/filters.py sharded_catalog) — the TPU answer to the
+    # reference's PAlgorithm "model bigger than one host" case, which
     # issues a Spark job per query instead
     # (examples/.../ALSAlgorithm.scala:88)
     sharded_serving: bool = False
@@ -409,12 +408,9 @@ class ALSModel:
         chip: exact rows, coarse copy and ids per device, the user table
         left on the host (a query reads one row of it)."""
         if self._sharded is None:
-            from predictionio_tpu.obs import trace as obs_trace
-            from predictionio_tpu.parallel.mesh import serving_mesh
-            from predictionio_tpu.parallel.shard_topk import ShardedCatalog
+            from predictionio_tpu.models.filters import sharded_catalog
 
-            with obs_trace.region("model.load_segments"):
-                self._sharded = ShardedCatalog(self.item_table(), serving_mesh())
+            self._sharded = sharded_catalog(self.item_table())
         return self._sharded
 
     def coarse_catalog(self):

@@ -71,7 +71,10 @@ alone decides exact or two-stage, runs shortlist -> rescore, and every
 Nth two-stage dispatch re-scores row 0 exactly (the live recall probe).
 Where the table is a ``parallel.shard_topk.ShardedCatalog`` — item rows
 split over a mesh, for a catalog one chip cannot hold — the same
-decision runs both stages and a merge as one program on the shards.
+decision runs both stages and a merge as one program on the shards,
+``Vectors`` under rules included (the rules' vectors sharded like the
+rows, a query's own list resolved on every shard; a sum of rows under
+rules is refused there by name).
 Engagement is catalog-size gated: a catalog under
 ``PIO_RETRIEVAL_THRESHOLD`` rows (default 100_000) — every small test
 fixture — is served by the form's exact op, bit for bit what it was
@@ -243,6 +246,12 @@ _m_exact = obs_metrics.counter(
 _m_sharded = obs_metrics.counter(
     "pio_retrieval_queries_total", _QUERIES_HELP, path="sharded",
 )
+_m_sharded_masked = obs_metrics.counter(
+    "pio_retrieval_sharded_masked_total",
+    "queries served by the masked sharded programs: business rules applied "
+    "on every shard, in its scan and its rescore (a whiteList: in the "
+    "rescore of the listed rows it holds)",
+)
 _m_gather_bytes = obs_metrics.counter(
     "pio_retrieval_shard_gather_bytes_total",
     "bytes the sharded chain's all-gather moved: shards x B x k x 8 a "
@@ -332,10 +341,12 @@ _m_coarse_mode = {
 
 # what a served factor model keeps on the device, by part: the exact
 # table's values (and the f32 scales of an int8 pair), the coarse tiles
-# with their scales and row ids, the user table. Set where each is put
-# up (``CoarseCatalog``; a template's ``device_factors``).
+# with their scales and row ids, the user table, the business rules'
+# catalog-wide vectors. Set where each is put up (``CoarseCatalog``; a
+# template's ``device_factors``; the E-Commerce template's rules).
 RESIDENT_PARTS = (
     "table", "table_scales", "coarse", "coarse_scales", "coarse_ids", "users",
+    "rules",
 )
 _m_resident = {
     part: obs_metrics.gauge(
@@ -343,7 +354,9 @@ _m_resident = {
         "device bytes the served model keeps resident, by part: table / "
         "table_scales = the exact item table (int8 values and their f32 "
         "scales, or the dense rows and 0); coarse / coarse_scales / "
-        "coarse_ids = the tiled coarse catalog; users = the user table",
+        "coarse_ids = the tiled coarse catalog; users = the user table; "
+        "rules = the catalog-wide business rules' availability and category "
+        "vectors (on a sharded catalog: what ONE shard holds of them)",
         part=part,
     )
     for part in RESIDENT_PARTS
@@ -401,6 +414,7 @@ def stats_block() -> dict:
         "two_stage_queries": _m_two_stage.value(),
         "exact_queries": _m_exact.value(),
         "sharded_queries": _m_sharded.value(),
+        "sharded_masked_queries": _m_sharded_masked.value(),
         "shards": int(_m_shards.value()),
         "shard_gather_bytes": _m_gather_bytes.value(),
         "load_seconds": {st: m.summary() for st, m in _m_load.items()},
@@ -1369,7 +1383,11 @@ def rescore_top_k_batch(user_vectors, item_factors, cand_ids, k: int,
                         rules: Rules | None = None):
     """Shortlist-gather variant of ``top_k_items_batch``: [B, D] query
     vectors against a [B, S] candidate-id matrix, under ``rules`` where
-    given (``device_rules``, their per-query rows for these B queries)."""
+    given (``device_rules``, their per-query rows for these B queries).
+    Over a ``ShardedCatalog`` the ``rules`` are required and are the
+    host's (``query_rules``, its ``row_vector`` s): they go up packed."""
+    if getattr(item_factors, "shards", 0):
+        return _listed_sharded(user_vectors, item_factors, cand_ids, k, rules)
     return _read_rescore(_launch_vectors(
         user_vectors, item_factors, cand_ids, k, rules
     ), len(cand_ids))
@@ -1554,24 +1572,60 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
     the conversion, the upload and that launch, ``dispatch.fetch`` the
     one read; there is no second enqueue to call ``dispatch.rescore``.
     The decision is ``top_k``'s (kp = 0: the sharded exact program), the
-    probe re-scores the first query with that program."""
-    if query.rules is not None:
-        raise ValueError("a sharded catalog serves no query under rules yet")
+    probe re-scores the first query with that program. ``Vectors`` under
+    rules go up packed (``pack``: the vectors and the queries' own rules
+    in one replicated buffer, lists in global row ids) and the masked
+    programs run, the rules' vectors sharded like the rows
+    (``ShardedCatalog.row_vector``); a sum of rows would have to gather
+    its rows across the shards first, and is refused."""
+    rules, layout = query.rules, None
+    if isinstance(query, SumRows):
+        raise ValueError(
+            "a sharded catalog serves no SumRows query under rules yet: the "
+            "rows a query sums lie on other shards than the rows it scores"
+        )
     n = len(query[0])
     with _shortlist_stage():
-        q = catalog.put_queries(query.coarse_vectors())
-        out = catalog.launch(q, kp, k) if kp else catalog.launch_exact(q, k)
+        if rules is None:
+            q = catalog.put_queries(query.coarse_vectors())
+        else:
+            packed, layout = pack(query.coarse_vectors(), rules)
+            q, rules = catalog.put_replicated(packed, np.int32), _resident(rules)
+        out = (catalog.launch(q, kp, k, rules, layout) if kp
+               else catalog.launch_exact(q, k, rules, layout))
     s, ids = _fetch(out, n)
     _m_gather_bytes.inc(catalog.gather_bytes(len(q), min(k, kp) if kp else k))
+    if rules is not None:
+        _m_sharded_masked.inc(n)
     if not kp:
         if engaged(catalog.num_rows):
             _m_exact.inc(n)
         return s, ids
     _m_sharded.inc(n)
     _count_scan(len(q), min(kp, catalog.tile), catalog.dim, catalog.mode,
-                catalog.tiles_per_shard * catalog.tile)
+                catalog.stored_rows)
     probe(
         ids[0, :probe_n],
-        lambda: _fetch(catalog.launch_exact(q[:1], k), 1)[1][0, :probe_n],
+        lambda: _fetch(
+            catalog.launch_exact(q[:1], k, rules, layout), 1
+        )[1][0, :probe_n],
     )
     return s, ids
+
+
+def _listed_sharded(vectors, catalog, cand_ids, k: int, rules: Rules):
+    """``rescore_top_k_batch`` over a ``ShardedCatalog``: the masked
+    sharded program with the [B, S] candidate lists (global row ids) in
+    the scan's place — every shard scores the listed rows it holds under
+    the host ``rules``, which go up packed with the vectors."""
+    n = len(cand_ids)
+    with _rescore_stage():
+        packed, layout = pack(vectors, rules)
+        q = catalog.put_replicated(packed, np.int32)
+        out = catalog.launch(
+            q, 1, k, _resident(rules), layout,
+            catalog.put_replicated(cand_ids, np.int32, len(q)),
+        )
+    _m_sharded.inc(n)
+    _m_sharded_masked.inc(n)
+    return _fetch(out, n)

@@ -27,6 +27,19 @@ the item rows stand still and the query travels:
 - the host reads the answer once (``retrieval.top_k`` owns the chain and
   its one ``device_get``).
 
+Under business rules (``ops.topk.Rules``: the E-Commerce template's
+seen, unavailable, blackList and category filters) the catalog-wide
+vectors are sharded like the rows they guard — device ``i`` holds the
+availability byte and the category ids of its own stored rows, padding
+unavailable — and a query's own list travels replicated, in GLOBAL row
+ids, inside the dispatch's one packed upload (``retrieval.pack``). Each
+shard turns the list into its own rows (``retrieval.shard.rules``: id -
+first, a row of another shard a pad) and runs the scan and the rescore
+the one-chip storefront runs, under those rules; a shard with no
+allowed row answers -1s, which the merge drops. A ``whiteList`` is the
+same program with the list in the scan's place: a shard scores the
+listed rows it holds.
+
 The union of the local shortlists holds the one-chip shortlist (a row
 among the catalog's k' best is among its shard's k' best), so recall is
 no lower than the one-chip chain's, and every served score is the f32
@@ -40,6 +53,7 @@ shortlist, so 4.6 GB of catalog stays put and a few KB move.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -52,7 +66,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.obs import device as obs_device
 from predictionio_tpu.ops import retrieval
-from predictionio_tpu.ops.topk import NEG_INF, _f32_scores
+from predictionio_tpu.ops.topk import NEG_INF, Rules, _f32_scores, _top_k_allowed
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -131,6 +145,91 @@ def _sharded_exact(q, rows, ids, k: int, mesh: Mesh, axis: str):
     )(q, rows, ids)
 
 
+def _own_rows(gids, first, r: int):
+    """Global row ids -> positions among the ``r`` rows of the shard that
+    starts at ``first``; -1 for a row of another shard, and for a pad."""
+    return jnp.where((gids >= first) & (gids < first + r), gids - first, -1)
+
+
+def _shard_rules(packed, layout, avail, cats, first, r: int):
+    """A packed dispatch on one shard: (the f32 queries, their ``Rules``
+    over THIS shard's stored rows). The query's own list arrives in
+    global row ids; a row this shard holds becomes its position here
+    (``id - first``), any other a pad — as is a list's own padding."""
+    with jax.named_scope("retrieval.shard.rules"):
+        q, rules, _, _ = retrieval._unpack(
+            packed, layout, Rules(avail, cats, None, None, None)
+        )
+        return q, rules._replace(ex=_own_rows(rules.ex, first, r))
+
+
+@obs_device.track_jit("retrieval.sharded_topk_masked")
+@functools.partial(
+    jax.jit, static_argnames=("r", "kp", "k", "mode", "mesh", "axis", "layout")
+)
+def _sharded_topk_masked(packed, cand, rows, tiles, ids, avail, cats, r: int,
+                         kp: int, k: int, mode: str, mesh: Mesh, axis: str,
+                         layout):
+    """``_sharded_topk`` under business rules: ``packed`` is
+    ``retrieval.pack``'s replicated buffer (the queries and their own
+    rules, lists in global ids), ``avail`` / ``cats`` the catalog-wide
+    rules sharded like the rows. Each shard runs the one-chip masked
+    scan and masked rescore over the rows it holds. ``cand`` None: the
+    scan shortlists; else [B, S] replicated global candidate ids (a
+    ``whiteList``) stand in the scan's place, each shard scoring those
+    it holds. A program of its own: the unmasked one stays what it is."""
+
+    def local(packed, cand, rows, tiles, ids, avail, cats):
+        first = jax.lax.axis_index(axis) * r
+        q, rules = _shard_rules(packed, layout, avail, cats, first, r)
+        if cand is None:
+            with jax.named_scope("retrieval.shard.scan"):
+                _, cand = retrieval._coarse_scan(
+                    q, tiles, None, ids, kp, mode, rules
+                )
+        with jax.named_scope("retrieval.shard.rescore"):
+            s, lix = retrieval._score_candidates(
+                q, rows, _own_rows(cand, first, r), k, rules,
+                precision=_HIGHEST,
+            )
+            gid = jnp.where(lix >= 0, lix + first, -1)
+        return _gather_merge(s, gid, k, axis)
+
+    sharded = P(axis)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(), None if cand is None else P(), sharded, sharded,
+                  sharded, sharded, tuple(sharded for _ in cats)),
+        out_specs=(P(), P()), check_vma=False,
+    )(packed, cand, rows, tiles, ids, avail, cats)
+
+
+@obs_device.track_jit("retrieval.sharded_exact_masked")
+@functools.partial(jax.jit, static_argnames=("r", "k", "mesh", "axis", "layout"))
+def _sharded_exact_masked(packed, rows, ids, avail, cats, r: int, k: int,
+                          mesh: Mesh, axis: str, layout):
+    """``_sharded_exact`` under business rules: every row a shard holds
+    scored in f32, the rules applied before its top-k
+    (``ops.topk._top_k_allowed``, the one-chip masked exact program's)."""
+
+    def local(packed, rows, ids, avail, cats):
+        first = jax.lax.axis_index(axis) * r
+        q, rules = _shard_rules(packed, layout, avail, cats, first, r)
+        with jax.named_scope("retrieval.shard.exact"):
+            gids = ids.reshape(-1)
+            sc = jnp.where(gids[None, :] >= 0, _f32_scores(q, rows), NEG_INF)
+            s, ix = _top_k_allowed(sc, rules, k)
+            gid = jnp.where(ix >= 0, gids[jnp.maximum(ix, 0)], -1)
+        return _gather_merge(s, gid, min(k, mesh.shape[axis] * s.shape[1]), axis)
+
+    sharded = P(axis)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(), sharded, sharded, sharded, tuple(sharded for _ in cats)),
+        out_specs=(P(), P()), check_vma=False,
+    )(packed, rows, ids, avail, cats)
+
+
 @functools.partial(jax.jit, static_argnames=("nt", "t"))
 def _coarse_copy(rows, nt: int, t: int):
     return rows.astype(jnp.bfloat16).reshape(nt, t, rows.shape[1])
@@ -173,9 +272,12 @@ class ShardedCatalog:
     ceil(I / shards), into ONE host block of that shard's stored size
     and put on device ``i``, the shards side by side (a thread each): the
     whole table is never one host array nor one device array.
+    ``row_weights`` ([I] f32, the E-Commerce template's weighted items)
+    multiply a shard's block before it goes up.
     """
 
-    def __init__(self, item_table, mesh: Mesh, axis: str = "data"):
+    def __init__(self, item_table, mesh: Mesh, axis: str = "data",
+                 row_weights=None):
         if mesh.axis_names != (axis,):
             raise ValueError(
                 f"a sharded catalog takes a 1-D mesh over {axis!r}, "
@@ -200,6 +302,10 @@ class ShardedCatalog:
             t0 = time.perf_counter()
             block = np.zeros((stored, self.dim), np.float32)
             _host_rows(item_table, lo, hi, block)
+            if row_weights is not None:
+                block[: hi - lo] *= np.asarray(
+                    row_weights[lo:hi], np.float32
+                )[:, None]
             ids = np.full(stored, -1, np.int32)
             ids[: hi - lo] = np.arange(lo, hi, dtype=np.int32)
             # one upload at a time: four 3 GB uploads side by side took 14-21 s
@@ -234,6 +340,31 @@ class ShardedCatalog:
         self._replicated = NamedSharding(mesh, P())
         retrieval._m_shards.set(float(n))
 
+    @property
+    def stored_rows(self) -> int:
+        """Rows ONE shard's tiles hold, padding included: a shard's
+        share of a ``row_vector``."""
+        return self.tiles_per_shard * self.tile
+
+    def row_vector(self, fill, dtype, pad, each=contextlib.nullcontext):
+        """A per-row vector sharded like the rows it describes (a
+        ``Rules.avail`` / ``Rules.cats`` entry): ``fill(lo, hi)`` gives
+        the values of catalog rows [lo, hi), asked a shard at a time;
+        shard ``i``'s ``stored_rows`` entries — ``pad`` past the rows it
+        holds — go to device ``i``, as its ids did. ``each()`` wraps a
+        shard's build and upload (a caller's span)."""
+        r, stored = self.rows_per_shard, self.stored_rows
+        parts = []
+        for i, device in enumerate(self.mesh.devices.flat):
+            lo, hi = min(i * r, self.num_rows), min((i + 1) * r, self.num_rows)
+            with each():
+                block = np.full(stored, pad, dtype)
+                block[: hi - lo] = fill(lo, hi)
+                parts.append(jax.device_put(block, device))
+        return jax.make_array_from_single_device_arrays(
+            (self.shards * stored,), NamedSharding(self.mesh, P(self.axis)), parts
+        )
+
     def gather_bytes(self, b: int, k: int) -> int:
         """What one dispatch's all-gather moves: every shard's [b, k] f32
         scores and int32 ids."""
@@ -242,27 +373,48 @@ class ShardedCatalog:
     def put_queries(self, vectors):
         """[B, D] host vectors -> the replicated device batch, padded to
         the power of two at or above B (copies of row 0, discarded)."""
-        return retrieval._up(
-            vectors, np.float32, retrieval._pow2(len(vectors)), self._replicated
+        return self.put_replicated(
+            vectors, np.float32, retrieval._pow2(len(vectors))
         )
 
-    def launch(self, q, kp: int, k: int):
+    def put_replicated(self, a, dtype, rows: int = 0):
+        """A host array on every shard (``retrieval._up``): the queries,
+        a packed dispatch, a ``whiteList`` batch's candidate ids."""
+        return retrieval._up(a, dtype, rows, self._replicated)
+
+    def launch(self, q, kp: int, k: int, rules=None, layout=None, cand=None):
         """The two-stage program enqueued on ``put_queries``' batch,
         nothing read: replicated device ([bp, k] scores, [bp, k] ids).
         k' clamps to what a shard can shortlist (its tile width), k to
-        k'."""
+        k'. Under ``rules`` (their ``row_vector`` s alone) ``q`` is
+        ``retrieval.pack``'s buffer of ``layout``, replicated, and the
+        masked program runs; ``cand`` ([bp, S] replicated global ids)
+        then stands in the scan's place."""
         kp = max(1, min(int(kp), self.tile))
-        return _sharded_topk(
-            q, self._rows, self._tiles, self._ids, r=self.rows_per_shard,
-            kp=kp, k=min(int(k), kp), mode=self.mode, mesh=self.mesh,
-            axis=self.axis,
+        k = min(int(k), kp if cand is None else cand.shape[1])
+        if rules is None:
+            return _sharded_topk(
+                q, self._rows, self._tiles, self._ids, r=self.rows_per_shard,
+                kp=kp, k=k, mode=self.mode, mesh=self.mesh, axis=self.axis,
+            )
+        return _sharded_topk_masked(
+            q, cand, self._rows, self._tiles, self._ids, rules.avail,
+            rules.cats, r=self.rows_per_shard, kp=kp, k=k, mode=self.mode,
+            mesh=self.mesh, axis=self.axis, layout=layout,
         )
 
-    def launch_exact(self, q, k: int):
-        """The exact program enqueued, nothing read."""
-        return _sharded_exact(
-            q, self._rows, self._ids, k=max(1, min(int(k), self.num_rows)),
-            mesh=self.mesh, axis=self.axis,
+    def launch_exact(self, q, k: int, rules=None, layout=None):
+        """The exact program enqueued, nothing read (``rules`` and
+        ``layout`` as ``launch``'s)."""
+        k = max(1, min(int(k), self.num_rows))
+        if rules is None:
+            return _sharded_exact(
+                q, self._rows, self._ids, k=k, mesh=self.mesh, axis=self.axis,
+            )
+        return _sharded_exact_masked(
+            q, self._rows, self._ids, rules.avail, rules.cats,
+            r=self.rows_per_shard, k=min(k, self.stored_rows), mesh=self.mesh,
+            axis=self.axis, layout=layout,
         )
 
     def exact_top_k(self, vectors, k: int):

@@ -41,6 +41,8 @@ FETCH = {  # metric -> (the end-to-end metric it moves, the cells that list it)
     "fetch_ms.storefront": ("query_p50_ms", ["ecommerce-taobao.serve-storefront"]),
     "fetch_ms.itempage": ("query_p50_ms", ["similarproduct-taobao.serve-itempage"]),  # PR 30
     "fetch_ms.sharded": ("query_p50_ms", ["recommendation-amazon23.serve-sharded-steady"]),  # PR 32
+    "fetch_ms.shardstore": ("query_p50_ms",
+                            ["ecommerce-amazon23.serve-storefront-sharded"]),  # PR 48
 }
 LISTED = [n for n in FETCH if n != "fetch_ms.storefront"]  # see the docstring
 
@@ -97,7 +99,8 @@ def test_traced_rehearsal_prints_the_three_stages(cell, tmp_path):
     would = json.loads(next(ln for ln in lines if ln.startswith("would print: "))[13:])
     m = would["metrics"]
     sharded = "sharded" in cell  # PR 32: one program a dispatch, no second enqueue
-    sfx = ".sharded" if sharded else \
+    sfx = ".shardstore" if cell.endswith("storefront-sharded") else \
+        ".sharded" if sharded else \
         "." + cell.rsplit("-", 1)[1] if not cell.endswith("steady") else ""
     # the score layer's three stages lie inside a dispatch, one after the
     # other, and the wait for the device is in the last of them

@@ -305,14 +305,17 @@ class TestShardedChain:
         np.testing.assert_array_equal(ids, ri)
         np.testing.assert_allclose(s, rs, rtol=0, atol=2e-6)
 
-    def test_rules_are_refused_by_name(self, mesh4, two_stage):
+    def test_a_sum_of_rows_under_rules_is_refused_by_name(self, mesh4, two_stage):
+        """(``Vectors`` under rules are served: tests/test_shard_rules.py.)"""
         from predictionio_tpu.ops.topk import Rules
 
         U, V = _tables(2000, seed=11)
         rules = Rules(avail=None, cats=(), qcat=None, has_cat=None, ex=None)
-        with pytest.raises(ValueError, match="under rules"):
-            retrieval.top_k(retrieval.Vectors(U[:1], rules),
-                            ShardedCatalog(V, mesh4), len(V), None, 8)
+        query = retrieval.SumRows(
+            np.zeros((1, 8), np.int32), np.ones((1, 8), np.float32),
+            lambda ixs, weights: V[ixs[:, 0]], rules)
+        with pytest.raises(ValueError, match="SumRows query under rules"):
+            retrieval.top_k(query, ShardedCatalog(V, mesh4), len(V), None, 8)
 
     def test_a_mesh_of_two_axes_is_refused(self):
         with pytest.raises(ValueError, match="1-D mesh"):

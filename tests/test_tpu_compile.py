@@ -375,6 +375,41 @@ def test_the_four_chip_chain_runs_the_same_step(one_chip):
         == {"all-gather"}
 
 
+@pytest.mark.parametrize("b", [1, 16])
+def test_the_masked_four_chip_chain_fits_a_chip_beside_its_catalog(one_chip, b):
+    """``_sharded_topk_masked`` for the sharded storefront's four chips (46
+    tiles a chip; the rules' vectors sharded like the rows, the dispatch
+    one packed replicated buffer): it compiles, its one collective is the
+    all-gather, every rule vector is read where it lies (no copy of a
+    [stored] vector), and its temporaries — the stored scores and the
+    [46, B, 2^18] mask — leave a 16 GB chip's 4.8 GB of catalog room."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from predictionio_tpu.parallel import shard_topk
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    n, nt, d = 4, 46, 64
+    whole, split = _on(NamedSharding(mesh, P())), _on(NamedSharding(mesh, P("data")))
+    layout = retrieval.Layout(d, 1, 128)
+    compiled = shard_topk._sharded_topk_masked.lower(
+        whole((b, sum(layout[:3]) + 1), jnp.int32), None,
+        split((n * nt * TILE, d), jnp.float32),
+        split((n * nt, TILE, d), jnp.bfloat16),
+        split((n * nt, *retrieval.side_shape(nt, TILE)[1:]), jnp.int32),
+        split((n * nt * TILE,), jnp.uint8), (split((n * nt * TILE,), jnp.int32),),
+        r=12_047_500, kp=KP, k=16, mode="bf16", mesh=mesh, axis="data",
+        layout=layout,
+    ).compile()
+    text = compiled.as_text()
+    assert set(re.findall(r"(all-gather|all-reduce|all-to-all|collective-permute)", text)) \
+        == {"all-gather"}
+    resident = nt * TILE * (d * 6 + 4 + 4 + 1)
+    assert compiled.memory_analysis().temp_size_in_bytes + resident < 12e9
+
+
 @pytest.mark.parametrize("b,k", [(8, 8), (16, 32)])
 def test_the_folds_solve_gathers_from_the_resident_table_in_place(one_chip, b, k):
     """PR 45: the fold-in's solve program over the int8 catalog's resident
