@@ -37,8 +37,8 @@ With ``sharded_serving`` (``pio deploy --mesh data=N``: a catalog one
 chip cannot hold) the item rows stand split over the mesh and the same
 rules are applied on every shard: the catalog-wide vectors sharded like
 the rows they guard, built a shard at a time; the per-query lists
-replicated, in global rows, each shard resolving the ones it holds
-(parallel/shard_topk.py). The item table is then never one host array
+handed to every shard, in global rows, each shard resolving the ones it
+holds (parallel/shard_topk.py). The item table is then never one host array
 nor one device array, and the user table stays on the host.
 """
 

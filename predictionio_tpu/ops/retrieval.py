@@ -20,7 +20,7 @@ ads serving stack runs at scale (PAPERS.md, arxiv 2501.10546):
    faster for a batch and 2.5x slower for one query: PERF.md section 6,
    PR 41); dense catalogs carry a bf16 coarse copy. On a mesh each
    device runs this same scan over the rows it holds
-   (parallel/shard_topk.py: stationary shards, the query replicated).
+   (parallel/shard_topk.py: stationary shards, the query handed round).
    A step selects nothing: it scores the tile, scales, guards, masks
    and writes the [B, T] scores and the maximum of each group of G of
    them to the scan's stacked outputs, and the k' best come once, after
@@ -257,6 +257,14 @@ _m_gather_bytes = obs_metrics.counter(
     "bytes the sharded chain's all-gather moved: shards x B x k x 8 a "
     "dispatch (every shard's [B, k] f32 scores and int32 ids)",
 )
+_m_shard_h2d = obs_metrics.counter(
+    "pio_retrieval_shard_h2d_copies_total",
+    "device buffers the sharded chain wrote from the host: ONE a host array "
+    "of a dispatch (the queries or the packed buffer, to the mesh's first "
+    "device: the program hands it round; a whiteList's candidate ids are a "
+    "second), and shards - 1 resident zero blocks the first time a shape "
+    "goes up",
+)
 _m_shards = obs_metrics.gauge(
     "pio_retrieval_shards",
     "devices the served catalog's rows are split over (0: one chip)",
@@ -417,6 +425,7 @@ def stats_block() -> dict:
         "sharded_masked_queries": _m_sharded_masked.value(),
         "shards": int(_m_shards.value()),
         "shard_gather_bytes": _m_gather_bytes.value(),
+        "shard_h2d_copies": _m_shard_h2d.value(),
         "load_seconds": {st: m.summary() for st, m in _m_load.items()},
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
@@ -910,17 +919,18 @@ def _pad_rows(a, rows: int):
     return a
 
 
-def _up(a, dtype, rows: int = 0, sharding=None):
+def _up(a, dtype, rows: int = 0, put=None):
     """``a`` as a device array: one that is there already as it lies
     (the chain's arrays go up once, for both stages), a host array
     converted to ``dtype``, padded to ``rows`` rows with copies of row 0
     (discarded after the read) and uploaded — to the default device, or
-    as ``sharding`` says (the sharded chain's replicated queries)."""
+    by ``put``, the way a sharded catalog's arrays reach its shards
+    (``ShardedCatalog.put_replicated``)."""
     if isinstance(a, jax.Array):
         return a
     a = _pad_rows(np.ascontiguousarray(a, dtype=dtype), rows)
     _m_uploads.inc()
-    return jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
+    return jnp.asarray(a) if put is None else put(a)
 
 
 _shortlist_stage = functools.partial(
@@ -1567,17 +1577,20 @@ def top_k(query, table, num_rows: int, coarse, k: int,
 def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
     """``top_k`` over a ``parallel.shard_topk.ShardedCatalog`` (the
     exact rows AND the coarse copy, split row-wise over a mesh): the
-    form gives its f32 vectors, which go up replicated, and ONE program
-    scans, rescores and merges on the shards — ``dispatch.shortlist`` is
-    the conversion, the upload and that launch, ``dispatch.fetch`` the
-    one read; there is no second enqueue to call ``dispatch.rescore``.
-    The decision is ``top_k``'s (kp = 0: the sharded exact program), the
-    probe re-scores the first query with that program. ``Vectors`` under
-    rules go up packed (``pack``: the vectors and the queries' own rules
-    in one replicated buffer, lists in global row ids) and the masked
-    programs run, the rules' vectors sharded like the rows
-    (``ShardedCatalog.row_vector``); a sum of rows would have to gather
-    its rows across the shards first, and is refused."""
+    form gives its f32 vectors, which go up ONCE, to the mesh's first
+    device (``ShardedCatalog.put_replicated``: the device batch is
+    [shards, bp, D], shard 0's block the upload), and ONE program hands
+    them round, scans, rescores and merges on the shards —
+    ``dispatch.shortlist`` is the conversion, the upload and that
+    launch, ``dispatch.fetch`` the one read; there is no second enqueue
+    to call ``dispatch.rescore``. The decision is ``top_k``'s (kp = 0:
+    the sharded exact program), the probe re-scores the first query with
+    that program. ``Vectors`` under rules go up packed (``pack``: the
+    vectors and the queries' own rules in one buffer, lists in global
+    row ids, by the same route) and the masked programs run, the rules'
+    vectors sharded like the rows (``ShardedCatalog.row_vector``); a sum
+    of rows would have to gather its rows across the shards first, and
+    is refused."""
     rules, layout = query.rules, None
     if isinstance(query, SumRows):
         raise ValueError(
@@ -1594,7 +1607,8 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
         out = (catalog.launch(q, kp, k, rules, layout) if kp
                else catalog.launch_exact(q, k, rules, layout))
     s, ids = _fetch(out, n)
-    _m_gather_bytes.inc(catalog.gather_bytes(len(q), min(k, kp) if kp else k))
+    bp = q.shape[1]  # the padded batch: q is [shards, bp, ...]
+    _m_gather_bytes.inc(catalog.gather_bytes(bp, min(k, kp) if kp else k))
     if rules is not None:
         _m_sharded_masked.inc(n)
     if not kp:
@@ -1602,12 +1616,12 @@ def _top_k_sharded(query, catalog, kp: int, k: int, probe_n: int | None):
             _m_exact.inc(n)
         return s, ids
     _m_sharded.inc(n)
-    _count_scan(len(q), min(kp, catalog.tile), catalog.dim, catalog.mode,
+    _count_scan(bp, min(kp, catalog.tile), catalog.dim, catalog.mode,
                 catalog.stored_rows)
     probe(
         ids[0, :probe_n],
         lambda: _fetch(
-            catalog.launch_exact(q[:1], k, rules, layout), 1
+            catalog.launch_exact(q[:, :1], k, rules, layout), 1
         )[1][0, :probe_n],
     )
     return s, ids
@@ -1624,7 +1638,7 @@ def _listed_sharded(vectors, catalog, cand_ids, k: int, rules: Rules):
         q = catalog.put_replicated(packed, np.int32)
         out = catalog.launch(
             q, 1, k, _resident(rules), layout,
-            catalog.put_replicated(cand_ids, np.int32, len(q)),
+            catalog.put_replicated(cand_ids, np.int32, q.shape[1]),
         )
     _m_sharded.inc(n)
     _m_sharded_masked.inc(n)

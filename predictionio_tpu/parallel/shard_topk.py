@@ -12,8 +12,14 @@ the item rows stand still and the query travels:
   coarse copy in tiles (what ``ops.retrieval.CoarseCatalog`` holds for
   one chip), and their GLOBAL ids (-1 marks padding: a catalog the mesh
   does not divide, a last tile not full);
-- a dispatch replicates the [B, D] query vectors (256 B a query at rank
-  64) and runs ONE program under ``shard_map``: each device scans its
+- a dispatch copies the [B, D] query vectors (256 B a query at rank
+  64) to ONE device, the mesh's first — the device batch is
+  [shards, bp, D] split over the mesh axis: shard 0's block is that
+  copy, the others' are resident zeros, stitched without a copy or a
+  launch (``ShardedCatalog.put_replicated``) — and runs ONE program
+  under ``shard_map``, whose first statement hands shard 0's block to
+  every shard over the chips' links (``retrieval.shard.broadcast``:
+  bits moved, never added); each device then scans its
   own tiles with the one-chip scan (``retrieval._coarse_scan``, not a
   copy — so a step only scores its tile and keeps the scores and their
   group maxima, each device selects its k' best once, after its own
@@ -31,8 +37,9 @@ Under business rules (``ops.topk.Rules``: the E-Commerce template's
 seen, unavailable, blackList and category filters) the catalog-wide
 vectors are sharded like the rows they guard — device ``i`` holds the
 availability byte and the category ids of its own stored rows, padding
-unavailable — and a query's own list travels replicated, in GLOBAL row
-ids, inside the dispatch's one packed upload (``retrieval.pack``). Each
+unavailable — and a query's own list travels with the queries, in
+GLOBAL row ids, inside the dispatch's one packed upload
+(``retrieval.pack``), by the same route. Each
 shard turns the list into its own rows (``retrieval.shard.rules``: id -
 first, a row of another shard a pad) and runs the scan and the rescore
 the one-chip storefront runs, under those rules; a shard with no
@@ -70,6 +77,17 @@ from predictionio_tpu.ops.topk import NEG_INF, Rules, _f32_scores, _top_k_allowe
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
+
+def _from_first(x, axis: str):
+    """A shard's [1, ...] block of ``put_replicated``'s device batch ->
+    shard 0's block, on every shard: the one copy a dispatch made,
+    handed round in one collective. Bits are moved, not added (an f32
+    sum would turn -0.0 into +0.0), so a shard holds what a copy of its
+    own would have given it."""
+    with jax.named_scope("retrieval.shard.broadcast"):
+        return jax.lax.all_gather(x[0], axis)[0]
+
+
 def _merge(all_s, all_i, k: int):
     """[n, B, k] local answers -> the global ([B, k] scores, [B, k]
     ids): shard-major candidates, so equal scores keep the lower id."""
@@ -100,11 +118,13 @@ def _gather_merge(s, gid, k: int, axis: str):
 )
 def _sharded_topk(q, rows, tiles, ids, r: int, kp: int, k: int, mode: str,
                   mesh: Mesh, axis: str):
-    """One dispatch of two-stage retrieval over stationary shards: [B, D]
-    replicated queries -> replicated ([B, k] scores, [B, k] global ids).
-    Shard ``i`` holds the catalog's rows from ``i * r`` on."""
+    """One dispatch of two-stage retrieval over stationary shards:
+    ``put_replicated``'s [shards, B, D] queries -> replicated ([B, k]
+    scores, [B, k] global ids). Shard ``i`` holds the catalog's rows
+    from ``i * r`` on."""
 
     def local(q, rows, tiles, ids):
+        q = _from_first(q, axis)
         first = jax.lax.axis_index(axis) * r  # the global id of local row 0
         with jax.named_scope("retrieval.shard.scan"):
             _, cand = retrieval._coarse_scan(q, tiles, None, ids, kp, mode)
@@ -118,7 +138,7 @@ def _sharded_topk(q, rows, tiles, ids, r: int, kp: int, k: int, mode: str,
         return _gather_merge(s, gid, k, axis)
 
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(), P(axis), P(axis), P(axis)),
+        local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(), P()), check_vma=False,
     )(q, rows, tiles, ids)
 
@@ -132,6 +152,7 @@ def _sharded_exact(q, rows, ids, k: int, mesh: Mesh, axis: str):
     retrieval threshold."""
 
     def local(q, rows, ids):
+        q = _from_first(q, axis)
         with jax.named_scope("retrieval.shard.exact"):
             gids = ids.reshape(-1)
             sc = jnp.where(gids[None, :] >= 0, _f32_scores(q, rows), NEG_INF)
@@ -140,7 +161,7 @@ def _sharded_exact(q, rows, ids, k: int, mesh: Mesh, axis: str):
         return _gather_merge(s, gid, min(k, mesh.shape[axis] * s.shape[1]), axis)
 
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(), P(axis), P(axis)),
+        local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(), P()), check_vma=False,
     )(q, rows, ids)
 
@@ -151,11 +172,13 @@ def _own_rows(gids, first, r: int):
     return jnp.where((gids >= first) & (gids < first + r), gids - first, -1)
 
 
-def _shard_rules(packed, layout, avail, cats, first, r: int):
+def _shard_rules(packed, layout, avail, cats, first, r: int, axis: str):
     """A packed dispatch on one shard: (the f32 queries, their ``Rules``
-    over THIS shard's stored rows). The query's own list arrives in
-    global row ids; a row this shard holds becomes its position here
-    (``id - first``), any other a pad — as is a list's own padding."""
+    over THIS shard's stored rows). The buffer is handed round first
+    (``_from_first``). The query's own list arrives in global row ids;
+    a row this shard holds becomes its position here (``id - first``),
+    any other a pad — as is a list's own padding."""
+    packed = _from_first(packed, axis)
     with jax.named_scope("retrieval.shard.rules"):
         q, rules, _, _ = retrieval._unpack(
             packed, layout, Rules(avail, cats, None, None, None)
@@ -171,18 +194,21 @@ def _sharded_topk_masked(packed, cand, rows, tiles, ids, avail, cats, r: int,
                          kp: int, k: int, mode: str, mesh: Mesh, axis: str,
                          layout):
     """``_sharded_topk`` under business rules: ``packed`` is
-    ``retrieval.pack``'s replicated buffer (the queries and their own
-    rules, lists in global ids), ``avail`` / ``cats`` the catalog-wide
-    rules sharded like the rows. Each shard runs the one-chip masked
-    scan and masked rescore over the rows it holds. ``cand`` None: the
-    scan shortlists; else [B, S] replicated global candidate ids (a
-    ``whiteList``) stand in the scan's place, each shard scoring those
-    it holds. A program of its own: the unmasked one stays what it is."""
+    ``retrieval.pack``'s buffer as ``put_replicated`` left it (the
+    queries and their own rules, lists in global ids), ``avail`` /
+    ``cats`` the catalog-wide rules sharded like the rows. Each shard
+    runs the one-chip masked scan and masked rescore over the rows it
+    holds. ``cand`` None: the scan shortlists; else ``put_replicated``'s
+    [shards, B, S] global candidate ids (a ``whiteList``) stand in the
+    scan's place, each shard scoring those it holds. A program of its
+    own: the unmasked one stays what it is."""
 
     def local(packed, cand, rows, tiles, ids, avail, cats):
         first = jax.lax.axis_index(axis) * r
-        q, rules = _shard_rules(packed, layout, avail, cats, first, r)
-        if cand is None:
+        q, rules = _shard_rules(packed, layout, avail, cats, first, r, axis)
+        if cand is not None:
+            cand = _from_first(cand, axis)
+        else:
             with jax.named_scope("retrieval.shard.scan"):
                 _, cand = retrieval._coarse_scan(
                     q, tiles, None, ids, kp, mode, rules
@@ -198,7 +224,7 @@ def _sharded_topk_masked(packed, cand, rows, tiles, ids, avail, cats, r: int,
     sharded = P(axis)
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(), None if cand is None else P(), sharded, sharded,
+        in_specs=(sharded, None if cand is None else sharded, sharded, sharded,
                   sharded, sharded, tuple(sharded for _ in cats)),
         out_specs=(P(), P()), check_vma=False,
     )(packed, cand, rows, tiles, ids, avail, cats)
@@ -214,7 +240,7 @@ def _sharded_exact_masked(packed, rows, ids, avail, cats, r: int, k: int,
 
     def local(packed, rows, ids, avail, cats):
         first = jax.lax.axis_index(axis) * r
-        q, rules = _shard_rules(packed, layout, avail, cats, first, r)
+        q, rules = _shard_rules(packed, layout, avail, cats, first, r, axis)
         with jax.named_scope("retrieval.shard.exact"):
             gids = ids.reshape(-1)
             sc = jnp.where(gids[None, :] >= 0, _f32_scores(q, rows), NEG_INF)
@@ -225,7 +251,8 @@ def _sharded_exact_masked(packed, rows, ids, avail, cats, r: int, k: int,
     sharded = P(axis)
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(), sharded, sharded, sharded, tuple(sharded for _ in cats)),
+        in_specs=(sharded, sharded, sharded, sharded,
+                  tuple(sharded for _ in cats)),
         out_specs=(P(), P()), check_vma=False,
     )(packed, rows, ids, avail, cats)
 
@@ -337,7 +364,8 @@ class ShardedCatalog:
         self._rows = whole((s[0] for s in staged), (n * stored, self.dim))
         self._tiles = whole((s[1] for s in staged), (n * nt, t, self.dim))
         self._ids = whole((s[2] for s in staged), (n * nt, *sides[1:]))
-        self._replicated = NamedSharding(mesh, P())
+        self._devices, self._split = devices, sharding
+        self._zeros = {}  # (a block's shape, its dtype) -> the other shards' blocks
         retrieval._m_shards.set(float(n))
 
     @property
@@ -371,27 +399,51 @@ class ShardedCatalog:
         return self.shards * b * k * 8
 
     def put_queries(self, vectors):
-        """[B, D] host vectors -> the replicated device batch, padded to
-        the power of two at or above B (copies of row 0, discarded)."""
+        """[B, D] host vectors -> the [shards, bp, D] device batch of
+        ``put_replicated``, bp the power of two at or above B (copies of
+        row 0, discarded)."""
         return self.put_replicated(
             vectors, np.float32, retrieval._pow2(len(vectors))
         )
 
     def put_replicated(self, a, dtype, rows: int = 0):
-        """A host array on every shard (``retrieval._up``): the queries,
-        a packed dispatch, a ``whiteList`` batch's candidate ids."""
-        return retrieval._up(a, dtype, rows, self._replicated)
+        """A host array for every shard — the queries, a packed
+        dispatch, a ``whiteList`` batch's candidate ids — in ONE
+        host-to-device copy (``retrieval._up``): [bp, W] goes to the
+        mesh's first device and is stitched with the other shards'
+        resident zero blocks (made once a shape) into one [shards, bp,
+        W] array split over the mesh axis, no copy and no launch; the
+        programs' first statement hands shard 0's block round
+        (``_from_first``). A replicated ``device_put`` is a copy a
+        device, one after another, in front of the launch."""
+        return retrieval._up(a, dtype, rows, self._stitch)
+
+    def _stitch(self, a: np.ndarray):
+        block = a[None]
+        key = (block.shape, block.dtype)
+        zeros = self._zeros.get(key)
+        if zeros is None:
+            zeros = self._zeros[key] = [
+                jax.device_put(np.zeros_like(block), d) for d in self._devices[1:]
+            ]
+            retrieval._m_shard_h2d.inc(len(zeros))
+        retrieval._m_shard_h2d.inc()
+        return jax.make_array_from_single_device_arrays(
+            (self.shards, *a.shape), self._split,
+            [jax.device_put(block, self._devices[0]), *zeros],
+        )
 
     def launch(self, q, kp: int, k: int, rules=None, layout=None, cand=None):
         """The two-stage program enqueued on ``put_queries``' batch,
         nothing read: replicated device ([bp, k] scores, [bp, k] ids).
         k' clamps to what a shard can shortlist (its tile width), k to
         k'. Under ``rules`` (their ``row_vector`` s alone) ``q`` is
-        ``retrieval.pack``'s buffer of ``layout``, replicated, and the
-        masked program runs; ``cand`` ([bp, S] replicated global ids)
-        then stands in the scan's place."""
+        ``retrieval.pack``'s buffer of ``layout`` as ``put_replicated``
+        left it, and the masked program runs; ``cand``
+        (``put_replicated``'s [shards, bp, S] global ids) then stands in
+        the scan's place."""
         kp = max(1, min(int(kp), self.tile))
-        k = min(int(k), kp if cand is None else cand.shape[1])
+        k = min(int(k), kp if cand is None else cand.shape[2])
         if rules is None:
             return _sharded_topk(
                 q, self._rows, self._tiles, self._ids, r=self.rows_per_shard,

@@ -97,6 +97,58 @@ def test_sharded_exact_answers_equal_the_reference(world, kind):
 
 
 @pytest.mark.parametrize("engaged", [True, False], ids=["two_stage", "exact"])
+@pytest.mark.parametrize("batch", [1, 3, 16])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_the_masked_programs_on_meshes_of_one_two_and_four(
+        storage, monkeypatch, shards, batch, engaged):
+    """Every query kind in one batch (a ``whiteList`` among them: the
+    listed program), on a mesh of one, two and four devices, through
+    the masked two-stage program and the masked exact one: the plain
+    reference's answers, and the one-chip storefront's items in its
+    order."""
+    monkeypatch.setenv("PIO_MESH", f"data={shards}")
+    if engaged:
+        monkeypatch.setenv("PIO_RETRIEVAL_THRESHOLD", "500")
+        monkeypatch.setenv("PIO_RETRIEVAL_TILE", "256")
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "0")
+    w = ShardedWorld(storage, "float32")
+    queries = [(n, w.query(KINDS[n % len(KINDS)], n)) for n in range(batch)]
+    out = dict(w.algo.batch_predict(w.model, queries))
+    assert retrieval.stats_block()["shards"] == shards
+    one = dict(w.one_chip.batch_predict(w.model, queries))
+    for n, q in queries:
+        w.check(q, out[n], exact=(not engaged or KINDS[n % len(KINDS)] == "whiteList"))
+        assert [s.item for s in out[n].itemScores] == \
+            [s.item for s in one[n].itemScores]
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_a_dispatch_under_rules_is_one_copy_and_a_whitelist_two(
+        storage, monkeypatch, two_stage, shards):
+    """``shard_h2d_copies`` once a bucket's zero blocks are there: the
+    packed buffer alone, and for a ``whiteList`` the candidate ids
+    beside it — where a replicated upload wrote ``shards`` and twice
+    ``shards`` device buffers. ``uploads`` as they were."""
+    monkeypatch.setenv("PIO_MESH", f"data={shards}")
+    w = ShardedWorld(storage, "float32")
+
+    def dispatch(kind, n):
+        before = retrieval.stats_block()
+        w.algo.predict(w.model, w.query(kind, n))
+        after = retrieval.stats_block()
+        return (after["shard_h2d_copies"] - before["shard_h2d_copies"],
+                after["uploads"] - before["uploads"])
+
+    assert dispatch("home", 1) == (shards, 1)  # the copy + the zero blocks
+    assert dispatch("home", 2) == (1, 1)
+    assert dispatch("category", 3) == (1, 1)  # a layout is a shape: the same
+    assert dispatch("whiteList", 4) == (1 + shards, 2)  # the ids' zeros
+    assert dispatch("whiteList", 6) == (2, 2)
+    catalog = w.algo._sharded_catalog(w.model)
+    assert len(catalog._zeros) == 2
+
+
+@pytest.mark.parametrize("engaged", [True, False], ids=["two_stage", "exact"])
 @pytest.mark.parametrize("kind", KINDS)
 def test_sharded_answers_are_the_one_chip_storefronts(
         f32_world, monkeypatch, kind, engaged):
@@ -247,7 +299,8 @@ def test_spans_and_counters_of_the_sharded_rules(f32_world, two_stage):
             "dispatch.rescore", "dispatch.fetch"} <= set(spans)
     text = shard_topk._sharded_topk_masked.lower(
         *_masked_args(w), **_masked_static(w)).as_text(debug_info=True)
-    for scope in ("retrieval.shard.rules", "retrieval.shard.scan",
+    for scope in ("retrieval.shard.broadcast", "retrieval.shard.rules",
+                  "retrieval.shard.scan",
                   "retrieval.shard.rescore", "retrieval.shard.gather",
                   "retrieval.shard.merge", "retrieval.shortlist.mask",
                   "retrieval.rescore.mask"):
@@ -258,7 +311,7 @@ def _masked_args(w):
     catalog = w.algo._sharded_catalog(w.model)
     avail, cats = w.algo._catalog_rules(w.model, catalog, None)
     layout = retrieval.Layout(catalog.dim, 1, ec._EXCLUDED_BUCKET)
-    packed = jax.ShapeDtypeStruct((1, sum(layout[:3]) + 1), np.int32)
+    packed = jax.ShapeDtypeStruct((SHARDS, 1, sum(layout[:3]) + 1), np.int32)
     return packed, None, catalog._rows, catalog._tiles, catalog._ids, avail, cats
 
 
@@ -285,7 +338,7 @@ class TestWithoutRules:
         mesh4 = make_mesh([("data", SHARDS)])
         nt, t, d = 2, 256, 16
         args = (
-            jax.ShapeDtypeStruct((batch, d), np.float32),
+            jax.ShapeDtypeStruct((SHARDS, batch, d), np.float32),
             jax.ShapeDtypeStruct((SHARDS * nt * t, d), np.float32),
             jax.ShapeDtypeStruct((SHARDS * nt, t, d), jax.numpy.bfloat16),
             jax.ShapeDtypeStruct((SHARDS * nt, *retrieval.side_shape(nt, t)[1:]),
@@ -294,6 +347,7 @@ class TestWithoutRules:
         text = shard_topk._sharded_topk.lower(
             *args, r=500, kp=128, k=16, mode="bf16", mesh=mesh4, axis="data",
         ).as_text(debug_info=True)
+        assert "retrieval.shard.broadcast" in text
         assert "retrieval.shard.scan" in text
         for scope in ("retrieval.shard.rules", "shortlist.mask", "rescore.mask"):
             assert scope not in text, scope

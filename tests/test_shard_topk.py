@@ -10,8 +10,10 @@ table and knows nothing of shards.
 import jax
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.ops import retrieval
+from predictionio_tpu.ops.topk import Rules
 from predictionio_tpu.parallel import shard_topk
 from predictionio_tpu.parallel.mesh import make_mesh, parse_axes, serving_mesh
 from predictionio_tpu.parallel.shard_topk import ShardedCatalog
@@ -121,7 +123,7 @@ class TestShardedChain:
         inside the loop over the tiles; the selections follow it."""
         nt, t, d = 2, 8192, 16
         shapes = lambda b: (  # noqa: E731
-            jax.ShapeDtypeStruct((b, d), np.float32),
+            jax.ShapeDtypeStruct((4, b, d), np.float32),
             jax.ShapeDtypeStruct((4 * nt * t, d), np.float32),
             jax.ShapeDtypeStruct((4 * nt, t, d), jax.numpy.bfloat16),
             jax.ShapeDtypeStruct((4 * nt, t), np.int32),
@@ -172,7 +174,7 @@ class TestShardedChain:
                 *a, r=nt * t, kp=128, k=16, mode="bf16", mesh=mesh4, axis="data",
             )
         )(
-            jax.ShapeDtypeStruct((batch, d), np.float32),
+            jax.ShapeDtypeStruct((4, batch, d), np.float32),
             jax.ShapeDtypeStruct((4 * nt * t, d), np.float32),
             jax.ShapeDtypeStruct((4 * nt, t, d), jax.numpy.bfloat16),
             jax.ShapeDtypeStruct((4 * nt, t // 128, 128), np.int32),
@@ -259,7 +261,7 @@ class TestShardedChain:
         _served(cat, U, range(3), len(V), 16)
         after = retrieval.stats_block()
         assert after["host_reads"] - before["host_reads"] == 1
-        # the replicated vectors and nothing else: the chain without rules
+        # the vectors and nothing else: the chain without rules
         assert after["uploads"] - before["uploads"] == 1
         assert after["sharded_queries"] - before["sharded_queries"] == 3
         assert after["two_stage_queries"] == before["two_stage_queries"]
@@ -307,8 +309,6 @@ class TestShardedChain:
 
     def test_a_sum_of_rows_under_rules_is_refused_by_name(self, mesh4, two_stage):
         """(``Vectors`` under rules are served: tests/test_shard_rules.py.)"""
-        from predictionio_tpu.ops.topk import Rules
-
         U, V = _tables(2000, seed=11)
         rules = Rules(avail=None, cats=(), qcat=None, has_cat=None, ex=None)
         query = retrieval.SumRows(
@@ -320,6 +320,128 @@ class TestShardedChain:
     def test_a_mesh_of_two_axes_is_refused(self):
         with pytest.raises(ValueError, match="1-D mesh"):
             ShardedCatalog(_tables(64)[1], make_mesh([("data", 2), ("model", 2)]))
+
+
+MESHES = (1, 2, 4)
+
+
+@pytest.fixture(scope="module")
+def catalogs():
+    """{shards: (a 3,001-row catalog over that many devices, U, V)}."""
+    U, V = _tables(3001, seed=13)
+    return {n: (ShardedCatalog(V, make_mesh([("data", n)])), U, V) for n in MESHES}
+
+
+def _host_batch(kind, b, rng):
+    """(host array, dtype, rows it is padded to) of the three arrays a
+    dispatch sends: f32 vectors (-0.0, a denormal, an infinity among
+    them), ``retrieval.pack``'s buffer (f32 bit patterns beside negative
+    ids) and a ``whiteList`` batch's candidate ids."""
+    vecs = rng.standard_normal((b, 16)).astype(np.float32)
+    vecs[0, :3] = [-0.0, 1e-42, -np.inf]
+    if kind == "vectors":
+        return vecs, np.float32, retrieval._pow2(b)
+    if kind == "packed":
+        ex = rng.integers(0, 3001, (b, 8)).astype(np.int32)
+        ex[:, 5:] = -1
+        rules = Rules(None, (), np.full((b, 1), -2, np.int32), np.arange(b) % 2 == 0, ex)
+        return retrieval.pack(vecs, rules)[0], np.int32, 0
+    cand = rng.integers(0, 3001, (b, 24)).astype(np.int32)
+    cand[:, 20:] = -1
+    return cand, np.int32, retrieval._pow2(b)
+
+
+class TestOneCopyADispatch:
+    """A host array reaches the shards by ONE route: a copy to the mesh's
+    first device, the stitch, and the programs' first statement."""
+
+    @pytest.mark.parametrize("kind", ["vectors", "packed", "candidates"])
+    @pytest.mark.parametrize("batch", [1, 3, 16])
+    @pytest.mark.parametrize("shards", MESHES)
+    def test_every_shard_holds_what_a_copy_of_its_own_would_give_it(
+            self, catalogs, shards, batch, kind):
+        """Bit for bit, on every shard: what ``_from_first`` leaves there
+        against a replicated ``device_put`` of the same host array."""
+        cat = catalogs[shards][0]
+        a, dtype, rows = _host_batch(kind, batch, np.random.default_rng(batch))
+        uploads = retrieval.stats_block()["uploads"]
+        got = cat.put_replicated(a, dtype, rows)
+        assert retrieval.stats_block()["uploads"] == uploads + 1
+        bp = retrieval._pow2(batch)
+        assert got.shape == (shards, bp, a.shape[1]) and got.dtype == dtype
+        assert got.sharding.is_equivalent_to(cat._ids.sharding, 3)
+        blocks = [np.asarray(sh.data) for sh in got.addressable_shards]
+        assert all(not blk.any() for blk in blocks[1:])  # the resident zeros
+        handed = jax.jit(jax.shard_map(
+            lambda x: shard_topk._from_first(x, cat.axis)[None], mesh=cat.mesh,
+            in_specs=P(cat.axis), out_specs=P(cat.axis), check_vma=False,
+        ))(got)
+        want = jax.device_put(
+            retrieval._pad_rows(np.ascontiguousarray(a, dtype), rows),
+            NamedSharding(cat.mesh, P()),
+        )
+        assert len(handed.addressable_shards) == shards
+        for mine, own in zip(handed.addressable_shards, want.addressable_shards):
+            assert mine.device == own.device
+            np.testing.assert_array_equal(
+                np.asarray(mine.data)[0].view(np.uint32),
+                np.asarray(own.data).view(np.uint32))
+
+    @pytest.mark.parametrize("batch", [1, 3, 16])
+    @pytest.mark.parametrize("shards", MESHES)
+    def test_both_programs_answer_as_the_plain_reference(
+            self, catalogs, two_stage, shards, batch):
+        """The two-stage and the exact program on meshes of one, two and
+        four devices: the reference's ids, its scores to f32 rounding —
+        and the same answer from a batch whose EVERY shard was given a
+        copy of its own (what the replicated route held), bit for bit."""
+        cat, U, V = catalogs[shards]
+        rs, ri = _reference(U[:batch], V, 16)
+        s, ids = _served(cat, U, range(batch), len(V), 16)
+        np.testing.assert_array_equal(ids, ri)
+        np.testing.assert_allclose(s, rs, rtol=0, atol=4e-6 * np.abs(rs).max())
+        es, eids = cat.exact_top_k(U[:batch], 16)
+        np.testing.assert_array_equal(eids, ri)
+        np.testing.assert_allclose(es, rs, rtol=0, atol=4e-6 * np.abs(rs).max())
+        padded = retrieval._pad_rows(U[:batch], retrieval._pow2(batch))[None]
+        copies = jax.make_array_from_single_device_arrays(
+            (shards, *padded.shape[1:]), cat._split,
+            [jax.device_put(padded, d) for d in cat._devices])
+        kp = retrieval.two_stage_k(16, len(V))
+        for got, out in ((s, cat.launch(copies, kp, 16)),
+                         (es, cat.launch_exact(copies, 16))):
+            np.testing.assert_array_equal(
+                got.view(np.uint32),
+                np.asarray(out[0])[:batch].view(np.uint32))
+
+    @pytest.mark.parametrize("shards", MESHES)
+    def test_one_copy_a_dispatch_and_the_zero_blocks_once_a_shape(
+            self, two_stage, monkeypatch, shards):
+        """``shard_h2d_copies``: the first dispatch of a bucket writes
+        every shard's block (one copy, shards - 1 zero blocks), every
+        later one the copy alone; the recall probe slices the dispatch's
+        device batch and writes none."""
+        monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "1")
+        U, V = _tables(3001, seed=14)
+        cat = ShardedCatalog(V, make_mesh([("data", shards)]))
+
+        def dispatch(uix):
+            before = retrieval.stats_block()
+            _served(cat, U, uix, len(V), 16)
+            after = retrieval.stats_block()
+            assert after["uploads"] - before["uploads"] == 1
+            assert after["host_reads"] - before["host_reads"] == 2  # + the probe
+            assert after["probes"] - before["probes"] == 1
+            return after["shard_h2d_copies"] - before["shard_h2d_copies"]
+
+        assert dispatch(range(3)) == shards  # bucket 4: the copy + the zeros
+        assert dispatch(range(4)) == 1
+        assert dispatch([5, 6, 7]) == 1
+        assert len(cat._zeros) == 1
+        assert dispatch(range(1)) == shards  # another bucket
+        assert dispatch([9]) == 1
+        assert {k[0] for k in cat._zeros} == {(1, 4, 16), (1, 1, 16)}
+        assert all(len(z) == shards - 1 for z in cat._zeros.values())
 
 
 class TestServingMesh:

@@ -348,8 +348,9 @@ def test_the_four_chip_chain_runs_the_same_step(one_chip):
     """``_sharded_topk`` for the sharded cell's four chips (46 tiles a
     chip, one query): each device's ids are ``s32[46,2048,128]``, its
     loop body the one-chip single's — the three rows' sum, no
-    ``compare_select_fusion`` over a strided row — and the program's one
-    collective is the all-gather."""
+    ``compare_select_fusion`` over a strided row — and the program's
+    collectives are all-gathers: the broadcast of shard 0's queries in
+    front (PR 49) and the answers' behind."""
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -359,9 +360,9 @@ def test_the_four_chip_chain_runs_the_same_step(one_chip):
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     mesh = Mesh(np.array(topo.devices), ("data",))
     n, nt, d = 4, 46, 64
-    whole, split = _on(NamedSharding(mesh, P())), _on(NamedSharding(mesh, P("data")))
+    split = _on(NamedSharding(mesh, P("data")))
     text = shard_topk._sharded_topk.lower(
-        whole((1, d), jnp.float32),
+        split((n, 1, d), jnp.float32),
         split((n * nt * TILE, d), jnp.float32),
         split((n * nt, TILE, d), jnp.bfloat16),
         split((n * nt, *retrieval.side_shape(nt, TILE)[1:]), jnp.int32),
@@ -379,8 +380,8 @@ def test_the_four_chip_chain_runs_the_same_step(one_chip):
 def test_the_masked_four_chip_chain_fits_a_chip_beside_its_catalog(one_chip, b):
     """``_sharded_topk_masked`` for the sharded storefront's four chips (46
     tiles a chip; the rules' vectors sharded like the rows, the dispatch
-    one packed replicated buffer): it compiles, its one collective is the
-    all-gather, every rule vector is read where it lies (no copy of a
+    one packed buffer on shard 0, handed round): it compiles, its
+    collectives are all-gathers, every rule vector is read where it lies (no copy of a
     [stored] vector), and its temporaries — the stored scores and the
     [46, B, 2^18] mask — leave a 16 GB chip's 4.8 GB of catalog room."""
     import numpy as np
@@ -392,10 +393,10 @@ def test_the_masked_four_chip_chain_fits_a_chip_beside_its_catalog(one_chip, b):
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     mesh = Mesh(np.array(topo.devices), ("data",))
     n, nt, d = 4, 46, 64
-    whole, split = _on(NamedSharding(mesh, P())), _on(NamedSharding(mesh, P("data")))
+    split = _on(NamedSharding(mesh, P("data")))
     layout = retrieval.Layout(d, 1, 128)
     compiled = shard_topk._sharded_topk_masked.lower(
-        whole((b, sum(layout[:3]) + 1), jnp.int32), None,
+        split((n, b, sum(layout[:3]) + 1), jnp.int32), None,
         split((n * nt * TILE, d), jnp.float32),
         split((n * nt, TILE, d), jnp.bfloat16),
         split((n * nt, *retrieval.side_shape(nt, TILE)[1:]), jnp.int32),
