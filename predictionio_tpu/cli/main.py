@@ -420,6 +420,38 @@ def cmd_profile(args) -> int:
     return 0
 
 
+def cmd_layers(args) -> int:
+    """``pio layers --url BASE --seconds N`` / ``--windows
+    DIR/gen.windows.json``: where a query's time goes, per request and
+    per dispatch, from two scrapes of a server's ``/metrics`` — a live
+    server's, N seconds apart, or the two a benchmark run kept with
+    ``--save-logs``. No profiler: the always-on histograms of the span
+    chain, down to the uploads, launches and the read of a dispatch."""
+    from predictionio_tpu.cli import layers
+
+    if bool(args.url) == bool(args.windows):
+        print("layers: give --url BASE or --windows FILE", file=sys.stderr)
+        return 2
+    try:
+        if args.windows:
+            docs = layers.from_windows(args.windows)
+        else:
+            docs = [(args.url, layers.from_url(args.url, args.seconds))]
+    except (OSError, ValueError, KeyError) as e:
+        print(f"layers failed: {e}", file=sys.stderr)
+        return 1
+    if not docs:
+        print("layers: no measured window in the file", file=sys.stderr)
+        return 1
+    if args.json:
+        print(json.dumps(
+            {label: doc for label, doc in docs}, separators=(",", ":")
+        ))
+        return 0
+    print("\n\n".join(layers.render(doc, label) for label, doc in docs))
+    return 0
+
+
 def cmd_incidents(args) -> int:
     """``pio incidents list|show|prune``: inspect the flight recorder's
     bundle directory (``$PIO_RUN_DIR/incidents``). ``show NAME`` prints
@@ -1773,6 +1805,25 @@ def build_parser() -> argparse.ArgumentParser:
         "regions and the runtime's events)",
     )
     pr.set_defaults(fn=cmd_profile)
+
+    ly = sub.add_parser(
+        "layers",
+        help="where a query's time goes, from two /metrics scrapes",
+    )
+    ly.add_argument(
+        "--url", help="scrape this server twice (e.g. http://127.0.0.1:8000)",
+    )
+    ly.add_argument(
+        "--seconds", type=float, default=10.0,
+        help="seconds between the two scrapes of --url (default 10)",
+    )
+    ly.add_argument(
+        "--windows",
+        help="a gen.windows.json kept by benchmark/run.py --save-logs: its "
+        "measured windows' two scrapes",
+    )
+    ly.add_argument("--json", action="store_true", help="one JSON line")
+    ly.set_defaults(fn=cmd_layers)
 
     inc = sub.add_parser("incidents")
     incsub = inc.add_subparsers(dest="incidents_command")

@@ -140,12 +140,11 @@ def save_checkpoint(
         faults.fault_point("train.checkpoint")
         path.parent.mkdir(parents=True, exist_ok=True)
         arrays: dict = {}
-        _pack_table("U", U, arrays)
-        _pack_table("V", V, arrays)
-        # _pack_table's np.asarray pulled the carry off the device
-        obs_device.count_transfer(
-            "d2h", "checkpoint", sum(a.nbytes for a in arrays.values())
-        )
+        # _pack_table's np.asarray pulls the carry off the device
+        with obs_device.transfer("d2h", "checkpoint") as pulled:
+            _pack_table("U", U, arrays)
+            _pack_table("V", V, arrays)
+            pulled.nbytes = sum(a.nbytes for a in arrays.values())
         with open(tmp, "wb") as f:
             np.savez(
                 f,

@@ -6,24 +6,32 @@ black box — the telemetry ALX (arxiv 2112.02194) uses to attribute TPU
 time between gather, solve, and collectives, and that arxiv 2501.10546
 treats as first-class production signals:
 
-- **Compile tracking** — :func:`track_jit` wraps a jitted entry point
-  and detects recompiles by the executable-cache-size delta across each
-  call (``fn._cache_size()``), exporting ``pio_jit_compiles_total{fn}``
-  / ``pio_jit_cache_hits_total{fn}`` and a per-function hit-ratio
-  gauge. A process-global ``jax.monitoring`` listener feeds backend
-  compile durations into ``pio_jit_compile_seconds``. Shape-churn
-  recompiles (the micro-batcher's known failure mode) become a counter
-  on ``/metrics`` instead of mystery latency.
+- **Compile tracking and the launch** — :func:`track_jit` wraps a
+  jitted entry point and detects recompiles by the
+  executable-cache-size delta across each call (``fn._cache_size()``),
+  exporting ``pio_jit_compiles_total{fn}`` /
+  ``pio_jit_cache_hits_total{fn}``. A process-global ``jax.monitoring``
+  listener feeds backend compile durations into
+  ``pio_jit_compile_seconds``. Shape-churn recompiles (the
+  micro-batcher's known failure mode) become a counter on ``/metrics``
+  instead of mystery latency. The call itself is the ``launch[<fn>]``
+  region: what the host pays to hand a program to the runtime
+  (``pio_jit_call_seconds{fn}``; a call that compiled is left out).
 - **Memory & transfer telemetry** — per-device gauges evaluated at
   scrape time from ``device.memory_stats()`` (None-tolerant: CPU
   backends report no stats and export zeros with a ``supported`` gauge
-  saying so), plus byte-accounting counters
-  (``pio_device_transfer_bytes_total{direction,op}``) fed by the
-  explicit host<->device copy sites: training bucket upload, sharded
-  pack upload, checkpoint snapshot gather, deploy/patch model put.
+  saying so), plus ONE family for every host<->device copy,
+  ``pio_device_transfer_{seconds,bytes_total}{direction,op}`` and
+  ``pio_device_transfers_total{direction,op}``, fed by :class:`transfer`
+  (the ``xfer.<direction>[<op>]`` region around a copy) at the copy
+  sites: a dispatch's uploads and its read, the training bucket upload,
+  the sharded pack upload, the checkpoint snapshot gather, a fold-in's
+  patch.
 - **On-demand profiling** — :func:`profile_capture` runs a bounded
   ``jax.profiler`` trace capture behind a process lock (one capture at
-  a time), backing ``pio profile`` and the ``POST /profile`` endpoint.
+  a time), backing ``pio profile`` and the ``POST /profile`` endpoint,
+  and books what each of its phases cost the process
+  (``pio_profile_seconds_total{phase}``).
 
 Everything is lazy about jax: importing this module never imports jax,
 and scrape-time paths only look at devices when ``jax`` is already in
@@ -46,7 +54,9 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "track_jit",
+    "transfer",
     "count_transfer",
+    "transfer_count",
     "count_restage",
     "transfer_totals",
     "compile_snapshot",
@@ -118,11 +128,26 @@ def track_jit(name: str):
     backend_compile events per jit (sub-compiles), so durations come
     from the listener while counts come from here.
 
+    The call is the ``launch[<name>]`` region (``obs.trace.region``: a
+    span under the stage that launched, an annotation while a capture
+    runs): what the host pays to hand the program to the runtime — the
+    arguments checked, the executable looked up, the launch enqueued;
+    nothing is waited for. ``pio_jit_call_seconds{fn}`` observes it,
+    but for a call that compiled: that time is a compile's, and
+    ``pio_jit_compile_seconds`` has it.
+
     Apply ABOVE the ``jax.jit`` decoration (outermost). Overhead when
-    enabled is two getattr+int reads and two counter incs per call;
-    disabled cost is one flag check (bench obs/device gates it <1%).
+    enabled is one region, two getattr+int reads and a counter inc per
+    call; disabled cost is one flag check.
     """
     stats = _jit_stats.setdefault(name, _JitStats())
+    span = f"launch[{name}]"
+    m_call = _metrics.histogram(
+        "pio_jit_call_seconds",
+        "host time inside a tracked jit call that hit the executable "
+        "cache: the launch, nothing waited for",
+        fn=name,
+    )
     m_compiles = _metrics.counter(
         "pio_jit_compiles_total",
         "XLA compiles triggered by tracked jit entry points",
@@ -132,13 +157,6 @@ def track_jit(name: str):
         "pio_jit_cache_hits_total",
         "Tracked jit calls served from the executable cache",
         fn=name,
-    )
-    _metrics.gauge(
-        "pio_jit_cache_hit_ratio",
-        "Fraction of tracked jit calls served without a compile",
-        fn=name,
-    ).set_function(
-        lambda s=stats: (s.cache_hits / s.calls) if s.calls else 0.0
     )
 
     def deco(fn):
@@ -152,7 +170,8 @@ def track_jit(name: str):
                 before = cache_size()
             except Exception:
                 before = -1
-            out = fn(*args, **kwargs)
+            with _trace.region(span) as launch:
+                out = fn(*args, **kwargs)
             stats.calls += 1
             try:
                 after = cache_size()
@@ -164,6 +183,7 @@ def track_jit(name: str):
             else:
                 stats.cache_hits += 1
                 m_hits.inc()
+                m_call.observe(launch.seconds)
             return out
 
         wrapper.__name__ = getattr(fn, "__name__", name)
@@ -194,32 +214,86 @@ def compile_snapshot() -> dict[str, dict[str, int]]:
     }
 
 
-# -- transfer byte accounting -------------------------------------------------
+# -- host <-> device copies: one family ---------------------------------------
 
-_transfer_lock = threading.Lock()
-_transfer_totals: dict[tuple[str, str], int] = {}
+_TRANSFER_BYTES = "pio_device_transfer_bytes_total"
+_sites: dict[tuple[str, str], tuple] = {}
+
+
+def _site(direction: str, op: str) -> tuple:
+    """(span name, seconds histogram, bytes counter, copies counter) of
+    one site, made once: a dispatch passes here at every crossing."""
+    key = (direction, op)
+    found = _sites.get(key)
+    if found is None:
+        found = _sites[key] = (
+            f"xfer.{direction}[{op}]",
+            _metrics.histogram(
+                "pio_device_transfer_seconds",
+                "Host time of one host<->device copy, to its return, by site",
+                direction=direction, op=op,
+            ),
+            _metrics.counter(
+                _TRANSFER_BYTES,
+                "Bytes moved between host and device, by site",
+                direction=direction, op=op,
+            ),
+            _metrics.counter(
+                "pio_device_transfers_total",
+                "Host<->device copies, by site",
+                direction=direction, op=op,
+            ),
+        )
+    return found
 
 
 def count_transfer(direction: str, op: str, nbytes: int) -> None:
-    """Account one host<->device copy: ``direction`` is ``h2d``/``d2h``,
-    ``op`` names the site (train.buckets, checkpoint, serve.model_put,
-    ...). Feeds ``pio_device_transfer_bytes_total`` and the stats
-    block's transfer table."""
+    """Book one host<->device copy that no :class:`transfer` region
+    timed — a site that only learns afterwards what went up (a model
+    that stages itself at its first query): the bytes and the copy,
+    without a duration. ``direction`` is ``h2d``/``d2h``; ``op`` names
+    the site, from a closed set — on the serving path ``serve.dispatch``
+    (a dispatch's host arrays), ``serve.rules`` (the per-query rules put
+    up one by one), ``serve.zero_blocks`` (a sharded catalog's resident
+    blocks, once a shape), ``serve.answers`` (the read),
+    ``serve.model_patch``; at load ``serve.model_put``,
+    ``train.buckets``, ``train.packed_side``, ``checkpoint``."""
     if not _metrics.enabled() or nbytes <= 0:
         return
-    _metrics.counter(
-        "pio_device_transfer_bytes_total",
-        "Bytes moved between host and device, by site",
-        direction=direction, op=op,
-    ).inc(int(nbytes))
-    _metrics.counter(
-        "pio_device_transfers_total",
-        "Host<->device copies, by site",
-        direction=direction, op=op,
-    ).inc()
-    with _transfer_lock:
-        key = (direction, op)
-        _transfer_totals[key] = _transfer_totals.get(key, 0) + int(nbytes)
+    _, _, m_bytes, m_copies = _site(direction, op)
+    m_bytes.inc(int(nbytes))
+    m_copies.inc()
+
+
+class transfer(_trace.region):
+    """The region around ONE host<->device copy:
+    ``with transfer("h2d", "serve.dispatch", a.nbytes): jnp.asarray(a)``
+    is the span ``xfer.h2d[serve.dispatch]`` under the stage that
+    copies (an annotation while a capture runs) and, when the copy
+    returns, one observation of the family: its seconds, its bytes, the
+    copy (``op``: :func:`count_transfer`'s closed set). An upload is
+    timed to its return — the runtime may still be moving the bytes — a
+    read to the bytes on the host; a read may set ``nbytes`` inside the
+    block, from what arrived."""
+
+    __slots__ = ("nbytes", "_site")
+
+    def __init__(self, direction: str, op: str, nbytes: int = 0):
+        site = self._site = _site(direction, op)
+        super().__init__(site[0], hist=site[1])
+        self.nbytes = nbytes
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        if self._on:
+            self._site[2].inc(int(self.nbytes))
+            self._site[3].inc()
+        return False
+
+
+def transfer_count(direction: str, *ops: str) -> int:
+    """Copies booked so far at the named sites of one direction."""
+    return sum(_site(direction, op)[3].value() for op in ops)
 
 
 def count_restage(part: str) -> None:
@@ -237,10 +311,13 @@ def count_restage(part: str) -> None:
 
 
 def transfer_totals() -> dict[str, int]:
-    with _transfer_lock:
-        return {
-            f"{d}.{op}": n for (d, op), n in sorted(_transfer_totals.items())
-        }
+    """Bytes by ``direction.op``, read from the registry's family."""
+    totals = {}
+    for m in _metrics.REGISTRY.family(_TRANSFER_BYTES):
+        lab = dict(m.labels)
+        if m.value():
+            totals[f"{lab['direction']}.{lab['op']}"] = m.value()
+    return dict(sorted(totals.items()))
 
 
 # -- device memory gauges -----------------------------------------------------
@@ -381,6 +458,15 @@ def profile_active() -> bool:
     return _profile_running
 
 
+# the last stretch of a capture's window, in which nothing new is annotated
+_CLOSING_S = 0.06
+
+
+def _sleep_until(deadline: float) -> None:
+    while time.perf_counter() < deadline:
+        time.sleep(min(0.05, max(deadline - time.perf_counter(), 0)))
+
+
 def _default_profile_dir() -> str:
     base = os.path.join(
         os.path.expanduser(os.environ.get("PIO_RUN_DIR", "~/.pio_tpu/run")),
@@ -389,19 +475,43 @@ def _default_profile_dir() -> str:
     return os.path.join(base, time.strftime("%Y%m%d-%H%M%S"))
 
 
+_PHASES = ("start", "capture", "stop")
+_m_profile = {
+    phase: _metrics.counter(
+        "pio_profile_seconds_total",
+        "seconds this process spent in profiler captures it was asked for, "
+        "by phase: start = jax.profiler.start_trace; capture = the window "
+        "asked for; stop = stop_trace, the trace collected and written",
+        phase=phase,
+    )
+    for phase in _PHASES
+}
+
+
 def profile_capture(
     seconds: float, out_dir: str | None = None, burn: bool = False,
     python_tracer: bool = False,
 ) -> dict:
     """Capture a ``jax.profiler`` trace for ``seconds`` and return
-    {trace_dir, seconds, files, bytes}.
+    {trace_dir, seconds, start_s, stop_s, files, bytes}.
 
     The host plane holds the program's own regions
     (``obs.trace.region`` / ``annotate`` become ``TraceAnnotation`` s
     for the length of the capture) and the runtime's events; the
     profiler's Python tracer — one event per Python call, which slowed a
     saturated server by a sixth — is off unless ``python_tracer`` asks
-    for frames.
+    for frames. The runtime's own host tracer stays at the profiler's
+    default level (2): at 1 the capture loses only the allocator's and
+    the transposes' events and its ``stop_trace`` is no shorter (PERF.md
+    section 6, PR 50).
+
+    A capture records itself: three regions — ``profile.start``
+    (``start_trace``), ``profile.capture`` (the window; ``seconds`` in
+    the reply), ``profile.stop`` (``stop_trace``: the trace collected,
+    converted and written, while the server goes on serving) — each
+    added to ``pio_profile_seconds_total{phase}``, so that two scrapes
+    around any interval say how much of it a capture took, and
+    ``start_s`` / ``stop_s`` in the reply.
 
     One capture at a time (RuntimeError when one is already running —
     the /profile route maps it to 409); seconds is clamped to
@@ -415,6 +525,7 @@ def profile_capture(
     trace_dir = out_dir or _default_profile_dir()
     if not _profile_lock.acquire(blocking=False):
         raise RuntimeError("a profile capture is already running")
+    phases = {name: _trace.region(f"profile.{name}") for name in _PHASES}
     try:
         _profile_running = True
         import jax
@@ -423,26 +534,37 @@ def profile_capture(
         os.makedirs(trace_dir, exist_ok=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 1 if python_tracer else 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        with phases["start"]:
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         _trace.set_annotating(True)
         try:
-            deadline = time.perf_counter() + seconds
-            if burn:
-                import jax.numpy as jnp
+            with phases["capture"]:
+                deadline = time.perf_counter() + seconds
+                closing = deadline - min(_CLOSING_S, seconds / 2)
+                if burn:
+                    import jax.numpy as jnp
 
-                f = jax.jit(lambda x: (x @ x.T).sum())
-                x = jnp.ones((256, 256), jnp.float32)
-                while time.perf_counter() < deadline:
-                    f(x).block_until_ready()
-            else:
-                while time.perf_counter() < deadline:
-                    time.sleep(min(0.05, max(deadline - time.perf_counter(), 0)))
+                    f = jax.jit(lambda x: (x @ x.T).sum())
+                    x = jnp.ones((256, 256), jnp.float32)
+                    while time.perf_counter() < closing:
+                        f(x).block_until_ready()
+                else:
+                    _sleep_until(closing)
+                # the tracer keeps an annotation only if it ENDS inside
+                # the capture: no new ones from here, and a wait that
+                # polls ``obs.trace.annotating()`` (the batch worker's,
+                # every 50 ms) leaves its own before the tracer stops
+                _trace.set_annotating(False)
+                _sleep_until(deadline)
         finally:
             _trace.set_annotating(False)
-            jax.profiler.stop_trace()
+            with phases["stop"]:
+                jax.profiler.stop_trace()
     finally:
         _profile_running = False
         _profile_lock.release()
+        for name, r in phases.items():
+            _m_profile[name].inc(r.seconds)
     n_files = 0
     n_bytes = 0
     for root, _dirs, files in os.walk(trace_dir):
@@ -455,6 +577,8 @@ def profile_capture(
     return {
         "trace_dir": trace_dir,
         "seconds": round(seconds, 3),
+        "start_s": round(phases["start"].seconds, 3),
+        "stop_s": round(phases["stop"].seconds, 3),
         "files": n_files,
         "bytes": n_bytes,
     }

@@ -330,6 +330,11 @@ class Registry:
     ) -> Histogram:
         return self._get(Histogram, name, help_, labels, bounds=bounds)
 
+    def family(self, name: str) -> list:
+        """Every registered series of one metric name, any labels."""
+        with self._lock:
+            return [m for m in self._metrics.values() if m.name == name]
+
     def clear(self) -> None:
         """Drop every registered metric (tests/bench isolation)."""
         with self._lock:

@@ -150,13 +150,10 @@ def _on_gc(phase: str, info: dict) -> None:
     if dt >= _GC_SPAN_S and _metrics.enabled():
         # a child of the region this thread is in: it enters the
         # region's children, so the region's self time stays self time
-        tls = _trace._tls
-        tls.children_s = getattr(tls, "children_s", 0.0) + dt
-        tr = getattr(tls, "trace", None)
-        if tr is not None:
-            tr.add_span(
-                f"gc.pause[{gen}]", _gc_t0, end, getattr(tls, "region", None)
-            )
+        st = _trace._state()  # [trace, region, children's seconds]
+        st[2] += dt
+        if st[0] is not None:
+            st[0].add_span(f"gc.pause[{gen}]", _gc_t0, end, st[1])
 
 
 # -- 4. process stops ---------------------------------------------------------

@@ -51,7 +51,9 @@ __all__ = [
     "set_current_trace",
     "use_trace",
     "region",
+    "one_in_every",
     "annotate",
+    "annotating",
     "set_annotating",
     "new_trace_id",
 ]
@@ -168,12 +170,23 @@ class Fanout:
 _tls = threading.local()
 
 
+def _state() -> list:
+    """This thread's [current trace, innermost open region's name,
+    seconds of the regions already closed inside it]: ONE thread-local
+    read for a region's entry (a region is entered ~16 times a query)."""
+    try:
+        return _tls.st
+    except AttributeError:
+        st = _tls.st = [None, None, 0.0]
+        return st
+
+
 def current_trace() -> "Trace | Fanout | None":
-    return getattr(_tls, "trace", None)
+    return _state()[0]
 
 
 def set_current_trace(trace: "Trace | Fanout | None") -> None:
-    _tls.trace = trace
+    _state()[0] = trace
 
 
 class use_trace:
@@ -188,15 +201,14 @@ class use_trace:
         self._parent = parent
 
     def __enter__(self):
-        tls = _tls
-        self._prev = (
-            getattr(tls, "trace", None), getattr(tls, "region", None)
-        )
-        tls.trace, tls.region = self._trace, self._parent
+        st = _state()
+        self._prev = (st[0], st[1])
+        st[0], st[1] = self._trace, self._parent
         return self._trace
 
     def __exit__(self, *exc):
-        _tls.trace, _tls.region = self._prev
+        st = _state()
+        st[0], st[1] = self._prev
         return False
 
 
@@ -210,6 +222,13 @@ _annotating = False
 def set_annotating(flag: bool) -> None:
     global _annotating
     _annotating = bool(flag)
+
+
+def annotating() -> bool:
+    """Is a capture running? For a wait that outlasts captures: an
+    annotation is decided where it is entered, so a thread that waits in
+    one for seconds enters it again when this changes."""
+    return _annotating
 
 
 class _Null:
@@ -251,7 +270,20 @@ def annotate(name: str):
 # Seven and not eight: a stride that shares a factor with a batcher's
 # rhythm (a small batch, a large one, ...) would always meet the same kind.
 CPU_EVERY = 7
-_cpu_calls: dict[int, int] = {}
+_calls: dict[int, int] = {}
+
+
+def one_in_every(key) -> bool:
+    """True on one call in ``CPU_EVERY``, counted per ``key`` (an
+    instrument): the stride for a reading that costs too much to take on
+    every call — a second clock, a second runtime call. Never under
+    ``PIO_OBS=0``."""
+    if not _metrics._enabled:
+        return False
+    k = id(key)
+    n = _calls.get(k, 0)
+    _calls[k] = n + 1
+    return n % CPU_EVERY == 0
 
 
 class region:
@@ -273,7 +305,7 @@ class region:
 
     __slots__ = (
         "name", "start", "end", "seconds", "self_seconds",
-        "_hist", "_trace", "_parent", "_outer_children", "_ann", "_on",
+        "_hist", "_trace", "_st", "_parent", "_outer_children", "_ann", "_on",
         "_cpu_hist", "_cpu0",
     )
 
@@ -287,28 +319,28 @@ class region:
         self._cpu_hist = cpu_hist
 
     def __enter__(self):
-        on = self._on = _metrics.enabled()
+        on = self._on = _metrics._enabled
         if not on:
             return self
-        tls = _tls
+        try:
+            st = self._st = _tls.st
+        except AttributeError:
+            st = self._st = _state()
         if self._trace is None:
-            self._trace = getattr(tls, "trace", None)
-        self._parent = getattr(tls, "region", None)
-        self._outer_children = getattr(tls, "children_s", 0.0)
-        tls.region = self.name
-        tls.children_s = 0.0
+            self._trace = st[0]
+        self._parent = st[1]
+        self._outer_children = st[2]
+        st[1] = self.name
+        st[2] = 0.0
         self._ann = None
         if _annotating:
             self._ann = _annotation(self.name)
             self._ann.__enter__()
         if self._cpu_hist is not None:
-            key = id(self._cpu_hist)
-            n = _cpu_calls.get(key, 0)
-            _cpu_calls[key] = n + 1
-            if n % CPU_EVERY:
-                self._cpu_hist = None
-            else:
+            if one_in_every(self._cpu_hist):
                 self._cpu0 = time.thread_time()
+            else:
+                self._cpu_hist = None
         if self.start is None:
             self.start = time.perf_counter()
         return self
@@ -322,10 +354,10 @@ class region:
         if self._ann is not None:
             self._ann.__exit__(*exc)
         dt = self.seconds = end - self.start
-        tls = _tls
-        self.self_seconds = dt - tls.children_s
-        tls.children_s = self._outer_children + dt
-        tls.region = self._parent
+        st = self._st
+        self.self_seconds = dt - st[2]
+        st[2] = self._outer_children + dt
+        st[1] = self._parent
         if self._hist is not None:
             self._hist.observe(dt)
         if self._trace is not None:

@@ -854,26 +854,23 @@ def _train_fused(U, V, row_arrays, col_arrays, params: ALSParams, iterations):
 
 def _device_bucket_arrays(buckets: Sequence[PaddedBucket]):
     """Upload bucket arrays once; returned as a tuple usable as a jit arg."""
-    obs_device.count_transfer(
-        "h2d",
-        "train.buckets",
-        sum(
-            b.row_ids.nbytes + b.col_ids.nbytes + b.ratings.nbytes
-            + b.mask.nbytes
-            + (b.seg_row.nbytes if b.seg_row is not None else 0)
-            for b in buckets
-        ),
-    )
-    return tuple(
-        (
-            jnp.asarray(b.row_ids),
-            jnp.asarray(b.col_ids),
-            jnp.asarray(b.ratings),
-            jnp.asarray(b.mask),
-            jnp.asarray(b.seg_row) if b.seg_row is not None else None,
-        )
+    nbytes = sum(
+        b.row_ids.nbytes + b.col_ids.nbytes + b.ratings.nbytes
+        + b.mask.nbytes
+        + (b.seg_row.nbytes if b.seg_row is not None else 0)
         for b in buckets
     )
+    with obs_device.transfer("h2d", "train.buckets", nbytes):
+        return tuple(
+            (
+                jnp.asarray(b.row_ids),
+                jnp.asarray(b.col_ids),
+                jnp.asarray(b.ratings),
+                jnp.asarray(b.mask),
+                jnp.asarray(b.seg_row) if b.seg_row is not None else None,
+            )
+            for b in buckets
+        )
 
 
 # Diagnostics of the most recent als_train / sharded_als_train run in

@@ -123,9 +123,17 @@ stage's conversions, uploads and launch: what it costs to enqueue),
 the one blocking read that ends the chain as ``dispatch.fetch``
 (``pio_retrieval_fetch_seconds``: the device time of both programs and
 the copy back; ``pio_retrieval_host_reads_total`` counts such reads,
-one a dispatch through ``top_k``, and ``pio_retrieval_uploads_total``
-the transfers the other way: one a dispatch under rules — ``pack``'s
-buffer — two for ``UserRows``), and the two serving programs
+one a dispatch through ``top_k``). Below the stages, the crossings of
+the host <-> device boundary are regions of their own: every upload is
+an ``xfer.h2d[serve.dispatch]`` (``_up``; ``obs.device.transfer`` —
+``pio_device_transfer_seconds{direction,op}`` with its bytes and its
+count: one a dispatch under rules — ``pack``'s buffer — two for
+``UserRows``), every launch a ``launch[<fn>]`` (``obs.device.track_jit``,
+``pio_jit_call_seconds{fn}``), and on one dispatch in
+``obs.trace.CPU_EVERY`` the read is told apart into ``fetch.wait`` (the
+device still working: ``pio_retrieval_fetch_wait_seconds``) and
+``xfer.d2h[serve.answers]`` (the copy back) — so a stage's self time is
+its Python: convert, pad, ``pack``. The two serving programs
 carry ``jax.named_scope`` s (``retrieval.shortlist.*`` — a step is
 ``score`` / ``mask`` / ``group_max`` and ``select`` follows the loop —
 and ``retrieval.rescore.*``) that name their ops in a trace viewer.
@@ -257,14 +265,6 @@ _m_gather_bytes = obs_metrics.counter(
     "bytes the sharded chain's all-gather moved: shards x B x k x 8 a "
     "dispatch (every shard's [B, k] f32 scores and int32 ids)",
 )
-_m_shard_h2d = obs_metrics.counter(
-    "pio_retrieval_shard_h2d_copies_total",
-    "device buffers the sharded chain wrote from the host: ONE a host array "
-    "of a dispatch (the queries or the packed buffer, to the mesh's first "
-    "device: the program hands it round; a whiteList's candidate ids are a "
-    "second), and shards - 1 resident zero blocks the first time a shape "
-    "goes up",
-)
 _m_shards = obs_metrics.gauge(
     "pio_retrieval_shards",
     "devices the served catalog's rows are split over (0: one chip)",
@@ -310,11 +310,10 @@ _m_host_reads = obs_metrics.counter(
     "pio_retrieval_host_reads_total",
     "blocking device-to-host reads made by two-stage retrieval",
 )
-_m_uploads = obs_metrics.counter(
-    "pio_retrieval_uploads_total",
-    "host-to-device transfers made by the serving chain: every host array "
-    "a stage converts and puts up, each per-query part of device_rules, "
-    "the one packed buffer of a dispatch under rules",
+_m_fetch_wait = obs_metrics.histogram(
+    "pio_retrieval_fetch_wait_seconds",
+    "the wait in front of the chain's one read: what the device still had "
+    "to do when the host had nothing left to enqueue",
 )
 _m_probe_recall = obs_metrics.gauge(
     "pio_retrieval_probe_recall",
@@ -425,14 +424,22 @@ def stats_block() -> dict:
         "sharded_masked_queries": _m_sharded_masked.value(),
         "shards": int(_m_shards.value()),
         "shard_gather_bytes": _m_gather_bytes.value(),
-        "shard_h2d_copies": _m_shard_h2d.value(),
+        # device buffers a sharded chain wrote from the host: ONE a host
+        # array of a dispatch, and shards - 1 zero blocks once a shape
+        "shard_h2d_copies": obs_device.transfer_count(
+            "h2d", "serve.dispatch", "serve.zero_blocks"
+        ) if _m_shards.value() else 0,
         "load_seconds": {st: m.summary() for st, m in _m_load.items()},
         "shortlist_size": _m_shortlist_size.summary(),
         "shortlist_seconds": _m_shortlist_secs.summary(),
         "rescore_seconds": _m_rescore_secs.summary(),
         "fetch_seconds": _m_fetch_secs.summary(),
         "host_reads": _m_host_reads.value(),
-        "uploads": _m_uploads.value(),
+        # every host array a stage converted and put up, each per-query
+        # part of ``device_rules``, the packed buffer of a dispatch under rules
+        "uploads": obs_device.transfer_count(
+            "h2d", "serve.dispatch", "serve.rules"
+        ),
         "score_form": {f: m.value() for f, m in _m_score_form.items()},
         "coarse_mode": {c: m.value() for c, m in _m_coarse_mode.items()},
         "resident_bytes": {p: m.value() for p, m in _m_resident.items()},
@@ -898,11 +905,14 @@ def device_rules(rules: Rules) -> Rules:
     each: for the callers off ``top_k``'s packed chain (the exact
     programs, a ``whiteList``'s host-built candidates, the tests'
     references)."""
-    _m_uploads.inc(3)
+    def up(part, dtype):
+        part = np.asarray(part, dtype)
+        with obs_device.transfer("h2d", "serve.rules", part.nbytes):
+            return jnp.asarray(part)
+
     return rules._replace(
-        qcat=jnp.asarray(np.asarray(rules.qcat, np.int32)),
-        has_cat=jnp.asarray(np.asarray(rules.has_cat, bool)),
-        ex=jnp.asarray(np.asarray(rules.ex, np.int32)),
+        qcat=up(rules.qcat, np.int32), has_cat=up(rules.has_cat, bool),
+        ex=up(rules.ex, np.int32),
     )
 
 
@@ -925,12 +935,13 @@ def _up(a, dtype, rows: int = 0, put=None):
     converted to ``dtype``, padded to ``rows`` rows with copies of row 0
     (discarded after the read) and uploaded — to the default device, or
     by ``put``, the way a sharded catalog's arrays reach its shards
-    (``ShardedCatalog.put_replicated``)."""
+    (``ShardedCatalog.put_replicated``). The copy alone is the
+    ``xfer.h2d[serve.dispatch]`` region, to its return."""
     if isinstance(a, jax.Array):
         return a
     a = _pad_rows(np.ascontiguousarray(a, dtype=dtype), rows)
-    _m_uploads.inc()
-    return jnp.asarray(a) if put is None else put(a)
+    with obs_device.transfer("h2d", "serve.dispatch", a.nbytes):
+        return jnp.asarray(a) if put is None else put(a)
 
 
 _shortlist_stage = functools.partial(
@@ -943,9 +954,22 @@ def _fetch(out, n: int):
     """The read that ends a chain of launches: device ``(scores, ids)``
     -> their first ``n`` rows on the host, in one ``device_get``, as a
     ``dispatch.fetch`` region. The host waits here, and only here, for
-    whatever the programs behind ``out`` still have to do."""
+    whatever the programs behind ``out`` still have to do. On one call
+    in ``obs.trace.CPU_EVERY`` the two halves are told apart: the wait
+    for the device is ``fetch.wait``, the copy of the finished answer
+    ``xfer.d2h[serve.answers]`` — on that call alone, because a read
+    asked for once the device is done no longer overlaps it: on a TPU
+    v5e the split costs a single's dispatch 0.23 ms (PERF.md section 6,
+    PR 50). One read either way."""
     with obs_trace.region("dispatch.fetch", hist=_m_fetch_secs):
-        s, ids = jax.device_get(out)
+        if obs_trace.one_in_every(_m_fetch_wait):
+            with obs_trace.region("fetch.wait", hist=_m_fetch_wait):
+                jax.block_until_ready(out)
+            with obs_device.transfer("d2h", "serve.answers") as read:
+                s, ids = jax.device_get(out)
+                read.nbytes = s.nbytes + ids.nbytes
+        else:
+            s, ids = jax.device_get(out)
     _m_host_reads.inc()
     return s[:n], ids[:n]
 

@@ -532,21 +532,20 @@ def splice_packed_side(
 def upload_packed_side(ps: PackedSide, mesh: Mesh, axis: str) -> tuple:
     """Place one packed side on the mesh: tables sharded ``P(axis)`` on
     the shard-major dim, scatter row-ids replicated."""
-    obs_device.count_transfer(
-        "h2d",
-        "train.packed_side",
-        ps.row_ids.nbytes + ps.col_ids.nbytes + ps.ratings.nbytes
-        + ps.mask.nbytes + ps.seg.nbytes,
-    )
     table = factor_sharding(mesh, axis)
     repl = replicated_sharding(mesh)
-    return (
-        jax.device_put(ps.row_ids, repl),
-        jax.device_put(ps.col_ids, table),
-        jax.device_put(ps.ratings, table),
-        jax.device_put(ps.mask, table),
-        jax.device_put(ps.seg, table),
+    nbytes = (
+        ps.row_ids.nbytes + ps.col_ids.nbytes + ps.ratings.nbytes
+        + ps.mask.nbytes + ps.seg.nbytes
     )
+    with obs_device.transfer("h2d", "train.packed_side", nbytes):
+        return (
+            jax.device_put(ps.row_ids, repl),
+            jax.device_put(ps.col_ids, table),
+            jax.device_put(ps.ratings, table),
+            jax.device_put(ps.mask, table),
+            jax.device_put(ps.seg, table),
+        )
 
 
 def packed_table_bytes_per_chip(sides: Sequence[PackedSide], shards: int) -> int:

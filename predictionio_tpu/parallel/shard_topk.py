@@ -419,15 +419,20 @@ class ShardedCatalog:
         return retrieval._up(a, dtype, rows, self._stitch)
 
     def _stitch(self, a: np.ndarray):
+        """``_up``'s ``put``: inside its ``xfer.h2d[serve.dispatch]``
+        region, which so times the ONE copy and the stitch; the other
+        shards' zero blocks are copies of their own site, once a shape."""
         block = a[None]
         key = (block.shape, block.dtype)
         zeros = self._zeros.get(key)
         if zeros is None:
-            zeros = self._zeros[key] = [
-                jax.device_put(np.zeros_like(block), d) for d in self._devices[1:]
-            ]
-            retrieval._m_shard_h2d.inc(len(zeros))
-        retrieval._m_shard_h2d.inc()
+            zeros = []
+            for d in self._devices[1:]:
+                with obs_device.transfer(
+                    "h2d", "serve.zero_blocks", block.nbytes
+                ):
+                    zeros.append(jax.device_put(np.zeros_like(block), d))
+            self._zeros[key] = zeros
         return jax.make_array_from_single_device_arrays(
             (self.shards, *a.shape), self._split,
             [jax.device_put(block, self._devices[0]), *zeros],

@@ -429,13 +429,16 @@ class ALSFoldIn:
             return np.concatenate([a, np.repeat(a[:1], pad, axis=0)])
 
         sent = (up(ixs), up(rows)) + (() if rows_s is None else (up(rows_s),))
+        nbytes = sum(a.nbytes for a in sent)
+        with obs_device.transfer("h2d", "serve.model_patch", nbytes):
+            on_device = jax.device_put(sent)
         users_dev = retrieval.patch_rows(
-            model.device_factors()[0], sent[0],
-            sent[1] if rows_s is None else sent[1:],
+            model.device_factors()[0], on_device[0],
+            on_device[1] if rows_s is None else on_device[1:],
         )
         retrieval.set_resident(users=users_dev)
         patched = model.patched(user_index, values, scales, users_dev)
-        patched.patch_h2d_bytes = sum(a.nbytes for a in sent)
+        patched.patch_h2d_bytes = nbytes
         _m_rows.inc(len(users))
         _m_added.inc(len(new_ids))
         _m_h2d.inc(patched.patch_h2d_bytes)

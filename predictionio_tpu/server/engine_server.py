@@ -382,18 +382,27 @@ class _MicroBatcher:
         next batch — with the slot HELD, ``_loop`` gives it back — or
         None once the batcher has stopped. One ``batch.collect``
         annotation covers the whole wait, so a profile can put an idle
-        device down to "no request was queued"."""
+        device down to "no request was queued" — the whole wait INSIDE a
+        capture: an annotation is decided where it is entered, and since
+        lone queries are scored on their request threads this thread can
+        sit here for minutes, so the wait is entered again when a
+        capture begins or ends."""
         import queue
 
         clock = self.clock
-        with obs_trace.annotate("batch.collect"):
-            while not self._stopped:
-                try:
-                    first = self._q.get(timeout=0.05)
-                except queue.Empty:
-                    # idle so far is counted now: a scrape never finds
-                    # more than this timeout of the worker's time missing
-                    clock.to("idle")
+        while not self._stopped:
+            with obs_trace.annotate("batch.collect"):
+                capture = obs_trace.annotating()
+                first = None
+                while not self._stopped and capture == obs_trace.annotating():
+                    try:
+                        first = self._q.get(timeout=0.05)
+                        break
+                    except queue.Empty:
+                        # idle so far is counted now: a scrape never finds
+                        # more than this timeout of the worker's time missing
+                        clock.to("idle")
+                if first is None:
                     continue
                 clock.to("collect")
                 # the slot BEFORE the rest of the queue: the batch is
@@ -599,15 +608,18 @@ class _Variant:
             self._epoch += 1
             self._foldin_epoch += 1
             epoch = self._epoch
-        # book what went up: a patched model that holds the served one's
-        # resident item side sent its rows; one that does not goes up whole
-        sent = 0
+        # a patched model that holds the served one's resident item side
+        # sent its rows, and the fold booked that copy where it made it
+        # (``xfer.h2d[serve.model_patch]``); one that does not goes up
+        # whole at its next query: booked here, with no copy to time
+        whole = 0
         for old, new in zip(served, models):
             nbytes, restaged = _patch_cost(old, new)
-            sent += nbytes
+            if restaged or getattr(new, "patch_h2d_bytes", None) is None:
+                whole += nbytes
             for part in restaged:
                 obs_device.count_restage(part)
-        obs_device.count_transfer("h2d", "serve.model_patch", sent)
+        obs_device.count_transfer("h2d", "serve.model_patch", whole)
         if self.query_cache is not None:
             self.query_cache.sweep(epoch, variant=self.name)
         return True

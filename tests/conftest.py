@@ -57,15 +57,20 @@ def storage():
 @pytest.fixture()
 def region_uploads(monkeypatch):
     """``watch(name)`` -> a list that gets, for every later region
-    ``name``, (how far ``pio_retrieval_uploads_total`` moved inside it,
-    the ``jnp.asarray`` / ``jax.device_put`` calls made inside it): what
-    the template tests hold the build regions to."""
+    ``name``, (how many uploads of the serving chain the transfer family
+    booked inside it — ``pio_device_transfers_total{direction="h2d"}`` at
+    ``serve.dispatch`` and ``serve.rules`` — the ``jnp.asarray`` /
+    ``jax.device_put`` calls made inside it): what the template tests
+    hold the build regions to."""
     import jax.numpy as jnp
 
+    from predictionio_tpu.obs import device as obs_device
     from predictionio_tpu.obs import trace as obs_trace
-    from predictionio_tpu.ops import retrieval
 
     open_, calls = [], []
+
+    def uploads():
+        return obs_device.transfer_count("h2d", "serve.dispatch", "serve.rules")
 
     def watch(name):
         seen = []
@@ -73,15 +78,13 @@ def region_uploads(monkeypatch):
         class Watched(obs_trace.region):
             def __enter__(self):
                 if self.name == name:
-                    open_.append((retrieval._m_uploads.value(), len(calls)))
+                    open_.append((uploads(), len(calls)))
                 return super().__enter__()
 
             def __exit__(self, *exc):
                 if self.name == name:
                     before, n = open_.pop()
-                    seen.append(
-                        (retrieval._m_uploads.value() - before, calls[n:])
-                    )
+                    seen.append((uploads() - before, calls[n:]))
                 return super().__exit__(*exc)
 
         monkeypatch.setattr(obs_trace, "region", Watched)

@@ -630,13 +630,15 @@ class TestSubThresholdParity:
 
 
 class TestStageSpans:
-    def test_stages_record_themselves_and_nothing_carries_over(self):
+    def test_stages_record_themselves_and_nothing_carries_over(self, monkeypatch):
         """The two stages land on the current trace as spans at their
         true start and end, children of the enclosing region — and a
         call made with no trace leaves nothing behind for the next one
         (the thread-local side channel this replaced added up)."""
         from predictionio_tpu.obs import trace as obs_trace
 
+        # the read is told apart on one call in CPU_EVERY: here on every one
+        monkeypatch.setattr(obs_trace, "CPU_EVERY", 1)
         v = _dense(300, 8, seed=27)
         cat = CoarseCatalog(v, tile=256)
         q = _dense(2, 8, seed=28)
@@ -648,14 +650,28 @@ class TestStageSpans:
             with obs_trace.region("batch.dispatch[2]") as outer:
                 _, cand = cat.shortlist(q, 32)
                 retrieval.rescore_top_k_batch(q, v, cand, k=8)
-        # each host-facing stage is its launch, then its own read
-        assert [name for name, *_ in tr.spans] == [
-            "dispatch.shortlist", "dispatch.fetch", "dispatch.rescore",
-            "dispatch.fetch", "batch.dispatch[2]",
+        # each host-facing stage is its launch, then its own read; below
+        # a stage, its crossings: every upload, the launch, the wait and
+        # the copy back, each a child of its stage
+        assert [(name, parent) for name, _, _, parent in tr.spans] == [
+            ("xfer.h2d[serve.dispatch]", "dispatch.shortlist"),
+            ("launch[retrieval.coarse_topk]", "dispatch.shortlist"),
+            ("dispatch.shortlist", "batch.dispatch[2]"),
+            ("fetch.wait", "dispatch.fetch"),
+            ("xfer.d2h[serve.answers]", "dispatch.fetch"),
+            ("dispatch.fetch", "batch.dispatch[2]"),
+            ("xfer.h2d[serve.dispatch]", "dispatch.rescore"),  # the ids
+            ("xfer.h2d[serve.dispatch]", "dispatch.rescore"),  # the vectors
+            ("launch[retrieval.rescore_vectors]", "dispatch.rescore"),
+            ("dispatch.rescore", "batch.dispatch[2]"),
+            ("fetch.wait", "dispatch.fetch"),
+            ("xfer.d2h[serve.answers]", "dispatch.fetch"),
+            ("dispatch.fetch", "batch.dispatch[2]"),
+            ("batch.dispatch[2]", None),
         ]
+        stages = [sp for sp in tr.spans if sp[3] in ("batch.dispatch[2]", None)]
         (s_off, s_dur), (f1_off, f1_dur), (r_off, r_dur), (f2_off, f2_dur), \
-            (d_off, d_dur) = [(off, dur) for _, off, dur, _ in tr.spans]
-        assert {parent for *_, parent in tr.spans[:4]} == {"batch.dispatch[2]"}
+            (d_off, d_dur) = [(off, dur) for _, off, dur, _ in stages]
         assert min(s_dur, f1_dur, r_dur, f2_dur) > 0
         # inside the parent, in order, not overlapping
         assert d_off <= s_off and s_off + s_dur <= f1_off

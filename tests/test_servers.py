@@ -1317,6 +1317,20 @@ def cached_engine(deployed_engine):
     server.stop()
 
 
+def _scraped_uploads(scraped) -> float:
+    """The serving chain's uploads in a ``/metrics`` scrape: the copies —
+    and as many timed observations — of the transfer family's ``h2d``
+    sites ``serve.dispatch`` (every host array a stage converted) and
+    ``serve.rules`` (each per-query part of ``device_rules``)."""
+    total = 0.0
+    for op in ("serve.dispatch", "serve.rules"):
+        site = f'{{direction="h2d",op="{op}"}}'
+        n = scraped.get("pio_device_transfers_total" + site, 0.0)
+        assert scraped.get("pio_device_transfer_seconds_count" + site, 0.0) == n
+        total += n
+    return total
+
+
 class TestQueryCacheServing:
     def _count_predict(self, server):
         """Wrap the deployed algorithm's predict with a call
@@ -1409,7 +1423,7 @@ class TestQueryCacheServing:
     def test_stats_route_carries_the_upload_counter(self, deployed_engine):
         """``retrieval.uploads`` beside ``retrieval.host_reads``: the
         host-to-device transfers of the serving chain, as ``/metrics``
-        has them under ``pio_retrieval_uploads_total``."""
+        has them in the transfer family (``_scraped_uploads``)."""
         import numpy as np
 
         from predictionio_tpu.obs import metrics as obs_metrics
@@ -1423,7 +1437,7 @@ class TestQueryCacheServing:
             with urllib.request.urlopen(base + "/metrics", timeout=10) as r:
                 scraped = obs_metrics.parse_prometheus(r.read().decode())
             block = body["retrieval"]
-            assert scraped["pio_retrieval_uploads_total"] == block["uploads"]
+            assert _scraped_uploads(scraped) == block["uploads"]
             assert scraped["pio_retrieval_host_reads_total"] == block["host_reads"]
             return block["uploads"]
 
@@ -1463,7 +1477,7 @@ class TestQueryCacheServing:
                 assert scraped[
                     f'pio_retrieval_score_form_total{{form="{f}"}}'
                 ] == n
-            assert scraped["pio_retrieval_uploads_total"] == block["uploads"]
+            assert _scraped_uploads(scraped) == block["uploads"]
             assert scraped["pio_retrieval_host_reads_total"] == block["host_reads"]
             return {**block["score_form"], "uploads": block["uploads"],
                     "host_reads": block["host_reads"]}

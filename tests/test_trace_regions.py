@@ -26,12 +26,24 @@ from predictionio_tpu.obs import trace as obs_trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-CHAIN = (
+STAGES = (
     "http.handoff", "http.read_parse", "dispatch", "serve", "serve.submit",
     "batch.queue_wait", "batch.dispatch[1]", "dispatch.shortlist",
     "dispatch.rescore", "dispatch.fetch", "serve.wake", "serve.tail",
     "http.write",
 )
+# below the stages, the crossings of a ``UserRows`` dispatch (PR 50): the
+# vectors up and the scan launched, the indices up behind it and the
+# rescore launched, the wait and the copy back — once a crossing
+CROSSINGS = (
+    ("xfer.h2d[serve.dispatch]", "dispatch.shortlist"),
+    ("launch[retrieval.coarse_topk]", "dispatch.shortlist"),
+    ("xfer.h2d[serve.dispatch]", "dispatch.rescore"),
+    ("launch[retrieval.rescore_gather]", "dispatch.rescore"),
+    ("fetch.wait", "dispatch.fetch"),
+    ("xfer.d2h[serve.answers]", "dispatch.fetch"),
+)
+CHAIN = STAGES + tuple(name for name, _ in CROSSINGS)
 PARENTS = {
     "http.handoff": None, "http.read_parse": None, "dispatch": None,
     "http.write": None, "serve": "dispatch", "serve.submit": "serve",
@@ -210,6 +222,8 @@ def served(storage, monkeypatch, request):
     monkeypatch.setenv("PIO_RETRIEVAL_THRESHOLD", "16")
     monkeypatch.setenv("PIO_RETRIEVAL_TILE", "16")
     monkeypatch.setenv("PIO_RETRIEVAL_PROBE_EVERY", "0")
+    # the read is told apart on one dispatch in CPU_EVERY: here on every one
+    monkeypatch.setattr(obs_trace, "CPU_EVERY", 1)
     info = commands.app_new("TraceChainApp", storage=storage)
     events = storage.get_events()
     rng = np.random.default_rng(0)
@@ -310,8 +324,15 @@ class TestServingChain:
         assert sorted(names) == sorted(CHAIN), names
         by_name = {s["name"]: s for s in spans}
         eps = 2e-3  # offsets and durations are rounded to 1 us each
+        assert sorted(
+            (s["name"], s["parent"]) for s in spans if s["name"] not in PARENTS
+        ) == sorted(CROSSINGS)
+        for stage in ("dispatch.shortlist", "dispatch.rescore", "dispatch.fetch"):
+            inside = sum(s["durationMs"] for s in spans if s["parent"] == stage)
+            assert inside <= by_name[stage]["durationMs"] + 4 * eps, stage
         for s in spans:
-            assert s["parent"] == PARENTS[s["name"]], s
+            if s["name"] in PARENTS:
+                assert s["parent"] == PARENTS[s["name"]], s
             assert s["offsetMs"] >= -eps
             if s["parent"] is not None:
                 p = by_name[s["parent"]]
@@ -438,7 +459,14 @@ class TestServingChain:
         staged = {got.pop(n, "batch.dispatch[1]")
                   for n in ("model.stage_table", "model.coarse_build")}
         assert staged == {"batch.dispatch[1]"}
-        assert got == {
+        # staging launches and copies of its own, under the staging spans
+        staging = ("model.stage_table", "model.coarse_build", "batch.dispatch[1]")
+        crossings = sorted(
+            (s[0], s[3]) for s in tr.spans
+            if s[0].startswith(("xfer.", "launch[", "fetch.")) and s[3] not in staging
+        )
+        assert crossings == sorted(CROSSINGS)
+        assert {n: p for n, p in got.items() if n.startswith(("dispatch.", "batch."))} == {
             "dispatch.shortlist": "batch.dispatch[1]",
             "dispatch.rescore": "batch.dispatch[1]",
             "dispatch.fetch": "batch.dispatch[1]",
